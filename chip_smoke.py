@@ -1,0 +1,740 @@
+"""Does the system start on the chip? The quickest proof, kept at the root.
+
+Drives the main path once through the entry points a user calls, at the
+full width and depth of the repo's "1b" Llama (d_model 2048, 22 layers,
+16 heads / 8 kv heads, d_ff 5632, vocabulary 32,128, bf16 compute;
+random weights from ``--seed``), with small request and step counts:
+
+    python chip_smoke.py             # one chip: serve, train, kernels
+    python chip_smoke.py --chips 4   # four chips: sharded train, 4 replicas
+
+- serve: ``ray_tpu.init()`` -> ``serve.run(Deployment(LLMPool, ...))`` with
+  one decode replica that its node agent granted the chip -> requests
+  over the HTTP proxy (one streamed, greedy, seeded-sampled twice), once
+  with speculative decoding on and once, from a second replica start,
+  with it off. Every request returns the asked number of tokens, seed
+  replay is exact, the replica reports the TPU from inside its own
+  process, no other process holds a chip, and the second start reads
+  compiled programs from the cache. Spec-on and spec-off tokens are
+  compared: equal in f32; in bf16 they part at near-tied logits, so the
+  agreement is reported (see ``serve_phase``).
+- train: ``JaxTrainer``, one worker that owns the chip, bench.py's 1B
+  recipe (b2 x T2048, fused_adamw with bf16 moments, bf16 grads,
+  flash_qkv remat) for a few steps: finite, falling loss, the flash
+  kernel in the compiled step, peak HBM reported by the worker.
+- kernels: in a child that holds the chip, flash forward and backward at
+  the 1B shape against ``attention_reference``, and the compiled 1B
+  forward has the kernel in it.
+
+With ``--chips 4`` only what exists across chips runs, each beside what
+it is compared with: the 1B train step on an fsdp=2 x tp=2 mesh in one
+worker against the same step on one chip, and LLMPool with four decode
+replicas, each its own process on its own chip, against one replica.
+
+This process never initialises a JAX backend: a chip belongs to one
+process at a time, and here that is always a worker. Every phase prints
+one JSON line. The last line of a run that passed is exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+as the chip-holding workers reported it; any failure exits non-zero
+without that line. Needs no network and stops what it starts.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import http.client
+import json
+import os
+import shutil
+import statistics
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+KERNEL = "tpu_custom_call"
+
+
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """What a run drives. The defaults are the contract: full 1B widths
+    and depth on the TPU. tests/test_chip_smoke.py rehearses the control
+    flow on the CPU with ``Plan.tiny()``; the command line cannot."""
+
+    model_size: str = "1b"
+    platform: str = "tpu"
+    chips: int = 1
+    seed: int = 0
+    # serve: 8 slots x 288 rows, prompts of 128 and 20 tokens (buckets
+    # 128 and 32), 24 new tokens; speculation depth 4 on a 1-layer draft
+    slots: int = 8
+    max_len: int = 288
+    chunk_tokens: int = 8
+    prompt_buckets: tuple = (32, 128)
+    prompt_lens: tuple = (128, 20)
+    max_tokens: int = 24
+    # train: bench.py's 1B recipe
+    batch: int = 2
+    seq: int = 2048
+    steps: int = 4
+    # kernels: (batch, seq, q heads, kv heads, head dim) of the 1B step
+    flash_shape: tuple = (2, 2048, 16, 8, 128)
+
+    @staticmethod
+    def tiny(**kw) -> "Plan":
+        base = dict(model_size="tiny", platform="cpu", slots=4, max_len=96,
+                    chunk_tokens=4, prompt_buckets=(8, 32),
+                    prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
+                    steps=3, flash_shape=(2, 128, 4, 2, 64))
+        return Plan(**{**base, **kw})
+
+    @property
+    def on_tpu(self) -> bool:
+        return self.platform == "tpu"
+
+
+class SmokeFailure(AssertionError):
+    """A check of a phase did not hold."""
+
+
+def check(cond, what: str, **facts):
+    if not cond:
+        raise SmokeFailure(f"{what}: {json.dumps(facts, default=str)}")
+
+
+# --------------------------------------------------------------- set-up
+
+
+def build_native() -> dict:
+    """Build the C++ scheduler and object store from the committed .cc
+    files. Any .so already lying there was built by someone else from
+    who knows what: it goes first. No toolchain, or a failed build, is an
+    error here — not a silent pure-Python scheduler."""
+    from ray_tpu import _native
+    from ray_tpu.core.object_store import _build as store_build
+
+    t0 = time.monotonic()
+    for so in (os.path.join(os.path.dirname(_native.__file__),
+                            "_scheduler.so"), store_build.SO):
+        if os.path.exists(so):
+            os.remove(so)
+    built = [_native.ensure_built("scheduler"), store_build.ensure_built()]
+    return {"built": [os.path.relpath(p, REPO) for p in built],
+            "seconds": round(time.monotonic() - t0, 1)}
+
+
+def model_fields(size: str, max_len: int, **kw) -> dict:
+    """LlamaConfig fields of the model a phase runs: the repo's named
+    size with the 32,128 vocabulary in bf16 (as serve/llm.py and bench.py
+    build it), or the test-sized stand-in for the CPU rehearsal."""
+    from ray_tpu.models import llama
+
+    if size == "tiny":
+        base = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+                    n_kv_heads=2, d_ff=128, dtype="float32")
+    else:
+        base = {**llama.llama2_size(size).__dict__, "vocab_size": 32128,
+                "dtype": "bfloat16"}
+    return {**base, "max_seq_len": max_len, **kw}
+
+
+def store_bytes(plan: Plan) -> int:
+    """Object store for the run: the pool publishes the f32 master tree
+    as ONE object (4,660 MiB at 1B widths). Fails with the reason if
+    shared memory here cannot hold it."""
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(**model_fields(plan.model_size, plan.max_len))
+    need = int(cfg.num_params() * 4 * 1.25) + 2**30
+    free = shutil.disk_usage("/dev/shm").free
+    check(free > need + 2**30,
+          "shared memory cannot hold the published weights",
+          need_bytes=need, dev_shm_free_bytes=free)
+    return need
+
+
+def wait_chips_free(timeout: float = 60.0) -> None:
+    """Each chip-holding process is gone before the next one starts."""
+    from ray_tpu._private import accelerator
+
+    deadline = time.monotonic() + timeout
+    while accelerator.chip_holders():
+        check(time.monotonic() < deadline, "a process still holds a chip",
+              holders=accelerator.chip_holders())
+        time.sleep(0.2)
+
+
+def check_device(plan: Plan, dev: dict, chips: int, who: str) -> dict:
+    """``dev`` is what a worker reported from inside its own process."""
+    check(dev["platform"] == plan.platform, f"{who} is on the wrong platform",
+          want=plan.platform, got=dev)
+    if plan.on_tpu:
+        check(dev["count"] == chips and len(dev["granted_chips"]) == chips
+              and len(dev["nodes"]) == chips,
+              f"{who} does not see exactly its {chips} chip(s)", got=dev)
+    return {"platform": dev["platform"], "kind": dev["kind"],
+            "count": dev["count"]}
+
+
+# ---------------------------------------------------------------- serve
+
+
+def _post(addr, body: dict, timeout: float = 300.0):
+    conn = http.client.HTTPConnection(*addr, timeout=timeout)
+    try:
+        conn.request("POST", "/llm", json.dumps(body),
+                     {"Content-Type": "application/json"})
+        r = conn.getresponse()
+        if r.status != 200 or not body.get("stream"):
+            return r.status, json.loads(r.read() or b"null")
+        check(r.getheader("Transfer-Encoding") == "chunked",
+              "asked for a stream, got one plain reply (the proxy falls "
+              "back to it when the deployment refuses the stream)",
+              headers=r.getheaders())
+        toks, done = [], False
+        for line in r:  # http.client de-chunks the NDJSON line by line
+            msg = json.loads(line) if line.strip() else {}
+            check("error" not in msg, "stream failed mid-way", msg=msg)
+            toks.extend(msg.get("tokens", ()))
+            done = done or bool(msg.get("done"))
+        check(done, "stream ended without its done message", tokens=toks)
+        return 200, {"tokens": toks}
+    finally:
+        conn.close()
+
+
+def ask(addr, body: dict) -> list:
+    """One request over HTTP -> its tokens. A 404 means the proxy has
+    not learnt the route yet; anything else that is not 200 fails."""
+    deadline = time.monotonic() + 60.0
+    while True:
+        status, payload = _post(addr, body)
+        if status == 404 and time.monotonic() < deadline:
+            time.sleep(0.5)
+            continue
+        check(status == 200, "request failed", status=status,
+              payload=payload, body={**body, "prompt_ids": "..."})
+        return [int(t) for t in payload["tokens"]]
+
+
+def serve_requests(plan: Plan) -> dict:
+    """The handful of requests every pool in this run answers."""
+    import random
+
+    rnd = random.Random(plan.seed)
+    vocab = model_fields(plan.model_size, 0)["vocab_size"]
+    long_p, short_p = ([rnd.randrange(1, vocab) for _ in range(n)]
+                       for n in plan.prompt_lens)
+    n = plan.max_tokens
+    # In this order. The stream goes first: it is polled, so the cold
+    # compiles it sets off (a prefill bucket and the decode chunk) cannot
+    # run into the proxy's 120 s limit on a plain request. Greedy before
+    # sampled: a spec-off engine decodes with the argmax chunk until it
+    # has seen a sampled request, and both chunks should run.
+    return {
+        "streamed": {"prompt_ids": short_p, "max_tokens": n,
+                     "stream": True},
+        "greedy": {"prompt_ids": long_p, "max_tokens": n},
+        "sampled": {"prompt_ids": long_p, "max_tokens": n,
+                    "temperature": 1.0, "top_p": 0.9, "seed": 1234},
+    }
+
+
+def run_pool(plan: Plan, *, replicas: int, spec: bool, copies: int = 1):
+    """Deploy one LLMPool, answer the requests (``copies`` of each at
+    once, so that every replica of a wide pool gets some), check what
+    must hold inside one pool, tear it down. -> (answers, facts)."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu._private import accelerator
+    from ray_tpu.serve.api import Deployment
+    from ray_tpu.serve.llm_pool import LLMPool
+
+    t0 = time.monotonic()
+    dep = Deployment(LLMPool, max_concurrent_queries=64,
+                     resources={"CPU": 0}, route_prefix="/llm")
+    serve.run(dep, name="llm", init_kwargs=dict(
+        model_size=plan.model_size, slots=plan.slots, max_len=plan.max_len,
+        chunk_tokens=plan.chunk_tokens, prompt_buckets=plan.prompt_buckets,
+        seed=plan.seed, min_replicas=replicas, max_replicas=replicas,
+        prefill_workers=0, autoscale=False, chunk_delay_s=0.0,
+        spec_depth=4 if spec else 0, spec_draft_layers=1 if spec else 0))
+    try:
+        addr = serve.start_http_proxy()
+        start_s = time.monotonic() - t0
+        answers: dict = {}
+        first_s: dict = {}
+        for name, body in serve_requests(plan).items():
+            t1 = time.monotonic()
+            got: list = [None] * copies
+            errs: list = []
+
+            def one(i, body=body):
+                try:
+                    got[i] = ask(addr, body)
+                except BaseException as e:  # noqa: BLE001 — re-raised below
+                    errs.append(e)
+
+            threads = [threading.Thread(target=one, args=(i,))
+                       for i in range(copies)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join()
+            if errs:
+                raise errs[0]
+            first_s[name] = round(time.monotonic() - t1, 2)
+            check(all(len(t) == plan.max_tokens for t in got),
+                  f"{name}: wrong number of tokens",
+                  want=plan.max_tokens, got=[len(t) for t in got])
+            check(all(t == got[0] for t in got),
+                  f"{name}: replicas disagree on the same request", got=got)
+            answers[name] = got[0]
+        replay = ask(addr, serve_requests(plan)["sampled"])
+        check(replay == answers["sampled"], "seed replay is not exact",
+              first=answers["sampled"], again=replay)
+
+        st = ray_tpu.get(serve.get_handle("llm").method("stats").remote(),
+                         timeout=120)
+        reps = st["per_replica"]
+        check(len(reps) == replicas and all(
+            "device" in r for r in reps.values()), "replica stats missing",
+            got=reps)
+        devices = [check_device(plan, r["device"], 1, name)
+                   for name, r in reps.items()]
+        check(st["device"]["platform"] == "cpu",
+              "the pool process must not be on a chip", got=st["device"])
+        holders = accelerator.chip_holders()
+        want = {r["device"]["pid"] for r in reps.values()} \
+            if plan.on_tpu else set()
+        check(set(holders) == want,
+              "chips are held by other processes than the decode replicas "
+              "(pool, proxy and driver must hold none)",
+              holders=holders, replica_pids=sorted(want))
+        if plan.on_tpu:
+            nodes = [tuple(r["device"]["nodes"]) for r in reps.values()]
+            check(len(set(nodes)) == replicas,
+                  "replicas do not hold distinct chips", nodes=nodes)
+        check(all(r["total_tokens"] > 0 for r in reps.values()),
+              "a replica answered nothing",
+              tokens={k: r["total_tokens"] for k, r in reps.items()})
+        if spec:
+            check(all(r["spec"]["accepted"] > 0 for r in reps.values()),
+                  "speculation accepted no draft token",
+                  spec={k: r.get("spec") for k, r in reps.items()})
+        facts = {
+            "replicas": replicas, "spec": spec, "device": devices[0],
+            "chips": sorted(r["device"]["nodes"] for r in reps.values()),
+            "start_s": round(start_s, 1), "first_request_s": first_s,
+            "compile": {k: r["device"]["compile"] for k, r in reps.items()},
+            "peak_bytes_in_use": {k: r["device"]["peak_bytes_in_use"]
+                                  for k, r in reps.items()},
+            "spec_acceptance": {k: r["spec"]["acceptance_rate"]
+                                for k, r in reps.items() if "spec" in r},
+        }
+        return answers, facts
+    finally:
+        # the pool's replicas are its own actors: deleting the
+        # deployment would orphan them, still holding their chips
+        ray_tpu.get(serve.get_handle("llm").method("shutdown").remote(),
+                    timeout=120)
+        serve.shutdown()
+        wait_chips_free()
+
+
+def check_same_answers(a: dict, b: dict, what: str) -> None:
+    for name in a:
+        check(a[name] == b[name], f"{what}: {name} tokens differ",
+              first=a[name], second=b[name])
+
+
+def agreement(a: dict, b: dict) -> dict:
+    """Per request, how many leading tokens two pools agree on."""
+    return {name: next((i for i, (x, y) in enumerate(zip(a[name], b[name]))
+                        if x != y), len(a[name])) for name in a}
+
+
+def serve_phase(plan: Plan) -> dict:
+    """Speculation on, then off from a second replica start. The two
+    decode with different programs (a depth+1-wide verify against a
+    1-wide step). In f32 those give the same tokens bit for bit, which
+    is what the CPU rehearsal holds them to. In bf16 — what every named
+    size serves in — they round differently, and with random weights
+    the top two of 32,128 logits are often closer than that rounding
+    (tests/test_decode_spec.py pins both facts): on the chip the first
+    token, which both pools take from the same prefill program, must
+    agree, and how far the rest agrees is reported, not asserted."""
+    on, on_facts = run_pool(plan, replicas=1, spec=True)
+    off, off_facts = run_pool(plan, replicas=1, spec=False)
+    agree = agreement(on, off)
+    exact = model_fields(plan.model_size, 0)["dtype"] == "float32"
+    check(all(n == plan.max_tokens if exact else n >= 1
+              for n in agree.values()),
+          "speculation on and off disagree where they must not",
+          agree=agree, on=on, off=off)
+    warm = next(iter(off_facts["compile"].values()))
+    if warm["requests"]:  # the persistent cache is on in this run
+        check(warm["hits"] > 0,
+              "the second replica start found nothing in the compile cache",
+              compile=off_facts["compile"])
+    cold = next(iter(on_facts["compile"].values()))
+    return {"device": on_facts["device"], "tokens_per_request":
+            plan.max_tokens, "seed_replay_exact": True,
+            "spec_on_vs_off_agreeing_tokens": agree,
+            "compile_s": cold["seconds"], "compile_s_second_start":
+            warm["seconds"], "spec_on": on_facts, "spec_off": off_facts}
+
+
+def pool4_phase(plan: Plan) -> dict:
+    """Four decode replicas, each its own process on its own chip,
+    answer the same seeded requests with the tokens one replica gives."""
+    one, one_facts = run_pool(plan, replicas=1, spec=False)
+    four, four_facts = run_pool(plan, replicas=4, spec=False, copies=8)
+    check_same_answers(one, four, "one replica vs four")
+    return {"device": four_facts["device"], "distinct_chips":
+            four_facts["chips"], "tokens_equal_one_replica": True,
+            "compile_s": next(iter(one_facts["compile"].values()))["seconds"],
+            "one_replica": one_facts, "four_replicas": four_facts}
+
+
+# ---------------------------------------------------------------- train
+
+
+def _train_loop(config: dict) -> None:
+    """Runs in the train worker (shipped by value): bench.py's recipe on
+    the mesh the config names, a few steps on one seeded batch."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import MeshConfig, build_mesh, use_mesh
+    from ray_tpu.train import (batch_sharding, init_train_state,
+                               make_train_step, session)
+    from ray_tpu.train.optim import fused_adamw
+
+    cfg = llama.LlamaConfig(**config["model"])
+    mesh = build_mesh(MeshConfig(**config["mesh"]), jax.devices())
+    opt = fused_adamw(1e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16,
+                      nu_dtype=jnp.bfloat16)
+    state, state_sh = init_train_state(
+        lambda k: llama.init_params(cfg, k), llama.param_logical_axes(cfg),
+        opt, mesh, key=jax.random.PRNGKey(config["seed"]))
+    step = make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh, state_sh,
+        compute_grad_norm=False, grads_dtype=jnp.bfloat16)
+    toks = np.random.RandomState(config["seed"]).randint(
+        0, cfg.vocab_size, (config["batch"], config["seq"] + 1)
+    ).astype(np.int32)
+    losses, step_s = [], []
+    with use_mesh(mesh):
+        data = jax.device_put(
+            {"inputs": toks[:, :-1], "targets": toks[:, 1:]},
+            batch_sharding(mesh))
+        t0 = time.monotonic()
+        compiled = step.lower(state, data).compile()
+        compile_s = time.monotonic() - t0
+        text = compiled.as_text()
+        for _ in range(config["steps"]):
+            t0 = time.monotonic()
+            state, metrics = compiled(state, data)
+            losses.append(float(metrics["loss"]))  # waits for the device
+            step_s.append(time.monotonic() - t0)
+    session.report({
+        "losses": losses, "step_s": step_s, "compile_s": compile_s,
+        "has_kernel": "tpu_custom_call" in text,
+        "collectives": {op: text.count(op) for op in (
+            "all-reduce(", "all-gather(", "reduce-scatter(")},
+        "device": accelerator.device_report(),
+        "params_bytes_per_device": [
+            sum(s.data.nbytes for leaf in jax.tree.leaves(state.params)
+                for s in leaf.addressable_shards if s.device == d)
+            for d in jax.local_devices()],
+    })
+
+
+def run_trainer(plan: Plan, *, chips: int, mesh: dict, batch: int) -> dict:
+    """One JaxTrainer run on a worker that owns ``chips`` chips."""
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    res = {"CPU": 1, **({"TPU": chips} if plan.on_tpu else {})}
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_train_") as tmp:
+        result = JaxTrainer(
+            _train_loop,
+            train_loop_config={
+                "model": model_fields(
+                    plan.model_size, plan.seq, remat=True,
+                    remat_policy="flash_qkv"),
+                "mesh": mesh, "seed": plan.seed, "batch": batch,
+                "seq": plan.seq, "steps": plan.steps},
+            scaling_config=ScalingConfig(
+                num_workers=1, resources_per_worker=res,
+                platform=plan.platform,
+                # the CPU rehearsal widens its worker to a virtual mesh
+                devices_per_worker=None if plan.on_tpu else chips),
+            run_config=RunConfig(name="chip_smoke", storage_path=tmp),
+        ).fit()
+    wait_chips_free()
+    out = dict(result.metrics)
+    losses = out["losses"]
+    check(all(x == x and abs(x) < 1e4 for x in losses)
+          and losses[-1] < losses[0], "loss is not finite and falling",
+          losses=losses)
+    check(out["has_kernel"] == plan.on_tpu,
+          "flash kernel in the compiled step: expected on the TPU only",
+          has_kernel=out["has_kernel"])
+    out["device_summary"] = check_device(plan, out["device"], chips,
+                                         "train worker")
+    return out
+
+
+def _train_facts(out: dict, batch: int) -> dict:
+    return {"batch": batch, "losses": out["losses"],
+            "compile_s": round(out["compile_s"], 1),
+            "compile_cache": out["device"]["compile"],
+            "step_s_median": round(statistics.median(out["step_s"][1:]), 4),
+            "peak_bytes_in_use": out["device"]["peak_bytes_in_use"],
+            "kernel_in_step": out["has_kernel"]}
+
+
+def train_phase(plan: Plan) -> dict:
+    """bench.py's 1B recipe. The compiler puts the b2 step at 16,352 MiB
+    of a 16 GB chip; if the chip refuses it the batch is cut to 1, never
+    a width, the depth or the vocabulary, and the cut is reported."""
+    from ray_tpu.train.backend_executor import TrainingFailedError
+
+    batch = plan.batch
+    try:
+        out = run_trainer(plan, chips=1, mesh={}, batch=batch)
+    except TrainingFailedError as e:
+        if "RESOURCE_EXHAUSTED" not in str(e) or batch == 1:
+            raise
+        print(f"train: batch {batch} does not fit the chip, cutting to 1",
+              file=sys.stderr, flush=True)
+        wait_chips_free()
+        batch = 1
+        out = run_trainer(plan, chips=1, mesh={}, batch=batch)
+    return {"device": out["device_summary"], **_train_facts(out, batch),
+            "batch_cut_to_fit": batch != plan.batch}
+
+
+LOSS_TOLERANCE = 0.05  # |loss(4 chips) - loss(1 chip)|, bf16 compute
+
+
+def train4_phase(plan: Plan) -> dict:
+    """The same seeded global batch on one chip and on an fsdp=2 x tp=2
+    mesh over four: losses agree, the kernel is in the sharded step, and
+    all four devices hold parameters."""
+    one = run_trainer(plan, chips=1, mesh={}, batch=plan.batch)
+    four = run_trainer(plan, chips=4, mesh={"fsdp": 2, "tp": 2},
+                       batch=plan.batch)
+    diffs = [abs(a - b) for a, b in zip(one["losses"], four["losses"])]
+    check(max(diffs) <= LOSS_TOLERANCE, "four-chip losses leave the "
+          "one-chip losses", one=one["losses"], four=four["losses"],
+          tolerance=LOSS_TOLERANCE)
+    held = four["params_bytes_per_device"]
+    check(len(held) == 4 and min(held) > 0 and max(held) < sum(held) / 2,
+          "parameters are not spread over the four devices", held=held)
+    check(four["collectives"]["all-reduce("] > 0
+          and four["collectives"]["all-gather("] > 0,
+          "the sharded step has no collectives", got=four["collectives"])
+    return {"device": four["device_summary"], "mesh": "fsdp=2 x tp=2",
+            "max_loss_diff": max(diffs), "loss_tolerance": LOSS_TOLERANCE,
+            "params_bytes_per_device": held,
+            "collectives": four["collectives"],
+            "one_chip": _train_facts(one, plan.batch),
+            "four_chips": _train_facts(four, plan.batch),
+            "compile_s": round(four["compile_s"], 1)}
+
+
+# -------------------------------------------------------------- kernels
+
+FLASH_TOLERANCE = 3e-2  # max |flash - reference| over max |reference|
+
+
+def kernels_check(shape: list, model: dict, batch: int, seq: int,
+                  interpret: bool) -> dict:
+    """Runs in a child that holds the chip: flash forward and backward
+    against ``attention_reference`` on the same device, and the compiled
+    forward of the model takes the kernel (``use_flash=None``: the
+    dispatch decides from the backend it finds)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import llama
+    from ray_tpu.ops.attention import attention_reference
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    accelerator.claim_device()
+    b, t, hq, hkv, d = shape
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(kq, (b, t, hq, d), jnp.bfloat16)
+    k = jax.random.normal(kk, (b, t, hkv, d), jnp.bfloat16)
+    v = jax.random.normal(kv, (b, t, hkv, d), jnp.bfloat16)
+    w = jax.random.normal(kw, (b, t, hq, d), jnp.float32)
+
+    def outputs(fn):
+        def f(q, k, v):
+            o = fn(q, k, v).astype(jnp.float32)
+            return (o * w).sum(), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (o, *grads)
+
+    got = outputs(functools.partial(flash_attention, causal=True,
+                                    interpret=interpret))
+    want = outputs(functools.partial(attention_reference, causal=True))
+    errs = {}
+    for name, g, r in zip(("out", "dq", "dk", "dv"), got, want):
+        g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+        errs[name] = float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
+
+    cfg = llama.LlamaConfig(**model)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    t0 = time.monotonic()
+    text = jax.jit(lambda p, x: llama.forward(p, x, cfg)).lower(
+        params, tokens).compile().as_text()
+    return {"rel_err": errs, "forward_has_kernel": KERNEL in text,
+            "forward_compile_s": round(time.monotonic() - t0, 1),
+            "device": accelerator.device_report()}
+
+
+def kernels_phase(plan: Plan) -> dict:
+    from ray_tpu._private import accelerator
+
+    args = {"shape": list(plan.flash_shape), "batch": plan.batch,
+            "seq": plan.seq, "interpret": not plan.on_tpu,
+            "model": model_fields(plan.model_size, plan.seq, remat=True,
+                                  remat_policy="flash_qkv")}
+    # the child's platform and chip, as a node agent would hand them out
+    host_chips = accelerator.detect_tpu_chips()
+    env = {**os.environ, **accelerator.worker_env(
+        (0,) if plan.on_tpu else (), host_chips)}
+    code = ("import json, sys, chip_smoke; print(json.dumps("
+            "chip_smoke.kernels_check(**json.loads(sys.argv[1]))))")
+    proc = subprocess.run(
+        [sys.executable, "-c", code, json.dumps(args)], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=600)
+    check(proc.returncode == 0, "kernels child failed",
+          stderr=proc.stderr[-3000:])
+    wait_chips_free()
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    check(max(out["rel_err"].values()) <= FLASH_TOLERANCE,
+          "flash kernel leaves the reference", got=out["rel_err"],
+          tolerance=FLASH_TOLERANCE)
+    check(out["forward_has_kernel"] == plan.on_tpu,
+          "flash kernel in the compiled forward: expected on the TPU only",
+          got=out["forward_has_kernel"])
+    return {"device": check_device(plan, out["device"], 1, "kernels child"),
+            "flash_shape": list(plan.flash_shape),
+            "rel_err_vs_reference": out["rel_err"],
+            "tolerance": FLASH_TOLERANCE,
+            "kernel_in_forward": out["forward_has_kernel"],
+            "compile_s": out["device"]["compile"]["seconds"]}
+
+
+# ------------------------------------------------------------------ run
+
+ONE_CHIP = (("serve", serve_phase), ("train", train_phase),
+            ("kernels", kernels_phase))
+FOUR_CHIPS = (("train4", train4_phase), ("pool4", pool4_phase))
+
+
+def run(plan: Plan, phases=None, out=None) -> int:
+    """Run the phases in order on an initialised cluster; one JSON line
+    each on ``out`` (stdout); the contract's last line only if every
+    phase passed."""
+    from jax._src import xla_bridge
+
+    out = out or sys.stdout
+    if phases is None:
+        phases = FOUR_CHIPS if plan.chips == 4 else ONE_CHIP
+    devices = []
+    for name, phase in phases:
+        t0 = time.monotonic()
+        try:
+            facts = phase(plan)
+            # (a CPU rehearsal runs under pytest, which has one)
+            check(not (plan.on_tpu and xla_bridge.backends_are_initialized()),
+                  "the orchestrating process initialised a JAX backend")
+        except BaseException:  # noqa: BLE001 — reported, then exit code 1
+            traceback.print_exc()
+            print(f"chip_smoke: phase {name!r} FAILED after "
+                  f"{time.monotonic() - t0:.0f}s", file=sys.stderr,
+                  flush=True)
+            return 1
+        devices.append(facts.pop("device"))
+        print(json.dumps({
+            "phase": name, "ok": True,
+            "seconds": round(time.monotonic() - t0, 1),
+            "compile_seconds": facts.pop("compile_s"), "device": devices[-1],
+            "checked": facts}), file=out, flush=True)
+    final = devices[0]
+    if plan.chips == 4:  # the process that drove all four reports four
+        final = max(devices, key=lambda d: d["count"])
+    # (a CPU rehearsal's workers see the test suite's virtual devices)
+    if final["platform"] != plan.platform \
+            or (plan.on_tpu and final["count"] != plan.chips) \
+            or any(d["kind"] != final["kind"] for d in devices):
+        print(f"chip_smoke: phases disagree on the device: {devices}",
+              file=sys.stderr, flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": final}), file=out, flush=True)
+    return 0
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+    plan = Plan(chips=args.chips, seed=args.seed)
+
+    # This process orchestrates and must be UNABLE to take a chip.
+    # Workers do not inherit the pin: the node agent sets each worker's
+    # platform from its TPU grant (ray_tpu/_private/accelerator.py).
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import ray_tpu
+    from ray_tpu._private import accelerator, api
+
+    found = accelerator.detect_tpu_chips()
+    if found < plan.chips:
+        print(f"chip_smoke: needs {plan.chips} TPU chip(s), found {found} "
+              f"device node(s) ({accelerator.chip_device_paths()})",
+              file=sys.stderr)
+        return 1
+    # Standard output carries this script's JSON lines and nothing else,
+    # so that the result is its LAST line: whatever else writes to stdout
+    # from here on (worker logs forwarded to the driver) goes to stderr.
+    out, sys.stdout = os.fdopen(os.dup(sys.stdout.fileno()), "w"), sys.stderr
+    try:
+        print(json.dumps({"phase": "build", "ok": True, **build_native()}),
+              file=out, flush=True)
+        ray_tpu.init(object_store_memory=store_bytes(plan))
+        if api._cluster.agent._native_sched is None:
+            print("chip_smoke: the node agent fell back to the pure-Python "
+                  "scheduler", file=sys.stderr)
+            return 1
+        return run(plan, out=out)
+    except BaseException:  # noqa: BLE001 — reported, then exit code 1
+        traceback.print_exc()
+        return 1
+    finally:
+        ray_tpu.shutdown()
+        sys.stdout = sys.__stdout__
+
+
+if __name__ == "__main__":
+    sys.exit(main())

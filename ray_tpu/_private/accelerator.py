@@ -1,0 +1,210 @@
+"""Who may touch the TPU, decided in one place.
+
+A chip belongs to one process at a time: the first process that
+initialises a JAX TPU backend takes every chip it can see, and any later
+process fails, hangs, or comes up on the CPU. So the node agent decides
+at SPAWN time, from the ``TPU`` amount of the work the worker is for
+(:func:`worker_env`): a worker with a grant sees exactly its chips and
+must come up on them, every other worker is pinned to the CPU platform.
+JAX honours the plain ``JAX_PLATFORMS`` variable, so nothing re-asserts
+a platform in code. Processes that compile for the chip call
+:func:`claim_device` once before their first compile: it fails loudly
+when a granted process is not on the TPU and places the persistent
+compile cache; :func:`device_report` is what their reports carry.
+
+The driver is the user's process: the agent never spawned it, so it is
+the user's call whether it computes (then nothing else on the node may
+hold a chip) or only orchestrates (then it should stay off JAX or set
+``JAX_PLATFORMS=cpu`` itself, as ``chip_smoke.py`` does).
+"""
+
+from __future__ import annotations
+
+import glob
+import math
+import os
+
+# chips handed to this worker by its node agent ("0" or "2,3"); unset or
+# empty on a grantless worker
+GRANT_ENV = "RAY_TPU_GRANTED_CHIPS"
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
+
+# libtpu's chip bounds for a process that owns a SUBSET of the host's
+# chips (a whole-host grant keeps the host's own topology variables)
+_SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
+def chip_device_paths() -> list[str]:
+    """Device nodes libtpu opens, one per chip: ``/dev/accel<N>`` on
+    hosts with the accel driver, ``/dev/vfio/<N>`` where chips are
+    passed through vfio (the v5e machines this repo runs on). Numbered
+    nodes only: bare ``/dev/accel`` is the DRM accelerator class
+    directory and ``/dev/vfio/vfio`` the vfio container."""
+    for pattern in ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*"):
+        paths = sorted(glob.glob(pattern))
+        if paths:
+            return paths
+    return []
+
+
+def detect_tpu_chips() -> int:
+    """Count local TPU chips without initialising JAX (which would take
+    them). ``RAY_TPU_CHIPS`` overrides for tests and virtual topologies."""
+    chips = os.environ.get("RAY_TPU_CHIPS")
+    if chips:
+        return int(float(chips))
+    return len(chip_device_paths())
+
+
+def chips_for(resources: dict | None) -> int:
+    """Whole chips a ``TPU`` amount needs; a fractional request still
+    owns its chip's process, so it rounds up."""
+    return math.ceil(float((resources or {}).get("TPU", 0)) - 1e-9)
+
+
+def worker_env(chips: tuple[int, ...], host_chips: int) -> dict[str, str]:
+    """Environment overrides for a worker spawned with ``chips`` (indices
+    into the host's ``host_chips``; empty = no grant)."""
+    if not chips:
+        return {"JAX_PLATFORMS": "cpu", GRANT_ENV: ""}
+    ids = ",".join(str(c) for c in chips)
+    # an explicit platform list makes JAX FAIL when the TPU cannot
+    # initialise, instead of warning and falling back to the CPU
+    env = {"JAX_PLATFORMS": "tpu,cpu", GRANT_ENV: ids,
+           "TPU_VISIBLE_CHIPS": ids}
+    if len(chips) < host_chips:
+        bounds = _SUBSET_BOUNDS.get(len(chips))
+        if bounds is None:
+            raise ValueError(
+                f"cannot give one process {len(chips)} of {host_chips} "
+                f"chips: grant 1, 2 or all of a host's chips")
+        # the subset is its own one-host slice of that shape (tried on a
+        # four-chip v5e host: four one-chip and two two-chip processes
+        # side by side, each opening only its own /dev/vfio nodes)
+        env.update({"TPU_CHIPS_PER_HOST_BOUNDS": bounds,
+                    "TPU_HOST_BOUNDS": "1,1,1"})
+    return env
+
+
+def granted_chips() -> tuple[int, ...]:
+    """Chip indices this process was spawned with (empty = none)."""
+    raw = os.environ.get(GRANT_ENV, "")
+    return tuple(int(c) for c in raw.split(",") if c)
+
+
+_compile_stats: dict | None = None
+
+
+def _place_compile_cache() -> dict:
+    """Persistent compile cache, placed from outside: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX's own handling of it stands
+    and no directory is set in code; otherwise one fixed path inside the
+    checkout (the path is part of the cache key's home, so it must never
+    move with a pid, a timestamp or a temp dir). Also counts cache
+    requests/hits and backend-compile seconds for this process."""
+    global _compile_stats
+    if _compile_stats is not None:
+        return _compile_stats
+    import jax
+    from jax import monitoring
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    stats = _compile_stats = {
+        "dir": env_dir or COMPILE_CACHE_DIR, "requests": 0, "hits": 0,
+        "seconds": 0.0}
+
+    def _on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            stats["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            stats["hits"] += 1
+
+    def _on_duration(event: str, duration: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            stats["seconds"] += duration
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    return stats
+
+
+_claim: dict | None = None
+
+
+def claim_device() -> dict:
+    """Call once, before the first compile, in every process that
+    computes with JAX on a worker: initialises the backend this process
+    was spawned for and returns what it got. A process that was granted
+    a chip and is not on the TPU raises — there is no CPU path to fall
+    back to silently."""
+    global _claim
+    import jax
+
+    _place_compile_cache()
+    devices = jax.devices()
+    _claim = {
+        "pid": os.getpid(),
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "ids": [d.id for d in devices],
+        "granted_chips": list(granted_chips()),
+    }
+    if _claim["granted_chips"] and _claim["platform"] != "tpu":
+        raise RuntimeError(
+            f"worker {os.getpid()} was granted TPU chips "
+            f"{_claim['granted_chips']} but JAX came up on "
+            f"{_claim['platform']!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r})")
+    return dict(_claim)
+
+
+def device_report() -> dict:
+    """What :func:`claim_device` found, plus this process's compile
+    counters ({dir, requests, hits, seconds}) and each device's peak
+    bytes in use (None where the backend keeps no memory stats) — the
+    facts a replica's ``stats()`` or a train worker's report carries."""
+    import jax
+
+    report = claim_device() if _claim is None else dict(_claim)
+    # A one-chip process calls its device id 0 whichever chip it has;
+    # the device nodes it holds open tell the chips apart. Read now, not
+    # at the claim: libtpu opens them when the device is first used.
+    report["nodes"] = _held_nodes(os.getpid(), set(chip_device_paths()))
+    report["compile"] = {**_compile_stats,
+                         "seconds": round(_compile_stats["seconds"], 3)}
+    report["peak_bytes_in_use"] = [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.local_devices()]
+    return report
+
+
+def _held_nodes(pid: int | str, nodes: set[str]) -> list[str]:
+    fd_dir = f"/proc/{pid}/fd"
+    held = set()
+    try:
+        fds = os.listdir(fd_dir)
+    except OSError:  # process gone, or not ours to read
+        return []
+    for fd in fds:
+        try:
+            held.add(os.readlink(os.path.join(fd_dir, fd)))
+        except OSError:  # closed meanwhile (our own listing's fd is one)
+            continue
+    return sorted(held & nodes)
+
+
+def chip_holders() -> dict[int, list[str]]:
+    """pid -> chip device nodes it holds open, from ``/proc`` (needs no
+    JAX, so a process that must stay off the chip can still check who is
+    on it)."""
+    nodes = set(chip_device_paths())
+    if not nodes:
+        return {}
+    holders = {int(pid): _held_nodes(pid, nodes)
+               for pid in os.listdir("/proc") if pid.isdigit()}
+    return {pid: held for pid, held in holders.items() if held}

@@ -284,6 +284,15 @@ def init(address: str | None = None, *, num_cpus: float | None = None,
                 from ray_tpu.core.node_agent import detect_resources
 
                 res = {**detect_resources(), **res}
+            if "TPU" not in res:
+                # naming the CPUs must not hide the chips: work that
+                # asks for TPU would wait forever for a resource the
+                # node never offered
+                from ray_tpu._private import accelerator
+
+                chips = accelerator.detect_tpu_chips()
+                if chips:
+                    res["TPU"] = float(chips)
             res.setdefault("memory", 8 * 2**30)
             _cluster = LocalCluster(
                 resources=res, store_capacity=object_store_memory,

@@ -29,6 +29,12 @@ _DEFS: dict[str, Any] = {
     "spill_high_fraction": 0.8,          # spill primaries above this fill
     "spill_low_fraction": 0.5,           # ...until back under this
     "worker_register_timeout_s": 60.0,
+    # how long an actor's constructor may run: the agent fails the actor
+    # past it, and a caller waits that long (plus the register timeout)
+    # for the actor before its call fails. Was 120 s at the agent and
+    # 60 s at callers; on a four-chip host the 1B LLMPool's constructor
+    # (weights on the CPU, then four replica starts) outlived the 60.
+    "actor_create_timeout_s": 300.0,
     # pull admission (pull_manager.py; reference pull_manager.h:52)
     "pull_max_active": 8,
     "pull_admission_watermark": 0.8,

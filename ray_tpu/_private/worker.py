@@ -2026,12 +2026,15 @@ class CoreWorker:
         )
         return reply
 
-    def _actor_client(self, actor_id: bytes,
-                      timeout: float = 60.0) -> rpc.SyncRpcClient:
+    def _actor_client(self, actor_id: bytes) -> rpc.SyncRpcClient:
         cli = self._actor_clients.get(actor_id)
         if cli is not None:
             return cli
-        deadline = time.monotonic() + timeout
+        # as long as the agent gives the actor to come up (it turns DEAD
+        # there past that, which ends this wait at once)
+        deadline = time.monotonic() + _config.get(
+            "worker_register_timeout_s") + _config.get(
+            "actor_create_timeout_s")
         while time.monotonic() < deadline:
             info = self._actor_info.get(actor_id)
             if info is None or info["state"] not in ("ALIVE", "DEAD"):

@@ -20,23 +20,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # jax < 0.6: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
     # Replication-check off: collective outputs are replicated by
     # construction (psum/all_gather), which shard_map's static checker
-    # can't always infer. The kwarg is check_vma on current jax,
-    # check_rep before the rename.
-    try:
-        return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+    # can't always infer.
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _replicated(mesh):

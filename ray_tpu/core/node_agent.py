@@ -2,9 +2,11 @@
 
 Composes, like the reference NodeManager (`node_manager.h:115`):
 - WorkerPool        — worker process lifecycle, reuse, idle cull
-                      (worker_pool.h:156); TPU-aware: workers holding TPU
-                      chips are never idle-culled (device init + compile
-                      cache are expensive to recreate).
+                      (worker_pool.h:156); hands out TPU chips at spawn
+                      (_private/accelerator.py: a granted worker sees
+                      its chips, every other worker is pinned to the
+                      CPU); chip holders are never idle-culled (device
+                      init + compiled programs are expensive to recreate).
 - ClusterTaskManager— local-vs-spill decision from the synced cluster view
                       (cluster_task_manager.h:42); hybrid policy: prefer
                       local while resources fit, else best remote node.
@@ -34,6 +36,7 @@ import time
 from collections import deque
 from typing import Any
 
+from ray_tpu._private import accelerator
 from ray_tpu._private import config as cfg
 from ray_tpu._private import fault_injection, rpc, task_spec
 from ray_tpu._private.rpc import AsyncRpcClient, OobReply, RpcServer
@@ -104,29 +107,12 @@ def _transfer_metrics() -> dict:
     return _xfer_metrics
 
 
-def detect_tpu_chips() -> int:
-    """Count local TPU chips without initializing jax (which would grab
-    them): libtpu exposes one /dev/accel* (v4/v5) or /dev/vfio group per
-    chip. RAY_TPU_CHIPS overrides for tests/virtual topologies."""
-    chips = os.environ.get("RAY_TPU_CHIPS")
-    if chips:
-        return int(float(chips))
-    import glob
-
-    # numbered chip devices only: a bare /dev/accel directory is the
-    # Linux DRM compute-accelerator class (NPUs etc.), not a TPU
-    accels = glob.glob("/dev/accel[0-9]*")
-    if accels:
-        return len(accels)
-    return 0
-
-
 def detect_resources() -> dict:
     import psutil
 
     res = {"CPU": float(os.cpu_count() or 1),
            "memory": float(psutil.virtual_memory().total)}
-    chips = detect_tpu_chips()
+    chips = accelerator.detect_tpu_chips()
     if chips:
         res["TPU"] = float(chips)
         topo = os.environ.get("RAY_TPU_TOPOLOGY")
@@ -166,7 +152,9 @@ class WorkerHandle:
         self.pool_inflight: set[bytes] = set()
         self.actor_id: bytes | None = None
         self.job_id: bytes | None = None
-        self.holds_tpu = False
+        # chip indices this process was spawned with (accelerator.py):
+        # fixed for its lifetime, so reuse matches on the count
+        self.chips: tuple[int, ...] = ()
         self.idle_since = time.monotonic()
         self.started_at = time.monotonic()
         self.actor_resources: dict | None = None
@@ -216,6 +204,12 @@ class NodeAgent:
                 hugepage=bool(cfg.get("object_store_hugepages")))
         self.head: AsyncRpcClient | None = None
         self.workers: dict[bytes, WorkerHandle] = {}
+        # chip index -> the process it was handed to. A chip is free
+        # once that process has EXITED (not merely been told to): the
+        # device is released by the process dying, not by its grant
+        self._chip_procs: dict[int, subprocess.Popen | None] = {
+            c: None
+            for c in range(int(self.resources_total.get("TPU", 0)))}
         self.task_queue: deque[dict] = deque()
         self.running: dict[bytes, dict] = {}  # task_id → spec
         self.cluster_view: dict[bytes, dict] = {}
@@ -279,15 +273,17 @@ class NodeAgent:
         # pending!" and skips the kill.
         self._escalations: set[asyncio.Task] = set()
         # Native (C++) hybrid placement core; None falls back to the pure-
-        # Python policy in _choose_node (e.g. no g++ on the host).
+        # Python policy in _choose_node (no g++ on the host, or a failed
+        # build) — said out loud, so nobody times the wrong scheduler.
         self._native_sched = None
         if cfg.get("scheduler_use_native"):
             try:
                 from ray_tpu._native.scheduler import NativeScheduler
 
                 self._native_sched = NativeScheduler()
-            except Exception:
-                self._native_sched = None
+            except (OSError, subprocess.CalledProcessError) as e:
+                logger.warning("native scheduler unavailable (%s); using "
+                               "the pure-Python placement policy", e)
         self._install_routes()
         self._dead = False
 
@@ -540,7 +536,7 @@ class NodeAgent:
         return gate
 
     async def _spawn_worker_registered(
-            self, job_id: bytes | None, holds_tpu: bool = False,
+            self, job_id: bytes | None, n_chips: int = 0,
             runtime_env: dict | None = None, *,
             reserve: bool = False, recheck_pool_cap: bool = False,
             gate_deadline: float | None = None) -> WorkerHandle | None:
@@ -548,6 +544,12 @@ class NodeAgent:
         fork to registered. Env materialization (package fetch, pip
         plugin installs — possibly minutes) runs BEFORE acquiring the
         gate so slow installs never serialize unrelated startups.
+
+        n_chips: whole TPU chips the work this worker is for was granted.
+        The worker's environment shows it exactly those chips, or pins it
+        to the CPU platform when there are none (accelerator.worker_env)
+        — set last, so neither the inherited environment nor a
+        runtime_env can put a grantless process on a chip.
 
         recheck_pool_cap: re-evaluate the pool cap AFTER acquiring the
         gate — spawns parked at the gate are invisible to callers' cap
@@ -603,8 +605,13 @@ class NodeAgent:
                     _release_uris()
                     return None
             try:
+                # claim -> fork with no await between: the claim is only
+                # recorded by the fork (chip -> process)
+                chips = await self._claim_chips(n_chips)
+                env.update(accelerator.worker_env(
+                    chips, len(self._chip_procs)))
                 w = self._fork_worker(worker_id, py_exe, env, cwd,
-                                      pkg_uris, job_id, holds_tpu,
+                                      pkg_uris, job_id, chips,
                                       runtime_env)
             except BaseException:
                 _release_uris()
@@ -624,6 +631,30 @@ class NodeAgent:
             return w
         finally:
             self._spawn_gate.release()
+
+    async def _claim_chips(self, n: int) -> tuple[int, ...]:
+        """n chip indices that no live process holds. Resource accounting
+        already admitted the work, so a missing chip is held either by an
+        idle pool worker left over from a finished TPU task (evicted
+        here) or by a process still exiting (waited for). Returns with
+        no await after the final check — the caller forks at once."""
+        if n == 0:
+            return ()
+        deadline = time.monotonic() + cfg.get("worker_register_timeout_s")
+        while True:
+            free = [c for c, proc in self._chip_procs.items()
+                    if proc is None or proc.poll() is not None]
+            if len(free) >= n:
+                return tuple(free[:n])
+            for w in list(self.workers.values()):
+                if w.chips and w.idle and w.ready.is_set():
+                    self._kill_worker(w)
+            if time.monotonic() > deadline:
+                raise RuntimeError(
+                    f"no {n} free TPU chip(s) on this node: "
+                    f"{len(self._chip_procs)} chips, {len(free)} free "
+                    f"(a fractional TPU request owns a whole chip)")
+            await asyncio.sleep(0.05)
 
     async def _materialize_env(self, env: dict, pkg_uris: list,
                                runtime_env: dict | None):
@@ -692,7 +723,7 @@ class NodeAgent:
 
     def _fork_worker(self, worker_id: bytes, py_exe: str, env: dict,
                      cwd, pkg_uris: list, job_id: bytes | None,
-                     holds_tpu: bool,
+                     chips: tuple[int, ...],
                      runtime_env: dict | None) -> WorkerHandle:
         """Fork the worker process and register its handle (synchronous:
         the handle is in self.workers before any await, so cap counts
@@ -706,7 +737,9 @@ class NodeAgent:
         )
         handle = WorkerHandle(worker_id, proc)
         handle.job_id = job_id
-        handle.holds_tpu = holds_tpu
+        handle.chips = chips
+        for c in chips:
+            self._chip_procs[c] = proc
         handle.env_hash = _env_hash(runtime_env)
         handle.pkg_uris = pkg_uris  # acquired in _materialize_env
         self.workers[worker_id] = handle
@@ -879,13 +912,15 @@ class NodeAgent:
         fail."""
 
     async def _pop_worker(self, job_id: bytes | None,
-                          holds_tpu: bool = False,
+                          n_chips: int = 0,
                           runtime_env: dict | None = None, *,
                           wait: bool = True,
                           spawn_wait: bool = True,
                           allow_pipeline: bool = False) -> WorkerHandle | None:
-        """Idle worker of the same job AND runtime env, else spawn
-        (worker_pool.h PopWorker; env mismatch forces a new process).
+        """Idle worker of the same job, runtime env AND chip count, else
+        spawn (worker_pool.h PopWorker; env mismatch forces a new
+        process, and so does a chip mismatch: a process's platform and
+        chips are fixed at spawn).
         At the pool cap: evict an idle MISMATCHED worker to make room,
         else wait for one to free (wait=False returns None instead — the
         lease fast path must not camp on granted resources)."""
@@ -900,6 +935,7 @@ class NodeAgent:
             for w in self.workers.values():
                 if w.idle and w.ready.is_set() and w.job_id == job_id \
                         and getattr(w, "env_hash", None) == want \
+                        and len(w.chips) == n_chips \
                         and w.proc.poll() is None:
                     w.idle_since = time.monotonic()
                     return w
@@ -919,6 +955,7 @@ class NodeAgent:
                             and not w.blocked
                             and w.ready.is_set() and w.job_id == job_id
                             and getattr(w, "env_hash", None) == want
+                            and len(w.chips) == n_chips
                             and w.proc.poll() is None
                             and 0 < len(w.pool_inflight) < depth):
                         if best is None or len(w.pool_inflight) < len(
@@ -974,7 +1011,7 @@ class NodeAgent:
                             # them — only spawns still under the cap at
                             # their turn may fork.
                             await self._spawn_worker_registered(
-                                job_id, holds_tpu, runtime_env,
+                                job_id, n_chips, runtime_env,
                                 recheck_pool_cap=True,
                                 gate_deadline=time.monotonic() + cfg.get(
                                     "worker_register_timeout_s"))
@@ -986,7 +1023,7 @@ class NodeAgent:
                     asyncio.ensure_future(_bg_spawn())
                     return None
                 w = await self._spawn_worker_registered(
-                    job_id, holds_tpu, runtime_env, reserve=True,
+                    job_id, n_chips, runtime_env, reserve=True,
                     recheck_pool_cap=True, gate_deadline=deadline)
                 if w is None:
                     continue  # cap filled while parked at the gate
@@ -1081,7 +1118,7 @@ class NodeAgent:
                 code = w.proc.poll()
                 if code is not None:
                     await self._on_worker_death(w, code)
-                elif (w.idle and not w.holds_tpu and w.ready.is_set()
+                elif (w.idle and not w.chips and w.ready.is_set()
                       and now - w.idle_since > IDLE_CULL_S):
                     self._kill_worker(w)
 
@@ -1627,7 +1664,7 @@ class NodeAgent:
         try:
             w = await self._pop_worker(
                 spec.get("job_id"),
-                holds_tpu=spec.get("resources", {}).get("TPU", 0) > 0,
+                n_chips=accelerator.chips_for(spec.get("resources")),
                 runtime_env=spec.get("runtime_env"),
                 allow_pipeline=True,
             )
@@ -1742,7 +1779,7 @@ class NodeAgent:
             # resources at the pool cap — returning None makes the owner
             # fall back to queued submission
             w = await self._pop_worker(
-                p.get("job_id"), holds_tpu=need.get("TPU", 0) > 0,
+                p.get("job_id"), n_chips=accelerator.chips_for(need),
                 runtime_env=p.get("runtime_env"), wait=False,
                 spawn_wait=False,
             )
@@ -2182,7 +2219,7 @@ class NodeAgent:
         try:
             try:
                 w = await self._spawn_worker_registered(
-                    p.get("job_id"), holds_tpu=need.get("TPU", 0) > 0,
+                    p.get("job_id"), n_chips=accelerator.chips_for(need),
                     runtime_env=p.get("runtime_env"), reserve=True,
                 )
             except asyncio.TimeoutError:
@@ -2199,7 +2236,7 @@ class NodeAgent:
                 "max_concurrency": p.get("max_concurrency", 1),
                 "concurrency_groups": p.get("concurrency_groups") or {},
                 "method_groups": p.get("method_groups") or {},
-            }, timeout=120.0)
+            }, timeout=cfg.get("actor_create_timeout_s"))
             await self.head.call("actor_started", {
                 "actor_id": p["actor_id"], "addr": w.addr, "port": w.port,
                 "worker_id": w.worker_id,

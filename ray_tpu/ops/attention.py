@@ -9,6 +9,9 @@ attention here is the framework's flagship MXU kernel (see ops/flash_attention.p
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -56,30 +59,87 @@ def attention_reference(q, k, v, *, causal: bool = True, logits_dtype=jnp.float3
 def attention(q, k, v, *, causal: bool = True, use_flash: bool | None = None):
     """Dispatching attention entry point.
 
-    use_flash=None → flash kernel on TPU backends when block divisibility
-    holds, reference elsewhere. The flash kernel is TPU-only (pltpu memory
-    spaces); other accelerators use the reference path, which XLA fuses.
-    Explicit use_flash=True is a hard request: non-divisible sequence lengths
-    raise (pad to the block size) instead of silently hitting the O(T*S) path.
-    """
-    auto = use_flash is None
-    if auto:
-        use_flash = jax.default_backend() == "tpu"
-    if use_flash:
-        from ray_tpu._private import config as _cfg
-        from ray_tpu.ops.flash_attention import flash_attention
+    use_flash=None → the backend's kernel: flash on a TPU backend, the
+    reference elsewhere (the flash kernel is TPU-only: pltpu memory
+    spaces). Once flash is chosen nothing falls back: sequence lengths
+    that are not multiples of the blocks raise (pad to the block size),
+    on a TPU backend as for an explicit use_flash=True, instead of
+    silently taking the O(T*S) path. use_flash=False is the explicit
+    request for the reference.
 
-        t, s = q.shape[1], k.shape[1]
-        # same config flags flash_attention resolves itself
-        # (RAY_TPU_FLASH_BLOCK_Q/_K), so deployments retune in one place
-        bq = min(_cfg.get("flash_block_q"), t)
-        bk = min(_cfg.get("flash_block_k"), s)
-        if t % bq == 0 and s % bk == 0:
-            return flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-        if not auto:
-            raise ValueError(
-                f"use_flash=True but seq lengths (T={t}, S={s}) are not "
-                f"multiples of the flash blocks ({bq}, {bk}); pad the "
-                "sequence or pass use_flash=None for automatic fallback"
-            )
-    return attention_reference(q, k, v, causal=causal)
+    Under an ambient mesh of more than one device the kernel runs inside
+    a shard_map over that mesh (a Mosaic kernel cannot be partitioned by
+    GSPMD): batch over the data axes, heads and kv heads over tp, as the
+    logical rules place them; the sequence stays whole.
+    """
+    if use_flash is None:
+        use_flash = jax.default_backend() == "tpu"
+    if not use_flash:
+        return attention_reference(q, k, v, causal=causal)
+
+    from ray_tpu._private import config as _cfg
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    t, s = q.shape[1], k.shape[1]
+    # same config flags flash_attention resolves itself
+    # (RAY_TPU_FLASH_BLOCK_Q/_K), so deployments retune in one place
+    bq = min(_cfg.get("flash_block_q"), t)
+    bk = min(_cfg.get("flash_block_k"), s)
+    if t % bq or s % bk:
+        raise ValueError(
+            f"flash attention needs seq lengths (T={t}, S={s}) that are "
+            f"multiples of the flash blocks ({bq}, {bk}); pad the "
+            "sequence, or pass use_flash=False for the reference"
+        )
+    kernel = functools.partial(
+        flash_attention, causal=causal, block_q=bq, block_k=bk)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size <= 1:  # no ambient mesh (size 0), or one device
+        return kernel(q, k, v)
+    return _shard_over_mesh(kernel, mesh, q, k, v)
+
+
+def _shard_over_mesh(kernel, mesh, q, k, v):
+    """Run ``kernel(q, k, v)`` per shard of the ambient mesh, with the
+    operand layout the logical rules give q/k/v (parallel/sharding.py)."""
+    from ray_tpu.parallel.sharding import logical_to_mesh_spec
+
+    q_spec = logical_to_mesh_spec(
+        ("batch", "seq", "heads", "head_dim"), mesh=mesh)
+    kv_spec = logical_to_mesh_spec(
+        ("batch", "seq", "kv_heads", "head_dim"), mesh=mesh)
+    # pad to rank 4: a trailing replicated dim is trimmed from the spec
+    b_ax, seq_ax, h_ax, _ = (tuple(q_spec) + (None,) * 4)[:4]
+    kv_h_ax = (tuple(kv_spec) + (None,) * 4)[2]
+
+    def ways(ax):
+        axes = (ax,) if isinstance(ax, str) else tuple(ax or ())
+        return math.prod(mesh.shape[a] for a in axes)
+
+    if seq_ax is not None:
+        raise NotImplementedError(
+            f"flash attention on a mesh that shards the sequence "
+            f"({seq_ax}={ways(seq_ax)}) is not brought up: the kernel "
+            "needs whole rows (ops/ring_attention.py and ops/ulysses.py "
+            "are not wired into the model); use sp=1 or use_flash=False")
+    if q.shape[0] % ways(b_ax) or k.shape[2] % ways(kv_h_ax) \
+            or q.shape[2] % ways(h_ax):
+        raise ValueError(
+            f"flash attention cannot split batch={q.shape[0]}, "
+            f"heads={q.shape[2]}, kv_heads={k.shape[2]} over mesh axes "
+            f"{b_ax}={ways(b_ax)}, {h_ax}={ways(h_ax)}: each must divide "
+            "evenly (tp has to divide n_kv_heads)")
+    if mesh.manual_axes:
+        raise NotImplementedError(
+            f"flash attention inside a region already manual over "
+            f"{sorted(mesh.manual_axes)} (parallel/pipeline.py's pp "
+            "stages) is not brought up: the nested shard_map's cotangents "
+            "lose the region's varying-axes type; use use_flash=False on "
+            "a pp mesh")
+    # manual over EVERY axis: one left automatic would hand the Mosaic
+    # call back to GSPMD, which cannot partition it. check_vma off:
+    # pallas_call outputs carry no varying-axes type.
+    return jax.shard_map(
+        kernel, in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+        check_vma=False,
+    )(q, k, v)

@@ -805,22 +805,24 @@ def flash_attention(
     causal: bool = True,
     block_q: int | None = None,
     block_k: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Flash attention. Layout [B, T, H, D] (matching ops.attention).
 
     Requires T and S to be multiples of the (clamped) block sizes; callers
     pad. Block sizes default from the config flags flash_block_q/_k
     (RAY_TPU_FLASH_BLOCK_Q/_K) so deployments can retune per chip
-    generation without code changes.
+    generation without code changes. ``interpret=True`` runs the kernels
+    in the Pallas interpreter — a test's explicit choice on a host
+    without the chip, never inferred from the backend: a process that
+    should be on a TPU and is not must fail to lower, not serve from the
+    interpreter.
     """
     if block_q is None or block_k is None:
         from ray_tpu._private import config as _cfg
 
         block_q = block_q or _cfg.get("flash_block_q")
         block_k = block_k or _cfg.get("flash_block_k")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     # Kernel-internal layout is [B, H, T, D].
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -111,15 +111,8 @@ def _take_factor(n: int, want: int) -> int:
 
 
 def use_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Compat shim: jax renamed use_mesh -> jax.set_mesh.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # oldest jax: Mesh is itself the context manager
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def local_mesh() -> Mesh:

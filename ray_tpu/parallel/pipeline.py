@@ -32,12 +32,7 @@ from jax.sharding import PartitionSpec as P
 def pipeline_stages(mesh=None, axis: str = "pp") -> int:
     """Size of the pipeline axis in ``mesh`` (or the ambient mesh)."""
     if mesh is None:
-        if hasattr(jax.sharding, "get_abstract_mesh"):
-            mesh = jax.sharding.get_abstract_mesh()
-        else:  # older jax: `with mesh:` context, no abstract-mesh API
-            from jax._src import mesh as _mesh_lib
-
-            mesh = _mesh_lib.thread_resources.env.physical_mesh
+        mesh = jax.sharding.get_abstract_mesh()
     return dict(mesh.shape).get(axis, 1)
 
 

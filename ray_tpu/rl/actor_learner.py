@@ -90,9 +90,6 @@ class ActorLearnerConfig:
     trajectories_per_iter: int = 8
     num_learners: int = 1
     min_learners: int | None = None
-    # forwarded to ScalingConfig: learner processes must pin a platform
-    # on hosts where autodetect would reach for a missing accelerator
-    learner_platform: str | None = None
     learner_devices: int | None = None
     lr: float = 4.0  # per-TOKEN step: grads are summed then divided by
     # the GLOBAL token count (world-split-invariant mean)
@@ -548,7 +545,6 @@ class ActorLearnerLoop:
                 num_workers=cfg.num_learners,
                 resources_per_worker={"CPU": 1}, backend="dcn",
                 min_workers=cfg.min_learners,
-                platform=cfg.learner_platform,
                 devices_per_worker=cfg.learner_devices,
                 placement_strategy="PACK"),
             run_config=RunConfig(

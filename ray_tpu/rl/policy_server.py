@@ -14,7 +14,6 @@ arrays the Learner/LearnerGroup consume unchanged.
 from __future__ import annotations
 
 import json
-import os
 import threading
 
 
@@ -27,8 +26,6 @@ class _PolicyDeploymentImpl:
                  explore: bool = True, seed: int = 0):
         import jax
 
-        if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-            jax.config.update("jax_platforms", "cpu")
         from ray_tpu._private import serialization
 
         self.module = serialization.unpack_payload(

@@ -688,9 +688,16 @@ class DeploymentHandle:
         return _HandleMethod(self, method_name)
 
     def options(self, *, multiplexed_model_id: str = "",
-                method_name: str = "__call__") -> "_HandleMethod":
+                method_name: str = "__call__",
+                affinity_key: str = "") -> "_HandleMethod":
+        """``multiplexed_model_id`` routes to the replica that prefers
+        that model AND tells it which model the request is for.
+        ``affinity_key`` only routes: calls sharing a key prefer one
+        replica (a stream's submit and polls), and the replica never
+        sees it — it is not a model id."""
         return _HandleMethod(self, method_name,
-                             model_id=multiplexed_model_id)
+                             model_id=multiplexed_model_id,
+                             route_key=affinity_key)
 
     def remote(self, *args, **kwargs):
         return self.method("__call__").remote(*args, **kwargs)
@@ -735,15 +742,16 @@ class DeploymentHandle:
 
 class _HandleMethod:
     def __init__(self, handle: DeploymentHandle, method: str,
-                 model_id: str = ""):
+                 model_id: str = "", route_key: str = ""):
         self._h = handle
         self._method = method
         self._model_id = model_id
+        self._route_key = route_key or model_id
 
     def remote(self, *args, **kwargs):
         h = self._h
         for attempt in (0, 1):
-            idx = h._assign(self._model_id)
+            idx = h._assign(self._route_key)
             try:
                 replica = h._replicas[idx]
                 ref = replica.handle_request.remote(self._method,

@@ -169,17 +169,19 @@ class _ProxyServer:
         try:
             handle = self._handle_for(name)
             # submit and every poll must land on the SAME replica (the
-            # stream state lives there); the multiplex model-id hint
-            # pins both to one preferred replica when the deployment
-            # runs more than one (best-effort under backpressure — the
-            # LLM pool architecture keeps its pool deployment at one
-            # replica precisely so this can never diverge)
+            # stream state lives there); a shared affinity key pins
+            # both to one preferred replica when the deployment runs
+            # more than one (best-effort under backpressure — the LLM
+            # pool architecture keeps its pool deployment at one replica
+            # precisely so this can never diverge). A routing key only:
+            # sent as a multiplexed model id it reached LLMPool, which
+            # refused the stream as a request for an unregistered model
             import os as _os
 
             skey = _os.urandom(8).hex()
             sub = await loop.run_in_executor(
                 None, lambda: ray_tpu.get(
-                    handle.options(multiplexed_model_id=skey,
+                    handle.options(affinity_key=skey,
                                    method_name="submit_stream")
                     .remote(req),
                     timeout=120))
@@ -199,7 +201,7 @@ class _ProxyServer:
             while True:
                 out = await loop.run_in_executor(
                     None, lambda: ray_tpu.get(
-                        handle.options(multiplexed_model_id=skey,
+                        handle.options(affinity_key=skey,
                                        method_name="poll_stream")
                         .remote(rid),
                         timeout=120))

@@ -20,9 +20,10 @@ decode replicas behind a shared admission queue:
   object-store ref straight from the prefill worker to the adopting
   decode replica (PR-9 pipelined pull), so long prompts never stall a
   decode pump's chunk cadence.
-- **One-put weight publishing.** The pool builds the model once,
-  `ray_tpu.put`s the host weight tree, and every replica (and prefill
-  worker) constructor adopts the same ref — replicas added by the
+- **One-put weight publishing.** The pool builds the model once (on the
+  CPU: the pool process holds no chip), `ray_tpu.put`s the host weight
+  tree, and every replica (and prefill worker) constructor adopts the
+  same ref — replicas added by the
   autoscaler pull from any node already holding the blob (multi-source
   striped pull), never from a per-replica serialization.
 - **Failover.** A replica death re-queues its in-flight requests to
@@ -76,10 +77,9 @@ class PrefillWorker:
                  params_blob=None, name: str = ""):
         import os
 
-        import jax
+        from ray_tpu._private import accelerator
 
-        if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-            jax.config.update("jax_platforms", "cpu")
+        accelerator.claim_device()
         self.params, self.cfg = build_model(
             model_size, max_len=max_len, vocab_size=vocab_size,
             seed=seed, params_blob=params_blob)
@@ -271,6 +271,22 @@ class LLMPool:
         self.slots = slots
         self.min_replicas = max(1, min_replicas)
         self.max_replicas = max(self.min_replicas, max_replicas)
+        # On a cluster with TPU chips every decode replica and prefill
+        # worker is its own process on its own chip (the node agent
+        # hands the chip out, _private/accelerator.py); the pool itself
+        # holds none and builds weights on the CPU. No chips: members
+        # run on the CPU too.
+        chips = int(ray_tpu.cluster_resources().get("TPU", 0))
+        self._member_tpus = 1 if chips else 0
+        if chips:
+            if self.min_replicas + prefill_workers > chips:
+                raise ValueError(
+                    f"{self.min_replicas} decode replica(s) + "
+                    f"{prefill_workers} prefill worker(s) need one TPU "
+                    f"chip each; the cluster has {chips}")
+            # a replica that can never get a chip would only wait
+            self.max_replicas = min(self.max_replicas,
+                                    chips - prefill_workers)
         self.target_ttft_s = target_ttft_s
         self.target_queue_per_replica = target_queue_per_replica
         self.prefill_threshold = prefill_threshold
@@ -346,7 +362,7 @@ class LLMPool:
         self._prefill: list = []
         if prefill_workers > 0:
             self._prefill = [
-                _PrefillActor.remote(
+                _PrefillActor.options(num_tpus=self._member_tpus).remote(
                     **self._model_kwargs,
                     prompt_buckets=tuple(prompt_buckets),
                     params_blob=self._params_ref,
@@ -377,6 +393,7 @@ class LLMPool:
             ref, version = self._params_ref, self._weights_version
         h = _DecodeReplica.options(
             max_concurrency=self._max_inflight + 8,
+            num_tpus=self._member_tpus,
         ).remote(**self._replica_kwargs, params_blob=ref,
                  engine_name=name, weights_version=version)
         return _Replica(h, name)
@@ -1344,7 +1361,15 @@ class LLMPool:
         total = hits + sum(p["misses"] for p in pc)
         with self._lock:
             tenants = sorted({tn for _, _, tn in self._ttfts})
+        import os
+
+        import jax
+
         return {
+            # the pool process holds no chip (it was spawned without a
+            # TPU grant): weights were built on this platform
+            "device": {"pid": os.getpid(),
+                       "platform": jax.default_backend()},
             "replicas": len(reps),
             "queue_depth": waiting,
             "inflight": sum(r.inflight for r in reps),

@@ -55,7 +55,7 @@ def _path_names(path) -> tuple[str, ...]:
     return tuple(path_name(path).split("/"))
 
 
-def init_train_state(
+def train_state_shardings(
     init_params_fn: Callable[[jax.Array], Any],
     param_axes,
     optimizer: optax.GradientTransformation,
@@ -63,14 +63,11 @@ def init_train_state(
     rules: LogicalRules = DEFAULT_RULES,
     *,
     key=None,
-) -> tuple[TrainState, Any]:
-    """Create a fully-sharded TrainState directly on device.
-
-    Init runs under jit with out_shardings so no replicated copy of the params
-    ever materializes (critical for fsdp-sharded 7B+ states).
-
-    Returns (state, state_shardings).
-    """
+):
+    """(init_fn, abstract_state, state_shardings) for a TrainState on
+    ``mesh`` — shapes only, nothing is placed. `init_train_state` runs
+    ``init_fn``; a compile for a described (not attached) device hands
+    the abstract state to ``step.lower`` instead."""
     if key is None:
         key = jax.random.PRNGKey(0)
 
@@ -106,12 +103,31 @@ def init_train_state(
         return scalar
 
     opt_sh = jax.tree_util.tree_map_with_path(match, abstract.opt_state)
-    state_sh = TrainState(scalar, p_sh, opt_sh)
+    return _init, abstract, TrainState(scalar, p_sh, opt_sh)
 
+
+def init_train_state(
+    init_params_fn: Callable[[jax.Array], Any],
+    param_axes,
+    optimizer: optax.GradientTransformation,
+    mesh: Mesh,
+    rules: LogicalRules = DEFAULT_RULES,
+    *,
+    key=None,
+) -> tuple[TrainState, Any]:
+    """Create a fully-sharded TrainState directly on device.
+
+    Init runs under jit with out_shardings so no replicated copy of the params
+    ever materializes (critical for fsdp-sharded 7B+ states).
+
+    Returns (state, state_shardings).
+    """
+    if key is None:
+        key = jax.random.PRNGKey(0)
+    init_fn, _, state_sh = train_state_shardings(
+        init_params_fn, param_axes, optimizer, mesh, rules, key=key)
     with use_mesh(mesh):
-        state = jax.jit(
-            _init, out_shardings=state_sh
-        )(key)
+        state = jax.jit(init_fn, out_shardings=state_sh)(key)
     return state, state_sh
 
 

@@ -112,7 +112,10 @@ class ScalingConfig:
     num_workers: int = 1
     resources_per_worker: dict = field(default_factory=lambda: {"CPU": 1})
     devices_per_worker: int | None = None  # virtual CPU devices (tests)
-    platform: str | None = None  # "cpu" | "tpu" | None = autodetect
+    # a CHECK, not a switch: a worker runs on the chips of its "TPU"
+    # grant in resources_per_worker, else on the CPU; startup fails if
+    # that is not the platform named here (None = no check)
+    platform: str | None = None
     placement_strategy: str = "SPREAD"
     backend: str = "jax"  # "jax" (one mesh) | "dcn" (per-worker jax)
     min_workers: int | None = None

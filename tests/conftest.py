@@ -14,6 +14,10 @@ import os
 # jax may already be imported (pytest plugins) with its config snapshotted from
 # the env, so set both the env var and the live config; backends init lazily.
 os.environ["JAX_PLATFORMS"] = "cpu"
+# the persistent compile cache stays off under test, here and in every
+# worker that inherits this environment: ray_tpu._private.accelerator
+# would otherwise fill <repo>/.jax_cache with CPU programs
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (
@@ -24,12 +28,8 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax (< 0.5) has no jax_num_cpu_devices; the
-    # xla_force_host_platform_device_count XLA flag above covers it
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
+jax.config.update("jax_enable_compilation_cache", False)
 
 assert jax.default_backend() == "cpu", (
     "jax backend initialized before conftest could force CPU; "

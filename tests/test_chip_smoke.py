@@ -1,0 +1,135 @@
+"""CPU rehearsal of chip_smoke.py's control flow.
+
+The script's defaults are its contract (1B widths on the TPU) and its
+command line has no way round them; here its phase functions run with
+``Plan.tiny()`` — test-sized widths, the CPU platform — on one small
+shared cluster. This finds wrong paths, arguments and control flow
+before a chip call does; it says nothing about the chip. The train and
+kernels phases are tier-1; the serve phase (two pool starts, ~30 s) and
+the four-chip phases (~90 s) are ``-m slow`` — run them before a chip
+call that changes what they drive.
+"""
+
+import json
+
+import pytest
+
+import chip_smoke
+import ray_tpu
+
+TINY = chip_smoke.Plan.tiny()
+CPU = {"platform": "cpu", "kind": "cpu"}
+
+
+@pytest.fixture(scope="module")
+def cluster():
+    ray_tpu.init(num_cpus=8)
+    yield
+    ray_tpu.shutdown()
+
+
+def _run(capsys, plan, phases=None):
+    rc = chip_smoke.run(plan, phases)
+    out = capsys.readouterr()
+    # (workers' own stdout is forwarded to the driver's; main() moves
+    # all of that to stderr, run() alone does not)
+    return rc, [json.loads(ln) for ln in out.out.splitlines()
+                if ln.startswith("{")], out.err
+
+
+def _check_lines(lines, names):
+    assert [ln.get("phase") for ln in lines[:-1]] == names
+    for ln in lines[:-1]:
+        assert ln["ok"] is True and ln["seconds"] >= 0
+        assert ln["compile_seconds"] >= 0 and ln["checked"]
+        assert ln["device"].items() >= CPU.items()
+    # the contract's last line: these keys and nothing more
+    assert set(lines[-1]) == {"ok", "device"} and lines[-1]["ok"] is True
+    assert set(lines[-1]["device"]) == {"platform", "kind", "count"}
+    assert lines[-1]["device"].items() >= CPU.items()
+
+
+def _stub(**facts):
+    return lambda plan: dict(device={**CPU, "count": 1}, compile_s=0.0,
+                             **facts)
+
+
+def test_run_prints_a_line_per_phase_then_the_result(capsys):
+    rc, lines, _ = _run(capsys, TINY, (("a", _stub(x=1)), ("b", _stub(y=2))))
+    assert rc == 0
+    _check_lines(lines, ["a", "b"])
+
+
+def test_a_failed_phase_fails_the_run(capsys):
+    def boom(plan):
+        chip_smoke.check(False, "forced failure", plan=plan.model_size)
+
+    rc, lines, err = _run(capsys, TINY, (
+        ("first", _stub(x=1)), ("second", boom), ("never", _stub(x=1))))
+    assert rc != 0
+    assert [ln["phase"] for ln in lines] == ["first"], \
+        "no line for the failed phase, none after it, no result"
+    assert "forced failure" in err and "'second' FAILED" in err
+
+
+def test_phases_that_disagree_on_the_device_fail_the_run(capsys):
+    other = lambda plan: dict(  # noqa: E731
+        device={"platform": "cpu", "kind": "other", "count": 1},
+        compile_s=0.0, x=1)
+    rc, lines, err = _run(capsys, TINY, (("a", _stub(x=1)), ("b", other)))
+    assert rc != 0 and "disagree on the device" in err
+    assert all("phase" in ln for ln in lines)
+
+
+def test_no_chip_means_no_result(capsys, monkeypatch):
+    """As the driver first runs it: in a sandbox without an accelerator
+    the script exits non-zero and prints no result."""
+    monkeypatch.delenv("RAY_TPU_CHIPS", raising=False)
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr()
+    assert out.out == "" and "found 0 device node(s)" in out.err
+
+
+def test_the_store_is_sized_for_the_published_weights():
+    # 1,168,199,680 f32 parameters in one object, plus headroom
+    assert chip_smoke.store_bytes(chip_smoke.Plan()) > 4660 * 2**20
+    assert chip_smoke.store_bytes(TINY) < 2 * 2**30
+
+
+def test_train_phase_rehearses_on_the_cpu(cluster):
+    facts = chip_smoke.train_phase(TINY)
+    assert facts["device"].items() >= CPU.items()
+    assert len(facts["losses"]) == TINY.steps
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert not facts["batch_cut_to_fit"] and not facts["kernel_in_step"]
+
+
+def test_kernels_phase_rehearses_on_the_cpu():
+    facts = chip_smoke.kernels_phase(TINY)
+    assert facts["device"].items() >= CPU.items()
+    assert set(facts["rel_err_vs_reference"]) == {"out", "dq", "dk", "dv"}
+    assert not facts["kernel_in_forward"]  # the CPU takes the reference
+
+
+@pytest.mark.slow
+def test_serve_phase_rehearses_on_the_cpu(cluster, capsys):
+    rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[:1])
+    assert rc == 0
+    _check_lines(lines, ["serve"])
+    serve = lines[0]["checked"]
+    assert serve["seed_replay_exact"]
+    # the tiny model computes in f32: speculation changes no token
+    assert set(serve["spec_on_vs_off_agreeing_tokens"].values()) == {
+        TINY.max_tokens}
+    assert serve["spec_on"]["spec_acceptance"]
+
+
+@pytest.mark.slow
+def test_four_chip_phases_rehearse_on_the_cpu(cluster, capsys):
+    rc, lines, _ = _run(capsys, chip_smoke.Plan.tiny(chips=4))
+    assert rc == 0
+    _check_lines(lines, ["train4", "pool4"])
+    train4, pool4 = (ln["checked"] for ln in lines[:-1])
+    assert train4["max_loss_diff"] <= train4["loss_tolerance"]
+    assert len(train4["params_bytes_per_device"]) == 4
+    assert pool4["four_replicas"]["replicas"] == 4

@@ -120,15 +120,23 @@ def test_moe_top_k_masks_experts(rng):
         )
 
 
-def test_remat_policies_agree(rng):
+def test_remat_policies_agree(rng, monkeypatch):
     """dots vs dots_flash vs nothing: same gradients, different remat."""
+    import functools
+
+    from ray_tpu.ops import flash_attention as fa
+
+    # no chip here: the kernel runs in the Pallas interpreter, and that
+    # is this test's choice (the op never infers it from the backend)
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
     tokens = jax.random.randint(rng, (2, 33), 0, 256)
     batch = {"tokens": tokens}
     grads = {}
     for policy in ("dots", "dots_flash", "dots_flash_qkv",
                    "dots_flash_qkv_mlp", "nothing"):
-        # use_flash=True: the flash kernel (interpret mode on CPU) must be
-        # in the graph or the flash_out/flash_lse plumbing goes untested
+        # use_flash=True: the flash kernel must be in the graph or the
+        # flash_out/flash_lse plumbing goes untested
         cfg = llama.LlamaConfig.tiny(
             remat=True, remat_policy=policy, use_flash=True,
             max_seq_len=32,

@@ -313,3 +313,53 @@ def test_flash_bwd_heads_per_block_matches_reference(rng, hb):
     for a, b_ in zip(g_ref, g_flash):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=2e-4, rtol=1e-3)
+
+
+def test_attention_shards_flash_over_the_mesh(rng, monkeypatch):
+    """Under a multi-device mesh `attention` runs the kernel inside a
+    shard_map (batch over fsdp, heads and kv heads over tp): outputs and
+    gradients must equal the unsharded reference."""
+    import functools
+
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops.attention import attention
+    from ray_tpu.parallel import MeshConfig, build_mesh, use_mesh
+
+    # no chip here: the interpreter is this test's explicit choice
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))
+    k1, k2, k3 = jax.random.split(rng, 3)
+    q = jax.random.normal(k1, (2, 128, 4, 64), jnp.float32)
+    k = jax.random.normal(k2, (2, 128, 2, 64), jnp.float32)
+    v = jax.random.normal(k3, (2, 128, 2, 64), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    ref = functools.partial(attention_reference, causal=True)
+    want = jax.value_and_grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), jax.devices()[:4])
+    with use_mesh(mesh):
+        got = jax.jit(jax.value_and_grad(
+            loss(functools.partial(attention, use_flash=True)),
+            argnums=(0, 1, 2)))(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2e-4, rtol=2e-4)
+
+
+def test_attention_never_falls_back_once_flash_is_chosen(rng):
+    """Ragged lengths raise instead of quietly taking the O(T*S)
+    reference; use_flash=False is the explicit way to the reference,
+    and on a CPU backend use_flash=None means the reference."""
+    from ray_tpu._private import config as _cfg
+    from ray_tpu.ops.attention import attention
+
+    block = _cfg.get("flash_block_q")
+    q = jnp.zeros((1, block + 64, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="multiples of the flash blocks"):
+        jax.eval_shape(lambda: attention(q, q, q, use_flash=True))
+    assert jax.eval_shape(
+        lambda: attention(q, q, q, use_flash=False)).shape == q.shape
+    assert jax.eval_shape(
+        lambda: attention(q, q, q, use_flash=None)).shape == q.shape

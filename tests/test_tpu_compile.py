@@ -1,0 +1,256 @@
+"""Compile the main path's kernels and step programs for the real chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED, not attached (on-chip-measurement guide, section 2): what it
+refuses here — a Mosaic kernel GSPMD cannot partition, a tile that does
+not align, a program that does not fit HBM — costs no chip time. Nothing
+runs, so these say nothing about results or speed; ``chip_smoke.py`` is
+the run.
+
+Code that asks ``jax.default_backend()`` sees the CPU during such a
+compile, so every case asks for the kernel explicitly (``use_flash=True``,
+``interpret=False``) and asserts the custom call is in the compiled text.
+The cheap cases are tier-1; the 14-25 s programs are ``-m slow``:
+
+    JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_compile.py -m slow -s
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import Mesh, SingleDeviceSharding  # noqa: E402
+
+from ray_tpu.models import decode_engine as de  # noqa: E402
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.ops.flash_attention import flash_attention  # noqa: E402
+from ray_tpu.parallel import AXES, MeshConfig, use_mesh  # noqa: E402
+from ray_tpu.train import batch_sharding, make_train_step  # noqa: E402
+from ray_tpu.train.optim import fused_adamw  # noqa: E402
+from ray_tpu.train.step import train_state_shardings  # noqa: E402
+
+KERNEL = "tpu_custom_call"
+MIB = 2**20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e 2x2 host. The persistent compile cache is off
+    around these compiles: an entry written for a described device
+    cannot be read back without the chip (it only warns next time)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this host
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a tree
+    of them): a described device cannot hold arrays."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _serve_cfg(size="1b", max_len=288):
+    # as serve/llm.py build_model makes it
+    return llama.LlamaConfig(**{
+        **llama.llama2_size(size).__dict__, "vocab_size": 32128,
+        "max_seq_len": max_len, "dtype": "bfloat16", "remat": False})
+
+
+def _train_cfg(size="1b", seq=2048, **kw):
+    # bench.py's 1B recipe (chip_smoke.model_fields + train phase);
+    # use_flash=True because the dispatch would read the CPU backend
+    # here and take the reference
+    return llama.LlamaConfig(**{
+        **llama.llama2_size(size).__dict__, "vocab_size": 32128,
+        "max_seq_len": seq, "dtype": "bfloat16", "remat": True,
+        "remat_policy": "flash_qkv", "use_flash": True, **kw})
+
+
+def _mem(compiled) -> dict:
+    m = compiled.memory_analysis()
+    return {"arguments_mib": m.argument_size_in_bytes // MIB,
+            "temporaries_mib": m.temp_size_in_bytes // MIB,
+            "outputs_mib": m.output_size_in_bytes // MIB,
+            "aliased_mib": m.alias_size_in_bytes // MIB}
+
+
+# ---- kernels ----
+
+FLASH_SHAPES = {
+    # name: (batch, seq, q heads, kv heads, head dim)
+    "1b": (2, 2048, 16, 8, 128),
+    "350m": (8, 2048, 8, 8, 128),
+}
+# The GQA 1B shape compiles in 2-4 s; tier-1 keeps its backward case,
+# whose program holds the forward kernel too. The MHA 350M shape takes
+# the multi-head grid cells (flash_heads_per_block=4) and Mosaic needs
+# ~12 s for each direction, so it rides with the long programs.
+_slow = pytest.mark.slow
+
+
+@pytest.mark.parametrize("shape,direction", [
+    pytest.param("1b", "forward", marks=_slow), ("1b", "backward"),
+    pytest.param("350m", "forward", marks=_slow),
+    pytest.param("350m", "backward", marks=_slow)])
+def test_flash_kernel_compiles(topo, shape, direction):
+    b, t, hq, hkv, d = FLASH_SHAPES[shape]
+    chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((b, t, hq, d), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    fn = fwd if direction == "forward" else jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    # backward: the forward kernel and the fused backward kernel
+    assert text.count(KERNEL) >= (1 if direction == "forward" else 2)
+
+
+def _engine_args(cfg, chip, slots=8, max_len=288):
+    params = _on(chip, jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on(chip, jax.eval_shape(
+        lambda: de.init_ragged_cache(cfg, slots, max_len)))
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=chip)  # noqa: E731
+    return params, cache, vec
+
+
+def test_decode_chunk_compiles_at_1b_widths(topo):
+    cfg = _serve_cfg()
+    chip = SingleDeviceSharding(topo.devices[0])
+    params, cache, vec = _engine_args(cfg, chip)
+    compiled = de.decode_chunk.lower(
+        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        chunk=8).compile()
+    mem = _mem(compiled)
+    # the f32 masters are the arguments; they must fit a 16 GB chip
+    # beside the temporaries
+    assert mem["arguments_mib"] + mem["temporaries_mib"] < 15 * 1024, mem
+
+
+# ---- the train step, on one chip and sharded over four ----
+
+
+def _train_step(topo, cfg, mesh_cfg: MeshConfig, batch=2, seq=2048):
+    """bench.py's / chip_smoke.py's train step, compiled for a mesh over
+    the first ``mesh_cfg.size`` described chips. `init_train_state`
+    would place real arrays; `train_state_shardings` gives the same
+    shardings with shapes only."""
+    devices = np.asarray(topo.devices[:mesh_cfg.size])
+    mesh = Mesh(devices.reshape(mesh_cfg.shape), AXES)
+    opt = fused_adamw(1e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16,
+                      nu_dtype=jnp.bfloat16)
+    _, abstract, state_sh = train_state_shardings(
+        lambda k: llama.init_params(cfg, k), llama.param_logical_axes(cfg),
+        opt, mesh)
+    step = make_train_step(
+        lambda p, b: llama.loss_fn(p, b, cfg), opt, mesh, state_sh,
+        compute_grad_norm=False, grads_dtype=jnp.bfloat16)
+    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding(mesh))
+    with use_mesh(mesh):
+        return step.lower(_on(state_sh, abstract),
+                          {"inputs": tok, "targets": tok}).compile()
+
+
+def test_sharded_train_step_compiles_with_kernel(topo):
+    """2 layers at 1B widths on fsdp=2 x tp=2: before the shard_map in
+    ops/attention.py this failed with 'Mosaic kernels cannot be
+    automatically partitioned'."""
+    text = _train_step(topo, _train_cfg(n_layers=2),
+                       MeshConfig(fsdp=2, tp=2)).as_text()
+    assert KERNEL in text
+    assert "all-reduce" in text and "all-gather" in text
+
+
+def test_sharded_flash_refuses_what_it_cannot_split(topo):
+    from ray_tpu.ops.attention import attention
+
+    q = jnp.zeros((2, 128, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((2, 128, 1, 128), jnp.bfloat16)
+    devs = np.asarray(topo.devices)
+    with use_mesh(Mesh(devs.reshape(MeshConfig(fsdp=2, tp=2).shape), AXES)):
+        with pytest.raises(ValueError, match="tp has to divide n_kv_heads"):
+            jax.eval_shape(
+                lambda: attention(q, kv, kv, use_flash=True))
+    with use_mesh(Mesh(devs.reshape(MeshConfig(sp=2, tp=2).shape), AXES)):
+        with pytest.raises(NotImplementedError, match="shards the sequence"):
+            jax.eval_shape(
+                lambda: attention(q, kv, kv, use_flash=True))
+    # inside parallel/pipeline.py's pp stages: not brought up, said so
+    cfg = _train_cfg(n_layers=2, pipeline_microbatches=2)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((4, 2048), jnp.int32)
+    with use_mesh(Mesh(devs.reshape(MeshConfig(pp=2, tp=2).shape), AXES)):
+        with pytest.raises(NotImplementedError, match="already manual"):
+            jax.eval_shape(lambda p, t: llama.forward(p, t, cfg), params, tok)
+
+
+# ---- the long programs: -m slow, run before a chip call ----
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["sampled", "spec", "prefill_32",
+                                     "prefill_128"])
+def test_serving_programs_compile_at_1b_widths(topo, program):
+    cfg = _serve_cfg()
+    chip = SingleDeviceSharding(topo.devices[0])
+    params, cache, vec = _engine_args(cfg, chip)
+    lanes = (vec(jnp.uint32), vec(jnp.float32), vec(jnp.float32))
+    if program == "sampled":
+        lowered = de.decode_chunk_sampled.lower(
+            params, cache, vec(jnp.int32), vec(jnp.bool_), *lanes,
+            cfg=cfg, chunk=8)
+    elif program == "spec":
+        lowered = de.decode_chunk_spec.lower(
+            params, None, cache, vec(jnp.int32), vec(jnp.bool_), *lanes,
+            cfg=cfg, rounds=8, depth=4, draft_layers=1)
+    else:
+        bucket = int(program.split("_")[1])
+        prompts = jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=chip)
+        lowered = de._prefill_batch_into_slots.lower(
+            params, prompts, vec(jnp.int32), vec(jnp.int32), *lanes,
+            cache, vec(jnp.int32), cfg=cfg)
+    mem = _mem(lowered.compile())
+    print(f"\n{program}: {mem}")
+    assert mem["arguments_mib"] + mem["temporaries_mib"] < 15 * 1024, mem
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chips", [1, 4])
+def test_full_1b_train_step_compiles(topo, chips):
+    """All 22 layers, b2 x T2048, flash_qkv remat, bf16 grads and
+    moments: the program the smoke's train phases run. Prints
+    memory_analysis() — on one chip it sits at the edge of 16 GB."""
+    compiled = _train_step(
+        topo, _train_cfg(),
+        MeshConfig(fsdp=2, tp=2) if chips == 4 else MeshConfig())
+    text = compiled.as_text()
+    print(f"\n1b train step, {chips} chip(s): {_mem(compiled)} "
+          f"kernel calls={text.count(KERNEL)}")
+    assert KERNEL in text
