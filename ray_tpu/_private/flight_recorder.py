@@ -18,6 +18,15 @@ wall-clock anchor captured at recorder init converts to epoch seconds
 for the timeline (wall = mono + anchor), so durations never jump under
 clock adjustment but cross-process rendering still lines up.
 
+The profiler's clock: in a process that has already imported ``jax``
+(a span never imports it) every :func:`span` is ALSO a
+``jax.profiler.TraceAnnotation`` and every :func:`mark` an empty one, so
+under any ``jax.profiler`` capture the program's spans land on their
+thread's line of ``/host:CPU``, timed by the profiler beside the device
+planes, with their attrs as the event's stats. With no capture running
+an annotation is a flag test (about half a microsecond). The recorder
+stays always on; the profiler decides whether an annotation is kept.
+
 Overhead budget: ``record()`` on the hot path is a dict build + deque
 append under a lock (no I/O, no syscalls beyond the clock reads); the
 runtime_perf ``obs`` family holds it to <=3% on serve tokens/s and ring
@@ -31,6 +40,7 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
@@ -100,18 +110,46 @@ def wall(mono: float) -> float:
     return mono + _get().anchor
 
 
+def _annotation(name: str, attrs: dict):
+    """An un-entered ``TraceAnnotation`` carrying ``attrs``, or None in
+    a process that has not imported jax."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        return jax.profiler.TraceAnnotation(name, **_plain(attrs))
+    except Exception:  # noqa: BLE001 — observability is best-effort
+        return None
+
+
+def _plain(attrs: dict) -> dict:
+    """Attrs as the profiler's metadata takes them: numbers and strings
+    (a bool as 0/1); anything else is left to the ring."""
+    return {k: (int(v) if isinstance(v, bool) else v)
+            for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 def record(kind: str, name: str, start_mono: float, end_mono: float, *,
            attrs: dict | None = None, trace: dict | None = None,
-           flush: bool = True) -> None:
+           flush: bool = True, annotate: bool = False) -> None:
     """Record a completed span (monotonic start/end stamps).
 
     ``flush=False`` keeps the span ring-only (postmortem visibility,
     no head traffic) — use it for per-chunk hot-path spans. ``trace``
     overrides the ambient trace context (``{"trace_id", "parent"}``)
     for spans recorded on behalf of another request (stream polls).
+    ``annotate=True`` also leaves an instant event of the same name and
+    attrs on the profiler's host line: for a span whose interval is only
+    known afterwards (the attrs carry its parts).
     """
     if not _on():
         return
+    if annotate:
+        ann = _annotation(name, attrs or {})
+        if ann is not None:
+            with ann:
+                pass
     r = _get()
     if trace is None:
         from ray_tpu._private import trace as _trace
@@ -143,13 +181,35 @@ def record(kind: str, name: str, start_mono: float, end_mono: float, *,
 def span(kind: str, name: str, *, attrs: dict | None = None,
          flush: bool = True):
     """Context-manager form; yields the attrs dict so the body can
-    attach fields (byte counts, breakdowns) before the span closes."""
+    attach fields (byte counts, breakdowns) before the span closes.
+    The body runs inside a profiler annotation of the same name (module
+    docstring); fields the body attached reach it when it closes."""
     a = dict(attrs) if attrs else {}
+    ann = _annotation(name, a) if _on() else None
+    n_entry = len(a)
+    if ann is not None:
+        ann.__enter__()
     t0 = time.monotonic()
     try:
         yield a
     finally:
-        record(kind, name, t0, time.monotonic(), attrs=a, flush=flush)
+        t1 = time.monotonic()
+        if ann is not None:
+            if len(a) > n_entry:
+                ann.set_metadata(**_plain(
+                    dict(list(a.items())[n_entry:])))
+            ann.__exit__(None, None, None)
+        record(kind, name, t0, t1, attrs=a, flush=flush)
+
+
+def mark(kind: str, name: str, *, attrs: dict | None = None,
+         trace: dict | None = None, flush: bool = True) -> None:
+    """A zero-length span for a fact known only at one instant (a first
+    token, a poll that found tokens): one ring entry with start = end
+    and one empty profiler annotation carrying the attrs."""
+    now = time.monotonic()
+    record(kind, name, now, now, attrs=attrs, trace=trace, flush=flush,
+           annotate=True)
 
 
 # -- flusher: spans -> head task-event ring ------------------------------

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import flight_recorder as _fr
+from ray_tpu._private import trace as _trace
 from ray_tpu.models import llama, mlp
 from ray_tpu.models.llama import LlamaConfig
 
@@ -96,22 +98,25 @@ def _layer_decode_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
     s = ck.shape[1]
 
     q, k, v = llama._qkv(cfg, p, h, sin, cos)  # [B, 1, H*, hd]
-    rows = jnp.arange(b)
-    ck = ck.at[rows, pos].set(k[:, 0])
-    cv = cv.at[rows, pos].set(v[:, 0])
+    with jax.named_scope("cache"):
+        rows = jnp.arange(b)
+        ck = ck.at[rows, pos].set(k[:, 0])
+        cv = cv.at[rows, pos].set(v[:, 0])
 
-    kk = _repeat_kv(ck, hq // hkv)
-    vv = _repeat_kv(cv, hq // hkv)
-    logits = jnp.einsum(
-        "bthd,bshd->bhts", q, kk, preferred_element_type=jnp.float32
-    ) * (hd ** -0.5)
-    k_pos = jnp.arange(s, dtype=jnp.int32)[None, :]  # [1, S]
-    live = k_pos <= pos[:, None]  # [B, S] — each slot sees its prefix
-    logits = jnp.where(live[:, None, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(cdt)
-    o = jnp.einsum(
-        "bhts,bshd->bthd", probs, vv, preferred_element_type=jnp.float32
-    ).astype(cdt)
+    with jax.named_scope("attn"):
+        kk = _repeat_kv(ck, hq // hkv)
+        vv = _repeat_kv(cv, hq // hkv)
+        logits = jnp.einsum(
+            "bthd,bshd->bhts", q, kk, preferred_element_type=jnp.float32
+        ) * (hd ** -0.5)
+        k_pos = jnp.arange(s, dtype=jnp.int32)[None, :]  # [1, S]
+        live = k_pos <= pos[:, None]  # [B, S]: each slot sees its prefix
+        logits = jnp.where(live[:, None, None, :], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(cdt)
+        o = jnp.einsum(
+            "bhts,bshd->bthd", probs, vv,
+            preferred_element_type=jnp.float32,
+        ).astype(cdt)
     h = llama._attn_out_and_mlp(cfg, p, h, o)
     return h, ck, cv
 
@@ -550,14 +555,46 @@ def _prefill_suffix_into_slot(params, pref_k, pref_v, n_prefix, suffix,
     return cache, cur_tok.at[slot].set(tok0), tok0, logp0
 
 
+# Birth stamps a request may carry, in the order they are taken, and the
+# name of the part between each and the next (the last: engine.submit).
+_STAMPS = ("proxy_recv", "pool_enqueue", "pool_admitted")
+_STAMP_PARTS = ("proxy_to_pool_ms", "admission_wait_ms",
+                "pool_to_replica_ms")
+
+
+def _upstream_ms(stamps, submitted_wall: float) -> dict:
+    """A request's way to ``engine.submit`` split at its birth stamps
+    (epoch seconds): each part whose two ends are there, and
+    ``upstream_ms`` from the earliest stamp to the submit. ``stamps``
+    came with the request: anything that is no number is passed over."""
+    if not isinstance(stamps, dict):
+        return {}
+    pts = [stamps.get(k) for k in _STAMPS] + [submitted_wall]
+    pts = [t if isinstance(t, (int, float)) else None for t in pts]
+    out = {name: round(1e3 * (b - a), 3)
+           for name, a, b in zip(_STAMP_PARTS, pts, pts[1:])
+           if a is not None and b is not None}
+    first = next((t for t in pts[:-1] if t is not None), None)
+    if first is not None:
+        out["upstream_ms"] = round(1e3 * (submitted_wall - first), 3)
+    return out
+
+
+def _caller_trace() -> dict | None:
+    cur = _trace.current()
+    return None if cur is None else {"trace_id": cur[0], "parent": cur[1]}
+
+
 @dataclass
 class _Stream:
     sid: int
     prompt: np.ndarray
     max_new: int
     tokens: list = field(default_factory=list)
-    token_times: list = field(default_factory=list)  # perf_counter stamps
+    token_times: list = field(default_factory=list)  # monotonic stamps
     submitted: float = 0.0
+    admitted_at: float = 0.0  # slot granted, prefill dispatched
+    bucket: int = 0  # static prompt width it was prefilled at (0: none)
     done: bool = False
     taken: int = 0  # tokens already handed out via take_tokens()
     prefilled: dict | None = None  # external KV payload (k/v/first_token)
@@ -573,6 +610,12 @@ class _Stream:
     version: int | None = None
     # tenant for per-tenant SLO attribution (TBT histograms)
     tenant: str = "-"
+    # the submitter's trace context ({"trace_id", "parent"}): the pump
+    # thread records this stream's spans under it
+    trace: dict | None = None
+    # birth stamps the request carried (epoch seconds on the recorder's
+    # clock: proxy_recv, pool_enqueue, pool_admitted), or None
+    stamps: dict | None = None
 
 
 class RaggedDecoder:
@@ -622,6 +665,11 @@ class RaggedDecoder:
         # stamp the version live at their admission
         self.weights_version = int(weights_version)
         self.pumps = 0  # engine steps — staleness windows count these
+        # calls of the static-width prefill program, the real prompts
+        # they held and the rows they paid for (monotonic totals)
+        self.prefill_calls = 0
+        self.prefill_prompts = 0
+        self.prefill_rows = 0
         self.slot_stream: list[_Stream | None] = [None] * slots
         self.queue: collections.deque[_Stream] = collections.deque()
         self._next_sid = 0
@@ -657,11 +705,13 @@ class RaggedDecoder:
 
     def submit(self, prompt_tokens, max_new: int, *,
                temperature: float = 0.0, top_p: float = 1.0,
-               seed: int = 0, tenant: str = "-") -> int:
+               seed: int = 0, tenant: str = "-",
+               stamps: dict | None = None) -> int:
         """Validates HERE (caller's thread) so a bad request raises at
         the submitter, never inside the pump loop. ``temperature`` 0 is
         greedy decode; > 0 samples on the stream's (seed, position)
-        RNG lane with nucleus (top-p) filtering."""
+        RNG lane with nucleus (top-p) filtering. ``stamps``: the
+        request's birth stamps, for the first-token span's split."""
         prompt = np.asarray(prompt_tokens, np.int32)
         self._bucket(len(prompt))  # raises if no bucket fits
         # clamp generation to the slot's cache capacity: past max_len
@@ -677,9 +727,10 @@ class RaggedDecoder:
         if float(temperature) > 0.0:
             self._sampling_seen = True
         s = _Stream(self._next_sid, prompt, min(max_new, room),
-                    submitted=time.perf_counter(),
+                    submitted=time.monotonic(),
                     temperature=float(temperature), top_p=float(top_p),
-                    seed=int(seed) & 0xFFFFFFFF, tenant=str(tenant))
+                    seed=int(seed) & 0xFFFFFFFF, tenant=str(tenant),
+                    trace=_caller_trace(), stamps=stamps)
         self._next_sid += 1
         self.queue.append(s)
         self._by_sid[s.sid] = s
@@ -688,7 +739,8 @@ class RaggedDecoder:
     def submit_prefilled(self, prompt_tokens, max_new: int,
                          kv: dict, *, temperature: float = 0.0,
                          top_p: float = 1.0, seed: int = 0,
-                         tenant: str = "-") -> int:
+                         tenant: str = "-",
+                         stamps: dict | None = None) -> int:
         """Enqueue a stream whose prefill already happened elsewhere
         (a dedicated prefill worker, serve/llm_pool.py). `kv`:
         {"k"/"v": [n_layers, S, n_kv_heads, head_dim] with S == this
@@ -716,9 +768,10 @@ class RaggedDecoder:
         if float(temperature) > 0.0:
             self._sampling_seen = True
         s = _Stream(self._next_sid, prompt, min(max_new, room),
-                    submitted=time.perf_counter(),
+                    submitted=time.monotonic(),
                     temperature=float(temperature), top_p=float(top_p),
                     seed=int(seed) & 0xFFFFFFFF, tenant=str(tenant),
+                    trace=_caller_trace(), stamps=stamps,
                     prefilled={"k": k, "v": np.asarray(kv["v"]),
                                "first_token": int(kv["first_token"]),
                                "first_logprob":
@@ -783,10 +836,23 @@ class RaggedDecoder:
             grabbed.append((free.pop(), self.queue.popleft()))
         if not grabbed:
             return
+        n_prefilled = sum(s.prefilled is not None for _, s in grabbed)
+        with _fr.span("serve", "engine.admit", flush=False, attrs={
+                "admitted": len(grabbed),
+                "prefilled": n_prefilled}) as sp:
+            n_cold = self._admit_grabbed(grabbed)
+            sp["cold"] = n_cold
+            sp["warm"] = len(grabbed) - n_prefilled - n_cold
+
+    def _admit_grabbed(self, grabbed) -> int:
+        """Put each (slot, stream) on the device by the path it takes:
+        adopted KV, prefix-cache warm, or the batched cold prefill.
+        Returns how many went cold."""
         cold: list[tuple[int, _Stream]] = []
-        t_now = time.perf_counter()
+        t_now = time.monotonic()
         for slot, s in grabbed:
             s.version = self.weights_version
+            s.admitted_at = t_now
             self._set_lane(slot, s)
             if s.prefilled is not None:
                 # disaggregated path: the KV rows were computed by a
@@ -802,6 +868,7 @@ class RaggedDecoder:
                 s.logprobs.append(p.get("first_logprob", 0.0))
                 s.tokens.append(p["first_token"])
                 s.token_times.append(t_now)
+                self._record_first_token(s, t_now)
                 s.prefilled = None  # free the host slab
                 self.slot_stream[slot] = s
             elif self.prefix_cache is not None and self._admit_warm(
@@ -813,8 +880,21 @@ class RaggedDecoder:
         for slot, s in cold:
             by_bucket.setdefault(
                 self._bucket(len(s.prompt)), []).append((slot, s))
-        f = self.slots  # static prefill width: one compile per bucket
         for pb, entries in by_bucket.items():
+            self._prefill_cold(pb, entries)
+        return len(cold)
+
+    def _prefill_cold(self, pb: int, entries) -> None:
+        """One call of the static-width prefill program for the streams
+        of one bucket: what the call held is counted HERE, where it is
+        exact (``prefill_*`` in stats(), the ``engine.prefill`` span)."""
+        f = self.slots  # static prefill width: one compile per bucket
+        self.prefill_calls += 1
+        self.prefill_prompts += len(entries)
+        self.prefill_rows += f
+        with _fr.span("serve", "engine.prefill", flush=False, attrs={
+                "bucket": pb, "prompts": len(entries), "rows": f,
+                "tokens": sum(len(s.prompt) for _, s in entries)}):
             prompts = np.zeros((f, pb), np.int32)
             lens = np.ones((f,), np.int32)
             slots_arr = np.full((f,), f + 1024, np.int32)  # OOB: dropped
@@ -823,6 +903,7 @@ class RaggedDecoder:
             topps = np.ones((f,), np.float32)
             for i, (slot, s) in enumerate(entries):
                 n = len(s.prompt)
+                s.bucket = pb
                 prompts[i, :n] = s.prompt  # right-pad
                 lens[i] = n
                 slots_arr[i] = slot
@@ -835,14 +916,14 @@ class RaggedDecoder:
                 jnp.asarray(slots_arr), jnp.asarray(seeds),
                 jnp.asarray(temps), jnp.asarray(topps),
                 self.cache, self.cur_tok, self.cfg)
-            # NO host sync here: first tokens ride the next chunk's
-            # single device_get (a per-admission sync would stall the
-            # host until the prefill finished)
-            for i, (slot, s) in enumerate(entries):
-                self._pending_first.append((s, toks0[i], logp0[i]))
-                self.slot_stream[slot] = s
-            if self.prefix_cache is not None:
-                self._insert_prefixes(entries)
+        # NO host sync here: first tokens ride the next chunk's
+        # single device_get (a per-admission sync would stall the
+        # host until the prefill finished)
+        for i, (slot, s) in enumerate(entries):
+            self._pending_first.append((s, toks0[i], logp0[i]))
+            self.slot_stream[slot] = s
+        if self.prefix_cache is not None:
+            self._insert_prefixes(entries)
 
     def _set_lane(self, slot: int, s: _Stream) -> None:
         self._slot_seed[slot] = s.seed
@@ -889,6 +970,7 @@ class RaggedDecoder:
             np.int32(slot), self.cache, self.cur_tok, self.cfg)
         self._pending_first.append((s, tok0, logp0))
         self.slot_stream[slot] = s
+        s.bucket = sb
         pc.record_outcome(True)  # cached rows actually served
         return True
 
@@ -929,59 +1011,122 @@ class RaggedDecoder:
                 depth = 0
         if depth > 0:
             return self._pump_spec(active_mask, depth)
-        if self._sampling_seen:
-            toks, lps, self.cache, self.cur_tok = decode_chunk_sampled(
-                self.params, self.cache, self.cur_tok, active_mask,
-                jnp.asarray(self._slot_seed),
-                jnp.asarray(self._slot_temp),
-                jnp.asarray(self._slot_topp), self.cfg, self.chunk)
-        else:
-            # greedy-only engine: the legacy argmax kernel — no
-            # per-token argsort/softmax; logprobs placeholder 0.0
-            toks, self.cache, self.cur_tok = decode_chunk(
-                self.params, self.cache, self.cur_tok, active_mask,
-                self.cfg, self.chunk)
-            lps = None
-        if self.chunk_delay_s:
-            time.sleep(self.chunk_delay_s)  # see __init__: emulated
-            # device dispatch latency (GIL released; replicas overlap)
-        firsts, self._pending_first = self._pending_first, []
-        toks, lps, pos_np, first_toks, first_lps = jax.device_get(
-            (toks, lps, self.cache["pos"],
-             [t for _, t, _ in firsts], [lp for _, _, lp in firsts]))
+        n_active = int(active_mask.sum())
+        with _fr.span("serve", "engine.decode_dispatch", flush=False,
+                      attrs={"active": n_active, "chunk": self.chunk,
+                             "depth": 0}):
+            if self._sampling_seen:
+                toks, lps, self.cache, self.cur_tok = \
+                    decode_chunk_sampled(
+                        self.params, self.cache, self.cur_tok,
+                        active_mask, jnp.asarray(self._slot_seed),
+                        jnp.asarray(self._slot_temp),
+                        jnp.asarray(self._slot_topp), self.cfg,
+                        self.chunk)
+            else:
+                # greedy-only engine: the legacy argmax kernel — no
+                # per-token argsort/softmax; logprobs placeholder 0.0
+                toks, self.cache, self.cur_tok = decode_chunk(
+                    self.params, self.cache, self.cur_tok, active_mask,
+                    self.cfg, self.chunk)
+                lps = None
+        firsts = self._take_pending_first()
+        toks, lps, pos_np, first_toks, first_lps = self._readback(
+            (toks, lps, self.cache["pos"]), firsts)
         if lps is None:
             lps = np.zeros((self.slots, self.chunk), np.float32)
-        t_now = time.perf_counter()
-        delivered = 0
-        for (s, _, _), t0, lp0 in zip(firsts, first_toks, first_lps):
-            # logprob first, token second: take_tokens slices both lists
-            # by len(tokens), so the parallel list must never lag it
-            s.logprobs.append(float(lp0))
-            s.tokens.append(int(t0))
-            s.token_times.append(t_now)
-            delivered += 1
-        for slot, s in enumerate(self.slot_stream):
-            if s is None:
-                continue
-            take = min(self.chunk, s.max_new - len(s.tokens))
-            s.logprobs.extend(float(p) for p in lps[slot, :take])
-            s.tokens.extend(int(t) for t in toks[slot, :take])
-            s.token_times.extend([t_now] * take)
-            delivered += take
-            # per-token TBT: this stream's inter-chunk gap amortized
-            # over the chunk's tokens (tokens inside one chunk land
-            # together — the gap IS the per-token pacing a client sees)
-            if take > 0 and len(s.token_times) > take:
-                prev = s.token_times[-take - 1]
-                if t_now > prev:
-                    self._tbt_obs((t_now - prev) / take, s.tenant)
-            if len(s.tokens) >= s.max_new \
-                    or int(pos_np[slot]) >= self.max_len - 1:
-                s.done = True
-                self.finished[s.sid] = s
-                self.slot_stream[slot] = None  # slot freed THIS chunk
+        t_now = time.monotonic()
+        with _fr.span("serve", "engine.deliver", flush=False) as sp:
+            delivered = self._deliver_firsts(
+                firsts, first_toks, first_lps, t_now)
+            finished = 0
+            for slot, s in enumerate(self.slot_stream):
+                if s is None:
+                    continue
+                take = min(self.chunk, s.max_new - len(s.tokens))
+                finished += self._deliver(
+                    slot, s, [int(t) for t in toks[slot, :take]],
+                    [float(p) for p in lps[slot, :take]], t_now,
+                    int(pos_np[slot]))
+                delivered += take
+            sp.update(delivered=delivered, firsts=len(firsts),
+                      finished=finished)
         self._account(t_now, delivered)
-        return int(active_mask.sum())
+        return n_active
+
+    # -- one chunk's host side, shared by the plain and the speculative
+    # pump --
+
+    def _take_pending_first(self) -> list:
+        firsts, self._pending_first = self._pending_first, []
+        return firsts
+
+    def _readback(self, chunk_out: tuple, firsts: list) -> tuple:
+        """The chunk's ONE device→host sync: ``chunk_out`` plus the first
+        tokens and logprobs of the streams prefilled before it."""
+        with _fr.span("serve", "engine.readback", flush=False):
+            if self.chunk_delay_s:
+                time.sleep(self.chunk_delay_s)  # see __init__: emulated
+                # device time (GIL released; replicas overlap)
+            *out, first_toks, first_lps = jax.device_get(
+                (*chunk_out, [t for _, t, _ in firsts],
+                 [lp for _, _, lp in firsts]))
+        return (*out, first_toks, first_lps)
+
+    def _deliver_firsts(self, firsts, first_toks, first_lps,
+                        t_now: float) -> int:
+        for (s, _, _), t0, lp0 in zip(firsts, first_toks, first_lps):
+            # logprob and stamp first, token last: take_tokens slices
+            # by len(tokens), so the parallel lists must never lag it
+            s.logprobs.append(float(lp0))
+            s.token_times.append(t_now)
+            s.tokens.append(int(t0))
+            self._record_first_token(s, t_now)
+        return len(firsts)
+
+    def _deliver(self, slot: int, s: _Stream, toks: list, lps: list,
+                 t_now: float, pos: int) -> int:
+        """Hand one slot's tokens of this chunk to its stream; frees the
+        slot when the stream is done. Returns 1 if it finished."""
+        take = len(toks)
+        s.logprobs.extend(lps)
+        s.token_times.extend([t_now] * take)
+        s.tokens.extend(toks)
+        # per-token TBT: this stream's inter-chunk gap amortized
+        # over the chunk's tokens (tokens inside one chunk land
+        # together — the gap IS the per-token pacing a client sees)
+        if take > 0 and len(s.token_times) > take:
+            prev = s.token_times[-take - 1]
+            if t_now > prev:
+                self._tbt_obs((t_now - prev) / take, s.tenant)
+        if len(s.tokens) < s.max_new and pos < self.max_len - 1:
+            return 0
+        s.done = True
+        self.finished[s.sid] = s
+        self.slot_stream[slot] = None  # slot freed THIS chunk
+        if len(s.token_times) > 1:
+            _fr.record("serve", "serve.decode", s.token_times[0],
+                       s.token_times[-1], trace=s.trace,
+                       attrs={"sid": s.sid, "tokens": len(s.tokens)})
+        return 1
+
+    def _record_first_token(self, s: _Stream, t_now: float) -> None:
+        """``serve.first_token``, once per stream, at the moment its
+        first token is stamped on the host: submit -> first token under
+        the submitter's trace, split in the attrs into engine queue wait
+        and prefill-to-token, with the way to ``submit`` from the
+        request's birth stamps where it carried any. Also an instant
+        event on the profiler's host line."""
+        attrs = {
+            "sid": s.sid, "engine": self.name,
+            "queue_wait_ms": round(
+                1e3 * (s.admitted_at - s.submitted), 3),
+            "prefill_to_token_ms": round(1e3 * (t_now - s.admitted_at), 3),
+            "bucket": s.bucket, "prompt_len": len(s.prompt),
+            **_upstream_ms(s.stamps, _fr.wall(s.submitted)),
+        }
+        _fr.record("serve", "serve.first_token", s.submitted, t_now,
+                   attrs=attrs, trace=s.trace, annotate=True)
 
     MAX_SPEC_DEPTH = 8  # each distinct depth compiles its own kernel
 
@@ -1005,61 +1150,58 @@ class RaggedDecoder:
         dispatch, emitting 1..depth+1 tokens per slot per round. Same
         single device→host sync as the plain pump; per-slot sequences
         are assembled host-side from the per-round accept counts."""
-        t0 = time.perf_counter()
-        toks, lps, counts, self.cache, self.cur_tok = decode_chunk_spec(
-            self.params, self.spec_draft_head, self.cache,
-            self.cur_tok, active_mask, jnp.asarray(self._slot_seed),
-            jnp.asarray(self._slot_temp), jnp.asarray(self._slot_topp),
-            self.cfg, self.chunk, depth, self.spec_draft_layers)
-        if self.chunk_delay_s:
-            time.sleep(self.chunk_delay_s)  # emulated dispatch latency
-        firsts, self._pending_first = self._pending_first, []
-        toks, lps, counts, pos_np, first_toks, first_lps = \
-            jax.device_get(
-                (toks, lps, counts, self.cache["pos"],
-                 [t for _, t, _ in firsts],
-                 [lp for _, _, lp in firsts]))
-        if not self._sampling_seen:
-            # greedy-only engine: match the plain kernel's logprob
-            # surface (placeholder 0.0) so spec on/off is
-            # indistinguishable to consumers
-            lps = np.zeros_like(lps)
-        t_now = time.perf_counter()
-        delivered = 0
-        for (s, _, _), tk0, lp0 in zip(firsts, first_toks, first_lps):
-            s.logprobs.append(float(lp0))
-            s.tokens.append(int(tk0))
-            s.token_times.append(t_now)
-            delivered += 1
-        proposed = accepted = 0
-        for slot, s in enumerate(self.slot_stream):
-            if s is None:
-                continue
-            seq_t: list = []
-            seq_lp: list = []
-            for r in range(counts.shape[1]):
-                m = int(counts[slot, r])
-                if m <= 0:
-                    continue
-                seq_t.extend(int(x) for x in toks[slot, r, :m])
-                seq_lp.extend(float(x) for x in lps[slot, r, :m])
-                proposed += depth
-                accepted += m - 1
-                self._spec_hist[m - 1] += 1
-            take = min(len(seq_t), s.max_new - len(s.tokens))
-            s.logprobs.extend(seq_lp[:take])
-            s.tokens.extend(seq_t[:take])
-            s.token_times.extend([t_now] * take)
-            delivered += take
-            if take > 0 and len(s.token_times) > take:
-                prev = s.token_times[-take - 1]
-                if t_now > prev:
-                    self._tbt_obs((t_now - prev) / take, s.tenant)
-            if len(s.tokens) >= s.max_new \
-                    or int(pos_np[slot]) >= self.max_len - 1:
-                s.done = True
-                self.finished[s.sid] = s
-                self.slot_stream[slot] = None
+        n_active = int(active_mask.sum())
+        with _fr.span("serve", "serve.spec_verify", flush=False, attrs={
+                "engine": self.name, "depth": depth,
+                "rounds": self.chunk}) as sv:
+            with _fr.span("serve", "engine.decode_dispatch", flush=False,
+                          attrs={"active": n_active, "chunk": self.chunk,
+                                 "depth": depth}):
+                toks, lps, counts, self.cache, self.cur_tok = \
+                    decode_chunk_spec(
+                        self.params, self.spec_draft_head, self.cache,
+                        self.cur_tok, active_mask,
+                        jnp.asarray(self._slot_seed),
+                        jnp.asarray(self._slot_temp),
+                        jnp.asarray(self._slot_topp), self.cfg,
+                        self.chunk, depth, self.spec_draft_layers)
+            firsts = self._take_pending_first()
+            toks, lps, counts, pos_np, first_toks, first_lps = \
+                self._readback((toks, lps, counts, self.cache["pos"]),
+                               firsts)
+            if not self._sampling_seen:
+                # greedy-only engine: match the plain kernel's logprob
+                # surface (placeholder 0.0) so spec on/off is
+                # indistinguishable to consumers
+                lps = np.zeros_like(lps)
+            t_now = time.monotonic()
+            proposed = accepted = 0
+            with _fr.span("serve", "engine.deliver", flush=False) as sp:
+                delivered = self._deliver_firsts(
+                    firsts, first_toks, first_lps, t_now)
+                finished = 0
+                for slot, s in enumerate(self.slot_stream):
+                    if s is None:
+                        continue
+                    seq_t: list = []
+                    seq_lp: list = []
+                    for r in range(counts.shape[1]):
+                        m = int(counts[slot, r])
+                        if m <= 0:
+                            continue
+                        seq_t.extend(int(x) for x in toks[slot, r, :m])
+                        seq_lp.extend(float(x) for x in lps[slot, r, :m])
+                        proposed += depth
+                        accepted += m - 1
+                        self._spec_hist[m - 1] += 1
+                    take = min(len(seq_t), s.max_new - len(s.tokens))
+                    finished += self._deliver(
+                        slot, s, seq_t[:take], seq_lp[:take], t_now,
+                        int(pos_np[slot]))
+                    delivered += take
+                sp.update(delivered=delivered, firsts=len(firsts),
+                          finished=finished)
+            sv.update(proposed=proposed, accepted=accepted)
         self._spec_proposed += proposed
         self._spec_accepted += accepted
         self._spec_pumps += 1
@@ -1071,19 +1213,8 @@ class RaggedDecoder:
                 m["spec_accepted"].inc(accepted, tags)
             except Exception:  # noqa: BLE001 — telemetry never breaks
                 pass
-        try:
-            from ray_tpu._private import flight_recorder as _fr
-            off = time.monotonic() - time.perf_counter()
-            _fr.record(
-                "serve", "serve.spec_verify", t0 + off, t_now + off,
-                attrs={"engine": self.name, "depth": depth,
-                       "rounds": self.chunk, "proposed": proposed,
-                       "accepted": accepted},
-                flush=False)  # per-pump hot path: ring-only
-        except Exception:  # noqa: BLE001
-            pass
         self._account(t_now, delivered)
-        return int(active_mask.sum())
+        return n_active
 
     def set_params(self, params, version: int) -> None:
         """Adopt published weights at a chunk boundary (call ONLY from
@@ -1128,22 +1259,23 @@ class RaggedDecoder:
 
     def stats(self) -> dict:
         """Scaling signals for the serving pool (serve/llm_pool.py):
-        per-slot occupancy, queue depth, and recent tokens/s — also
+        occupied slots, queue depth, and recent tokens/s — also
         exported as Prometheus gauges (util/metrics.py) alongside the
-        collective OpStats family."""
-        occupancy = [st.sid if st is not None else None
-                     for st in self.slot_stream]
+        collective OpStats family — and monotonic totals an outside
+        reader takes deltas of (``total_tokens``, ``pumps``, the
+        ``prefill_*`` counts of the static-width prefill program)."""
         active = sum(1 for st in self.slot_stream if st is not None)
         out = {
             "slots": self.slots,
             "active": active,
-            "occupancy": occupancy,
-            "utilization": active / self.slots if self.slots else 0.0,
             "queued": len(self.queue),
             "tokens_per_sec": round(self.tokens_per_sec(), 1),
             "total_tokens": self._total_tokens,
             "weights_version": self.weights_version,
             "pumps": self.pumps,
+            "prefill_calls": self.prefill_calls,
+            "prefill_prompts": self.prefill_prompts,
+            "prefill_rows": self.prefill_rows,
         }
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()

@@ -209,11 +209,15 @@ def _qkv(cfg: LlamaConfig, p, h, sin, cos):
     b, t, _ = h.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-    x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-    q = (x @ p["wq"].astype(cdt)).reshape(b, t, hq, hd)
-    k = (x @ p["wk"].astype(cdt)).reshape(b, t, hkv, hd)
-    v = (x @ p["wv"].astype(cdt)).reshape(b, t, hkv, hd)
-    return apply_rotary(q, sin, cos), apply_rotary(k, sin, cos), v
+    # named scopes (attn / mlp / embed / lm_head here, cache and
+    # optimizer at their sites) are metadata: a device trace's
+    # operations carry them, the compiled program does not change
+    with jax.named_scope("attn"):
+        x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
+        q = (x @ p["wq"].astype(cdt)).reshape(b, t, hq, hd)
+        k = (x @ p["wk"].astype(cdt)).reshape(b, t, hkv, hd)
+        v = (x @ p["wv"].astype(cdt)).reshape(b, t, hkv, hd)
+        return apply_rotary(q, sin, cos), apply_rotary(k, sin, cos), v
 
 
 def moe_gates(cfg: LlamaConfig, router, x):
@@ -320,21 +324,23 @@ def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o):
     b, t, _ = h.shape
     hq, hd = cfg.n_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-    h = h + shard_constraint(
-        o.reshape(b, t, hq * hd) @ p["wo"].astype(cdt),
-        ("batch", "seq", "embed"),
-    )
-    x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-    if cfg.n_experts > 0:
-        return h + _moe_mlp(cfg, p, x)
-    from jax.ad_checkpoint import checkpoint_name
+    with jax.named_scope("attn"):
+        h = h + shard_constraint(
+            o.reshape(b, t, hq * hd) @ p["wo"].astype(cdt),
+            ("batch", "seq", "embed"),
+        )
+    with jax.named_scope("mlp"):
+        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+        if cfg.n_experts > 0:
+            return h + _moe_mlp(cfg, p, x)
+        from jax.ad_checkpoint import checkpoint_name
 
-    # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
-    # activations so the backward skips the gate/up matmul recomputes
-    gate = checkpoint_name(x @ p["w_gate"].astype(cdt), "mlp_gate")
-    up = checkpoint_name(x @ p["w_up"].astype(cdt), "mlp_up")
-    y = (jax.nn.silu(gate) * up) @ p["w_down"].astype(cdt)
-    return h + shard_constraint(y, ("batch", "seq", "embed"))
+        # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
+        # activations so the backward skips the gate/up matmul recomputes
+        gate = checkpoint_name(x @ p["w_gate"].astype(cdt), "mlp_gate")
+        up = checkpoint_name(x @ p["w_up"].astype(cdt), "mlp_up")
+        y = (jax.nn.silu(gate) * up) @ p["w_down"].astype(cdt)
+        return h + shard_constraint(y, ("batch", "seq", "embed"))
 
 
 def _layer(cfg: LlamaConfig, h, layer_params, sin, cos):
@@ -350,7 +356,8 @@ def _layer(cfg: LlamaConfig, h, layer_params, sin, cos):
     q = checkpoint_name(q, "qkv_q")
     k = checkpoint_name(k, "qkv_k")
     v = checkpoint_name(v, "qkv_v")
-    o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
+    with jax.named_scope("attn"):
+        o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
     return _attn_out_and_mlp(cfg, p, h, o)
 
 
@@ -371,11 +378,12 @@ def forward(params, tokens, cfg: LlamaConfig, *, positions=None):
     # resharding the gather output; one explicit all-gather of the table
     # (V x D in compute dtype, the fsdp weights-gather pattern) makes the
     # gather local and its scatter-add transpose a clean reduce-scatter.
-    w_embed = shard_constraint(
-        params["embed"].astype(cdt), (None, None)
-    )
-    h = w_embed[tokens]
-    h = shard_constraint(h, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        w_embed = shard_constraint(
+            params["embed"].astype(cdt), (None, None)
+        )
+        h = w_embed[tokens]
+        h = shard_constraint(h, ("batch", "seq", "embed"))
 
     layer_fn = lambda h_, p_: (_layer(cfg, h_, p_, sin, cos), None)
     if cfg.remat:
@@ -451,15 +459,17 @@ def forward(params, tokens, cfg: LlamaConfig, *, positions=None):
     else:
         h, _ = jax.lax.scan(layer_fn, h, params["layers"])
 
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    w_out = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cdt)
-    # logits stay in COMPUTE dtype: materializing an f32 copy of
-    # [B, T, V] costs ~2 GB of extra HBM traffic per step at the bench
-    # shape; the loss upcasts to f32 inside its fused reductions instead
-    logits = h @ w_out
-    return shard_constraint(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+        w_out = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cdt)
+        # logits stay in COMPUTE dtype: materializing an f32 copy of
+        # [B, T, V] costs ~2 GB of extra HBM traffic per step at the
+        # bench shape; the loss upcasts to f32 inside its fused
+        # reductions instead
+        logits = h @ w_out
+        return shard_constraint(logits, ("batch", "seq", "vocab"))
 
 
 def loss_fn(params, batch, cfg: LlamaConfig):
@@ -472,7 +482,8 @@ def loss_fn(params, batch, cfg: LlamaConfig):
         inputs, targets = toks[:, :-1], toks[:, 1:]
         mask = None
     logits = forward(params, inputs, cfg)
-    loss, n = softmax_cross_entropy(logits, targets, mask=mask)
+    with jax.named_scope("lm_head"):
+        loss, n = softmax_cross_entropy(logits, targets, mask=mask)
     return loss, {"loss": loss, "tokens": n}
 
 

@@ -308,6 +308,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
             vmem_limit_bytes=_vmem_limit(),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse4[..., 0]  # lse: [B, H, T] f32
 
@@ -613,6 +614,7 @@ def _flash_bwd_fused(q, k, v, o, lse, do, *, causal, block_q, block_k,
             vmem_limit_bytes=_vmem_limit(),
         ),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q, k, v, do, lse2_r, delta_r)
 
     dq = jnp.sum(dqp.astype(jnp.float32), axis=0).astype(q.dtype)
@@ -700,6 +702,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, block_q, block_k, interpret):
             vmem_limit_bytes=_vmem_limit(),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse_r, delta_r)
 
     block_q, block_k = bq_kv, bk_kv
@@ -736,6 +739,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, block_q, block_k, interpret):
             vmem_limit_bytes=_vmem_limit(),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse_r, delta_r)
 
     if group > 1:

@@ -27,11 +27,21 @@ from __future__ import annotations
 import json
 import logging
 import threading
+import time
 from urllib.parse import parse_qs, urlsplit
 
 import ray_tpu
+from ray_tpu._private import flight_recorder as _fr
 
 logger = logging.getLogger(__name__)
+
+
+def _stamp_recv(req: dict) -> None:
+    """Birth stamp of an LLM request: when the proxy had parsed its
+    body (epoch seconds on the recorder's clock). Rides the request to
+    the pool and the replica, whose first-token span reads it; whatever
+    a client sent under the key is replaced."""
+    req["stamps"] = {"proxy_recv": _fr.wall(time.monotonic())}
 
 
 def _match_route(routes: dict[str, str], path: str) -> str | None:
@@ -155,6 +165,7 @@ class _ProxyServer:
         to the normal dispatch path."""
         import asyncio
 
+        _stamp_recv(req)
         loop = asyncio.get_running_loop()
         parts = urlsplit(target)
         route = _match_route(self.routes, parts.path)
@@ -235,6 +246,10 @@ class _ProxyServer:
                 arg = json.loads(body)
             except json.JSONDecodeError:
                 arg = body.decode(errors="replace")
+            # an LLM request by its shape; any other deployment gets
+            # its body as it was sent
+            if isinstance(arg, dict) and "prompt_ids" in arg:
+                _stamp_recv(arg)
         else:
             arg = {
                 k: v[0] if len(v) == 1 else v

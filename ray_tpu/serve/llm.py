@@ -21,8 +21,11 @@ deterministic drain used on replica downscale.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
+
+from ray_tpu._private import flight_recorder as _fr
 
 
 def build_model(model_size: str = "tiny", *, max_len: int = 512,
@@ -98,6 +101,14 @@ def build_spec_draft(cfg, *, draft_layers: int = 0,
     return n, head
 
 
+def _pump_span(working: bool, name: str, **attrs):
+    """A ring-only span of the pump thread, for a WORKING iteration
+    only: an idle replica spins every 5 ms and would flush the ring."""
+    if not working:
+        return contextlib.nullcontext()
+    return _fr.span("serve", name, attrs=attrs, flush=False)
+
+
 class LLMServer:
     """Deployable class (wrap with @serve.deployment or Deployment(...)).
 
@@ -156,7 +167,6 @@ class LLMServer:
         # sids being consumed via poll_stream: the pump must NOT purge
         # their finished entries (no _done_events waiter is registered)
         self._stream_sids: dict[int, float] = {}  # sid -> last poll
-        self._stream_ft: set[int] = set()  # sids with first-token span
         # poll RPCs served (single + batched): the batching test's
         # falsifiability counter — N streams should NOT mean N RPCs/tick
         self._poll_rpcs = 0
@@ -173,53 +183,68 @@ class LLMServer:
         # dict (written here BEFORE the event is set, read by the
         # handler only AFTER it) — the pump never holds a lock across
         # device work, so submissions land during the chunk wait
+        eng = self.engine
+        while not self._stop:
+            active = sum(st is not None for st in eng.slot_stream)
+            queued = len(eng.queue)
+            working = bool(active or queued)
+            # mono_ns: the one pair that maps the profiler's time to
+            # time.monotonic, which the processes of a machine share
+            with _pump_span(working, "serve.pump",
+                            mono_ns=time.monotonic_ns(), active=active,
+                            queued=queued):
+                busy = self._pump_once(working)
+            if not busy:
+                time.sleep(0.005)  # idle: don't spin the device
+
+    def _pump_once(self, working: bool) -> int:
         import logging
 
         from ray_tpu._private import fault_injection as _fi
 
-        while not self._stop:
-            try:
-                # chaos site: replica death / stall mid-decode (ctx
-                # carries the engine name so a plan can pin ONE replica)
-                _fi.fire("serve.replica_pump", engine=self.engine.name)
-                pending = None
-                with self._lock:
-                    pending, self._pending_weights = (
-                        self._pending_weights, None)
-                if pending is not None:
-                    import jax.numpy as jnp
-
-                    import jax as _jax
-
-                    tree, version = pending
-                    self.engine.set_params(
-                        _jax.tree_util.tree_map(jnp.asarray, tree),
-                        version)
-                busy = self.engine.pump()
-            except Exception:  # noqa: BLE001 — the pump must survive:
-                # a dead pump thread bricks the replica for every
-                # in-flight and future request (submit-time validation
-                # rejects bad requests; this is the backstop)
-                logging.getLogger(__name__).exception("decode pump error")
-                busy = 0
-            now = time.monotonic()
+        try:
+            # chaos site: replica death / stall mid-decode (ctx
+            # carries the engine name so a plan can pin ONE replica)
+            _fi.fire("serve.replica_pump", engine=self.engine.name)
+            pending = None
             with self._lock:
-                for sid, ev in list(self._done_events.items()):
-                    if sid in self.engine.finished:
-                        ev.set()
-                for sid in list(self.engine.finished):
-                    if sid not in self._done_events \
-                            and sid not in self._stream_sids:
-                        # abandoned (handler timed out): don't pin the
-                        # stream's tokens forever
-                        self.engine.purge(sid)
-                for sid, last in list(self._stream_sids.items()):
-                    if now - last > self.STREAM_IDLE_PURGE_S:
-                        # streaming client went away mid-stream
-                        self._stream_sids.pop(sid, None)
-                        self.engine.purge(sid)
-            if not busy:
-                time.sleep(0.005)  # idle: don't spin the device
+                pending, self._pending_weights = (
+                    self._pending_weights, None)
+            if pending is not None:
+                import jax.numpy as jnp
+
+                import jax as _jax
+
+                tree, version = pending
+                self.engine.set_params(
+                    _jax.tree_util.tree_map(jnp.asarray, tree),
+                    version)
+            busy = self.engine.pump()
+        except Exception:  # noqa: BLE001 — the pump must survive:
+            # a dead pump thread bricks the replica for every
+            # in-flight and future request (submit-time validation
+            # rejects bad requests; this is the backstop)
+            logging.getLogger(__name__).exception("decode pump error")
+            busy = 0
+        now = time.monotonic()
+        with _pump_span(working, "serve.pump_bookkeeping",
+                        waiters=len(self._done_events),
+                        streams=len(self._stream_sids)), self._lock:
+            for sid, ev in list(self._done_events.items()):
+                if sid in self.engine.finished:
+                    ev.set()
+            for sid in list(self.engine.finished):
+                if sid not in self._done_events \
+                        and sid not in self._stream_sids:
+                    # abandoned (handler timed out): don't pin the
+                    # stream's tokens forever
+                    self.engine.purge(sid)
+            for sid, last in list(self._stream_sids.items()):
+                if now - last > self.STREAM_IDLE_PURGE_S:
+                    # streaming client went away mid-stream
+                    self._stream_sids.pop(sid, None)
+                    self.engine.purge(sid)
+        return busy
 
     # -- blocking API --
 
@@ -249,28 +274,6 @@ class LLMServer:
             # registered waiter (abandoned by a timed-out handler)
             with self._lock:
                 self._done_events.pop(sid, None)
-        try:
-            from ray_tpu._private import flight_recorder as _fr
-
-            stamps = s.token_times
-            if stamps:
-                # engine stamps are perf_counter; rebase onto monotonic
-                # via one paired read so the span clock stays coherent
-                off = time.monotonic() - time.perf_counter()
-                _fr.record("serve", "serve.first_token",
-                           s.submitted + off, stamps[0] + off,
-                           attrs={"sid": sid,
-                                  "engine": self.engine.name})
-                if len(stamps) > 1:
-                    _fr.record(
-                        "serve", "serve.decode", stamps[0] + off,
-                        stamps[-1] + off,
-                        attrs={"sid": sid, "tokens": len(stamps),
-                               "tbt_mean_s": round(
-                                   (stamps[-1] - stamps[0])
-                                   / (len(stamps) - 1), 6)})
-        except Exception:  # noqa: BLE001 — observability best-effort
-            pass
         return {
             "tokens": s.tokens[:max_tokens],
             "submitted_s": s.submitted,
@@ -281,20 +284,23 @@ class LLMServer:
 
     def generate(self, prompt_ids: list, max_tokens: int = 64, *,
                  temperature: float = 0.0, top_p: float = 1.0,
-                 seed: int = 0, tenant: str = "-") -> dict:
+                 seed: int = 0, tenant: str = "-",
+                 stamps: dict | None = None) -> dict:
         """Blocking single-request API (one handler thread per call;
-        all calls share the slot batch)."""
+        all calls share the slot batch). ``stamps``: the request's birth
+        stamps (proxy, pool), for the first-token span."""
         sid, ev = self._submit_locked(
             lambda: self.engine.submit(
                 list(prompt_ids), int(max_tokens),
                 temperature=temperature, top_p=top_p, seed=seed,
-                tenant=tenant))
+                tenant=tenant, stamps=stamps))
         return self._wait_result(sid, ev, int(max_tokens))
 
     def adopt_prefilled(self, kv: dict, prompt_ids: list,
                         max_tokens: int = 64, *,
                         temperature: float = 0.0, top_p: float = 1.0,
-                        seed: int = 0, tenant: str = "-") -> dict:
+                        seed: int = 0, tenant: str = "-",
+                        stamps: dict | None = None) -> dict:
         """Blocking generate for a stream prefilled ELSEWHERE: `kv` is
         the prefill worker's payload (decode_engine.prefill_kv rows +
         first token), typically passed as an ObjectRef so the KV rows
@@ -306,7 +312,7 @@ class LLMServer:
             lambda: self.engine.submit_prefilled(
                 list(prompt_ids), int(max_tokens), kv,
                 temperature=temperature, top_p=top_p, seed=seed,
-                tenant=tenant))
+                tenant=tenant, stamps=stamps))
         self._record_kv_handoff(kv, t0, tenant=tenant)
         return self._wait_result(sid, ev, int(max_tokens))
 
@@ -321,7 +327,6 @@ class LLMServer:
         the handoff proceeds (the bytes already arrived; the claim paces
         the link, it does not gate correctness)."""
         try:
-            from ray_tpu._private import flight_recorder as _fr
             from ray_tpu._private import net_accounting as _net
             from ray_tpu._private import net_qos as _qos
 
@@ -356,6 +361,7 @@ class LLMServer:
         max_tokens = int(req.get("max_tokens", 64))
         sampling = self._sampling(req)
         tenant = str(req.get("tenant", "-"))
+        stamps = req.get("stamps")
         t0 = time.monotonic()
         with self._lock:
             if self._draining:
@@ -363,10 +369,11 @@ class LLMServer:
             if req.get("kv") is not None:
                 sid = self.engine.submit_prefilled(
                     prompt_ids, max_tokens, req["kv"], tenant=tenant,
-                    **sampling)
+                    stamps=stamps, **sampling)
             else:
                 sid = self.engine.submit(prompt_ids, max_tokens,
-                                         tenant=tenant, **sampling)
+                                         tenant=tenant, stamps=stamps,
+                                         **sampling)
             self._stream_sids[sid] = time.monotonic()
         if req.get("kv") is not None:
             self._record_kv_handoff(req["kv"], t0, tenant=tenant)
@@ -377,7 +384,8 @@ class LLMServer:
                                 temperature: float = 0.0,
                                 top_p: float = 1.0,
                                 seed: int = 0,
-                                tenant: str = "-") -> dict:
+                                tenant: str = "-",
+                                stamps: dict | None = None) -> dict:
         """submit_stream for an externally-prefilled stream. `kv` is a
         dedicated TOP-LEVEL argument (not nested in a request dict) so
         an ObjectRef passed here is resolved by the executor's arg
@@ -390,7 +398,7 @@ class LLMServer:
             sid = self.engine.submit_prefilled(
                 list(prompt_ids), int(max_tokens), kv,
                 temperature=temperature, top_p=top_p, seed=seed,
-                tenant=tenant)
+                tenant=tenant, stamps=stamps)
             self._stream_sids[sid] = time.monotonic()
         self._record_kv_handoff(kv, t0, tenant=tenant)
         return {"sid": sid}
@@ -417,50 +425,26 @@ class LLMServer:
             if sid not in self._stream_sids:
                 return {"tokens": [], "logprobs": [], "done": True,
                         "version": None}
-            self._stream_sids[sid] = time.monotonic()
+            now = self._stream_sids[sid] = time.monotonic()
             # read BEFORE take_tokens: the final (fully-drained) take
             # purges the stream and with it the version record
             version = self.engine.stream_version(sid)
             s = self.engine._by_sid.get(sid)
+            taken = s.taken if s is not None else 0
             new, lps, done = self.engine.take_tokens(
                 sid, with_logprobs=True)
             if done:
                 self._stream_sids.pop(sid, None)
-        self._record_stream_spans(sid, s, bool(new), done)
+        if new:
+            # how long the oldest token taken lay in the replica before
+            # this poll fetched it (ring-only: one per poll that found
+            # tokens)
+            _fr.mark("serve", "serve.poll_pickup", flush=False, attrs={
+                "sid": sid, "tokens": len(new), "first": taken == 0,
+                "pickup_ms": round(
+                    1e3 * (now - s.token_times[taken]), 3)})
         return {"tokens": new, "logprobs": lps, "done": done,
                 "version": version}
-
-    def _record_stream_spans(self, sid: int, s, fresh: bool,
-                             done: bool) -> None:
-        """Streaming twin of _wait_result's span pair: first_token on
-        the first poll that surfaces tokens, decode when the stream
-        finishes. Runs under the poller's trace scope (the pool
-        re-enters the stream's trace on every poll)."""
-        try:
-            from ray_tpu._private import flight_recorder as _fr
-
-            stamps = s.token_times if s is not None else []
-            if not stamps:
-                return
-            off = time.monotonic() - time.perf_counter()
-            if fresh and sid not in self._stream_ft:
-                self._stream_ft.add(sid)
-                _fr.record("serve", "serve.first_token",
-                           s.submitted + off, stamps[0] + off,
-                           attrs={"sid": sid,
-                                  "engine": self.engine.name})
-            if done:
-                self._stream_ft.discard(sid)
-                if len(stamps) > 1:
-                    _fr.record(
-                        "serve", "serve.decode", stamps[0] + off,
-                        stamps[-1] + off,
-                        attrs={"sid": sid, "tokens": len(stamps),
-                               "tbt_mean_s": round(
-                                   (stamps[-1] - stamps[0])
-                                   / (len(stamps) - 1), 6)})
-        except Exception:  # noqa: BLE001 — observability best-effort
-            pass
 
     # -- weight publishing (actor-learner loop) --
 
@@ -504,11 +488,28 @@ class LLMServer:
         return self.generate(list(req["prompt_ids"]),
                              int(req.get("max_tokens", 64)))
 
+    # -- profiler capture of THIS replica (only the process that holds
+    # the chip can trace it) --
+
+    def start_trace(self, log_dir: str) -> bool:
+        """Start a ``jax.profiler`` capture into ``log_dir``: device
+        operations and this process's flight-recorder spans (pump,
+        prefill, read-back, first tokens) in one file, on one clock."""
+        import jax
+
+        jax.profiler.start_trace(log_dir)
+        return True
+
+    def stop_trace(self) -> bool:
+        import jax
+
+        jax.profiler.stop_trace()
+        return True
+
     def stats(self) -> dict:
         with self._lock:
             st = self.engine.stats()
             st["draining"] = self._draining
-            st["waiters"] = len(self._done_events)
             st["stream_polls"] = self._poll_rpcs
         from ray_tpu._private import accelerator
 

@@ -196,6 +196,17 @@ class _Replica:
 _pool_metrics = None
 
 
+def _born(stamps) -> dict:
+    """A request's birth stamps on entering the pool: what it came with
+    (the proxy's ``proxy_recv``) plus ``pool_enqueue``, epoch seconds on
+    the recorder's clock. The pool adds ``pool_admitted`` when
+    ``_acquire`` returns and sends the dict on to the replica, whose
+    first-token span reads it (decode_engine._upstream_ms)."""
+    out = dict(stamps) if isinstance(stamps, dict) else {}
+    out["pool_enqueue"] = _fr.wall(time.monotonic())
+    return out
+
+
 def _get_pool_metrics():
     global _pool_metrics
     if _pool_metrics is None:
@@ -750,12 +761,15 @@ class LLMPool:
                  temperature: float = 0.0, top_p: float = 1.0,
                  seed: int | None = None, tenant: str = "-",
                  model_id: str | None = None,
-                 deadline_s: float | None = None) -> dict:
+                 deadline_s: float | None = None,
+                 stamps: dict | None = None) -> dict:
         """Blocking generate with transparent replica failover. The
         whole request runs under ONE trace id (joined from the ambient
         context when deployed as an actor, rooted fresh for direct
         use), so the prefill worker's and decode replica's spans
-        decompose this request's TTFT in the timeline.
+        decompose this request's TTFT in the timeline. ``stamps``: the
+        birth stamps the request came with (the proxy's); the pool adds
+        its own and the replica's first-token span reads them.
 
         ``deadline_s`` is the client's TTFT budget from submission: a
         request whose predicted queue wait already exceeds it fast-
@@ -766,13 +780,15 @@ class LLMPool:
             return self._generate_traced(
                 prompt_ids, max_tokens, temperature=temperature,
                 top_p=top_p, seed=seed, tenant=tenant,
-                model_id=model_id, deadline_s=deadline_s)
+                model_id=model_id, deadline_s=deadline_s, stamps=stamps)
 
     def _generate_traced(self, prompt_ids: list, max_tokens: int = 64, *,
                          temperature: float = 0.0, top_p: float = 1.0,
                          seed: int | None = None, tenant: str = "-",
                          model_id: str | None = None,
-                         deadline_s: float | None = None) -> dict:
+                         deadline_s: float | None = None,
+                         stamps: dict | None = None) -> dict:
+        stamps = _born(stamps)
         self._ensure_model(model_id)
         prompt_ids = list(prompt_ids)
         max_tokens = int(max_tokens)
@@ -789,6 +805,7 @@ class LLMPool:
             rep = self._acquire(tenant, deadline_abs,
                                 first=(attempt == 0))
             t_admitted = time.monotonic()
+            stamps["pool_admitted"] = _fr.wall(t_admitted)
             queue_wait = t_admitted - t_enqueue
             _fr.record("serve", "serve.admission_wait", t_enqueue,
                        t_admitted, attrs={"replica": rep.name,
@@ -799,11 +816,11 @@ class LLMPool:
                     ref = rep.handle.adopt_prefilled.options(
                         fetch_tags=_KV_TAGS).remote(
                         kv_ref, prompt_ids, max_tokens, tenant=tenant,
-                        **sampling)
+                        stamps=stamps, **sampling)
                 else:
                     ref = rep.handle.generate.remote(
                         prompt_ids, max_tokens, tenant=tenant,
-                        **sampling)
+                        stamps=stamps, **sampling)
                 out = ray_tpu.get(ref, timeout=600)
                 self._record_ttft(out, queue_wait, tenant)
                 self._note_tokens(len(out.get("tokens", [])))
@@ -841,7 +858,8 @@ class LLMPool:
             seed=req.get("seed"),
             tenant=str(req.get("tenant", "-")),
             model_id=req.get("model_id"),
-            deadline_s=float(dl) if dl is not None else None)
+            deadline_s=float(dl) if dl is not None else None,
+            stamps=req.get("stamps"))
 
     # ---------- streaming ----------
 
@@ -859,6 +877,7 @@ class LLMPool:
                 self._release(rep)
 
     def submit_stream(self, req: dict) -> dict:
+        stamps = _born(req.get("stamps"))
         self._sweep_streams()
         self._ensure_model(req.get("model_id"))
         prompt_ids = list(req["prompt_ids"])
@@ -883,7 +902,7 @@ class LLMPool:
                "emitted": 0, "rep": None, "sid": None, "done": False,
                "last_poll": time.monotonic(), "sampling": sampling,
                "version": self._weights_version, "trace": tr,
-               "tenant": tenant,
+               "tenant": tenant, "stamps": stamps,
                "deadline_abs": (time.monotonic() + float(dl)
                                 if dl is not None else None)}
         with _trace.scope(*tr):
@@ -912,14 +931,18 @@ class LLMPool:
         rep = self._acquire(tenant, rec.get("deadline_abs"),
                             first=not rec.get("was_assigned"))
         rec["was_assigned"] = True
+        t_admitted = time.monotonic()
+        # a failover re-assignment keeps the stream's first pool_enqueue
+        stamps = rec["stamps"]
+        stamps["pool_admitted"] = _fr.wall(t_admitted)
         _fr.record("serve", "serve.admission_wait", t_enqueue,
-                   time.monotonic(), attrs={"replica": rep.name,
-                                            "tenant": tenant,
-                                            "queued": self._waiting})
+                   t_admitted, attrs={"replica": rep.name,
+                                      "tenant": tenant,
+                                      "queued": self._waiting})
         try:
             body = {"prompt_ids": rec["prompt_ids"],
                     "max_tokens": rec["max_tokens"], "tenant": tenant,
-                    **rec["sampling"]}
+                    "stamps": stamps, **rec["sampling"]}
             sid = None
             if rec["kv_ref"] is not None and rec["emitted"] == 0:
                 # adopt path only for a fresh stream (KV as a TOP-LEVEL
@@ -931,7 +954,7 @@ class LLMPool:
                             fetch_tags=_KV_TAGS).remote(
                             rec["kv_ref"], rec["prompt_ids"],
                             rec["max_tokens"], tenant=tenant,
-                            **rec["sampling"]),
+                            stamps=stamps, **rec["sampling"]),
                         timeout=600)["sid"]
                 except ray_tpu.RayActorError:
                     if self._replica_alive(rep):
@@ -1384,10 +1407,29 @@ class LLMPool:
             "registered_models": sorted(self._model_store),
             "resident_models": list(self._resident_ref._cache),
             "per_replica": per_replica,
-            "tokens_per_s_window": round(self.tokens_per_s(), 1),
             "overload": (self._guardian.state()
                          if self._guardian is not None else None),
         }
+
+    def trace_replicas(self, log_dir: str, seconds: float) -> list:
+        """Capture every live decode replica with ``jax.profiler`` for
+        ``seconds`` of whatever traffic is running: device operations
+        and the replica's flight-recorder spans (pump, prefill,
+        read-back, first tokens) in one file each, on one clock.
+        Returns the directories written, ``<log_dir>/<replica>`` (on the
+        replica's node); open them in XProf or Perfetto."""
+        import os
+
+        reps = self._alive()
+        dirs = [os.path.join(log_dir, r.name) for r in reps]
+        ray_tpu.get([r.handle.start_trace.remote(d)
+                     for r, d in zip(reps, dirs)], timeout=120)
+        try:
+            time.sleep(max(0.0, float(seconds)))
+        finally:
+            ray_tpu.get([r.handle.stop_trace.remote() for r in reps],
+                        timeout=600)
+        return dirs
 
     def health(self) -> bool:
         return not self._stop

@@ -168,8 +168,10 @@ def make_train_step(
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         if compute_grad_norm:
             metrics = dict(metrics, grad_norm=optax.global_norm(grads))
         return TrainState(state.step + 1, params, opt_state), metrics
