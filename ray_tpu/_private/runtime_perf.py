@@ -392,8 +392,8 @@ def run_serve_benchmarks(*, quick: bool = False) -> list[dict]:
         head = ([int(x) for x in np.random.RandomState(7)
                  .randint(1, 250, 8)] if prefix else None)
         try:
-            # warm EVERY replica through BOTH prefill paths (cold
-            # batched prefill, then the prefix-cache suffix path) so
+            # warm EVERY replica through BOTH prefill paths (the cold
+            # prefill, then the prefix-cache suffix path) so
             # jit compiles stay out of the timed window
             warm = prompt_for(0, head)
             ray_tpu.get([r.handle.generate.remote(warm, 8)

@@ -428,12 +428,12 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
 def _prefill_batch_into_slots(params, prompts, true_lens, slots,
                               seeds, temps, top_ps,
                               cache, cur_tok, cfg: LlamaConfig):
-    """Prefill a BATCH of streams ([F, P] RIGHT-padded tokens, one
-    shared static bucket P) into their slots of the shared ragged cache
-    — prefills, k/v scatters, pos and first-token updates all in ONE
-    dispatch instead of one per stream (what that saves on the chip is
-    not measured). Unused rows carry an
-    OUT-OF-RANGE slot index; mode='drop' makes their scatters no-ops.
+    """Prefill streams ([F, P] RIGHT-padded tokens, one shared bucket P)
+    into their slots of the shared ragged cache: prefill, k/v scatters,
+    pos and first-token updates in ONE dispatch. The engine calls it
+    with F = 1, one prompt a call, so one program per bucket: at
+    F = `slots` the padding rows were a third to a half of the device's
+    time (PERF.md, PR 25). `slots` [F] are in-range slot indices.
     seeds/temps/top_ps [F] are the per-stream sampling lanes
     (temperature 0 = greedy). Returns (new cache, new cur_tok,
     [F] first tokens, [F] first-token logprobs).
@@ -464,12 +464,11 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
         last_logits, seeds, true_lens - 1, temps, top_ps)
     # tmp k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
     cache = {
-        "k": cache["k"].at[:, slots].set(tmp["k"], mode="drop"),
-        "v": cache["v"].at[:, slots].set(tmp["v"], mode="drop"),
-        "pos": cache["pos"].at[slots].set(true_lens, mode="drop"),
+        "k": cache["k"].at[:, slots].set(tmp["k"]),
+        "v": cache["v"].at[:, slots].set(tmp["v"]),
+        "pos": cache["pos"].at[slots].set(true_lens),
     }
-    return (cache, cur_tok.at[slots].set(toks0, mode="drop"),
-            toks0, logp0)
+    return cache, cur_tok.at[slots].set(toks0), toks0, logp0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
@@ -594,7 +593,7 @@ class _Stream:
     token_times: list = field(default_factory=list)  # monotonic stamps
     submitted: float = 0.0
     admitted_at: float = 0.0  # slot granted, prefill dispatched
-    bucket: int = 0  # static prompt width it was prefilled at (0: none)
+    bucket: int = 0  # padded prompt width it was prefilled at (0: none)
     done: bool = False
     taken: int = 0  # tokens already handed out via take_tokens()
     prefilled: dict | None = None  # external KV payload (k/v/first_token)
@@ -665,11 +664,9 @@ class RaggedDecoder:
         # stamp the version live at their admission
         self.weights_version = int(weights_version)
         self.pumps = 0  # engine steps — staleness windows count these
-        # calls of the static-width prefill program, the real prompts
-        # they held and the rows they paid for (monotonic totals)
+        # calls of the cold prefill program, one prompt each (a
+        # monotonic total)
         self.prefill_calls = 0
-        self.prefill_prompts = 0
-        self.prefill_rows = 0
         self.slot_stream: list[_Stream | None] = [None] * slots
         self.queue: collections.deque[_Stream] = collections.deque()
         self._next_sid = 0
@@ -846,8 +843,8 @@ class RaggedDecoder:
 
     def _admit_grabbed(self, grabbed) -> int:
         """Put each (slot, stream) on the device by the path it takes:
-        adopted KV, prefix-cache warm, or the batched cold prefill.
-        Returns how many went cold."""
+        adopted KV, prefix-cache warm, or a cold prefill of its own, in
+        queue order. Returns how many went cold."""
         cold: list[tuple[int, _Stream]] = []
         t_now = time.monotonic()
         for slot, s in grabbed:
@@ -876,54 +873,36 @@ class RaggedDecoder:
                 pass  # adopted a cached prefix + suffix prefill
             else:
                 cold.append((slot, s))
-        by_bucket: dict[int, list] = {}
         for slot, s in cold:
-            by_bucket.setdefault(
-                self._bucket(len(s.prompt)), []).append((slot, s))
-        for pb, entries in by_bucket.items():
-            self._prefill_cold(pb, entries)
+            self._prefill_cold(slot, s)
+        if self.prefix_cache is not None:
+            self._insert_prefixes(cold)
         return len(cold)
 
-    def _prefill_cold(self, pb: int, entries) -> None:
-        """One call of the static-width prefill program for the streams
-        of one bucket: what the call held is counted HERE, where it is
-        exact (``prefill_*`` in stats(), the ``engine.prefill`` span)."""
-        f = self.slots  # static prefill width: one compile per bucket
+    def _prefill_cold(self, slot: int, s: _Stream) -> None:
+        """One call of the prefill program for one prompt, at one row of
+        its bucket's width (``prefill_calls`` in stats(), one
+        ``engine.prefill`` span a call)."""
+        n = len(s.prompt)
+        s.bucket = pb = self._bucket(n)
         self.prefill_calls += 1
-        self.prefill_prompts += len(entries)
-        self.prefill_rows += f
         with _fr.span("serve", "engine.prefill", flush=False, attrs={
-                "bucket": pb, "prompts": len(entries), "rows": f,
-                "tokens": sum(len(s.prompt) for _, s in entries)}):
-            prompts = np.zeros((f, pb), np.int32)
-            lens = np.ones((f,), np.int32)
-            slots_arr = np.full((f,), f + 1024, np.int32)  # OOB: dropped
-            seeds = np.zeros((f,), np.uint32)
-            temps = np.zeros((f,), np.float32)
-            topps = np.ones((f,), np.float32)
-            for i, (slot, s) in enumerate(entries):
-                n = len(s.prompt)
-                s.bucket = pb
-                prompts[i, :n] = s.prompt  # right-pad
-                lens[i] = n
-                slots_arr[i] = slot
-                seeds[i] = s.seed
-                temps[i] = s.temperature
-                topps[i] = s.top_p
-            (self.cache, self.cur_tok, toks0,
+                "bucket": pb, "prompts": 1, "rows": 1, "tokens": n}):
+            prompt = np.zeros((1, pb), np.int32)
+            prompt[0, :n] = s.prompt  # right-pad
+            (self.cache, self.cur_tok, tok0,
              logp0) = _prefill_batch_into_slots(
-                self.params, jnp.asarray(prompts), jnp.asarray(lens),
-                jnp.asarray(slots_arr), jnp.asarray(seeds),
-                jnp.asarray(temps), jnp.asarray(topps),
+                self.params, prompt, np.array([n], np.int32),
+                np.array([slot], np.int32),
+                np.array([s.seed], np.uint32),
+                np.array([s.temperature], np.float32),
+                np.array([s.top_p], np.float32),
                 self.cache, self.cur_tok, self.cfg)
         # NO host sync here: first tokens ride the next chunk's
         # single device_get (a per-admission sync would stall the
         # host until the prefill finished)
-        for i, (slot, s) in enumerate(entries):
-            self._pending_first.append((s, toks0[i], logp0[i]))
-            self.slot_stream[slot] = s
-        if self.prefix_cache is not None:
-            self._insert_prefixes(entries)
+        self._pending_first.append((s, tok0[0], logp0[0]))
+        self.slot_stream[slot] = s
 
     def _set_lane(self, slot: int, s: _Stream) -> None:
         self._slot_seed[slot] = s.seed
@@ -975,7 +954,7 @@ class RaggedDecoder:
         return True
 
     def _insert_prefixes(self, entries) -> None:
-        """After a cold batched prefill, capture each stream's
+        """After the cold prefills, capture each stream's
         block-aligned prefix rows into the prefix cache. Costs one
         device_get per stream that actually has uncached blocks — the
         amortized price of never prefilling that prefix again."""
@@ -1262,8 +1241,8 @@ class RaggedDecoder:
         occupied slots, queue depth, and recent tokens/s — also
         exported as Prometheus gauges (util/metrics.py) alongside the
         collective OpStats family — and monotonic totals an outside
-        reader takes deltas of (``total_tokens``, ``pumps``, the
-        ``prefill_*`` counts of the static-width prefill program)."""
+        reader takes deltas of (``total_tokens``, ``pumps``,
+        ``prefill_calls``: cold prefills, one prompt each)."""
         active = sum(1 for st in self.slot_stream if st is not None)
         out = {
             "slots": self.slots,
@@ -1274,8 +1253,6 @@ class RaggedDecoder:
             "weights_version": self.weights_version,
             "pumps": self.pumps,
             "prefill_calls": self.prefill_calls,
-            "prefill_prompts": self.prefill_prompts,
-            "prefill_rows": self.prefill_rows,
         }
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()

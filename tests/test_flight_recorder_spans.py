@@ -172,11 +172,8 @@ def test_one_first_token_span_per_request_with_its_split(engine):
     # serve.decode closes each stream; the counters count the calls
     assert len([s for s in _ring("serve.decode")
                 if s["attrs"]["sid"] in sids]) == 3
-    st = engine.stats()
-    calls = st["prefill_calls"] - stats0["prefill_calls"]
-    assert st["prefill_prompts"] - stats0["prefill_prompts"] == 3
-    assert st["prefill_rows"] - stats0["prefill_rows"] == 2 * calls
-    assert calls == 2  # two slots free at once, then one
+    # one prefill call a prompt, whatever number of slots was free
+    assert engine.stats()["prefill_calls"] - stats0["prefill_calls"] == 3
 
 
 @pytest.mark.parametrize("stamps, want", [
@@ -330,7 +327,7 @@ def test_replica_pump_spans_and_poll_pickup_marks(tmp_path):
             == len(new["engine.deliver"]) == 2
         assert len(new["engine.admit"]) == len(new["engine.prefill"]) == 1
         assert new["engine.prefill"][0]["attrs"] == {
-            "bucket": 8, "prompts": 1, "rows": 2, "tokens": 7}
+            "bucket": 8, "prompts": 1, "rows": 1, "tokens": 7}
         assert new["engine.admit"][0]["attrs"] == {
             "admitted": 1, "prefilled": 0, "cold": 1, "warm": 0}
         assert [s["attrs"]["delivered"] for s in new["engine.deliver"]] \

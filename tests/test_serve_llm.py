@@ -23,6 +23,12 @@ TINY = llama.LlamaConfig(
     d_ff=128, max_seq_len=128, dtype="float32", remat=False)
 
 
+def _greedy(params, prompt, max_new, max_len):
+    return np.asarray(llama.greedy_generate(
+        params, jax.numpy.asarray(np.asarray(prompt)[None]), TINY,
+        max_new, max_len=max_len))[0, len(prompt):]
+
+
 def test_ragged_engine_matches_greedy_generate():
     """Every stream decoded by the continuous-batching engine — under
     queueing, staggered admission, and slot reuse — must match the
@@ -38,11 +44,8 @@ def test_ragged_engine_matches_greedy_generate():
     sids = [eng.submit(p, max_new) for p in prompts]
     eng.drain()
     for sid, p in zip(sids, prompts):
-        want = np.asarray(llama.greedy_generate(
-            params, jax.numpy.asarray(p[None, :]), TINY, max_new,
-            max_len=64))[0, len(p):]
         got = np.asarray(eng.pop_finished(sid).tokens[:max_new])
-        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, _greedy(params, p, max_new, 64))
 
 
 def test_engine_interleaves_new_streams_into_free_slots():
@@ -67,6 +70,93 @@ def test_engine_interleaves_new_streams_into_free_slots():
     assert long_sid not in eng.finished  # interleaved, not serialized
     eng.drain()
     assert late_sid in eng.finished and long_sid in eng.finished
+
+
+# ---- cold prefill: one prompt a call, one row (ISSUE-25) ----
+
+
+def _prefill_spans():
+    from ray_tpu._private import flight_recorder as fr
+
+    return [s["attrs"] for s in fr._get().ring
+            if s["name"] == "engine.prefill"]
+
+
+@pytest.mark.parametrize("n_prompts", [1, 2, 4], ids=["one", "two", "slots"])
+def test_one_pump_prefills_each_prompt_in_a_call_of_its_own(n_prompts):
+    """Prompts of two buckets admitted by ONE pump: a prefill call and an
+    ``engine.prefill`` span for each, one row wide, and every stream's
+    tokens are those of the prompt served alone and of the reference."""
+    params = llama.init_params(TINY, jax.random.PRNGKey(0))
+    rng = np.random.RandomState(25)
+    lens = [6, 13, 16, 3][:n_prompts]  # buckets 8, 16, 16, 8
+    prompts = [rng.randint(1, 256, size=n).astype(np.int32) for n in lens]
+    kw = dict(slots=4, max_len=64, chunk_tokens=3, prompt_buckets=(8, 16))
+    eng = RaggedDecoder(params, TINY, **kw)
+    sids = [eng.submit(p, 7) for p in prompts]
+    n_spans = len(_prefill_spans())
+    assert eng.pump() == n_prompts  # all admitted at once
+    assert eng.stats()["prefill_calls"] == n_prompts
+    assert _prefill_spans()[n_spans:] == [  # in queue order
+        {"bucket": 8 if n <= 8 else 16, "prompts": 1, "rows": 1,
+         "tokens": n} for n in lens]
+    eng.drain()
+    alone = RaggedDecoder(params, TINY, **kw)
+    for sid, p in zip(sids, prompts):
+        got = np.asarray(eng.pop_finished(sid).tokens)
+        a = alone.submit(p, 7)
+        alone.drain()
+        np.testing.assert_array_equal(got, alone.pop_finished(a).tokens)
+        np.testing.assert_array_equal(got, _greedy(params, p, 7, 64))
+
+
+def test_a_burst_compiles_no_prefill_program_beyond_one_per_bucket():
+    """What the benchmark's warm-up rests on: one request per bucket
+    compiles every prefill shape; a burst that fills every slot at once
+    adds none."""
+    from ray_tpu.models.decode_engine import _prefill_batch_into_slots
+
+    params = llama.init_params(TINY, jax.random.PRNGKey(0))
+    rng = np.random.RandomState(26)
+    # max_len 72: shapes no other test of this process compiles
+    eng = RaggedDecoder(params, TINY, slots=8, max_len=72, chunk_tokens=4,
+                        prompt_buckets=(8, 16, 32))
+    n0 = _prefill_batch_into_slots._cache_size()
+    for n in (5, 12, 20):
+        eng.submit(rng.randint(1, 256, size=n).astype(np.int32), 2)
+        eng.drain()
+    assert _prefill_batch_into_slots._cache_size() - n0 == 3
+    for n in (3, 8, 9, 16, 17, 32, 7, 30):
+        eng.submit(rng.randint(1, 256, size=n).astype(np.int32), 2)
+    assert eng.pump() == 8
+    assert eng.stats()["prefill_calls"] == 3 + 8
+    assert _prefill_batch_into_slots._cache_size() - n0 == 3
+
+
+def test_reused_slot_holds_nothing_of_its_previous_occupant():
+    """Full-slot overwrite with the one-row call: a short prompt that
+    takes over a slot a long stream decoded to the cache's edge in finds
+    zeros past its own rows, and decodes to the edge itself (the clamped
+    write at row max_len-1) with the reference's tokens."""
+    params = llama.init_params(TINY, jax.random.PRNGKey(0))
+    rng = np.random.RandomState(27)
+    eng = RaggedDecoder(params, TINY, slots=1, max_len=48, chunk_tokens=4,
+                        prompt_buckets=(8, 32))
+    long_p = rng.randint(1, 256, size=30).astype(np.int32)
+    short_p = rng.randint(1, 256, size=5).astype(np.int32)
+    sid = eng.submit(long_p, 100)  # clamped to the slot's room
+    eng.drain()
+    assert len(eng.pop_finished(sid).tokens) == 48 - 30 - 1
+    assert np.abs(np.asarray(eng.cache["k"][:, 0, 40:])).min(
+        axis=(0, 2, 3)).max() > 0  # the long stream's rows are there
+    sid = eng.submit(short_p, 100)
+    eng.pump()  # prefill at bucket 8, then 4 decode steps: rows 5..8
+    for kv in ("k", "v"):
+        assert not np.asarray(eng.cache[kv][:, 0, 9:]).any()
+    eng.drain()
+    got = np.asarray(eng.pop_finished(sid).tokens)
+    assert len(got) == 48 - 5 - 1
+    np.testing.assert_array_equal(got, _greedy(params, short_p, 42, 48))
 
 
 @pytest.fixture(scope="module")

@@ -135,8 +135,20 @@ def _engine_args(cfg, chip, slots=8, max_len=288):
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
     cache = _on(chip, jax.eval_shape(
         lambda: de.init_ragged_cache(cfg, slots, max_len)))
-    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt, sharding=chip)  # noqa: E731
+    vec = lambda dt, n=slots: jax.ShapeDtypeStruct(  # noqa: E731
+        (n,), dt, sharding=chip)
     return params, cache, vec
+
+
+def _lower_prefill(cfg, chip, bucket, **engine):
+    """The engine's cold prefill call: one prompt, one row of its
+    bucket's width, into a cache of ``slots`` x ``max_len``."""
+    params, cache, vec = _engine_args(cfg, chip, **engine)
+    prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip)
+    return de._prefill_batch_into_slots.lower(
+        params, prompt, vec(jnp.int32, 1), vec(jnp.int32, 1),
+        vec(jnp.uint32, 1), vec(jnp.float32, 1), vec(jnp.float32, 1),
+        cache, vec(jnp.int32), cfg=cfg)
 
 
 def test_decode_chunk_compiles_at_1b_widths(topo):
@@ -150,6 +162,25 @@ def test_decode_chunk_compiles_at_1b_widths(topo):
     # the f32 masters are the arguments; they must fit a 16 GB chip
     # beside the temporaries
     assert mem["arguments_mib"] + mem["temporaries_mib"] < 15 * 1024, mem
+
+
+def test_one_row_prefill_compiles_at_internlm2_widths(topo):
+    """The benchmark's doc cell, two layers deep: one prompt in the
+    1024 bucket into 8 slots of 1296 rows. The temporaries are the
+    row's logits over the 92,544-wide head and its full-length k/v,
+    not ``slots`` times that."""
+    cfg = llama.LlamaConfig(
+        vocab_size=92544, d_model=2048, n_layers=2, n_heads=16,
+        n_kv_heads=8, d_ff=8192, rope_theta=1e6, rms_eps=1e-5,
+        max_seq_len=1296, dtype="bfloat16", remat=False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled = _lower_prefill(cfg, chip, 1024, slots=8,
+                              max_len=1296).compile()
+    mem = _mem(compiled)
+    print(f"\nprefill 1 x 1024: {mem}")
+    # the cache is donated: updated in place, never copied
+    assert mem["aliased_mib"] >= 2 * 2 * 8 * 1296 * 8 * 128 * 2 // MIB, mem
+    assert mem["temporaries_mib"] < 512, mem
 
 
 # ---- the train step, on one chip and sharded over four ----
@@ -231,11 +262,7 @@ def test_serving_programs_compile_at_1b_widths(topo, program):
             params, None, cache, vec(jnp.int32), vec(jnp.bool_), *lanes,
             cfg=cfg, rounds=8, depth=4, draft_layers=1)
     else:
-        bucket = int(program.split("_")[1])
-        prompts = jax.ShapeDtypeStruct((8, bucket), jnp.int32, sharding=chip)
-        lowered = de._prefill_batch_into_slots.lower(
-            params, prompts, vec(jnp.int32), vec(jnp.int32), *lanes,
-            cache, vec(jnp.int32), cfg=cfg)
+        lowered = _lower_prefill(cfg, chip, int(program.split("_")[1]))
     mem = _mem(lowered.compile())
     print(f"\n{program}: {mem}")
     assert mem["arguments_mib"] + mem["temporaries_mib"] < 15 * 1024, mem
