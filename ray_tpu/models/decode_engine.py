@@ -86,7 +86,8 @@ def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
     }
 
 
-def _layer_decode_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
+def _layer_decode_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
+                         aux: dict | None = None):
     """One-token decode layer with PER-SLOT positions. h: [B, 1, D];
     ck/cv: [B, S, Hkv, D]; pos: [B]. Writes each slot's k/v at its own
     offset (scatter) and masks attention to k_pos <= pos per slot."""
@@ -117,11 +118,12 @@ def _layer_decode_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
             "bhts,bshd->bthd", probs, vv,
             preferred_element_type=jnp.float32,
         ).astype(cdt)
-    h = llama._attn_out_and_mlp(cfg, p, h, o)
+    h = llama._attn_out_and_mlp(cfg, p, h, o, aux)
     return h, ck, cv
 
 
-def _layer_verify_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
+def _layer_verify_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
+                         aux: dict | None = None):
     """T-query generalization of :func:`_layer_decode_ragged` for the
     speculative VERIFY step: h is [B, T, D] (the current token plus the
     K drafted tokens, T == K+1) and pos [B] is each slot's base
@@ -154,8 +156,20 @@ def _layer_verify_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
     o = jnp.einsum(
         "bhts,bshd->bthd", probs, vv, preferred_element_type=jnp.float32
     ).astype(cdt)
-    h = llama._attn_out_and_mlp(cfg, p, h, o)
+    h = llama._attn_out_and_mlp(cfg, p, h, o, aux)
     return h, ck, cv
+
+
+def _experts_touched(cfg: LlamaConfig, aux: dict | None, active) -> tuple:
+    """What a layer adds to its scan's outputs for the routing counters:
+    ``()`` for a model that reports no routing (its program is the one
+    it was), else the number of distinct experts that got a row from an
+    ACTIVE slot in this layer (an int32 scalar)."""
+    if aux is None:
+        return ()
+    hit = jax.nn.one_hot(aux["expert_ids"], cfg.n_experts, dtype=jnp.bool_)
+    hit = hit & active[:, None, None, None]  # ids are [B, T, top_k]
+    return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),)
 
 
 def _sample_from_logits(logits, seeds, pos, temps, top_ps):
@@ -218,12 +232,16 @@ def decode_chunk_sampled(params, cache, tok, active, seeds, temps,
     logprobs. seeds [B] uint32 / temps [B] / top_ps [B] ride alongside
     the slot batch; a slot with temperature 0 decodes greedily
     (bit-identical tokens to `decode_chunk`). Returns
-    ([B, chunk] tokens, [B, chunk] f32 logprobs, new cache, [B] last)."""
+    ([B, chunk] tokens, [B, chunk] f32 logprobs, new cache, [B] last)
+    and, for a model that reports its routing, ``experts_touched``
+    [chunk, L] (see ``_experts_touched``)."""
     cdt = cfg.compute_dtype
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cdt)
     max_len = cache["k"].shape[2]
+    routed = llama.reports_routing(cfg)
+    layers, attach = llama.split_layers(cfg, params["layers"])
 
     def one_step(carry, _):
         t, k, v, pos = carry
@@ -233,24 +251,25 @@ def decode_chunk_sampled(params, cache, tok, active, seeds, temps,
 
         def body(h_, xs):
             p_, ck, cv = xs
+            aux = {} if routed else None
             h_, ck, cv = _layer_decode_ragged(
-                cfg, h_, p_, sin, cos, ck, cv, pos)
-            return h_, (ck, cv)
+                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
+            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
 
-        h, (k, v) = jax.lax.scan(body, h, (params["layers"], k, v))
+        h, (k, v, *touched) = jax.lax.scan(body, h, (layers, k, v))
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
         nxt, lp = _sample_from_logits(logits, seeds, pos, temps, top_ps)
         nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
         # pos clamp: see decode_chunk
         pos = jnp.minimum(pos + active.astype(pos.dtype), max_len - 1)
-        return (nxt, k, v, pos), (nxt, lp)
+        return (nxt, k, v, pos), (nxt, lp, *touched)
 
-    (last, k, v, pos), (toks, lps) = jax.lax.scan(
+    (last, k, v, pos), (toks, lps, *touched) = jax.lax.scan(
         one_step, (tok, cache["k"], cache["v"], cache["pos"]),
         None, length=chunk)
     return (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(lps, 0, 1),
-            {"k": k, "v": v, "pos": pos}, last)
+            {"k": k, "v": v, "pos": pos}, last, *touched)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "chunk"),
@@ -262,12 +281,15 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
     tok: [B] current token per slot; active: [B] bool. Inactive slots
     re-write garbage at their frozen pos (invisible: their mask never
     advances; a later prefill overwrites). Returns ([B, chunk] tokens,
-    new cache, [B] last token)."""
+    new cache, [B] last token) and, for a model that reports its
+    routing, ``experts_touched`` [chunk, L] (see ``_experts_touched``)."""
     cdt = cfg.compute_dtype
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cdt)
     max_len = cache["k"].shape[2]
+    routed = llama.reports_routing(cfg)
+    layers, attach = llama.split_layers(cfg, params["layers"])
 
     def one_step(carry, _):
         t, k, v, pos = carry
@@ -277,11 +299,12 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
 
         def body(h_, xs):
             p_, ck, cv = xs
+            aux = {} if routed else None
             h_, ck, cv = _layer_decode_ragged(
-                cfg, h_, p_, sin, cos, ck, cv, pos)
-            return h_, (ck, cv)
+                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
+            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
 
-        h, (k, v) = jax.lax.scan(body, h, (params["layers"], k, v))
+        h, (k, v, *touched) = jax.lax.scan(body, h, (layers, k, v))
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
         nxt = jnp.argmax(logits, axis=-1).astype(t.dtype)
@@ -293,12 +316,13 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
         # the cache and pump()'s pos >= max_len-1 finish check stays
         # exact instead of relying on overflow
         pos = jnp.minimum(pos + active.astype(pos.dtype), max_len - 1)
-        return (nxt, k, v, pos), nxt
+        return (nxt, k, v, pos), (nxt, *touched)
 
-    (last, k, v, pos), toks = jax.lax.scan(
+    (last, k, v, pos), (toks, *touched) = jax.lax.scan(
         one_step, (tok, cache["k"], cache["v"], cache["pos"]),
         None, length=chunk)
-    return jnp.moveaxis(toks, 0, 1), {"k": k, "v": v, "pos": pos}, last
+    return (jnp.moveaxis(toks, 0, 1), {"k": k, "v": v, "pos": pos}, last,
+            *touched)
 
 
 @functools.partial(jax.jit,
@@ -336,7 +360,8 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
 
     Returns (toks [B, rounds, K+1], lps [B, rounds, K+1],
     counts [B, rounds] — tokens emitted per round (0 for inactive
-    slots), new cache, [B] last token)."""
+    slots), new cache, [B] last token) and, for a model that reports
+    its routing, the verify passes' ``experts_touched`` [rounds, L]."""
     cdt = cfg.compute_dtype
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -344,9 +369,11 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     max_len = cache["k"].shape[2]
     b = tok.shape[0]
     t_wide = depth + 1
-    dlayers = jax.tree_util.tree_map(
-        lambda a: a[:draft_layers], params["layers"])
     rows = jnp.arange(b)
+    routed = llama.reports_routing(cfg)
+    layers, attach = llama.split_layers(cfg, params["layers"])
+    # the draft scans the first layers of the same stack
+    dlayers = jax.tree_util.tree_map(lambda a: a[:draft_layers], layers)
 
     def one_round(carry, _):
         t, k, v, pos = carry
@@ -361,7 +388,7 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
             def body(h_, xs):
                 p_, ck, cv = xs
                 h_, ck, cv = _layer_decode_ragged(
-                    cfg, h_, p_, sin, cos, ck, cv, dpos)
+                    cfg, h_, attach(p_), sin, cos, ck, cv, dpos)
                 return h_, (ck, cv)
 
             h, (kd, vd) = jax.lax.scan(body, h, (dlayers, kd, vd))
@@ -393,11 +420,12 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
 
         def vbody(h_, xs_):
             p_, ck, cv = xs_
+            aux = {} if routed else None
             h_, ck, cv = _layer_verify_ragged(
-                cfg, h_, p_, sin, cos, ck, cv, pos)
-            return h_, (ck, cv)
+                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
+            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
 
-        h, (k, v) = jax.lax.scan(vbody, h, (params["layers"], k, v))
+        h, (k, v, *touched) = jax.lax.scan(vbody, h, (layers, k, v))
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h @ w_out).astype(jnp.float32)  # [B, T, V]
         y, lp = _sample_from_logits(
@@ -413,14 +441,14 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         m = jnp.where(active, m, 0)
         t = jnp.where(active, y[rows, jnp.maximum(m - 1, 0)], t)
         pos = jnp.minimum(pos + m, max_len - 1)
-        return (t, k, v, pos), (y, lp, m)
+        return (t, k, v, pos), (y, lp, m, *touched)
 
-    (last, k, v, pos), (toks, lps, counts) = jax.lax.scan(
+    (last, k, v, pos), (toks, lps, counts, *touched) = jax.lax.scan(
         one_round, (tok, cache["k"], cache["v"], cache["pos"]),
         None, length=rounds)
     return (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(lps, 0, 1),
             jnp.moveaxis(counts, 0, 1), {"k": k, "v": v, "pos": pos},
-            last)
+            last, *touched)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -436,7 +464,9 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     time (PERF.md, PR 25). `slots` [F] are in-range slot indices.
     seeds/temps/top_ps [F] are the per-stream sampling lanes
     (temperature 0 = greedy). Returns (new cache, new cur_tok,
-    [F] first tokens, [F] first-token logprobs).
+    [F] first tokens, [F] first-token logprobs) and, for a model that
+    reports its routing (``llama.reports_routing``), ``expert_tokens``
+    [L, E] (see ``_expert_tokens``).
 
     Right-padding is safe without a pad mask: causal attention means
     real tokens (a prefix) never see the pad garbage, the first token
@@ -454,7 +484,8 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     f = prompts.shape[0]
     slot_len = cache["k"].shape[2]
     tmp = llama.init_cache(cfg, f, slot_len)
-    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp)
+    aux = {}
+    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp, aux)
     last_logits = logits[jnp.arange(f), true_lens - 1].astype(jnp.float32)
     # the first token is emitted from position true_len-1 — the same
     # (seed, position) RNG lane scheme as decode_chunk_sampled, so a
@@ -468,7 +499,20 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
         "v": cache["v"].at[:, slots].set(tmp["v"]),
         "pos": cache["pos"].at[slots].set(true_lens),
     }
-    return cache, cur_tok.at[slots].set(toks0), toks0, logp0
+    return (cache, cur_tok.at[slots].set(toks0), toks0, logp0,
+            *_expert_tokens(cfg, aux, true_lens))
+
+
+def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
+    """``()`` for a model that reports no routing, else ([L, E] int32,):
+    the assignments each expert got in each layer from the REAL
+    positions of the prompts (``true_lens`` masks the bucket's padding)."""
+    if "expert_ids" not in aux:
+        return ()
+    ids = aux["expert_ids"]  # [L, F, P, top_k]
+    real = jnp.arange(ids.shape[2])[None, :] < true_lens[:, None]  # [F, P]
+    hit = jax.nn.one_hot(ids, cfg.n_experts, dtype=jnp.int32)
+    return (jnp.sum(hit * real[None, :, :, None, None], axis=(1, 2, 3)),)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
@@ -667,6 +711,16 @@ class RaggedDecoder:
         # calls of the cold prefill program, one prompt each (a
         # monotonic total)
         self.prefill_calls = 0
+        # routing counters of a mixture-of-experts model (monotonic
+        # totals; both stay 0 for a model that reports no routing):
+        # (token, expert) assignments of the prompts' real positions in
+        # cold prefills, and experts touched summed over decode steps
+        # and layers
+        self.moe_assignments = 0
+        self.moe_touched_expert_steps = 0
+        # [L, E] device counts of the cold prefills since the last
+        # read-back, fetched with it
+        self._pending_expert_tokens: list = []
         self.slot_stream: list[_Stream | None] = [None] * slots
         self.queue: collections.deque[_Stream] = collections.deque()
         self._next_sid = 0
@@ -890,8 +944,8 @@ class RaggedDecoder:
                 "bucket": pb, "prompts": 1, "rows": 1, "tokens": n}):
             prompt = np.zeros((1, pb), np.int32)
             prompt[0, :n] = s.prompt  # right-pad
-            (self.cache, self.cur_tok, tok0,
-             logp0) = _prefill_batch_into_slots(
+            (self.cache, self.cur_tok, tok0, logp0,
+             *expert_tokens) = _prefill_batch_into_slots(
                 self.params, prompt, np.array([n], np.int32),
                 np.array([slot], np.int32),
                 np.array([s.seed], np.uint32),
@@ -902,6 +956,7 @@ class RaggedDecoder:
         # single device_get (a per-admission sync would stall the
         # host until the prefill finished)
         self._pending_first.append((s, tok0[0], logp0[0]))
+        self._pending_expert_tokens.extend(expert_tokens)
         self.slot_stream[slot] = s
 
     def _set_lane(self, slot: int, s: _Stream) -> None:
@@ -995,7 +1050,7 @@ class RaggedDecoder:
                       attrs={"active": n_active, "chunk": self.chunk,
                              "depth": 0}):
             if self._sampling_seen:
-                toks, lps, self.cache, self.cur_tok = \
+                toks, lps, self.cache, self.cur_tok, *touched = \
                     decode_chunk_sampled(
                         self.params, self.cache, self.cur_tok,
                         active_mask, jnp.asarray(self._slot_seed),
@@ -1005,13 +1060,13 @@ class RaggedDecoder:
             else:
                 # greedy-only engine: the legacy argmax kernel — no
                 # per-token argsort/softmax; logprobs placeholder 0.0
-                toks, self.cache, self.cur_tok = decode_chunk(
+                toks, self.cache, self.cur_tok, *touched = decode_chunk(
                     self.params, self.cache, self.cur_tok, active_mask,
                     self.cfg, self.chunk)
                 lps = None
         firsts = self._take_pending_first()
         toks, lps, pos_np, first_toks, first_lps = self._readback(
-            (toks, lps, self.cache["pos"]), firsts)
+            (toks, lps, self.cache["pos"]), firsts, touched)
         if lps is None:
             lps = np.zeros((self.slots, self.chunk), np.float32)
         t_now = time.monotonic()
@@ -1040,17 +1095,41 @@ class RaggedDecoder:
         firsts, self._pending_first = self._pending_first, []
         return firsts
 
-    def _readback(self, chunk_out: tuple, firsts: list) -> tuple:
+    def _readback(self, chunk_out: tuple, firsts: list,
+                  touched: list) -> tuple:
         """The chunk's ONE device→host sync: ``chunk_out`` plus the first
-        tokens and logprobs of the streams prefilled before it."""
-        with _fr.span("serve", "engine.readback", flush=False):
+        tokens and logprobs of the streams prefilled before it and, for
+        a model that reports its routing, the chunk's
+        ``experts_touched`` and the prefills' ``expert_tokens``."""
+        loads, self._pending_expert_tokens = self._pending_expert_tokens, []
+        with _fr.span("serve", "engine.readback", flush=False) as sp:
             if self.chunk_delay_s:
                 time.sleep(self.chunk_delay_s)  # see __init__: emulated
                 # device time (GIL released; replicas overlap)
-            *out, first_toks, first_lps = jax.device_get(
+            *out, first_toks, first_lps, touched, loads = jax.device_get(
                 (*chunk_out, [t for _, t, _ in firsts],
-                 [lp for _, _, lp in firsts]))
+                 [lp for _, _, lp in firsts], touched, loads))
+            self._count_routing(sp, touched, loads)
         return (*out, first_toks, first_lps)
+
+    def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
+        """The routing counters a read-back brought: ``touched`` holds
+        the chunk's [steps, L] experts touched (or nothing), ``loads``
+        one [L, E] array of assignments per cold prefill since the last
+        read-back. Span attrs: ``experts_touched`` (mean over the
+        chunk's steps and layers) and, with a prefill's counts,
+        ``expert_load_max`` / ``expert_load_mean`` (assignments on the
+        fullest expert and the mean over experts, of one call's layers;
+        means over the calls where there were several)."""
+        for t in touched:
+            self.moe_touched_expert_steps += int(t.sum())
+            sp["experts_touched"] = float(t.mean())
+        if loads:
+            self.moe_assignments += int(sum(a.sum() for a in loads))
+            sp["expert_load_max"] = float(
+                np.mean([a.max() for a in loads]))
+            sp["expert_load_mean"] = float(
+                np.mean([a.mean() for a in loads]))
 
     def _deliver_firsts(self, firsts, first_toks, first_lps,
                         t_now: float) -> int:
@@ -1136,7 +1215,7 @@ class RaggedDecoder:
             with _fr.span("serve", "engine.decode_dispatch", flush=False,
                           attrs={"active": n_active, "chunk": self.chunk,
                                  "depth": depth}):
-                toks, lps, counts, self.cache, self.cur_tok = \
+                toks, lps, counts, self.cache, self.cur_tok, *touched = \
                     decode_chunk_spec(
                         self.params, self.spec_draft_head, self.cache,
                         self.cur_tok, active_mask,
@@ -1147,7 +1226,7 @@ class RaggedDecoder:
             firsts = self._take_pending_first()
             toks, lps, counts, pos_np, first_toks, first_lps = \
                 self._readback((toks, lps, counts, self.cache["pos"]),
-                               firsts)
+                               firsts, touched)
             if not self._sampling_seen:
                 # greedy-only engine: match the plain kernel's logprob
                 # surface (placeholder 0.0) so spec on/off is
@@ -1242,7 +1321,9 @@ class RaggedDecoder:
         exported as Prometheus gauges (util/metrics.py) alongside the
         collective OpStats family — and monotonic totals an outside
         reader takes deltas of (``total_tokens``, ``pumps``,
-        ``prefill_calls``: cold prefills, one prompt each)."""
+        ``prefill_calls``: cold prefills, one prompt each; for a
+        mixture-of-experts model ``moe_assignments`` and
+        ``moe_touched_expert_steps``, see ``__init__``)."""
         active = sum(1 for st in self.slot_stream if st is not None)
         out = {
             "slots": self.slots,
@@ -1254,6 +1335,9 @@ class RaggedDecoder:
             "pumps": self.pumps,
             "prefill_calls": self.prefill_calls,
         }
+        if llama.reports_routing(self.cfg):
+            out["moe_assignments"] = self.moe_assignments
+            out["moe_touched_expert_steps"] = self.moe_touched_expert_steps
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         if self.spec_depth or self._spec_pumps:

@@ -48,7 +48,16 @@ class LlamaConfig:
     remat_policy: str = "dots"
     use_flash: bool | None = None  # None = auto (flash on TPU)
     tie_embeddings: bool = False
-    # Mixture-of-experts MLP (0 = dense MLP). Two TPU-first impls:
+    # RMS-normalise the whole q and the whole k projection (a learned
+    # scale of n_heads * head_dim / n_kv_heads * head_dim each) before
+    # the heads are split and rotated, as OLMoE's block does.
+    qk_norm: bool = False
+    # Mixture-of-experts MLP (0 = dense MLP). Three impls:
+    # - "dropless": every (token, expert) assignment is computed at any
+    #   load. The assignments are sorted by expert and go through one
+    #   grouped matmul (ops/grouped_matmul.py) that skips experts nobody
+    #   was routed to. What a server runs; one device's experts only (a
+    #   mesh whose ep axis is larger than 1 is refused).
     # - "capacity" (default): GShard-style top-k token routing with a
     #   per-row capacity buffer — dispatch/combine einsums whose expert
     #   dim shards over the ep mesh axis, so GSPMD lowers the dispatch to
@@ -59,7 +68,10 @@ class LlamaConfig:
     #   the capacity path. (The reference has no MoE at all, SURVEY §2.7.)
     n_experts: int = 0
     top_k: int = 2
-    moe_impl: str = "capacity"  # "capacity" | "dense"
+    moe_impl: str = "capacity"  # "capacity" | "dense" | "dropless"
+    # renormalise the kept top-k router probabilities to sum to 1
+    # (False: OLMoE's norm_topk_prob, the softmax's own values)
+    norm_topk_prob: bool = True
     # Expert buffer size multiplier: capacity = ceil(top_k*T/E * factor).
     # Tokens routed past a full expert are dropped (their residual path
     # still carries them) — GShard semantics.
@@ -86,6 +98,8 @@ class LlamaConfig:
         else:
             mlp = 3 * d * f
         per_layer = attn + mlp + 2 * d
+        if self.qk_norm:
+            per_layer += (self.n_heads + self.n_kv_heads) * hd
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * per_layer + d + head
 
@@ -143,6 +157,9 @@ def init_params(cfg: LlamaConfig, key):
             "wk": dense(next(k), (l, d, hkv * hd), d),
             "wv": dense(next(k), (l, d, hkv * hd), d),
             "wo": dense(next(k), (l, hq * hd, d), hq * hd),
+            **({"q_norm": jnp.ones((l, hq * hd), jnp.float32),
+                "k_norm": jnp.ones((l, hkv * hd), jnp.float32)}
+               if cfg.qk_norm else {}),
             "mlp_norm": jnp.ones((l, d), jnp.float32),
             **(
                 {
@@ -176,6 +193,9 @@ def param_logical_axes(cfg: LlamaConfig):
             "wk": ("layers", "embed", "kv_heads"),
             "wv": ("layers", "embed", "kv_heads"),
             "wo": ("layers", "heads", "embed"),
+            **({"q_norm": ("layers", "heads"),
+                "k_norm": ("layers", "kv_heads")}
+               if cfg.qk_norm else {}),
             "mlp_norm": ("layers", "norm"),
             **(
                 {
@@ -214,22 +234,37 @@ def _qkv(cfg: LlamaConfig, p, h, sin, cos):
     # operations carry them, the compiled program does not change
     with jax.named_scope("attn"):
         x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-        q = (x @ p["wq"].astype(cdt)).reshape(b, t, hq, hd)
-        k = (x @ p["wk"].astype(cdt)).reshape(b, t, hkv, hd)
+        q = x @ p["wq"].astype(cdt)
+        if cfg.qk_norm:  # over the whole projection, before the heads
+            q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        q = q.reshape(b, t, hq, hd)
+        k = x @ p["wk"].astype(cdt)
+        if cfg.qk_norm:
+            k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+        k = k.reshape(b, t, hkv, hd)
         v = (x @ p["wv"].astype(cdt)).reshape(b, t, hkv, hd)
         return apply_rotary(q, sin, cos), apply_rotary(k, sin, cos), v
 
 
-def moe_gates(cfg: LlamaConfig, router, x):
-    """Router probabilities with top-k masking; [B, T, E], rows sum to 1
-    over exactly top_k nonzero entries."""
-    logits = x @ router.astype(cfg.compute_dtype)  # [B, T, E]
+def moe_topk(cfg: LlamaConfig, router, x):
+    """The router: x [..., D] -> (weights [..., top_k] f32, expert ids
+    [..., top_k] int32). Logits in the compute type, softmax over ALL
+    experts in float32, ``lax.top_k`` picks exactly ``top_k``; the kept
+    probabilities are renormalised only with ``norm_topk_prob``."""
+    logits = x @ router.astype(cfg.compute_dtype)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    if cfg.top_k < cfg.n_experts:
-        kth = jnp.sort(probs, axis=-1)[..., -cfg.top_k][..., None]
-        probs = jnp.where(probs >= kth, probs, 0.0)
-        probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
-    return probs
+    weights, ids = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, ids
+
+
+def moe_gates(cfg: LlamaConfig, router, x):
+    """:func:`moe_topk` as a dense [B, T, E] f32 matrix: exactly top_k
+    nonzero entries a row (rows sum to 1 with ``norm_topk_prob``)."""
+    weights, ids = moe_topk(cfg, router, x)
+    return jnp.sum(jax.nn.one_hot(ids, cfg.n_experts, dtype=weights.dtype)
+                   * weights[..., None], axis=-2)
 
 
 def _moe_mlp_dense(cfg: LlamaConfig, p, x):
@@ -310,17 +345,99 @@ def _moe_mlp_capacity(cfg: LlamaConfig, p, x):
     return shard_constraint(y, ("batch", "seq", "embed"))
 
 
-def _moe_mlp(cfg: LlamaConfig, p, x):
+def reports_routing(cfg: LlamaConfig) -> bool:
+    """Whether the MLP leaves its chosen expert ids in a caller's
+    ``aux`` (the dropless expert layer does; the serving engine's
+    programs then return routing counters beside their tokens)."""
+    return cfg.n_experts > 0 and cfg.moe_impl == "dropless"
+
+
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def split_layers(cfg: LlamaConfig, layers):
+    """How a SERVING program scans the layer stack: -> (xs, attach), the
+    scan's input and the function that makes a layer's parameters from
+    one slice of it. For every model but a dropless mixture of experts
+    that is the stack itself and the identity (the programs of today).
+    A dropless model's expert matrices stay OUT of the scanned input: a
+    scan slices its input, and a slice of [L, E, K, N] handed to the
+    grouped matmul kernel is a copy of every expert of the layer, read
+    or not, in every decode step. They ride along whole (cast to the
+    compute type once, here) beside the layer's index, and the kernel
+    reads ``stack[layer]``'s blocks in place."""
+    if not reports_routing(cfg):
+        return layers, lambda p: p
+    cdt = cfg.compute_dtype
+    experts = {w: layers[w].astype(cdt) for w in _EXPERT_WEIGHTS}
+    xs = {k: v for k, v in layers.items() if k not in experts}
+    xs["layer"] = jnp.arange(layers["router"].shape[0], dtype=jnp.int32)
+    return xs, lambda p: {**p, **experts}
+
+
+def _moe_mlp_dropless(cfg: LlamaConfig, p, x, aux: dict | None = None):
+    """Dropless top-k routing: every (token, expert) assignment is
+    computed, at any load. The N x top_k assignments are sorted by
+    expert (stable), the tokens' rows gathered in that order, and the
+    experts applied by one grouped matmul each for gate, up and down
+    (ops/grouped_matmul.py, which never reads an expert without rows);
+    then unsorted, weighted and summed over top_k. Static shapes
+    (N x top_k rows always), f32 router, compute type elsewhere. ``p``
+    holds one layer's expert matrices [E, ...] or, from a serving
+    program's scan, the stack and the layer's index (``split_layers``).
+    With ``aux`` the chosen expert ids [B, T, top_k] are left in
+    ``aux["expert_ids"]`` (the serving engine's routing counters)."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    if dict(jax.sharding.get_abstract_mesh().shape).get("ep", 1) > 1:
+        raise ValueError(
+            "moe_impl='dropless' keeps every expert on one device; a mesh "
+            "with an ep axis larger than 1 needs moe_impl='capacity'")
+    cdt = cfg.compute_dtype
+    b, t, d = x.shape
+    e, k = cfg.n_experts, cfg.top_k
+    xf = x.reshape(b * t, d)
+    with jax.named_scope("moe_router"):
+        weights, ids = moe_topk(cfg, p["router"], xf)  # [N, k]
+        if aux is not None:
+            aux["expert_ids"] = ids.reshape(b, t, k)
+    with jax.named_scope("moe_experts"):
+        flat = ids.reshape(-1)  # assignment j belongs to token j // k
+        order = jnp.argsort(flat, stable=True)
+        group_sizes = jnp.sum(
+            jax.nn.one_hot(flat, e, dtype=jnp.int32), axis=0)
+        rows = xf[order // k]  # [N * k, D], expert by expert
+        experts = functools.partial(grouped_matmul, group_sizes=group_sizes,
+                                    layer=p.get("layer"))
+        gate = experts(rows, p["w_gate"].astype(cdt))
+        up = experts(rows, p["w_up"].astype(cdt))
+        y = experts(jax.nn.silu(gate) * up, p["w_down"].astype(cdt))
+        unsort = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = y[unsort].reshape(b * t, k, d).astype(jnp.float32)
+        out = jnp.sum(y * weights[..., None], axis=1).astype(cdt)
+    return shard_constraint(out.reshape(b, t, d), ("batch", "seq", "embed"))
+
+
+def _moe_mlp(cfg: LlamaConfig, p, x, aux: dict | None = None):
+    """What each ``moe_impl`` promises: "dropless" computes every
+    assignment (serving); "capacity" shards experts over ep and drops
+    past a buffer; "dense" is the every-expert oracle at tiny sizes."""
+    if cfg.moe_impl == "dropless":
+        return _moe_mlp_dropless(cfg, p, x, aux)
     if cfg.moe_impl == "dense":
         return _moe_mlp_dense(cfg, p, x)
     if cfg.moe_impl == "capacity":
         return _moe_mlp_capacity(cfg, p, x)
     raise ValueError(
-        f"unknown moe_impl {cfg.moe_impl!r}; expected 'capacity' or 'dense'")
+        f"unknown moe_impl {cfg.moe_impl!r}; expected 'dropless', "
+        "'capacity' or 'dense'")
 
 
-def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o):
-    """Shared wo projection + residual + MLP (SwiGLU dense or MoE)."""
+def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o, aux: dict | None = None):
+    """Shared wo projection + residual + MLP (SwiGLU dense or MoE).
+    ``aux``, where a caller passes one, receives what the MLP leaves for
+    it (a dropless MoE: ``expert_ids``); a dense model leaves nothing."""
     b, t, _ = h.shape
     hq, hd = cfg.n_heads, cfg.head_dim
     cdt = cfg.compute_dtype
@@ -332,7 +449,7 @@ def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o):
     with jax.named_scope("mlp"):
         x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
         if cfg.n_experts > 0:
-            return h + _moe_mlp(cfg, p, x)
+            return h + _moe_mlp(cfg, p, x, aux)
         from jax.ad_checkpoint import checkpoint_name
 
         # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
@@ -507,7 +624,8 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     }
 
 
-def _layer_with_cache(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
+def _layer_with_cache(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
+                      aux: dict | None = None):
     """_layer variant that appends this block's k/v at `pos` and attends
     the cache prefix. h: [B, T, D]; ck/cv: [B, S, Hkv, D]."""
     from ray_tpu.ops.attention import _repeat_kv
@@ -535,13 +653,16 @@ def _layer_with_cache(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos):
     o = jnp.einsum(
         "bhts,bshd->bthd", probs, vv, preferred_element_type=jnp.float32
     ).astype(cdt)
-    h = _attn_out_and_mlp(cfg, p, h, o)
+    h = _attn_out_and_mlp(cfg, p, h, o, aux)
     return h, ck, cv
 
 
-def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict):
+def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict,
+                       aux: dict | None = None):
     """Run tokens [B, T] starting at cache['pos']; returns (logits [B,T,V],
-    new cache). Covers both prefill (T=prompt len) and decode (T=1)."""
+    new cache). Covers both prefill (T=prompt len) and decode (T=1).
+    With ``aux`` and a model that reports its routing, every layer's
+    expert ids [L, B, T, top_k] are left in ``aux["expert_ids"]``."""
     b, t = tokens.shape
     cdt = cfg.compute_dtype
     pos = cache["pos"]
@@ -550,14 +671,22 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict):
 
     h = params["embed"].astype(cdt)[tokens]
 
+    routed = aux is not None and reports_routing(cfg)
+    layers, attach = split_layers(cfg, params["layers"])
+
     def body(h_, xs):
         p_, ck, cv = xs
-        h_, ck, cv = _layer_with_cache(cfg, h_, p_, sin, cos, ck, cv, pos)
-        return h_, (ck, cv)
+        layer_aux = {} if routed else None
+        h_, ck, cv = _layer_with_cache(cfg, h_, attach(p_), sin, cos, ck,
+                                       cv, pos, layer_aux)
+        return h_, (ck, cv,
+                    *((layer_aux["expert_ids"],) if routed else ()))
 
-    h, (ck, cv) = jax.lax.scan(
-        body, h, (params["layers"], cache["k"], cache["v"])
+    h, (ck, cv, *ids) = jax.lax.scan(
+        body, h, (layers, cache["k"], cache["v"])
     )
+    if routed:
+        aux["expert_ids"] = ids[0]
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]

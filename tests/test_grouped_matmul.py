@@ -1,0 +1,94 @@
+"""``ops/grouped_matmul.py``: the Pallas kernel, run in interpret mode,
+against ``jax.lax.ragged_dot`` (what the same function is off the TPU):
+forward, both gradients, empty groups, rows that do not fill a tile,
+and the stacked form a serving program's layer scan uses. That the
+kernel compiles for the chip at OLMoE's shapes is
+``tests/test_tpu_compile.py``'s."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops.grouped_matmul import (group_metadata, grouped_matmul,
+                                        tiling)
+
+CASES = {
+    # name: (rows, k, n, tm, tn, group sizes or number of groups)
+    "even": (64, 128, 256, 16, 128, 8),
+    "empty-groups": (64, 128, 256, 64, 128, [0, 10, 0, 0, 30, 1, 0, 23]),
+    "ragged-rows": (100, 128, 256, 32, 128, 8),
+    "one-group": (256, 256, 128, 64, 128, [256, 0, 0, 0]),
+    "decode-like": (24, 128, 128, None, None, 16),
+}
+
+
+def _case(name):
+    m, k, n, tm, tn, groups = CASES[name]
+    keys = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+    if isinstance(groups, int):
+        ids = jax.random.randint(keys[0], (m,), 0, groups)
+        sizes = jnp.bincount(ids, length=groups).astype(jnp.int32)
+    else:
+        sizes = jnp.asarray(groups, jnp.int32)
+    lhs = jax.random.normal(keys[1], (m, k), jnp.float32)
+    rhs = jax.random.normal(keys[2], (sizes.shape[0], k, n), jnp.float32)
+    weight = jax.random.normal(keys[3], (m, n), jnp.float32)
+    return lhs, rhs, sizes, weight, dict(tm=tm, tn=tn)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_matches_ragged_dot(name):
+    lhs, rhs, sizes, weight, tile = _case(name)
+
+    def loss(fn):
+        return lambda a, b: (fn(a, b) * weight).sum()
+
+    kernel = lambda a, b: grouped_matmul(  # noqa: E731
+        a, b, sizes, interpret=True, **tile)
+    plain = lambda a, b: grouped_matmul(a, b, sizes)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(kernel(lhs, rhs)),
+                               np.asarray(plain(lhs, rhs)), atol=2e-4)
+    got = jax.grad(loss(kernel), (0, 1))(lhs, rhs)
+    want = jax.grad(loss(plain), (0, 1))(lhs, rhs)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4)
+
+
+def test_a_stack_is_read_in_place_at_the_layer():
+    lhs, rhs, sizes, _, tile = _case("empty-groups")
+    stack = jnp.stack([rhs * 0, rhs, rhs * 2])
+    for use in (dict(interpret=True, **tile), dict()):
+        got = jax.jit(lambda layer: grouped_matmul(
+            lhs, stack, sizes, layer=layer, **use))(jnp.int32(1))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(grouped_matmul(lhs, rhs, sizes)),
+            atol=2e-4)
+
+
+def test_the_grid_visits_no_empty_group():
+    """The kernel's steps are the (row tile, group) pairs that hold a
+    row: an expert nobody was routed to is never read."""
+    sizes = jnp.asarray([0, 10, 0, 0, 30, 1, 0, 23], jnp.int32)
+    (offsets, group_ids, tiles), steps = group_metadata(
+        sizes, 64, 16, visit_empty=False)
+    steps = int(steps)
+    visited = np.asarray(group_ids)[:steps].tolist()
+    assert set(visited) == {1, 4, 5, 7}
+    # 10 rows: tile 0; 30: tiles 0-2; 1: tile 2; 23: tiles 2-3
+    assert visited == [1, 4, 4, 4, 5, 7, 7]
+    assert np.asarray(tiles)[:steps].tolist() == [0, 0, 1, 2, 2, 2, 3]
+    assert np.asarray(offsets).tolist() == [0, 0, 10, 10, 10, 40, 41, 41, 64]
+    _, steps_all = group_metadata(sizes, 64, 16, visit_empty=True)
+    assert int(steps_all) == steps + 4  # tgmm zeroes the four empty ones
+
+
+def test_tiling_fits_the_shapes():
+    assert tiling(64, 2048, 1024) == (64, 1024)    # a decode step: one tile
+    assert tiling(8192, 1024, 2048) == (256, 2048)  # a 1024-token prefill
+    assert tiling(40, 2048, 1024) == (48, 1024)    # rows padded to 16
+    assert tiling(8192, 2048, 3072) == (256, 1024)  # tn divides n
+    assert tiling(64, 128, 96) == (64, 96)         # under a lane tile
+    # an expert's [k, tn] block stays within 8 MiB
+    assert tiling(8192, 14336, 4096) == (256, 256)
+    assert tiling(8192, 4096, 14336, itemsize=4) == (256, 512)

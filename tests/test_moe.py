@@ -140,3 +140,66 @@ def test_moe_tiny_trains():
     # same init, generous capacity: trajectories should be close
     np.testing.assert_allclose(losses["capacity"][-1], losses["dense"][-1],
                                rtol=0.15)
+
+
+# What each of the three ``moe_impl`` values promises, in one place:
+# "dense" computes every expert for every token (the oracle), "dropless"
+# computes exactly the routed assignments and equals the oracle at ANY
+# load, "capacity" equals it only while no expert's buffer overflows.
+
+def _biased(p, x, expert: int):
+    """The layer's parameters and inputs with the router pushed towards
+    ``expert``: the router has no bias term, so the inputs get a common
+    component and the expert's column points along it (its logit reads
+    about 10 where the others spread by 1.4)."""
+    router = p["router"].at[:, expert].set(10.0 / x.shape[-1])
+    return {**p, "router": router}, x + 1.0
+
+
+@pytest.mark.parametrize("norm_topk_prob", [True, False])
+@pytest.mark.parametrize("load", ["even", "overloaded"])
+def test_dropless_matches_dense_at_any_load(load, norm_topk_prob):
+    """(d) identical weights, dropless against dense; with a router
+    biased so that one expert is in every token's top-2 as well."""
+    kw = dict(norm_topk_prob=norm_topk_prob, n_experts=8)
+    cfg_d = _cfg(moe_impl="dense", **kw)
+    cfg_r = _cfg(moe_impl="dropless", **kw)
+    p = _mlp_params(cfg_d, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64), jnp.float32)
+    if load == "overloaded":
+        p, x = _biased(p, x, 3)
+    np.testing.assert_allclose(
+        np.asarray(llama._moe_mlp(cfg_r, p, x)),
+        np.asarray(llama._moe_mlp(cfg_d, p, x)), atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("impl,loses_tokens", [("dropless", False),
+                                               ("capacity", True)])
+def test_an_overloaded_expert(impl, loses_tokens):
+    """(e) a router biased so that one expert is every token's first
+    choice and receives half of all the assignments (the most top-2 of
+    distinct experts allows): dropless loses no token, capacity at
+    factor 1.25 drops the rows past that expert's buffer."""
+    cfg_d = _cfg(moe_impl="dense", n_experts=8)
+    cfg = _cfg(moe_impl=impl, n_experts=8, capacity_factor=1.25)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64), jnp.float32)
+    p, x = _biased(_mlp_params(cfg_d, jax.random.PRNGKey(0)), x, 3)
+    _, ids = llama.moe_topk(cfg, p["router"], x)
+    assert float(jnp.mean(ids == 3)) == 0.5
+    y, want = llama._moe_mlp(cfg, p, x), llama._moe_mlp(cfg_d, p, x)
+    lost = np.abs(np.asarray(y - want)).max(-1) > 1e-3  # [B, T]
+    assert bool(lost.any()) == loses_tokens
+    if loses_tokens:  # capacity = ceil(2 * 32 / 8 * 1.25) = 10 of 32 rows
+        assert lost.sum() >= 2 * (32 - 10)
+
+
+def test_router_picks_exactly_top_k_and_renormalises_only_if_asked():
+    cfg = _cfg(n_experts=8, top_k=3)
+    router = jnp.zeros((64, 8))  # all experts tie: still exactly 3 kept
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 64), jnp.float32)
+    gates = llama.moe_gates(cfg, router, x)
+    assert np.asarray((gates > 0).sum(-1)).tolist() == [[3] * 5] * 2
+    np.testing.assert_allclose(np.asarray(gates.sum(-1)), 1.0, rtol=1e-6)
+    raw = llama.moe_gates(_cfg(n_experts=8, top_k=3, norm_topk_prob=False),
+                          router, x)
+    np.testing.assert_allclose(np.asarray(raw.sum(-1)), 3 / 8, rtol=1e-6)

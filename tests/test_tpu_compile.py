@@ -183,6 +183,67 @@ def test_one_row_prefill_compiles_at_internlm2_widths(topo):
     assert mem["temporaries_mib"] < 512, mem
 
 
+# ---- the dropless expert layer (OLMoE's widths) ----
+
+OLMOE = dict(vocab_size=50304, d_model=2048, n_layers=4, n_heads=16,
+             n_kv_heads=16, d_ff=1024, rope_theta=1e4, rms_eps=1e-5,
+             n_experts=64, top_k=8, norm_topk_prob=False, qk_norm=True,
+             moe_impl="dropless", max_seq_len=1296, dtype="bfloat16",
+             remat=False)
+
+
+@pytest.mark.parametrize("rows,direction", [
+    (64, "forward"), (8192, "forward"), (8192, "backward")])
+def test_grouped_matmul_compiles_at_olmoe_shapes(topo, rows, direction):
+    """A decode step's 64 assignment rows and a 1024-token prefill's
+    8192, gate (2048 -> 1024) and down (1024 -> 2048), at the tile the
+    kernel picks; backward: the transposed product and ``moe_tgmm``."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=chip)
+    for k, n in ((2048, 1024), (1024, 2048)):
+        lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=chip)
+        rhs = jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=chip)
+
+        def fwd(a, b, s):
+            return grouped_matmul(a, b, s, use_kernel=True)
+
+        fn = fwd if direction == "forward" else jax.grad(
+            lambda a, b, s: fwd(a, b, s).astype(jnp.float32).sum(), (0, 1))
+        text = jax.jit(fn).lower(lhs, rhs, sizes).compile().as_text()
+        assert text.count(KERNEL) >= (1 if direction == "forward" else 2)
+
+
+def test_olmoe_decode_chunk_reads_the_expert_stack_in_place(
+        topo, monkeypatch):
+    """The cell's decode program (4 layers, 8 slots x 1296 rows): three
+    kernel calls a layer, and no copy of a layer's experts out of the
+    stack: a scan that sliced ``[L, 64, 2048, 1024]`` for the kernel
+    copied all 64 experts in every step, read or not."""
+    import functools
+
+    from ray_tpu.ops import grouped_matmul as gm
+
+    # (the dispatch would read the CPU backend here and take ragged_dot)
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    cfg = llama.LlamaConfig(**OLMOE)
+    chip = SingleDeviceSharding(topo.devices[0])
+    params, cache, vec = _engine_args(cfg, chip, slots=8, max_len=1296)
+    compiled = de.decode_chunk.lower(
+        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        chunk=16).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 3
+    assert "bf16[64,2048,1024]" not in text
+    assert "bf16[64,1024,2048]" not in text
+    mem = _mem(compiled)
+    print(f"\nolmoe decode chunk: {mem}")
+    # f32 masters (7.5 GB) and their bf16 copies beside the cache
+    assert mem["arguments_mib"] + mem["temporaries_mib"] < 13 * 1024, mem
+
+
 # ---- the train step, on one chip and sharded over four ----
 
 
