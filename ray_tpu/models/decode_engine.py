@@ -86,78 +86,81 @@ def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
     }
 
 
-def _layer_decode_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
-                         aux: dict | None = None):
-    """One-token decode layer with PER-SLOT positions. h: [B, 1, D];
-    ck/cv: [B, S, Hkv, D]; pos: [B]. Writes each slot's k/v at its own
-    offset (scatter) and masks attention to k_pos <= pos per slot."""
-    from ray_tpu.ops.attention import _repeat_kv
-
-    b = h.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    cdt = cfg.compute_dtype
-    s = ck.shape[1]
-
-    q, k, v = llama._qkv(cfg, p, h, sin, cos)  # [B, 1, H*, hd]
-    with jax.named_scope("cache"):
-        rows = jnp.arange(b)
-        ck = ck.at[rows, pos].set(k[:, 0])
-        cv = cv.at[rows, pos].set(v[:, 0])
-
-    with jax.named_scope("attn"):
-        kk = _repeat_kv(ck, hq // hkv)
-        vv = _repeat_kv(cv, hq // hkv)
-        logits = jnp.einsum(
-            "bthd,bshd->bhts", q, kk, preferred_element_type=jnp.float32
-        ) * (hd ** -0.5)
-        k_pos = jnp.arange(s, dtype=jnp.int32)[None, :]  # [1, S]
-        live = k_pos <= pos[:, None]  # [B, S]: each slot sees its prefix
-        logits = jnp.where(live[:, None, None, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(cdt)
-        o = jnp.einsum(
-            "bhts,bshd->bthd", probs, vv,
-            preferred_element_type=jnp.float32,
-        ).astype(cdt)
-    h = llama._attn_out_and_mlp(cfg, p, h, o, aux)
-    return h, ck, cv
-
-
-def _layer_verify_ragged(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
-                         aux: dict | None = None):
-    """T-query generalization of :func:`_layer_decode_ragged` for the
-    speculative VERIFY step: h is [B, T, D] (the current token plus the
-    K drafted tokens, T == K+1) and pos [B] is each slot's base
-    position. All T k/v rows scatter at pos..pos+T-1 in one write, and
-    the mask is per-query causal (query j of slot b attends
-    k_pos <= pos[b]+j) — so the wide pass computes exactly the T
-    sequential ragged-decode steps, in one layer sweep."""
-    from ray_tpu.ops.attention import _repeat_kv
-
-    b, t, _ = h.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    cdt = cfg.compute_dtype
-    s = ck.shape[1]
-
-    q, k, v = llama._qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
-    rows = jnp.arange(b)[:, None]
-    cols = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    ck = ck.at[rows, cols].set(k)
-    cv = cv.at[rows, cols].set(v)
-
-    kk = _repeat_kv(ck, hq // hkv)
-    vv = _repeat_kv(cv, hq // hkv)
+def _attend_ragged(q, ck, cv, qpos):
+    """Attention of T query rows a slot over the cache AS STORED.
+    q: [B, T, Hq, hd]; ck/cv: [B, S, Hkv, hd]; qpos: [B, T], the position
+    of each query row; row t of slot b sees k_pos <= qpos[b, t]. The
+    query heads are grouped by the kv head they share (head
+    h = kv * group + r, the order a repeat of the kv heads would give)
+    and each group contracts against its one kv head: no repeated copy
+    of the cache is made, and the cache is read once in its own dtype.
+    Products accumulate in float32, the softmax is float32, the
+    probabilities are cast to q's dtype. Returns [B, T, Hq, hd]."""
+    b, t, hq, hd = q.shape
+    s, hkv = ck.shape[1:3]
+    qg = q.reshape(b, t, hkv, hq // hkv, hd)
     logits = jnp.einsum(
-        "bthd,bshd->bhts", q, kk, preferred_element_type=jnp.float32
+        "btkgd,bskd->bkgts", qg, ck, preferred_element_type=jnp.float32
     ) * (hd ** -0.5)
     k_pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]  # [1, 1, S]
-    live = k_pos <= cols[:, :, None]  # [B, T, S]
-    logits = jnp.where(live[:, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(cdt)
+    live = k_pos <= qpos[:, :, None]  # [B, T, S]
+    logits = jnp.where(live[:, None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     o = jnp.einsum(
-        "bhts,bshd->bthd", probs, vv, preferred_element_type=jnp.float32
-    ).astype(cdt)
-    h = llama._attn_out_and_mlp(cfg, p, h, o, aux)
-    return h, ck, cv
+        "bkgts,bskd->btkgd", probs, cv, preferred_element_type=jnp.float32
+    ).astype(q.dtype)
+    return o.reshape(b, t, hq, hd)
+
+
+def _layer_ragged(cfg: LlamaConfig, h, p, sin, cos, k, v, layer, pos,
+                  aux: dict | None = None):
+    """One layer over T rows a slot at PER-SLOT positions, on the STACKED
+    cache. h: [B, T, D] (T == 1: a decode step; T == K+1: the
+    speculative verify, the current token plus the K drafted ones);
+    k/v: [L, B, S, Hkv, hd], the whole cache; pos: [B], each slot's
+    base position. The layer writes its B x T new rows at
+    [layer, slot, pos..pos+T-1] into the stack it was given (a scatter
+    of rows: nothing else of the cache moves) and attends over
+    ``stack[layer]`` with a per-query causal mask, so a T-wide pass
+    computes exactly T sequential one-row steps in one layer sweep.
+    Returns (h, k, v), the stacks updated."""
+    b, t, _ = h.shape
+    q, k_new, v_new = llama._qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
+    with jax.named_scope("cache"):
+        rows = jnp.arange(b)[:, None]
+        cols = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        k = k.at[layer, rows, cols].set(k_new)
+        v = v.at[layer, rows, cols].set(v_new)
+    with jax.named_scope("attn"):
+        o = _attend_ragged(q, k[layer], v[layer], cols)
+    return llama._attn_out_and_mlp(cfg, p, h, o, aux), k, v
+
+
+def _layers_ragged(cfg: LlamaConfig, layers, attach, h, sin, cos, k, v,
+                   pos, active=None):
+    """The one layer loop of the chunk programs, and how the cache
+    travels through it: the stacked k and v are loop STATE beside h and
+    the layer's index, and only the layer parameters (``layers``, as
+    ``llama.split_layers`` gives them with ``attach``) are scanned. As a
+    scan's xs and ys each layer's [B, S, Hkv, hd] would be sliced out of
+    the stack and written back whole around B new rows (two copies a
+    layer and step); as state the stack stays where it lies
+    (:func:`_layer_ragged`). The loop runs as many layers as ``layers``
+    holds (the draft's: the first few). With ``active`` [B], a model
+    that reports its routing also returns ``experts_touched`` [L] (see
+    ``_experts_touched``). Returns (h, k, v, *touched)."""
+    routed = active is not None and llama.reports_routing(cfg)
+
+    def body(carry, p_):
+        h_, k_, v_, layer = carry
+        aux = {} if routed else None
+        h_, k_, v_ = _layer_ragged(
+            cfg, h_, attach(p_), sin, cos, k_, v_, layer, pos, aux)
+        return (h_, k_, v_, layer + 1), _experts_touched(cfg, aux, active)
+
+    (h, k, v, _), touched = jax.lax.scan(
+        body, (h, k, v, jnp.int32(0)), layers)
+    return h, k, v, *touched
 
 
 def _experts_touched(cfg: LlamaConfig, aux: dict | None, active) -> tuple:
@@ -240,7 +243,6 @@ def decode_chunk_sampled(params, cache, tok, active, seeds, temps,
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cdt)
     max_len = cache["k"].shape[2]
-    routed = llama.reports_routing(cfg)
     layers, attach = llama.split_layers(cfg, params["layers"])
 
     def one_step(carry, _):
@@ -249,14 +251,8 @@ def decode_chunk_sampled(params, cache, tok, active, seeds, temps,
             pos[:, None], cfg.head_dim, cfg.rope_theta)
         h = params["embed"].astype(cdt)[t[:, None]]  # [B, 1, D]
 
-        def body(h_, xs):
-            p_, ck, cv = xs
-            aux = {} if routed else None
-            h_, ck, cv = _layer_decode_ragged(
-                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
-            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
-
-        h, (k, v, *touched) = jax.lax.scan(body, h, (layers, k, v))
+        h, k, v, *touched = _layers_ragged(
+            cfg, layers, attach, h, sin, cos, k, v, pos, active)
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
         nxt, lp = _sample_from_logits(logits, seeds, pos, temps, top_ps)
@@ -280,15 +276,19 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
 
     tok: [B] current token per slot; active: [B] bool. Inactive slots
     re-write garbage at their frozen pos (invisible: their mask never
-    advances; a later prefill overwrites). Returns ([B, chunk] tokens,
-    new cache, [B] last token) and, for a model that reports its
-    routing, ``experts_touched`` [chunk, L] (see ``_experts_touched``)."""
+    advances; a later prefill overwrites). The donated cache is loop
+    state of the step loop and, inside it, of the layer loop
+    (:func:`_layers_ragged`): a step writes B rows a layer into the
+    stack and reads one layer of it; no layer's cache is sliced out and
+    written back, and none is repeated for its query group. Returns
+    ([B, chunk] tokens, new cache, [B] last token) and, for a model that
+    reports its routing, ``experts_touched`` [chunk, L] (see
+    ``_experts_touched``)."""
     cdt = cfg.compute_dtype
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cdt)
     max_len = cache["k"].shape[2]
-    routed = llama.reports_routing(cfg)
     layers, attach = llama.split_layers(cfg, params["layers"])
 
     def one_step(carry, _):
@@ -297,14 +297,8 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
             pos[:, None], cfg.head_dim, cfg.rope_theta)
         h = params["embed"].astype(cdt)[t[:, None]]  # [B, 1, D]
 
-        def body(h_, xs):
-            p_, ck, cv = xs
-            aux = {} if routed else None
-            h_, ck, cv = _layer_decode_ragged(
-                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
-            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
-
-        h, (k, v, *touched) = jax.lax.scan(body, h, (layers, k, v))
+        h, k, v, *touched = _layers_ragged(
+            cfg, layers, attach, h, sin, cos, k, v, pos, active)
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
         nxt = jnp.argmax(logits, axis=-1).astype(t.dtype)
@@ -370,7 +364,6 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     b = tok.shape[0]
     t_wide = depth + 1
     rows = jnp.arange(b)
-    routed = llama.reports_routing(cfg)
     layers, attach = llama.split_layers(cfg, params["layers"])
     # the draft scans the first layers of the same stack
     dlayers = jax.tree_util.tree_map(lambda a: a[:draft_layers], layers)
@@ -385,13 +378,8 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
                 dpos[:, None], cfg.head_dim, cfg.rope_theta)
             h = params["embed"].astype(cdt)[dt[:, None]]
 
-            def body(h_, xs):
-                p_, ck, cv = xs
-                h_, ck, cv = _layer_decode_ragged(
-                    cfg, h_, attach(p_), sin, cos, ck, cv, dpos)
-                return h_, (ck, cv)
-
-            h, (kd, vd) = jax.lax.scan(body, h, (dlayers, kd, vd))
+            h, kd, vd = _layers_ragged(
+                cfg, dlayers, attach, h, sin, cos, kd, vd, dpos)
             h = mlp.apply_draft_head(draft_head, h)
             h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
             logits = (h[:, 0] @ w_out).astype(jnp.float32)
@@ -418,14 +406,8 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
             qpos, cfg.head_dim, cfg.rope_theta)
         h = params["embed"].astype(cdt)[xs]  # [B, T, D]
 
-        def vbody(h_, xs_):
-            p_, ck, cv = xs_
-            aux = {} if routed else None
-            h_, ck, cv = _layer_verify_ragged(
-                cfg, h_, attach(p_), sin, cos, ck, cv, pos, aux)
-            return h_, (ck, cv, *_experts_touched(cfg, aux, active))
-
-        h, (k, v, *touched) = jax.lax.scan(vbody, h, (layers, k, v))
+        h, k, v, *touched = _layers_ragged(
+            cfg, layers, attach, h, sin, cos, k, v, pos, active)
         h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
         logits = (h @ w_out).astype(jnp.float32)  # [B, T, V]
         y, lp = _sample_from_logits(

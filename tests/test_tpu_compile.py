@@ -15,7 +15,9 @@ The cheap cases are tier-1; the 14-25 s programs are ``-m slow``:
     JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_compile.py -m slow -s
 """
 
+import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -164,15 +166,18 @@ def test_decode_chunk_compiles_at_1b_widths(topo):
     assert mem["arguments_mib"] + mem["temporaries_mib"] < 15 * 1024, mem
 
 
+# InternLM2-1.8B's widths, two layers deep
+INTERNLM2 = dict(vocab_size=92544, d_model=2048, n_layers=2, n_heads=16,
+                 n_kv_heads=8, d_ff=8192, rope_theta=1e6, rms_eps=1e-5,
+                 max_seq_len=1296, dtype="bfloat16", remat=False)
+
+
 def test_one_row_prefill_compiles_at_internlm2_widths(topo):
     """The benchmark's doc cell, two layers deep: one prompt in the
     1024 bucket into 8 slots of 1296 rows. The temporaries are the
     row's logits over the 92,544-wide head and its full-length k/v,
     not ``slots`` times that."""
-    cfg = llama.LlamaConfig(
-        vocab_size=92544, d_model=2048, n_layers=2, n_heads=16,
-        n_kv_heads=8, d_ff=8192, rope_theta=1e6, rms_eps=1e-5,
-        max_seq_len=1296, dtype="bfloat16", remat=False)
+    cfg = llama.LlamaConfig(**INTERNLM2)
     chip = SingleDeviceSharding(topo.devices[0])
     compiled = _lower_prefill(cfg, chip, 1024, slots=8,
                               max_len=1296).compile()
@@ -221,8 +226,6 @@ def test_olmoe_decode_chunk_reads_the_expert_stack_in_place(
     kernel calls a layer, and no copy of a layer's experts out of the
     stack: a scan that sliced ``[L, 64, 2048, 1024]`` for the kernel
     copied all 64 experts in every step, read or not."""
-    import functools
-
     from ray_tpu.ops import grouped_matmul as gm
 
     # (the dispatch would read the CPU backend here and take ragged_dot)
@@ -242,6 +245,44 @@ def test_olmoe_decode_chunk_reads_the_expert_stack_in_place(
     print(f"\nolmoe decode chunk: {mem}")
     # f32 masters (7.5 GB) and their bf16 copies beside the cache
     assert mem["arguments_mib"] + mem["temporaries_mib"] < 13 * 1024, mem
+
+
+@pytest.mark.parametrize("model", ["internlm2", "olmoe"])
+def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
+                                                     model):
+    """The doc cell's decode program (8 slots x 1296 rows, 2 layers): a
+    step writes 8 rows into the stacked cache and reads one layer of it.
+    No kv head is repeated for its query group (f32 ``[8,1296,8,2,128]``
+    broadcasts were 1.6 s of an 8 s trace), no layer's cache is written
+    back into the stack whole, the stack is never copied (OLMoE's
+    ``copy.129`` / ``.130``), and the donated cache is updated in place."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    cfg = llama.LlamaConfig(**(
+        INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}))
+    chip = SingleDeviceSharding(topo.devices[0])
+    params, cache, vec = _engine_args(cfg, chip, slots=8, max_len=1296)
+    compiled = de.decode_chunk.lower(
+        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        chunk=16).compile()
+    text = compiled.as_text()
+    if cfg.n_kv_heads < cfg.n_heads:
+        assert "[8,1296,8,2,128]" not in text
+        assert "[8,1296,16,128]" not in text
+    layer_elems = 8 * 1296 * cfg.n_kv_heads * 128
+    elems = {name: int(np.prod([int(d) for d in dims.split(",")]))
+             for name, dims in re.findall(
+                 r"%([\w.\-]+) = \w+\[([\d,]+)\]", text)}
+    for update in re.findall(
+            r" dynamic-update-slice\(%[\w.\-]+, %([\w.\-]+),", text):
+        assert elems.get(update, 0) < layer_elems, update
+    assert not re.search(
+        rf"= \w+\[{cfg.n_layers},8,1296,[\d,]+\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * cfg.n_layers * layer_elems * 2  # k and v, bf16
+    assert mem.alias_size_in_bytes >= cache_bytes, _mem(compiled)
 
 
 # ---- the train step, on one chip and sharded over four ----
