@@ -227,81 +227,70 @@ def _sample_from_logits(logits, seeds, pos, temps, top_ps):
     return jax.vmap(one)(keys, logits, temps, top_ps)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "chunk"),
-                   donate_argnames=("cache", "tok"))
-def decode_chunk_sampled(params, cache, tok, active, seeds, temps,
-                         top_ps, cfg: LlamaConfig, chunk: int):
-    """`decode_chunk` with per-slot sampling lanes and per-token
-    logprobs. seeds [B] uint32 / temps [B] / top_ps [B] ride alongside
-    the slot batch; a slot with temperature 0 decodes greedily
-    (bit-identical tokens to `decode_chunk`). Returns
-    ([B, chunk] tokens, [B, chunk] f32 logprobs, new cache, [B] last)
-    and, for a model that reports its routing, ``experts_touched``
-    [chunk, L] (see ``_experts_touched``)."""
-    cdt = cfg.compute_dtype
+def _split_model(cfg: LlamaConfig, params):
+    """What a chunk program prepares once: the layers as the layer loop
+    scans them (``llama.split_layers``: (layers, attach)) and the
+    unembedding in the compute dtype."""
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cdt)
-    max_len = cache["k"].shape[2]
-    layers, attach = llama.split_layers(cfg, params["layers"])
+    ).astype(cfg.compute_dtype)
+    return *llama.split_layers(cfg, params["layers"]), w_out
 
-    def one_step(carry, _):
-        t, k, v, pos = carry
-        sin, cos = llama.rotary_embedding(
-            pos[:, None], cfg.head_dim, cfg.rope_theta)
-        h = params["embed"].astype(cdt)[t[:, None]]  # [B, 1, D]
 
-        h, k, v, *touched = _layers_ragged(
-            cfg, layers, attach, h, sin, cos, k, v, pos, active)
-        h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-        logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
-        nxt, lp = _sample_from_logits(logits, seeds, pos, temps, top_ps)
-        nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
-        # pos clamp: see decode_chunk
-        pos = jnp.minimum(pos + active.astype(pos.dtype), max_len - 1)
-        return (nxt, k, v, pos), (nxt, lp, *touched)
-
-    (last, k, v, pos), (toks, lps, *touched) = jax.lax.scan(
-        one_step, (tok, cache["k"], cache["v"], cache["pos"]),
-        None, length=chunk)
-    return (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(lps, 0, 1),
-            {"k": k, "v": v, "pos": pos}, last, *touched)
+def _step_logits(cfg: LlamaConfig, params, layers, attach, w_out, toks, k,
+                 v, pos, qpos, active=None, before_norm=None):
+    """The one model step of the chunk programs: T tokens a slot through
+    ``layers`` (all of them, or the draft's first few) on the stacked
+    cache. toks: [B, T] at positions qpos [B, T], where
+    qpos[:, 0] == pos [B], the slots' base positions; ``before_norm``
+    is applied between the layers and the final norm (the draft's
+    adapter head). Returns (float32 logits [B, T, V], k, v, *touched):
+    see :func:`_layers_ragged` for the stacks and ``active``."""
+    sin, cos = llama.rotary_embedding(qpos, cfg.head_dim, cfg.rope_theta)
+    h = params["embed"].astype(cfg.compute_dtype)[toks]  # [B, T, D]
+    h, k, v, *touched = _layers_ragged(
+        cfg, layers, attach, h, sin, cos, k, v, pos, active)
+    if before_norm is not None:
+        h = before_norm(h)
+    h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
+    return (h @ w_out).astype(jnp.float32), k, v, *touched
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "chunk"),
                    donate_argnames=("cache", "tok"))
-def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
+def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
                  chunk: int):
-    """Advance every ACTIVE slot `chunk` greedy tokens inside one jit.
+    """Advance every ACTIVE slot `chunk` tokens inside one jit.
 
-    tok: [B] current token per slot; active: [B] bool. Inactive slots
-    re-write garbage at their frozen pos (invisible: their mask never
-    advances; a later prefill overwrites). The donated cache is loop
-    state of the step loop and, inside it, of the layer loop
+    tok: [B] current token per slot; active: [B] bool. ``lanes`` is
+    ``None``, the greedy program (argmax; no per-token sort or
+    log-softmax, no logprob output), or the per-slot sampling lanes
+    ``(seeds [B] uint32, temps [B], top_ps [B])``: a slot with
+    temperature 0 then decodes greedily too, with bit-identical tokens,
+    and every token carries its logprob. Inactive slots re-write
+    garbage at their frozen pos (invisible: their mask never advances;
+    a later prefill overwrites). The donated cache is loop state of the
+    step loop and, inside it, of the layer loop
     (:func:`_layers_ragged`): a step writes B rows a layer into the
     stack and reads one layer of it; no layer's cache is sliced out and
     written back, and none is repeated for its query group. Returns
-    ([B, chunk] tokens, new cache, [B] last token) and, for a model that
-    reports its routing, ``experts_touched`` [chunk, L] (see
-    ``_experts_touched``)."""
-    cdt = cfg.compute_dtype
-    w_out = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cdt)
+    ([B, chunk] tokens, [B, chunk] f32 logprobs or without lanes
+    ``None``, new cache, [B] last token) and, for a model that reports
+    its routing, ``experts_touched`` [chunk, L] (``_experts_touched``)."""
     max_len = cache["k"].shape[2]
-    layers, attach = llama.split_layers(cfg, params["layers"])
+    layers, attach, w_out = _split_model(cfg, params)
 
     def one_step(carry, _):
         t, k, v, pos = carry
-        sin, cos = llama.rotary_embedding(
-            pos[:, None], cfg.head_dim, cfg.rope_theta)
-        h = params["embed"].astype(cdt)[t[:, None]]  # [B, 1, D]
-
-        h, k, v, *touched = _layers_ragged(
-            cfg, layers, attach, h, sin, cos, k, v, pos, active)
-        h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-        logits = (h[:, 0] @ w_out).astype(jnp.float32)  # [B, V]
-        nxt = jnp.argmax(logits, axis=-1).astype(t.dtype)
+        logits, k, v, *touched = _step_logits(
+            cfg, params, layers, attach, w_out, t[:, None], k, v, pos,
+            pos[:, None], active)
+        logits = logits[:, 0]  # [B, V]
+        if lanes is None:
+            nxt, lp = jnp.argmax(logits, axis=-1).astype(t.dtype), None
+        else:
+            seeds, temps, top_ps = lanes
+            nxt, lp = _sample_from_logits(logits, seeds, pos, temps, top_ps)
         nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
         # clamp: a slot that exhausts its cache rows mid-chunk (pump()
         # only frees slots at chunk boundaries) must keep scattering
@@ -310,13 +299,14 @@ def decode_chunk(params, cache, tok, active, cfg: LlamaConfig,
         # the cache and pump()'s pos >= max_len-1 finish check stays
         # exact instead of relying on overflow
         pos = jnp.minimum(pos + active.astype(pos.dtype), max_len - 1)
-        return (nxt, k, v, pos), (nxt, *touched)
+        return (nxt, k, v, pos), (nxt, lp, touched)
 
-    (last, k, v, pos), (toks, *touched) = jax.lax.scan(
+    (last, k, v, pos), (toks, lps, touched) = jax.lax.scan(
         one_step, (tok, cache["k"], cache["v"], cache["pos"]),
         None, length=chunk)
-    return (jnp.moveaxis(toks, 0, 1), {"k": k, "v": v, "pos": pos}, last,
-            *touched)
+    return (jnp.moveaxis(toks, 0, 1),
+            None if lanes is None else jnp.moveaxis(lps, 0, 1),
+            {"k": k, "v": v, "pos": pos}, last, *touched)
 
 
 @functools.partial(jax.jit,
@@ -331,14 +321,15 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     pump, like `decode_chunk`, but each round can emit up to K+1 tokens
     per slot.
 
-    The draft is the target's own first `draft_layers` layers (a
-    shared-trunk weight view — llama.draft_params semantics — plus an
-    optional residual adapter head, mlp.apply_draft_head). Because the
-    trunk layers ARE the target's, the draft reads the target's ragged
-    cache rows directly; the k/v rows it writes for drafted positions
-    are kept in a private carry and DISCARDED — the verify re-writes
-    every layer's rows at pos..pos+K itself before attending, so draft
-    state never leaks into the persistent cache.
+    The draft is the target's own first `draft_layers` layers under the
+    target's embedding, final norm and head (a view of the same
+    weights) plus an optional residual adapter head,
+    mlp.apply_draft_head. Because the trunk layers ARE the target's,
+    the draft reads the target's ragged cache rows directly; the k/v
+    rows it writes for drafted positions are kept in a private carry
+    and DISCARDED — the verify re-writes every layer's rows at
+    pos..pos+K itself before attending, so draft state never leaks into
+    the persistent cache.
 
     The verify computes the target's OWN token y_j at every position
     via the same (seed, position) RNG lanes as the non-speculative
@@ -356,15 +347,11 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     counts [B, rounds] — tokens emitted per round (0 for inactive
     slots), new cache, [B] last token) and, for a model that reports
     its routing, the verify passes' ``experts_touched`` [rounds, L]."""
-    cdt = cfg.compute_dtype
-    w_out = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cdt)
     max_len = cache["k"].shape[2]
     b = tok.shape[0]
     t_wide = depth + 1
     rows = jnp.arange(b)
-    layers, attach = llama.split_layers(cfg, params["layers"])
+    layers, attach, w_out = _split_model(cfg, params)
     # the draft scans the first layers of the same stack
     dlayers = jax.tree_util.tree_map(lambda a: a[:draft_layers], layers)
 
@@ -374,21 +361,16 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         # -- draft: K sequential 1-wide steps over the trunk layers --
         def draft_step(dc, _):
             dt, kd, vd, dpos = dc
-            sin, cos = llama.rotary_embedding(
-                dpos[:, None], cfg.head_dim, cfg.rope_theta)
-            h = params["embed"].astype(cdt)[dt[:, None]]
-
-            h, kd, vd = _layers_ragged(
-                cfg, dlayers, attach, h, sin, cos, kd, vd, dpos)
-            h = mlp.apply_draft_head(draft_head, h)
-            h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-            logits = (h[:, 0] @ w_out).astype(jnp.float32)
+            logits, kd, vd = _step_logits(
+                cfg, params, dlayers, attach, w_out, dt[:, None], kd, vd,
+                dpos, dpos[:, None],
+                before_norm=lambda h: mlp.apply_draft_head(draft_head, h))
             # the proposal for position dpos+1 rides lane dpos — the
             # SAME lane the verify uses for its token at dpos+1's
             # predecessor, so under sampling the draft and target draw
             # with shared Gumbel noise (agreement is higher than the
             # argmax overlap of their distributions)
-            d, _ = _sample_from_logits(logits, seeds, dpos, temps,
+            d, _ = _sample_from_logits(logits[:, 0], seeds, dpos, temps,
                                        top_ps)
             dpos = jnp.minimum(dpos + 1, max_len - 1)
             return (d, kd, vd, dpos), d
@@ -402,14 +384,9 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         # -- verify: ONE wide forward over the K+1 positions --
         xs = jnp.concatenate([t[:, None], drafts], axis=1)  # [B, T]
         qpos = pos[:, None] + jnp.arange(t_wide, dtype=jnp.int32)
-        sin, cos = llama.rotary_embedding(
-            qpos, cfg.head_dim, cfg.rope_theta)
-        h = params["embed"].astype(cdt)[xs]  # [B, T, D]
-
-        h, k, v, *touched = _layers_ragged(
-            cfg, layers, attach, h, sin, cos, k, v, pos, active)
-        h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-        logits = (h @ w_out).astype(jnp.float32)  # [B, T, V]
+        logits, k, v, *touched = _step_logits(
+            cfg, params, layers, attach, w_out, xs, k, v, pos, qpos,
+            active)
         y, lp = _sample_from_logits(
             logits.reshape(b * t_wide, -1),
             jnp.repeat(seeds, t_wide), qpos.reshape(-1),
@@ -433,28 +410,59 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
             last, *touched)
 
 
+def _prefill_core(params, prompts, true_lens, seeds, temps, top_ps,
+                  cfg: LlamaConfig, slot_len: int, prefix=None):
+    """The one prefill: [F, P] RIGHT-padded tokens (one shared bucket P,
+    ``true_lens`` [F] of them real) through a temporary cache of
+    ``slot_len`` rows a stream. ``prefix`` is ``None`` (a fresh cache:
+    the tokens are whole prompts) or ``(k, v, n_prefix)``: rows
+    [L, F, S, Hkv, D] filled up to the scalar ``n_prefix`` and zero
+    beyond, behind which the tokens (the prompts' suffixes) are written.
+    The first token comes from the TRUE last prompt position on the
+    (seed, position) lane of the chunk programs (seeds/temps/top_ps [F];
+    temperature 0 = greedy), so a failover replay reproduces it
+    whichever prefill path (inline, suffix, disaggregated) the
+    replacement replica takes. Returns (k, v [L, F, S, Hkv, D], [F]
+    whole prompt lengths, [F] first tokens, [F] their logprobs) and,
+    for a model that reports its routing, ``expert_tokens`` [L, E].
+
+    Right-padding is safe without a pad mask: causal attention means
+    real tokens (a prefix) never see the pad garbage, and each later
+    decode step overwrites a pad cache row at its position before the
+    growing per-slot mask can expose it."""
+    f = prompts.shape[0]
+    if prefix is None:
+        tmp, full_lens = llama.init_cache(cfg, f, slot_len), true_lens
+    else:
+        k, v, n_prefix = prefix
+        tmp = {"k": k, "v": v, "pos": n_prefix}
+        full_lens = n_prefix + true_lens
+    aux = {}
+    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp, aux)
+    last_logits = logits[jnp.arange(f), true_lens - 1].astype(jnp.float32)
+    toks0, logp0 = _sample_from_logits(
+        last_logits, seeds, full_lens - 1, temps, top_ps)
+    return (tmp["k"], tmp["v"], full_lens, toks0, logp0,
+            *_expert_tokens(cfg, aux, true_lens))
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache", "cur_tok"))
 def _prefill_batch_into_slots(params, prompts, true_lens, slots,
                               seeds, temps, top_ps,
-                              cache, cur_tok, cfg: LlamaConfig):
-    """Prefill streams ([F, P] RIGHT-padded tokens, one shared bucket P)
-    into their slots of the shared ragged cache: prefill, k/v scatters,
-    pos and first-token updates in ONE dispatch. The engine calls it
-    with F = 1, one prompt a call, so one program per bucket: at
-    F = `slots` the padding rows were a third to a half of the device's
-    time (PERF.md, PR 25). `slots` [F] are in-range slot indices.
-    seeds/temps/top_ps [F] are the per-stream sampling lanes
-    (temperature 0 = greedy). Returns (new cache, new cur_tok,
-    [F] first tokens, [F] first-token logprobs) and, for a model that
-    reports its routing (``llama.reports_routing``), ``expert_tokens``
-    [L, E] (see ``_expert_tokens``).
-
-    Right-padding is safe without a pad mask: causal attention means
-    real tokens (a prefix) never see the pad garbage, the first token
-    samples from the TRUE last prompt position, and each later decode
-    step overwrites a pad cache row at its position before the growing
-    per-slot mask can expose it.
+                              cache, cur_tok, cfg: LlamaConfig,
+                              prefix=None):
+    """Prefill streams (:func:`_prefill_core`) into their slots of the
+    shared ragged cache: prefill, k/v scatters, pos and first-token
+    updates in ONE dispatch. The engine calls it with F = 1, one prompt
+    a call, so one program per bucket: at F = `slots` the padding rows
+    were a third to a half of the device's time (PERF.md, PR 25).
+    `slots` [F] are in-range slot indices. With ``prefix`` (the
+    prefix-cache warm path) ``prompts`` hold the suffixes behind the
+    cached rows: row independence + exact softmax masking make the
+    result identical to a cold prefill of the whole prompt
+    (kv_prefix_cache.py docstring). Returns (new cache, new cur_tok,
+    [F] first tokens, [F] first-token logprobs, *expert_tokens).
 
     FULL-SLOT-OVERWRITE ASSUMPTION: correctness of slot reuse depends on
     this scatter replacing ALL max_len cache rows of the slot (tmp is a
@@ -463,26 +471,17 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     prompt, and the new stream's growing mask — or a clamped write at
     row max_len-1 from a slot that decoded to the cache edge — would
     eventually attend over stale tokens."""
-    f = prompts.shape[0]
-    slot_len = cache["k"].shape[2]
-    tmp = llama.init_cache(cfg, f, slot_len)
-    aux = {}
-    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp, aux)
-    last_logits = logits[jnp.arange(f), true_lens - 1].astype(jnp.float32)
-    # the first token is emitted from position true_len-1 — the same
-    # (seed, position) RNG lane scheme as decode_chunk_sampled, so a
-    # failover replay reproduces it regardless of which prefill path
-    # (inline, suffix, disaggregated) the replacement replica takes
-    toks0, logp0 = _sample_from_logits(
-        last_logits, seeds, true_lens - 1, temps, top_ps)
-    # tmp k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
+    k, v, full_lens, toks0, logp0, *expert_tokens = _prefill_core(
+        params, prompts, true_lens, seeds, temps, top_ps, cfg,
+        cache["k"].shape[2], prefix)
+    # k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
     cache = {
-        "k": cache["k"].at[:, slots].set(tmp["k"]),
-        "v": cache["v"].at[:, slots].set(tmp["v"]),
-        "pos": cache["pos"].at[slots].set(true_lens),
+        "k": cache["k"].at[:, slots].set(k),
+        "v": cache["v"].at[:, slots].set(v),
+        "pos": cache["pos"].at[slots].set(full_lens),
     }
     return (cache, cur_tok.at[slots].set(toks0), toks0, logp0,
-            *_expert_tokens(cfg, aux, true_lens))
+            *expert_tokens)
 
 
 def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
@@ -498,41 +497,19 @@ def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
-def prefill_kv(params, prompts, true_lens, cfg: LlamaConfig,
-               slot_len: int):
-    """Prefill WITHOUT a slot: run [F, P] right-padded prompts through a
-    fresh slot_len cache and return the raw KV rows + first greedy
-    tokens ((k, v) [L, F, S, Hkv, D], toks0 [F]). This is the dedicated
-    prefill worker's op (serve/llm_pool.py): the rows travel through the
-    object store and a decode replica adopts them into a slot with
-    `RaggedDecoder.submit_prefilled` — same math as
-    `_prefill_batch_into_slots` (init_cache + forward_with_cache), so
-    the adopted stream's greedy continuation is identical to an
-    inline-prefilled one."""
-    f = prompts.shape[0]
-    tmp = llama.init_cache(cfg, f, slot_len)
-    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp)
-    toks0 = jnp.argmax(
-        logits[jnp.arange(f), true_lens - 1], axis=-1).astype(jnp.int32)
-    return tmp["k"], tmp["v"], toks0
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
-def prefill_kv_sampled(params, prompts, true_lens, seeds, temps,
-                       top_ps, cfg: LlamaConfig, slot_len: int):
-    """:func:`prefill_kv` with the sampling lanes: the first token comes
-    from the same (seed, position true_len-1) RNG lane as an inline
-    prefill, and its behavior logprob rides the payload — so a
-    disaggregated-prefill stream is bit-identical to an inline one under
-    sampling too. Returns ((k, v) [L, F, S, Hkv, D], toks0 [F],
-    logp0 [F])."""
-    f = prompts.shape[0]
-    tmp = llama.init_cache(cfg, f, slot_len)
-    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp)
-    last_logits = logits[jnp.arange(f), true_lens - 1].astype(jnp.float32)
-    toks0, logp0 = _sample_from_logits(
-        last_logits, seeds, true_lens - 1, temps, top_ps)
-    return tmp["k"], tmp["v"], toks0, logp0
+def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
+               cfg: LlamaConfig, slot_len: int):
+    """Prefill WITHOUT a slot (:func:`_prefill_core` on a fresh cache):
+    the raw KV rows, the first tokens and their behavior logprobs
+    ((k, v) [L, F, S, Hkv, D], toks0 [F], logp0 [F]). This is the
+    dedicated prefill worker's op (serve/llm_pool.py): the rows travel
+    through the object store and a decode replica adopts them into a
+    slot with `RaggedDecoder.submit_prefilled` — the same prefill and
+    lane as an inline one, so the adopted stream is bit-identical to an
+    inline-prefilled one, greedy or sampled."""
+    k, v, _, toks0, logp0, *_ = _prefill_core(
+        params, prompts, true_lens, seeds, temps, top_ps, cfg, slot_len)
+    return k, v, toks0, logp0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -548,36 +525,6 @@ def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
         "pos": cache["pos"].at[slot].set(true_len),
     }
     return cache, cur_tok.at[slot].set(tok0)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",),
-                   donate_argnames=("cache", "cur_tok"))
-def _prefill_suffix_into_slot(params, pref_k, pref_v, n_prefix, suffix,
-                              suffix_len, seed, temp, top_p, slot,
-                              cache, cur_tok, cfg: LlamaConfig):
-    """Prefix-cache warm path: seed a temp cache with the cached prefix
-    rows (pref_k/v: [L, S, Hkv, D] zero-padded to the slot length),
-    prefill only the suffix ([SB] right-padded static bucket) at
-    pos=n_prefix, then full-slot-scatter into `slot`. Row independence
-    + exact softmax masking make the result identical to a cold full
-    prefill of the whole prompt (kv_prefix_cache.py docstring); the
-    first token rides the (seed, true_len-1) sampling lane so warm and
-    cold admission sample identically too."""
-    tmp = {"k": pref_k[:, None], "v": pref_v[:, None], "pos": n_prefix}
-    logits, tmp = llama.forward_with_cache(
-        params, suffix[None, :], cfg, tmp)
-    true_len = n_prefix + suffix_len
-    last_logits = logits[0, suffix_len - 1].astype(jnp.float32)
-    tok0, logp0 = _sample_from_logits(
-        last_logits[None], seed[None], (true_len - 1)[None],
-        temp[None], top_p[None])
-    tok0, logp0 = tok0[0], logp0[0]
-    cache = {
-        "k": cache["k"].at[:, slot].set(tmp["k"][:, 0]),
-        "v": cache["v"].at[:, slot].set(tmp["v"][:, 0]),
-        "pos": cache["pos"].at[slot].set(true_len),
-    }
-    return cache, cur_tok.at[slot].set(tok0), tok0, logp0
 
 
 # Birth stamps a request may carry, in the order they are taken, and the
@@ -681,8 +628,8 @@ class RaggedDecoder:
         self._slot_temp = np.zeros((slots,), np.float32)
         self._slot_topp = np.ones((slots,), np.float32)
         # sticky: flips at the first sampled submit and stays — a
-        # greedy-only engine (the serving default) keeps the legacy
-        # argmax kernel (no per-token argsort/log_softmax cost, token
+        # greedy-only engine (the serving default) hands decode_chunk
+        # no lanes (no per-token argsort/log_softmax cost, token
         # logprobs reported as 0.0); after any sampled request the
         # engine pays for exact logprobs on every stream
         self._sampling_seen = False
@@ -695,12 +642,12 @@ class RaggedDecoder:
         self.prefill_calls = 0
         # routing counters of a mixture-of-experts model (monotonic
         # totals; both stay 0 for a model that reports no routing):
-        # (token, expert) assignments of the prompts' real positions in
-        # cold prefills, and experts touched summed over decode steps
-        # and layers
+        # (token, expert) assignments of the real positions the prefill
+        # program ran (whole prompts, and a warm admission's suffix),
+        # and experts touched summed over decode steps and layers
         self.moe_assignments = 0
         self.moe_touched_expert_steps = 0
-        # [L, E] device counts of the cold prefills since the last
+        # [L, E] device counts of the prefill calls since the last
         # read-back, fetched with it
         self._pending_expert_tokens: list = []
         self.slot_stream: list[_Stream | None] = [None] * slots
@@ -745,29 +692,8 @@ class RaggedDecoder:
         greedy decode; > 0 samples on the stream's (seed, position)
         RNG lane with nucleus (top-p) filtering. ``stamps``: the
         request's birth stamps, for the first-token span's split."""
-        prompt = np.asarray(prompt_tokens, np.int32)
-        self._bucket(len(prompt))  # raises if no bucket fits
-        # clamp generation to the slot's cache capacity: past max_len
-        # the k/v scatters drop and tokens would come from a silently
-        # truncated attention window
-        room = self.max_len - len(prompt) - 1
-        if room < 1:
-            raise ValueError(
-                f"prompt of {len(prompt)} tokens leaves no decode room "
-                f"in a max_len={self.max_len} cache")
-        if not 0.0 < float(top_p) <= 1.0:
-            raise ValueError(f"top_p must be in (0, 1], got {top_p}")
-        if float(temperature) > 0.0:
-            self._sampling_seen = True
-        s = _Stream(self._next_sid, prompt, min(max_new, room),
-                    submitted=time.monotonic(),
-                    temperature=float(temperature), top_p=float(top_p),
-                    seed=int(seed) & 0xFFFFFFFF, tenant=str(tenant),
-                    trace=_caller_trace(), stamps=stamps)
-        self._next_sid += 1
-        self.queue.append(s)
-        self._by_sid[s.sid] = s
-        return s.sid
+        return self._enqueue(prompt_tokens, max_new, None, temperature,
+                             top_p, seed, tenant, stamps)
 
     def submit_prefilled(self, prompt_tokens, max_new: int,
                          kv: dict, *, temperature: float = 0.0,
@@ -779,24 +705,38 @@ class RaggedDecoder:
         {"k"/"v": [n_layers, S, n_kv_heads, head_dim] with S == this
         engine's max_len, "first_token": int, "true_len": int}.
         Admission is a pure slot scatter — no prefill dispatch."""
-        prompt = np.asarray(prompt_tokens, np.int32)
         k = np.asarray(kv["k"])
         if k.shape[1] != self.max_len:
             raise ValueError(
                 f"prefilled KV has {k.shape[1]} rows; this engine's "
                 f"slots hold {self.max_len} (prefill and decode pools "
                 f"must agree on max_len)")
-        if int(kv["true_len"]) != len(prompt):
+        if int(kv["true_len"]) != len(prompt_tokens):
             raise ValueError("prefilled true_len != prompt length")
+        prefilled = {"k": k, "v": np.asarray(kv["v"]),
+                     "first_token": int(kv["first_token"]),
+                     "first_logprob": float(kv.get("first_logprob", 0.0))}
+        return self._enqueue(prompt_tokens, max_new, prefilled,
+                             temperature, top_p, seed, tenant, stamps)
+
+    def _enqueue(self, prompt_tokens, max_new: int, prefilled, temperature,
+                 top_p, seed, tenant, stamps) -> int:
+        """The one way in: validate, build the stream, queue it."""
+        prompt = np.asarray(prompt_tokens, np.int32)
+        if prefilled is None:
+            self._bucket(len(prompt))  # raises if no bucket fits
+        # clamp generation to the slot's cache capacity: past max_len
+        # the k/v scatters drop and tokens would come from a silently
+        # truncated attention window
         room = self.max_len - len(prompt) - 1
         if room < 1:
             raise ValueError(
                 f"prompt of {len(prompt)} tokens leaves no decode room "
                 f"in a max_len={self.max_len} cache")
         if not 0.0 < float(top_p) <= 1.0:
-            # same submit-time guard as submit(): an out-of-range top_p
-            # reaching the kernel filters EVERY logit to -inf (NaN
-            # logprobs, arbitrary tokens) instead of failing loudly
+            # an out-of-range top_p reaching the kernel filters EVERY
+            # logit to -inf (NaN logprobs, arbitrary tokens) instead of
+            # failing loudly
             raise ValueError(f"top_p must be in (0, 1], got {top_p}")
         if float(temperature) > 0.0:
             self._sampling_seen = True
@@ -805,10 +745,7 @@ class RaggedDecoder:
                     temperature=float(temperature), top_p=float(top_p),
                     seed=int(seed) & 0xFFFFFFFF, tenant=str(tenant),
                     trace=_caller_trace(), stamps=stamps,
-                    prefilled={"k": k, "v": np.asarray(kv["v"]),
-                               "first_token": int(kv["first_token"]),
-                               "first_logprob":
-                                   float(kv.get("first_logprob", 0.0))})
+                    prefilled=prefilled)
         self._next_sid += 1
         self.queue.append(s)
         self._by_sid[s.sid] = s
@@ -924,16 +861,24 @@ class RaggedDecoder:
         self.prefill_calls += 1
         with _fr.span("serve", "engine.prefill", flush=False, attrs={
                 "bucket": pb, "prompts": 1, "rows": 1, "tokens": n}):
-            prompt = np.zeros((1, pb), np.int32)
-            prompt[0, :n] = s.prompt  # right-pad
-            (self.cache, self.cur_tok, tok0, logp0,
-             *expert_tokens) = _prefill_batch_into_slots(
-                self.params, prompt, np.array([n], np.int32),
-                np.array([slot], np.int32),
-                np.array([s.seed], np.uint32),
-                np.array([s.temperature], np.float32),
-                np.array([s.top_p], np.float32),
-                self.cache, self.cur_tok, self.cfg)
+            self._prefill_into_slot(slot, s, s.prompt, pb)
+
+    def _prefill_into_slot(self, slot: int, s: _Stream, tokens, width: int,
+                           prefix=None) -> None:
+        """``_prefill_batch_into_slots`` for one stream: ``tokens`` (its
+        prompt, or with ``prefix`` the suffix after the cached rows)
+        right-padded to one row of ``width``."""
+        n = len(tokens)
+        row = np.zeros((1, width), np.int32)
+        row[0, :n] = tokens
+        (self.cache, self.cur_tok, tok0, logp0,
+         *expert_tokens) = _prefill_batch_into_slots(
+            self.params, row, np.array([n], np.int32),
+            np.array([slot], np.int32),
+            np.array([s.seed], np.uint32),
+            np.array([s.temperature], np.float32),
+            np.array([s.top_p], np.float32),
+            self.cache, self.cur_tok, self.cfg, prefix)
         # NO host sync here: first tokens ride the next chunk's
         # single device_get (a per-admission sync would stall the
         # host until the prefill finished)
@@ -969,23 +914,16 @@ class RaggedDecoder:
             # the static suffix write window would clamp into the prefix
             pc.record_outcome(False)
             return False
+        # the cached rows, zero-padded to one stream's full slot
         pad_k = np.zeros(
-            (self.cfg.n_layers, self.max_len, self.cfg.n_kv_heads,
+            (self.cfg.n_layers, 1, self.max_len, self.cfg.n_kv_heads,
              self.cfg.head_dim), dtype=entry["k"].dtype)
         pad_v = np.zeros_like(pad_k)
-        pad_k[:, :n_pref] = entry["k"][:, :n_pref]
-        pad_v[:, :n_pref] = entry["v"][:, :n_pref]
-        suf = np.zeros((sb,), np.int32)
-        suf[:len(suffix)] = suffix
-        self.cache, self.cur_tok, tok0, logp0 = _prefill_suffix_into_slot(
-            self.params, jnp.asarray(pad_k, self.cfg.compute_dtype),
-            jnp.asarray(pad_v, self.cfg.compute_dtype),
-            np.int32(n_pref), jnp.asarray(suf),
-            np.int32(len(suffix)), np.uint32(s.seed),
-            np.float32(s.temperature), np.float32(s.top_p),
-            np.int32(slot), self.cache, self.cur_tok, self.cfg)
-        self._pending_first.append((s, tok0, logp0))
-        self.slot_stream[slot] = s
+        pad_k[:, 0, :n_pref] = entry["k"][:, :n_pref]
+        pad_v[:, 0, :n_pref] = entry["v"][:, :n_pref]
+        self._prefill_into_slot(slot, s, suffix, sb, prefix=(
+            jnp.asarray(pad_k, self.cfg.compute_dtype),
+            jnp.asarray(pad_v, self.cfg.compute_dtype), np.int32(n_pref)))
         s.bucket = sb
         pc.record_outcome(True)  # cached rows actually served
         return True
@@ -1031,73 +969,79 @@ class RaggedDecoder:
         with _fr.span("serve", "engine.decode_dispatch", flush=False,
                       attrs={"active": n_active, "chunk": self.chunk,
                              "depth": 0}):
-            if self._sampling_seen:
-                toks, lps, self.cache, self.cur_tok, *touched = \
-                    decode_chunk_sampled(
-                        self.params, self.cache, self.cur_tok,
-                        active_mask, jnp.asarray(self._slot_seed),
-                        jnp.asarray(self._slot_temp),
-                        jnp.asarray(self._slot_topp), self.cfg,
-                        self.chunk)
-            else:
-                # greedy-only engine: the legacy argmax kernel — no
-                # per-token argsort/softmax; logprobs placeholder 0.0
-                toks, self.cache, self.cur_tok, *touched = decode_chunk(
-                    self.params, self.cache, self.cur_tok, active_mask,
-                    self.cfg, self.chunk)
-                lps = None
-        firsts = self._take_pending_first()
-        toks, lps, pos_np, first_toks, first_lps = self._readback(
-            (toks, lps, self.cache["pos"]), firsts, touched)
-        if lps is None:
-            lps = np.zeros((self.slots, self.chunk), np.float32)
-        t_now = time.monotonic()
-        with _fr.span("serve", "engine.deliver", flush=False) as sp:
-            delivered = self._deliver_firsts(
-                firsts, first_toks, first_lps, t_now)
-            finished = 0
-            for slot, s in enumerate(self.slot_stream):
-                if s is None:
-                    continue
-                take = min(self.chunk, s.max_new - len(s.tokens))
-                finished += self._deliver(
-                    slot, s, [int(t) for t in toks[slot, :take]],
-                    [float(p) for p in lps[slot, :take]], t_now,
-                    int(pos_np[slot]))
-                delivered += take
-            sp.update(delivered=delivered, firsts=len(firsts),
-                      finished=finished)
-        self._account(t_now, delivered)
+            toks, lps, self.cache, self.cur_tok, *touched = decode_chunk(
+                self.params, self.cache, self.cur_tok, active_mask,
+                self._lanes() if self._sampling_seen else None,
+                self.cfg, self.chunk)
+        toks, lps, pos_np, firsts = self._readback((toks, lps), touched)
+        self._account(*self._deliver_chunk(firsts, pos_np, {
+            slot: (toks[slot].tolist(),
+                   None if lps is None else lps[slot].tolist())
+            for slot, s in enumerate(self.slot_stream) if s is not None}))
         return n_active
 
     # -- one chunk's host side, shared by the plain and the speculative
     # pump --
 
-    def _take_pending_first(self) -> list:
-        firsts, self._pending_first = self._pending_first, []
-        return firsts
+    def _lanes(self) -> tuple:
+        return (jnp.asarray(self._slot_seed), jnp.asarray(self._slot_temp),
+                jnp.asarray(self._slot_topp))
 
-    def _readback(self, chunk_out: tuple, firsts: list,
-                  touched: list) -> tuple:
-        """The chunk's ONE device→host sync: ``chunk_out`` plus the first
-        tokens and logprobs of the streams prefilled before it and, for
-        a model that reports its routing, the chunk's
-        ``experts_touched`` and the prefills' ``expert_tokens``."""
+    def _readback(self, chunk_out: tuple, touched: list) -> tuple:
+        """The chunk's ONE device→host sync: ``chunk_out`` and each
+        slot's pos, plus the first tokens and logprobs of the streams
+        prefilled before it and, for a model that reports its routing,
+        the chunk's ``experts_touched`` and the prefills'
+        ``expert_tokens``. Returns (*chunk_out, pos [B], firsts) on the
+        host, firsts as [(stream, token, logprob)]."""
+        firsts, self._pending_first = self._pending_first, []
         loads, self._pending_expert_tokens = self._pending_expert_tokens, []
         with _fr.span("serve", "engine.readback", flush=False) as sp:
             if self.chunk_delay_s:
                 time.sleep(self.chunk_delay_s)  # see __init__: emulated
                 # device time (GIL released; replicas overlap)
             *out, first_toks, first_lps, touched, loads = jax.device_get(
-                (*chunk_out, [t for _, t, _ in firsts],
+                (*chunk_out, self.cache["pos"], [t for _, t, _ in firsts],
                  [lp for _, _, lp in firsts], touched, loads))
             self._count_routing(sp, touched, loads)
-        return (*out, first_toks, first_lps)
+        return (*out, [(s, int(t0), float(lp0)) for (s, _, _), t0, lp0
+                       in zip(firsts, first_toks, first_lps)])
+
+    def _deliver_chunk(self, firsts: list, pos_np, emitted: dict) -> tuple:
+        """A fetched chunk to its streams: the first tokens of the
+        streams prefilled before it, then to every occupied slot the
+        tokens the chunk emitted for it, ``emitted[slot]`` = (tokens,
+        logprobs) as lists, cut here to what the stream still wants.
+        Logprobs ``None``: a greedy-only engine's, reported as the
+        placeholder 0.0. Returns (the tokens' host stamp, how many were
+        delivered) for :meth:`_account`."""
+        t_now = time.monotonic()
+        with _fr.span("serve", "engine.deliver", flush=False) as sp:
+            for s, t0, lp0 in firsts:
+                # logprob and stamp first, token last: take_tokens slices
+                # by len(tokens), so the parallel lists must never lag it
+                s.logprobs.append(lp0)
+                s.token_times.append(t_now)
+                s.tokens.append(t0)
+                self._record_first_token(s, t_now)
+            delivered, finished = len(firsts), 0
+            for slot, (toks, lps) in emitted.items():
+                s = self.slot_stream[slot]
+                if lps is None:
+                    lps = [0.0] * len(toks)
+                take = min(len(toks), s.max_new - len(s.tokens))
+                finished += self._deliver(
+                    slot, s, toks[:take], lps[:take], t_now,
+                    int(pos_np[slot]))
+                delivered += take
+            sp.update(delivered=delivered, firsts=len(firsts),
+                      finished=finished)
+        return t_now, delivered
 
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
         """The routing counters a read-back brought: ``touched`` holds
         the chunk's [steps, L] experts touched (or nothing), ``loads``
-        one [L, E] array of assignments per cold prefill since the last
+        one [L, E] array of assignments per prefill call since the last
         read-back. Span attrs: ``experts_touched`` (mean over the
         chunk's steps and layers) and, with a prefill's counts,
         ``expert_load_max`` / ``expert_load_mean`` (assignments on the
@@ -1112,17 +1056,6 @@ class RaggedDecoder:
                 np.mean([a.max() for a in loads]))
             sp["expert_load_mean"] = float(
                 np.mean([a.mean() for a in loads]))
-
-    def _deliver_firsts(self, firsts, first_toks, first_lps,
-                        t_now: float) -> int:
-        for (s, _, _), t0, lp0 in zip(firsts, first_toks, first_lps):
-            # logprob and stamp first, token last: take_tokens slices
-            # by len(tokens), so the parallel lists must never lag it
-            s.logprobs.append(float(lp0))
-            s.token_times.append(t_now)
-            s.tokens.append(int(t0))
-            self._record_first_token(s, t_now)
-        return len(firsts)
 
     def _deliver(self, slot: int, s: _Stream, toks: list, lps: list,
                  t_now: float, pos: int) -> int:
@@ -1200,48 +1133,35 @@ class RaggedDecoder:
                 toks, lps, counts, self.cache, self.cur_tok, *touched = \
                     decode_chunk_spec(
                         self.params, self.spec_draft_head, self.cache,
-                        self.cur_tok, active_mask,
-                        jnp.asarray(self._slot_seed),
-                        jnp.asarray(self._slot_temp),
-                        jnp.asarray(self._slot_topp), self.cfg,
-                        self.chunk, depth, self.spec_draft_layers)
-            firsts = self._take_pending_first()
-            toks, lps, counts, pos_np, first_toks, first_lps = \
-                self._readback((toks, lps, counts, self.cache["pos"]),
-                               firsts, touched)
-            if not self._sampling_seen:
-                # greedy-only engine: match the plain kernel's logprob
-                # surface (placeholder 0.0) so spec on/off is
+                        self.cur_tok, active_mask, *self._lanes(),
+                        self.cfg, self.chunk, depth,
+                        self.spec_draft_layers)
+            toks, lps, counts, pos_np, firsts = self._readback(
+                (toks, lps, counts), touched)
+            emitted = {}
+            for slot, s in enumerate(self.slot_stream):
+                if s is None:
+                    continue
+                seq_t: list = []
+                seq_lp: list = []
+                for r, m in enumerate(counts[slot].tolist()):
+                    if m > 0:
+                        seq_t.extend(toks[slot, r, :m].tolist())
+                        seq_lp.extend(lps[slot, r, :m].tolist())
+                # greedy-only engine: match the plain program's logprob
+                # surface (the placeholder) so spec on/off is
                 # indistinguishable to consumers
-                lps = np.zeros_like(lps)
-            t_now = time.monotonic()
-            proposed = accepted = 0
-            with _fr.span("serve", "engine.deliver", flush=False) as sp:
-                delivered = self._deliver_firsts(
-                    firsts, first_toks, first_lps, t_now)
-                finished = 0
-                for slot, s in enumerate(self.slot_stream):
-                    if s is None:
-                        continue
-                    seq_t: list = []
-                    seq_lp: list = []
-                    for r in range(counts.shape[1]):
-                        m = int(counts[slot, r])
-                        if m <= 0:
-                            continue
-                        seq_t.extend(int(x) for x in toks[slot, r, :m])
-                        seq_lp.extend(float(x) for x in lps[slot, r, :m])
-                        proposed += depth
-                        accepted += m - 1
-                        self._spec_hist[m - 1] += 1
-                    take = min(len(seq_t), s.max_new - len(s.tokens))
-                    finished += self._deliver(
-                        slot, s, seq_t[:take], seq_lp[:take], t_now,
-                        int(pos_np[slot]))
-                    delivered += take
-                sp.update(delivered=delivered, firsts=len(firsts),
-                          finished=finished)
+                emitted[slot] = (
+                    seq_t, seq_lp if self._sampling_seen else None)
+            # a round that emitted m tokens accepted m - 1 of its
+            # `depth` proposals
+            rounds = counts[sorted(emitted)]
+            rounds = rounds[rounds > 0]
+            proposed, accepted = depth * rounds.size, int((rounds - 1).sum())
+            self._spec_hist.update((rounds - 1).tolist())
             sv.update(proposed=proposed, accepted=accepted)
+            t_now, delivered = self._deliver_chunk(firsts, pos_np, emitted)
+        self._account(t_now, delivered)
         self._spec_proposed += proposed
         self._spec_accepted += accepted
         self._spec_pumps += 1
@@ -1253,7 +1173,6 @@ class RaggedDecoder:
                 m["spec_accepted"].inc(accepted, tags)
             except Exception:  # noqa: BLE001 — telemetry never breaks
                 pass
-        self._account(t_now, delivered)
         return n_active
 
     def set_params(self, params, version: int) -> None:

@@ -114,10 +114,6 @@ class LlamaConfig:
         return LlamaConfig(**base)
 
 
-def llama2_7b() -> LlamaConfig:
-    return llama2_size("7b")
-
-
 def llama2_size(name: str) -> LlamaConfig:
     """Named sizes for benchmarks: '125m', '350m', '1b', '7b'."""
     table = {
@@ -693,79 +689,3 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict,
     ).astype(cdt)
     logits = (h @ w_out).astype(jnp.float32)
     return logits, {"k": ck, "v": cv, "pos": pos + t}
-
-
-def draft_config(cfg: LlamaConfig, n_layers: int) -> LlamaConfig:
-    """Config of the shared-trunk draft: the target's FIRST `n_layers`
-    transformer blocks plus the target's own final norm and unembedding.
-    Everything else (vocab, heads, dims, rope) is inherited, so the
-    draft's logits live in the target's token space."""
-    if not 1 <= n_layers <= cfg.n_layers:
-        raise ValueError(
-            f"draft depth {n_layers} outside [1, {cfg.n_layers}]")
-    return LlamaConfig(**{**cfg.__dict__, "n_layers": n_layers})
-
-
-def draft_params(params, n_layers: int) -> dict:
-    """Weight VIEW for the shared-trunk draft used by speculative decode
-    (models/decode_engine.py): embedding + the first `n_layers` stacked
-    blocks + final norm (+ lm_head when untied), all shared with the
-    target — zero extra parameters, and the draft's layer-i KV for any
-    position equals the target's layer-i KV (identical weights applied
-    to the identical prefix), which is why the draft can read AND write
-    the first `n_layers` of the target's ragged cache instead of
-    keeping one of its own."""
-    out = {"embed": params["embed"],
-           "layers": jax.tree_util.tree_map(
-               lambda a: a[:n_layers], params["layers"]),
-           "final_norm": params["final_norm"]}
-    if "lm_head" in params:
-        out["lm_head"] = params["lm_head"]
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _fwd_with_cache_jit(params, tokens, cache, cfg: LlamaConfig):
-    # LlamaConfig is frozen/hashable, so the compiled step is cached per
-    # config across calls (one prefill shape + one decode shape).
-    return forward_with_cache(params, tokens, cfg, cache)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "max_new_tokens"))
-def generate_scan(params, prompt, cfg: LlamaConfig, max_new_tokens: int,
-                  cache: dict):
-    """Prefill + greedy decode with the WHOLE decode loop inside one jit
-    (lax.scan over steps, static-shape cache): one dispatch per sequence
-    instead of one per token — the right shape for TPU. Returns
-    ([B, max_new_tokens] generated tokens, final cache)."""
-    logits, cache = forward_with_cache(params, prompt, cfg, cache)
-    tok0 = jnp.argmax(logits[:, -1:], axis=-1).astype(prompt.dtype)
-
-    def step(carry, _):
-        tok, c = carry
-        lg, c = forward_with_cache(params, tok, cfg, c)
-        nxt = jnp.argmax(lg[:, -1:], axis=-1).astype(tok.dtype)
-        return (nxt, c), tok[:, 0]
-
-    (last, cache), toks = jax.lax.scan(
-        step, (tok0, cache), None, length=max_new_tokens - 1
-    )
-    out = jnp.concatenate([jnp.moveaxis(toks, 0, 1), last], axis=1)
-    return out, cache
-
-
-def greedy_generate(params, prompt, cfg: LlamaConfig, max_new_tokens: int,
-                    max_len: int | None = None):
-    """Prefill + greedy decode loop (eager driver loop; each step is one
-    jitted decode). prompt: [B, T0] -> [B, T0 + max_new_tokens]."""
-    b, t0 = prompt.shape
-    max_len = max_len or (t0 + max_new_tokens)
-    cache = init_cache(cfg, b, max_len)
-    logits, cache = _fwd_with_cache_jit(params, prompt, cache, cfg)
-    tok = jnp.argmax(logits[:, -1:], axis=-1).astype(prompt.dtype)
-    out = [prompt, tok]
-    for _ in range(max_new_tokens - 1):
-        logits, cache = _fwd_with_cache_jit(params, tok, cache, cfg)
-        tok = jnp.argmax(logits[:, -1:], axis=-1).astype(prompt.dtype)
-        out.append(tok)
-    return jnp.concatenate(out, axis=1)

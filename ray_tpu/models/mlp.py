@@ -1,5 +1,5 @@
 """Minimal MLP classifier — the MNIST-class smoke-test workload
-(reference anchor: Ray Train TorchTrainer MNIST MLP, BASELINE.json config #1).
+(reference anchor: Ray Train TorchTrainer MNIST MLP).
 """
 
 from __future__ import annotations

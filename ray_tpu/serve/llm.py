@@ -82,8 +82,8 @@ def build_spec_draft(cfg, *, draft_layers: int = 0,
     target (every replica derives the identical draft from the same cfg
     + seed, so failover replicas propose identically — irrelevant for
     correctness, it only keeps acceptance rates comparable). The draft
-    is a WEIGHT VIEW: the target's first `draft_layers` layers
-    (llama.draft_params semantics; default half the stack) plus an
+    is a WEIGHT VIEW: the target's first `draft_layers` layers (default
+    half the stack) under the target's own final norm and head, plus an
     optional zero-init residual adapter head (mlp.init_draft_head —
     identity at init, a later distillation pass can train it). Returns
     (draft_layers, head_tree_or_None); the head is ENGINE-LOCAL state,

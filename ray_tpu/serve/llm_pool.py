@@ -100,7 +100,7 @@ class PrefillWorker:
         import numpy as np
 
         from ray_tpu._private import fault_injection as _fi
-        from ray_tpu.models.decode_engine import prefill_kv_sampled
+        from ray_tpu.models.decode_engine import prefill_kv
 
         # chaos site: prefill-worker death / stall mid-prefill
         _fi.fire("serve.prefill", worker=self.name)
@@ -113,7 +113,7 @@ class PrefillWorker:
                 f"bucket {self.buckets[-1]}")
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(prompt)] = prompt
-        k, v, toks0, logp0 = prefill_kv_sampled(
+        k, v, toks0, logp0 = prefill_kv(
             self.params, jnp.asarray(padded),
             jnp.asarray([len(prompt)], jnp.int32),
             jnp.asarray([int(seed) & 0xFFFFFFFF], jnp.uint32),

@@ -104,6 +104,35 @@ def test_train_phase_rehearses_on_the_cpu(cluster):
     assert not facts["batch_cut_to_fit"] and not facts["kernel_in_step"]
 
 
+def test_parting_margins_measure_against_the_models_own_logits():
+    """What ``serve_phase`` judges a spec / plain parting by on the
+    chip: where two decodes first differ, and how far under the best of
+    the uncached f32 forward's logits the two tokens lie."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(**chip_smoke.model_fields("tiny", 32))
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [np.arange(1, 9, dtype=np.int32),
+               np.arange(9, 17, dtype=np.int32)]
+    a = [5, 6, 7, 8]
+    lg = np.asarray(llama.forward(
+        params, np.concatenate([prompts[1], a[:2]])[None], cfg)[0, -1])
+    order = np.argsort(lg)
+    worst, second, best = (int(order[i]) for i in (0, -2, -1))
+    for other, gap in ((second, lg[best] - lg[second]),
+                       (worst, lg[best] - lg[worst])):
+        same, parted = chip_smoke.parting_margins(
+            params, cfg, prompts, [a, [5, 6, best, 8]],
+            [a, [5, 6, other, 9]])
+        assert same is None
+        assert parted["at"] == 2  # the first difference, not 8 != 9
+        np.testing.assert_allclose(parted["margin"], gap, rtol=1e-4)
+    assert gap > chip_smoke.NEAR_TIE
+
+
 def test_kernels_phase_rehearses_on_the_cpu():
     facts = chip_smoke.kernels_phase(TINY)
     assert facts["device"].items() >= CPU.items()

@@ -4,9 +4,7 @@
 stored; the plain reference kept here repeats the kv heads first
 (``ops.attention._repeat_kv``, the formulation the engine had). And the
 chunk program, whose layer loop carries the stacked cache as state,
-returns the tokens of ``llama.greedy_generate``, whose layer
-(``llama._layer_with_cache``) scans the cache as xs / ys and repeats the
-kv heads as before.
+returns the tokens of the uncached forward (``tests/_oracle.py``).
 """
 
 import numpy as np
@@ -15,6 +13,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from _oracle import greedy_tokens  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.ops.attention import _repeat_kv  # noqa: E402
@@ -83,13 +82,11 @@ def test_decode_chunk_returns_the_greedy_tokens(group):
     got = [np.asarray(toks0)[:, None]]
     active = np.ones(slots, bool)
     for _ in range(2):
-        toks, cache, tok = de.decode_chunk(
-            params, cache, tok, active, cfg, chunk)
+        toks, _, cache, tok = de.decode_chunk(
+            params, cache, tok, active, None, cfg, chunk)
         got.append(np.asarray(toks))
     got = np.concatenate(got, axis=1)  # [slots, 1 + 2 * chunk]
     assert list(np.asarray(cache["pos"])) == [n + 2 * chunk for n in lens]
     for i, n in enumerate(lens):
-        want = np.asarray(llama.greedy_generate(
-            params, jnp.asarray(prompts[i:i + 1, :n]), cfg,
-            1 + 2 * chunk, max_len=max_len))[0, n:]
-        np.testing.assert_array_equal(got[i], want)
+        np.testing.assert_array_equal(got[i], greedy_tokens(
+            params, prompts[i, :n], cfg, 1 + 2 * chunk))

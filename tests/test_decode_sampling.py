@@ -19,10 +19,12 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from _oracle import greedy_tokens  # noqa: E402
+from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.models.decode_engine import (  # noqa: E402
     RaggedDecoder,
-    prefill_kv_sampled,
+    prefill_kv,
 )
 
 TINY = llama.LlamaConfig(
@@ -35,10 +37,18 @@ def params():
     return llama.init_params(TINY, jax.random.PRNGKey(0))
 
 
-def _greedy(params, prompt, n, max_len=64):
-    return np.asarray(llama.greedy_generate(
-        params, jnp.asarray(np.asarray(prompt)[None]), TINY, n,
-        max_len=max_len))[0, len(prompt):]
+def _greedy(params, prompt, n):
+    return greedy_tokens(params, prompt, TINY, n)
+
+
+def _teacher_forced_logprobs(params, cfg, prompt, toks):
+    """log-softmax of the uncached forward over prompt + toks, at each
+    of ``toks`` from the position before it."""
+    seq = np.concatenate([prompt, toks]).astype(np.int32)
+    logp = np.asarray(jax.nn.log_softmax(llama.forward(
+        params, jnp.asarray(seq[None]), cfg)[0].astype(jnp.float32)))
+    at = np.arange(len(prompt) - 1, len(seq) - 1)
+    return logp[at, toks]
 
 
 def _run_stream(params, prompt, n, *, temperature, seed, top_p=1.0,
@@ -116,18 +126,12 @@ def test_logprobs_match_teacher_forced_forward(params):
     prompt = rng.randint(1, 250, 8).astype(np.int32)
     toks, lps = _run_stream(params, prompt, 8, temperature=1.0,
                             seed=1234)
-    seq = np.concatenate([prompt, toks]).astype(np.int32)
-    logits = np.asarray(
-        llama.forward(params, jnp.asarray(seq[None]), TINY), np.float32)
-    ref = np.asarray([
-        jax.nn.log_softmax(jnp.asarray(logits[0, len(prompt) - 1 + t])
-                           )[toks[t]]
-        for t in range(len(toks))], np.float32)
-    np.testing.assert_allclose(lps, ref, atol=1e-4)
+    np.testing.assert_allclose(
+        lps, _teacher_forced_logprobs(params, TINY, prompt, toks), atol=1e-4)
 
 
 def test_disaggregated_prefill_samples_same_first_token(params):
-    """prefill_kv_sampled on a 'prefill worker' must sample the SAME
+    """prefill_kv on a 'prefill worker' must sample the SAME
     first token/logprob as an inline sampled admission (same (seed,
     true_len-1) lane), and the adopted stream continues identically."""
     rng = np.random.RandomState(6)
@@ -136,7 +140,7 @@ def test_disaggregated_prefill_samples_same_first_token(params):
         params, prompt, 10, temperature=1.0, seed=4321)
     padded = np.zeros((1, 8), np.int32)
     padded[0, :len(prompt)] = prompt
-    k, v, tok0, lp0 = prefill_kv_sampled(
+    k, v, tok0, lp0 = prefill_kv(
         params, jnp.asarray(padded),
         jnp.asarray([len(prompt)], jnp.int32),
         jnp.asarray([4321], jnp.uint32), jnp.asarray([1.0], jnp.float32),
@@ -181,13 +185,148 @@ def test_take_tokens_streams_logprobs_in_lockstep(params):
     assert eng.take_tokens(sid) == ([], True)
 
 
-def test_submit_validates_top_p(params):
+@pytest.mark.parametrize("entry", ["submit", "submit_prefilled"])
+def test_submit_validates_top_p(params, entry):
+    """Both ways in refuse, at the submitter, a ``top_p`` outside (0, 1]
+    and a prompt that leaves no room to decode."""
     eng = RaggedDecoder(params, TINY, slots=2, max_len=64,
-                        chunk_tokens=4, prompt_buckets=(8,))
-    with pytest.raises(ValueError):
-        eng.submit([1, 2, 3], 4, temperature=1.0, top_p=0.0)
-    with pytest.raises(ValueError):
-        eng.submit([1, 2, 3], 4, temperature=1.0, top_p=1.5)
+                        chunk_tokens=4, prompt_buckets=(8, 64))
+
+    def enqueue(prompt, **kw):
+        if entry == "submit":
+            return eng.submit(prompt, 4, **kw)
+        rows = np.zeros((TINY.n_layers, 64, TINY.n_kv_heads, TINY.head_dim),
+                        np.float32)
+        return eng.submit_prefilled(prompt, 4, {
+            "k": rows, "v": rows, "first_token": 1,
+            "true_len": len(prompt)}, **kw)
+
+    with pytest.raises(ValueError, match="top_p"):
+        enqueue([1, 2, 3], temperature=1.0, top_p=0.0)
+    with pytest.raises(ValueError, match="top_p"):
+        enqueue([1, 2, 3], temperature=1.0, top_p=1.5)
+    with pytest.raises(ValueError, match="no decode room"):
+        enqueue([1] * 63)
+    assert not eng.queue
+    enqueue([1, 2, 3], temperature=1.0, top_p=1.0)
+    assert len(eng.queue) == 1
+
+
+def _gqa(group):
+    cfg = llama.LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4,
+        n_kv_heads=4 // group, d_ff=128, max_seq_len=48, dtype="float32",
+        remat=False)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(10 + group))
+
+
+def _lanes(n, seed=0, temp=0.0):
+    return (np.full(n, seed, np.uint32), np.full(n, temp, np.float32),
+            np.ones(n, np.float32))
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_chunk_program_with_lanes_at_temperature_zero_is_the_greedy_one(
+        group):
+    """The one chunk program, traced without lanes and with lanes at
+    temperature 0, on the same prefilled slots: the same tokens; the
+    second also returns each token's logprob, which is the uncached
+    forward's log-softmax at that token."""
+    cfg, params = _gqa(group)
+    slots, max_len, bucket, chunk = 2, 48, 16, 8
+    rng = np.random.RandomState(29)
+    lens = [7, 13]
+    prompts = np.zeros((slots, bucket), np.int32)
+    for i, n in enumerate(lens):
+        prompts[i, :n] = rng.randint(1, 250, n)
+
+    def prefilled():
+        return de._prefill_batch_into_slots(
+            params, prompts, np.array(lens, np.int32),
+            np.arange(slots, dtype=np.int32), *_lanes(slots),
+            de.init_ragged_cache(cfg, slots, max_len),
+            jnp.zeros((slots,), jnp.int32), cfg)
+
+    active = np.ones(slots, bool)
+    cache, tok, toks0, lps0 = prefilled()
+    greedy, none, _, _ = de.decode_chunk(
+        params, cache, tok, active, None, cfg, chunk)
+    assert none is None
+    cache, tok, _, _ = prefilled()
+    toks, lps, _, _ = de.decode_chunk(
+        params, cache, tok, active, _lanes(slots), cfg, chunk)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(greedy))
+    for i, n in enumerate(lens):
+        served = np.concatenate([np.asarray(toks0)[i:i + 1],
+                                 np.asarray(toks)[i]])
+        want = _teacher_forced_logprobs(params, cfg, prompts[i, :n], served)
+        np.testing.assert_allclose(
+            np.concatenate([np.asarray(lps0)[i:i + 1], np.asarray(lps)[i]]),
+            want, atol=1e-4)
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8])
+def test_the_prefill_entries_agree(temp):
+    """One GQA prompt of 11 tokens through the three ways into a slot —
+    cold, the prefix cache's rows + a suffix prefill, rows out of
+    ``prefill_kv`` and adopted — leaves the same k/v rows in the slot,
+    the same pos, and gives the same first token and logprob on the
+    (seed, position 10) lane, greedy and sampled."""
+    cfg, params = _gqa(2)
+    slots, max_len, n, n_pref = 2, 48, 11, 8
+    prompt = np.random.RandomState(30).randint(1, 250, n).astype(np.int32)
+    lane = _lanes(1, seed=77, temp=temp)
+    slot = np.array([1], np.int32)
+
+    def into_slot(tokens, width, prefix=None):
+        row = np.zeros((1, width), np.int32)
+        row[0, :len(tokens)] = tokens
+        cache, cur, tok0, lp0 = de._prefill_batch_into_slots(
+            params, row, np.array([len(tokens)], np.int32), slot, *lane,
+            de.init_ragged_cache(cfg, slots, max_len),
+            jnp.zeros((slots,), jnp.int32), cfg, prefix)
+        assert int(cur[1]) == int(tok0[0])
+        return cache, int(tok0[0]), float(lp0[0])
+
+    cold, tok0, lp0 = into_slot(prompt, 16)
+    assert list(np.asarray(cold["pos"])) == [0, n]
+    assert not np.asarray(cold["k"][:, 0]).any()  # the other slot
+    assert not np.asarray(cold["k"][:, 1, 16:]).any()  # full-slot write
+
+    pref = {kv: np.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
+                          cfg.head_dim), np.float32) for kv in "kv"}
+    for kv in "kv":
+        pref[kv][:, 0, :n_pref] = np.asarray(cold[kv][:, 1, :n_pref])
+    warm, tok_w, lp_w = into_slot(
+        prompt[n_pref:], 4, (pref["k"], pref["v"], np.int32(n_pref)))
+
+    row = np.zeros((1, 16), np.int32)
+    row[0, :n] = prompt
+    k, v, tok_r, lp_r = de.prefill_kv(
+        params, row, np.array([n], np.int32), *lane, cfg, max_len)
+    adopted, cur = de._adopt_kv_into_slot(
+        k[:, 0], v[:, 0], np.int32(n), tok_r[0], np.int32(1),
+        de.init_ragged_cache(cfg, slots, max_len),
+        jnp.zeros((slots,), jnp.int32), cfg)
+    assert int(cur[1]) == int(tok_r[0])
+
+    assert tok_w == tok0 and int(tok_r[0]) == tok0
+    np.testing.assert_allclose([lp_w, float(lp_r[0])], lp0, atol=1e-5)
+    for other in (warm, adopted):
+        assert list(np.asarray(other["pos"])) == [0, n]
+        for kv in "kv":  # the rows a stream can ever see: its prompt's
+            np.testing.assert_allclose(
+                np.asarray(other[kv][:, 1, :n]),
+                np.asarray(cold[kv][:, 1, :n]), atol=1e-5)
+    # and against the uncached forward: the logprob under the sampling
+    # distribution (temperature-scaled; top_p is 1), greedy the argmax
+    logits = llama.forward(params, jnp.asarray(prompt[None]), cfg)[0, -1]
+    logits = logits.astype(jnp.float32)
+    if not temp:
+        assert tok0 == int(jnp.argmax(logits))
+    np.testing.assert_allclose(
+        lp0, jax.nn.log_softmax(logits / temp if temp else logits)[tok0],
+        atol=1e-4)
 
 
 def test_stats_carry_version_and_pumps(params):

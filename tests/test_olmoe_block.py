@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _oracle import greedy_tokens
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import llama
@@ -134,8 +135,8 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
         ref, cfg, params):
     """Two prompts of 5 and 11 tokens in one bucket of 16 (so 11 and 5
     padded positions), each prefilled by the engine's program, then 8
-    greedy steps of ``decode_chunk`` and of ``decode_chunk_sampled`` (at
-    temperature 0 it reports the chosen token's log-probability: a
+    greedy steps of ``decode_chunk`` without and with the sampling lanes
+    (at temperature 0 it reports the chosen token's log-probability: a
     logit-level reading). Against the reference's full forward over
     prompt + tokens: the same tokens, the same log-probabilities."""
     prompts = [list(map(int, _tokens(4, (5,)))),
@@ -144,12 +145,13 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(
     active = np.ones(slots, bool)
     cache, cur, toks0, lps0, loads = _prefill(cfg, params, prompts, 16,
                                               slots, max_len)
-    toks, _, _, touched = de.decode_chunk(params, cache, cur, active, cfg,
-                                          chunk)
+    toks, _, _, _, touched = de.decode_chunk(params, cache, cur, active,
+                                             None, cfg, chunk)
     cache, cur, _, _, _ = _prefill(cfg, params, prompts, 16, slots, max_len)
-    toks_s, lps, _, _, _ = de.decode_chunk_sampled(
-        params, cache, cur, active, np.zeros(slots, np.uint32),
-        np.zeros(slots, np.float32), np.ones(slots, np.float32), cfg, chunk)
+    toks_s, lps, _, _, _ = de.decode_chunk(
+        params, cache, cur, active, (
+            np.zeros(slots, np.uint32), np.zeros(slots, np.float32),
+            np.ones(slots, np.float32)), cfg, chunk)
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(toks_s))
     for slot, prompt in enumerate(prompts):
         served = [toks0[slot]] + [int(t) for t in toks[slot]]
@@ -180,9 +182,7 @@ def test_the_serving_programs_serve_a_dropless_model(cfg, params):
     dense = dataclasses.replace(cfg, moe_impl="dense")
     shared = list(map(int, _tokens(6, (8,))))
     prompts = [shared + [3, 4, 5], shared + [9, 8], [7, 8, 9]]
-    want = [np.asarray(llama.greedy_generate(
-        params, jnp.asarray([p]), dense, 10))[0, len(p):].tolist()
-        for p in prompts]
+    want = [greedy_tokens(params, p, dense, 10).tolist() for p in prompts]
     for kw in (dict(), dict(spec_depth=2, spec_draft_layers=1),
                dict(prefix_cache=PrefixCache(block=4))):
         eng = de.RaggedDecoder(params, cfg, slots=2, max_len=40,
@@ -251,9 +251,10 @@ def test_a_dense_model_is_the_program_it_was(cfg):
     cache = jax.eval_shape(lambda: de.init_ragged_cache(dense, 2, 32))
     out = jax.eval_shape(
         lambda p, c: de.decode_chunk(p, c, jnp.zeros(2, jnp.int32),
-                                     jnp.ones(2, bool), dense, 4),
+                                     jnp.ones(2, bool), None, dense, 4),
         shapes, cache)
-    assert len(out) == 3  # tokens, cache, last token: no routing counter
+    # tokens, no logprobs, cache, last token: no routing counter
+    assert len(out) == 4 and out[1] is None
     assert dense.num_params() == sum(
         int(np.prod(s)) for s in leaves.values())
     assert cfg.num_params() == FAMILY.num_params(M)

@@ -363,8 +363,8 @@ def test_decode_chunk_clamps_pos_at_cache_edge(chunk):
     cache["pos"] = jax.numpy.asarray(np.array([max_len - 2, 3], np.int32))
     tok = jax.numpy.zeros((2,), jax.numpy.int32)
     active = np.array([True, False])
-    toks, cache, last = decode_chunk(params, cache, tok, active, cfg,
-                                     chunk)
+    toks, _, cache, last = decode_chunk(params, cache, tok, active, None,
+                                        cfg, chunk)
     pos = np.asarray(cache["pos"])
     assert pos[0] == max_len - 1, f"pos ran past the cache edge: {pos}"
     assert pos[1] == 3  # frozen slot untouched

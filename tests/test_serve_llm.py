@@ -11,6 +11,7 @@ import pytest
 import jax
 
 import ray_tpu
+from _oracle import greedy_tokens
 from ray_tpu import serve
 from ray_tpu.cluster_utils import Cluster
 from ray_tpu.models import llama
@@ -23,10 +24,8 @@ TINY = llama.LlamaConfig(
     d_ff=128, max_seq_len=128, dtype="float32", remat=False)
 
 
-def _greedy(params, prompt, max_new, max_len):
-    return np.asarray(llama.greedy_generate(
-        params, jax.numpy.asarray(np.asarray(prompt)[None]), TINY,
-        max_new, max_len=max_len))[0, len(prompt):]
+def _greedy(params, prompt, max_new):
+    return greedy_tokens(params, prompt, TINY, max_new)
 
 
 def test_ragged_engine_matches_greedy_generate():
@@ -45,7 +44,7 @@ def test_ragged_engine_matches_greedy_generate():
     eng.drain()
     for sid, p in zip(sids, prompts):
         got = np.asarray(eng.pop_finished(sid).tokens[:max_new])
-        np.testing.assert_array_equal(got, _greedy(params, p, max_new, 64))
+        np.testing.assert_array_equal(got, _greedy(params, p, max_new))
 
 
 def test_engine_interleaves_new_streams_into_free_slots():
@@ -107,7 +106,7 @@ def test_one_pump_prefills_each_prompt_in_a_call_of_its_own(n_prompts):
         a = alone.submit(p, 7)
         alone.drain()
         np.testing.assert_array_equal(got, alone.pop_finished(a).tokens)
-        np.testing.assert_array_equal(got, _greedy(params, p, 7, 64))
+        np.testing.assert_array_equal(got, _greedy(params, p, 7))
 
 
 def test_a_burst_compiles_no_prefill_program_beyond_one_per_bucket():
@@ -156,7 +155,7 @@ def test_reused_slot_holds_nothing_of_its_previous_occupant():
     eng.drain()
     got = np.asarray(eng.pop_finished(sid).tokens)
     assert len(got) == 48 - 5 - 1
-    np.testing.assert_array_equal(got, _greedy(params, short_p, 42, 48))
+    np.testing.assert_array_equal(got, _greedy(params, short_p, 42))
 
 
 @pytest.fixture(scope="module")
@@ -188,9 +187,7 @@ def test_llm_deployment_concurrent_requests(cluster):
     outs = ray_tpu.get(refs, timeout=300)
     assert time.perf_counter() - t0 < 300
     for p, out in zip(prompts, outs):
-        want = np.asarray(llama.greedy_generate(
-            params, jax.numpy.asarray(p[None, :]), TINY, max_new,
-            max_len=96))[0, len(p):]
-        np.testing.assert_array_equal(np.asarray(out["tokens"]), want)
+        np.testing.assert_array_equal(
+            np.asarray(out["tokens"]), _greedy(params, p, max_new))
         assert len(out["token_times_s"]) == max_new
         assert out["token_times_s"][0] >= out["submitted_s"]

@@ -16,6 +16,7 @@ import pytest
 import jax
 
 import ray_tpu
+from _oracle import greedy_tokens
 from ray_tpu import serve
 from ray_tpu.cluster_utils import Cluster
 from ray_tpu.models import llama
@@ -29,10 +30,8 @@ TINY = llama.LlamaConfig(
     d_ff=128, max_seq_len=96, dtype="float32", remat=False)
 
 
-def _greedy(params, prompt, max_new, max_len=96):
-    return np.asarray(llama.greedy_generate(
-        params, jax.numpy.asarray(np.asarray(prompt)[None]), TINY,
-        max_new, max_len=max_len))[0, len(prompt):]
+def _greedy(params, prompt, max_new):
+    return greedy_tokens(params, prompt, TINY, max_new)
 
 
 # ---------------- pure units (no cluster) ----------------
@@ -130,7 +129,7 @@ def test_prefix_cache_decode_bit_identical_to_cold_prefill():
         sid = eng.submit(p, 10)
         eng.drain()
         got = np.asarray(eng.pop_finished(sid).tokens[:10])
-        np.testing.assert_array_equal(got, _greedy(params, p, 10, 64))
+        np.testing.assert_array_equal(got, _greedy(params, p, 10))
     st = pc.stats()
     assert st["hits"] >= len(prompts) - 1, st
     assert st["hit_rate"] > 0.5
@@ -146,9 +145,10 @@ def test_disaggregated_prefill_adopt_bit_identical():
     prompt = rng.randint(1, 256, size=20).astype(np.int32)
     padded = np.zeros((1, 32), np.int32)
     padded[0, :len(prompt)] = prompt
-    k, v, toks0 = prefill_kv(params, jnp.asarray(padded),
-                             jnp.asarray([len(prompt)], jnp.int32),
-                             TINY, 64)
+    k, v, toks0, _ = prefill_kv(
+        params, jnp.asarray(padded), jnp.asarray([len(prompt)], jnp.int32),
+        jnp.zeros(1, jnp.uint32), jnp.zeros(1, jnp.float32),
+        jnp.ones(1, jnp.float32), TINY, 64)
     kv = {"k": np.asarray(k[:, 0]), "v": np.asarray(v[:, 0]),
           "first_token": int(toks0[0]), "true_len": len(prompt)}
     eng = RaggedDecoder(params, TINY, slots=2, max_len=64,
@@ -156,7 +156,7 @@ def test_disaggregated_prefill_adopt_bit_identical():
     sid = eng.submit_prefilled(prompt, 10, kv)
     eng.drain()
     got = np.asarray(eng.pop_finished(sid).tokens[:10])
-    np.testing.assert_array_equal(got, _greedy(params, prompt, 10, 64))
+    np.testing.assert_array_equal(got, _greedy(params, prompt, 10))
     # wrong-shape KV is rejected at submit, not inside the pump
     with pytest.raises(ValueError):
         eng.submit_prefilled(prompt, 10, {**kv, "k": kv["k"][:, :32]})
@@ -177,7 +177,7 @@ def test_engine_stats_and_streaming_take():
         new, done = eng.take_tokens(sid)
         got.extend(new)
     np.testing.assert_array_equal(np.asarray(got[:9]),
-                                  _greedy(params, prompt, 9, 64))
+                                  _greedy(params, prompt, 9))
     st = eng.stats()
     assert st["total_tokens"] >= 9
     assert "tokens_per_sec" in st

@@ -13,11 +13,22 @@ compile, so every case asks for the kernel explicitly (``use_flash=True``,
 The cheap cases are tier-1; the 14-25 s programs are ``-m slow``:
 
     JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_compile.py -m slow -s
+
+Run as a script from a checkout's root it writes the optimised HLO of
+the programs the serving cells run, made comparable between two
+checkouts (``dump_serving_programs``): a refactor that claims to leave
+those programs alone diffs the parent's ``<dir>`` against its own.
+
+    PYTHONPATH=. JAX_PLATFORMS=cpu python3 tests/test_tpu_compile.py <dir>
 """
 
+import base64
 import functools
+import hashlib
+import json
 import os
 import re
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -80,7 +91,7 @@ def _serve_cfg(size="1b", max_len=288):
 
 
 def _train_cfg(size="1b", seq=2048, **kw):
-    # bench.py's 1B recipe (chip_smoke.model_fields + train phase);
+    # the 1B recipe of chip_smoke.model_fields and its train phase;
     # use_flash=True because the dispatch would read the CPU backend
     # here and take the reference
     return llama.LlamaConfig(**{
@@ -158,7 +169,7 @@ def test_decode_chunk_compiles_at_1b_widths(topo):
     chip = SingleDeviceSharding(topo.devices[0])
     params, cache, vec = _engine_args(cfg, chip)
     compiled = de.decode_chunk.lower(
-        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=8).compile()
     mem = _mem(compiled)
     # the f32 masters are the arguments; they must fit a 16 GB chip
@@ -235,7 +246,7 @@ def test_olmoe_decode_chunk_reads_the_expert_stack_in_place(
     chip = SingleDeviceSharding(topo.devices[0])
     params, cache, vec = _engine_args(cfg, chip, slots=8, max_len=1296)
     compiled = de.decode_chunk.lower(
-        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=16).compile()
     text = compiled.as_text()
     assert text.count(KERNEL) == 3
@@ -265,7 +276,7 @@ def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
     chip = SingleDeviceSharding(topo.devices[0])
     params, cache, vec = _engine_args(cfg, chip, slots=8, max_len=1296)
     compiled = de.decode_chunk.lower(
-        params, cache, vec(jnp.int32), vec(jnp.bool_), cfg=cfg,
+        params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=16).compile()
     text = compiled.as_text()
     if cfg.n_kv_heads < cfg.n_heads:
@@ -289,7 +300,7 @@ def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
 
 
 def _train_step(topo, cfg, mesh_cfg: MeshConfig, batch=2, seq=2048):
-    """bench.py's / chip_smoke.py's train step, compiled for a mesh over
+    """chip_smoke.py's train step, compiled for a mesh over
     the first ``mesh_cfg.size`` described chips. `init_train_state`
     would place real arrays; `train_state_shardings` gives the same
     shardings with shapes only."""
@@ -356,8 +367,8 @@ def test_serving_programs_compile_at_1b_widths(topo, program):
     params, cache, vec = _engine_args(cfg, chip)
     lanes = (vec(jnp.uint32), vec(jnp.float32), vec(jnp.float32))
     if program == "sampled":
-        lowered = de.decode_chunk_sampled.lower(
-            params, cache, vec(jnp.int32), vec(jnp.bool_), *lanes,
+        lowered = de.decode_chunk.lower(
+            params, cache, vec(jnp.int32), vec(jnp.bool_), lanes,
             cfg=cfg, chunk=8)
     elif program == "spec":
         lowered = de.decode_chunk_spec.lower(
@@ -383,3 +394,75 @@ def test_full_1b_train_step_compiles(topo, chips):
     print(f"\n1b train step, {chips} chip(s): {_mem(compiled)} "
           f"kernel calls={text.count(KERNEL)}")
     assert KERNEL in text
+
+
+# ---- as a script: the cells' serving programs as comparable text ----
+
+SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
+                 ("internlm2-1.8b", "chat-steady"),
+                 ("olmoe-1b-7b-0125-1chip", "doc-saturated"))
+
+
+def _comparable(compiled) -> str:
+    """``as_text()`` without what names the checkout: ``metadata={...}``,
+    the header's source tables, and in a Mosaic call the module's bytes
+    (they carry paths and line numbers) for a hash of its text without
+    locations. Instruction suffixes ``.N`` are renamed in order of first
+    appearance: numbering apart, the same program gives the same text."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def mosaic(m):
+        with mlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(base64.b64decode(m.group(1))) \
+                .operation.get_asm(enable_debug_info=False)
+        return '"body": "%s"' % hashlib.sha1(asm.encode()).hexdigest()
+
+    text = re.sub(r", metadata=\{[^}]*\}", "", compiled.as_text())
+    text = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n(\d+ .*\n)*", "\n", text)
+    text = re.sub(r'\\?"body\\?": ?\\?"([A-Za-z0-9+/=]+)\\?"', mosaic, text)
+    names: dict = {}
+    return re.sub(r"\.\d+\b", lambda m: names.setdefault(
+        m.group(0), f".n{len(names)}"), text)
+
+
+def dump_serving_programs(out_dir: str) -> None:
+    """``decode_chunk(lanes=None)`` and the one-row
+    ``_prefill_batch_into_slots`` at every bucket, at each serving
+    configuration's own fields and each engine shape of its cells
+    (``benchmark/``), compiled for one described v5e chip."""
+    from jax.experimental import topologies
+
+    from benchmark import manifest
+    from ray_tpu.ops import grouped_matmul as gm
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    # (the dispatch would read the CPU backend here and take ragged_dot)
+    gm.grouped_matmul = functools.partial(gm.grouped_matmul, use_kernel=True)
+    chip = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    for config, traffic in SERVING_CELLS:
+        with open(f"benchmark/traffic/{traffic}.json") as f:
+            eng = json.load(f)["engine"]
+        shape = dict(slots=eng["slots"], max_len=eng["max_len"])
+        cfg = llama.LlamaConfig(**{**manifest.model(config)[1],
+                                   "max_seq_len": eng["max_len"]})
+        params, cache, vec = _engine_args(cfg, chip, **shape)
+        programs = {"decode_chunk": de.decode_chunk.lower(
+            params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+            chunk=eng["chunk_tokens"])}
+        for bucket in eng["prompt_buckets"]:
+            programs[f"prefill_{bucket}"] = _lower_prefill(
+                cfg, chip, bucket, **shape)
+        for name, lowered in programs.items():
+            path = (f"{out_dir}/{config}.{eng['slots']}x{eng['max_len']}."
+                    f"{name}.txt")
+            with open(path, "w") as f:
+                f.write(_comparable(lowered.compile()))
+            print(path, flush=True)
+
+
+if __name__ == "__main__":
+    dump_serving_programs(sys.argv[1])
