@@ -230,7 +230,8 @@ def _sample_from_logits(logits, seeds, pos, temps, top_ps):
 def _split_model(cfg: LlamaConfig, params):
     """What a chunk program prepares once: the layers as the layer loop
     scans them (``llama.split_layers``: (layers, attach)) and the
-    unembedding in the compute dtype."""
+    unembedding in the compute dtype (as it lies in the engine's serving
+    tree; a program handed f32 masters casts it here)."""
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cfg.compute_dtype)
@@ -527,6 +528,24 @@ def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
     return cache, cur_tok.at[slot].set(tok0)
 
 
+def _nbytes(tree) -> int:
+    return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
+
+
+def adopt_weights(cfg: LlamaConfig, params, version: int):
+    """The one way a serving process takes weights in: -> the serving
+    tree of ``params`` (``llama.serving_params``). The cast runs here,
+    once an adoption (a replica's start, a weight publish), and is
+    waited for, so the ``serve.weights_cast`` span (ring-only) is what
+    the adoption cost; the caller keeps the serving tree alone and lets
+    go of ``params``."""
+    with _fr.span("serve", "serve.weights_cast", flush=False, attrs={
+            "version": int(version), "bytes_in": _nbytes(params)}) as sp:
+        serving = jax.block_until_ready(llama.serving_params(cfg, params))
+        sp["bytes_out"] = _nbytes(serving)
+    return serving
+
+
 # Birth stamps a request may carry, in the order they are taken, and the
 # name of the part between each and the next (the last: engine.submit).
 _STAMPS = ("proxy_recv", "pool_enqueue", "pool_admitted")
@@ -606,7 +625,9 @@ class RaggedDecoder:
                  chunk_delay_s: float = 0.0, weights_version: int = 0,
                  spec_depth: int = 0, spec_draft_layers: int = 0,
                  spec_draft_head=None):
-        self.params = params
+        # the serving tree (llama.serving_params), the only weights the
+        # engine holds: the caller's f32 masters are not kept
+        self.params = adopt_weights(cfg, params, weights_version)
         # Emulated per-chunk device time for exercising the SERVING
         # tier on hosts without an accelerator: on a TPU each chunk
         # waits on the device, time that overlaps across replicas — a
@@ -1183,8 +1204,13 @@ class RaggedDecoder:
         keep their already-computed KV (their continuation mixes
         versions inside the bounded staleness window — their recorded
         per-token logprobs stay exact regardless, which is what the RL
-        importance correction consumes)."""
-        self.params = params
+        importance correction consumes). ``params`` is the published
+        tree, f32 masters as a rule: the engine keeps its serving cast
+        (``adopt_weights``), made here, once a publish."""
+        # the old tree goes first: beside it, the incoming masters and
+        # their cast do not fit a chip that holds a 1.9 B model
+        self.params = None
+        self.params = adopt_weights(self.cfg, params, version)
         self.weights_version = int(version)
         if self.prefix_cache is not None:
             self.prefix_cache.clear()
@@ -1222,8 +1248,9 @@ class RaggedDecoder:
         exported as Prometheus gauges (util/metrics.py) alongside the
         collective OpStats family — and monotonic totals an outside
         reader takes deltas of (``total_tokens``, ``pumps``,
-        ``prefill_calls``: cold prefills, one prompt each; for a
-        mixture-of-experts model ``moe_assignments`` and
+        ``prefill_calls``: cold prefills, one prompt each;
+        ``weights_bytes``: what the serving tree holds on the device;
+        for a mixture-of-experts model ``moe_assignments`` and
         ``moe_touched_expert_steps``, see ``__init__``)."""
         active = sum(1 for st in self.slot_stream if st is not None)
         out = {
@@ -1233,6 +1260,7 @@ class RaggedDecoder:
             "tokens_per_sec": round(self.tokens_per_sec(), 1),
             "total_tokens": self._total_tokens,
             "weights_version": self.weights_version,
+            "weights_bytes": _nbytes(self.params),
             "pumps": self.pumps,
             "prefill_calls": self.prefill_calls,
         }

@@ -348,6 +348,41 @@ def reports_routing(cfg: LlamaConfig) -> bool:
     return cfg.n_experts > 0 and cfg.moe_impl == "dropless"
 
 
+# the vectors the model paths consume in float32 (rms_norm's weights)
+_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm")
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_leaves(leaves, dtype):
+    return [a.astype(dtype) for a in leaves]
+
+
+def serving_params(cfg: LlamaConfig, params):
+    """The tree a SERVING process holds: every leaf in the type the
+    cached model paths consume it in. The matrices (embedding, head,
+    projections, MLP or experts, router), which those paths round to
+    ``cfg.compute_dtype`` before each product, are rounded to it here,
+    once, in one jitted call; the norm vectors, consumed in float32,
+    stay as they are. The products see the operand values they saw from
+    the f32 masters, and the ``.astype`` in front of each is then a
+    no-op: the same model code runs from either tree (the trainer feeds
+    it masters). A tree already in those types comes back itself, no
+    copy; with ``dtype="float32"`` that is every f32 tree. Called where
+    a replica adopts weights (``decode_engine.adopt_weights``), never
+    on the request path."""
+    cdt = cfg.compute_dtype
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    todo = [i for i, (path, leaf) in enumerate(flat)
+            if getattr(path[-1], "key", None) not in _F32_LEAVES
+            and leaf.dtype != cdt]
+    if not todo:
+        return params
+    leaves = [leaf for _, leaf in flat]
+    for i, cast in zip(todo, _cast_leaves([leaves[i] for i in todo], cdt)):
+        leaves[i] = cast
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
@@ -359,9 +394,11 @@ def split_layers(cfg: LlamaConfig, layers):
     A dropless model's expert matrices stay OUT of the scanned input: a
     scan slices its input, and a slice of [L, E, K, N] handed to the
     grouped matmul kernel is a copy of every expert of the layer, read
-    or not, in every decode step. They ride along whole (cast to the
-    compute type once, here) beside the layer's index, and the kernel
-    reads ``stack[layer]``'s blocks in place."""
+    or not, in every decode step. They ride along whole beside the
+    layer's index (in the compute type: as they are from a serving
+    tree, :func:`serving_params`; f32 masters are cast here, once a
+    program), and the kernel reads ``stack[layer]``'s blocks in
+    place."""
     if not reports_routing(cfg):
         return layers, lambda p: p
     cdt = cfg.compute_dtype

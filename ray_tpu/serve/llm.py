@@ -35,7 +35,13 @@ def build_model(model_size: str = "tiny", *, max_len: int = 512,
     and prefill workers so both pools run the identical network. When
     `params_blob` (a host tree published through the object store) is
     given, weights are adopted instead of re-initialized: one shared
-    put serves every replica via the multi-source pull path."""
+    put serves every replica via the multi-source pull path.
+
+    The tree is the published one, f32 masters (``llama.init_params``).
+    What a replica HOLDS is its serving cast: ``RaggedDecoder`` and
+    ``PrefillWorker`` round the matrices to the compute dtype once, when
+    they adopt the tree (``decode_engine.adopt_weights``), and let the
+    masters go; a replica never trains."""
     import jax
 
     from ray_tpu.models import llama
@@ -158,6 +164,7 @@ class LLMServer:
             weights_version=weights_version,
             spec_depth=spec_depth, spec_draft_layers=draft_layers,
             spec_draft_head=draft_head)
+        del params  # the engine holds its serving cast, nobody the masters
         # (host params tree, version) staged by update_weights(); the
         # pump thread adopts it at the next chunk boundary — engine
         # params are touched only by the pump owner

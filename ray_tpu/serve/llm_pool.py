@@ -79,10 +79,15 @@ class PrefillWorker:
 
         from ray_tpu._private import accelerator
 
+        from ray_tpu.models.decode_engine import adopt_weights
+
         accelerator.claim_device()
-        self.params, self.cfg = build_model(
+        params, self.cfg = build_model(
             model_size, max_len=max_len, vocab_size=vocab_size,
             seed=seed, params_blob=params_blob)
+        # the serving tree, as a decode replica holds it: prefill_kv is
+        # the engine's own prefill program
+        self.params = adopt_weights(self.cfg, params, 0)
         self.max_len = max_len
         self.buckets = tuple(sorted(prompt_buckets))
         self.name = name or f"prefill-{os.getpid()}"
@@ -159,9 +164,14 @@ class PrefillWorker:
 
         import ray_tpu
 
+        from ray_tpu.models.decode_engine import adopt_weights
+
         if isinstance(params_blob, ray_tpu.ObjectRef):
             params_blob = ray_tpu.get(params_blob, timeout=600)
-        self.params = jax.tree_util.tree_map(jnp.asarray, params_blob)
+        self.params = None  # the old tree goes before the new one is made
+        self.params = adopt_weights(
+            self.cfg, jax.tree_util.tree_map(jnp.asarray, params_blob),
+            version)
         self._version = int(version)
         return self._version
 
