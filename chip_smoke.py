@@ -84,13 +84,18 @@ class Plan:
     steps: int = 4
     # kernels: (batch, seq, q heads, kv heads, head dim) of the 1B step
     flash_shape: tuple = (2, 2048, 16, 8, 128)
+    # hybrid: the KDA / MLA block (models/ling.py) at its published
+    # widths, prompts that cross a chunk boundary of the KDA prefill
+    hybrid_widths: str = "published"
+    hybrid_lens: tuple = (65, 200)
 
     @staticmethod
     def tiny(**kw) -> "Plan":
         base = dict(model_size="tiny", platform="cpu", slots=4, max_len=96,
                     chunk_tokens=4, prompt_buckets=(8, 32),
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
-                    steps=3, flash_shape=(2, 128, 4, 2, 64))
+                    steps=3, flash_shape=(2, 128, 4, 2, 64),
+                    hybrid_widths="tiny", hybrid_lens=(9, 21))
         return Plan(**{**base, **kw})
 
     @property
@@ -745,10 +750,93 @@ def kernels_phase(plan: Plan) -> dict:
             "compile_s": out["device"]["compile"]["seconds"]}
 
 
+# A layer's two forms against each other on the chip, bf16: largest
+# difference over the largest value. Readings at the published widths,
+# prompts of 65, 200 and 700 tokens (my chip run, PR 32): KDA outputs
+# 0.0030-0.0033, state 0.00004-0.00016, convolution rows 0.0005-0.0009
+# (a projection of one row and of T rows rounds differently on the
+# chip; the CPU reads 0); MLA outputs 0.0034-0.0078, rows up to 0.0024.
+# Four times the largest; a form that drops a norm or a decay reads 0.3
+# and more.
+HYBRID_TOLERANCE = 0.03
+
+
+def hybrid_check(widths: str, lens: list, seed: int) -> dict:
+    """Runs in a child that holds the chip: one KDA layer's chunkwise
+    prefill against its own stepping (outputs, the final state S and the
+    convolution rows), and one MLA layer's unabsorbed prefill against
+    the absorbed decode step over the latent rows, in the compute type,
+    for prompts of ``lens`` tokens. -> relative errors by length."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import ling
+
+    accelerator.claim_device()
+    # layers 0-4 KDA, layer 5 MLA; dense MLPs only (they are not run)
+    kw = dict(n_layers=6, first_k_dense=6, vocab_size=1024)
+    cfg = ling.LingConfig.tiny(**kw, dtype="bfloat16") if widths == "tiny" \
+        else ling.LingConfig(**kw)
+    layers = ling.init_params(cfg, jax.random.PRNGKey(seed))["layers"]
+    kda, mla = layers[0]["attn"], layers[5]["attn"]
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    @jax.jit
+    def both(x):
+        t = x.shape[1]
+        xs = jnp.moveaxis(x, 1, 0)[:, :, None]  # [T, 1, 1, D]
+        on = jnp.ones((1,), bool)
+        y_kda, st = ling.kda_prefill(cfg, kda, x, jnp.array([t]))
+        zero = jax.tree_util.tree_map(jnp.zeros_like, st)
+        st_step, y_kda_step = jax.lax.scan(
+            lambda s, x_t: ling.kda_step(cfg, kda, x_t, s, on)[::-1],
+            zero, xs)
+        y_mla, rows = ling.mla_prefill(cfg, mla, x)
+        cache, y_mla_step = jax.lax.scan(
+            lambda c, xp: ling.mla_step(cfg, mla, xp[0], c, xp[1])[::-1],
+            jax.tree_util.tree_map(jnp.zeros_like, rows),
+            (xs, jnp.arange(t)[:, None]))
+        rows, cache = (jnp.concatenate([c["latent"], c["k_rope"]], -1)
+                       for c in (rows, cache))
+        return {
+            "kda_out": (y_kda, jnp.moveaxis(y_kda_step[:, :, 0], 0, 1)),
+            "kda_state": (st["s"], st_step["s"]),
+            "kda_conv": (st["conv"], st_step["conv"]),
+            "mla_out": (y_mla, jnp.moveaxis(y_mla_step[:, :, 0], 0, 1)),
+            "mla_rows": (rows, cache)}
+
+    errs = {}
+    for t in lens:
+        x = jax.random.normal(jax.random.PRNGKey(seed + t),
+                              (1, t, cfg.d_model), cfg.compute_dtype)
+        errs[str(t)] = {k: rel(a, b) for k, (a, b) in both(x).items()}
+    return {"rel_err": errs, "device": accelerator.device_report()}
+
+
+def hybrid_phase(plan: Plan) -> dict:
+    out = chip_child(plan, "hybrid_check", {
+        "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
+        "seed": plan.seed})
+    worst = max(v for by_len in out["rel_err"].values()
+                for v in by_len.values())
+    check(worst <= HYBRID_TOLERANCE,
+          "a hybrid layer's two forms part (KDA chunkwise / stepping, "
+          "MLA unabsorbed / absorbed)", got=out["rel_err"],
+          tolerance=HYBRID_TOLERANCE)
+    return {"device": check_device(plan, out["device"], 1, "hybrid child"),
+            "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
+            "tolerance": HYBRID_TOLERANCE,
+            "compile_s": out["device"]["compile"]["seconds"]}
+
+
 # ------------------------------------------------------------------ run
 
 ONE_CHIP = (("serve", serve_phase), ("train", train_phase),
-            ("kernels", kernels_phase))
+            ("kernels", kernels_phase), ("hybrid", hybrid_phase))
 FOUR_CHIPS = (("train4", train4_phase), ("pool4", pool4_phase))
 
 

@@ -14,6 +14,12 @@ are paid once per chunk, not per token. Admission happens at chunk
 boundaries — continuous batching at chunk granularity.
 Prefill runs per stream at a bucketed prompt length (one compile per
 bucket) into a temp slot-1 cache, then scatters into the slot's rows.
+
+What a slot HOLDS is the model's (:func:`slot_model`): for the Llama
+block rows of k and v, for a model with recurrent or latent layers
+whatever its own module says. The engine carries that state, donates
+it to its two programs and reads ``state["pos"]``; it looks at nothing
+else.
 """
 
 from __future__ import annotations
@@ -74,6 +80,26 @@ def _get_metrics():
                 tag_keys=("engine",)),
         }
     return _metrics
+
+
+def slot_model(cfg):
+    """The model's half of the engine, found from its configuration: a
+    configuration that is no ``LlamaConfig`` carries its own as
+    ``cfg.slot_model``; the Llama block's is :class:`_LlamaSlots`, whose
+    docstring is the protocol."""
+    return getattr(cfg, "slot_model", _LlamaSlots)
+
+
+def require_rows(cfg, mechanism: str) -> None:
+    """Raise for a model whose slot state is not rows of positions:
+    ``mechanism`` (the prefix cache, speculative decoding, a prefill
+    worker) cuts, copies or rewinds a state at a position, which a
+    recurrent state does not have."""
+    if not slot_model(cfg).rows_state:
+        raise ValueError(
+            f"{mechanism} needs a slot state that is rows of positions; "
+            f"{type(cfg).__name__}'s is its own (recurrent or latent "
+            "layers) and cannot be cut at a position")
 
 
 def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
@@ -270,23 +296,24 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
     temperature 0 then decodes greedily too, with bit-identical tokens,
     and every token carries its logprob. Inactive slots re-write
     garbage at their frozen pos (invisible: their mask never advances;
-    a later prefill overwrites). The donated cache is loop state of the
-    step loop and, inside it, of the layer loop
+    a later prefill overwrites). The donated cache (the model's slot
+    state, :func:`slot_model`) is loop state of the step loop and, for
+    the Llama block, inside it of the layer loop
     (:func:`_layers_ragged`): a step writes B rows a layer into the
     stack and reads one layer of it; no layer's cache is sliced out and
     written back, and none is repeated for its query group. Returns
     ([B, chunk] tokens, [B, chunk] f32 logprobs or without lanes
     ``None``, new cache, [B] last token) and, for a model that reports
-    its routing, ``experts_touched`` [chunk, L] (``_experts_touched``)."""
-    max_len = cache["k"].shape[2]
-    layers, attach, w_out = _split_model(cfg, params)
+    its routing, its step counters, [chunk, L] each (the Llama block:
+    ``experts_touched``, see ``_experts_touched``)."""
+    model = slot_model(cfg)
+    max_len = model.max_len(cache)
+    prepared = model.split(cfg, params)
 
     def one_step(carry, _):
-        t, k, v, pos = carry
-        logits, k, v, *touched = _step_logits(
-            cfg, params, layers, attach, w_out, t[:, None], k, v, pos,
-            pos[:, None], active)
-        logits = logits[:, 0]  # [B, V]
+        t, state, pos = carry
+        logits, state, *touched = model.step(
+            cfg, params, prepared, t, state, pos, active)
         if lanes is None:
             nxt, lp = jnp.argmax(logits, axis=-1).astype(t.dtype), None
         else:
@@ -300,14 +327,14 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
         # the cache and pump()'s pos >= max_len-1 finish check stays
         # exact instead of relying on overflow
         pos = jnp.minimum(pos + active.astype(pos.dtype), max_len - 1)
-        return (nxt, k, v, pos), (nxt, lp, touched)
+        return (nxt, state, pos), (nxt, lp, touched)
 
-    (last, k, v, pos), (toks, lps, touched) = jax.lax.scan(
-        one_step, (tok, cache["k"], cache["v"], cache["pos"]),
-        None, length=chunk)
+    state = {k: v for k, v in cache.items() if k != "pos"}
+    (last, state, pos), (toks, lps, touched) = jax.lax.scan(
+        one_step, (tok, state, cache["pos"]), None, length=chunk)
     return (jnp.moveaxis(toks, 0, 1),
             None if lanes is None else jnp.moveaxis(lps, 0, 1),
-            {"k": k, "v": v, "pos": pos}, last, *touched)
+            {**state, "pos": pos}, last, *touched)
 
 
 @functools.partial(jax.jit,
@@ -348,6 +375,7 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     counts [B, rounds] — tokens emitted per round (0 for inactive
     slots), new cache, [B] last token) and, for a model that reports
     its routing, the verify passes' ``experts_touched`` [rounds, L]."""
+    require_rows(cfg, "speculative decoding (decode_chunk_spec)")
     max_len = cache["k"].shape[2]
     b = tok.shape[0]
     t_wide = depth + 1
@@ -472,15 +500,11 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     prompt, and the new stream's growing mask — or a clamped write at
     row max_len-1 from a slot that decoded to the cache edge — would
     eventually attend over stale tokens."""
-    k, v, full_lens, toks0, logp0, *expert_tokens = _prefill_core(
+    model = slot_model(cfg)
+    streams, full_lens, toks0, logp0, *expert_tokens = model.prefill(
         params, prompts, true_lens, seeds, temps, top_ps, cfg,
-        cache["k"].shape[2], prefix)
-    # k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
-    cache = {
-        "k": cache["k"].at[:, slots].set(k),
-        "v": cache["v"].at[:, slots].set(v),
-        "pos": cache["pos"].at[slots].set(full_lens),
-    }
+        model.max_len(cache), prefix)
+    cache = model.scatter(cache, slots, streams, full_lens)
     return (cache, cur_tok.at[slots].set(toks0), toks0, logp0,
             *expert_tokens)
 
@@ -497,6 +521,66 @@ def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
     return (jnp.sum(hit * real[None, :, :, None, None], axis=(1, 2, 3)),)
 
 
+class _LlamaSlots:
+    """The Llama block's half of the engine, and the protocol a model
+    with a slot state of its own implements (``models/ling.py``):
+
+    - ``rows_state``: whether a slot's state is rows of positions that
+      can be cut, copied and rewound at any position (what the prefix
+      cache, speculative decoding and the prefill workers need);
+    - ``serving_params(cfg, params)``: the tree a replica holds;
+    - ``init_state(cfg, slots, max_len)``: every slot's state, a dict
+      with ``pos`` [slots]; ``max_len(state)``; ``state_bytes(state)``
+      by kind;
+    - ``split(cfg, params)``: what a chunk prepares once;
+      ``step(cfg, params, prepared, tok, state, pos, active)``: one
+      token a slot on ``state`` (the dict without ``pos``) -> (float32
+      logits [B, V], state, *step counters named by ``step_counters``);
+    - ``prefill(params, prompts, true_lens, seeds, temps, top_ps, cfg,
+      slot_len, prefix)`` -> (the streams' state, whole prompt lengths,
+      first tokens, their logprobs, *per-expert assignment counts);
+      ``scatter(state, slots, streams, full_lens)``: that state into
+      its slots, each slot REPLACED whole (a reused slot keeps nothing
+      of its last stream);
+    - ``reports_routing(cfg)``."""
+
+    rows_state = True
+    step_counters = ("experts_touched",)
+    serving_params = staticmethod(llama.serving_params)
+    reports_routing = staticmethod(llama.reports_routing)
+    init_state = staticmethod(init_ragged_cache)
+    split = staticmethod(_split_model)
+
+    @staticmethod
+    def max_len(state: dict) -> int:
+        return state["k"].shape[2]
+
+    @staticmethod
+    def state_bytes(state: dict) -> dict:
+        return {"kv": state["k"].nbytes + state["v"].nbytes}
+
+    @staticmethod
+    def step(cfg, params, prepared, tok, state, pos, active):
+        logits, k, v, *touched = _step_logits(
+            cfg, params, *prepared, tok[:, None], state["k"], state["v"],
+            pos, pos[:, None], active)
+        return logits[:, 0], {"k": k, "v": v}, *touched
+
+    @staticmethod
+    def prefill(*args):
+        k, v, *rest = _prefill_core(*args)
+        return {"k": k, "v": v}, *rest
+
+    @staticmethod
+    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
+        # k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
+        return {
+            "k": state["k"].at[:, slots].set(streams["k"]),
+            "v": state["v"].at[:, slots].set(streams["v"]),
+            "pos": state["pos"].at[slots].set(full_lens),
+        }
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
 def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
                cfg: LlamaConfig, slot_len: int):
@@ -508,6 +592,7 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
     slot with `RaggedDecoder.submit_prefilled` — the same prefill and
     lane as an inline one, so the adopted stream is bit-identical to an
     inline-prefilled one, greedy or sampled."""
+    require_rows(cfg, "disaggregated prefill (prefill_kv)")
     k, v, _, toks0, logp0, *_ = _prefill_core(
         params, prompts, true_lens, seeds, temps, top_ps, cfg, slot_len)
     return k, v, toks0, logp0
@@ -532,16 +617,17 @@ def _nbytes(tree) -> int:
     return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
 
 
-def adopt_weights(cfg: LlamaConfig, params, version: int):
+def adopt_weights(cfg, params, version: int):
     """The one way a serving process takes weights in: -> the serving
-    tree of ``params`` (``llama.serving_params``). The cast runs here,
+    tree of ``params`` (the model's ``serving_params``). The cast runs here,
     once an adoption (a replica's start, a weight publish), and is
     waited for, so the ``serve.weights_cast`` span (ring-only) is what
     the adoption cost; the caller keeps the serving tree alone and lets
     go of ``params``."""
     with _fr.span("serve", "serve.weights_cast", flush=False, attrs={
             "version": int(version), "bytes_in": _nbytes(params)}) as sp:
-        serving = jax.block_until_ready(llama.serving_params(cfg, params))
+        serving = jax.block_until_ready(
+            slot_model(cfg).serving_params(cfg, params))
         sp["bytes_out"] = _nbytes(serving)
     return serving
 
@@ -618,15 +704,23 @@ class RaggedDecoder:
     Thread-unsafe by design: ONE pump owner (the serve replica's loop
     thread) drives it; submit/result queues are the boundary."""
 
-    def __init__(self, params, cfg: LlamaConfig, *, slots: int = 8,
+    def __init__(self, params, cfg, *, slots: int = 8,
                  max_len: int = 512, chunk_tokens: int = 32,
                  prompt_buckets: tuple = (32, 64, 128, 256),
                  prefix_cache=None, name: str = "default",
                  chunk_delay_s: float = 0.0, weights_version: int = 0,
                  spec_depth: int = 0, spec_draft_layers: int = 0,
                  spec_draft_head=None):
-        # the serving tree (llama.serving_params), the only weights the
-        # engine holds: the caller's f32 masters are not kept
+        # ``cfg`` is the model's own configuration (a ``LlamaConfig``,
+        # or one that carries its ``slot_model``); the model's half of
+        # the engine is found from it
+        self.model = slot_model(cfg)
+        if prefix_cache is not None:
+            require_rows(cfg, "the prefix cache (kv_prefix_cache)")
+        if int(spec_depth) > 0:
+            require_rows(cfg, "speculative decoding (spec_depth > 0)")
+        # the serving tree (the model's serving_params), the only weights
+        # the engine holds: the caller's f32 masters are not kept
         self.params = adopt_weights(cfg, params, weights_version)
         # Emulated per-chunk device time for exercising the SERVING
         # tier on hosts without an accelerator: on a TPU each chunk
@@ -641,7 +735,10 @@ class RaggedDecoder:
         self.max_len = max_len
         self.chunk = chunk_tokens
         self.buckets = tuple(sorted(prompt_buckets))
-        self.cache = init_ragged_cache(cfg, slots, max_len)
+        # every slot's state, the model's: carried, donated to the two
+        # programs, never looked into (``pos`` apart)
+        self.cache = self.model.init_state(cfg, slots, max_len)
+        self.state_bytes = self.model.state_bytes(self.cache)
         self.cur_tok = jnp.zeros((slots,), jnp.int32)
         # per-slot sampling lanes, rewritten at admission; frozen slots'
         # values are dead (their sampled token is overwritten anyway)
@@ -701,6 +798,18 @@ class RaggedDecoder:
         # (stamp, n_tokens) per pump for the tokens/s scaling signal
         self._rate_window: collections.deque = collections.deque()
         self._metrics_t = 0.0
+        self.mark_state()
+
+    def mark_state(self) -> None:
+        """``engine.state_init`` (ring-only, and an instant event on the
+        profiler's host line): what the slots hold, ``<kind>_bytes`` for
+        each kind of state the model keeps. Once an engine, and again
+        where a trace starts (``LLMServer.start_trace``), so that a
+        trace says what engine it is of."""
+        _fr.mark("serve", "engine.state_init", flush=False, attrs={
+            "engine": self.name, "slots": self.slots,
+            "max_len": self.max_len,
+            **{f"{kind}_bytes": n for kind, n in self.state_bytes.items()}})
 
     # -- submission boundary --
 
@@ -726,6 +835,7 @@ class RaggedDecoder:
         {"k"/"v": [n_layers, S, n_kv_heads, head_dim] with S == this
         engine's max_len, "first_token": int, "true_len": int}.
         Admission is a pure slot scatter — no prefill dispatch."""
+        require_rows(self.cfg, "disaggregated prefill (submit_prefilled)")
         k = np.asarray(kv["k"])
         if k.shape[1] != self.max_len:
             raise ValueError(
@@ -1061,16 +1171,20 @@ class RaggedDecoder:
 
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
         """The routing counters a read-back brought: ``touched`` holds
-        the chunk's [steps, L] experts touched (or nothing), ``loads``
-        one [L, E] array of assignments per prefill call since the last
-        read-back. Span attrs: ``experts_touched`` (mean over the
-        chunk's steps and layers) and, with a prefill's counts,
+        the chunk's [steps, L] step counters in the order of the model's
+        ``step_counters`` (or nothing), ``loads`` one [L, E] array of
+        assignments per prefill call since the last read-back (a model
+        that holds a part of its experts counts those it holds). Span
+        attrs: each step counter's mean over the chunk's steps and
+        layers (``experts_touched``; with held experts ``assignments``
+        and ``held_assignments`` too) and, with a prefill's counts,
         ``expert_load_max`` / ``expert_load_mean`` (assignments on the
         fullest expert and the mean over experts, of one call's layers;
         means over the calls where there were several)."""
-        for t in touched:
-            self.moe_touched_expert_steps += int(t.sum())
-            sp["experts_touched"] = float(t.mean())
+        for name, t in zip(self.model.step_counters, touched):
+            sp[name] = float(t.mean())
+            if name == "experts_touched":
+                self.moe_touched_expert_steps += int(t.sum())
         if loads:
             self.moe_assignments += int(sum(a.sum() for a in loads))
             sp["expert_load_max"] = float(
@@ -1249,7 +1363,8 @@ class RaggedDecoder:
         collective OpStats family — and monotonic totals an outside
         reader takes deltas of (``total_tokens``, ``pumps``,
         ``prefill_calls``: cold prefills, one prompt each;
-        ``weights_bytes``: what the serving tree holds on the device;
+        ``weights_bytes``: what the serving tree holds on the device,
+        ``state_bytes``: what the slots' state holds there, by kind;
         for a mixture-of-experts model ``moe_assignments`` and
         ``moe_touched_expert_steps``, see ``__init__``)."""
         active = sum(1 for st in self.slot_stream if st is not None)
@@ -1261,10 +1376,11 @@ class RaggedDecoder:
             "total_tokens": self._total_tokens,
             "weights_version": self.weights_version,
             "weights_bytes": _nbytes(self.params),
+            "state_bytes": dict(self.state_bytes),
             "pumps": self.pumps,
             "prefill_calls": self.prefill_calls,
         }
-        if llama.reports_routing(self.cfg):
+        if self.model.reports_routing(self.cfg):
             out["moe_assignments"] = self.moe_assignments
             out["moe_touched_expert_steps"] = self.moe_touched_expert_steps
         if self.prefix_cache is not None:

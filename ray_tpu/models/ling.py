@@ -1,0 +1,818 @@
+"""A hybrid decoder: delta-rule linear attention (KDA) beside latent
+attention (MLA), and a group-limited sigmoid router over experts of
+which this device holds a part. The language model of Ling-3.0-flash-VL
+as its ``config.json`` gives it; the second block beside ``llama.py``.
+
+Three kinds of layer in one stack. Layer ``i`` attends with **MLA** if
+``(i + 1) % layer_group_size == 0`` and with **KDA** otherwise; its MLP
+is a dense SwiGLU if ``i < first_k_dense`` and the mixture of experts
+after that. The layers are NOT a stack scanned by one loop: each is its
+own dict of leaves and the programs unroll them, so no layer's weights
+are ever sliced out of a stack.
+
+- **KDA** (Kimi Delta Attention, arXiv:2510.26692): q, k, v through a
+  causal depthwise convolution (kernel 4) and SiLU; q and k L2-normalised
+  a head; a per-channel decay ``log a = lower_bound * sigmoid(exp(A_log)
+  * (x W_f + dt_bias))`` and a per-head ``beta = sigmoid(x W_beta)``; a
+  float32 state ``S [H, dk, dv]`` a stream: ``S <- Diag(a) S``,
+  ``S <- S + beta k (v - S^T k)^T``, ``o = S^T q``; a head-wise RMS norm
+  gated by ``sigmoid(x W_g)``. A decode step is that recurrence
+  (:func:`kda_step`); prefill is its chunkwise form (:func:`kda_chunked`,
+  chunks of ``kda_chunk``) and leaves the same ``S`` and the same last
+  ``conv_kernel - 1`` convolution inputs. No position encoding.
+- **MLA** (DeepSeek-V2 section 2.1, no query compression): a cache row
+  is the normalised latent and the one rotated key all heads share
+  (``kv_lora_rank + qk_rope_head_dim`` numbers a token). Prefill attends
+  unabsorbed; a decode step attends in the absorbed form over the latent
+  rows (:func:`mla_step`). A learned RMS norm over each head's whole q;
+  a head-wise sigmoid gate on the output.
+- **MoE** (DeepSeek-V3's routing): sigmoid scores in float32, a bias
+  added for selection only, ``topk_group`` of ``n_group`` groups kept by
+  the sum of their two best, the ``top_k`` best of those chosen, their
+  unbiased scores renormalised and scaled; a shared expert beside them.
+  ``held_experts = (first, count)`` tells the layer which experts live
+  here: it routes over all of them and computes the part of the result
+  that its own give (:func:`moe`); what the others would add is left out.
+
+A slot's state in the serving engine is this model's own
+(:data:`SLOTS`, what ``decode_engine.slot_model`` finds through
+``LingConfig.slot_model``): for a KDA layer ``S`` and the convolution
+rows, for an MLA layer ``max_len`` rows of the latent and of the rotated
+key. It is not rows that can
+be cut at a position, so the prefix cache, speculative decoding and the
+prefill workers refuse this model by name (``rows_state``).
+
+Types: matrices in ``dtype`` (bf16 as published), products accumulated
+in float32; norm vectors, ``a_log``, ``dt_bias`` and the router's bias
+float32; router scores, softmax and the KDA state float32.
+:func:`init_params` makes the tree in those types leaf by leaf, in
+blocks: the whole model as float32 masters does not fit the chip that
+serves it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import llama
+from ray_tpu.models.decode_engine import _sample_from_logits
+from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.rope import apply_rotary, rotary_embedding
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class LingConfig:
+    vocab_size: int = 157184
+    d_model: int = 2560
+    n_layers: int = 42
+    n_heads: int = 32
+    first_k_dense: int = 2
+    layer_group_size: int = 6  # every sixth layer attends with MLA
+    dense_d_ff: int = 6144
+    # mixture of experts: d_ff is ONE expert's width
+    d_ff: int = 768
+    shared_d_ff: int = 768
+    n_experts: int = 512
+    top_k: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    # (first, count): the experts this device holds; None = all of them
+    held_experts: tuple | None = None
+    # KDA
+    kda_head_dim: int = 128  # key and value width of a head
+    conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    # MLA
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 6e6
+    rms_eps: float = 1e-6
+    max_seq_len: int = 4096
+    dtype: str = "bfloat16"
+    # the depth the weights are initialised for (init_params); 0 =
+    # n_layers. A configuration cut in depth names its model's own.
+    published_layers: int = 0
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def held(self) -> tuple:
+        return self.held_experts or (0, self.n_experts)
+
+    def attn_kind(self, i: int) -> str:
+        return "mla" if (i + 1) % self.layer_group_size == 0 else "kda"
+
+    def mlp_kind(self, i: int) -> str:
+        return "dense" if i < self.first_k_dense else "moe"
+
+    @property
+    def moe_layers(self) -> int:
+        return self.n_layers - min(self.first_k_dense, self.n_layers)
+
+    @property
+    def slot_model(self):
+        return SLOTS
+
+    @staticmethod
+    def tiny(**kw) -> "LingConfig":
+        """Test-size config: every kind of layer, runs on the CPU."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=7, n_heads=4,
+            first_k_dense=1, layer_group_size=6, dense_d_ff=128, d_ff=32,
+            shared_d_ff=32, n_experts=32, top_k=4, n_group=4, topk_group=2,
+            kda_head_dim=16, kda_chunk=8, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            rope_theta=1e4, max_seq_len=128, dtype="float32")
+        base.update(kw)
+        return LingConfig(**base)
+
+
+# --------------------------------------------------------------------------
+# Parameters
+# --------------------------------------------------------------------------
+
+# leaves the model paths consume in float32
+_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "q_norm",
+               "kv_norm", "a_log", "dt_bias", "router_bias")
+_BLOCK_ELEMS = 1 << 22  # a leaf is drawn in float32 blocks of this many
+
+
+def _draw(key, shape, scale: float, dtype):
+    """Normal(0, scale) of ``shape`` in ``dtype``, drawn in float32
+    blocks along the leading axis and rounded block by block: a 250 M
+    leaf never exists in float32. The bits come from the device's own
+    generator (an ``rbg`` key made of ``key``): threefry in XLA
+    operations takes over a minute for 5 B numbers on the chip."""
+    data = jax.random.key_data(key) if jnp.issubdtype(
+        key.dtype, jax.dtypes.prng_key) else key
+    key = jax.random.wrap_key_data(
+        jnp.concatenate([data, data ^ jnp.uint32(0x9E3779B9)]), impl="rbg")
+    n = shape[0]
+    rows = max(1, _BLOCK_ELEMS // max(1, math.prod(shape[1:])))
+    rows = max(r for r in range(1, min(rows, n) + 1) if n % r == 0)
+    if rows == n:
+        return (jax.random.normal(key, shape, jnp.float32)
+                * scale).astype(dtype)
+    blocks = jax.lax.map(
+        lambda k: (jax.random.normal(k, (rows, *shape[1:]), jnp.float32)
+                   * scale).astype(dtype),
+        jax.random.split(key, n // rows))
+    return blocks.reshape(shape)
+
+
+def init_params(cfg: LingConfig, key):
+    """The tree in the SERVING types (module docstring). Matrices are
+    normal / sqrt(fan_in), and those that write into the residual stream
+    (``wo``, every ``w_down``) are scaled by (2 x depth)^-1/2 besides
+    (GPT-2's and Megatron's scaled initialisation; depth is
+    ``published_layers``, the model's own where the configuration is cut
+    in depth: the layers kept write into the stream of the whole
+    model). Unscaled, one near-tie of the router that bf16 decides the
+    other way moves the stream by a sixth of its norm, and the logits of
+    a bf16 step part from a float32 reference's by over 1 where the top
+    two are 0.5 apart (my chip run, PR 32). The norm scales are drawn around 1, the decay parameters and
+    the router's bias away from 0, so that a part left out of a path
+    shows against the reference."""
+    cdt = cfg.compute_dtype
+    d, h = cfg.d_model, cfg.n_heads
+    dk = cfg.kda_head_dim
+    first, count = cfg.held
+    keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
+
+    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
+
+    def mat(*shape, out=False):
+        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
+        return _draw(next(keys), shape, scale, cdt)
+
+    def around_one(*shape):
+        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    def kda():
+        return {
+            "w_qkv": mat(d, 3 * h * dk),
+            "conv": _draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
+                          cfg.conv_kernel ** -0.5, cdt),
+            "w_f": mat(d, h * dk),
+            "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
+            "a_log": jnp.log(jax.random.uniform(
+                next(keys), (h,), jnp.float32, 0.5, 4.0)),
+            "w_beta": mat(d, h),
+            "w_g": mat(d, h * dk),
+            "o_norm": around_one(dk),
+            "wo": mat(h * dk, d, out=True),
+        }
+
+    def mla():
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        r = cfg.kv_lora_rank
+        return {
+            "wq": mat(d, h * qk),
+            "q_norm": around_one(qk),
+            "w_kva": mat(d, r + cfg.qk_rope_head_dim),
+            "kv_norm": around_one(r),
+            "w_kvb": mat(r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "w_gate": mat(d, h),
+            "wo": mat(h * cfg.v_head_dim, d, out=True),
+        }
+
+    def dense():
+        f = cfg.dense_d_ff
+        return {"w_gate": mat(d, f), "w_up": mat(d, f),
+                "w_down": mat(f, d, out=True)}
+
+    def experts():
+        f, fs = cfg.d_ff, cfg.shared_d_ff
+        return {
+            "router": mat(d, cfg.n_experts),
+            # (small against the scores' spread of 0.2: the top 3% of
+            # sigmoids lie where a bias of 0.1 is a standard deviation
+            # of the logits, and one expert in eight took most rows)
+            "router_bias": 0.01 * jax.random.normal(
+                next(keys), (cfg.n_experts,), jnp.float32),
+            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
+            "w_down": mat(count, f, d, out=True),
+            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
+            "shared_down": mat(fs, d, out=True),
+        }
+
+    layers = []
+    for i in range(cfg.n_layers):
+        layers.append({
+            "attn_norm": around_one(d),
+            "attn": kda() if cfg.attn_kind(i) == "kda" else mla(),
+            "mlp_norm": around_one(d),
+            "mlp": dense() if cfg.mlp_kind(i) == "dense" else experts(),
+        })
+    return {
+        "embed": _draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
+        "layers": layers,
+        "final_norm": around_one(d),
+        "lm_head": mat(d, cfg.vocab_size),
+    }
+
+
+def serving_params(cfg: LingConfig, params):
+    """The tree a serving process holds (``llama.serving_params`` with
+    this block's float32 leaves): :func:`init_params` makes that tree
+    already, and it comes back itself; a published tree of another type
+    is cast once, here."""
+    return llama.serving_params(cfg, params, _F32_LEAVES)
+
+
+# --------------------------------------------------------------------------
+# KDA
+# --------------------------------------------------------------------------
+
+def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
+    """What both forms of KDA start from. x: [B, T, D] (normed);
+    ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
+    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk],
+    beta [B, T, H], the output gate [B, T, H, dk], the projection rows
+    [B, K-1+T, 3*H*dk] whose tail is the next ``conv_rows``)."""
+    b, t, _ = x.shape
+    h, dk = cfg.n_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    u = jnp.concatenate([conv_rows, x @ p["w_qkv"]], axis=1)
+    w = p["conv"].astype(f32)
+    y = sum(w[i] * u[:, i:i + t].astype(f32)
+            for i in range(cfg.conv_kernel))
+    q, k, v = (a.reshape(b, t, h, dk)
+               for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+        * dk ** -0.5
+    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    f = jnp.dot(x, p["w_f"], preferred_element_type=f32) + p["dt_bias"]
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(p["a_log"])[:, None] * f.reshape(b, t, h, dk))
+    beta = jax.nn.sigmoid(jnp.dot(x, p["w_beta"],
+                                  preferred_element_type=f32))
+    gate = jax.nn.sigmoid(jnp.dot(
+        x, p["w_g"], preferred_element_type=f32)).reshape(b, t, h, dk)
+    return q, k, v, g, beta, gate, u
+
+
+def _kda_out(cfg: LingConfig, p, o, gate):
+    """o, gate [B, T, H, dk] float32 -> [B, T, D]: the head-wise RMS
+    norm, the sigmoid gate and the output projection."""
+    b, t = o.shape[:2]
+    o = rms_norm(o, p["o_norm"], cfg.rms_eps) * gate
+    return o.reshape(b, t, -1).astype(cfg.compute_dtype) @ p["wo"]
+
+
+def kda_recurrence(s, q, k, v, g, beta):
+    """One token of the delta rule on the state s [B, H, dk, dv]
+    (float32, elementwise: no product is rounded). q, k, g [B, H, dk];
+    v [B, H, dv]; beta [B, H]. -> (s, o [B, H, dv])."""
+    s = s * jnp.exp(g)[..., None]
+    pred = jnp.sum(s * k[..., None], axis=-2)
+    s = s + (beta[..., None] * k)[..., None] * (v - pred)[..., None, :]
+    return s, jnp.sum(s * q[..., None], axis=-2)
+
+
+def kda_step(cfg: LingConfig, p, x, state, active):
+    """A decode step of a KDA layer. x [B, 1, D] (normed); ``state``
+    {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
+    is not ``active`` keeps its state. -> ([B, 1, D], state)."""
+    q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, state["conv"])
+    s, o = kda_recurrence(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                          beta[:, 0])
+    keep = active[:, None, None]
+    new = {"s": jnp.where(keep[..., None], s, state["s"]),
+           "conv": jnp.where(keep, u[:, 1:], state["conv"])}
+    return _kda_out(cfg, p, o[:, None], gate), new
+
+
+def _unit_lower_inverse(n):
+    """(I + n)^-1 for strictly lower-triangular n [..., C, C], by
+    forward substitution a row at a time in float32 elementwise
+    arithmetic (C steps, each over every chunk and head at once)."""
+    c = n.shape[-1]
+    eye = jnp.broadcast_to(jnp.eye(c, dtype=n.dtype), n.shape)
+
+    def row(i, t):
+        n_i = jax.lax.dynamic_index_in_dim(n, i, axis=-2, keepdims=False)
+        r = jax.lax.dynamic_index_in_dim(eye, i, axis=-2, keepdims=False) \
+            - jnp.sum(n_i[..., :, None] * t, axis=-2)
+        return jax.lax.dynamic_update_index_in_dim(t, r, i, axis=-2)
+
+    return jax.lax.fori_loop(1, c, row, eye)
+
+
+def kda_chunked(cfg: LingConfig, q, k, v, g, beta, s0):
+    """The chunkwise form of :func:`kda_recurrence` over T tokens (T a
+    multiple of ``kda_chunk``). q, k, g [B, T, H, dk], v [B, T, H, dv],
+    beta [B, T, H], all float32; s0 [B, H, dk, dv]. A token with
+    ``beta`` 0 and ``g`` 0 leaves the state as it was (padding).
+    -> (o [B, T, H, dv], the state after the last token).
+
+    Inside a chunk, with G the running sum of g from the chunk's start:
+    the pseudo-values U solve (I + Diag(beta) A) U = Diag(beta) (V -
+    (K * e^G) S0) with A[i, j] = sum_c k_i k_j e^(G_i - G_j) for j < i;
+    O = (Q * e^G) S0 + B U with B[i, j] = sum_c q_i k_j e^(G_i - G_j)
+    for j <= i; S' = Diag(e^G_C) S0 + (K * e^(G_C - G))^T U. The decays
+    are taken pairwise, e^(G_i - G_j) <= 1, never as e^-G_j, which
+    overflows float32 within a chunk at this model's lower bound."""
+    b, t, h, dk = q.shape
+    c = cfg.kda_chunk
+    nc = t // c
+
+    def chunks(a):  # [B, T, H, ...] -> [NC, B, H, C, ...]
+        a = a.reshape(b, nc, c, h, *a.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (chunks(a) for a in (q, k, v, g, beta))
+    gc = jnp.cumsum(g, axis=-2)  # [NC, B, H, C, dk]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+
+    def pairwise(xs):
+        q_, k_, g_, beta_ = xs
+        diff = g_[..., :, None, :] - g_[..., None, :, :]  # [B,H,C,C,dk]
+        decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
+        kd = k_[..., None, :, :] * decay
+        a_ = jnp.sum(k_[..., :, None, :] * kd, -1)
+        b_ = jnp.sum(q_[..., :, None, :] * kd, -1)
+        # strictly lower for the solve: the diagonal pairs i with itself
+        n_ = beta_[..., None] * jnp.where(jnp.tril(lower, -1), a_, 0.0)
+        return n_, b_
+
+    n, bm = jax.lax.map(pairwise, (q, k, gc, beta))
+    tinv = _unit_lower_inverse(n)  # [NC, B, H, C, C]
+    g_end = gc[..., -1:, :]
+    kg, qg = k * jnp.exp(gc), q * jnp.exp(gc)
+    k_end = k * jnp.exp(g_end - gc)
+
+    def one(s, xs):
+        kg_, qg_, k_end_, v_, beta_, tinv_, bm_, g_end_ = xs
+        mm = functools.partial(jnp.matmul, precision=_HI)
+        u = mm(tinv_, beta_[..., None] * (v_ - mm(kg_, s)))
+        o = mm(qg_, s) + mm(bm_, u)
+        s = jnp.exp(g_end_)[..., 0, :, None] * s \
+            + mm(jnp.swapaxes(k_end_, -1, -2), u)
+        return s, o
+
+    s, o = jax.lax.scan(one, s0, (kg, qg, k_end, v, beta, tinv, bm, g_end))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [B, NC, C, H, dv]
+    return o.reshape(b, t, h, -1), s
+
+
+def kda_prefill(cfg: LingConfig, p, x, true_lens):
+    """A KDA layer over whole prompts from an empty state. x [B, T, D]
+    (normed, right-padded; ``true_lens`` [B] real). -> ([B, T, D], the
+    state after each prompt's last real token)."""
+    b, t, _ = x.shape
+    h, dk = cfg.n_heads, cfg.kda_head_dim
+    kw = cfg.conv_kernel - 1
+    zeros = jnp.zeros((b, kw, 3 * h * dk), cfg.compute_dtype)
+    q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, zeros)
+    real = jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
+    g = jnp.where(real[..., None, None], g, 0.0)
+    beta = jnp.where(real[..., None], beta, 0.0)
+    pad = -t % cfg.kda_chunk
+    if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (a.ndim - 2))
+                            for a in (q, k, v, g, beta))
+    o, s = kda_chunked(cfg, q, k, v, g, beta,
+                       jnp.zeros((b, h, dk, dk), jnp.float32))
+    # the last K-1 projection rows of each prompt: u's rows
+    # true_len .. true_len + K-2 (u starts K-1 rows before the prompt)
+    rows = true_lens[:, None] + jnp.arange(kw)[None, :]
+    conv = jnp.take_along_axis(u, rows[..., None], axis=1)
+    return _kda_out(cfg, p, o[:, :t], gate), {"s": s, "conv": conv}
+
+
+# --------------------------------------------------------------------------
+# MLA
+# --------------------------------------------------------------------------
+
+def _mla_inputs(cfg: LingConfig, p, x, positions):
+    """x [B, T, D] (normed) at ``positions`` [B, T] -> (q_nope [B, T, H,
+    dn], q_rope [B, T, H, dr] rotated, the cache rows {"latent" [B, T,
+    r]: the normalised latent, "k_rope" [B, T, dr]: the rotated key all
+    heads share}, the head gate [B, T, H] float32)."""
+    b, t, _ = x.shape
+    h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    sin, cos = rotary_embedding(positions, dr, cfg.rope_theta)
+    q = rms_norm((x @ p["wq"]).reshape(b, t, h, dn + dr), p["q_norm"],
+                 cfg.rms_eps)
+    q_nope, q_rope = q[..., :dn], apply_rotary(q[..., dn:], sin, cos)
+    kva = x @ p["w_kva"]
+    latent = rms_norm(kva[..., :cfg.kv_lora_rank], p["kv_norm"],
+                      cfg.rms_eps)
+    k_rope = apply_rotary(kva[..., None, cfg.kv_lora_rank:], sin, cos)
+    gate = jax.nn.sigmoid(jnp.dot(x, p["w_gate"],
+                                  preferred_element_type=jnp.float32))
+    return q_nope, q_rope, {"latent": latent, "k_rope": k_rope[..., 0, :]}, \
+        gate
+
+
+def _mla_out(cfg: LingConfig, p, o, gate):
+    b, t = o.shape[:2]
+    o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.compute_dtype)
+    return o.reshape(b, t, -1) @ p["wo"]
+
+
+def mla_prefill(cfg: LingConfig, p, x):
+    """An MLA layer over whole prompts from position 0, unabsorbed: k
+    and v are made from the latent and attended as any attention's.
+    -> ([B, T, D], the prompts' cache rows {"latent" [B, T, r],
+    "k_rope" [B, T, dr]})."""
+    b, t, _ = x.shape
+    h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+    q_nope, q_rope, rows, gate = _mla_inputs(cfg, p, x, positions)
+    kv = (rows["latent"] @ p["w_kvb"]).reshape(b, t, h, dn + dv)
+    f32 = jnp.float32
+    logits = (jnp.einsum("bthd,bshd->bhts", q_nope, kv[..., :dn],
+                         preferred_element_type=f32)
+              + jnp.einsum("bthd,bsd->bhts", q_rope, rows["k_rope"],
+                           preferred_element_type=f32))
+    logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    probs = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
+    o = jnp.einsum("bhts,bshd->bthd", probs.astype(x.dtype), kv[..., dn:],
+                   preferred_element_type=f32)
+    return _mla_out(cfg, p, o, gate), rows
+
+
+def mla_step(cfg: LingConfig, p, x, cache, pos):
+    """A decode step of an MLA layer in the absorbed form. x [B, 1, D]
+    (normed); ``cache`` {"latent" [B, S, r], "k_rope" [B, S, dr]}, each
+    slot's rows (two arrays, so that neither product reads a slice of
+    the other's); pos [B]. The new row is written at [slot, pos] and the
+    step attends over the latent rows themselves: q_nope is carried
+    through the key half of ``w_kvb`` into the latent's space, the
+    probabilities weigh latents, and the value half brings the result
+    back. -> ([B, 1, D], cache)."""
+    b = x.shape[0]
+    h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    f32 = jnp.float32
+    q_nope, q_rope, rows, gate = _mla_inputs(cfg, p, x, pos[:, None])
+    with jax.named_scope("cache"):
+        cache = {k: cache[k].at[jnp.arange(b), pos].set(rows[k][:, 0])
+                 for k in ("latent", "k_rope")}
+    w_kvb = p["w_kvb"].reshape(r, h, dn + dv)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :dn],
+                       preferred_element_type=f32).astype(x.dtype)
+    logits = (jnp.einsum("bhr,bsr->bhs", q_lat, cache["latent"],
+                         preferred_element_type=f32)
+              + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0], cache["k_rope"],
+                           preferred_element_type=f32))
+    logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
+    live = jnp.arange(cache["latent"].shape[1])[None, :] <= pos[:, None]
+    probs = jax.nn.softmax(jnp.where(live[:, None], logits, -1e30), -1)
+    o_lat = jnp.einsum("bhs,bsr->bhr", probs.astype(x.dtype),
+                       cache["latent"], preferred_element_type=f32)
+    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype), w_kvb[..., dn:],
+                   preferred_element_type=f32)
+    return _mla_out(cfg, p, o[:, None], gate), cache
+
+
+# --------------------------------------------------------------------------
+# MLPs
+# --------------------------------------------------------------------------
+
+def _swiglu(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def route(cfg: LingConfig, scores, bias):
+    """The router's choice from its sigmoid ``scores`` [..., E] float32:
+    -> (weights [..., top_k] float32, expert ids [..., top_k]). The bias
+    moves the SELECTION only: groups are ranked by the sum of their two
+    best biased scores, the ``top_k`` best biased scores of the kept
+    groups are chosen (``lax.top_k``: exactly top_k, the lower index on
+    a tie), and the weights are the chosen experts' unbiased scores,
+    renormalised to 1 and scaled."""
+    e, ng = cfg.n_experts, cfg.n_group
+    biased = scores + bias
+    grouped = biased.reshape(*biased.shape[:-1], ng, e // ng)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.any(jax.nn.one_hot(kept, ng, dtype=jnp.bool_), axis=-2)
+    masked = jnp.where(keep[..., None], grouped, -jnp.inf)
+    _, ids = jax.lax.top_k(masked.reshape(biased.shape), cfg.top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
+        * cfg.routed_scaling_factor
+    return weights, ids
+
+
+def moe(cfg: LingConfig, p, x, aux: dict | None = None):
+    """The expert layer of a device that holds ``cfg.held`` = (first,
+    count) of the experts. x [B, T, D]. Every token is routed over ALL
+    experts; an assignment to an expert that is not held is left out:
+    in the sort by expert it falls behind the held ones, into rows that
+    belong to no group, which the grouped matmul never visits
+    (``ops/grouped_matmul.py``: its grid covers the groups' rows only;
+    off the TPU ``ragged_dot`` leaves such rows zero), its gather reads
+    row 0 and its part of the sum is masked. The shared expert is
+    computed in full. On one device the layer runs without an exchange:
+    the other devices' partial sums are not here and nothing stands in
+    for them. With ``aux`` the chosen ids [B, T, top_k] are left in
+    ``aux["expert_ids"]``."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    cdt = cfg.compute_dtype
+    b, t, d = x.shape
+    kk = cfg.top_k
+    first, count = cfg.held
+    xf = x.reshape(b * t, d)
+    with jax.named_scope("moe_router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            xf, p["router"], preferred_element_type=jnp.float32))
+        weights, ids = route(cfg, scores, p["router_bias"])
+        if aux is not None:
+            aux["expert_ids"] = ids.reshape(b, t, kk)
+    with jax.named_scope("moe_experts"):
+        local = ids.reshape(-1) - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count)  # the others sort last
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.sum(
+            jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
+        rows = xf[jnp.where(held[order], order // kk, 0)]
+        experts = functools.partial(grouped_matmul,
+                                    group_sizes=group_sizes)
+        gate = experts(rows, p["w_gate"])
+        up = experts(rows, p["w_up"])
+        y = experts(jax.nn.silu(gate) * up, p["w_down"])
+        unsort = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
+        out = jnp.sum(y.reshape(b * t, kk, d) * weights[..., None], axis=1)
+    with jax.named_scope("moe_shared"):
+        out = out.astype(cdt) + _swiglu(
+            xf, p["shared_gate"], p["shared_up"], p["shared_down"])
+    return out.reshape(b, t, d)
+
+
+def _mlp(cfg: LingConfig, i: int, p, x, aux: dict | None = None):
+    with jax.named_scope("mlp"):
+        if cfg.mlp_kind(i) == "dense":
+            return _swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
+        return moe(cfg, p, x, aux)
+
+
+# --------------------------------------------------------------------------
+# The model: whole sequences, prefill into a slot's state, a ragged step
+# --------------------------------------------------------------------------
+
+def _logits(cfg: LingConfig, params, h):
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
+
+
+def prefill(params, tokens, true_lens, cfg: LingConfig,
+            aux: dict | None = None):
+    """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
+    state -> (h [B, T, D] before the final norm, the streams' state: a
+    list with one entry a layer, {"s", "conv"} or {"latent", "k_rope"}:
+    the T cache rows, padding's among them). With
+    ``aux`` every expert layer's ids are left in ``aux["expert_ids"]``
+    [L_moe, B, T, top_k]."""
+    h = params["embed"][tokens]
+    state, ids = [], []
+    for i, p in enumerate(params["layers"]):
+        with jax.named_scope("attn"):
+            x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
+            if cfg.attn_kind(i) == "kda":
+                y, st = kda_prefill(cfg, p["attn"], x, true_lens)
+            else:
+                y, st = mla_prefill(cfg, p["attn"], x)
+        h = h + y
+        state.append(st)
+        layer_aux = {} if aux is not None else None
+        h = h + _mlp(cfg, i, p["mlp"],
+                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), layer_aux)
+        if layer_aux:
+            ids.append(layer_aux["expert_ids"])
+    if ids:
+        aux["expert_ids"] = jnp.stack(ids)
+    return h, state
+
+
+def forward(params, tokens, cfg: LingConfig):
+    """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
+    chunkwise KDA and the unabsorbed MLA."""
+    b, t = tokens.shape
+    h, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
+    return _logits(cfg, params, h)
+
+
+def loss_fn(params, batch, cfg: LingConfig):
+    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
+    (or inputs / targets). No cell trains this block: the forward is
+    the serving one, in the serving types."""
+    from ray_tpu.ops.losses import softmax_cross_entropy
+
+    if "inputs" in batch:
+        inputs, targets = batch["inputs"], batch["targets"]
+    else:
+        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
+                                    mask=batch.get("mask"))
+    return loss, {"loss": loss, "tokens": n}
+
+
+def step(cfg: LingConfig, params, tok, layers_state, pos, active):
+    """One token a slot at PER-SLOT positions. tok, pos, active [B];
+    ``layers_state`` as :func:`prefill` leaves it, over all slots.
+    -> (float32 logits [B, V], the state updated, and for a model with
+    expert layers three [L_moe] int32 counters of the ACTIVE slots'
+    routing: distinct held experts touched, assignments, assignments
+    to held experts)."""
+    h = params["embed"][tok][:, None]  # [B, 1, D]
+    new_state, counts = [], []
+    for i, (p, st) in enumerate(zip(params["layers"], layers_state)):
+        with jax.named_scope("attn"):
+            x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
+            if cfg.attn_kind(i) == "kda":
+                y, st = kda_step(cfg, p["attn"], x, st, active)
+            else:
+                y, st = mla_step(cfg, p["attn"], x, st, pos)
+        h = h + y
+        new_state.append(st)
+        aux = {} if cfg.mlp_kind(i) == "moe" else None
+        h = h + _mlp(cfg, i, p["mlp"],
+                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), aux)
+        if aux:
+            counts.append(_routing_counts(cfg, aux["expert_ids"], active))
+    counters = tuple(jnp.stack(c) for c in zip(*counts))
+    return _logits(cfg, params, h)[:, 0], new_state, *counters
+
+
+def _routing_counts(cfg: LingConfig, ids, active) -> tuple:
+    """ids [B, 1, top_k] of one expert layer -> (distinct held experts
+    that got a row from an active slot, the active slots' assignments,
+    those of them to held experts), int32 scalars."""
+    first, count = cfg.held
+    hit = jax.nn.one_hot(ids - first, count, dtype=jnp.bool_)
+    hit = hit & active[:, None, None, None]
+    return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),
+            jnp.sum(active, dtype=jnp.int32) * ids.shape[-1],
+            jnp.sum(hit, dtype=jnp.int32))
+
+
+# --------------------------------------------------------------------------
+# The serving engine's half (decode_engine.slot_model's protocol)
+# --------------------------------------------------------------------------
+
+class _Slots:
+    """What ``models/decode_engine.py`` asks of a model whose slot state
+    is its own. The engine carries the state, donates it to its two
+    programs and reads ``state["pos"]``; it looks at nothing else."""
+
+    # the state is not rows that can be cut at a position
+    rows_state = False
+    step_counters = ("experts_touched", "assignments", "held_assignments")
+    serving_params = staticmethod(serving_params)
+
+    @staticmethod
+    def reports_routing(cfg: LingConfig) -> bool:
+        return cfg.moe_layers > 0
+
+    @staticmethod
+    def init_state(cfg: LingConfig, slots: int, max_len: int) -> dict:
+        h, dk = cfg.n_heads, cfg.kda_head_dim
+        cdt = cfg.compute_dtype
+        layers = []
+        for i in range(cfg.n_layers):
+            if cfg.attn_kind(i) == "kda":
+                layers.append({
+                    "s": jnp.zeros((slots, h, dk, dk), jnp.float32),
+                    "conv": jnp.zeros((slots, cfg.conv_kernel - 1,
+                                       3 * h * dk), cdt)})
+            else:
+                layers.append({
+                    "latent": jnp.zeros(
+                        (slots, max_len, cfg.kv_lora_rank), cdt),
+                    "k_rope": jnp.zeros(
+                        (slots, max_len, cfg.qk_rope_head_dim), cdt)})
+        return {"layers": layers, "max_len": jnp.int32(max_len),
+                "pos": jnp.zeros((slots,), jnp.int32)}
+
+    @staticmethod
+    def max_len(state: dict):
+        # (a scalar on the device: a recurrent state has no shape that
+        # says how far a slot's position may grow)
+        return state["max_len"]
+
+    @staticmethod
+    def state_bytes(state: dict) -> dict:
+        by_kind = {"recurrent": 0, "latent": 0}
+        for st in state["layers"]:
+            kind = "latent" if "latent" in st else "recurrent"
+            by_kind[kind] += sum(a.size * a.dtype.itemsize
+                                 for a in st.values())
+        return by_kind
+
+    @staticmethod
+    def split(cfg: LingConfig, params):
+        return None
+
+    @staticmethod
+    def step(cfg: LingConfig, params, prepared, tok, state, pos, active):
+        logits, layers, *counters = step(
+            cfg, params, tok, state["layers"], pos, active)
+        return logits, {**state, "layers": layers}, *counters
+
+    @staticmethod
+    def prefill(params, prompts, true_lens, seeds, temps, top_ps,
+                cfg: LingConfig, slot_len: int, prefix=None):
+        """Whole prompts from EMPTY state (a reused slot starts from a
+        zero ``S`` and zero convolution rows: the state returned here
+        replaces the slot's whole). -> (the streams' state, [F] prompt
+        lengths, [F] first tokens, [F] their logprobs, the held experts'
+        assignments from the real positions [L_moe, count])."""
+        if prefix is not None:
+            raise ValueError(
+                "a prefix of cached rows cannot seed this model's slot: "
+                "its state is recurrent (KDA), not rows")
+        aux = {} if cfg.moe_layers else None
+        h, layers = prefill(params, prompts, true_lens, cfg, aux)
+        f = prompts.shape[0]
+        last = _logits(cfg, params, h[jnp.arange(f), true_lens - 1][:, None])
+        toks0, logp0 = _sample_from_logits(
+            last[:, 0], seeds, true_lens - 1, temps, top_ps)
+        loads = ()
+        if aux:
+            first, count = cfg.held
+            ids = aux["expert_ids"]  # [L_moe, F, P, top_k]
+            real = jnp.arange(ids.shape[2])[None, :] < true_lens[:, None]
+            hit = jax.nn.one_hot(ids - first, count, dtype=jnp.int32)
+            loads = (jnp.sum(hit * real[None, :, :, None, None],
+                             axis=(1, 2, 3)),)
+        return {"layers": layers}, true_lens, toks0, logp0, *loads
+
+    @staticmethod
+    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
+        """The prefilled streams' state into their slots, every leaf of
+        a slot replaced whole (a prompt's latent rows, zeros behind)."""
+        def put(all_, new):
+            whole = jnp.zeros((new.shape[0], *all_.shape[1:]), all_.dtype)
+            return all_.at[slots].set(
+                whole.at[:, :new.shape[1]].set(new.astype(all_.dtype)))
+
+        layers = jax.tree_util.tree_map(put, state["layers"],
+                                        streams["layers"])
+        return {**state, "layers": layers,
+                "pos": state["pos"].at[slots].set(full_lens)}
+
+
+SLOTS = _Slots

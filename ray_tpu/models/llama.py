@@ -357,7 +357,7 @@ def _cast_leaves(leaves, dtype):
     return [a.astype(dtype) for a in leaves]
 
 
-def serving_params(cfg: LlamaConfig, params):
+def serving_params(cfg, params, f32_leaves=_F32_LEAVES):
     """The tree a SERVING process holds: every leaf in the type the
     cached model paths consume it in. The matrices (embedding, head,
     projections, MLP or experts, router), which those paths round to
@@ -369,11 +369,13 @@ def serving_params(cfg: LlamaConfig, params):
     it masters). A tree already in those types comes back itself, no
     copy; with ``dtype="float32"`` that is every f32 tree. Called where
     a replica adopts weights (``decode_engine.adopt_weights``), never
-    on the request path."""
+    on the request path. ``cfg`` is any configuration with a
+    ``compute_dtype``; ``f32_leaves`` names the leaves ITS model paths
+    consume in float32 (another block's own: ``models/ling.py``)."""
     cdt = cfg.compute_dtype
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     todo = [i for i, (path, leaf) in enumerate(flat)
-            if getattr(path[-1], "key", None) not in _F32_LEAVES
+            if getattr(path[-1], "key", None) not in f32_leaves
             and leaf.dtype != cdt]
     if not todo:
         return params

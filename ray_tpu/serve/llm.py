@@ -505,6 +505,7 @@ class LLMServer:
         import jax
 
         jax.profiler.start_trace(log_dir)
+        self.engine.mark_state()  # the trace says what engine it is of
         return True
 
     def stop_trace(self) -> bool:

@@ -79,12 +79,14 @@ class PrefillWorker:
 
         from ray_tpu._private import accelerator
 
-        from ray_tpu.models.decode_engine import adopt_weights
+        from ray_tpu.models.decode_engine import (adopt_weights,
+                                                  require_rows)
 
         accelerator.claim_device()
         params, self.cfg = build_model(
             model_size, max_len=max_len, vocab_size=vocab_size,
             seed=seed, params_blob=params_blob)
+        require_rows(self.cfg, "a prefill worker (PrefillWorker)")
         # the serving tree, as a decode replica holds it: prefill_kv is
         # the engine's own prefill program
         self.params = adopt_weights(self.cfg, params, 0)

@@ -140,6 +140,23 @@ def test_kernels_phase_rehearses_on_the_cpu():
     assert not facts["kernel_in_forward"]  # the CPU takes the reference
 
 
+def test_hybrid_phase_rehearses_on_the_cpu(capsys):
+    """The KDA / MLA block's two forms of each layer at tiny widths, in
+    bf16: prompts of 9 and 21 tokens cross the tiny chunk of 8. The
+    convolution rows and the latent rows agree exactly; the state and
+    the outputs within the phase's tolerance (a few 1e-3 on the CPU)."""
+    rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[3:])
+    assert rc == 0
+    _check_lines(lines, ["hybrid"])
+    facts = lines[0]["checked"]
+    assert set(facts["rel_err"]) == {"9", "21"}
+    for errs in facts["rel_err"].values():
+        assert set(errs) == {"kda_out", "kda_state", "kda_conv", "mla_out",
+                             "mla_rows"}
+        assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
+        assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+
+
 @pytest.mark.slow
 def test_serve_phase_rehearses_on_the_cpu(cluster, capsys):
     rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[:1])

@@ -355,6 +355,65 @@ def test_serving_programs_hold_no_cast_of_a_weight(topo, monkeypatch,
     assert len(_weight_casts(from_masters, cfg)) >= 8
 
 
+# ---- the hybrid block (models/ling.py) at the reason cell's sizes ----
+
+
+def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
+        topo, monkeypatch):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s decode program
+    (7 layers, 128 of 512 experts held, 32 slots x 3088 rows): three
+    kernel calls an expert layer; the donated state is updated in place
+    and never copied (the six float32 ``[32,32,128,128]`` KDA states,
+    the latent rows ``[32,3088,512]``); no matrix exists in float32 (the tree arrives in
+    the serving types: a cast of one 250 M expert stack is 1 GB); and
+    arguments and temporaries stay under 13 GiB of the chip's 16."""
+    from benchmark import manifest
+    from ray_tpu.models import ling
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    with open("benchmark/traffic/reason-saturated.json") as f:
+        eng = json.load(f)["engine"]
+    slots, max_len = eng["slots"], eng["max_len"]
+    fam, m = manifest.model("ling-3.0-flash-vl-ep4-1chip")
+    prog = fam.build(m, max_seq_len=max_len, remat=False)
+    cfg = prog.cfg
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(prog.init_params,
+                                      jax.random.PRNGKey(0)))
+    state = _on(chip, jax.eval_shape(
+        lambda: ling.SLOTS.init_state(cfg, slots, max_len)))
+    vec = lambda dt: jax.ShapeDtypeStruct(  # noqa: E731
+        (slots,), dt, sharding=chip)
+    compiled = de.decode_chunk.lower(
+        params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+        chunk=eng["chunk_tokens"]).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 3 * cfg.moe_layers == 18
+    for dims in (f"f32[{slots},32,128,128]", f"bf16[{slots},{max_len},512]"):
+        assert dims in text
+        assert not re.search(re.escape(dims) + r"\S* copy\(", text), dims
+    # (the 64-wide rotated keys, 2% of the state, change their layout
+    # once a chunk on the way in and out of the step loop: XLA's choice
+    # for a minor dimension of half a lane tile, outside the loop)
+    assert len(re.findall(rf"bf16\[{slots},{max_len},64\]\S* copy\(",
+                          text)) <= 2
+    matrices = {a.shape for a in jax.tree_util.tree_leaves(params)
+                if a.dtype == jnp.bfloat16 and a.size > 1 << 20}
+    assert (128, 2560, 768) in matrices and (2560, 12288) in matrices
+    for shape in matrices:
+        assert f"f32[{','.join(map(str, shape))}]" not in text, shape
+    mem = compiled.memory_analysis()
+    state_bytes = sum(ling.SLOTS.state_bytes(state).values())
+    assert state_bytes == slots * sum(
+        fam.state_bytes_per_slot(m, max_len).values())
+    assert mem.alias_size_in_bytes >= state_bytes, _mem(compiled)
+    print(f"\nling decode chunk: {_mem(compiled)}")
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 13 * 1024 * MIB), _mem(compiled)
+
+
 # ---- the train step, on one chip and sharded over four ----
 
 
