@@ -18,7 +18,7 @@ random weights from ``--seed``), with small request and step counts:
   compiled programs from the cache. Spec-on and spec-off tokens are
   compared: equal in f32; in bf16 they part, and a child that holds the
   chip checks that they part at near-tied logits only (see
-  ``serve_phase``, ``spec_parting``).
+  ``serve_phase``, ``spec_parting``, ``kernel_parting``).
 - train: ``JaxTrainer``, one worker that owns the chip, the 1B recipe
   (``model_fields``; b2 x T2048, fused_adamw with bf16 moments, bf16
   grads, flash_qkv remat) for a few steps: finite, falling loss, the
@@ -435,6 +435,69 @@ def spec_parting(model: dict, seed: int, slots: int, max_len: int,
             "device": accelerator.device_report()}
 
 
+def kernel_parting(model: dict, kv_heads: list, seed: int, slots: int,
+                   max_len: int, chunk_tokens: int, buckets: list,
+                   lens: list, max_tokens: int,
+                   interpret: bool = False) -> dict:
+    """Runs in a process that holds the device: for each head layout
+    (``kv_heads`` under the model's query heads) seeded prompts of
+    ``lens`` tokens, fewer than ``slots`` (the slots decode at different
+    positions and some stay empty), through two engines on the same
+    weights, greedy: the decode step's attention as the backend gives it
+    (on a TPU the ``decode_attn`` kernel of ops/decode_attention.py;
+    with ``interpret``, the CPU rehearsal's, the kernel in the Pallas
+    interpreter) and as the XLA body over every row of the layer.
+    -> per layout ``parting_margins`` of the two and how many kernel
+    calls the first engine's programs were traced with, and the
+    device."""
+    import functools
+    from unittest import mock
+
+    import jax
+    import numpy as np
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import llama
+    from ray_tpu.models.decode_engine import RaggedDecoder
+    from ray_tpu.ops import decode_attention as da
+
+    accelerator.claim_device()
+    rng = np.random.RandomState(seed)
+    out = {}
+    for hkv in kv_heads:
+        cfg = llama.LlamaConfig(**{**model, "n_kv_heads": hkv})
+        params = llama.init_params(cfg, jax.random.PRNGKey(seed))
+        asked = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+                 for n in lens]
+        traced = []
+
+        def counted(*a, _kernel=da._decode_attn, **kw):
+            traced.append(1)
+            return _kernel(*a, **kw)
+
+        def decode(**how) -> list:
+            jax.clear_caches()  # the programs are cached by their shapes,
+            # not by the attention they were traced with
+            with mock.patch.object(da, "decode_attention", functools.partial(
+                    da.decode_attention, **how)), \
+                    mock.patch.object(da, "_decode_attn", counted):
+                eng = RaggedDecoder(params, cfg, slots=slots,
+                                    max_len=max_len,
+                                    chunk_tokens=chunk_tokens,
+                                    prompt_buckets=tuple(buckets))
+                sids = [eng.submit(p, max_tokens) for p in asked]
+                eng.drain()
+            return [list(eng.finished[s].tokens) for s in sids]
+
+        kernel = decode(**({"interpret": True} if interpret else {}))
+        calls = len(traced)
+        out[f"{cfg.n_heads}/{hkv}"] = {
+            "kernel_calls_traced": calls,
+            "partings": parting_margins(params, cfg, asked, kernel,
+                                        decode(use_kernel=False))}
+    return {"layouts": out, "device": accelerator.device_report()}
+
+
 def chip_child(plan: Plan, call: str, args: dict) -> dict:
     """``chip_smoke.<call>(**args)`` in a child that holds the chip (or,
     in a rehearsal, the CPU), with the platform and chip in its
@@ -472,7 +535,12 @@ def serve_phase(plan: Plan) -> dict:
     near-ties of the model's own f32 logits: a flip inside the
     arithmetic's noise, not a leak of speculative state. (A CPU rounds
     a bf16 product once whatever its width: there the two agree in bf16
-    too, tests/test_decode_spec.py.)"""
+    too, tests/test_decode_spec.py.) The same is asked of the decode
+    step's attention (``kernel_parting``): the ``decode_attn`` kernel,
+    which sums a slot's rows block by block under a running maximum,
+    against the XLA body over every row, at 16 / 8 and 16 / 16 heads
+    (InternLM2's and OLMoE's layouts) with slots at different positions
+    and some empty."""
     on, on_facts = run_pool(plan, replicas=1, spec=True)
     off, off_facts = run_pool(plan, replicas=1, spec=False)
     agree = agreement(on, off)
@@ -493,6 +561,26 @@ def serve_phase(plan: Plan) -> dict:
         p["margin"] < NEAR_TIE for p in parted),
         "speculation on and off part where the model's own logits do not "
         "tie", partings=widths["partings"], near_tie=NEAR_TIE)
+    heads = model_fields(plan.model_size, 0)["n_heads"]
+    attn = chip_child(plan, "kernel_parting", {
+        "model": model_fields(plan.model_size, plan.max_len, n_layers=1,
+                              remat=False, use_flash=False),
+        "kv_heads": [heads // 2, heads], "seed": plan.seed,
+        "slots": plan.slots, "max_len": plan.max_len,
+        "chunk_tokens": plan.chunk_tokens,
+        "buckets": list(plan.prompt_buckets),
+        "lens": [*plan.prompt_lens, max(plan.prompt_lens) // 2 + 1],
+        "max_tokens": plan.max_tokens, "interpret": not plan.on_tpu})
+    check_device(plan, attn["device"], 1, "kernel_parting child")
+    for layout, found in attn["layouts"].items():
+        check(found["kernel_calls_traced"] > 0,
+              "the decode step was traced without the decode_attn kernel",
+              layout=layout)
+        check(all(p is None or p["margin"] < NEAR_TIE
+                  for p in found["partings"]),
+              "the decode_attn kernel and the XLA body part where the "
+              "model's own logits do not tie", layout=layout,
+              partings=found["partings"], near_tie=NEAR_TIE)
     warm = next(iter(off_facts["compile"].values()))
     if warm["requests"]:  # the persistent cache is on in this run
         check(warm["hits"] > 0,
@@ -503,6 +591,7 @@ def serve_phase(plan: Plan) -> dict:
             plan.max_tokens, "seed_replay_exact": True,
             "spec_on_vs_off_agreeing_tokens": agree,
             "spec_on_vs_off_partings": widths["partings"],
+            "decode_attn_vs_xla_body": attn["layouts"],
             "near_tie": NEAR_TIE,
             "compile_s": cold["seconds"], "compile_s_second_start":
             warm["seconds"], "spec_on": on_facts, "spec_off": off_facts}

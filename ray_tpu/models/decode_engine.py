@@ -37,6 +37,8 @@ from ray_tpu._private import flight_recorder as _fr
 from ray_tpu._private import trace as _trace
 from ray_tpu.models import llama, mlp
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops import decode_attention as _da
+from ray_tpu.ops.decode_attention import attend_ragged as _attend_ragged  # noqa: F401 — the XLA body, under the name the tests know
 
 
 _metrics = None
@@ -103,7 +105,12 @@ def require_rows(cfg, mechanism: str) -> None:
 
 
 def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
-    shape = (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim)
+    """The Llama block's slot state: k and v stacks [L, slots, max_len,
+    Hkv * hd] in the compute dtype, a row the position's kv heads laid
+    end to end (the layout ``ops/decode_attention.py`` reads in place:
+    a head is whole lanes of a block of rows), and each slot's filled
+    length."""
+    shape = (cfg.n_layers, slots, max_len, cfg.n_kv_heads * cfg.head_dim)
     cdt = cfg.compute_dtype
     return {
         "k": jnp.zeros(shape, cdt),
@@ -112,53 +119,39 @@ def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
     }
 
 
-def _attend_ragged(q, ck, cv, qpos):
-    """Attention of T query rows a slot over the cache AS STORED.
-    q: [B, T, Hq, hd]; ck/cv: [B, S, Hkv, hd]; qpos: [B, T], the position
-    of each query row; row t of slot b sees k_pos <= qpos[b, t]. The
-    query heads are grouped by the kv head they share (head
-    h = kv * group + r, the order a repeat of the kv heads would give)
-    and each group contracts against its one kv head: no repeated copy
-    of the cache is made, and the cache is read once in its own dtype.
-    Products accumulate in float32, the softmax is float32, the
-    probabilities are cast to q's dtype. Returns [B, T, Hq, hd]."""
-    b, t, hq, hd = q.shape
-    s, hkv = ck.shape[1:3]
-    qg = q.reshape(b, t, hkv, hq // hkv, hd)
-    logits = jnp.einsum(
-        "btkgd,bskd->bkgts", qg, ck, preferred_element_type=jnp.float32
-    ) * (hd ** -0.5)
-    k_pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]  # [1, 1, S]
-    live = k_pos <= qpos[:, :, None]  # [B, T, S]
-    logits = jnp.where(live[:, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    o = jnp.einsum(
-        "bkgts,bskd->btkgd", probs, cv, preferred_element_type=jnp.float32
-    ).astype(q.dtype)
-    return o.reshape(b, t, hq, hd)
+def _kv_rows(rows):
+    """k or v rows [..., Hkv, hd] as the stack holds them:
+    [..., Hkv * hd]."""
+    return rows.reshape(*rows.shape[:-2], -1)
 
 
 def _layer_ragged(cfg: LlamaConfig, h, p, sin, cos, k, v, layer, pos,
-                  aux: dict | None = None):
+                  lengths, plan, aux: dict | None = None):
     """One layer over T rows a slot at PER-SLOT positions, on the STACKED
     cache. h: [B, T, D] (T == 1: a decode step; T == K+1: the
     speculative verify, the current token plus the K drafted ones);
-    k/v: [L, B, S, Hkv, hd], the whole cache; pos: [B], each slot's
-    base position. The layer writes its B x T new rows at
-    [layer, slot, pos..pos+T-1] into the stack it was given (a scatter
-    of rows: nothing else of the cache moves) and attends over
-    ``stack[layer]`` with a per-query causal mask, so a T-wide pass
-    computes exactly T sequential one-row steps in one layer sweep.
-    Returns (h, k, v), the stacks updated."""
+    k/v: [L, B, S, Hkv * hd], the whole cache; pos: [B], each slot's
+    base position; lengths: [B], the rows of a slot that hold something
+    once this layer's are written (pos + T; 0: the slot is inactive),
+    and ``plan`` the kernel's visits for them (made once a step).
+    The layer writes its B x T new rows at [layer, slot, pos..pos+T-1]
+    into the stack it was given (a scatter of rows: nothing else of the
+    cache moves) and attends over the stack's ``layer`` in place, each
+    slot up to its own length with a per-query causal mask
+    (``ops.decode_attention``: on a TPU the ``decode_attn`` kernel,
+    which reads only blocks that hold a row; elsewhere the XLA body
+    over ``stack[layer]``), so a T-wide pass computes exactly T
+    sequential one-row steps in one layer sweep. Returns (h, k, v), the
+    stacks updated."""
     b, t, _ = h.shape
     q, k_new, v_new = llama._qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
     with jax.named_scope("cache"):
         rows = jnp.arange(b)[:, None]
         cols = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-        k = k.at[layer, rows, cols].set(k_new)
-        v = v.at[layer, rows, cols].set(v_new)
+        k = k.at[layer, rows, cols].set(_kv_rows(k_new))
+        v = v.at[layer, rows, cols].set(_kv_rows(v_new))
     with jax.named_scope("attn"):
-        o = _attend_ragged(q, k[layer], v[layer], cols)
+        o = _da.decode_attention(q, k, v, layer, lengths, plan=plan)
     return llama._attn_out_and_mlp(cfg, p, h, o, aux), k, v
 
 
@@ -172,16 +165,24 @@ def _layers_ragged(cfg: LlamaConfig, layers, attach, h, sin, cos, k, v,
     the stack and written back whole around B new rows (two copies a
     layer and step); as state the stack stays where it lies
     (:func:`_layer_ragged`). The loop runs as many layers as ``layers``
-    holds (the draft's: the first few). With ``active`` [B], a model
-    that reports its routing also returns ``experts_touched`` [L] (see
-    ``_experts_touched``). Returns (h, k, v, *touched)."""
+    holds (the draft's: the first few). With ``active`` [B], an inactive
+    slot's rows are not attended over (its length is 0, its attention
+    output zeros), and a model that reports its routing also returns
+    ``experts_touched`` [L] (see ``_experts_touched``). Returns
+    (h, k, v, *touched)."""
     routed = active is not None and llama.reports_routing(cfg)
+    lengths = pos + h.shape[1]
+    if active is not None:
+        lengths = jnp.where(active, lengths, 0)
+    # the same for every layer of the step: made here, not in the body
+    plan = _da.visits(lengths, k.shape[2])
 
     def body(carry, p_):
         h_, k_, v_, layer = carry
         aux = {} if routed else None
         h_, k_, v_ = _layer_ragged(
-            cfg, h_, attach(p_), sin, cos, k_, v_, layer, pos, aux)
+            cfg, h_, attach(p_), sin, cos, k_, v_, layer, pos, lengths,
+            plan, aux)
         return (h_, k_, v_, layer + 1), _experts_touched(cfg, aux, active)
 
     (h, k, v, _), touched = jax.lax.scan(
@@ -300,8 +301,10 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
     state, :func:`slot_model`) is loop state of the step loop and, for
     the Llama block, inside it of the layer loop
     (:func:`_layers_ragged`): a step writes B rows a layer into the
-    stack and reads one layer of it; no layer's cache is sliced out and
-    written back, and none is repeated for its query group. Returns
+    stack and reads, of that layer, each active slot's rows up to its
+    own length (``ops.decode_attention``); no layer's cache is sliced
+    out or written back, and none is repeated for its query group.
+    Returns
     ([B, chunk] tokens, [B, chunk] f32 logprobs or without lanes
     ``None``, new cache, [B] last token) and, for a model that reports
     its routing, its step counters, [chunk, L] each (the Llama block:
@@ -575,8 +578,8 @@ class _LlamaSlots:
     def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
         # k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
         return {
-            "k": state["k"].at[:, slots].set(streams["k"]),
-            "v": state["v"].at[:, slots].set(streams["v"]),
+            "k": state["k"].at[:, slots].set(_kv_rows(streams["k"])),
+            "v": state["v"].at[:, slots].set(_kv_rows(streams["v"])),
             "pos": state["pos"].at[slots].set(full_lens),
         }
 
@@ -602,12 +605,12 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
                    donate_argnames=("cache", "cur_tok"))
 def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
                         cur_tok, cfg: LlamaConfig):
-    """Scatter externally-prefilled KV rows ([L, S, Hkv, D], S == the
-    slot cache length — FULL-SLOT-OVERWRITE, see
+    """Scatter externally-prefilled KV rows ([L, S, Hkv, D] as a prefill
+    makes them, S == the slot cache length — FULL-SLOT-OVERWRITE, see
     _prefill_batch_into_slots) into `slot` and seed its current token."""
     cache = {
-        "k": cache["k"].at[:, slot].set(k_rows),
-        "v": cache["v"].at[:, slot].set(v_rows),
+        "k": cache["k"].at[:, slot].set(_kv_rows(k_rows)),
+        "v": cache["v"].at[:, slot].set(_kv_rows(v_rows)),
         "pos": cache["pos"].at[slot].set(true_len),
     }
     return cache, cur_tok.at[slot].set(tok0)
@@ -765,6 +768,10 @@ class RaggedDecoder:
         # and experts touched summed over decode steps and layers
         self.moe_assignments = 0
         self.moe_touched_expert_steps = 0
+        # rows the chunks' attention had to read against rows the slots
+        # hold (monotonic totals, one addition a read-back: _count_rows)
+        self.attn_live_rows = 0
+        self.attn_cache_rows = 0
         # [L, E] device counts of the prefill calls since the last
         # read-back, fetched with it
         self._pending_expert_tokens: list = []
@@ -1071,7 +1078,10 @@ class RaggedDecoder:
                 continue
             k, v = jax.device_get((self.cache["k"][:, slot, :n_ins],
                                    self.cache["v"][:, slot, :n_ins]))
-            pc.insert(s.prompt[:n_ins], k, v)
+            # the prefix cache keeps rows as the prefill makes them,
+            # [L, n, Hkv, hd]
+            heads = (*k.shape[:2], self.cfg.n_kv_heads, self.cfg.head_dim)
+            pc.insert(s.prompt[:n_ins], k.reshape(heads), v.reshape(heads))
 
     def pump(self) -> int:
         """Admit + advance one chunk; returns number of active slots.
@@ -1134,6 +1144,7 @@ class RaggedDecoder:
             *out, first_toks, first_lps, touched, loads = jax.device_get(
                 (*chunk_out, self.cache["pos"], [t for _, t, _ in firsts],
                  [lp for _, _, lp in firsts], touched, loads))
+            self._count_rows(sp, out[-1])
             self._count_routing(sp, touched, loads)
         return (*out, [(s, int(t0), float(lp0)) for (s, _, _), t0, lp0
                        in zip(firsts, first_toks, first_lps)])
@@ -1168,6 +1179,20 @@ class RaggedDecoder:
             sp.update(delivered=delivered, firsts=len(firsts),
                       finished=finished)
         return t_now, delivered
+
+    def _count_rows(self, sp: dict, pos_np) -> None:
+        """How much of the slots' rows the chunk's attention had to
+        read, from the positions the read-back fetched anyway:
+        ``live_rows``, the sum over the occupied slots of their position
+        at the chunk's end, and ``cache_rows``, slots x max_len (span
+        attrs; ``attn_live_rows`` / ``attn_cache_rows`` in stats() are
+        their monotonic totals). Their ratio is the share of the cache
+        that held a row."""
+        occupied = [st is not None for st in self.slot_stream]
+        sp["live_rows"] = live = int(pos_np[occupied].sum())
+        sp["cache_rows"] = self.slots * self.max_len
+        self.attn_live_rows += live
+        self.attn_cache_rows += self.slots * self.max_len
 
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
         """The routing counters a read-back brought: ``touched`` holds
@@ -1365,6 +1390,7 @@ class RaggedDecoder:
         ``prefill_calls``: cold prefills, one prompt each;
         ``weights_bytes``: what the serving tree holds on the device,
         ``state_bytes``: what the slots' state holds there, by kind;
+        ``attn_live_rows`` / ``attn_cache_rows``: see ``_count_rows``;
         for a mixture-of-experts model ``moe_assignments`` and
         ``moe_touched_expert_steps``, see ``__init__``)."""
         active = sum(1 for st in self.slot_stream if st is not None)
@@ -1379,6 +1405,8 @@ class RaggedDecoder:
             "state_bytes": dict(self.state_bytes),
             "pumps": self.pumps,
             "prefill_calls": self.prefill_calls,
+            "attn_live_rows": self.attn_live_rows,
+            "attn_cache_rows": self.attn_cache_rows,
         }
         if self.model.reports_routing(self.cfg):
             out["moe_assignments"] = self.moe_assignments

@@ -1,0 +1,273 @@
+"""Decode attention over the stacked ragged cache: each slot's few query
+rows against that slot's k and v rows **up to its own length**.
+
+``q [B, T, Hq, hd]`` (T == 1: a decode step; T == K + 1: the speculative
+verify) attends over ``k[layer]``, ``v[layer]`` of the engine's stack
+``[L, B, S, Hkv * hd]`` (``models/decode_engine.py``: a row of the cache
+is a position's kv heads laid end to end). ``lengths [B]`` says how many
+rows of a slot hold something, the T rows just written included
+(``pos + T``); 0 is an inactive slot. Query row t of slot b sees
+``k_pos < lengths[b] - (T - 1 - t)``, which is ``k_pos <= pos[b] + t``.
+
+On a TPU it is a Pallas kernel, shown in a device trace as
+``decode_attn``. Its grid **visits only the (slot, row block) pairs that
+hold a row** (``visits``, ``grouped_matmul.group_metadata``'s way): a
+slot half full costs half its rows' bytes, an inactive slot nothing (its
+output is zeros). The blocks are taken from the stack in place, by a
+scalar-prefetched ``layer`` in the block spec's index map: slicing
+``stack[layer]`` first would read the whole layer, filled or not. A
+visit holds every kv head of its rows; the query heads of a kv head
+(head h = kv * group + r) contract against that head's lanes of the
+block, so no head is repeated. The arithmetic is the XLA body's
+(``attend_ragged``, the path off the TPU and the tests' second opinion):
+products of the compute dtype accumulated in float32, float32 softmax
+statistics (a running max and sum across a slot's blocks), the
+probabilities cast to the compute dtype before the values' product.
+``interpret=True`` (a test's explicit choice) runs the kernel in the
+Pallas interpreter.
+
+The layout and the block's rows, read on the chip (TPU v5 lite, my chip
+runs, PR 33; the kernel's own event in a trace, median of 200 calls;
+bf16, hd 128; 8 slots of 1,296 rows at lengths 268...1,108, mean 683):
+  - the layout is the kernel's. With the kv heads in the lanes
+    (``[S, Hkv * hd]``) a head's ``[rows, hd]`` is a lane-aligned view
+    of the block; as ``[S, Hkv, hd]`` it is one sublane of every row's
+    tile, and the same kernel took twice as long (a layer-step of the
+    probe's loop, row write included: 54.3 against 107.8 us at 8 kv
+    heads, 110.2 against 218.7 at 16). The row write into the lanes'
+    layout costs what it did (1.6 us a stack for 8 rows);
+  - rows a block, 16 query / 8 kv heads: 336 -> 53.0 us, 432 -> 46.0,
+    656 -> 45.9, 1,296 (every row, as the XLA body reads) -> 60.5;
+    16 / 16 heads: 432 -> 84.5, 656 -> 90.8. The live rows' bytes at
+    the HBM's peak are 27.3 and 54.7 us: 59% and 65% of the roofline.
+    32 slots of 512 rows, 3 live (108...208): 512 -> 12.0, 256 -> 9.7;
+  - what bounds it: the products, not the reads. With every step on one
+    block (no new DMA) the 8-head call takes 46.3 us, with the blocks
+    fetched and not multiplied 38.2: a visit's 2 x Hkv products of 16
+    query rows against ``rows`` x 128 pass the block through the
+    matrix unit at about 1.2 times the time its DMA takes. A call
+    costs about 5 us before its first block is there.
+So: the fewest equal blocks of at most ``_BLOCK_ROWS`` rows
+(``block_rows``): 3 x 432 for 1,296 rows, one block for 512.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG = -1e30
+_BLOCK_ROWS = 512
+_VMEM_LIMIT = 64 * 1024 * 1024
+_ROW_PAD = 16  # query rows a kv head, padded to a bf16 tile's sublanes
+
+
+def _cdiv(a, b):
+    return -(-a // b)
+
+
+def block_rows(s: int) -> int:
+    """Rows a block for a cache of ``s`` rows: the fewest equal blocks
+    of at most ``_BLOCK_ROWS``, a multiple of 16 (1,296 -> 3 x 432, 512
+    -> 1 x 512), so that the last block ends with the cache wherever
+    ``s`` allows it."""
+    n = _cdiv(s, _BLOCK_ROWS)
+    return _cdiv(_cdiv(s, n), 16) * 16
+
+
+def attend_ragged(q, ck, cv, qpos):
+    """The XLA body: attention of T query rows a slot over ONE layer of
+    the cache, every row of it. q: [B, T, Hq, hd]; ck/cv:
+    [B, S, Hkv, hd]; qpos: [B, T], the position of each query row; row t
+    of slot b sees k_pos <= qpos[b, t]. The query heads are grouped by
+    the kv head they share (head h = kv * group + r, the order a repeat
+    of the kv heads would give) and each group contracts against its one
+    kv head: no repeated copy of the cache is made, and the cache is
+    read once in its own dtype. Products accumulate in float32, the
+    softmax is float32, the probabilities are cast to q's dtype. Returns
+    [B, T, Hq, hd]."""
+    b, t, hq, hd = q.shape
+    s, hkv = ck.shape[1:3]
+    qg = q.reshape(b, t, hkv, hq // hkv, hd)
+    logits = jnp.einsum(
+        "btkgd,bskd->bkgts", qg, ck, preferred_element_type=jnp.float32
+    ) * (hd ** -0.5)
+    k_pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]  # [1, 1, S]
+    live = k_pos <= qpos[:, :, None]  # [B, T, S]
+    logits = jnp.where(live[:, None, None], logits, _NEG)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    o = jnp.einsum(
+        "bkgts,bskd->btkgd", probs, cv, preferred_element_type=jnp.float32
+    ).astype(q.dtype)
+    return o.reshape(b, t, hq, hd)
+
+
+def visits(lengths, s: int, bs: int | None = None):
+    """Which slot and which of its row blocks each grid step works on,
+    for blocks of ``bs`` rows (``block_rows(s)`` unless given).
+
+    -> ((slot_ids [G], block_ids [G]), num_steps) with G = B x blocks of
+    ``s`` rows, of which the first ``num_steps`` are real: a slot's
+    blocks below its length, consecutively; a slot of length 0 has none.
+    At least one step (block 0 of an empty slot, which stores nothing)."""
+    bs = bs or block_rows(s)
+    b = lengths.shape[0]
+    slots = b * _cdiv(s, bs)
+    blocks = _cdiv(jnp.clip(lengths, 0, s), bs).astype(jnp.int32)
+    ends = jnp.cumsum(blocks)
+    slot_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), blocks,
+                          total_repeat_length=slots)
+    block_ids = jnp.arange(slots, dtype=jnp.int32) - (ends - blocks)[slot_ids]
+    return (slot_ids, block_ids), jnp.maximum(ends[-1], 1)
+
+
+def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
+            o_ref, m_scr, l_scr, acc_scr, *, s: int, bs: int, t: int,
+            group: int, hkv: int, hd: int, scale: float):
+    step = pl.program_id(0)
+    slot, j = slot_ids[step], block_ids[step]
+    length = lengths[slot]
+    last = (jnp.minimum(length, s) + bs - 1) // bs - 1
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # row r of a head's [_ROW_PAD, hd] is query row t = r // group (the
+    # padding rows take the last limit: finite, discarded)
+    r = jax.lax.broadcasted_iota(jnp.int32, (_ROW_PAD, bs), 0)
+    row_t = sum((r >= i * group).astype(jnp.int32) for i in range(1, t))
+    k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (_ROW_PAD, bs), 1)
+    seen = k_pos < length - (t - 1) + row_t
+    if s % bs:  # the cache ends inside the last block
+        seen &= k_pos < s
+        inside = j * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (bs, hd), 0) < s
+    for h in range(hkv):
+        lanes = pl.ds(h * hd, hd)
+        logits = jax.lax.dot_general(
+            q_ref[h], k_ref[:, lanes], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [_ROW_PAD, bs]
+        logits = jnp.where(seen, logits, _NEG)
+        m_prev = m_scr[h, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = corr * l_scr[h, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[:, lanes]
+        if s % bs:  # what lies past the cache is not zero, nor finite
+            v = jnp.where(inside, v, jnp.zeros_like(v))
+        acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(j == last)
+    def _store():
+        for h in range(hkv):
+            o_ref[h] = (acc_scr[h] / l_scr[h, :, :1]).astype(o_ref.dtype)
+
+
+def _decode_attn(q, k, v, layer, lengths, plan, *, bs: int,
+                 interpret: bool):
+    """The kernel's call: q [B, T, Hq, hd] -> [B, T, Hq, hd]; ``plan``
+    is ``visits(lengths, s, bs)``."""
+    b, t, hq, hd = q.shape
+    s = k.shape[2]
+    hkv = k.shape[3] // hd
+    group = hq // hkv
+    rows = t * group
+    if rows > _ROW_PAD:
+        raise ValueError(
+            f"{t} query rows x {group} heads a kv head exceed {_ROW_PAD}")
+    # [B, Hkv, T x group (padded), hd]: a kv head's query rows together
+    qh = q.reshape(b, t, hkv, group, hd).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, rows, hd)
+    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, _ROW_PAD - rows), (0, 0)))
+    meta, steps = plan
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_index(step, slot_ids, block_ids, lengths, layer_ref):
+        return slot_ids[step], 0, 0, 0
+
+    def kv_index(step, slot_ids, block_ids, lengths, layer_ref):
+        return layer_ref[0], slot_ids[step], block_ids[step], 0
+
+    kernel = functools.partial(
+        _kernel, s=s, bs=bs, t=t, group=group, hkv=hkv, hd=hd,
+        scale=hd ** -0.5)
+    kv_block = pl.BlockSpec((None, None, bs, hkv * hd), kv_index)
+    q_block = pl.BlockSpec((None, hkv, _ROW_PAD, hd), q_index)
+    itemsize = k.dtype.itemsize
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[q_block, kv_block, kv_block],
+            out_specs=q_block,
+            grid=(steps,),
+            scratch_shapes=[
+                pltpu.VMEM((hkv, _ROW_PAD, 128), jnp.float32),  # max
+                pltpu.VMEM((hkv, _ROW_PAD, 128), jnp.float32),  # sum
+                pltpu.VMEM((hkv, _ROW_PAD, hd), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * _ROW_PAD * s * hkv * hd,
+            transcendentals=b * _ROW_PAD * s * hkv,
+            bytes_accessed=2 * b * s * hkv * hd * itemsize),
+        interpret=interpret,
+        name="decode_attn",
+    )(*meta, lengths, layer, qh, k, v)
+    # a slot without a row was never visited: what its block of the
+    # output holds is whatever the buffer held
+    out = jnp.where((lengths > 0)[:, None, None, None], out[:, :, :rows], 0)
+    return out.reshape(b, hkv, t, group, hd).transpose(
+        0, 2, 1, 3, 4).reshape(b, t, hq, hd)
+
+
+def decode_attention(q, k, v, layer, lengths, *, plan=None,
+                     use_kernel: bool | None = None,
+                     interpret: bool = False, rows: int | None = None):
+    """q [B, T, Hq, hd] over ``k[layer]``, ``v[layer]`` of the stacks
+    [L, B, S, Hkv * hd] up to ``lengths`` [B] (``pos + T``; 0: the slot
+    is inactive and its output zeros) -> [B, T, Hq, hd] in q's dtype.
+
+    ``use_kernel=None`` takes the backend's: the Pallas kernel on a TPU
+    (where a head fills whole lanes), ``attend_ragged`` elsewhere, which
+    reads every row of the layer and leaves an inactive slot's output to
+    its frozen position. ``interpret=True`` runs the kernel in the
+    Pallas interpreter (never inferred). ``rows`` overrides the block's
+    rows (the chip's tuning sweep and the tests). ``plan`` is
+    ``visits(lengths, S, rows)`` where the caller made
+    it already: a layer loop makes it once a step, before the loop (as
+    written in the loop's body XLA leaves its dozen small operations
+    there, every layer)."""
+    b, t, hq, hd = q.shape
+    s = k.shape[2]
+    if use_kernel is None:
+        use_kernel = interpret or (
+            jax.default_backend() == "tpu" and hd % 128 == 0
+            and t * (hq * hd // k.shape[3]) <= _ROW_PAD)
+    if not use_kernel:
+        shape = (b, s, k.shape[3] // hd, hd)
+        qpos = (lengths - t)[:, None] + jnp.arange(t, dtype=jnp.int32)
+        o = attend_ragged(q, k[layer].reshape(shape),
+                          v[layer].reshape(shape), qpos)
+        return jnp.where((lengths > 0)[:, None, None, None], o, 0)
+    bs = rows or block_rows(s)
+    lengths = lengths.astype(jnp.int32)
+    return _decode_attn(q, k, v, layer, lengths,
+                        plan or visits(lengths, s, bs), bs=bs,
+                        interpret=interpret)

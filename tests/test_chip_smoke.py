@@ -133,6 +133,26 @@ def test_parting_margins_measure_against_the_models_own_logits():
     assert gap > chip_smoke.NEAR_TIE
 
 
+def test_kernel_parting_rehearses_on_the_cpu():
+    """What ``serve_phase`` asks of the decode step's attention on the
+    chip, at tiny widths with the kernel in the Pallas interpreter:
+    both head layouts, three prompts in four slots, and in f32 the
+    kernel and the XLA body part nowhere outside a near-tie."""
+    found = chip_smoke.kernel_parting(
+        chip_smoke.model_fields("tiny", TINY.max_len, n_layers=1,
+                                remat=False, use_flash=False),
+        [2, 4], TINY.seed, TINY.slots, TINY.max_len, TINY.chunk_tokens,
+        list(TINY.prompt_buckets), [24, 5, 13], TINY.max_tokens,
+        interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert set(found["layouts"]) == {"4/2", "4/4"}
+    for layout in found["layouts"].values():
+        assert layout["kernel_calls_traced"] > 0
+        assert len(layout["partings"]) == 3
+        assert all(p is None or p["margin"] < chip_smoke.NEAR_TIE
+                   for p in layout["partings"])
+
+
 def test_kernels_phase_rehearses_on_the_cpu():
     facts = chip_smoke.kernels_phase(TINY)
     assert facts["device"].items() >= CPU.items()

@@ -1,11 +1,17 @@
-"""The decode step's attention and how its cache travels (ISSUE 28).
+"""The decode step's attention and how its cache travels (ISSUEs 28, 33).
 
-``_attend_ragged`` contracts the query groups against the cache as it is
-stored; the plain reference kept here repeats the kv heads first
-(``ops.attention._repeat_kv``, the formulation the engine had). And the
-chunk program, whose layer loop carries the stacked cache as state,
-returns the tokens of the uncached forward (``tests/_oracle.py``).
+``_attend_ragged`` (the XLA body) contracts the query groups against the
+cache as it is stored; the plain reference kept here repeats the kv
+heads first (``ops.attention._repeat_kv``, the formulation the engine
+had). The ``decode_attn`` kernel (``ops/decode_attention.py``), run in
+the Pallas interpreter, reads each slot of the stacked cache up to its
+own length and agrees with both. And the chunk program, whose layer loop
+carries the stacked cache as state, returns the tokens of the uncached
+forward (``tests/_oracle.py``), through the XLA body and through the
+kernel.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -16,6 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 from _oracle import greedy_tokens  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.ops import decode_attention as da  # noqa: E402
 from ray_tpu.ops.attention import _repeat_kv  # noqa: E402
 
 
@@ -90,3 +97,105 @@ def test_decode_chunk_returns_the_greedy_tokens(group):
     for i, n in enumerate(lens):
         np.testing.assert_array_equal(got[i], greedy_tokens(
             params, prompts[i, :n], cfg, 1 + 2 * chunk))
+
+
+# ---- the kernel (ISSUE 33), in the Pallas interpreter ----
+
+BLOCK, ROWS = 16, 40  # the cache ends inside its third block
+
+
+@pytest.mark.parametrize("length", [0, 1, BLOCK - 1, BLOCK, BLOCK + 1, ROWS])
+@pytest.mark.parametrize("t", [1, 3])
+@pytest.mark.parametrize("group", [1, 2])
+def test_kernel_reads_each_slot_up_to_its_own_length(group, t, length):
+    """Slot 0 holds ``length`` rows (0: it is inactive), its neighbours
+    a block and a half and nothing; layer 1 of a stack of two. The
+    kernel agrees with the XLA body and with the repeated-kv oracle,
+    junk beyond a slot's length (in the block it ends in, and in blocks
+    it never visits) does not reach the output, and a slot without a row
+    returns zeros."""
+    layers, b, hkv, hd = 2, 3, 2, 16
+    length = max(length, t) if length else 0  # an active slot wrote t rows
+    lengths = jnp.array([length, BLOCK + BLOCK // 2, 0], jnp.int32)
+    kq, kk, kv, kj = jax.random.split(
+        jax.random.PRNGKey(100 * group + 10 * t + length), 4)
+    q = jax.random.normal(kq, (b, t, hkv * group, hd), jnp.float32)
+    k, v = (jax.random.normal(key, (layers, b, ROWS, hkv * hd), jnp.float32)
+            for key in (kk, kv))
+    layer = jnp.int32(1)
+    attend = functools.partial(da.decode_attention, interpret=True,
+                               rows=BLOCK)
+    got = attend(q, k, v, layer, lengths)
+    assert got.shape == q.shape and np.isfinite(got).all()
+    live = np.asarray(lengths) > 0
+    assert not np.asarray(got)[~live].any()
+    # the XLA body and the oracle, over every row of the layer
+    qpos = (lengths - t)[:, None] + jnp.arange(t, dtype=jnp.int32)
+    heads = (b, ROWS, hkv, hd)
+    for reference in (de._attend_ragged, _attend_repeated):
+        want = reference(q, k[1].reshape(heads), v[1].reshape(heads),
+                         jnp.maximum(qpos, 0))
+        np.testing.assert_allclose(np.asarray(got)[live],
+                                   np.asarray(want)[live],
+                                   atol=2e-5, rtol=2e-5)
+    # the dispatch off the TPU is the XLA body, zeros where it is told to
+    np.testing.assert_allclose(
+        da.decode_attention(q, k, v, layer, lengths), got,
+        atol=2e-5, rtol=2e-5)
+    beyond = jnp.arange(ROWS)[None, :, None] >= lengths[:, None, None]
+    junk = 1e3 * jax.random.normal(kj, k.shape[1:], jnp.float32)
+    kj_, vj_ = (jnp.where(beyond[None], junk[None], a) for a in (k, v))
+    np.testing.assert_array_equal(attend(q, kj_, vj_, layer, lengths), got)
+
+
+def test_the_visits_are_the_blocks_that_hold_a_row():
+    (slots, blocks), steps = da.visits(
+        jnp.array([0, 17, 0, 40, 16], jnp.int32), ROWS, BLOCK)
+    assert int(steps) == 2 + 3 + 1
+    assert list(np.asarray(slots)[:6]) == [1, 1, 3, 3, 3, 4]
+    assert list(np.asarray(blocks)[:6]) == [0, 1, 0, 1, 2, 0]
+    # nothing live: one step, of a slot that stores nothing
+    (slots, blocks), steps = da.visits(jnp.zeros(3, jnp.int32), ROWS, BLOCK)
+    assert int(steps) == 1 and int(blocks[0]) == 0
+    assert da.block_rows(1296) == 432 and da.block_rows(512) == 512
+    assert da.block_rows(288) == 288 and da.block_rows(40) == 48
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_decode_chunk_through_the_kernel_returns_the_greedy_tokens(
+        group, monkeypatch):
+    """The chunk program with the kernel interpreted in its layer loop:
+    two slots at different positions decode the oracle's tokens, the
+    third is inactive and keeps its token and its position."""
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, interpret=True))
+    cfg = llama.LlamaConfig(  # (a size of its own: decode_chunk is cached)
+        vocab_size=251, d_model=64, n_layers=2, n_heads=4,
+        n_kv_heads=4 // group, d_ff=128, max_seq_len=40, dtype="float32",
+        remat=False)
+    params = llama.init_params(cfg, jax.random.PRNGKey(33 + group))
+    slots, max_len, bucket, chunk = 3, 40, 16, 5
+    rng = np.random.RandomState(33)
+    lens = [4, 15, 9]
+    prompts = np.zeros((slots, bucket), np.int32)
+    for i, n in enumerate(lens):
+        prompts[i, :n] = rng.randint(1, 250, n)
+    cache = de.init_ragged_cache(cfg, slots, max_len)
+    cache, tok, toks0, _ = de._prefill_batch_into_slots(
+        params, prompts, np.array(lens, np.int32),
+        np.arange(slots, dtype=np.int32), np.zeros(slots, np.uint32),
+        np.zeros(slots, np.float32), np.ones(slots, np.float32),
+        cache, jnp.zeros((slots,), jnp.int32), cfg)
+    first = np.asarray(toks0)
+    active = np.array([True, True, False])
+    got = [first[:, None]]
+    for _ in range(2):  # (40 rows are one block of 48: it ends past them)
+        toks, _, cache, tok = de.decode_chunk(
+            params, cache, tok, active, None, cfg, chunk)
+        got.append(np.asarray(toks))
+    got = np.concatenate(got, axis=1)
+    assert list(np.asarray(cache["pos"])) == [4 + 10, 15 + 10, 9]
+    assert int(tok[2]) == int(first[2])
+    for i in (0, 1):
+        np.testing.assert_array_equal(got[i], greedy_tokens(
+            params, prompts[i, :lens[i]], cfg, 1 + 2 * chunk))

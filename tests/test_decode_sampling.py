@@ -295,8 +295,9 @@ def test_the_prefill_entries_agree(temp):
 
     pref = {kv: np.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
                           cfg.head_dim), np.float32) for kv in "kv"}
-    for kv in "kv":
-        pref[kv][:, 0, :n_pref] = np.asarray(cold[kv][:, 1, :n_pref])
+    for kv in "kv":  # (the slots hold a row's kv heads end to end)
+        pref[kv][:, 0, :n_pref] = np.asarray(
+            cold[kv][:, 1, :n_pref]).reshape(pref[kv][:, 0, :n_pref].shape)
     warm, tok_w, lp_w = into_slot(
         prompt[n_pref:], 4, (pref["k"], pref["v"], np.int32(n_pref)))
 

@@ -107,8 +107,12 @@ def _prefill_prefix(cfg, tree):
     seed the temporary cache, the suffixes are prefilled behind them."""
     n_pref = 4
     cold = _prefilled(cfg, tree)[0]
-    pref = {kv: jnp.zeros_like(cold[kv]).at[:, :, :n_pref].set(
-        cold[kv][:, :, :n_pref]) for kv in "kv"}
+    # (a prefix is rows as a prefill makes them, [L, F, S, Hkv, hd]; the
+    # slots hold a row's kv heads end to end)
+    heads = (*cold["k"].shape[:3], cfg.n_kv_heads, cfg.head_dim)
+    pref = {kv: jnp.zeros(heads, cold[kv].dtype).at[:, :, :n_pref].set(
+        cold[kv][:, :, :n_pref].reshape(*heads[:2], n_pref, *heads[3:]))
+        for kv in "kv"}
     suffix = np.zeros((SLOTS, BUCKET), np.int32)
     suffix[:, :BUCKET - n_pref] = _prompts()[:, n_pref:]
     return de._prefill_batch_into_slots(

@@ -189,6 +189,24 @@ def _lower_prefill(cfg, chip, bucket, **engine):
         cache, vec(jnp.int32), cfg=cfg)
 
 
+def _whole_layer_ops(text: str, cfg, slots: int, rows: int) -> list:
+    """Operations of a compiled serving program that make an array of
+    ``slots`` x ``rows`` cache rows (one layer of the cache, or the
+    stack) by moving it: a ``dynamic-slice`` (fused or not), a ``copy``
+    or a ``transpose``."""
+    layer = slots * rows * cfg.n_kv_heads * 128
+    found = []
+    for name, dims, op in re.findall(
+            r"%([\w.\-]+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        moved = op in ("copy", "transpose", "dynamic-slice") or (
+            op == "fusion" and re.search(r"dynamic-slice|copy|transpose",
+                                         name))
+        if moved and f"{slots},{rows}," in dims + "," and np.prod(
+                [int(d) for d in dims.split(",")]) >= layer:
+            found.append(f"{name}: [{dims}] {op}")
+    return found
+
+
 def test_decode_chunk_compiles_at_1b_widths(topo):
     cfg = _serve_cfg()
     chip = SingleDeviceSharding(topo.devices[0])
@@ -222,6 +240,10 @@ def test_one_row_prefill_compiles_at_internlm2_widths(topo):
     # the cache is donated: updated in place, never copied
     assert mem["aliased_mib"] >= 2 * 2 * 8 * 1296 * 8 * 128 * 2 // MIB, mem
     assert mem["temporaries_mib"] < 512, mem
+    # the prompt's own rows change layout on their way into the slot
+    # ([1296, 8, 128] as the prefill makes them -> [1296, 1024] as the
+    # stack holds them: one slot's rows); no layer of the cache moves
+    assert _whole_layer_ops(compiled.as_text(), cfg, 8, 1296) == []
 
 
 # ---- the dropless expert layer (OLMoE's widths) ----
@@ -284,19 +306,57 @@ def test_olmoe_decode_chunk_reads_the_expert_stack_in_place(
     assert mem["arguments_mib"] + mem["temporaries_mib"] < 5 * 1024, mem
 
 
+# (slots, rows a slot, query heads, kv heads) of the serving cells
+DECODE_ATTN_SHAPES = {
+    "internlm2-doc": (8, 1296, 16, 8),
+    "olmoe-doc": (8, 1296, 16, 16),
+    "internlm2-chat": (32, 512, 16, 8),
+}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_ATTN_SHAPES))
+def test_decode_attention_kernel_compiles_at_the_cells_shapes(topo, cell):
+    """``decode_attn`` at a decode step's one query row a slot, reading
+    a layer of a stack of 24 in place (the stack is an operand of the
+    custom call, no slice of it is)."""
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    slots, rows, hq, hkv = DECODE_ATTN_SHAPES[cell]
+    chip = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((slots, 1, hq, 128), jnp.bfloat16,
+                             sharding=chip)
+    stack = jax.ShapeDtypeStruct((24, slots, rows, hkv * 128),
+                                 jnp.bfloat16, sharding=chip)
+    text = jax.jit(functools.partial(
+        decode_attention, use_kernel=True)).lower(
+        q, stack, stack,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=chip)
+    ).compile().as_text()
+    assert text.count(KERNEL) == 1 and "decode_attn" in text
+    assert not re.search(
+        rf"= bf16\[(1,)?{slots},{rows},{hkv * 128}\]", text)
+
+
 @pytest.mark.parametrize("model", ["internlm2", "olmoe"])
 def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
                                                      model):
     """The doc cell's decode program (8 slots x 1296 rows, 2 layers): a
-    step writes 8 rows into the stacked cache and reads one layer of it.
-    No kv head is repeated for its query group (f32 ``[8,1296,8,2,128]``
-    broadcasts were 1.6 s of an 8 s trace), no layer's cache is written
-    back into the stack whole, the stack is never copied (OLMoE's
-    ``copy.129`` / ``.130``), and the donated cache is updated in place."""
+    step writes 8 rows into the stacked cache and the ``decode_attn``
+    kernel reads the layer's live blocks out of the stack in place. No
+    kv head is repeated for its query group (f32 ``[8,1296,8,2,128]``
+    broadcasts were 1.6 s of an 8 s trace), no layer's cache is sliced
+    out of the stack (``constant_dynamic-slice_fusion.17`` / ``.19``, a
+    fifth of a chunk), copied or transposed, none is written back into
+    the stack whole, the stack is never copied (OLMoE's ``copy.129`` /
+    ``.130``), and the donated cache is updated in place."""
+    from ray_tpu.ops import decode_attention as da
     from ray_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
         gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, use_kernel=True))
     cfg = llama.LlamaConfig(**(
         INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}))
     chip = SingleDeviceSharding(topo.devices[0])
@@ -305,6 +365,10 @@ def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
         params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=16).compile()
     text = compiled.as_text()
+    assert "decode_attn" in text
+    # one call in the layer loop's body (and OLMoE's three moe_gmm)
+    assert text.count(KERNEL) == (1 if model == "internlm2" else 4)
+    assert _whole_layer_ops(text, cfg, 8, 1296) == []
     if cfg.n_kv_heads < cfg.n_heads:
         assert "[8,1296,8,2,128]" not in text
         assert "[8,1296,16,128]" not in text
@@ -554,11 +618,15 @@ def dump_serving_programs(out_dir: str) -> None:
     from jax.experimental import topologies
 
     from benchmark import manifest
+    from ray_tpu.ops import decode_attention as da
     from ray_tpu.ops import grouped_matmul as gm
 
     jax.config.update("jax_enable_compilation_cache", False)
-    # (the dispatch would read the CPU backend here and take ragged_dot)
+    # (the dispatches would read the CPU backend here and take
+    # ragged_dot and the XLA body)
     gm.grouped_matmul = functools.partial(gm.grouped_matmul, use_kernel=True)
+    da.decode_attention = functools.partial(da.decode_attention,
+                                            use_kernel=True)
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     for config, traffic in SERVING_CELLS:
