@@ -88,6 +88,9 @@ class Plan:
     # widths, prompts that cross a chunk boundary of the KDA prefill
     hybrid_widths: str = "published"
     hybrid_lens: tuple = (65, 200)
+    # and a sliding-window layer's ring (models/exaone.py: 128 rows a
+    # slot at the published widths) filled past two wraps
+    ring_steps: int = 300
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -95,7 +98,7 @@ class Plan:
                     chunk_tokens=4, prompt_buckets=(8, 32),
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
-                    hybrid_widths="tiny", hybrid_lens=(9, 21))
+                    hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20)
         return Plan(**{**base, **kw})
 
     @property
@@ -906,6 +909,63 @@ def hybrid_check(widths: str, lens: list, seed: int) -> dict:
     return {"rel_err": errs, "device": accelerator.device_report()}
 
 
+def ring_check(widths: str, steps: int, seed: int,
+               interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: a sliding-window layer's
+    ring (``models/exaone.py``: ``window`` rows a slot, written at
+    ``pos % window``) filled for ``steps`` positions, past two wraps,
+    four slots that start at different positions and a fifth inactive;
+    at every step the decode step's attention over the ring as the
+    backend gives it (on a TPU the ``decode_attn`` kernel on the
+    128-row stack, lengths ``min(pos + 1, window)``; with ``interpret``
+    the kernel in the Pallas interpreter) against the XLA body over the
+    same rows. -> the largest relative error over the steps, and how
+    many wraps the first slot made."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import exaone
+    from ray_tpu.ops.decode_attention import decode_attention
+
+    accelerator.claim_device()
+    cfg = exaone.ExaoneConfig.tiny(dtype="bfloat16") if widths == "tiny" \
+        else exaone.ExaoneConfig()
+    w, slots, layer = cfg.sliding_window, 5, 1
+    start = jnp.array([0, 3, w - 1, w + 5, 0], jnp.int32)
+    active = jnp.array([True, True, True, True, False])
+    ring = jnp.zeros((2, slots, w, cfg.kv_width), cfg.compute_dtype)
+    kernel = functools.partial(decode_attention, **(
+        {"interpret": True} if interpret else {}))
+
+    def one(carry, key):
+        k_ring, v_ring, pos = carry
+        q, k, v = (jax.random.normal(kk, (slots, 1, h, cfg.head_dim),
+                                     cfg.compute_dtype)
+                   for kk, h in zip(jax.random.split(key, 3), (
+                       cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)))
+        at = (layer, jnp.arange(slots), pos % w)
+        k_ring = k_ring.at[at].set(k.reshape(slots, -1))
+        v_ring = v_ring.at[at].set(v.reshape(slots, -1))
+        lengths = jnp.where(active, jnp.minimum(pos + 1, w), 0)
+        got = kernel(q, k_ring, v_ring, layer, lengths)
+        want = decode_attention(q, k_ring, v_ring, layer, lengths,
+                                use_kernel=False)
+        err = jnp.max(jnp.abs(got.astype(jnp.float32)
+                              - want.astype(jnp.float32)))
+        return (k_ring, v_ring, pos + active), (
+            err, jnp.max(jnp.abs(want.astype(jnp.float32))))
+
+    _, (errs, sizes) = jax.lax.scan(
+        one, (ring, ring, start), jax.random.split(
+            jax.random.PRNGKey(seed), steps))
+    return {"rel_err": float(jnp.max(errs) / jnp.max(sizes)),
+            "wraps": steps // w, "window": w,
+            "device": accelerator.device_report()}
+
+
 def hybrid_phase(plan: Plan) -> dict:
     out = chip_child(plan, "hybrid_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
@@ -916,8 +976,16 @@ def hybrid_phase(plan: Plan) -> dict:
           "a hybrid layer's two forms part (KDA chunkwise / stepping, "
           "MLA unabsorbed / absorbed)", got=out["rel_err"],
           tolerance=HYBRID_TOLERANCE)
+    ring = chip_child(plan, "ring_check", {
+        "widths": plan.hybrid_widths, "steps": plan.ring_steps,
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, ring["device"], 1, "ring child")
+    check(ring["wraps"] >= 2 and ring["rel_err"] <= HYBRID_TOLERANCE,
+          "the decode_attn kernel and the XLA body part on a sliding "
+          "layer's ring", got=ring, tolerance=HYBRID_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
+            "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
             "tolerance": HYBRID_TOLERANCE,
             "compile_s": out["device"]["compile"]["seconds"]}
 

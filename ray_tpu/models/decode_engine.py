@@ -101,7 +101,7 @@ def require_rows(cfg, mechanism: str) -> None:
         raise ValueError(
             f"{mechanism} needs a slot state that is rows of positions; "
             f"{type(cfg).__name__}'s is its own (recurrent or latent "
-            "layers) and cannot be cut at a position")
+            "layers, or a ring of rows) and cannot be cut at a position")
 
 
 def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
@@ -534,7 +534,9 @@ class _LlamaSlots:
     - ``serving_params(cfg, params)``: the tree a replica holds;
     - ``init_state(cfg, slots, max_len)``: every slot's state, a dict
       with ``pos`` [slots]; ``max_len(state)``; ``state_bytes(state)``
-      by kind;
+      by kind; ``row_kinds(cfg)``: for a model whose layers keep rows of
+      several kinds, {kind: (layers, the most rows a slot keeps in one,
+      ``None`` = ``max_len``)}, else {} (what ``_count_rows`` counts by);
     - ``split(cfg, params)``: what a chunk prepares once;
       ``step(cfg, params, prepared, tok, state, pos, active)``: one
       token a slot on ``state`` (the dict without ``pos``) -> (float32
@@ -549,6 +551,7 @@ class _LlamaSlots:
 
     rows_state = True
     step_counters = ("experts_touched",)
+    row_kinds = staticmethod(lambda cfg: {})
     serving_params = staticmethod(llama.serving_params)
     reports_routing = staticmethod(llama.reports_routing)
     init_state = staticmethod(init_ragged_cache)
@@ -742,6 +745,7 @@ class RaggedDecoder:
         # programs, never looked into (``pos`` apart)
         self.cache = self.model.init_state(cfg, slots, max_len)
         self.state_bytes = self.model.state_bytes(self.cache)
+        self.row_kinds = self.model.row_kinds(cfg)
         self.cur_tok = jnp.zeros((slots,), jnp.int32)
         # per-slot sampling lanes, rewritten at admission; frozen slots'
         # values are dead (their sampled token is overwritten anyway)
@@ -772,6 +776,9 @@ class RaggedDecoder:
         # hold (monotonic totals, one addition a read-back: _count_rows)
         self.attn_live_rows = 0
         self.attn_cache_rows = 0
+        # and, for a model with rows of several kinds, the live rows of
+        # one layer of each kind
+        self.attn_live_rows_by_kind = dict.fromkeys(self.row_kinds, 0)
         # [L, E] device counts of the prefill calls since the last
         # read-back, fetched with it
         self._pending_expert_tokens: list = []
@@ -810,13 +817,16 @@ class RaggedDecoder:
     def mark_state(self) -> None:
         """``engine.state_init`` (ring-only, and an instant event on the
         profiler's host line): what the slots hold, ``<kind>_bytes`` for
-        each kind of state the model keeps. Once an engine, and again
+        each kind of state the model keeps and, where its layers keep
+        rows of several kinds, ``<kind>_layers``. Once an engine, and again
         where a trace starts (``LLMServer.start_trace``), so that a
         trace says what engine it is of."""
         _fr.mark("serve", "engine.state_init", flush=False, attrs={
             "engine": self.name, "slots": self.slots,
             "max_len": self.max_len,
-            **{f"{kind}_bytes": n for kind, n in self.state_bytes.items()}})
+            **{f"{kind}_bytes": n for kind, n in self.state_bytes.items()},
+            **{f"{kind}_layers": n
+               for kind, (n, _) in self.row_kinds.items()}})
 
     # -- submission boundary --
 
@@ -1187,12 +1197,19 @@ class RaggedDecoder:
         at the chunk's end, and ``cache_rows``, slots x max_len (span
         attrs; ``attn_live_rows`` / ``attn_cache_rows`` in stats() are
         their monotonic totals). Their ratio is the share of the cache
-        that held a row."""
-        occupied = [st is not None for st in self.slot_stream]
-        sp["live_rows"] = live = int(pos_np[occupied].sum())
+        that held a row. Where the model's layers keep rows of several
+        kinds (``row_kinds``), also ``live_rows_<kind>``: the same sum
+        with each slot's position cut to the most rows a layer of that
+        kind keeps (``attn_live_rows_by_kind`` in stats())."""
+        held = pos_np[[st is not None for st in self.slot_stream]]
+        sp["live_rows"] = live = int(held.sum())
         sp["cache_rows"] = self.slots * self.max_len
         self.attn_live_rows += live
         self.attn_cache_rows += self.slots * self.max_len
+        for kind, (_, most) in self.row_kinds.items():
+            sp[f"live_rows_{kind}"] = rows = int(
+                np.minimum(held, most or self.max_len).sum())
+            self.attn_live_rows_by_kind[kind] += rows
 
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
         """The routing counters a read-back brought: ``touched`` holds
@@ -1390,7 +1407,9 @@ class RaggedDecoder:
         ``prefill_calls``: cold prefills, one prompt each;
         ``weights_bytes``: what the serving tree holds on the device,
         ``state_bytes``: what the slots' state holds there, by kind;
-        ``attn_live_rows`` / ``attn_cache_rows``: see ``_count_rows``;
+        ``attn_live_rows`` / ``attn_cache_rows`` and, for a model with
+        rows of several kinds, ``attn_live_rows_by_kind``: see
+        ``_count_rows``;
         for a mixture-of-experts model ``moe_assignments`` and
         ``moe_touched_expert_steps``, see ``__init__``)."""
         active = sum(1 for st in self.slot_stream if st is not None)
@@ -1408,6 +1427,8 @@ class RaggedDecoder:
             "attn_live_rows": self.attn_live_rows,
             "attn_cache_rows": self.attn_cache_rows,
         }
+        if self.row_kinds:
+            out["attn_live_rows_by_kind"] = dict(self.attn_live_rows_by_kind)
         if self.model.reports_routing(self.cfg):
             out["moe_assignments"] = self.moe_assignments
             out["moe_touched_expert_steps"] = self.moe_touched_expert_steps

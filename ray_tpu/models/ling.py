@@ -26,13 +26,13 @@ are ever sliced out of a stack.
   unabsorbed; a decode step attends in the absorbed form over the latent
   rows (:func:`mla_step`). A learned RMS norm over each head's whole q;
   a head-wise sigmoid gate on the output.
-- **MoE** (DeepSeek-V3's routing): sigmoid scores in float32, a bias
-  added for selection only, ``topk_group`` of ``n_group`` groups kept by
-  the sum of their two best, the ``top_k`` best of those chosen, their
-  unbiased scores renormalised and scaled; a shared expert beside them.
+- **MoE** (DeepSeek-V3's routing, ``models/moe.py``, shared with
+  ``models/exaone.py``): sigmoid scores in float32, a bias added for
+  selection only, ``topk_group`` of ``n_group`` groups kept, the
+  ``top_k`` best of those chosen; a shared expert beside them.
   ``held_experts = (first, count)`` tells the layer which experts live
   here: it routes over all of them and computes the part of the result
-  that its own give (:func:`moe`); what the others would add is left out.
+  that its own give; what the others would add is left out.
 
 A slot's state in the serving engine is this model's own
 (:data:`SLOTS`, what ``decode_engine.slot_model`` finds through
@@ -54,13 +54,16 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
 from ray_tpu.models.decode_engine import _sample_from_logits
+from ray_tpu.models.moe import draw as _draw
+from ray_tpu.models.moe import moe, prefill_loads, routing_counts
+from ray_tpu.models.moe import route  # noqa: F401 — the name the tests know
+from ray_tpu.models.moe import swiglu as _swiglu
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rotary, rotary_embedding
 
@@ -147,30 +150,6 @@ class LingConfig:
 # leaves the model paths consume in float32
 _F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "q_norm",
                "kv_norm", "a_log", "dt_bias", "router_bias")
-_BLOCK_ELEMS = 1 << 22  # a leaf is drawn in float32 blocks of this many
-
-
-def _draw(key, shape, scale: float, dtype):
-    """Normal(0, scale) of ``shape`` in ``dtype``, drawn in float32
-    blocks along the leading axis and rounded block by block: a 250 M
-    leaf never exists in float32. The bits come from the device's own
-    generator (an ``rbg`` key made of ``key``): threefry in XLA
-    operations takes over a minute for 5 B numbers on the chip."""
-    data = jax.random.key_data(key) if jnp.issubdtype(
-        key.dtype, jax.dtypes.prng_key) else key
-    key = jax.random.wrap_key_data(
-        jnp.concatenate([data, data ^ jnp.uint32(0x9E3779B9)]), impl="rbg")
-    n = shape[0]
-    rows = max(1, _BLOCK_ELEMS // max(1, math.prod(shape[1:])))
-    rows = max(r for r in range(1, min(rows, n) + 1) if n % r == 0)
-    if rows == n:
-        return (jax.random.normal(key, shape, jnp.float32)
-                * scale).astype(dtype)
-    blocks = jax.lax.map(
-        lambda k: (jax.random.normal(k, (rows, *shape[1:]), jnp.float32)
-                   * scale).astype(dtype),
-        jax.random.split(key, n // rows))
-    return blocks.reshape(shape)
 
 
 def init_params(cfg: LingConfig, key):
@@ -527,81 +506,6 @@ def mla_step(cfg: LingConfig, p, x, cache, pos):
 # MLPs
 # --------------------------------------------------------------------------
 
-def _swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
-
-
-def route(cfg: LingConfig, scores, bias):
-    """The router's choice from its sigmoid ``scores`` [..., E] float32:
-    -> (weights [..., top_k] float32, expert ids [..., top_k]). The bias
-    moves the SELECTION only: groups are ranked by the sum of their two
-    best biased scores, the ``top_k`` best biased scores of the kept
-    groups are chosen (``lax.top_k``: exactly top_k, the lower index on
-    a tie), and the weights are the chosen experts' unbiased scores,
-    renormalised to 1 and scaled."""
-    e, ng = cfg.n_experts, cfg.n_group
-    biased = scores + bias
-    grouped = biased.reshape(*biased.shape[:-1], ng, e // ng)
-    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(group_score, cfg.topk_group)
-    keep = jnp.any(jax.nn.one_hot(kept, ng, dtype=jnp.bool_), axis=-2)
-    masked = jnp.where(keep[..., None], grouped, -jnp.inf)
-    _, ids = jax.lax.top_k(masked.reshape(biased.shape), cfg.top_k)
-    chosen = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
-        * cfg.routed_scaling_factor
-    return weights, ids
-
-
-def moe(cfg: LingConfig, p, x, aux: dict | None = None):
-    """The expert layer of a device that holds ``cfg.held`` = (first,
-    count) of the experts. x [B, T, D]. Every token is routed over ALL
-    experts; an assignment to an expert that is not held is left out:
-    in the sort by expert it falls behind the held ones, into rows that
-    belong to no group, which the grouped matmul never visits
-    (``ops/grouped_matmul.py``: its grid covers the groups' rows only;
-    off the TPU ``ragged_dot`` leaves such rows zero), its gather reads
-    row 0 and its part of the sum is masked. The shared expert is
-    computed in full. On one device the layer runs without an exchange:
-    the other devices' partial sums are not here and nothing stands in
-    for them. With ``aux`` the chosen ids [B, T, top_k] are left in
-    ``aux["expert_ids"]``."""
-    from ray_tpu.ops.grouped_matmul import grouped_matmul
-
-    cdt = cfg.compute_dtype
-    b, t, d = x.shape
-    kk = cfg.top_k
-    first, count = cfg.held
-    xf = x.reshape(b * t, d)
-    with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            xf, p["router"], preferred_element_type=jnp.float32))
-        weights, ids = route(cfg, scores, p["router_bias"])
-        if aux is not None:
-            aux["expert_ids"] = ids.reshape(b, t, kk)
-    with jax.named_scope("moe_experts"):
-        local = ids.reshape(-1) - first
-        held = (local >= 0) & (local < count)
-        key = jnp.where(held, local, count)  # the others sort last
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.sum(
-            jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
-        rows = xf[jnp.where(held[order], order // kk, 0)]
-        experts = functools.partial(grouped_matmul,
-                                    group_sizes=group_sizes)
-        gate = experts(rows, p["w_gate"])
-        up = experts(rows, p["w_up"])
-        y = experts(jax.nn.silu(gate) * up, p["w_down"])
-        unsort = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
-        out = jnp.sum(y.reshape(b * t, kk, d) * weights[..., None], axis=1)
-    with jax.named_scope("moe_shared"):
-        out = out.astype(cdt) + _swiglu(
-            xf, p["shared_gate"], p["shared_up"], p["shared_down"])
-    return out.reshape(b, t, d)
-
-
 def _mlp(cfg: LingConfig, i: int, p, x, aux: dict | None = None):
     with jax.named_scope("mlp"):
         if cfg.mlp_kind(i) == "dense":
@@ -692,21 +596,9 @@ def step(cfg: LingConfig, params, tok, layers_state, pos, active):
         h = h + _mlp(cfg, i, p["mlp"],
                      rms_norm(h, p["mlp_norm"], cfg.rms_eps), aux)
         if aux:
-            counts.append(_routing_counts(cfg, aux["expert_ids"], active))
+            counts.append(routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
     return _logits(cfg, params, h)[:, 0], new_state, *counters
-
-
-def _routing_counts(cfg: LingConfig, ids, active) -> tuple:
-    """ids [B, 1, top_k] of one expert layer -> (distinct held experts
-    that got a row from an active slot, the active slots' assignments,
-    those of them to held experts), int32 scalars."""
-    first, count = cfg.held
-    hit = jax.nn.one_hot(ids - first, count, dtype=jnp.bool_)
-    hit = hit & active[:, None, None, None]
-    return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),
-            jnp.sum(active, dtype=jnp.int32) * ids.shape[-1],
-            jnp.sum(hit, dtype=jnp.int32))
 
 
 # --------------------------------------------------------------------------
@@ -721,6 +613,7 @@ class _Slots:
     # the state is not rows that can be cut at a position
     rows_state = False
     step_counters = ("experts_touched", "assignments", "held_assignments")
+    row_kinds = staticmethod(lambda cfg: {})  # one layer in six keeps rows
     serving_params = staticmethod(serving_params)
 
     @staticmethod
@@ -790,14 +683,8 @@ class _Slots:
         last = _logits(cfg, params, h[jnp.arange(f), true_lens - 1][:, None])
         toks0, logp0 = _sample_from_logits(
             last[:, 0], seeds, true_lens - 1, temps, top_ps)
-        loads = ()
-        if aux:
-            first, count = cfg.held
-            ids = aux["expert_ids"]  # [L_moe, F, P, top_k]
-            real = jnp.arange(ids.shape[2])[None, :] < true_lens[:, None]
-            hit = jax.nn.one_hot(ids - first, count, dtype=jnp.int32)
-            loads = (jnp.sum(hit * real[None, :, :, None, None],
-                             axis=(1, 2, 3)),)
+        loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \
+            if aux else ()
         return {"layers": layers}, true_lens, toks0, logp0, *loads
 
     @staticmethod

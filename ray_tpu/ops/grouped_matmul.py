@@ -69,16 +69,21 @@ def tiling(m: int, k: int, n: int, tm: int | None = None,
            tn: int | None = None, itemsize: int = 2) -> tuple[int, int]:
     """-> (tm, tn) for ``m`` rows, ``k`` contracted and ``n`` output
     columns: the preferred tile, no larger than the (16-padded) rows;
-    ``tn`` a divisor of ``n`` whose [k, tn] block fits ``_BLOCK_BYTES``."""
+    ``tn`` a divisor of ``n`` whose [k, tn] block fits ``_BLOCK_BYTES``:
+    the budget halved until it divides ``n`` or, where halving leaves
+    the lanes (6144 -> 2048: 640 columns fit, and 2048 has no divisor
+    among 640, 320, ...), the largest whole-lane divisor within the
+    budget (512); all of ``n`` only where it has none."""
     tm = min(tm or TILE_M, _round_up(m, 16))
     if tn is None:
         tn = max(128, _BLOCK_BYTES // (k * itemsize) // 128 * 128)
         tn = min(TILE_N, tn)
-    tn = min(tn, n)
+    budget = tn = min(tn, n)
     while n % tn:
         tn //= 2
     if tn % 128 and tn != n:
-        tn = n
+        tn = max((c for c in range(128, budget + 1, 128) if n % c == 0),
+                 default=n)
     return tm, tn
 
 

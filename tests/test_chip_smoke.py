@@ -177,6 +177,19 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys):
         assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
 
 
+def test_ring_check_rehearses_on_the_cpu():
+    """What ``hybrid_phase`` asks of a sliding-window layer's ring on the
+    chip, at tiny widths (a window of 8) with the kernel in the Pallas
+    interpreter: 20 positions, two wraps, four slots at different
+    positions and one inactive; the kernel on the ring and the XLA body
+    agree at every step."""
+    found = chip_smoke.ring_check("tiny", TINY.ring_steps, TINY.seed,
+                                  interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert (found["window"], found["wraps"]) == (8, 2)
+    assert 0 <= found["rel_err"] <= chip_smoke.HYBRID_TOLERANCE
+
+
 @pytest.mark.slow
 def test_serve_phase_rehearses_on_the_cpu(cluster, capsys):
     rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[:1])

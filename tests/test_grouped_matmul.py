@@ -92,3 +92,22 @@ def test_tiling_fits_the_shapes():
     # an expert's [k, tn] block stays within 8 MiB
     assert tiling(8192, 14336, 4096) == (256, 256)
     assert tiling(8192, 4096, 14336, itemsize=4) == (256, 512)
+
+
+@pytest.mark.parametrize("shape, want", [
+    # K-EXAONE's experts, 6144 -> 2048: 640 columns fit the 8 MiB and
+    # halving 640 never divides 2048; the largest whole-lane divisor
+    # within the budget, not all 2,048 columns (a 25 MB block)
+    ((512, 6144, 2048), (256, 512)), ((8192, 6144, 2048), (256, 512)),
+    ((512, 2048, 6144), (256, 2048)),
+    # Ling's and OLMoE's, as they were
+    ((256, 2560, 768), (256, 768)), ((256, 768, 2560), (256, 512)),
+    ((64, 2048, 1024), (64, 1024)),
+    # no whole-lane divisor at all: every column
+    ((64, 6144, 200), (64, 200))])
+def test_tiling_keeps_whole_lanes_where_halving_leaves_them(shape, want):
+    tm, tn = tiling(*shape)
+    assert (tm, tn) == want
+    assert shape[2] % tn == 0 and (tn % 128 == 0 or tn == shape[2])
+    if tn % 128 == 0:
+        assert shape[1] * tn * 2 <= 8 * 1024 * 1024

@@ -431,7 +431,9 @@ def test_init_params_makes_the_serving_types_in_blocks(monkeypatch):
     leaf larger than a block drawn block by block (the same values
     whatever the block size would be a different draw: only types,
     shapes and statistics are held)."""
-    monkeypatch.setattr(ling, "_BLOCK_ELEMS", 1 << 10)
+    from ray_tpu.models import moe  # (where the blocks are drawn)
+
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
     cfg = _cfg(dtype="bfloat16")
     params = ling.init_params(cfg, jax.random.PRNGKey(0))
     assert ling.serving_params(cfg, params) is params
