@@ -501,6 +501,79 @@ def kernel_parting(model: dict, kv_heads: list, seed: int, slots: int,
     return {"layouts": out, "device": accelerator.device_report()}
 
 
+def prefill_parting(model: dict, kv_heads: list, seed: int, slots: int,
+                    max_len: int, buckets: list, lens: list,
+                    interpret: bool = False) -> dict:
+    """Runs in a process that holds the device: for each head layout
+    seeded prompts of ``lens`` tokens, each padded to its bucket, through
+    the engine's one-row prefill call twice on the same weights, greedy:
+    attention over the prompt's rows as the backend gives it (on a TPU
+    the ``flash_fwd`` kernel; with ``interpret``, the CPU rehearsal's,
+    the kernel in the Pallas interpreter) and as the plain product
+    (``use_flash=False``). -> per layout ``parting_margins`` of the two
+    first tokens, how many kernel calls the first program was traced
+    with, the largest difference between the rows the two left in the
+    slot, and the device."""
+    import functools
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import decode_engine as de
+    from ray_tpu.models import llama
+    from ray_tpu.ops import flash_attention as fa
+
+    accelerator.claim_device()
+    rng = np.random.RandomState(seed)
+    out = {}
+    for hkv in kv_heads:
+        cfg = llama.LlamaConfig(**{**model, "n_kv_heads": hkv})
+        params = llama.serving_params(
+            cfg, llama.init_params(cfg, jax.random.PRNGKey(seed)))
+        asked = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+                 for n in lens]
+        traced = []
+
+        def counted(*a, _kernel=fa._flash_fwd, **kw):
+            traced.append(1)
+            return _kernel(*a, **kw)
+
+        def first_tokens(cfg) -> tuple:
+            toks, rows = [], []
+            for slot, prompt in enumerate(asked):
+                width = next(b for b in sorted(buckets) if len(prompt) <= b)
+                row = np.zeros((1, width), np.int32)
+                row[0, :len(prompt)] = prompt
+                cache, _, tok0, *_ = de._prefill_batch_into_slots(
+                    params, row, np.array([len(prompt)], np.int32),
+                    np.array([slot], np.int32), np.zeros(1, np.uint32),
+                    np.zeros(1, np.float32), np.ones(1, np.float32),
+                    de.init_ragged_cache(cfg, slots, max_len),
+                    jnp.zeros((slots,), jnp.int32), cfg)
+                toks.append([int(tok0[0])])
+                rows.append(np.asarray(
+                    cache["k"][:, slot, :len(prompt)], np.float32))
+            return toks, rows
+
+        with mock.patch.object(fa, "_flash_fwd", counted), \
+                mock.patch.object(fa, "flash_attention", functools.partial(
+                    fa.flash_attention, interpret=interpret)):
+            kernel, k_rows = first_tokens(dataclasses.replace(
+                cfg, use_flash=True if interpret else None))
+        calls = len(traced)
+        plain, p_rows = first_tokens(dataclasses.replace(
+            cfg, use_flash=False))
+        out[f"{cfg.n_heads}/{hkv}"] = {
+            "kernel_calls_traced": calls,
+            "rows_max_diff": max(float(np.abs(a - b).max())
+                                 for a, b in zip(k_rows, p_rows)),
+            "partings": parting_margins(params, cfg, asked, kernel, plain)}
+    return {"layouts": out, "device": accelerator.device_report()}
+
+
 def chip_child(plan: Plan, call: str, args: dict) -> dict:
     """``chip_smoke.<call>(**args)`` in a child that holds the chip (or,
     in a rehearsal, the CPU), with the platform and chip in its
@@ -543,7 +616,10 @@ def serve_phase(plan: Plan) -> dict:
     which sums a slot's rows block by block under a running maximum,
     against the XLA body over every row, at 16 / 8 and 16 / 16 heads
     (InternLM2's and OLMoE's layouts) with slots at different positions
-    and some empty."""
+    and some empty; and of the cold prefill (``prefill_parting``): its
+    first token with attention over the prompt's rows through the
+    ``flash_fwd`` kernel against the plain product, at the same two
+    layouts, each prompt padded to its bucket."""
     on, on_facts = run_pool(plan, replicas=1, spec=True)
     off, off_facts = run_pool(plan, replicas=1, spec=False)
     agree = agreement(on, off)
@@ -565,7 +641,7 @@ def serve_phase(plan: Plan) -> dict:
         "speculation on and off part where the model's own logits do not "
         "tie", partings=widths["partings"], near_tie=NEAR_TIE)
     heads = model_fields(plan.model_size, 0)["n_heads"]
-    attn = chip_child(plan, "kernel_parting", {
+    attn_args = {
         "model": model_fields(plan.model_size, plan.max_len, n_layers=1,
                               remat=False, use_flash=False),
         "kv_heads": [heads // 2, heads], "seed": plan.seed,
@@ -573,7 +649,8 @@ def serve_phase(plan: Plan) -> dict:
         "chunk_tokens": plan.chunk_tokens,
         "buckets": list(plan.prompt_buckets),
         "lens": [*plan.prompt_lens, max(plan.prompt_lens) // 2 + 1],
-        "max_tokens": plan.max_tokens, "interpret": not plan.on_tpu})
+        "max_tokens": plan.max_tokens, "interpret": not plan.on_tpu}
+    attn = chip_child(plan, "kernel_parting", attn_args)
     check_device(plan, attn["device"], 1, "kernel_parting child")
     for layout, found in attn["layouts"].items():
         check(found["kernel_calls_traced"] > 0,
@@ -584,6 +661,21 @@ def serve_phase(plan: Plan) -> dict:
               "the decode_attn kernel and the XLA body part where the "
               "model's own logits do not tie", layout=layout,
               partings=found["partings"], near_tie=NEAR_TIE)
+    shape = {k: attn_args[k] for k in ("model", "kv_heads", "seed", "slots",
+                                       "max_len", "buckets", "lens")}
+    first = chip_child(plan, "prefill_parting", {
+        **shape, "interpret": not plan.on_tpu})
+    check_device(plan, first["device"], 1, "prefill_parting child")
+    for layout, found in first["layouts"].items():
+        check(found["kernel_calls_traced"] > 0,
+              "the cold prefill was traced without the flash_fwd kernel",
+              layout=layout)
+        check(all(p is None or p["margin"] < NEAR_TIE
+                  for p in found["partings"]),
+              "the prefill's first token through the flash kernel and "
+              "through the plain product part where the model's own logits "
+              "do not tie", layout=layout, partings=found["partings"],
+              near_tie=NEAR_TIE)
     warm = next(iter(off_facts["compile"].values()))
     if warm["requests"]:  # the persistent cache is on in this run
         check(warm["hits"] > 0,
@@ -595,6 +687,7 @@ def serve_phase(plan: Plan) -> dict:
             "spec_on_vs_off_agreeing_tokens": agree,
             "spec_on_vs_off_partings": widths["partings"],
             "decode_attn_vs_xla_body": attn["layouts"],
+            "prefill_flash_vs_product": first["layouts"],
             "near_tie": NEAR_TIE,
             "compile_s": cold["seconds"], "compile_s_second_start":
             warm["seconds"], "spec_on": on_facts, "spec_off": off_facts}
@@ -770,6 +863,48 @@ def train4_phase(plan: Plan) -> dict:
 FLASH_TOLERANCE = 3e-2  # max |flash - reference| over max |reference|
 
 
+PREFILL_ROWS = (256, 512, 1024)  # the doc cells' buckets
+MXU_FLOPS = 197e12  # a v5e's bf16 matrix unit (benchmark/peaks.json)
+
+
+def flash_prefill_ms(hq: int, hkv: int, d: int, interpret: bool,
+                     layers: int = 24) -> dict:
+    """The flash forward as a cold prefill calls it: batch 1, a bucket's
+    rows, ``layers`` calls in one program, each taking the last one's
+    output for its queries as a model's layers follow each other. -> per
+    bucket the best of five runs in ms a call beside the time of the
+    call's causal FLOPs (2 x P^2 x Hq x d: under 2,048 rows the kernel
+    takes a row's keys in one pass and multiplies twice that) at the
+    matrix unit's peak. A rehearsal times the interpreter: no speed."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    out = {}
+    for rows in PREFILL_ROWS:
+        q, k, v = (jax.random.normal(key, (1, rows, h, d), jnp.bfloat16)
+                   for key, h in zip(jax.random.split(jax.random.PRNGKey(1),
+                                                      3), (hq, hkv, hkv)))
+
+        @jax.jit
+        def calls(q, k, v):
+            return jax.lax.fori_loop(0, layers, lambda _, o: flash_attention(
+                o, k, v, causal=True, interpret=interpret), q)
+
+        jax.block_until_ready(calls(q, k, v))
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(calls(q, k, v))
+            best = min(best, time.perf_counter() - t0)
+        out[str(rows)] = {
+            "ms": round(1e3 * best / layers, 4),
+            "causal_flops_ms": round(
+                1e3 * 2 * rows * rows * hq * d / MXU_FLOPS, 4)}
+    return out
+
+
 def kernels_check(shape: list, model: dict, batch: int, seq: int,
                   interpret: bool) -> dict:
     """Runs in a child that holds the chip: flash forward and backward
@@ -819,6 +954,8 @@ def kernels_check(shape: list, model: dict, batch: int, seq: int,
         params, tokens).compile().as_text()
     return {"rel_err": errs, "forward_has_kernel": KERNEL in text,
             "forward_compile_s": round(time.monotonic() - t0, 1),
+            "prefill_ms": flash_prefill_ms(
+                hq, hkv, d, interpret, layers=2 if interpret else 24),
             "device": accelerator.device_report()}
 
 
@@ -839,6 +976,7 @@ def kernels_phase(plan: Plan) -> dict:
             "rel_err_vs_reference": out["rel_err"],
             "tolerance": FLASH_TOLERANCE,
             "kernel_in_forward": out["forward_has_kernel"],
+            "flash_fwd_ms_at_batch_1": out["prefill_ms"],
             "compile_s": out["device"]["compile"]["seconds"]}
 
 

@@ -443,38 +443,43 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
 
 
 def _prefill_core(params, prompts, true_lens, seeds, temps, top_ps,
-                  cfg: LlamaConfig, slot_len: int, prefix=None):
+                  cfg: LlamaConfig, prefix=None):
     """The one prefill: [F, P] RIGHT-padded tokens (one shared bucket P,
-    ``true_lens`` [F] of them real) through a temporary cache of
-    ``slot_len`` rows a stream. ``prefix`` is ``None`` (a fresh cache:
-    the tokens are whole prompts) or ``(k, v, n_prefix)``: rows
-    [L, F, S, Hkv, D] filled up to the scalar ``n_prefix`` and zero
-    beyond, behind which the tokens (the prompts' suffixes) are written.
-    The first token comes from the TRUE last prompt position on the
-    (seed, position) lane of the chunk programs (seeds/temps/top_ps [F];
+    ``true_lens`` [F] of them real). Its keys are the rows the call was
+    given plus the rows it makes (``llama.prefill``). ``prefix`` is
+    ``None``: the tokens are whole prompts, and the work is the
+    bucket's, P rows a layer whatever the slot's length (attention over
+    the prompt's own rows, the flash kernel on a TPU: one block up to
+    its 1,024 rows, a wider bucket whole blocks, or ``attention`` raises
+    when the program is traced); or ``(k, v, n_prefix)``: rows
+    [L, F, S, Hkv, D] filled up to the scalar ``n_prefix`` (the prefix
+    cache's), behind which the tokens (the prompts' suffixes) are
+    written. The final norm and the head see the TRUE last prompt
+    position alone, and the first token comes from it on the (seed,
+    position) lane of the chunk programs (seeds/temps/top_ps [F];
     temperature 0 = greedy), so a failover replay reproduces it
     whichever prefill path (inline, suffix, disaggregated) the
-    replacement replica takes. Returns (k, v [L, F, S, Hkv, D], [F]
-    whole prompt lengths, [F] first tokens, [F] their logprobs) and,
-    for a model that reports its routing, ``expert_tokens`` [L, E].
+    replacement replica takes. Returns (k, v [L, F, P or S, Hkv * D]:
+    rows as the stack holds them, [F] whole prompt lengths, [F] first
+    tokens, [F] their logprobs) and, for a model that reports its
+    routing, ``expert_tokens`` [L, E].
 
     Right-padding is safe without a pad mask: causal attention means
     real tokens (a prefix) never see the pad garbage, and each later
     decode step overwrites a pad cache row at its position before the
     growing per-slot mask can expose it."""
-    f = prompts.shape[0]
     if prefix is None:
-        tmp, full_lens = llama.init_cache(cfg, f, slot_len), true_lens
+        given, full_lens = None, true_lens
     else:
         k, v, n_prefix = prefix
-        tmp = {"k": k, "v": v, "pos": n_prefix}
+        given = (_kv_rows(k), _kv_rows(v), n_prefix)
         full_lens = n_prefix + true_lens
     aux = {}
-    logits, tmp = llama.forward_with_cache(params, prompts, cfg, tmp, aux)
-    last_logits = logits[jnp.arange(f), true_lens - 1].astype(jnp.float32)
+    last_logits, k, v = llama.prefill(
+        params, prompts, true_lens - 1, cfg, given, aux)
     toks0, logp0 = _sample_from_logits(
         last_logits, seeds, full_lens - 1, temps, top_ps)
-    return (tmp["k"], tmp["v"], full_lens, toks0, logp0,
+    return (k, v, full_lens, toks0, logp0,
             *_expert_tokens(cfg, aux, true_lens))
 
 
@@ -496,13 +501,18 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     (kv_prefix_cache.py docstring). Returns (new cache, new cur_tok,
     [F] first tokens, [F] first-token logprobs, *expert_tokens).
 
-    FULL-SLOT-OVERWRITE ASSUMPTION: correctness of slot reuse depends on
-    this scatter replacing ALL max_len cache rows of the slot (tmp is a
-    full-length cache, zeros past the prompt), never a prefix. A
-    partial-row write would leave the previous occupant's k/v beyond the
-    prompt, and the new stream's growing mask — or a clamped write at
-    row max_len-1 from a slot that decoded to the cache edge — would
-    eventually attend over stale tokens."""
+    NO READER LOOKS PAST A SLOT'S OWN LENGTH: the scatter writes the
+    rows the prefill made (a cold call: its bucket's P) and the slot's
+    ``pos``, and leaves what the slot's last stream wrote behind them.
+    Slot reuse is correct because nothing reads those rows before the
+    new stream has written them: a decode step (and a speculative
+    verify) writes its rows at ``pos`` before it attends, the attention
+    reads a slot up to ``lengths`` = the rows written (``decode_attn``
+    takes no block past them and masks inside the last; the XLA body
+    ``attend_ragged`` masks by them), an inactive slot's length is 0,
+    and a stream ends at ``pos == max_len - 1``, where the clamped write
+    lands on the row the mask has just reached
+    (``tests/test_serve_llm.py``: a reused slot)."""
     model = slot_model(cfg)
     streams, full_lens, toks0, logp0, *expert_tokens = model.prefill(
         params, prompts, true_lens, seeds, temps, top_ps, cfg,
@@ -545,8 +555,10 @@ class _LlamaSlots:
       slot_len, prefix)`` -> (the streams' state, whole prompt lengths,
       first tokens, their logprobs, *per-expert assignment counts);
       ``scatter(state, slots, streams, full_lens)``: that state into
-      its slots, each slot REPLACED whole (a reused slot keeps nothing
-      of its last stream);
+      its slots, so that a reused slot shows nothing of its last stream
+      (the Llama block: the rows a stream can read are its own, see
+      ``_prefill_batch_into_slots``; a state that is no rows is
+      replaced whole);
     - ``reports_routing(cfg)``."""
 
     rows_state = True
@@ -573,16 +585,20 @@ class _LlamaSlots:
         return logits[:, 0], {"k": k, "v": v}, *touched
 
     @staticmethod
-    def prefill(*args):
-        k, v, *rest = _prefill_core(*args)
+    def prefill(params, prompts, true_lens, seeds, temps, top_ps, cfg,
+                slot_len, prefix=None):
+        k, v, *rest = _prefill_core(
+            params, prompts, true_lens, seeds, temps, top_ps, cfg, prefix)
         return {"k": k, "v": v}, *rest
 
     @staticmethod
     def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
-        # k/v: [L, F, S, Hkv, D] -> scatter rows onto the slot axis
+        # k/v: [L, F, R, Hkv * D], the R rows the prefill made, onto the
+        # first R rows of their slots
+        rows = streams["k"].shape[2]
         return {
-            "k": state["k"].at[:, slots].set(_kv_rows(streams["k"])),
-            "v": state["v"].at[:, slots].set(_kv_rows(streams["v"])),
+            "k": state["k"].at[:, slots, :rows].set(streams["k"]),
+            "v": state["v"].at[:, slots, :rows].set(streams["v"]),
             "pos": state["pos"].at[slots].set(full_lens),
         }
 
@@ -590,9 +606,11 @@ class _LlamaSlots:
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
 def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
                cfg: LlamaConfig, slot_len: int):
-    """Prefill WITHOUT a slot (:func:`_prefill_core` on a fresh cache):
-    the raw KV rows, the first tokens and their behavior logprobs
-    ((k, v) [L, F, S, Hkv, D], toks0 [F], logp0 [F]). This is the
+    """Prefill WITHOUT a slot (a cold :func:`_prefill_core`): the raw
+    KV rows, the first tokens and their behavior logprobs
+    ((k, v) [L, F, S, Hkv, D], the prompt's rows with zeros behind them
+    up to ``slot_len``, the payload ``submit_prefilled`` checks; toks0
+    [F], logp0 [F]). This is the
     dedicated prefill worker's op (serve/llm_pool.py): the rows travel
     through the object store and a decode replica adopts them into a
     slot with `RaggedDecoder.submit_prefilled` — the same prefill and
@@ -600,17 +618,23 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
     inline-prefilled one, greedy or sampled."""
     require_rows(cfg, "disaggregated prefill (prefill_kv)")
     k, v, _, toks0, logp0, *_ = _prefill_core(
-        params, prompts, true_lens, seeds, temps, top_ps, cfg, slot_len)
-    return k, v, toks0, logp0
+        params, prompts, true_lens, seeds, temps, top_ps, cfg)
+
+    def payload(rows):  # [L, F, P, Hkv * D] -> [L, F, S, Hkv, D]
+        rows = jnp.pad(rows, ((0, 0), (0, 0),
+                              (0, slot_len - rows.shape[2]), (0, 0)))
+        return rows.reshape(*rows.shape[:3], cfg.n_kv_heads, cfg.head_dim)
+
+    return payload(k), payload(v), toks0, logp0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache", "cur_tok"))
 def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
                         cur_tok, cfg: LlamaConfig):
-    """Scatter externally-prefilled KV rows ([L, S, Hkv, D] as a prefill
-    makes them, S == the slot cache length — FULL-SLOT-OVERWRITE, see
-    _prefill_batch_into_slots) into `slot` and seed its current token."""
+    """Scatter externally-prefilled KV rows ([L, S, Hkv, D] as
+    ``prefill_kv`` hands them out, S == the slot cache length) into
+    `slot` and seed its current token."""
     cache = {
         "k": cache["k"].at[:, slot].set(_kv_rows(k_rows)),
         "v": cache["v"].at[:, slot].set(_kv_rows(v_rows)),

@@ -640,67 +640,79 @@ def loss_fn(params, batch, cfg: LlamaConfig):
 
 
 # --------------------------------------------------------------------------
-# KV-cache inference (prefill + incremental decode)
+# Serving prefill (the decode steps are models/decode_engine.py's)
 # --------------------------------------------------------------------------
 #
 # The reference serves models through torch (no in-tree decode path); this
-# is the framework-native equivalent that ray_tpu.serve replicas jit:
-# a static-shape cache ([L, B, max_len, Hkv, D]) updated with
-# dynamic_update_slice so the decode step compiles once for all positions.
+# is the framework-native equivalent that ray_tpu.serve replicas jit. A
+# prefill is sized by the rows it is given: a cold one by its prompts'
+# bucket, never by the slot that will hold them.
 
-def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    """Static-shape KV cache. pos = number of valid positions filled."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    cdt = cfg.compute_dtype
-    return {
-        "k": jnp.zeros(shape, cdt),
-        "v": jnp.zeros(shape, cdt),
-        "pos": jnp.zeros((), jnp.int32),
-    }
-
-
-def _layer_with_cache(cfg: LlamaConfig, h, p, sin, cos, ck, cv, pos,
-                      aux: dict | None = None):
-    """_layer variant that appends this block's k/v at `pos` and attends
-    the cache prefix. h: [B, T, D]; ck/cv: [B, S, Hkv, D]."""
+def _attend_behind(q, k, v, pos):
+    """The T rows of ``q`` at positions pos .. pos + T - 1 over S rows of
+    which the first pos + T hold something (``k`` / ``v`` [B, S, Hkv, hd]:
+    given rows, then the call's own): query i sees rows <= pos + i, the
+    rest is masked. The warm path's attention alone: ``pos`` is traced
+    there and the kernel's mask is static. -> [B, T, Hq, hd]."""
     from ray_tpu.ops.attention import _repeat_kv
 
-    b, t, _ = h.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    cdt = cfg.compute_dtype
-    s = ck.shape[1]
-
-    q, k, v = _qkv(cfg, p, h, sin, cos)
-    ck = jax.lax.dynamic_update_slice(ck, k, (0, pos, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
-
-    # Explicit-length attention: query i (global position pos+i) attends
-    # cache slots <= pos+i; slots beyond the filled region are masked.
-    kk = _repeat_kv(ck, hq // hkv)
-    vv = _repeat_kv(cv, hq // hkv)
+    t, hq, hd = q.shape[1:]
+    s = k.shape[1]
+    kk = _repeat_kv(k, hq // k.shape[2])
+    vv = _repeat_kv(v, hq // k.shape[2])
     logits = jnp.einsum(
         "bthd,bshd->bhts", q, kk, preferred_element_type=jnp.float32
     ) * (hd ** -0.5)
     q_pos = pos + jnp.arange(t, dtype=jnp.int32)[:, None]  # [T, 1]
     k_pos = jnp.arange(s, dtype=jnp.int32)[None, :]  # [1, S]
     logits = jnp.where((k_pos <= q_pos)[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(cdt)
-    o = jnp.einsum(
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum(
         "bhts,bshd->bthd", probs, vv, preferred_element_type=jnp.float32
-    ).astype(cdt)
-    h = _attn_out_and_mlp(cfg, p, h, o, aux)
-    return h, ck, cv
+    ).astype(q.dtype)
 
 
-def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict,
-                       aux: dict | None = None):
-    """Run tokens [B, T] starting at cache['pos']; returns (logits [B,T,V],
-    new cache). Covers both prefill (T=prompt len) and decode (T=1).
-    With ``aux`` and a model that reports its routing, every layer's
-    expert ids [L, B, T, top_k] are left in ``aux["expert_ids"]``."""
+def _prefill_layer(cfg: LlamaConfig, h, p, sin, cos, given,
+                   aux: dict | None = None):
+    """_layer variant that hands its k/v rows out. h: [B, T, D]. Its keys
+    are the rows the call was given plus the rows it makes: ``given`` is
+    ``None``, and the T rows (whole prompts from position 0) attend among
+    themselves exactly as :func:`_layer`'s do (``ops.attention``: the
+    flash kernel on a TPU, the reference product elsewhere; nothing
+    wider than T x T exists), or ``(k, v, pos)``, rows [B, S, Hkv * hd]
+    that hold something up to the scalar ``pos``, behind which this
+    layer's are written (:func:`_attend_behind`). Returns (h, k, v), the
+    rows [B, T or S, Hkv * hd] as a slot holds them: a position's kv
+    heads end to end."""
+    b, t, _ = h.shape
+    q, k, v = _qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
+    with jax.named_scope("attn"):
+        if given is None:
+            o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
+            k, v = k.reshape(b, t, -1), v.reshape(b, t, -1)
+        else:
+            gk, gv, pos = given
+            heads = (b, gk.shape[1], *k.shape[2:])
+            k = jax.lax.dynamic_update_slice(
+                gk, k.reshape(b, t, -1), (0, pos, 0))
+            v = jax.lax.dynamic_update_slice(
+                gv, v.reshape(b, t, -1), (0, pos, 0))
+            o = _attend_behind(q, k.reshape(heads), v.reshape(heads), pos)
+    return _attn_out_and_mlp(cfg, p, h, o, aux), k, v
+
+
+def prefill(params, tokens, last, cfg: LlamaConfig, given=None,
+            aux: dict | None = None):
+    """tokens [B, T], RIGHT-padded, from position 0, or with ``given`` =
+    (k, v [L, B, S, Hkv * hd], pos) from the scalar ``pos`` behind the
+    rows given. Returns (float32 logits [B, V] of row ``last`` [B] of the
+    T alone — the final norm and the head see one row a stream —, k, v
+    [L, B, T or S, Hkv * hd]: every layer's rows). With ``aux`` and a
+    model that reports its routing, every layer's expert ids
+    [L, B, T, top_k] are left in ``aux["expert_ids"]``."""
     b, t = tokens.shape
     cdt = cfg.compute_dtype
-    pos = cache["pos"]
+    pos = 0 if given is None else given[2]
     positions = pos + jnp.arange(t, dtype=jnp.int32)[None, :]
     sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
 
@@ -710,21 +722,19 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict,
     layers, attach = split_layers(cfg, params["layers"])
 
     def body(h_, xs):
-        p_, ck, cv = xs
+        p_, *rows = xs
         layer_aux = {} if routed else None
-        h_, ck, cv = _layer_with_cache(cfg, h_, attach(p_), sin, cos, ck,
-                                       cv, pos, layer_aux)
-        return h_, (ck, cv,
-                    *((layer_aux["expert_ids"],) if routed else ()))
+        h_, k, v = _prefill_layer(
+            cfg, h_, attach(p_), sin, cos, (*rows, pos) if rows else None,
+            layer_aux)
+        return h_, (k, v, *((layer_aux["expert_ids"],) if routed else ()))
 
-    h, (ck, cv, *ids) = jax.lax.scan(
-        body, h, (layers, cache["k"], cache["v"])
-    )
+    h, (k, v, *ids) = jax.lax.scan(
+        body, h, (layers,) if given is None else (layers, *given[:2]))
     if routed:
         aux["expert_ids"] = ids[0]
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    h = rms_norm(h[jnp.arange(b), last], params["final_norm"], cfg.rms_eps)
     w_out = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     ).astype(cdt)
-    logits = (h @ w_out).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv, "pos": pos + t}
+    return (h @ w_out).astype(jnp.float32), k, v

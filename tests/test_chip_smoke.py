@@ -153,11 +153,36 @@ def test_kernel_parting_rehearses_on_the_cpu():
                    for p in layout["partings"])
 
 
+def test_prefill_parting_rehearses_on_the_cpu():
+    """What ``serve_phase`` asks of the cold prefill on the chip, at tiny
+    widths with ``flash_fwd`` in the Pallas interpreter: both head
+    layouts, three prompts each padded to its bucket; in f32 the kernel
+    and the plain product leave the same rows and part nowhere outside
+    a near-tie."""
+    found = chip_smoke.prefill_parting(
+        chip_smoke.model_fields("tiny", TINY.max_len, n_layers=1,
+                                remat=False, use_flash=False),
+        [2, 4], TINY.seed, TINY.slots, TINY.max_len,
+        list(TINY.prompt_buckets), [24, 5, 13], interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert set(found["layouts"]) == {"4/2", "4/4"}
+    for layout in found["layouts"].values():
+        assert layout["kernel_calls_traced"] > 0
+        assert layout["rows_max_diff"] < 1e-5
+        assert len(layout["partings"]) == 3
+        assert all(p is None or p["margin"] < chip_smoke.NEAR_TIE
+                   for p in layout["partings"])
+
+
 def test_kernels_phase_rehearses_on_the_cpu():
     facts = chip_smoke.kernels_phase(TINY)
     assert facts["device"].items() >= CPU.items()
     assert set(facts["rel_err_vs_reference"]) == {"out", "dq", "dk", "dv"}
     assert not facts["kernel_in_forward"]  # the CPU takes the reference
+    # the kernel at a prefill's shapes (here the interpreter: no speed)
+    assert set(facts["flash_fwd_ms_at_batch_1"]) == {"256", "512", "1024"}
+    assert all(t["ms"] > 0 and t["causal_flops_ms"] > 0
+               for t in facts["flash_fwd_ms_at_batch_1"].values())
 
 
 def test_hybrid_phase_rehearses_on_the_cpu(capsys):

@@ -73,8 +73,7 @@ def test_the_router_chooses_the_references_experts(ref, cfg, params):
     toks = _tokens(2, (2, 24))
     _, chosen = ref.forward_and_routing(params, toks, M)
     aux = {}
-    llama.forward_with_cache(params, toks, cfg, llama.init_cache(cfg, 2, 24),
-                             aux)
+    llama.prefill(params, toks, np.full((2,), 23, np.int32), cfg, None, aux)
     assert aux["expert_ids"].shape == chosen.shape == (2, 2, 24, 2)
     np.testing.assert_array_equal(np.sort(aux["expert_ids"], -1),
                                   np.sort(chosen, -1))

@@ -3,6 +3,8 @@ greedy-parity of the ragged engine under slot churn, and the Serve
 deployment path end-to-end with concurrent requests sharing one slot
 batch (reference anchor: OPT-30B inference release test)."""
 
+import dataclasses
+import functools
 import time
 
 import numpy as np
@@ -16,6 +18,7 @@ from ray_tpu import serve
 from ray_tpu.cluster_utils import Cluster
 from ray_tpu.models import llama
 from ray_tpu.models.decode_engine import RaggedDecoder
+from ray_tpu.ops import decode_attention as da
 from ray_tpu.serve.api import Deployment
 from ray_tpu.serve.llm import LLMServer
 
@@ -132,31 +135,55 @@ def test_a_burst_compiles_no_prefill_program_beyond_one_per_bucket():
     assert _prefill_batch_into_slots._cache_size() - n0 == 3
 
 
-def test_reused_slot_holds_nothing_of_its_previous_occupant():
-    """Full-slot overwrite with the one-row call: a short prompt that
-    takes over a slot a long stream decoded to the cache's edge in finds
-    zeros past its own rows, and decodes to the edge itself (the clamped
-    write at row max_len-1) with the reference's tokens."""
-    params = llama.init_params(TINY, jax.random.PRNGKey(0))
+@pytest.mark.parametrize("first_ran_to", ["the_edge", "row_40"])
+@pytest.mark.parametrize("body", ["xla", "kernel"])
+def test_reused_slot_shows_nothing_of_its_previous_occupant(
+        body, first_ran_to, monkeypatch):
+    """No reader looks past a slot's own length. A long stream decodes
+    to the cache's edge (the clamped write at row max_len - 1) or stops
+    short of it; a short prompt then takes the slot, and its one-row
+    prefill writes its bucket's 8 rows and the slot's ``pos``, nothing
+    else: the long stream's rows still lie behind them. The short stream
+    decodes over them to the edge itself and emits the reference's
+    tokens, as a fresh engine does, through the XLA body and through the
+    ``decode_attn`` kernel (interpreted), which reads a slot's blocks up
+    to its length and masks inside the last."""
+    cfg = TINY
+    if body == "kernel":  # (a size of its own: decode_chunk is cached)
+        monkeypatch.setattr(da, "decode_attention", functools.partial(
+            da.decode_attention, interpret=True))
+        cfg = dataclasses.replace(
+            TINY, vocab_size=253 - (first_ran_to == "row_40"))
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.RandomState(27)
-    eng = RaggedDecoder(params, TINY, slots=1, max_len=48, chunk_tokens=4,
-                        prompt_buckets=(8, 32))
-    long_p = rng.randint(1, 256, size=30).astype(np.int32)
-    short_p = rng.randint(1, 256, size=5).astype(np.int32)
-    sid = eng.submit(long_p, 100)  # clamped to the slot's room
+    kw = dict(slots=1, max_len=48, chunk_tokens=4, prompt_buckets=(8, 32))
+    eng = RaggedDecoder(params, cfg, **kw)
+    long_p = rng.randint(1, 250, size=30).astype(np.int32)
+    short_p = rng.randint(1, 250, size=5).astype(np.int32)
+    first_new = 100 if first_ran_to == "the_edge" else 10
+    sid = eng.submit(long_p, first_new)  # (clamped to the slot's room)
     eng.drain()
-    assert len(eng.pop_finished(sid).tokens) == 48 - 30 - 1
+    assert len(eng.pop_finished(sid).tokens) == min(first_new, 48 - 30 - 1)
     # (a row of the stack is the position's kv heads end to end)
-    assert np.abs(np.asarray(eng.cache["k"][:, 0, 40:])).min(
-        axis=(0, 2)).max() > 0  # the long stream's rows are there
+    before = {kv: np.asarray(eng.cache[kv]) for kv in "kv"}
+    assert np.abs(before["k"][:, 0, 9:40]).min(axis=(0, 2)).min() > 0
     sid = eng.submit(short_p, 100)
     eng.pump()  # prefill at bucket 8, then 4 decode steps: rows 5..8
-    for kv in ("k", "v"):
-        assert not np.asarray(eng.cache[kv][:, 0, 9:]).any()
+    assert int(eng.cache["pos"][0]) == 9
+    for kv in "kv":  # the long stream's rows, where they were
+        np.testing.assert_array_equal(
+            np.asarray(eng.cache[kv])[:, 0, 9:], before[kv][:, 0, 9:])
+        assert not np.array_equal(
+            np.asarray(eng.cache[kv])[:, 0, :9], before[kv][:, 0, :9])
     eng.drain()
     got = np.asarray(eng.pop_finished(sid).tokens)
     assert len(got) == 48 - 5 - 1
-    np.testing.assert_array_equal(got, _greedy(params, short_p, 42))
+    np.testing.assert_array_equal(
+        got, greedy_tokens(params, short_p, cfg, 42))
+    fresh = RaggedDecoder(params, cfg, **kw)
+    sid = fresh.submit(short_p, 100)
+    fresh.drain()
+    np.testing.assert_array_equal(got, fresh.pop_finished(sid).tokens)
 
 
 @pytest.fixture(scope="module")

@@ -134,10 +134,9 @@ PROGRAMS = {
     "prefill_kv": lambda cfg, tree: de.prefill_kv(
         tree, _prompts(), np.array(LENS, np.int32),
         *_lanes(SLOTS, seed=7, temp=0.5), cfg, MAX_LEN),
-    # the logits behind every first token, at every prompt position
-    "first_token_logits": lambda cfg, tree: llama.forward_with_cache(
-        tree, jnp.asarray(_prompts()), cfg,
-        llama.init_cache(cfg, SLOTS, MAX_LEN)),
+    # the logits behind every first token, and every layer's rows
+    "first_token_logits": lambda cfg, tree: llama.prefill(
+        tree, jnp.asarray(_prompts()), np.array(LENS, np.int32) - 1, cfg),
 }
 
 

@@ -178,10 +178,11 @@ def _weight_casts(text: str, cfg) -> list:
     return found
 
 
-def _lower_prefill(cfg, chip, bucket, **engine):
+def _lower_prefill(cfg, chip, bucket, args=None, **engine):
     """The engine's cold prefill call: one prompt, one row of its
-    bucket's width, into a cache of ``slots`` x ``max_len``."""
-    params, cache, vec = _engine_args(cfg, chip, **engine)
+    bucket's width, into a cache of ``slots`` x ``max_len`` (``args``:
+    a model's own (params, state, vec) in place of ``_engine_args``')."""
+    params, cache, vec = args or _engine_args(cfg, chip, **engine)
     prompt = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=chip)
     return de._prefill_batch_into_slots.lower(
         params, prompt, vec(jnp.int32, 1), vec(jnp.int32, 1),
@@ -226,24 +227,58 @@ INTERNLM2 = dict(vocab_size=92544, d_model=2048, n_layers=2, n_heads=16,
                  max_seq_len=1296, dtype="bfloat16", remat=False)
 
 
-def test_one_row_prefill_compiles_at_internlm2_widths(topo):
-    """The benchmark's doc cell, two layers deep: one prompt in the
-    1024 bucket into 8 slots of 1296 rows. The temporaries are the
-    row's logits over the 92,544-wide head and its full-length k/v,
-    not ``slots`` times that."""
-    cfg = llama.LlamaConfig(**INTERNLM2)
+# the serving cells' engine shapes (benchmark/traffic/doc-saturated.json,
+# chat-steady.json and chat-bursty.json) and prompt buckets
+DOC, CHAT = dict(slots=8, max_len=1296), dict(slots=32, max_len=512)
+PREFILL_CALLS = [("internlm2", DOC, 256), ("internlm2", DOC, 512),
+                 ("internlm2", DOC, 1024), ("internlm2", CHAT, 64),
+                 ("internlm2", CHAT, 128), ("internlm2", CHAT, 256),
+                 ("olmoe", DOC, 1024)]
+
+
+def _shapes(text: str) -> set:
+    """Every array shape of a compiled program's text."""
+    return {tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text)}
+
+
+@pytest.mark.parametrize("model,engine,bucket", PREFILL_CALLS, ids=[
+    f"{m}-{e['slots']}x{e['max_len']}-{b}" for m, e, b in PREFILL_CALLS])
+def test_one_row_prefill_is_sized_by_its_bucket(topo, monkeypatch, model,
+                                                engine, bucket):
+    """The cells' cold prefill call, two layers deep, at each of their
+    six buckets (and the sparse model's widest): one prompt of P rows
+    into ``slots`` x ``max_len``. Attention is the ``flash_fwd`` kernel
+    over the prompt's own rows; nothing but the stack itself has an
+    extent of ``max_len`` rows (no temporary cache, no ``[.., P,
+    max_len]`` scores); the head sees one row (no ``[P, vocabulary]``
+    logits); the donated stack is updated in place, P rows of one slot,
+    and no layer of it moves."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    slots, max_len = engine["slots"], engine["max_len"]
+    # (use_flash: the dispatch would read the CPU backend here)
+    cfg = llama.LlamaConfig(**{
+        **(INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}),
+        "max_seq_len": max_len, "use_flash": True})
     chip = SingleDeviceSharding(topo.devices[0])
-    compiled = _lower_prefill(cfg, chip, 1024, slots=8,
-                              max_len=1296).compile()
+    compiled = _lower_prefill(cfg, chip, bucket, **engine).compile()
+    text = compiled.as_text()
     mem = _mem(compiled)
-    print(f"\nprefill 1 x 1024: {mem}")
+    print(f"\nprefill 1 x {bucket} into {slots} x {max_len}: {mem}")
+    assert "flash_fwd" in text
+    assert text.count(KERNEL) == (1 if model == "internlm2" else 4)
+    shapes = _shapes(text)
+    stack = (cfg.n_layers, slots, max_len, cfg.n_kv_heads * 128)
+    assert {s for s in shapes if max_len in s} == {stack}
+    assert not [s for s in shapes if cfg.vocab_size in s and bucket in s]
     # the cache is donated: updated in place, never copied
-    assert mem["aliased_mib"] >= 2 * 2 * 8 * 1296 * 8 * 128 * 2 // MIB, mem
-    assert mem["temporaries_mib"] < 512, mem
-    # the prompt's own rows change layout on their way into the slot
-    # ([1296, 8, 128] as the prefill makes them -> [1296, 1024] as the
-    # stack holds them: one slot's rows); no layer of the cache moves
-    assert _whole_layer_ops(compiled.as_text(), cfg, 8, 1296) == []
+    assert mem["aliased_mib"] >= 2 * 2 * slots * max_len * (
+        cfg.n_kv_heads * 128) * 2 // MIB, mem
+    assert mem["temporaries_mib"] < 64, mem
+    assert _whole_layer_ops(text, cfg, slots, max_len) == []
 
 
 # ---- the dropless expert layer (OLMoE's widths) ----
@@ -407,7 +442,11 @@ def test_serving_programs_hold_no_cast_of_a_weight(topo, monkeypatch,
         # (512: OLMoE's 256 x top-8 assignment rows are [2048, 2048]
         # themselves, the shape of its wq)
         text = _lower_prefill(cfg, chip, 512, **shape).compile().as_text()
-        assert _weight_casts(text, cfg) == []
+        # (the one row's logits are a fused multiply and reduce over the
+        # head, which converts it on the fly inside the fusion: no copy;
+        # on the chip 0.52 ms for the head's 379 MB, PERF.md PR 35)
+        assert [c for c in _weight_casts(text, cfg)
+                if not c.startswith("lm_head: ")] == []
         return
     params, cache, vec = _engine_args(cfg, chip, **shape)
     masters = _on(chip, jax.eval_shape(
@@ -683,11 +722,15 @@ def test_full_1b_train_step_compiles(topo, chips):
     assert KERNEL in text
 
 
-# ---- as a script: the cells' serving programs as comparable text ----
+# ---- as a script: the cells' programs as comparable text ----
 
 SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("internlm2-1.8b", "chat-steady"),
-                 ("olmoe-1b-7b-0125-1chip", "doc-saturated"))
+                 ("olmoe-1b-7b-0125-1chip", "doc-saturated"),
+                 ("ling-3.0-flash-vl-ep4-1chip", "reason-saturated"),
+                 ("k-exaone-236b-a23b-ep8-1chip", "reason-long-saturated"))
+TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
+               ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 
 
 def _comparable(compiled) -> str:
@@ -716,10 +759,14 @@ def _comparable(compiled) -> str:
 
 
 def dump_serving_programs(out_dir: str) -> None:
-    """``decode_chunk(lanes=None)`` and the one-row
+    """Every program the benchmark's cells run, compiled for a described
+    v5e: ``decode_chunk(lanes=None)`` and the one-row
     ``_prefill_batch_into_slots`` at every bucket, at each serving
     configuration's own fields and each engine shape of its cells
-    (``benchmark/``), compiled for one described v5e chip."""
+    (``benchmark/``; ``chat-bursty``'s is ``chat-steady``'s), and both
+    cells' train steps on their meshes."""
+    import dataclasses
+
     from jax.experimental import topologies
 
     from benchmark import manifest
@@ -728,31 +775,56 @@ def dump_serving_programs(out_dir: str) -> None:
 
     jax.config.update("jax_enable_compilation_cache", False)
     # (the dispatches would read the CPU backend here and take
-    # ragged_dot and the XLA body)
+    # ragged_dot, the XLA body and the reference product)
     gm.grouped_matmul = functools.partial(gm.grouped_matmul, use_kernel=True)
     da.decode_attention = functools.partial(da.decode_attention,
                                             use_kernel=True)
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
-    for config, traffic in SERVING_CELLS:
-        with open(f"benchmark/traffic/{traffic}.json") as f:
-            eng = json.load(f)["engine"]
-        shape = dict(slots=eng["slots"], max_len=eng["max_len"])
-        cfg = llama.LlamaConfig(**{**manifest.model(config)[1],
-                                   "max_seq_len": eng["max_len"]})
-        params, cache, vec = _engine_args(cfg, chip, **shape)
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def write(name, compiled):
+        path = f"{out_dir}/{name}.txt"
+        with open(path, "w") as f:
+            f.write(_comparable(compiled))
+        print(path, flush=True)
+
+    def traffic(name):
+        with open(f"benchmark/traffic/{name}.json") as f:
+            return json.load(f)
+
+    for config, cell in SERVING_CELLS:
+        eng = traffic(cell)["engine"]
+        slots, max_len = eng["slots"], eng["max_len"]
+        fam, m = manifest.model(config)
+        prog = fam.build(m, max_seq_len=max_len, remat=False)
+        cfg = prog.cfg
+        if isinstance(cfg, llama.LlamaConfig):
+            cfg = dataclasses.replace(cfg, use_flash=True)
+            params, state, _ = _engine_args(cfg, chip, slots, max_len)
+        else:  # (a block of its own draws the serving types itself)
+            params = _on(chip, jax.eval_shape(prog.init_params,
+                                              jax.random.PRNGKey(0)))
+            state = _on(chip, jax.eval_shape(
+                lambda: de.slot_model(cfg).init_state(cfg, slots, max_len)))
+        vec = lambda dt, n=slots: jax.ShapeDtypeStruct(  # noqa: E731
+            (n,), dt, sharding=chip)
         programs = {"decode_chunk": de.decode_chunk.lower(
-            params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+            params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
             chunk=eng["chunk_tokens"])}
         for bucket in eng["prompt_buckets"]:
             programs[f"prefill_{bucket}"] = _lower_prefill(
-                cfg, chip, bucket, **shape)
+                cfg, chip, bucket, (params, state, vec))
         for name, lowered in programs.items():
-            path = (f"{out_dir}/{config}.{eng['slots']}x{eng['max_len']}."
-                    f"{name}.txt")
-            with open(path, "w") as f:
-                f.write(_comparable(lowered.compile()))
-            print(path, flush=True)
+            write(f"{config}.{slots}x{max_len}.{name}", lowered.compile())
+    for config, cell in TRAIN_CELLS:
+        tr = traffic(cell)
+        fam, m = manifest.model(config)
+        cfg = dataclasses.replace(
+            fam.build(m, max_seq_len=tr["seq"], remat=True).cfg,
+            use_flash=True)
+        write(f"{config}.{cell}.train_step", _train_step(
+            topo, cfg, MeshConfig(**tr["mesh"]), tr["batch"], tr["seq"]))
 
 
 if __name__ == "__main__":
