@@ -1,0 +1,8 @@
+"""Device time in ``mlp`` (the dense SwiGLU with its norm) over the
+device's busy time of the traced part, all programs together, in percent
+(``benchmark/part_reduce.py``)."""
+from benchmark import part_reduce
+
+
+def read(facts):
+    return part_reduce.share_pct(facts, "mlp")

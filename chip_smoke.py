@@ -253,6 +253,57 @@ def serve_requests(plan: Plan) -> dict:
     }
 
 
+UNJOINED_LIMIT = 0.01  # of the engine's programs' device time
+
+
+def capture_check(plan: Plan, pool, addr) -> dict:
+    """A short capture of the pool's one replica under two requests
+    (``LLMPool.trace_replicas``): it must leave ``program_parts.json``
+    beside the trace with a map for the programs the engine ran, and on
+    the chip every operation on ``XLA Ops`` inside an execution of those
+    programs must be found in it by name: the one join only the chip can
+    check (the reader's own, ``benchmark/part_reduce.py``). -> what was
+    found; fails where more than ``UNJOINED_LIMIT`` of their time is
+    not."""
+    import ray_tpu
+    from benchmark import part_reduce, trace_reduce
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_trace_") as tmp:
+        t0 = time.monotonic()
+        tracing = pool.method("trace_replicas").remote(tmp, 3.0)
+        time.sleep(0.5)
+        for name in ("greedy", "sampled"):
+            ask(addr, serve_requests(plan)[name])
+        dirs = ray_tpu.get(tracing, timeout=600)
+        took = time.monotonic() - t0
+        doc = part_reduce.load_map(dirs[0])
+        check(doc is not None and doc["programs"],
+              "the capture left no program_parts.json, or an empty one",
+              dir=dirs[0], found=doc)
+        found = {"programs": {k: [v["what"] for v in vs]
+                              for k, vs in doc["programs"].items()},
+                 "map_s": doc["seconds"], "capture_s": round(took, 2),
+                 "map_bytes": os.path.getsize(
+                     os.path.join(dirs[0], part_reduce.FILE))}
+        table = part_reduce.by_part(trace_reduce.load_xplane(dirs[0]), doc)
+    check((table is not None) == plan.on_tpu,
+          "a device plane in the capture, or none on the chip", table=table)
+    if table is None:  # (a rehearsal: the CPU leaves no device plane)
+        return found
+    ours = {p: parts for p, parts in table["programs"].items()
+            if p in doc["programs"]}
+    total = sum(sum(parts.values()) for parts in ours.values())
+    unjoined = sum(parts.get(part_reduce.UNJOINED, 0.0)
+                   for parts in ours.values())
+    found.update(device_s=round(total, 4), unjoined_s=round(unjoined, 6),
+                 by_part=table["programs"], mixed_s=table["mixed_s"],
+                 unscoped_ops=table["unscoped_ops"])
+    check(total > 0 and unjoined <= UNJOINED_LIMIT * total,
+          "operations of the engine's programs are missing from "
+          "program_parts.json", **found)
+    return found
+
+
 def run_pool(plan: Plan, *, replicas: int, spec: bool, copies: int = 1):
     """Deploy one LLMPool, answer the requests (``copies`` of each at
     once, so that every replica of a wide pool gets some), check what
@@ -337,6 +388,9 @@ def run_pool(plan: Plan, *, replicas: int, spec: bool, copies: int = 1):
                   spec={k: r.get("spec") for k, r in reps.items()})
         facts = {
             "replicas": replicas, "spec": spec, "device": devices[0],
+            **({"capture": capture_check(
+                plan, serve.get_handle("llm"), addr)}
+               if replicas == 1 else {}),
             "chips": sorted(r["device"]["nodes"] for r in reps.values()),
             "start_s": round(start_s, 1), "first_request_s": first_s,
             "compile": {k: r["device"]["compile"] for k, r in reps.items()},

@@ -45,26 +45,13 @@ _metrics = None
 
 
 def _get_metrics():
-    """Lazy Prometheus-style gauges (collective/ring.py idiom): one
+    """Lazy Prometheus-style metrics (collective/ring.py idiom): one
     family per engine signal, tagged by engine name."""
     global _metrics
     if _metrics is None:
         from ray_tpu.util import metrics as M
 
         _metrics = {
-            "active": M.Gauge(
-                "decode_engine_active_slots",
-                "decode slots currently occupied", tag_keys=("engine",)),
-            "queued": M.Gauge(
-                "decode_engine_queue_depth",
-                "streams waiting for a free slot", tag_keys=("engine",)),
-            "tps": M.Gauge(
-                "decode_engine_tokens_per_sec",
-                "tokens/s over the recent window", tag_keys=("engine",)),
-            "hit_rate": M.Gauge(
-                "decode_prefix_cache_hit_rate",
-                "prefix-cache hit rate since start",
-                tag_keys=("engine",)),
             "tbt": M.Histogram(
                 "serve_tbt_seconds",
                 "per-token time-between-tokens (chunk gap / chunk "
@@ -171,11 +158,12 @@ def _layers_ragged(cfg: LlamaConfig, layers, attach, h, sin, cos, k, v,
     ``experts_touched`` [L] (see ``_experts_touched``). Returns
     (h, k, v, *touched)."""
     routed = active is not None and llama.reports_routing(cfg)
-    lengths = pos + h.shape[1]
-    if active is not None:
-        lengths = jnp.where(active, lengths, 0)
     # the same for every layer of the step: made here, not in the body
-    plan = _da.visits(lengths, k.shape[2])
+    with jax.named_scope("attn"):
+        lengths = pos + h.shape[1]
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
+        plan = _da.visits(lengths, k.shape[2])
 
     def body(carry, p_):
         h_, k_, v_, layer = carry
@@ -190,6 +178,7 @@ def _layers_ragged(cfg: LlamaConfig, layers, attach, h, sin, cos, k, v,
     return h, k, v, *touched
 
 
+@jax.named_scope("moe_router")
 def _experts_touched(cfg: LlamaConfig, aux: dict | None, active) -> tuple:
     """What a layer adds to its scan's outputs for the routing counters:
     ``()`` for a model that reports no routing (its program is the one
@@ -202,6 +191,7 @@ def _experts_touched(cfg: LlamaConfig, aux: dict | None, active) -> tuple:
     return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),)
 
 
+@jax.named_scope("sample")
 def _sample_from_logits(logits, seeds, pos, temps, top_ps):
     """Per-slot stateless sampling lane: the RNG key for the token
     emitted from position `pos` of a stream is
@@ -274,14 +264,18 @@ def _step_logits(cfg: LlamaConfig, params, layers, attach, w_out, toks, k,
     is applied between the layers and the final norm (the draft's
     adapter head). Returns (float32 logits [B, T, V], k, v, *touched):
     see :func:`_layers_ragged` for the stacks and ``active``."""
-    sin, cos = llama.rotary_embedding(qpos, cfg.head_dim, cfg.rope_theta)
-    h = params["embed"].astype(cfg.compute_dtype)[toks]  # [B, T, D]
+    with jax.named_scope("qkv"):
+        sin, cos = llama.rotary_embedding(qpos, cfg.head_dim,
+                                          cfg.rope_theta)
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cfg.compute_dtype)[toks]  # [B, T, D]
     h, k, v, *touched = _layers_ragged(
         cfg, layers, attach, h, sin, cos, k, v, pos, active)
-    if before_norm is not None:
-        h = before_norm(h)
-    h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return (h @ w_out).astype(jnp.float32), k, v, *touched
+    with jax.named_scope("lm_head"):
+        if before_norm is not None:
+            h = before_norm(h)
+        h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
+        return (h @ w_out).astype(jnp.float32), k, v, *touched
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "chunk"),
@@ -317,12 +311,14 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
         t, state, pos = carry
         logits, state, *touched = model.step(
             cfg, params, prepared, t, state, pos, active)
-        if lanes is None:
-            nxt, lp = jnp.argmax(logits, axis=-1).astype(t.dtype), None
-        else:
-            seeds, temps, top_ps = lanes
-            nxt, lp = _sample_from_logits(logits, seeds, pos, temps, top_ps)
-        nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
+        with jax.named_scope("sample"):
+            if lanes is None:
+                nxt, lp = jnp.argmax(logits, axis=-1).astype(t.dtype), None
+            else:
+                seeds, temps, top_ps = lanes
+                nxt, lp = _sample_from_logits(logits, seeds, pos, temps,
+                                              top_ps)
+            nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
         # clamp: a slot that exhausts its cache rows mid-chunk (pump()
         # only frees slots at chunk boundaries) must keep scattering
         # in-range — unclamped, jit's clamping scatter would write row
@@ -427,10 +423,11 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         lp = lp.reshape(b, t_wide)
 
         # -- accept until first mismatch; rollback = pos truncation --
-        match = (drafts == y[:, :depth]).astype(jnp.int32)
-        m = jnp.cumprod(match, axis=1).sum(axis=1) + 1  # [B] in 1..K+1
-        m = jnp.where(active, m, 0)
-        t = jnp.where(active, y[rows, jnp.maximum(m - 1, 0)], t)
+        with jax.named_scope("sample"):
+            match = (drafts == y[:, :depth]).astype(jnp.int32)
+            m = jnp.cumprod(match, axis=1).sum(axis=1) + 1  # [B], 1..K+1
+            m = jnp.where(active, m, 0)
+            t = jnp.where(active, y[rows, jnp.maximum(m - 1, 0)], t)
         pos = jnp.minimum(pos + m, max_len - 1)
         return (t, k, v, pos), (y, lp, m, *touched)
 
@@ -517,11 +514,13 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
     streams, full_lens, toks0, logp0, *expert_tokens = model.prefill(
         params, prompts, true_lens, seeds, temps, top_ps, cfg,
         model.max_len(cache), prefix)
-    cache = model.scatter(cache, slots, streams, full_lens)
-    return (cache, cur_tok.at[slots].set(toks0), toks0, logp0,
-            *expert_tokens)
+    with jax.named_scope("cache"):
+        cache = model.scatter(cache, slots, streams, full_lens)
+        return (cache, cur_tok.at[slots].set(toks0), toks0, logp0,
+                *expert_tokens)
 
 
+@jax.named_scope("moe_router")
 def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
     """``()`` for a model that reports no routing, else ([L, E] int32,):
     the assignments each expert got in each layer from the REAL
@@ -625,7 +624,8 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
                               (0, slot_len - rows.shape[2]), (0, 0)))
         return rows.reshape(*rows.shape[:3], cfg.n_kv_heads, cfg.head_dim)
 
-    return payload(k), payload(v), toks0, logp0
+    with jax.named_scope("cache"):
+        return payload(k), payload(v), toks0, logp0
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
@@ -635,12 +635,13 @@ def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
     """Scatter externally-prefilled KV rows ([L, S, Hkv, D] as
     ``prefill_kv`` hands them out, S == the slot cache length) into
     `slot` and seed its current token."""
-    cache = {
-        "k": cache["k"].at[:, slot].set(_kv_rows(k_rows)),
-        "v": cache["v"].at[:, slot].set(_kv_rows(v_rows)),
-        "pos": cache["pos"].at[slot].set(true_len),
-    }
-    return cache, cur_tok.at[slot].set(tok0)
+    with jax.named_scope("cache"):
+        cache = {
+            "k": cache["k"].at[:, slot].set(_kv_rows(k_rows)),
+            "v": cache["v"].at[:, slot].set(_kv_rows(v_rows)),
+            "pos": cache["pos"].at[slot].set(true_len),
+        }
+        return cache, cur_tok.at[slot].set(tok0)
 
 
 def _nbytes(tree) -> int:
@@ -835,7 +836,6 @@ class RaggedDecoder:
         self._total_tokens = 0
         # (stamp, n_tokens) per pump for the tokens/s scaling signal
         self._rate_window: collections.deque = collections.deque()
-        self._metrics_t = 0.0
         self.mark_state()
 
     def mark_state(self) -> None:
@@ -851,6 +851,59 @@ class RaggedDecoder:
             **{f"{kind}_bytes": n for kind, n in self.state_bytes.items()},
             **{f"{kind}_layers": n
                for kind, (n, _) in self.row_kinds.items()}})
+
+    def program_parts(self) -> dict:
+        """{program, as a trace's ``XLA Modules`` line names it: [{"what":
+        the call's shapes in words, "parts": {instruction: part}}, one
+        entry a signature]}: ``program_parts.parts_of`` the compiled
+        text of every program THIS engine has run, at the shapes it ran
+        them: the chunk (greedy, and with lanes once a sampled request
+        came; the speculative one where speculation is on), the prefill
+        call at each of its buckets (cold, and behind a cached prefix
+        where there is a prefix cache). A signature that has not run is
+        left out, never compiled (``program_parts.compiled_text``). For
+        a capture (``LLMServer.stop_trace``); any thread may call it:
+        nothing of the engine is written, and of its arrays only shapes
+        are read."""
+        from ray_tpu.models import program_parts as _pp
+
+        params, cache, tok = self.params, self.cache, self.cur_tok
+        if params is None:  # (between the two halves of a weight publish)
+            return {}
+        mask = np.zeros((self.slots,), bool)
+        lanes = tuple(jax.ShapeDtypeStruct((self.slots,), dt)
+                      for dt in (jnp.uint32, jnp.float32, jnp.float32))
+        calls = [("greedy", decode_chunk, (
+            params, cache, tok, mask, None, self.cfg, self.chunk))]
+        if self._sampling_seen:
+            calls.append(("sampled", decode_chunk, (
+                params, cache, tok, mask, lanes, self.cfg, self.chunk)))
+        if self.spec_depth:
+            calls.append((f"depth {self.spec_depth}", decode_chunk_spec, (
+                params, self.spec_draft_head, cache, tok, mask, *lanes,
+                self.cfg, self.chunk, self.spec_depth,
+                self.spec_draft_layers)))
+        one = lambda dt: np.zeros((1,), dt)  # noqa: E731
+        prefixes = [("cold", None)]
+        if self.prefix_cache is not None:
+            rows = jax.ShapeDtypeStruct(
+                (self.cfg.n_layers, 1, self.max_len, self.cfg.n_kv_heads,
+                 self.cfg.head_dim), self.cfg.compute_dtype)
+            prefixes.append(("warm", (rows, rows, np.int32(0))))
+        for width in self.buckets:
+            for kind, prefix in prefixes:
+                calls.append((
+                    f"{kind}, bucket {width}", _prefill_batch_into_slots,
+                    (params, np.zeros((1, width), np.int32), one(np.int32),
+                     one(np.int32), one(np.uint32), one(np.float32),
+                     one(np.float32), cache, tok, self.cfg, prefix)))
+        out: dict = {}
+        for what, jitted, args in calls:
+            text = _pp.compiled_text(jitted, *args)
+            if text is not None:
+                out.setdefault(_pp.program_name(text), []).append(
+                    {"what": what, "parts": _pp.parts_of(text)})
+        return out
 
     # -- submission boundary --
 
@@ -1396,7 +1449,6 @@ class RaggedDecoder:
             self.prefix_cache.clear()
 
     RATE_WINDOW_S = 5.0
-    METRICS_PERIOD_S = 1.0
 
     def _tbt_obs(self, v: float, tenant: str = "-") -> None:
         try:
@@ -1411,9 +1463,6 @@ class RaggedDecoder:
         w.append((t_now, delivered))
         while w and t_now - w[0][0] > self.RATE_WINDOW_S:
             w.popleft()
-        if t_now - self._metrics_t >= self.METRICS_PERIOD_S:
-            self._metrics_t = t_now
-            self._export_metrics(self.stats())
 
     def tokens_per_sec(self) -> float:
         w = self._rate_window
@@ -1424,10 +1473,8 @@ class RaggedDecoder:
 
     def stats(self) -> dict:
         """Scaling signals for the serving pool (serve/llm_pool.py):
-        occupied slots, queue depth, and recent tokens/s — also
-        exported as Prometheus gauges (util/metrics.py) alongside the
-        collective OpStats family — and monotonic totals an outside
-        reader takes deltas of (``total_tokens``, ``pumps``,
+        occupied slots, queue depth, and recent tokens/s, and monotonic
+        totals an outside reader takes deltas of (``total_tokens``, ``pumps``,
         ``prefill_calls``: cold prefills, one prompt each;
         ``weights_bytes``: what the serving tree holds on the device,
         ``state_bytes``: what the slots' state holds there, by kind;
@@ -1474,19 +1521,6 @@ class RaggedDecoder:
                     for k, v in sorted(self._spec_hist.items())},
             }
         return out
-
-    def _export_metrics(self, st: dict) -> None:
-        try:
-            m = _get_metrics()
-            tags = {"engine": self.name}
-            m["active"].set(st["active"], tags)
-            m["queued"].set(st["queued"], tags)
-            m["tps"].set(st["tokens_per_sec"], tags)
-            pc = st.get("prefix_cache")
-            if pc is not None:
-                m["hit_rate"].set(pc["hit_rate"], tags)
-        except Exception:  # noqa: BLE001 — telemetry never breaks decode
-            pass
 
     def drain(self, deadline_s: float = 600.0) -> None:
         t0 = time.monotonic()

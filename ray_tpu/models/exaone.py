@@ -314,20 +314,33 @@ def ring_rows(rows, true_lens, window: int):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
-def _mlp(cfg: ExaoneConfig, i: int, p, x, aux: dict | None = None):
-    with jax.named_scope("mlp"):
-        if cfg.mlp_layer_types[i] == DENSE:
-            return swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
-        return moe(cfg, p, x, aux)
+def _mlp(cfg: ExaoneConfig, i: int, p, h, aux: dict | None = None):
+    """Layer ``i``'s MLP with its norm, added to ``h`` [B, T, D]: the
+    dense SwiGLU (scope ``mlp``) or the expert layer (``moe_router``,
+    the norm with it, ``moe_experts``, ``moe_shared``, the residual
+    with it)."""
+    if cfg.mlp_layer_types[i] == DENSE:
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+            return h + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                              p["mlp"]["w_down"])
+    with jax.named_scope("moe_router"):
+        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+    y = moe(cfg, p["mlp"], x, aux)
+    with jax.named_scope("moe_shared"):
+        return h + y
 
 
+@jax.named_scope("lm_head")
 def _logits(cfg: ExaoneConfig, params, h):
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
 
 
 def _attn_scope(cfg: ExaoneConfig, i: int):
-    return jax.named_scope("attn_window" if cfg.windowed(i) else "attn_full")
+    """Attention proper of layer ``i``: ``attn``, its kind beneath."""
+    return jax.named_scope(
+        "attn/attn_window" if cfg.windowed(i) else "attn/attn_full")
 
 
 def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
@@ -337,24 +350,27 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
     rotates). With ``aux`` every expert layer's ids are left in
     ``aux["expert_ids"]`` [L_moe, B, T, top_k]."""
     b, t = tokens.shape
-    h = params["embed"][tokens]
-    rotation = rotary_embedding(
-        jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t)),
-        cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
+    with jax.named_scope("qkv"):
+        rotation = rotary_embedding(
+            jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t)),
+            cfg.head_dim, cfg.rope_theta)
     rows, ids = [], []
     for i, p in enumerate(params["layers"]):
-        with _attn_scope(cfg, i):
-            windowed = cfg.windowed(i)
+        windowed = cfg.windowed(i)
+        with jax.named_scope("qkv"):
             q, k, v = _qkv(cfg, p["attn"],
                            rms_norm(h, p["attn_norm"], cfg.rms_eps),
                            rotation if windowed else None)
+        with _attn_scope(cfg, i):
             o = _attend_prompt(cfg, q, k, v,
                                cfg.sliding_window if windowed else 0)
+        with jax.named_scope("attn_out"):
             h = h + o.reshape(b, t, -1) @ p["attn"]["wo"]
         rows.append((k.reshape(b, t, -1), v.reshape(b, t, -1)))
         layer_aux = {} if aux is not None else None
-        h = h + _mlp(cfg, i, p["mlp"],
-                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), layer_aux)
+        h = _mlp(cfg, i, p, h, layer_aux)
         if layer_aux:
             ids.append(layer_aux["expert_ids"])
     if ids:
@@ -399,8 +415,11 @@ def step(cfg: ExaoneConfig, params, tok, state, pos, active):
     b = tok.shape[0]
     w = cfg.sliding_window
     slots = jnp.arange(b)
-    h = params["embed"][tok][:, None]  # [B, 1, D]
-    rotation = rotary_embedding(pos[:, None], cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        h = params["embed"][tok][:, None]  # [B, 1, D]
+    with jax.named_scope("qkv"):
+        rotation = rotary_embedding(pos[:, None], cfg.head_dim,
+                                    cfg.rope_theta)
     # by kind of layer (sliding or not): the stacks' names, the row a
     # slot writes, and the rows it holds once written with the kernel's
     # visits for them
@@ -409,31 +428,32 @@ def step(cfg: ExaoneConfig, params, tok, state, pos, active):
         return names, row, lengths, _da.visits(
             lengths, state[names[0]].shape[2])
 
-    by_kind = {True: kind(("k_win", "v_win"), pos % w,
-                          jnp.minimum(pos + 1, w)),
-               False: kind(("k_full", "v_full"), pos, pos + 1)}
+    with jax.named_scope("attn"):
+        by_kind = {True: kind(("k_win", "v_win"), pos % w,
+                              jnp.minimum(pos + 1, w)),
+                   False: kind(("k_full", "v_full"), pos, pos + 1)}
     state = dict(state)
     counts = []
     for i, p in enumerate(params["layers"]):
         windowed = cfg.windowed(i)
         (kn, vn), row, lengths, plan = by_kind[windowed]
         layer = cfg.stack_index(i)
-        with _attn_scope(cfg, i):
+        with jax.named_scope("qkv"):
             q, k, v = _qkv(cfg, p["attn"],
                            rms_norm(h, p["attn_norm"], cfg.rms_eps),
                            rotation if windowed else None)
-            with jax.named_scope("cache"):
-                state[kn] = state[kn].at[layer, slots, row].set(
-                    k.reshape(b, -1))
-                state[vn] = state[vn].at[layer, slots, row].set(
-                    v.reshape(b, -1))
-            with jax.named_scope("attn"):
-                o = _da.decode_attention(q, state[kn], state[vn], layer,
-                                         lengths, plan=plan)
+        with jax.named_scope("cache"):
+            state[kn] = state[kn].at[layer, slots, row].set(
+                k.reshape(b, -1))
+            state[vn] = state[vn].at[layer, slots, row].set(
+                v.reshape(b, -1))
+        with _attn_scope(cfg, i):
+            o = _da.decode_attention(q, state[kn], state[vn], layer,
+                                     lengths, plan=plan)
+        with jax.named_scope("attn_out"):
             h = h + o.reshape(b, 1, -1) @ p["attn"]["wo"]
         aux = {} if cfg.mlp_layer_types[i] == SPARSE else None
-        h = h + _mlp(cfg, i, p["mlp"],
-                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), aux)
+        h = _mlp(cfg, i, p, h, aux)
         if aux:
             counts.append(routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
@@ -510,7 +530,9 @@ class _Slots:
         aux = {} if cfg.moe_layers else None
         h, rows = prefill(params, prompts, cfg, aux)
         f = prompts.shape[0]
-        last = _logits(cfg, params, h[jnp.arange(f), true_lens - 1][:, None])
+        with jax.named_scope("lm_head"):  # (the last real row alone)
+            last = _logits(cfg, params,
+                           h[jnp.arange(f), true_lens - 1][:, None])
         toks0, logp0 = _sample_from_logits(
             last[:, 0], seeds, true_lens - 1, temps, top_ps)
         def stack(parts, rows_each):  # (no layer of a kind: no rows)
@@ -519,13 +541,14 @@ class _Slots:
 
         w = cfg.sliding_window
         streams = {}
-        for j, name in enumerate(("k", "v")):
-            streams[name + "_full"] = stack(
-                [r[j] for i, r in enumerate(rows) if not cfg.windowed(i)],
-                prompts.shape[1])
-            streams[name + "_win"] = stack(
-                [ring_rows(r[j], true_lens, w)
-                 for i, r in enumerate(rows) if cfg.windowed(i)], w)
+        with jax.named_scope("cache"):
+            for j, name in enumerate(("k", "v")):
+                streams[name + "_full"] = stack(
+                    [r[j] for i, r in enumerate(rows)
+                     if not cfg.windowed(i)], prompts.shape[1])
+                streams[name + "_win"] = stack(
+                    [ring_rows(r[j], true_lens, w)
+                     for i, r in enumerate(rows) if cfg.windowed(i)], w)
         loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \
             if aux else ()
         return streams, true_lens, toks0, logp0, *loads

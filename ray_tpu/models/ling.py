@@ -256,6 +256,7 @@ def serving_params(cfg: LingConfig, params):
 # KDA
 # --------------------------------------------------------------------------
 
+@jax.named_scope("qkv")
 def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
     """What both forms of KDA start from. x: [B, T, D] (normed);
     ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
@@ -284,6 +285,7 @@ def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
     return q, k, v, g, beta, gate, u
 
 
+@jax.named_scope("attn_out")
 def _kda_out(cfg: LingConfig, p, o, gate):
     """o, gate [B, T, H, dk] float32 -> [B, T, D]: the head-wise RMS
     norm, the sigmoid gate and the output projection."""
@@ -307,11 +309,13 @@ def kda_step(cfg: LingConfig, p, x, state, active):
     {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
     is not ``active`` keeps its state. -> ([B, 1, D], state)."""
     q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, state["conv"])
-    s, o = kda_recurrence(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                          beta[:, 0])
-    keep = active[:, None, None]
-    new = {"s": jnp.where(keep[..., None], s, state["s"]),
-           "conv": jnp.where(keep, u[:, 1:], state["conv"])}
+    with jax.named_scope("attn/attn_linear"):
+        s, o = kda_recurrence(state["s"], q[:, 0], k[:, 0], v[:, 0],
+                              g[:, 0], beta[:, 0])
+    with jax.named_scope("cache"):
+        keep = active[:, None, None]
+        new = {"s": jnp.where(keep[..., None], s, state["s"]),
+               "conv": jnp.where(keep, u[:, 1:], state["conv"])}
     return _kda_out(cfg, p, o[:, None], gate), new
 
 
@@ -397,20 +401,22 @@ def kda_prefill(cfg: LingConfig, p, x, true_lens):
     kw = cfg.conv_kernel - 1
     zeros = jnp.zeros((b, kw, 3 * h * dk), cfg.compute_dtype)
     q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, zeros)
-    real = jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
-    g = jnp.where(real[..., None, None], g, 0.0)
-    beta = jnp.where(real[..., None], beta, 0.0)
-    pad = -t % cfg.kda_chunk
-    if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
-        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
-                                    + ((0, 0),) * (a.ndim - 2))
-                            for a in (q, k, v, g, beta))
-    o, s = kda_chunked(cfg, q, k, v, g, beta,
-                       jnp.zeros((b, h, dk, dk), jnp.float32))
-    # the last K-1 projection rows of each prompt: u's rows
-    # true_len .. true_len + K-2 (u starts K-1 rows before the prompt)
-    rows = true_lens[:, None] + jnp.arange(kw)[None, :]
-    conv = jnp.take_along_axis(u, rows[..., None], axis=1)
+    with jax.named_scope("attn/attn_linear"):
+        real = jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        pad = -t % cfg.kda_chunk
+        if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
+            q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
+                                        + ((0, 0),) * (a.ndim - 2))
+                                for a in (q, k, v, g, beta))
+        o, s = kda_chunked(cfg, q, k, v, g, beta,
+                           jnp.zeros((b, h, dk, dk), jnp.float32))
+    with jax.named_scope("cache"):
+        # the last K-1 projection rows of each prompt: u's rows
+        # true_len .. true_len + K-2 (u starts K-1 rows before the prompt)
+        rows = true_lens[:, None] + jnp.arange(kw)[None, :]
+        conv = jnp.take_along_axis(u, rows[..., None], axis=1)
     return _kda_out(cfg, p, o[:, :t], gate), {"s": s, "conv": conv}
 
 
@@ -418,6 +424,7 @@ def kda_prefill(cfg: LingConfig, p, x, true_lens):
 # MLA
 # --------------------------------------------------------------------------
 
+@jax.named_scope("qkv")
 def _mla_inputs(cfg: LingConfig, p, x, positions):
     """x [B, T, D] (normed) at ``positions`` [B, T] -> (q_nope [B, T, H,
     dn], q_rope [B, T, H, dr] rotated, the cache rows {"latent" [B, T,
@@ -439,6 +446,7 @@ def _mla_inputs(cfg: LingConfig, p, x, positions):
         gate
 
 
+@jax.named_scope("attn_out")
 def _mla_out(cfg: LingConfig, p, o, gate):
     b, t = o.shape[:2]
     o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.compute_dtype)
@@ -454,17 +462,19 @@ def mla_prefill(cfg: LingConfig, p, x):
     h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     q_nope, q_rope, rows, gate = _mla_inputs(cfg, p, x, positions)
-    kv = (rows["latent"] @ p["w_kvb"]).reshape(b, t, h, dn + dv)
+    with jax.named_scope("qkv"):  # (k and v out of the latent)
+        kv = (rows["latent"] @ p["w_kvb"]).reshape(b, t, h, dn + dv)
     f32 = jnp.float32
-    logits = (jnp.einsum("bthd,bshd->bhts", q_nope, kv[..., :dn],
-                         preferred_element_type=f32)
-              + jnp.einsum("bthd,bsd->bhts", q_rope, rows["k_rope"],
-                           preferred_element_type=f32))
-    logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    probs = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
-    o = jnp.einsum("bhts,bshd->bthd", probs.astype(x.dtype), kv[..., dn:],
-                   preferred_element_type=f32)
+    with jax.named_scope("attn/attn_latent"):
+        logits = (jnp.einsum("bthd,bshd->bhts", q_nope, kv[..., :dn],
+                             preferred_element_type=f32)
+                  + jnp.einsum("bthd,bsd->bhts", q_rope, rows["k_rope"],
+                               preferred_element_type=f32))
+        logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        probs = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
+        o = jnp.einsum("bhts,bshd->bthd", probs.astype(x.dtype),
+                       kv[..., dn:], preferred_element_type=f32)
     return _mla_out(cfg, p, o, gate), rows
 
 
@@ -486,19 +496,22 @@ def mla_step(cfg: LingConfig, p, x, cache, pos):
         cache = {k: cache[k].at[jnp.arange(b), pos].set(rows[k][:, 0])
                  for k in ("latent", "k_rope")}
     w_kvb = p["w_kvb"].reshape(r, h, dn + dv)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :dn],
-                       preferred_element_type=f32).astype(x.dtype)
-    logits = (jnp.einsum("bhr,bsr->bhs", q_lat, cache["latent"],
-                         preferred_element_type=f32)
-              + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0], cache["k_rope"],
-                           preferred_element_type=f32))
-    logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
-    live = jnp.arange(cache["latent"].shape[1])[None, :] <= pos[:, None]
-    probs = jax.nn.softmax(jnp.where(live[:, None], logits, -1e30), -1)
-    o_lat = jnp.einsum("bhs,bsr->bhr", probs.astype(x.dtype),
-                       cache["latent"], preferred_element_type=f32)
-    o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype), w_kvb[..., dn:],
-                   preferred_element_type=f32)
+    with jax.named_scope("qkv"):  # (q into the latent's space)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :dn],
+                           preferred_element_type=f32).astype(x.dtype)
+    with jax.named_scope("attn/attn_latent"):
+        logits = (jnp.einsum("bhr,bsr->bhs", q_lat, cache["latent"],
+                             preferred_element_type=f32)
+                  + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0],
+                               cache["k_rope"], preferred_element_type=f32))
+        logits = logits * (dn + cfg.qk_rope_head_dim) ** -0.5
+        live = jnp.arange(cache["latent"].shape[1])[None, :] <= pos[:, None]
+        probs = jax.nn.softmax(jnp.where(live[:, None], logits, -1e30), -1)
+        o_lat = jnp.einsum("bhs,bsr->bhr", probs.astype(x.dtype),
+                           cache["latent"], preferred_element_type=f32)
+    with jax.named_scope("attn_out"):  # (and back out of it)
+        o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(x.dtype),
+                       w_kvb[..., dn:], preferred_element_type=f32)
     return _mla_out(cfg, p, o[:, None], gate), cache
 
 
@@ -506,17 +519,28 @@ def mla_step(cfg: LingConfig, p, x, cache, pos):
 # MLPs
 # --------------------------------------------------------------------------
 
-def _mlp(cfg: LingConfig, i: int, p, x, aux: dict | None = None):
-    with jax.named_scope("mlp"):
-        if cfg.mlp_kind(i) == "dense":
-            return _swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
-        return moe(cfg, p, x, aux)
+def _mlp(cfg: LingConfig, i: int, p, h, aux: dict | None = None):
+    """Layer ``i``'s MLP with its norm, added to ``h`` [B, T, D]: the
+    dense SwiGLU (scope ``mlp``) or the expert layer (``moe_router``,
+    the norm with it, ``moe_experts``, ``moe_shared``, the residual
+    with it)."""
+    if cfg.mlp_kind(i) == "dense":
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+            return h + _swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                               p["mlp"]["w_down"])
+    with jax.named_scope("moe_router"):
+        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+    y = moe(cfg, p["mlp"], x, aux)
+    with jax.named_scope("moe_shared"):
+        return h + y
 
 
 # --------------------------------------------------------------------------
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
+@jax.named_scope("lm_head")
 def _logits(cfg: LingConfig, params, h):
     h = rms_norm(h, params["final_norm"], cfg.rms_eps)
     return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
@@ -530,20 +554,21 @@ def prefill(params, tokens, true_lens, cfg: LingConfig,
     the T cache rows, padding's among them). With
     ``aux`` every expert layer's ids are left in ``aux["expert_ids"]``
     [L_moe, B, T, top_k]."""
-    h = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
     state, ids = [], []
     for i, p in enumerate(params["layers"]):
-        with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
             x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-            if cfg.attn_kind(i) == "kda":
-                y, st = kda_prefill(cfg, p["attn"], x, true_lens)
-            else:
-                y, st = mla_prefill(cfg, p["attn"], x)
-        h = h + y
+        if cfg.attn_kind(i) == "kda":
+            y, st = kda_prefill(cfg, p["attn"], x, true_lens)
+        else:
+            y, st = mla_prefill(cfg, p["attn"], x)
+        with jax.named_scope("attn_out"):
+            h = h + y
         state.append(st)
         layer_aux = {} if aux is not None else None
-        h = h + _mlp(cfg, i, p["mlp"],
-                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), layer_aux)
+        h = _mlp(cfg, i, p, h, layer_aux)
         if layer_aux:
             ids.append(layer_aux["expert_ids"])
     if ids:
@@ -581,20 +606,21 @@ def step(cfg: LingConfig, params, tok, layers_state, pos, active):
     expert layers three [L_moe] int32 counters of the ACTIVE slots'
     routing: distinct held experts touched, assignments, assignments
     to held experts)."""
-    h = params["embed"][tok][:, None]  # [B, 1, D]
+    with jax.named_scope("embed"):
+        h = params["embed"][tok][:, None]  # [B, 1, D]
     new_state, counts = [], []
     for i, (p, st) in enumerate(zip(params["layers"], layers_state)):
-        with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
             x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-            if cfg.attn_kind(i) == "kda":
-                y, st = kda_step(cfg, p["attn"], x, st, active)
-            else:
-                y, st = mla_step(cfg, p["attn"], x, st, pos)
-        h = h + y
+        if cfg.attn_kind(i) == "kda":
+            y, st = kda_step(cfg, p["attn"], x, st, active)
+        else:
+            y, st = mla_step(cfg, p["attn"], x, st, pos)
+        with jax.named_scope("attn_out"):
+            h = h + y
         new_state.append(st)
         aux = {} if cfg.mlp_kind(i) == "moe" else None
-        h = h + _mlp(cfg, i, p["mlp"],
-                     rms_norm(h, p["mlp_norm"], cfg.rms_eps), aux)
+        h = _mlp(cfg, i, p, h, aux)
         if aux:
             counts.append(routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
@@ -680,7 +706,9 @@ class _Slots:
         aux = {} if cfg.moe_layers else None
         h, layers = prefill(params, prompts, true_lens, cfg, aux)
         f = prompts.shape[0]
-        last = _logits(cfg, params, h[jnp.arange(f), true_lens - 1][:, None])
+        with jax.named_scope("lm_head"):  # (the last real row alone)
+            last = _logits(cfg, params,
+                           h[jnp.arange(f), true_lens - 1][:, None])
         toks0, logp0 = _sample_from_logits(
             last[:, 0], seeds, true_lens - 1, temps, top_ps)
         loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \

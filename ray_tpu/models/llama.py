@@ -225,10 +225,13 @@ def _qkv(cfg: LlamaConfig, p, h, sin, cos):
     b, t, _ = h.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-    # named scopes (attn / mlp / embed / lm_head here, cache and
-    # optimizer at their sites) are metadata: a device trace's
-    # operations carry them, the compiled program does not change
-    with jax.named_scope("attn"):
+    # named scopes (``program_parts.VOCABULARY``: qkv / attn / attn_out /
+    # mlp / embed / lm_head here, cache, sample and optimizer at their
+    # sites) are metadata: the compiled program does not change, and its
+    # text keeps them in every instruction's ``op_name``. A TPU trace's
+    # operations do NOT carry them: a capture is read through the map
+    # ``program_parts.parts_of`` makes of that text
+    with jax.named_scope("qkv"):
         x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
         q = x @ p["wq"].astype(cdt)
         if cfg.qk_norm:  # over the whole projection, before the heads
@@ -460,10 +463,12 @@ def _moe_mlp(cfg: LlamaConfig, p, x, aux: dict | None = None):
     past a buffer; "dense" is the every-expert oracle at tiny sizes."""
     if cfg.moe_impl == "dropless":
         return _moe_mlp_dropless(cfg, p, x, aux)
-    if cfg.moe_impl == "dense":
-        return _moe_mlp_dense(cfg, p, x)
-    if cfg.moe_impl == "capacity":
-        return _moe_mlp_capacity(cfg, p, x)
+    # (the two training forms: their router is charged with them)
+    with jax.named_scope("moe_experts"):
+        if cfg.moe_impl == "dense":
+            return _moe_mlp_dense(cfg, p, x)
+        if cfg.moe_impl == "capacity":
+            return _moe_mlp_capacity(cfg, p, x)
     raise ValueError(
         f"unknown moe_impl {cfg.moe_impl!r}; expected 'dropless', "
         "'capacity' or 'dense'")
@@ -476,15 +481,19 @@ def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o, aux: dict | None = None):
     b, t, _ = h.shape
     hq, hd = cfg.n_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn_out"):
         h = h + shard_constraint(
             o.reshape(b, t, hq * hd) @ p["wo"].astype(cdt),
             ("batch", "seq", "embed"),
         )
+    if cfg.n_experts > 0:
+        with jax.named_scope("moe_router"):  # (the router's input)
+            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+        y = _moe_mlp(cfg, p, x, aux)
+        with jax.named_scope("moe_experts"):  # (the residual: their sum's)
+            return h + y
     with jax.named_scope("mlp"):
         x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-        if cfg.n_experts > 0:
-            return h + _moe_mlp(cfg, p, x, aux)
         from jax.ad_checkpoint import checkpoint_name
 
         # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
@@ -522,7 +531,8 @@ def forward(params, tokens, cfg: LlamaConfig, *, positions=None):
     cdt = cfg.compute_dtype
     if positions is None:
         positions = jnp.arange(t, dtype=jnp.int32)[None, :]
-    sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
 
     # Embedding lookup: gather from a fully-replicated view of the table.
     # With vocab/embed sharded at rest and seq sharded (sp), XLA's
@@ -634,7 +644,7 @@ def loss_fn(params, batch, cfg: LlamaConfig):
         inputs, targets = toks[:, :-1], toks[:, 1:]
         mask = None
     logits = forward(params, inputs, cfg)
-    with jax.named_scope("lm_head"):
+    with jax.named_scope("loss"):
         loss, n = softmax_cross_entropy(logits, targets, mask=mask)
     return loss, {"loss": loss, "tokens": n}
 
@@ -686,17 +696,19 @@ def _prefill_layer(cfg: LlamaConfig, h, p, sin, cos, given,
     heads end to end."""
     b, t, _ = h.shape
     q, k, v = _qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
-    with jax.named_scope("attn"):
-        if given is None:
+    if given is None:
+        with jax.named_scope("attn"):
             o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
-            k, v = k.reshape(b, t, -1), v.reshape(b, t, -1)
-        else:
-            gk, gv, pos = given
-            heads = (b, gk.shape[1], *k.shape[2:])
+        k, v = k.reshape(b, t, -1), v.reshape(b, t, -1)
+    else:
+        gk, gv, pos = given
+        heads = (b, gk.shape[1], *k.shape[2:])
+        with jax.named_scope("cache"):
             k = jax.lax.dynamic_update_slice(
                 gk, k.reshape(b, t, -1), (0, pos, 0))
             v = jax.lax.dynamic_update_slice(
                 gv, v.reshape(b, t, -1), (0, pos, 0))
+        with jax.named_scope("attn"):
             o = _attend_behind(q, k.reshape(heads), v.reshape(heads), pos)
     return _attn_out_and_mlp(cfg, p, h, o, aux), k, v
 
@@ -714,9 +726,10 @@ def prefill(params, tokens, last, cfg: LlamaConfig, given=None,
     cdt = cfg.compute_dtype
     pos = 0 if given is None else given[2]
     positions = pos + jnp.arange(t, dtype=jnp.int32)[None, :]
-    sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
-
-    h = params["embed"].astype(cdt)[tokens]
+    with jax.named_scope("qkv"):
+        sin, cos = rotary_embedding(positions, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cdt)[tokens]
 
     routed = aux is not None and reports_routing(cfg)
     layers, attach = split_layers(cfg, params["layers"])
@@ -733,8 +746,10 @@ def prefill(params, tokens, last, cfg: LlamaConfig, given=None,
         body, h, (layers,) if given is None else (layers, *given[:2]))
     if routed:
         aux["expert_ids"] = ids[0]
-    h = rms_norm(h[jnp.arange(b), last], params["final_norm"], cfg.rms_eps)
-    w_out = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cdt)
-    return (h @ w_out).astype(jnp.float32), k, v
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h[jnp.arange(b), last], params["final_norm"],
+                     cfg.rms_eps)
+        w_out = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(cdt)
+        return (h @ w_out).astype(jnp.float32), k, v

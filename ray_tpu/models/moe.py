@@ -128,6 +128,7 @@ def moe(cfg, p, x, aux: dict | None = None):
     return out.reshape(b, t, d)
 
 
+@jax.named_scope("moe_router")
 def routing_counts(cfg, ids, active) -> tuple:
     """ids [B, 1, top_k] of one expert layer's decode step -> (distinct
     held experts that got a row from an active slot, the active slots'
@@ -140,6 +141,7 @@ def routing_counts(cfg, ids, active) -> tuple:
             jnp.sum(hit, dtype=jnp.int32))
 
 
+@jax.named_scope("moe_router")
 def prefill_loads(cfg, ids, true_lens):
     """ids [L_moe, F, P, top_k] of a prefill -> [L_moe, count] int32: the
     assignments each HELD expert got in each layer from the REAL

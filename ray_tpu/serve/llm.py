@@ -169,6 +169,7 @@ class LLMServer:
         # pump thread adopts it at the next chunk boundary — engine
         # params are touched only by the pump owner
         self._pending_weights: tuple | None = None
+        self._trace_dir: str | None = None  # a capture's, while it runs
         self._lock = threading.Lock()
         self._done_events: dict[int, threading.Event] = {}
         # sids being consumed via poll_stream: the pump must NOT purge
@@ -505,13 +506,35 @@ class LLMServer:
         import jax
 
         jax.profiler.start_trace(log_dir)
+        self._trace_dir = log_dir
         self.engine.mark_state()  # the trace says what engine it is of
         return True
 
     def stop_trace(self) -> bool:
+        """End the capture, then write ``program_parts.json`` beside it:
+        which part of the model every operation of the engine's programs
+        came from (``models/program_parts.py``), the map a reader puts
+        the trace's ``XLA Ops`` events through. Made here, behind the
+        traced part, on the caller's thread, from the executables the
+        engine's calls already hold: nothing is compiled, and without a
+        capture nothing of it exists."""
+        import json
+        import os
+
         import jax
 
+        from ray_tpu.models import program_parts
+
         jax.profiler.stop_trace()
+        log_dir, self._trace_dir = self._trace_dir, None
+        t0 = time.monotonic()
+        programs = self.engine.program_parts()
+        with open(os.path.join(log_dir, program_parts.FILE), "w") as f:
+            json.dump({"engine": self.engine.name,
+                       "vocabulary": list(program_parts.VOCABULARY),
+                       # what the map cost, for whoever reads it
+                       "seconds": round(time.monotonic() - t0, 3),
+                       "programs": programs}, f)
         return True
 
     def stats(self) -> dict:

@@ -225,12 +225,6 @@ def _get_pool_metrics():
         from ray_tpu.util import metrics as M
 
         _pool_metrics = {
-            "replicas": M.Gauge(
-                "llm_pool_replicas", "live decode replicas"),
-            "queue": M.Gauge(
-                "llm_pool_queue_depth", "requests awaiting a replica"),
-            "ttft_p99": M.Gauge(
-                "llm_pool_ttft_p99_s", "TTFT p99 over the recent window"),
             "ttft_hist": M.Histogram(
                 "serve_ttft_seconds",
                 "client-observed time to first token "
@@ -1292,14 +1286,6 @@ class LLMPool:
             max_replicas=self.max_replicas,
             target_queue_per_replica=self.target_queue_per_replica,
             ttft_p99_s=ttft, target_ttft_s=self.target_ttft_s)
-        try:
-            m = _get_pool_metrics()
-            m["replicas"].set(n)
-            m["queue"].set(waiting)
-            if ttft is not None:
-                m["ttft_p99"].set(ttft)
-        except Exception:  # noqa: BLE001
-            pass
         if self._guardian is not None:
             # the brownout ladder rides the same cadence as scaling:
             # degradation buys time while new replicas spin up, and

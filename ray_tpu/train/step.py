@@ -158,11 +158,12 @@ def make_train_step(
 
     def step(state: TrainState, batch):
         if grads_dtype is not None:
-            p_low = jax.tree_util.tree_map(
-                lambda p: p.astype(grads_dtype)
-                if jnp.issubdtype(p.dtype, jnp.floating) else p,
-                state.params,
-            )
+            with jax.named_scope("optimizer"):  # (the masters' low view)
+                p_low = jax.tree_util.tree_map(
+                    lambda p: p.astype(grads_dtype)
+                    if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                    state.params,
+                )
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(p_low, batch)
         else:
@@ -173,7 +174,8 @@ def make_train_step(
                 grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
         if compute_grad_norm:
-            metrics = dict(metrics, grad_norm=optax.global_norm(grads))
+            with jax.named_scope("optimizer"):
+                metrics = dict(metrics, grad_norm=optax.global_norm(grads))
         return TrainState(state.step + 1, params, opt_state), metrics
 
     return jax.jit(

@@ -226,6 +226,11 @@ def test_serve_phase_rehearses_on_the_cpu(cluster, capsys):
     assert set(serve["spec_on_vs_off_agreeing_tokens"].values()) == {
         TINY.max_tokens}
     assert serve["spec_on"]["spec_acceptance"]
+    # each pool's capture left the map of the programs its engine ran
+    assert serve["spec_on"]["capture"]["programs"].keys() == {
+        "jit_decode_chunk_spec", "jit__prefill_batch_into_slots"}
+    assert set(serve["spec_off"]["capture"]["programs"][
+        "jit_decode_chunk"]) == {"greedy", "sampled"}
 
 
 @pytest.mark.slow

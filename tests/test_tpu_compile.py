@@ -681,6 +681,81 @@ def test_sharded_flash_refuses_what_it_cannot_split(topo):
             jax.eval_shape(lambda p, t: llama.forward(p, t, cfg), params, tok)
 
 
+# ---- every block's programs say which part each operation came from
+# (models/program_parts.py), at toy size ----
+
+_ALWAYS = {"embed", "qkv", "cache", "attn", "attn_out", "lm_head", "sample"}
+_MOE = {"moe_router", "moe_experts"}
+
+
+def _toy_block(block: str):
+    """-> (the block's toy configuration, the parts its serving programs
+    should have). The dispatches read the CPU backend here and take the
+    XLA bodies: the scopes are the same."""
+    if block in ("llama", "olmoe"):
+        cfg = llama.LlamaConfig(
+            d_model=128, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=256,
+            vocab_size=512, max_seq_len=64, remat=False,
+            **({"n_experts": 4, "top_k": 2, "moe_impl": "dropless"}
+               if block == "olmoe" else {}))
+        return cfg, _ALWAYS | ({"mlp"} if block == "llama" else _MOE)
+    if block == "ling":
+        from ray_tpu.models import ling
+
+        return ling.LingConfig.tiny(max_seq_len=64), \
+            _ALWAYS | _MOE | {"mlp", "moe_shared"}
+    from ray_tpu.models import exaone
+
+    return exaone.ExaoneConfig.tiny(max_seq_len=64), \
+        _ALWAYS | _MOE | {"mlp", "moe_shared"}
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "prefill"])
+@pytest.mark.parametrize("block", ["llama", "olmoe", "ling", "exaone"])
+def test_every_part_of_a_block_is_in_its_programs_map(topo, block, program):
+    """The map a capture is read through, from the text the TPU compiler
+    leaves: every part the block should have is there, the second level
+    under ``attn`` where the block has kinds of attention, and what the
+    map can put nowhere stays under a tenth of the instructions."""
+    from ray_tpu.models import program_parts as pp
+
+    cfg, wanted = _toy_block(block)
+    chip = SingleDeviceSharding(topo.devices[0])
+    model = de.slot_model(cfg)
+    key = jax.random.PRNGKey(0)
+    init = (lambda: llama.init_params(cfg, key)) \
+        if isinstance(cfg, llama.LlamaConfig) \
+        else (lambda: sys.modules[type(cfg).__module__].init_params(cfg, key))
+    params = _on(chip, jax.eval_shape(
+        lambda: model.serving_params(cfg, init())))
+    state = _on(chip, jax.eval_shape(
+        lambda: model.init_state(cfg, 4, 64)))
+    vec = lambda dt, n=4: jax.ShapeDtypeStruct(  # noqa: E731
+        (n,), dt, sharding=chip)
+    if program == "decode_chunk":
+        lowered = de.decode_chunk.lower(
+            params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+            chunk=4)
+    else:
+        lowered = _lower_prefill(cfg, chip, 16, (params, state, vec))
+    text = lowered.compile().as_text()
+    assert pp.program_name(text) == {
+        "decode_chunk": "jit_decode_chunk",
+        "prefill": "jit__prefill_batch_into_slots"}[program]
+    parts = pp.parts_of(text)
+    found = {p.removesuffix("+mixed") for p in parts.values()}
+    top = {p.split("/")[0] for p in found}
+    assert wanted <= top, sorted(wanted - top)
+    kinds = {"ling": {"attn/attn_linear", "attn/attn_latent"},
+             "exaone": {"attn/attn_window", "attn/attn_full"}}.get(
+        block, set())
+    assert kinds <= found, sorted(kinds - found)
+    if program == "decode_chunk":
+        assert "loop" in top  # the steps' own counters at the least
+    unscoped = [n for n, p in parts.items() if p.startswith("unscoped")]
+    assert len(unscoped) < 0.1 * len(parts), (len(parts), unscoped)
+
+
 # ---- the long programs: -m slow, run before a chip call ----
 
 
