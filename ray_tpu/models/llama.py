@@ -25,6 +25,7 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rotary, rotary_embedding
+from ray_tpu.parallel import tp_products as tpp
 from ray_tpu.parallel.pipeline import pipeline_apply, pipeline_stages
 from ray_tpu.parallel.sharding import shard_constraint
 
@@ -219,12 +220,45 @@ def param_logical_axes(cfg: LlamaConfig):
 # Forward
 # --------------------------------------------------------------------------
 
+def _row_ways(cfg: LlamaConfig, t: int) -> int:
+    """Over how many chips of the ambient mesh's tp group a layer keeps
+    its ``t`` rows apart (``parallel/tp_products.py``: the residual
+    stream sharded by sequence, the four products that meet the axis
+    split, their transfers behind the products); 1: the layer as GSPMD
+    partitions it (no mesh, tp = 1, a mesh with pipeline stages; a
+    mixture of experts, whose expert layer is not written that way; a
+    norm over the whole q / k projection, which needs every column)."""
+    whole = cfg.n_experts > 0 or cfg.qk_norm or pipeline_stages() > 1
+    n = 1 if whole else tpp.ways()
+    if t % n:
+        raise ValueError(
+            f"a sequence of {t} rows cannot be split over tp={n}: the "
+            "layer keeps each chip of a tp group its own rows (pad the "
+            "sequence to a multiple of tp)")
+    return n
+
+
 def _qkv(cfg: LlamaConfig, p, h, sin, cos):
     """Shared pre-norm QKV projection + rotary for both the training layer
     and the cached-decode layer."""
     b, t, _ = h.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hd = cfg.head_dim
     cdt = cfg.compute_dtype
+
+    def projections(h, norm, wq, wk, wv):
+        # (each chip of a tp group: its rows in, every row's products
+        # with its columns out, by heads)
+        rows = tpp.gather(rms_norm(h, norm, cfg.rms_eps))
+
+        def heads(w, scale=None):
+            y = tpp.in_order([x @ w.astype(cdt) for x in rows])
+            if scale is not None:  # over the whole projection
+                y = rms_norm(y, scale, cfg.rms_eps)
+            return y.reshape(b, t, -1, hd)
+
+        qk = (p["q_norm"], p["k_norm"]) if cfg.qk_norm else (None, None)
+        return heads(wq, qk[0]), heads(wk, qk[1]), heads(wv)
+
     # named scopes (``program_parts.VOCABULARY``: qkv / attn / attn_out /
     # mlp / embed / lm_head here, cache, sample and optimizer at their
     # sites) are metadata: the compiled program does not change, and its
@@ -232,16 +266,11 @@ def _qkv(cfg: LlamaConfig, p, h, sin, cos):
     # operations do NOT carry them: a capture is read through the map
     # ``program_parts.parts_of`` makes of that text
     with jax.named_scope("qkv"):
-        x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-        q = x @ p["wq"].astype(cdt)
-        if cfg.qk_norm:  # over the whole projection, before the heads
-            q = rms_norm(q, p["q_norm"], cfg.rms_eps)
-        q = q.reshape(b, t, hq, hd)
-        k = x @ p["wk"].astype(cdt)
-        if cfg.qk_norm:
-            k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-        k = k.reshape(b, t, hkv, hd)
-        v = (x @ p["wv"].astype(cdt)).reshape(b, t, hkv, hd)
+        q, k, v = tpp.over_tp(
+            projections, _row_ways(cfg, t),
+            in_specs=(tpp.ROWS, tpp.WHOLE) + (tpp.W_COLUMNS,) * 3,
+            out_specs=(tpp.COLUMNS,) * 3,
+        )(h, p["attn_norm"], p["wq"], p["wk"], p["wv"])
         return apply_rotary(q, sin, cos), apply_rotary(k, sin, cos), v
 
 
@@ -479,29 +508,48 @@ def _attn_out_and_mlp(cfg: LlamaConfig, p, h, o, aux: dict | None = None):
     ``aux``, where a caller passes one, receives what the MLP leaves for
     it (a dropless MoE: ``expert_ids``); a dense model leaves nothing."""
     b, t, _ = h.shape
-    hq, hd = cfg.n_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-    with jax.named_scope("attn_out"):
-        h = h + shard_constraint(
-            o.reshape(b, t, hq * hd) @ p["wo"].astype(cdt),
-            ("batch", "seq", "embed"),
-        )
+    o = o.reshape(b, t, cfg.n_heads * cfg.head_dim)
+
+    def attn_out(h, o, wo):
+        with jax.named_scope("attn_out"):
+            return h + shard_constraint(
+                tpp.scattered(tpp.pieces(o), wo.astype(cdt)),
+                ("batch", "seq", "embed"),
+            )
+
     if cfg.n_experts > 0:
+        h = attn_out(h, o, p["wo"])
         with jax.named_scope("moe_router"):  # (the router's input)
             x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
         y = _moe_mlp(cfg, p, x, aux)
         with jax.named_scope("moe_experts"):  # (the residual: their sum's)
             return h + y
-    with jax.named_scope("mlp"):
-        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+
+    def dense(h, o, wo, norm, w_gate, w_up, w_down):
+        # (each chip of a tp group: its rows of h and every row of o's
+        # columns in, its rows out)
         from jax.ad_checkpoint import checkpoint_name
 
-        # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
-        # activations so the backward skips the gate/up matmul recomputes
-        gate = checkpoint_name(x @ p["w_gate"].astype(cdt), "mlp_gate")
-        up = checkpoint_name(x @ p["w_up"].astype(cdt), "mlp_up")
-        y = (jax.nn.silu(gate) * up) @ p["w_down"].astype(cdt)
-        return h + shard_constraint(y, ("batch", "seq", "embed"))
+        h = attn_out(h, o, wo)
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, norm, cfg.rms_eps)
+            # policy-addressable: "dots_flash_qkv_mlp" saves the two widest
+            # activations so the backward skips the gate/up matmul recomputes
+            y = tpp.scattered(
+                [jax.nn.silu(checkpoint_name(x @ w_gate.astype(cdt),
+                                             "mlp_gate"))
+                 * checkpoint_name(x @ w_up.astype(cdt), "mlp_up")
+                 for x in tpp.gather(x)],
+                w_down.astype(cdt))
+            return h + shard_constraint(y, ("batch", "seq", "embed"))
+
+    return tpp.over_tp(
+        dense, _row_ways(cfg, t),
+        in_specs=(tpp.ROWS, tpp.COLUMNS, tpp.W_ROWS, tpp.WHOLE,
+                  tpp.W_COLUMNS, tpp.W_COLUMNS, tpp.W_ROWS),
+        out_specs=tpp.ROWS,
+    )(h, o, p["wo"], p["mlp_norm"], p["w_gate"], p["w_up"], p["w_down"])
 
 
 def _layer(cfg: LlamaConfig, h, layer_params, sin, cos):
@@ -545,7 +593,11 @@ def forward(params, tokens, cfg: LlamaConfig, *, positions=None):
             params["embed"].astype(cdt), (None, None)
         )
         h = w_embed[tokens]
-        h = shard_constraint(h, ("batch", "seq", "embed"))
+        # (a tp group's chips each take their rows here, once, and the
+        # final norm gathers them, once: ``_row_ways``)
+        row_ways = _row_ways(cfg, t)
+        h = shard_constraint(
+            h, ("batch", "seq" if row_ways == 1 else "rows", "embed"))
 
     layer_fn = lambda h_, p_: (_layer(cfg, h_, p_, sin, cos), None)
     if cfg.remat:
@@ -622,6 +674,8 @@ def forward(params, tokens, cfg: LlamaConfig, *, positions=None):
         h, _ = jax.lax.scan(layer_fn, h, params["layers"])
 
     with jax.named_scope("lm_head"):
+        if row_ways > 1:  # (a tp group's rows, gathered)
+            h = shard_constraint(h, ("batch", "seq", "embed"))
         h = rms_norm(h, params["final_norm"], cfg.rms_eps)
         w_out = (
             params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -24,6 +24,9 @@ LogicalRules = tuple[tuple[str, object], ...]
 DEFAULT_RULES: LogicalRules = (
     ("batch", ("dp", "fsdp")),
     ("seq", "sp"),
+    # the residual stream's rows where a layer splits its tp products
+    # (parallel/tp_products.py): each chip of a tp group keeps its own
+    ("rows", ("sp", "tp")),
     ("embed", "fsdp"),
     ("heads", "tp"),
     ("kv_heads", "tp"),

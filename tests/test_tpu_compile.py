@@ -657,6 +657,51 @@ def test_sharded_train_step_compiles_with_kernel(topo):
     assert "all-reduce" in text and "all-gather" in text
 
 
+def _in_flight(text: str, shape: str) -> dict:
+    """-> {collective-permute-start of ``shape``: the scheduled lines
+    between it and its ``-done``} (a compiled module's text is in
+    schedule order)."""
+    lines = text.splitlines()
+    out = {}
+    for i, line in enumerate(lines):
+        m = re.match(r"\s*(%[\w.\-]+) = \(" + re.escape(shape)
+                     + r".* collective-permute-start\(", line)
+        if m:
+            done = next(j for j in range(i + 1, len(lines))
+                        if f"collective-permute-done({m.group(1)})"
+                        in lines[j])
+            out[m.group(1)] = lines[i + 1:done]
+    return out
+
+
+def test_sharded_train_step_hides_its_tp_transfers(topo):
+    """2 layers at the four-chip cell's widths, batch and mesh
+    (``internlm2-1.8b.pretrain-4k-fsdp2tp2``): the residual stream's
+    all-reduces over the tp pair ([3, 4096, 2048], five a layer over
+    forward, recompute and backward, each synchronous) are gone from the
+    layer loops; in their place asynchronous transfers of half the rows,
+    products scheduled between their start and their done
+    (``parallel/tp_products.py``). What is left of that shape is the
+    head's input gradient, once a step. Says the mechanism engaged;
+    only the chip says how much of a transfer its product hides."""
+    text = _train_step(
+        topo, _train_cfg(seq=4096, n_layers=2, d_ff=8192, vocab_size=92544,
+                         rope_theta=1e6),
+        MeshConfig(fsdp=2, tp=2), batch=6, seq=4096).as_text()
+    assert KERNEL in text
+    whole = [ln for ln in text.splitlines()
+             if re.search(r"= bf16\[3,4096,2048\]\S* all-reduce\(", ln)]
+    assert all("lm_head" in ln for ln in whole) and len(whole) <= 1, whole
+    flights = _in_flight(text, "bf16[3,2048,2048]")
+    # a layer: 4 forward, 3 in the recompute (w_down's sum is not needed
+    # again), 4 backward
+    assert len(flights) == 11, list(flights)
+    covered = [name for name, between in flights.items()
+               if any("dot_general" in ln and " fusion(" in ln
+                      for ln in between)]
+    assert len(covered) >= 8, (covered, list(flights))
+
+
 def test_sharded_flash_refuses_what_it_cannot_split(topo):
     from ray_tpu.ops.attention import attention
 
