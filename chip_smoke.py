@@ -91,6 +91,9 @@ class Plan:
     # and a sliding-window layer's ring (models/exaone.py: 128 rows a
     # slot at the published widths) filled past two wraps
     ring_steps: int = 300
+    # and one gated MLA layer of models/instella.py at the published
+    # widths: prompts the flash kernel takes whole (one block)
+    latent_lens: tuple = (256, 1024)
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -98,7 +101,8 @@ class Plan:
                     chunk_tokens=4, prompt_buckets=(8, 32),
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
-                    hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20)
+                    hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
+                    latent_lens=(9, 21))
         return Plan(**{**base, **kw})
 
     @property
@@ -1158,6 +1162,61 @@ def ring_check(widths: str, steps: int, seed: int,
             "device": accelerator.device_report()}
 
 
+def latent_check(widths: str, lens: list, seed: int) -> dict:
+    """Runs in a child that holds the chip: one gated MLA layer of
+    ``models/instella.py`` (YaRN's rotary on interleaved pairs), its
+    unabsorbed prefill (``ops.attention``: the flash kernel on a TPU)
+    against its absorbed decode step over the slot's rows (on a TPU the
+    ``decode_attn_latent`` kernel, lengths ``pos + 1``), position by
+    position, in the compute type, for prompts of ``lens`` tokens.
+    -> relative errors of the outputs and of the rows by length."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import instella
+    from ray_tpu.ops import decode_attention as da
+
+    accelerator.claim_device()
+    kw = dict(n_layers=1, first_k_dense=1, vocab_size=1024)
+    cfg = instella.InstellaConfig.tiny(**kw, dtype="bfloat16") \
+        if widths == "tiny" else instella.InstellaConfig(**kw)
+    p = instella.init_params(cfg, jax.random.PRNGKey(seed))[
+        "layers"][0]["attn"]
+
+    @jax.jit
+    def both(x):
+        t = x.shape[1]
+        y, rows = instella.mla_prefill(
+            cfg, p, x, instella._rotation(cfg, jnp.arange(t)[None]))
+
+        def one(cache, xp):
+            x_t, pos = xp
+            lengths = (pos + 1).astype(jnp.int32)
+            plan = da.visits(lengths, t, da.block_rows(t, da.LATENT_BLOCK_ROWS))
+            y_t, cache = instella.mla_step(
+                cfg, p, x_t, instella._rotation(cfg, pos[:, None]), cache,
+                0, pos, lengths, plan)
+            return cache, y_t
+
+        cache, y_step = jax.lax.scan(
+            one, jnp.zeros((1, *rows.shape), rows.dtype),
+            (jnp.moveaxis(x, 1, 0)[:, :, None], jnp.arange(t)[:, None]))
+        return {"latent_out": (y, jnp.moveaxis(y_step[:, :, 0], 0, 1)),
+                "latent_rows": (rows, cache[0])}
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    errs = {}
+    for t in lens:
+        x = jax.random.normal(jax.random.PRNGKey(seed + t),
+                              (1, t, cfg.d_model), cfg.compute_dtype)
+        errs[str(t)] = {k: rel(a, b) for k, (a, b) in both(x).items()}
+    return {"rel_err": errs, "device": accelerator.device_report()}
+
+
 def hybrid_phase(plan: Plan) -> dict:
     out = chip_child(plan, "hybrid_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
@@ -1175,9 +1234,19 @@ def hybrid_phase(plan: Plan) -> dict:
     check(ring["wraps"] >= 2 and ring["rel_err"] <= HYBRID_TOLERANCE,
           "the decode_attn kernel and the XLA body part on a sliding "
           "layer's ring", got=ring, tolerance=HYBRID_TOLERANCE)
+    latent = chip_child(plan, "latent_check", {
+        "widths": plan.hybrid_widths, "lens": list(plan.latent_lens),
+        "seed": plan.seed})
+    check_device(plan, latent["device"], 1, "latent child")
+    check(max(v for by_len in latent["rel_err"].values()
+              for v in by_len.values()) <= HYBRID_TOLERANCE,
+          "a gated MLA layer's two forms part (unabsorbed through the "
+          "flash kernel / absorbed over the slot's rows)",
+          got=latent["rel_err"], tolerance=HYBRID_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
             "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
+            "latent": latent["rel_err"],
             "tolerance": HYBRID_TOLERANCE,
             "compile_s": out["device"]["compile"]["seconds"]}
 

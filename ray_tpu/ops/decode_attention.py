@@ -70,12 +70,12 @@ def _cdiv(a, b):
     return -(-a // b)
 
 
-def block_rows(s: int) -> int:
+def block_rows(s: int, most: int = _BLOCK_ROWS) -> int:
     """Rows a block for a cache of ``s`` rows: the fewest equal blocks
-    of at most ``_BLOCK_ROWS``, a multiple of 16 (1,296 -> 3 x 432, 512
+    of at most ``most``, a multiple of 16 (1,296 -> 3 x 432, 512
     -> 1 x 512), so that the last block ends with the cache wherever
     ``s`` allows it."""
-    n = _cdiv(s, _BLOCK_ROWS)
+    n = _cdiv(s, most)
     return _cdiv(_cdiv(s, n), 16) * 16
 
 
@@ -271,3 +271,167 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
     return _decode_attn(q, k, v, layer, lengths,
                         plan or visits(lengths, s, bs), bs=bs,
                         interpret=interpret)
+
+
+# --------------------------------------------------------------------------
+# Latent rows (MLA's absorbed step): a row is key AND value
+# --------------------------------------------------------------------------
+#
+# A slot's row of a latent layer is [the normalised latent (``dv`` wide)
+# ‖ the one rotated key all heads share ‖ zeros up to whole lanes]: every
+# query head contracts its ``[q into the latent's space ‖ q_rope ‖ 0]``
+# against the whole row, and the probabilities weigh the row's first
+# ``dv`` numbers. One "kv head" whose value is a lane-aligned view of its
+# key, so a block is fetched once and passes the matrix unit twice.
+#
+# The layout and the block's rows, read before they were fixed (PR 39).
+# Layout (compile for a described v5e): a rotated key of 32 kept apart
+# as ``[L, slots, rows, 32]`` bf16 is tiled to 128 lanes in HBM and takes
+# 256 B a row, the same as the 96 lanes of zeros that fill latent ‖ key
+# up to 640; one array is one DMA a block where two would be two. Rows a
+# block (TPU v5 lite, my chip runs, PR 39; one call over 32 slots of
+# 16,912 rows x 640 bf16 at the longdoc cell's lengths, prompts of 4,096 to 16,384
+# and some output, one inactive: 285,458 live rows = 365 MB as stored, 446 us at the
+# HBM's peak; mean of 600 calls in a loop): 512 -> 580 us, 1,024 ->
+# 526 (85%), 2,048 -> 551, 4,096 -> 614 (a slot's last block is read
+# whole: half a block a slot is waste). The XLA body over every row:
+# 4,088 us. With ``max_len`` doubled to 33,824 and the same lengths: the
+# kernel 528 us, the XLA body 8,087.
+
+LATENT_BLOCK_ROWS = 1024
+
+
+def attend_latent(q, rows, lengths, dv: int, scale: float):
+    """The XLA body: q [B, H, W] over ONE layer's rows [B, S, W], every
+    row of it, slot b seeing rows < ``lengths[b]``; the value of a row
+    is its first ``dv`` numbers. Products accumulate in float32, the
+    softmax is float32, the probabilities are cast to q's dtype.
+    -> [B, H, dv] in q's dtype (a slot of length 0: zeros)."""
+    logits = jnp.einsum("bhw,bsw->bhs", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
+        < lengths[:, None]
+    probs = jax.nn.softmax(jnp.where(live[:, None], logits, _NEG), axis=-1)
+    o = jnp.einsum("bhs,bsv->bhv", probs.astype(q.dtype), rows[..., :dv],
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    return jnp.where((lengths > 0)[:, None, None], o, 0)
+
+
+def _latent_kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, rows_ref,
+                   o_ref, m_scr, l_scr, acc_scr, *, s: int, bs: int,
+                   dv: int, scale: float):
+    step = pl.program_id(0)
+    slot, j = slot_ids[step], block_ids[step]
+    length = lengths[slot]
+    last = (jnp.minimum(length, s) + bs - 1) // bs - 1
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    heads = q_ref.shape[0]
+    k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (heads, bs), 1)
+    seen = k_pos < jnp.minimum(length, s)
+    logits = jax.lax.dot_general(
+        q_ref[...], rows_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale  # [heads, bs]
+    logits = jnp.where(seen, logits, _NEG)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    p = jnp.exp(logits - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = corr * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    v = rows_ref[:, :dv]
+    if s % bs:  # what lies past the cache is not zero, nor finite
+        inside = j * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (bs, dv), 0) < s
+        v = jnp.where(inside, v, jnp.zeros_like(v))
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == last)
+    def _store():
+        o_ref[...] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+def _decode_attn_latent(q, rows, layer, lengths, plan, *, dv: int,
+                        scale: float, bs: int, interpret: bool):
+    """The kernel's call: q [B, H, W] -> [B, H, dv]; ``plan`` is
+    ``visits(lengths, s, bs)``."""
+    b, h, w = q.shape
+    s = rows.shape[2]
+    meta, steps = plan
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_index(step, slot_ids, block_ids, lengths, layer_ref):
+        return slot_ids[step], 0, 0
+
+    def rows_index(step, slot_ids, block_ids, lengths, layer_ref):
+        return layer_ref[0], slot_ids[step], block_ids[step], 0
+
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, s=s, bs=bs, dv=dv, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[pl.BlockSpec((None, h, w), q_index),
+                      pl.BlockSpec((None, None, bs, w), rows_index)],
+            out_specs=pl.BlockSpec((None, h, dv), q_index),
+            grid=(steps,),
+            scratch_shapes=[
+                pltpu.VMEM((h, 128), jnp.float32),  # max
+                pltpu.VMEM((h, 128), jnp.float32),  # sum
+                pltpu.VMEM((h, dv), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * h * s * (w + dv),
+            transcendentals=b * h * s,
+            bytes_accessed=b * s * w * rows.dtype.itemsize),
+        interpret=interpret,
+        name="decode_attn_latent",
+    )(*meta, lengths, layer, q, rows)
+    # a slot without a row was never visited: what its block of the
+    # output holds is whatever the buffer held
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+
+def decode_attention_latent(q, rows, layer, lengths, *, dv: int,
+                            scale: float, plan=None,
+                            use_kernel: bool | None = None,
+                            interpret: bool = False,
+                            block: int | None = None):
+    """A decode step's attention over latent rows: q [B, H, W] (a head's
+    query carried into the row's space) over ``rows[layer]`` of the stack
+    [L, B, S, W] up to ``lengths`` [B] (``pos + 1``; 0: the slot is
+    inactive and its output zeros), logits times ``scale``, a row's
+    value its first ``dv`` numbers -> [B, H, dv] in q's dtype.
+
+    ``use_kernel=None`` takes the backend's: the Pallas kernel
+    (``decode_attn_latent`` in a trace) on a TPU where a row and its
+    value are whole lanes and the heads at most a tile's sublanes,
+    :func:`attend_latent` elsewhere. ``interpret=True`` runs the kernel
+    in the Pallas interpreter (never inferred); ``block`` overrides the
+    block's rows; ``plan`` is ``visits(lengths, S, block)`` where the
+    caller made it already (once a step, before its layers)."""
+    b, h, w = q.shape
+    s = rows.shape[2]
+    if use_kernel is None:
+        use_kernel = interpret or (
+            jax.default_backend() == "tpu" and w % 128 == 0
+            and dv % 128 == 0 and h <= _ROW_PAD)
+    lengths = lengths.astype(jnp.int32)
+    if not use_kernel:
+        return attend_latent(q, rows[layer], lengths, dv, scale)
+    bs = block or block_rows(s, LATENT_BLOCK_ROWS)
+    return _decode_attn_latent(
+        q, rows, layer, lengths, plan or visits(lengths, s, bs), dv=dv,
+        scale=scale, bs=bs, interpret=interpret)

@@ -200,6 +200,12 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys):
                              "mla_rows"}
         assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
         assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    # the fourth block's gated MLA layer: the rows the two forms keep
+    # are the same rows, the outputs agree within the tolerance
+    assert set(facts["latent"]) == {"9", "21"}
+    for errs in facts["latent"].values():
+        assert errs["latent_rows"] == 0.0
+        assert 0 < errs["latent_out"] <= chip_smoke.HYBRID_TOLERANCE
 
 
 def test_ring_check_rehearses_on_the_cpu():
