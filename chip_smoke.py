@@ -91,6 +91,9 @@ class Plan:
     # and a sliding-window layer's ring (models/exaone.py: 128 rows a
     # slot at the published widths) filled past two wraps
     ring_steps: int = 300
+    # and the KDA decode step's kernel against the XLA body for this
+    # many tokens (models/ling.py's state, ops/kda_step.py)
+    kda_steps: int = 24
     # and one gated MLA layer of models/instella.py at the published
     # widths: prompts the flash kernel takes whole (one block)
     latent_lens: tuple = (256, 1024)
@@ -102,7 +105,7 @@ class Plan:
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
-                    latent_lens=(9, 21))
+                    kda_steps=5, latent_lens=(9, 21))
         return Plan(**{**base, **kw})
 
     @property
@@ -1105,6 +1108,81 @@ def hybrid_check(widths: str, lens: list, seed: int) -> dict:
     return {"rel_err": errs, "device": accelerator.device_report()}
 
 
+# The kernel computes the XLA body's float32 lines in another order of
+# sums at most: PR 41's chip runs read 0.0 for state and output.
+KDA_KERNEL_TOLERANCE = 1e-6
+
+
+def kda_kernel_check(widths: str, steps: int, seed: int,
+                     interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: one KDA layer's decode step
+    for ``steps`` tokens over six slots of which two are not active, the
+    recurrence as the backend gives it (on a TPU at the published widths
+    the ``kda_step`` kernel, in place; with ``interpret`` the kernel in
+    the Pallas interpreter) against the XLA body on the same inputs.
+    -> the largest relative error of the state and of the outputs,
+    whether the inactive slots' state came back bit for bit
+    (``inactive_kept``), and whether the layer's own step
+    (``ling.kda_step``) compiles to a program with the kernel in it
+    (``in_program``: the backend's and the shape's choice)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import ling
+    from ray_tpu.ops import kda_step as ks
+
+    accelerator.claim_device()
+    kw = dict(n_layers=1, first_k_dense=1, vocab_size=1024)
+    cfg = ling.LingConfig.tiny(**kw, dtype="bfloat16") if widths == "tiny" \
+        else ling.LingConfig(**kw)
+    p = ling.init_params(cfg, jax.random.PRNGKey(seed))["layers"][0]["attn"]
+    slots, h, dk = 6, cfg.n_heads, cfg.kda_head_dim
+    active = jnp.arange(slots) % 3 != 1
+    kernel = functools.partial(ks.kda_step, **(
+        {"interpret": True} if interpret else {}))
+    k_s, k_x = jax.random.split(jax.random.PRNGKey(seed + 1))
+    s0 = jax.random.normal(k_s, (slots, h, dk, dk), jnp.float32)
+    conv0 = jnp.zeros((slots, cfg.conv_kernel - 1, 3 * h * dk),
+                      cfg.compute_dtype)
+    xs = jax.random.normal(k_x, (steps, slots, 1, cfg.d_model),
+                           cfg.compute_dtype)
+
+    @jax.jit
+    def both(s0, xs):
+        def one(carry, x_t):
+            s_kernel, s_body, conv = carry
+            q, k, v, g, beta, _, u = ling._kda_inputs(cfg, p, x_t, conv)
+            vectors = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                       active)
+            s_kernel, o_kernel = kernel(s_kernel, *vectors)
+            s_body, o_body = ks.kda_step(s_body, *vectors, use_kernel=False)
+            return (s_kernel, s_body, u[:, 1:]), (o_kernel, o_body)
+
+        (s_kernel, s_body, _), (o_kernel, o_body) = jax.lax.scan(
+            one, (s0, s0, conv0), xs)
+        return s_kernel, s_body, o_kernel, o_body
+
+    def rel(a, b):
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    s_kernel, s_body, o_kernel, o_body = both(s0, xs)
+    state = {"s": s0, "conv": conv0}
+    text = jax.jit(functools.partial(ling.kda_step, cfg, p)).lower(
+        xs[0], state, active).compile().as_text()
+    return {"rel_err": {"state": rel(s_kernel, s_body),
+                        "out": rel(o_kernel, o_body)},
+            "inactive_kept": bool(jnp.array_equal(s_kernel[~active],
+                                                  s0[~active])),
+            "moved": rel(s_kernel[active], s0[active]),
+            "in_program": any(
+                KERNEL in line and "kda_step" in line.split(" = ")[0]
+                for line in text.splitlines()),
+            "steps": steps, "device": accelerator.device_report()}
+
+
 def ring_check(widths: str, steps: int, seed: int,
                interpret: bool = False) -> dict:
     """Runs in a child that holds the chip: a sliding-window layer's
@@ -1234,6 +1312,17 @@ def hybrid_phase(plan: Plan) -> dict:
     check(ring["wraps"] >= 2 and ring["rel_err"] <= HYBRID_TOLERANCE,
           "the decode_attn kernel and the XLA body part on a sliding "
           "layer's ring", got=ring, tolerance=HYBRID_TOLERANCE)
+    kda = chip_child(plan, "kda_kernel_check", {
+        "widths": plan.hybrid_widths, "steps": plan.kda_steps,
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, kda["device"], 1, "kda kernel child")
+    check(max(kda["rel_err"].values()) <= KDA_KERNEL_TOLERANCE
+          and kda["inactive_kept"] and kda["moved"] > 0
+          and kda["in_program"] == plan.on_tpu,
+          "the kda_step kernel and the XLA body part on a KDA layer's "
+          "state, an inactive slot's state moved, or the layer's step "
+          "holds no kernel on the chip", got=kda,
+          tolerance=KDA_KERNEL_TOLERANCE)
     latent = chip_child(plan, "latent_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.latent_lens),
         "seed": plan.seed})
@@ -1247,6 +1336,8 @@ def hybrid_phase(plan: Plan) -> dict:
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
             "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
             "latent": latent["rel_err"],
+            "kda_kernel": {k: kda[k] for k in (
+                "rel_err", "inactive_kept", "in_program", "steps")},
             "tolerance": HYBRID_TOLERANCE,
             "compile_s": out["device"]["compile"]["seconds"]}
 

@@ -64,6 +64,9 @@ from ray_tpu.models.moe import draw as _draw
 from ray_tpu.models.moe import moe, prefill_loads, routing_counts
 from ray_tpu.models.moe import route  # noqa: F401 — the name the tests know
 from ray_tpu.models.moe import swiglu as _swiglu
+from ray_tpu.ops.kda_step import kda_recurrence  # noqa: F401 — the
+# recurrence kda_chunked is the chunkwise form of, under this module's name
+from ray_tpu.ops.kda_step import kda_step as _kda_step
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rotary, rotary_embedding
 
@@ -294,28 +297,20 @@ def _kda_out(cfg: LingConfig, p, o, gate):
     return o.reshape(b, t, -1).astype(cfg.compute_dtype) @ p["wo"]
 
 
-def kda_recurrence(s, q, k, v, g, beta):
-    """One token of the delta rule on the state s [B, H, dk, dv]
-    (float32, elementwise: no product is rounded). q, k, g [B, H, dk];
-    v [B, H, dv]; beta [B, H]. -> (s, o [B, H, dv])."""
-    s = s * jnp.exp(g)[..., None]
-    pred = jnp.sum(s * k[..., None], axis=-2)
-    s = s + (beta[..., None] * k)[..., None] * (v - pred)[..., None, :]
-    return s, jnp.sum(s * q[..., None], axis=-2)
-
-
 def kda_step(cfg: LingConfig, p, x, state, active):
     """A decode step of a KDA layer. x [B, 1, D] (normed); ``state``
     {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
-    is not ``active`` keeps its state. -> ([B, 1, D], state)."""
+    is not ``active`` keeps its state. -> ([B, 1, D], state). The
+    recurrence is ``ops.kda_step``: on a TPU one kernel that touches
+    ``s`` once, in the buffer it lies in; :func:`kda_recurrence` and a
+    ``where`` elsewhere."""
     q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, state["conv"])
     with jax.named_scope("attn/attn_linear"):
-        s, o = kda_recurrence(state["s"], q[:, 0], k[:, 0], v[:, 0],
-                              g[:, 0], beta[:, 0])
+        s, o = _kda_step(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                         beta[:, 0], active)
     with jax.named_scope("cache"):
-        keep = active[:, None, None]
-        new = {"s": jnp.where(keep[..., None], s, state["s"]),
-               "conv": jnp.where(keep, u[:, 1:], state["conv"])}
+        new = {"s": s, "conv": jnp.where(active[:, None, None], u[:, 1:],
+                                         state["conv"])}
     return _kda_out(cfg, p, o[:, None], gate), new
 
 

@@ -200,6 +200,14 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys):
                              "mla_rows"}
         assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
         assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    # the KDA step's kernel (interpreted here) against the XLA body:
+    # the same float32 lines, an inactive slot's state untouched, and no
+    # kernel in the layer's own program off the TPU
+    kda = facts["kda_kernel"]
+    assert set(kda["rel_err"]) == {"state", "out"}
+    assert max(kda["rel_err"].values()) <= chip_smoke.KDA_KERNEL_TOLERANCE
+    assert kda["inactive_kept"] is True and kda["in_program"] is False
+    assert kda["steps"] == TINY.kda_steps == 5
     # the fourth block's gated MLA layer: the rows the two forms keep
     # are the same rows, the outputs agree within the tolerance
     assert set(facts["latent"]) == {"9", "21"}
@@ -219,6 +227,22 @@ def test_ring_check_rehearses_on_the_cpu():
     assert found["device"].items() >= CPU.items()
     assert (found["window"], found["wraps"]) == (8, 2)
     assert 0 <= found["rel_err"] <= chip_smoke.HYBRID_TOLERANCE
+
+
+def test_kda_kernel_check_rehearses_on_the_cpu():
+    """What ``hybrid_phase`` asks of the KDA decode step's kernel on the
+    chip, at tiny widths with the kernel in the Pallas interpreter: five
+    tokens over six slots, two of them inactive; and with nobody asking
+    for the kernel the same check compares the XLA body with itself."""
+    found = chip_smoke.kda_kernel_check("tiny", TINY.kda_steps, TINY.seed,
+                                        interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert 0 <= max(found["rel_err"].values()) \
+        <= chip_smoke.KDA_KERNEL_TOLERANCE
+    assert found["inactive_kept"] and found["moved"] > 0.1
+    assert found["in_program"] is False
+    same = chip_smoke.kda_kernel_check("tiny", 2, TINY.seed)
+    assert same["rel_err"] == {"state": 0.0, "out": 0.0}
 
 
 @pytest.mark.slow

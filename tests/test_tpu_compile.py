@@ -476,6 +476,8 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
 
     monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
         gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_step", functools.partial(
+        ling._kda_step, use_kernel=True))
     with open("benchmark/traffic/reason-saturated.json") as f:
         eng = json.load(f)["engine"]
     slots, max_len = eng["slots"], eng["max_len"]
@@ -493,10 +495,29 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
     text = compiled.as_text()
-    assert text.count(KERNEL) == 3 * cfg.moe_layers == 18
+    kda_calls = [line for line in text.splitlines()
+                 if KERNEL in line and "kda_step" in line.split(" = ")[0]]
+    assert len(kda_calls) == sum(
+        cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
+    assert text.count(KERNEL) == 3 * cfg.moe_layers + len(kda_calls) == 24
     for dims in (f"f32[{slots},32,128,128]", f"bf16[{slots},{max_len},512]"):
         assert dims in text
         assert not re.search(re.escape(dims) + r"\S* copy\(", text), dims
+    # a KDA layer's step is one call that takes its state as operand 3
+    # (behind ``active``, the vectors and v) and returns it in that
+    # buffer; nothing else moves a state, whole or a quarter of it (the
+    # XLA body's slices between memories; and with the kernel's operand
+    # left to the compiler, its own: it brought four layers' states into
+    # VMEM in quarters before the call and copied them back behind it)
+    for line in kda_calls:
+        assert line.split(" = ")[1].startswith(
+            f"(f32[{slots},32,128,128]"), line
+        assert "output_to_operand_aliasing={{0}: (3, {})}" in line, line
+    moves = re.compile(r"\s*%(copy|copy-start|slice-start|async-start|"
+                       r"dynamic-slice-start)[.\d]* = ")
+    moved = [line[:160] for line in text.splitlines() if moves.match(line)
+             and re.search(rf"f32\[({slots}|{slots // 4}),32,128,128\]", line)]
+    assert not moved, moved[:3]
     # (the 64-wide rotated keys, 2% of the state, change their layout
     # once a chunk on the way in and out of the step loop: XLA's choice
     # for a minor dimension of half a lane tile, outside the loop)
@@ -1018,6 +1039,9 @@ def dump_serving_programs(out_dir: str) -> None:
     if hasattr(da, "decode_attention_latent"):  # (a parent before PR 39)
         da.decode_attention_latent = functools.partial(
             da.decode_attention_latent, use_kernel=True)
+    from ray_tpu.models import ling
+    if hasattr(ling, "_kda_step"):  # (a parent before PR 41)
+        ling._kda_step = functools.partial(ling._kda_step, use_kernel=True)
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
