@@ -97,6 +97,9 @@ class Plan:
     # and one gated MLA layer of models/instella.py at the published
     # widths: prompts the flash kernel takes whole (one block)
     latent_lens: tuple = (256, 1024)
+    # and one KDA and one gated NoPE GQA layer of models/solar.py at the
+    # published widths (64 heads): a prompt in one segment and in two
+    segment_lens: tuple = (200, 4096)
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -105,7 +108,7 @@ class Plan:
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
-                    kda_steps=5, latent_lens=(9, 21))
+                    kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21))
         return Plan(**{**base, **kw})
 
     @property
@@ -1295,6 +1298,79 @@ def latent_check(widths: str, lens: list, seed: int) -> dict:
     return {"rel_err": errs, "device": accelerator.device_report()}
 
 
+def segment_check(widths: str, lens: list, seed: int) -> dict:
+    """Runs in a child that holds the chip: the fifth block's two kinds
+    of layer (``models/solar.py``) at the published widths, each form
+    against the other, in the compute type, for prompts of ``lens``
+    tokens: a KDA layer's prefill in row segments, ``S`` and the
+    convolution rows carried (2,048 rows a segment), against its own
+    stepping (on a TPU the ``kda_step`` kernel at 64 heads, beta to 2);
+    a gated GQA layer without positions through ``ops.attention`` (the
+    flash kernel on a TPU) against its decode step over the slot's rows
+    (``decode_attn``, lengths ``pos + 1``). -> relative errors by
+    length, and how many segments each length ran in."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import solar
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops.attention import attention
+
+    accelerator.claim_device()
+    kw = dict(n_layers=2, vocab_size=1024, n_experts=8, top_k=2)
+    cfg = solar.SolarConfig.tiny(**kw, dtype="bfloat16") \
+        if widths == "tiny" else solar.SolarConfig(**kw)
+    gqa, kda = (layer["attn"] for layer in solar.init_params(
+        cfg, jax.random.PRNGKey(seed))["layers"])
+
+    @jax.jit
+    def both(x):
+        t = x.shape[1]
+        xs = jnp.moveaxis(x, 1, 0)[:, :, None]  # [T, 1, 1, D]
+        on, lens_ = jnp.ones((1,), bool), jnp.array([t])
+        empty = solar.kda_empty(cfg, 1)
+        st, y_kda = solar._in_segments(
+            lambda state, seg: solar.kda_segment(
+                cfg, kda, seg[1], state, seg[0], lens_)[::-1],
+            empty, x, solar.segment_rows(cfg, t))
+        st_step, y_kda_step = jax.lax.scan(
+            lambda s, x_t: solar.kda_step(cfg, kda, x_t, s, on)[::-1],
+            empty, xs)
+        q, k, v = solar._qkv(cfg, gqa, x)
+        y_gqa = solar._gqa_out(cfg, gqa, x, attention(q, k, v, causal=True))
+
+        def one(cache, xp):
+            x_t, pos = xp
+            q, k, v = solar._qkv(cfg, gqa, x_t)
+            kc, vc = (c.at[0, 0, pos[0]].set(r.reshape(-1))
+                      for c, r in zip(cache, (k, v)))
+            o = da.decode_attention(q, kc, vc, 0, (pos + 1).astype(jnp.int32))
+            return (kc, vc), solar._gqa_out(cfg, gqa, x_t, o)
+
+        rows = jnp.zeros((1, 1, t, cfg.kv_width), cfg.compute_dtype)
+        (kc, _), y_gqa_step = jax.lax.scan(
+            one, (rows, rows), (xs, jnp.arange(t)[:, None]))
+        return {"kda_out": (y_kda, jnp.moveaxis(y_kda_step[:, :, 0], 0, 1)),
+                "kda_state": (st["s"], st_step["s"]),
+                "kda_conv": (st["conv"], st_step["conv"]),
+                "gqa_out": (y_gqa, jnp.moveaxis(y_gqa_step[:, :, 0], 0, 1)),
+                "gqa_rows": (k.reshape(1, t, -1), kc[0])}
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    errs = {}
+    for t in lens:
+        x = jax.random.normal(jax.random.PRNGKey(seed + t),
+                              (1, t, cfg.d_model), cfg.compute_dtype)
+        errs[str(t)] = {k: rel(a, b) for k, (a, b) in both(x).items()}
+    return {"rel_err": errs, "device": accelerator.device_report(),
+            "segments": {str(t): solar.SLOTS.prefill_segments(cfg, t)
+                         for t in lens}}
+
+
 def hybrid_phase(plan: Plan) -> dict:
     out = chip_child(plan, "hybrid_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
@@ -1332,8 +1408,19 @@ def hybrid_phase(plan: Plan) -> dict:
           "a gated MLA layer's two forms part (unabsorbed through the "
           "flash kernel / absorbed over the slot's rows)",
           got=latent["rel_err"], tolerance=HYBRID_TOLERANCE)
+    segment = chip_child(plan, "segment_check", {
+        "widths": plan.hybrid_widths, "lens": list(plan.segment_lens),
+        "seed": plan.seed})
+    check_device(plan, segment["device"], 1, "segment child")
+    check(max(v for by_len in segment["rel_err"].values()
+              for v in by_len.values()) <= HYBRID_TOLERANCE,
+          "a layer of the block without positions parts from its other "
+          "form (KDA in carried segments / stepping at 64 heads, gated "
+          "GQA through the flash kernel / over the slot's rows)",
+          got=segment["rel_err"], tolerance=HYBRID_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
+            "segment": {k: segment[k] for k in ("rel_err", "segments")},
             "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
             "latent": latent["rel_err"],
             "kda_kernel": {k: kda[k] for k in (

@@ -557,12 +557,15 @@ class _LlamaSlots:
       its slots, so that a reused slot shows nothing of its last stream
       (the Llama block: the rows a stream can read are its own, see
       ``_prefill_batch_into_slots``; a state that is no rows is
-      replaced whole);
+      replaced whole); ``prefill_segments(cfg, bucket)``: in how many
+      segments of rows a ``bucket``-row call runs its tokenwise work
+      (1: whole, every block's but ``models/solar.py``'s);
     - ``reports_routing(cfg)``."""
 
     rows_state = True
     step_counters = ("experts_touched",)
     row_kinds = staticmethod(lambda cfg: {})
+    prefill_segments = staticmethod(lambda cfg, bucket: 1)
     serving_params = staticmethod(llama.serving_params)
     reports_routing = staticmethod(llama.reports_routing)
     init_state = staticmethod(init_ragged_cache)
@@ -1085,7 +1088,8 @@ class RaggedDecoder:
         s.bucket = pb = self._bucket(n)
         self.prefill_calls += 1
         with _fr.span("serve", "engine.prefill", flush=False, attrs={
-                "bucket": pb, "prompts": 1, "rows": 1, "tokens": n}):
+                "bucket": pb, "prompts": 1, "rows": 1, "tokens": n,
+                "segments": self.model.prefill_segments(self.cfg, pb)}):
             self._prefill_into_slot(slot, s, s.prompt, pb)
 
     def _prefill_into_slot(self, slot: int, s: _Stream, tokens, width: int,
@@ -1285,7 +1289,8 @@ class RaggedDecoder:
         self.attn_cache_rows += self.slots * self.max_len
         for kind, (_, most) in self.row_kinds.items():
             sp[f"live_rows_{kind}"] = rows = int(
-                np.minimum(held, most or self.max_len).sum())
+                np.minimum(held, self.max_len if most is None
+                           else most).sum())
             self.attn_live_rows_by_kind[kind] += rows
 
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:

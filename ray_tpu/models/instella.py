@@ -495,6 +495,7 @@ class _Slots:
     rows_state = False
     step_counters = ("experts_touched", "assignments", "held_assignments")
     serving_params = staticmethod(serving_params)
+    prefill_segments = staticmethod(lambda cfg, bucket: 1)
 
     @staticmethod
     def reports_routing(cfg: InstellaConfig) -> bool:

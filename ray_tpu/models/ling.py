@@ -19,7 +19,12 @@ are ever sliced out of a stack.
   gated by ``sigmoid(x W_g)``. A decode step is that recurrence
   (:func:`kda_step`); prefill is its chunkwise form (:func:`kda_chunked`,
   chunks of ``kda_chunk``) and leaves the same ``S`` and the same last
-  ``conv_kernel - 1`` convolution inputs. No position encoding.
+  ``conv_kernel - 1`` convolution inputs. No position encoding. A
+  second block runs this code at 64 heads (``models/solar.py``:
+  :func:`kda_qkv`, :func:`kda_chunked`, ``_kda_out``, ``ops/kda_step``);
+  the decay's two forms are told apart in the blocks' ``_kda_inputs``
+  (this one's bounded by ``kda_lower_bound``, that one's ``-exp(A_log)
+  softplus``, unbounded below), never in the shared functions.
 - **MLA** (DeepSeek-V2 section 2.1, no query compression): a cache row
   is the normalised latent and the one rotated key all heads share
   (``kv_lora_rank + qk_rope_head_dim`` numbers a token). Prefill attends
@@ -259,13 +264,14 @@ def serving_params(cfg: LingConfig, params):
 # KDA
 # --------------------------------------------------------------------------
 
-@jax.named_scope("qkv")
-def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
-    """What both forms of KDA start from. x: [B, T, D] (normed);
-    ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
-    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk],
-    beta [B, T, H], the output gate [B, T, H, dk], the projection rows
-    [B, K-1+T, 3*H*dk] whose tail is the next ``conv_rows``)."""
+def kda_qkv(cfg, p, x, conv_rows):
+    """What every KDA layer's q, k and v go through, this block's and
+    ``models/solar.py``'s (``cfg``: ``n_heads``, ``kda_head_dim``,
+    ``conv_kernel``): the projection, the causal depthwise convolution
+    over it and the rows before it, SiLU, the L2 norms. x [B, T, D]
+    (normed); ``conv_rows`` [B, K-1, 3*H*dk]. -> (q, k, v [B, T, H, dk]
+    float32, the projection rows [B, K-1+T, 3*H*dk] whose tail is the
+    next ``conv_rows``)."""
     b, t, _ = x.shape
     h, dk = cfg.n_heads, cfg.kda_head_dim
     f32 = jnp.float32
@@ -278,6 +284,20 @@ def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
     q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
         * dk ** -0.5
     k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    return q, k, v, u
+
+
+@jax.named_scope("qkv")
+def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
+    """What both forms of KDA start from. x: [B, T, D] (normed);
+    ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
+    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk],
+    beta [B, T, H], the output gate [B, T, H, dk], the projection rows
+    [B, K-1+T, 3*H*dk] whose tail is the next ``conv_rows``)."""
+    b, t, _ = x.shape
+    h, dk = cfg.n_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    q, k, v, u = kda_qkv(cfg, p, x, conv_rows)
     f = jnp.dot(x, p["w_f"], preferred_element_type=f32) + p["dt_bias"]
     g = cfg.kda_lower_bound * jax.nn.sigmoid(
         jnp.exp(p["a_log"])[:, None] * f.reshape(b, t, h, dk))
@@ -289,7 +309,7 @@ def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
 
 
 @jax.named_scope("attn_out")
-def _kda_out(cfg: LingConfig, p, o, gate):
+def _kda_out(cfg, p, o, gate):
     """o, gate [B, T, H, dk] float32 -> [B, T, D]: the head-wise RMS
     norm, the sigmoid gate and the output projection."""
     b, t = o.shape[:2]
@@ -330,7 +350,7 @@ def _unit_lower_inverse(n):
     return jax.lax.fori_loop(1, c, row, eye)
 
 
-def kda_chunked(cfg: LingConfig, q, k, v, g, beta, s0):
+def kda_chunked(cfg, q, k, v, g, beta, s0):
     """The chunkwise form of :func:`kda_recurrence` over T tokens (T a
     multiple of ``kda_chunk``). q, k, g [B, T, H, dk], v [B, T, H, dv],
     beta [B, T, H], all float32; s0 [B, H, dk, dv]. A token with
@@ -636,6 +656,7 @@ class _Slots:
     step_counters = ("experts_touched", "assignments", "held_assignments")
     row_kinds = staticmethod(lambda cfg: {})  # one layer in six keeps rows
     serving_params = staticmethod(serving_params)
+    prefill_segments = staticmethod(lambda cfg, bucket: 1)
 
     @staticmethod
     def reports_routing(cfg: LingConfig) -> bool:

@@ -1,0 +1,684 @@
+"""A hybrid decoder without positions: delta-rule linear attention (KDA)
+three layers in four beside gated softmax GQA layers, every layer a
+mixture of experts of which this device holds a part. The language
+model of Solar-Open2-250B (``model_type`` ``solar_open2``) as its
+``config.json`` gives it; the fifth block beside ``llama.py``,
+``ling.py``, ``exaone.py`` and ``instella.py``.
+
+Layer ``i`` attends with **GQA** if ``i`` is in ``gqa_layers``
+(published: 0, 4, 8, ...: ``gqa_interval`` 3 linear layers after each)
+and with **KDA** otherwise. Pre-norm, both halves added to the stream.
+The layers are NOT a stack scanned by one loop: each is its own dict of
+leaves and the programs unroll them.
+
+- **KDA** (Kimi Delta Attention, arXiv:2510.26692; ``fla/layers/kda.py``):
+  what ``models/ling.py`` runs, shared with it (the convolution and the
+  norms ``ling.kda_qkv``, the chunkwise form ``ling.kda_chunked``, the
+  output ``ling._kda_out``, the decode step ``ops/kda_step.py``), in
+  Kimi Linear's own parametrisation: log decay a channel ``g =
+  -exp(A_log_h) * softplus(x W_f_down W_f_up + dt_bias)``, unbounded
+  below (Ling's is bounded by its ``kda_lower_bound``: no such key
+  here), its projection and the output gate's of low rank (the head's
+  width: ``kda_use_full_proj`` false), and ``beta = 2 sigmoid(x
+  W_beta)`` in (0, 2) (``kda_allow_neg_eigval``: the eigenvalue ``1 -
+  beta`` of ``I - beta k k^T`` may be negative). State float32 ``S [H,
+  dk, dv]`` a stream and the last ``conv_kernel - 1`` projection rows.
+- **GQA**: q of ``n_heads`` x ``head_dim``, k and v of ``n_kv_heads`` x
+  ``head_dim``, NO rotary and no q / k norm (``use_rope`` false: no
+  position encoding anywhere in the model), causal softmax over q k^T /
+  sqrt(head_dim), the output times ``sigmoid(x W_gate)`` elementwise
+  before ``W_o`` (``use_gqa_gate``; arXiv:2505.06708). Prefill attends
+  through ``ops.attention`` (the flash kernel on a TPU), a decode step
+  through ``ops.decode_attention`` over the slot's rows.
+- **MoE**, every layer (``models/moe.py``, shared with the three blocks
+  above): sigmoid scores in float32 over all ``n_experts``, a bias for
+  the selection only, one group, ``top_k`` chosen, weights ``s / sum s``
+  scaled; one shared expert. ``held_experts = (first, count)``: the part
+  this device computes.
+
+**Prefill runs in row segments.** A prompt of 32,768 rows at 64 heads
+does not fit as whole arrays (q, k, v, g of ``[T, 64, 128]`` float32 are
+1.07 GB each, the expert layer's gather of ``T x top_k`` rows 2.1 GB).
+So everything that is a function of a row and a carried state (norms,
+projections, the convolution, the chunkwise delta rule, gates and
+``W_o``, router and experts) runs over segments of at most
+:data:`SEGMENT_ROWS` rows under one ``lax.scan`` a layer, a KDA layer's
+``S`` and last three projection rows carried from segment to segment;
+only what needs the whole prompt is whole: the GQA layer's q, k and v
+(bf16) and one flash call over them. The bucket decides: a bucket of at
+most ``SEGMENT_ROWS`` is one segment (:func:`segment_rows`). No option
+chooses it.
+
+A slot's state in the serving engine is this model's own
+(:data:`SLOTS`, found through ``SolarConfig.slot_model``), of two kinds
+side by side: for each KDA layer ``S [slots, H, dk, dv]`` float32 and
+``conv [slots, K-1, 3 H dk]`` (each layer its own array: the step
+kernel writes ``S`` into the buffer it came from), for the GQA layers k
+and v stacks ``[L_full, slots, max_len, Hkv * hd]`` in the Llama
+block's layout, read in place by ``decode_attn`` up to each slot's own
+length. A recurrent state cannot be cut at a position, so the prefix
+cache, speculative decoding and the prefill workers refuse this model
+by name (``rows_state``).
+
+Types: matrices in ``dtype`` (bf16), products accumulated in float32;
+norm vectors, ``a_log``, ``dt_bias`` and the router's bias float32;
+router scores, softmax statistics and ``S`` float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import ling, llama
+from ray_tpu.models.decode_engine import _sample_from_logits
+from ray_tpu.models.moe import draw, moe, prefill_loads, routing_counts
+from ray_tpu.ops import decode_attention as _da
+from ray_tpu.ops.attention import attention
+from ray_tpu.ops.kda_step import kda_step as _kda_step
+from ray_tpu.ops.norms import rms_norm
+
+# the most rows of a prompt whose tokenwise work is done at once (every
+# float32 ``[rows, 64, 128]`` array is then 67 MB); module docstring
+SEGMENT_ROWS = 2048
+
+
+@dataclasses.dataclass(frozen=True)
+class SolarConfig:
+    vocab_size: int = 196608
+    d_model: int = 4096
+    n_layers: int = 48
+    # the GQA layers' query heads AND the KDA layers' heads (published:
+    # 64 and 64; the family file refuses a configuration where they part)
+    n_heads: int = 64
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    # the layers that attend with softmax GQA; () = every
+    # (gqa_interval + 1)-th from layer 0
+    gqa_layers: tuple = ()
+    gqa_interval: int = 3
+    # KDA
+    kda_head_dim: int = 128  # key and value width of a head
+    conv_kernel: int = 4
+    kda_rank: int = 128  # of the decay's and the gate's projections
+    kda_chunk: int = 64
+    # mixture of experts: d_ff is ONE expert's width
+    d_ff: int = 1280
+    shared_d_ff: int = 1280
+    n_experts: int = 320
+    top_k: int = 8
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # (first, count): the experts this device holds; None = all of them
+    held_experts: tuple | None = None
+    rms_eps: float = 1e-5
+    max_seq_len: int = 4096
+    dtype: str = "bfloat16"
+    # None: ``ops.attention``'s own choice (flash on a TPU)
+    use_flash: bool | None = None
+    # the depth the weights are initialised for (init_params); 0 =
+    # n_layers. A configuration cut in depth names its model's own.
+    published_layers: int = 0
+
+    def __post_init__(self):
+        full = tuple(self.gqa_layers) or tuple(
+            range(0, self.n_layers, self.gqa_interval + 1))
+        if any(not 0 <= i < self.n_layers for i in full):
+            raise ValueError(f"gqa_layers {full} name a layer that "
+                             f"{self.n_layers} layers do not have")
+        object.__setattr__(self, "gqa_layers", full)
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def held(self) -> tuple:
+        return self.held_experts or (0, self.n_experts)
+
+    @property
+    def kv_width(self) -> int:
+        """What a cache row holds: the position's kv heads end to end."""
+        return self.n_kv_heads * self.head_dim
+
+    def full(self, i: int) -> bool:
+        return i in self.gqa_layers
+
+    def stack_index(self, i: int) -> int:
+        """Layer ``i``'s place among the layers of its kind."""
+        return sum(self.full(j) == self.full(i) for j in range(i))
+
+    @property
+    def full_layers(self) -> int:
+        return len(self.gqa_layers)
+
+    @property
+    def kda_layers(self) -> int:
+        return self.n_layers - self.full_layers
+
+    @property
+    def moe_layers(self) -> int:
+        return self.n_layers
+
+    @property
+    def slot_model(self):
+        return SLOTS
+
+    @staticmethod
+    def tiny(**kw) -> "SolarConfig":
+        """Test-size config: one period and a layer of the next, heads x
+        head_dim unequal to the hidden size; runs on the CPU."""
+        base = dict(
+            vocab_size=256, d_model=48, n_layers=5, n_heads=4, n_kv_heads=2,
+            head_dim=16, kda_head_dim=16, kda_rank=8, kda_chunk=8, d_ff=32,
+            shared_d_ff=32, n_experts=16, top_k=4, max_seq_len=128,
+            dtype="float32")
+        base.update(kw)
+        return SolarConfig(**base)
+
+
+def segment_rows(cfg: SolarConfig, t: int) -> int:
+    """The rows of one segment of a ``t``-row prefill: ``t`` itself up to
+    :data:`SEGMENT_ROWS`, else the equal segments of at most that many
+    rows, which must be whole KDA chunks."""
+    n = -(-t // SEGMENT_ROWS)
+    if t % n or (n > 1 and (t // n) % cfg.kda_chunk):
+        raise ValueError(
+            f"a prefill of {t} rows is run in {n} segments of at most "
+            f"{SEGMENT_ROWS} rows: {t} must divide into {n} equal "
+            f"segments of whole {cfg.kda_chunk}-row chunks")
+    return t // n
+
+
+# --------------------------------------------------------------------------
+# Parameters
+# --------------------------------------------------------------------------
+
+# leaves the model paths consume in float32
+_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "a_log",
+               "dt_bias", "router_bias")
+
+
+def init_params(cfg: SolarConfig, key):
+    """The tree in the SERVING types (module docstring), leaf by leaf in
+    blocks (``moe.draw``). Matrices are normal / sqrt(fan_in), and those
+    that write into the residual stream (``wo``, every ``w_down``) are
+    scaled by (2 x depth)^-1/2 besides (depth is ``published_layers``,
+    the model's own where the configuration is cut in depth;
+    ``ling.init_params`` says what the scaling is for). The norm scales
+    are drawn around 1, the decay parameters and the router's bias away
+    from 0, so that a part left out of a path shows against the
+    reference."""
+    cdt = cfg.compute_dtype
+    d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
+    dk, r = cfg.kda_head_dim, cfg.kda_rank
+    _, count = cfg.held
+    keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
+    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
+
+    def mat(*shape, out=False):
+        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
+        return draw(next(keys), shape, scale, cdt)
+
+    def around_one(*shape):
+        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    def kda():
+        return {
+            "w_qkv": mat(d, 3 * h * dk),
+            "conv": draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
+                         cfg.conv_kernel ** -0.5, cdt),
+            "w_f_down": mat(d, r), "w_f_up": mat(r, h * dk),
+            "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
+            "a_log": jnp.log(jax.random.uniform(
+                next(keys), (h,), jnp.float32, 0.5, 4.0)),
+            "w_beta": mat(d, h),
+            "w_g_down": mat(d, r), "w_g_up": mat(r, h * dk),
+            "o_norm": around_one(dk),
+            "wo": mat(h * dk, d, out=True),
+        }
+
+    def gqa():
+        return {
+            "w_qkv": mat(d, (h + 2 * cfg.n_kv_heads) * hd),
+            "w_gate": mat(d, h * hd),
+            "wo": mat(h * hd, d, out=True),
+        }
+
+    def experts():
+        f, fs = cfg.d_ff, cfg.shared_d_ff
+        return {
+            "router": mat(d, cfg.n_experts),
+            # (small against the scores' spread: ``ling.init_params``)
+            "router_bias": 0.01 * jax.random.normal(
+                next(keys), (cfg.n_experts,), jnp.float32),
+            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
+            "w_down": mat(count, f, d, out=True),
+            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
+            "shared_down": mat(fs, d, out=True),
+        }
+
+    layers = [{
+        "attn_norm": around_one(d),
+        "attn": gqa() if cfg.full(i) else kda(),
+        "mlp_norm": around_one(d), "mlp": experts(),
+    } for i in range(cfg.n_layers)]
+    return {
+        "embed": draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
+        "layers": layers,
+        "final_norm": around_one(d),
+        "lm_head": mat(d, cfg.vocab_size),
+    }
+
+
+def serving_params(cfg: SolarConfig, params):
+    """The tree a serving process holds (``llama.serving_params`` with
+    this block's float32 leaves): :func:`init_params` makes that tree
+    already, and it comes back itself; a published tree of another type
+    is cast once, here."""
+    return llama.serving_params(cfg, params, _F32_LEAVES)
+
+
+# --------------------------------------------------------------------------
+# KDA
+# --------------------------------------------------------------------------
+
+@jax.named_scope("qkv")
+def _kda_inputs(cfg: SolarConfig, p, x, conv_rows):
+    """What both forms of KDA start from. x [B, T, D] (normed);
+    ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
+    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk]
+    (Kimi Linear's: no lower bound), beta [B, T, H] in (0, 2), the
+    projection rows [B, K-1+T, 3*H*dk] whose tail is the next
+    ``conv_rows``)."""
+    b, t, _ = x.shape
+    h, dk = cfg.n_heads, cfg.kda_head_dim
+    f32 = jnp.float32
+    q, k, v, u = ling.kda_qkv(cfg, p, x, conv_rows)
+    f = jnp.dot(x @ p["w_f_down"], p["w_f_up"],
+                preferred_element_type=f32) + p["dt_bias"]
+    g = -jnp.exp(p["a_log"])[:, None] * jax.nn.softplus(
+        f.reshape(b, t, h, dk))
+    beta = 2.0 * jax.nn.sigmoid(jnp.dot(x, p["w_beta"],
+                                        preferred_element_type=f32))
+    return q, k, v, g, beta, u
+
+
+def _kda_out(cfg: SolarConfig, p, x, o):
+    """o [B, T, H, dk] float32 -> [B, T, D]: the low-rank output gate of
+    the layer's input x, then Ling's head-wise norm, gate and ``W_o``."""
+    with jax.named_scope("attn_out"):
+        gate = jax.nn.sigmoid(jnp.dot(
+            x @ p["w_g_down"], p["w_g_up"],
+            preferred_element_type=jnp.float32)).reshape(o.shape)
+    return ling._kda_out(cfg, p, o, gate)
+
+
+def kda_empty(cfg: SolarConfig, b: int) -> dict:
+    """The state of ``b`` streams before their first token."""
+    h, dk = cfg.n_heads, cfg.kda_head_dim
+    return {"s": jnp.zeros((b, h, dk, dk), jnp.float32),
+            "conv": jnp.zeros((b, cfg.conv_kernel - 1, 3 * h * dk),
+                              cfg.compute_dtype)}
+
+
+def kda_step(cfg: SolarConfig, p, x, state, active):
+    """A decode step of a KDA layer. x [B, 1, D] (normed); ``state``
+    {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
+    is not ``active`` keeps its state. -> ([B, 1, D], state)."""
+    q, k, v, g, beta, u = _kda_inputs(cfg, p, x, state["conv"])
+    with jax.named_scope("attn/attn_linear"):
+        s, o = _kda_step(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                         beta[:, 0], active)
+    with jax.named_scope("cache"):
+        new = {"s": s, "conv": jnp.where(active[:, None, None], u[:, 1:],
+                                         state["conv"])}
+    return _kda_out(cfg, p, x, o[:, None]), new
+
+
+def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
+    """A KDA layer over one segment of whole prompts: rows ``start`` ..
+    ``start + T - 1`` of x [B, T, D] (normed, right-padded: ``true_lens``
+    [B] rows of each prompt are real), from the ``state`` the rows
+    before them left ({"s", "conv"}: zeros at a prompt's start). A
+    padding row has beta 0 and g 0 and leaves ``S`` as it was, and the
+    convolution rows kept are the last K-1 REAL ones: the state after a
+    prompt's last segment is the state after its last real token.
+    -> ([B, T, D], state)."""
+    t = x.shape[1]
+    kw = cfg.conv_kernel - 1
+    q, k, v, g, beta, u = _kda_inputs(cfg, p, x, state["conv"])
+    with jax.named_scope("attn/attn_linear"):
+        real = start + jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        pad = -t % cfg.kda_chunk
+        if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
+            q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
+                                        + ((0, 0),) * (a.ndim - 2))
+                                for a in (q, k, v, g, beta))
+        o, s = ling.kda_chunked(cfg, q, k, v, g, beta, state["s"])
+    with jax.named_scope("cache"):
+        # u's row j is position start - (K-1) + j: the last K-1 real
+        # rows are j = n .. n + K-2 for n = the real rows in or before
+        # this segment; a prompt that ended earlier keeps what it had
+        n = jnp.clip(true_lens - start, 0, t)
+        rows = n[:, None] + jnp.arange(kw)[None, :]
+        conv = jnp.take_along_axis(u, rows[..., None], axis=1)
+    return _kda_out(cfg, p, x, o[:, :t]), {"s": s, "conv": conv}
+
+
+# --------------------------------------------------------------------------
+# GQA
+# --------------------------------------------------------------------------
+
+@jax.named_scope("qkv")
+def _qkv(cfg: SolarConfig, p, x):
+    """x [B, T, D] (normed) -> (q [B, T, Hq, hd], k, v [B, T, Hkv, hd]):
+    one product; no norm, no rotation."""
+    b, t, _ = x.shape
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    qkv = x @ p["w_qkv"]
+    return (qkv[..., :hq * hd].reshape(b, t, hq, hd),
+            qkv[..., hq * hd:(hq + hkv) * hd].reshape(b, t, hkv, hd),
+            qkv[..., (hq + hkv) * hd:].reshape(b, t, hkv, hd))
+
+
+@jax.named_scope("attn_out")
+def _gqa_out(cfg: SolarConfig, p, x, o):
+    """o [B, T, Hq, hd] -> [B, T, D]: the elementwise sigmoid gate of
+    the layer's input x, then ``W_o``."""
+    b, t = o.shape[:2]
+    gate = jax.nn.sigmoid(jnp.dot(x, p["w_gate"],
+                                  preferred_element_type=jnp.float32))
+    o = (o.reshape(b, t, -1).astype(jnp.float32) * gate)
+    return o.astype(cfg.compute_dtype) @ p["wo"]
+
+
+# --------------------------------------------------------------------------
+# The model: whole sequences, prefill into a slot's state, a ragged step
+# --------------------------------------------------------------------------
+
+def _mlp(cfg: SolarConfig, p, h, aux: dict | None = None):
+    """A layer's experts with their norm, added to ``h`` [B, T, D]
+    (``moe_router``, the norm with it, ``moe_experts``, ``moe_shared``,
+    the residual with it)."""
+    with jax.named_scope("moe_router"):
+        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+    y = moe(cfg, p["mlp"], x, aux)
+    with jax.named_scope("moe_shared"):
+        return h + y
+
+
+@jax.named_scope("lm_head")
+def _logits(cfg: SolarConfig, params, h):
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
+
+
+def _in_segments(body, carry, xs, seg: int):
+    """``body(carry, (segment's first row, the segment's rows of xs)) ->
+    (carry, outputs with a leading [B, seg])`` over the segments of
+    ``xs`` (a tree of [B, T, ...] arrays) in order -> (carry, the
+    outputs [B, T, ...]). One segment is one plain call."""
+    b, t = jax.tree_util.tree_leaves(xs)[0].shape[:2]
+    n = t // seg
+    if n == 1:
+        return body(carry, (jnp.int32(0), xs))
+
+    def rows(a):  # [B, T, ...] -> [n, B, seg, ...]
+        return jnp.moveaxis(a.reshape(b, n, seg, *a.shape[2:]), 1, 0)
+
+    carry, outs = jax.lax.scan(body, carry, (
+        jnp.arange(n, dtype=jnp.int32) * seg,
+        jax.tree_util.tree_map(rows, xs)))
+    return carry, jax.tree_util.tree_map(
+        lambda a: jnp.moveaxis(a, 0, 1).reshape(b, t, *a.shape[3:]), outs)
+
+
+def prefill(params, tokens, true_lens, cfg: SolarConfig,
+            loads: bool = False):
+    """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
+    state, the tokenwise parts in segments of :func:`segment_rows` rows
+    (module docstring) -> (h [B, T, D] before the final norm, the
+    streams' state {"kda": a list of {"s", "conv"} a KDA layer,
+    "k_full", "v_full" [L_full, B, T, Hkv * hd]: the GQA layers' rows,
+    padding's among them}, and with ``loads`` the held experts'
+    assignments from the real positions [L, count] int32, else None)."""
+    b, t = tokens.shape
+    seg = segment_rows(cfg, t)
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
+    kda, k_rows, v_rows, counts = [], [], [], []
+
+    def experts(p, h_seg, start):
+        aux = {} if loads else None
+        h_seg = _mlp(cfg, p, h_seg, aux)
+        return h_seg, (prefill_loads(cfg, aux["expert_ids"][None],
+                                     true_lens - start)[0] if loads else ())
+
+    for i, p in enumerate(params["layers"]):
+        def norm(h_seg, p=p):
+            with jax.named_scope("qkv"):
+                return rms_norm(h_seg, p["attn_norm"], cfg.rms_eps)
+
+        if cfg.full(i):
+            def project(_, xs, p=p):
+                return (), _qkv(cfg, p["attn"], norm(xs[1]))
+
+            _, (q, k, v) = _in_segments(project, (), h, seg)
+            with jax.named_scope("attn/attn_full"):
+                o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
+
+            def rest(count, xs, p=p):
+                start, (h_seg, o_seg) = xs
+                with jax.named_scope("attn_out"):
+                    h_seg = h_seg + _gqa_out(cfg, p["attn"], norm(h_seg),
+                                             o_seg)
+                h_seg, n = experts(p, h_seg, start)
+                return jax.tree_util.tree_map(jnp.add, count, n), h_seg
+
+            with jax.named_scope("cache"):
+                k_rows.append(k.reshape(b, t, -1))
+                v_rows.append(v.reshape(b, t, -1))
+            count, h = _in_segments(rest, _zero_loads(cfg, loads), (h, o),
+                                    seg)
+        else:
+            def layer(carry, xs, p=p):
+                state, count = carry
+                start, h_seg = xs
+                y, state = kda_segment(cfg, p["attn"], norm(h_seg), state,
+                                       start, true_lens)
+                with jax.named_scope("attn_out"):
+                    h_seg = h_seg + y
+                h_seg, n = experts(p, h_seg, start)
+                return (state, jax.tree_util.tree_map(jnp.add, count, n)), \
+                    h_seg
+
+            (state, count), h = _in_segments(
+                layer, (kda_empty(cfg, b), _zero_loads(cfg, loads)), h, seg)
+            kda.append(state)
+        counts.append(count)
+
+    def stack(parts):  # (no GQA layer: no rows)
+        return jnp.stack(parts) if parts else jnp.zeros(
+            (0, b, t, cfg.kv_width), cfg.compute_dtype)
+
+    with jax.named_scope("cache"):
+        state = {"kda": kda, "k_full": stack(k_rows),
+                 "v_full": stack(v_rows)}
+    return h, state, jnp.stack(counts) if loads else None
+
+
+def _zero_loads(cfg: SolarConfig, loads: bool):
+    return jnp.zeros((cfg.held[1],), jnp.int32) if loads else ()
+
+
+def forward(params, tokens, cfg: SolarConfig):
+    """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
+    chunkwise KDA and the prompt's attention."""
+    b, t = tokens.shape
+    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
+    return _logits(cfg, params, h)
+
+
+def loss_fn(params, batch, cfg: SolarConfig):
+    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
+    (or inputs / targets). No cell trains this block: the forward is
+    the serving one, in the serving types."""
+    from ray_tpu.ops.losses import softmax_cross_entropy
+
+    if "inputs" in batch:
+        inputs, targets = batch["inputs"], batch["targets"]
+    else:
+        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
+                                    mask=batch.get("mask"))
+    return loss, {"loss": loss, "tokens": n}
+
+
+def step(cfg: SolarConfig, params, tok, state, pos, active):
+    """One token a slot at PER-SLOT positions. tok, pos, active [B];
+    ``state`` as :meth:`_Slots.init_state` makes it, without ``pos``. A
+    KDA layer updates its ``S`` and convolution rows (``ops.kda_step``);
+    a GQA layer writes its B new rows at ``[layer, slot, pos]`` and
+    attends over the slot's ``pos + 1`` rows (``ops.decode_attention``
+    on the stack in place, the kernel's visits made here once, before
+    the layers); an inactive slot keeps its state and attends over
+    nothing. -> (float32 logits [B, V], the state updated, three [L]
+    int32 counters of the ACTIVE slots' routing: distinct held experts
+    touched, assignments, assignments to held experts)."""
+    b = tok.shape[0]
+    slots = jnp.arange(b)
+    with jax.named_scope("embed"):
+        h = params["embed"][tok][:, None]  # [B, 1, D]
+    with jax.named_scope("attn"):
+        lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
+        plan = _da.visits(lengths, state["k_full"].shape[2])
+    kf, vf, kda = state["k_full"], state["v_full"], list(state["kda"])
+    counts = []
+    for i, p in enumerate(params["layers"]):
+        layer = cfg.stack_index(i)
+        with jax.named_scope("qkv"):
+            x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
+        if cfg.full(i):
+            q, k, v = _qkv(cfg, p["attn"], x)
+            with jax.named_scope("cache"):
+                kf = kf.at[layer, slots, pos].set(k.reshape(b, -1))
+                vf = vf.at[layer, slots, pos].set(v.reshape(b, -1))
+            with jax.named_scope("attn/attn_full"):
+                o = _da.decode_attention(q, kf, vf, layer, lengths,
+                                         plan=plan)
+            y = _gqa_out(cfg, p["attn"], x, o)
+        else:
+            y, kda[layer] = kda_step(cfg, p["attn"], x, kda[layer], active)
+        with jax.named_scope("attn_out"):
+            h = h + y
+        aux = {}
+        h = _mlp(cfg, p, h, aux)
+        counts.append(routing_counts(cfg, aux["expert_ids"], active))
+    counters = tuple(jnp.stack(c) for c in zip(*counts))
+    state = {"kda": kda, "k_full": kf, "v_full": vf}
+    return _logits(cfg, params, h)[:, 0], state, *counters
+
+
+# --------------------------------------------------------------------------
+# The serving engine's half (decode_engine.slot_model's protocol)
+# --------------------------------------------------------------------------
+
+class _Slots:
+    """What ``models/decode_engine.py`` asks of a model whose slot state
+    is its own. The engine carries the state, donates it to its two
+    programs and reads ``state["pos"]``; it looks at nothing else."""
+
+    # a recurrent state cannot be cut or rewound at a position
+    rows_state = False
+    step_counters = ("experts_touched", "assignments", "held_assignments")
+    serving_params = staticmethod(serving_params)
+    reports_routing = staticmethod(lambda cfg: True)
+
+    @staticmethod
+    def row_kinds(cfg: SolarConfig) -> dict:
+        # (a recurrent layer keeps no rows: 0 of a slot's are live)
+        return {"recurrent": (cfg.kda_layers, 0),
+                "full": (cfg.full_layers, None)}
+
+    @staticmethod
+    def prefill_segments(cfg: SolarConfig, bucket: int) -> int:
+        return bucket // segment_rows(cfg, bucket)
+
+    @staticmethod
+    def init_state(cfg: SolarConfig, slots: int, max_len: int) -> dict:
+        cdt = cfg.compute_dtype
+        full = (cfg.full_layers, slots, max_len, cfg.kv_width)
+        return {
+            "kda": [kda_empty(cfg, slots) for _ in range(cfg.kda_layers)],
+            "k_full": jnp.zeros(full, cdt), "v_full": jnp.zeros(full, cdt),
+            "pos": jnp.zeros((slots,), jnp.int32)}
+
+    @staticmethod
+    def max_len(state: dict) -> int:
+        return state["k_full"].shape[2]
+
+    @staticmethod
+    def state_bytes(state: dict) -> dict:
+        def size(a):  # (by shape: the state may be described only)
+            return a.size * a.dtype.itemsize
+
+        return {"recurrent": sum(size(a) for st in state["kda"]
+                                 for a in st.values()),
+                "full": size(state["k_full"]) + size(state["v_full"])}
+
+    @staticmethod
+    def split(cfg: SolarConfig, params):
+        return None
+
+    @staticmethod
+    def step(cfg: SolarConfig, params, prepared, tok, state, pos, active):
+        return step(cfg, params, tok, state, pos, active)
+
+    @staticmethod
+    def prefill(params, prompts, true_lens, seeds, temps, top_ps,
+                cfg: SolarConfig, slot_len: int, prefix=None):
+        """Whole prompts from EMPTY state (a reused slot starts from a
+        zero ``S`` and zero convolution rows). -> (the streams' state,
+        [F] prompt lengths, [F] first tokens, [F] their logprobs, the
+        held experts' assignments from the real positions [L, count])."""
+        if prefix is not None:
+            raise ValueError(
+                "a prefix of cached rows cannot seed this model's slot: "
+                "its KDA layers' state is recurrent, not rows")
+        h, streams, loads = prefill(params, prompts, true_lens, cfg,
+                                    loads=True)
+        f = prompts.shape[0]
+        with jax.named_scope("lm_head"):  # (the last real row alone)
+            last = _logits(cfg, params,
+                           h[jnp.arange(f), true_lens - 1][:, None])
+        toks0, logp0 = _sample_from_logits(
+            last[:, 0], seeds, true_lens - 1, temps, top_ps)
+        return streams, true_lens, toks0, logp0, loads
+
+    @staticmethod
+    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
+        """The prefilled streams' state into their slots: a KDA layer's
+        ``S`` and convolution rows replaced whole, a GQA layer's P rows
+        onto the first P rows of the slot. What the slot's last stream
+        wrote behind them stays: no reader looks past a slot's own
+        length (``_prefill_batch_into_slots``' docstring)."""
+        def rows(all_, new):  # [L, slots, S, C] <- [L, F, P <= S, C]
+            return all_.at[:, slots, :new.shape[2]].set(
+                new.astype(all_.dtype))
+
+        return {
+            "kda": [{name: st[name].at[slots].set(
+                        new[name].astype(st[name].dtype)) for name in st}
+                    for st, new in zip(state["kda"], streams["kda"])],
+            "k_full": rows(state["k_full"], streams["k_full"]),
+            "v_full": rows(state["v_full"], streams["v_full"]),
+            "pos": state["pos"].at[slots].set(full_lens)}
+
+
+SLOTS = _Slots

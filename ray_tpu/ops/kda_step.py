@@ -1,6 +1,9 @@
 """One token of the delta rule on every slot's state, in place.
 
-A KDA layer (``models/ling.py``) keeps a float32 state ``S [slots, H,
+A KDA layer (``models/ling.py`` at 32 heads; ``models/solar.py`` at 64,
+with ``beta`` in (0, 2) and a log decay ``g`` unbounded below: the
+kernel takes ``g`` and ``beta`` as given, each block's ``_kda_inputs``
+makes its own) keeps a float32 state ``S [slots, H,
 dk, dv]``; a decode step decays it a channel, takes the state's
 prediction for the new key, adds the delta rule's outer product and
 reads the output::

@@ -214,6 +214,30 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys):
     for errs in facts["latent"].values():
         assert errs["latent_rows"] == 0.0
         assert 0 < errs["latent_out"] <= chip_smoke.HYBRID_TOLERANCE
+    # the fifth block's two kinds of layer, each in one segment here
+    assert facts["segment"]["segments"] == {"9": 1, "21": 1}
+    assert max(v for errs in facts["segment"]["rel_err"].values()
+               for v in errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+
+
+def test_segment_check_rehearses_on_the_cpu(monkeypatch):
+    """What ``hybrid_phase`` asks of the block without positions
+    (``models/solar.py``) on the chip, at tiny widths with segments of 8
+    rows: prompts of 16 and 32 tokens run their KDA layer in two and
+    four segments, the state carried, and agree with stepping; the gated
+    GQA layer's prompt attention agrees with its steps over the rows,
+    which are the same rows."""
+    from ray_tpu.models import solar
+
+    monkeypatch.setattr(solar, "SEGMENT_ROWS", 8)
+    found = chip_smoke.segment_check("tiny", [16, 32], TINY.seed)
+    assert found["device"].items() >= CPU.items()
+    assert found["segments"] == {"16": 2, "32": 4}
+    for errs in found["rel_err"].values():
+        assert set(errs) == {"kda_out", "kda_state", "kda_conv", "gqa_out",
+                             "gqa_rows"}
+        assert errs["kda_conv"] == 0.0 and errs["gqa_rows"] == 0.0
+        assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
 
 
 def test_ring_check_rehearses_on_the_cpu():
