@@ -100,6 +100,10 @@ class Plan:
     # and one KDA and one gated NoPE GQA layer of models/solar.py at the
     # published widths (64 heads): a prompt in one segment and in two
     segment_lens: tuple = (200, 4096)
+    # and the chunkwise delta rule's kernel (ops/kda_chunk.py) at that
+    # block's 64 heads against the XLA body and the recurrence, this
+    # many rows in two calls, S carried
+    kda_chunk_rows: int = 4096
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -108,7 +112,8 @@ class Plan:
                     prompt_lens=(24, 5), max_tokens=12, batch=2, seq=32,
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
-                    kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21))
+                    kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21),
+                    kda_chunk_rows=32)
         return Plan(**{**base, **kw})
 
     @property
@@ -1186,6 +1191,81 @@ def kda_kernel_check(widths: str, steps: int, seed: int,
             "steps": steps, "device": accelerator.device_report()}
 
 
+# Kernel and XLA body are the same float32 sums in another order, and
+# both stray from the recurrence a token at a time by its own rounding
+# (my chip run, PR 47, 4,096 rows at 64 heads in two calls: 7e-7 / 2e-7
+# between the two for o / S, 4e-6 / 4e-6 from the recurrence for either).
+KDA_CHUNK_TOLERANCE = 1e-4
+
+
+def kda_chunk_check(widths: str, rows: int, seed: int,
+                    interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: the chunkwise delta rule
+    over ``rows`` rows of one KDA layer of ``models/solar.py`` (64 heads
+    at the published widths, beta to 2, a decay without a lower bound;
+    q, k, v, g, beta as the layer's own ``_kda_inputs`` makes them) in
+    two calls with ``S`` carried, as the backend gives it (on a TPU the
+    ``kda_chunk`` kernel; with ``interpret`` the kernel in the Pallas
+    interpreter), against the XLA body in the same two calls and
+    against the recurrence a token at a time. -> the largest relative
+    errors of o and S between each two of the three, and whether the
+    layer's own segment (``solar.kda_segment``) compiles to a program
+    with the kernel in it (``in_program``: the backend's and the
+    shape's choice)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import solar
+    from ray_tpu.ops import kda_chunk as kc
+    from ray_tpu.ops.kda_step import kda_recurrence
+
+    accelerator.claim_device()
+    kw = dict(n_layers=2, vocab_size=1024, n_experts=8, top_k=2)
+    cfg = solar.SolarConfig.tiny(**kw, dtype="bfloat16") \
+        if widths == "tiny" else solar.SolarConfig(**kw)
+    p = solar.init_params(cfg, jax.random.PRNGKey(seed))["layers"][1]["attn"]
+    state = solar.kda_empty(cfg, 1)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (1, rows, cfg.d_model), cfg.compute_dtype)
+    kernel = functools.partial(kc.kda_chunk, chunk=cfg.kda_chunk, **(
+        {"interpret": True} if interpret else {}))
+    body = functools.partial(kc.kda_chunked, chunk=cfg.kda_chunk)
+
+    @jax.jit
+    def three(x):
+        *qkvgb, _ = solar._kda_inputs(cfg, p, x, state["conv"])
+        half = rows // 2
+        found = {}
+        for name, form in (("kernel", kernel), ("body", body)):
+            o1, s = form(*(a[:, :half] for a in qkvgb), state["s"])
+            o2, s = form(*(a[:, half:] for a in qkvgb), s)
+            found[name] = (jnp.concatenate([o1, o2], axis=1), s)
+        s, o = jax.lax.scan(lambda s, xs: kda_recurrence(s, *xs), state["s"],
+                            tuple(jnp.moveaxis(a, 1, 0) for a in qkvgb))
+        found["recurrence"] = (jnp.moveaxis(o, 0, 1), s)
+        return found
+
+    def rel(a, b):
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    found = three(x)
+    text = jax.jit(functools.partial(solar.kda_segment, cfg, p)).lower(
+        x[:, :rows // 2], state, 0, jnp.array([rows])).compile().as_text()
+    return {"rel_err": {
+                f"{a}_{b}": {"out": rel(found[a][0], found[b][0]),
+                             "state": rel(found[a][1], found[b][1])}
+                for a, b in (("kernel", "body"), ("kernel", "recurrence"),
+                             ("body", "recurrence"))},
+            "in_program": any(
+                KERNEL in line and "kda_chunk" in line.split(" = ")[0]
+                for line in text.splitlines()),
+            "rows": rows, "heads": cfg.n_heads,
+            "device": accelerator.device_report()}
+
+
 def ring_check(widths: str, steps: int, seed: int,
                interpret: bool = False) -> dict:
     """Runs in a child that holds the chip: a sliding-window layer's
@@ -1399,6 +1479,16 @@ def hybrid_phase(plan: Plan) -> dict:
           "state, an inactive slot's state moved, or the layer's step "
           "holds no kernel on the chip", got=kda,
           tolerance=KDA_KERNEL_TOLERANCE)
+    chunk = chip_child(plan, "kda_chunk_check", {
+        "widths": plan.hybrid_widths, "rows": plan.kda_chunk_rows,
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, chunk["device"], 1, "kda chunk child")
+    check(max(v for pair in chunk["rel_err"].values()
+              for v in pair.values()) <= KDA_CHUNK_TOLERANCE
+          and chunk["in_program"] == plan.on_tpu,
+          "the kda_chunk kernel, the XLA body and the recurrence part on "
+          "a KDA layer's rows, or the layer's segment holds no kernel on "
+          "the chip", got=chunk, tolerance=KDA_CHUNK_TOLERANCE)
     latent = chip_child(plan, "latent_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.latent_lens),
         "seed": plan.seed})
@@ -1425,6 +1515,8 @@ def hybrid_phase(plan: Plan) -> dict:
             "latent": latent["rel_err"],
             "kda_kernel": {k: kda[k] for k in (
                 "rel_err", "inactive_kept", "in_program", "steps")},
+            "kda_chunk": {k: chunk[k] for k in (
+                "rel_err", "in_program", "rows", "heads")},
             "tolerance": HYBRID_TOLERANCE,
             "compile_s": out["device"]["compile"]["seconds"]}
 

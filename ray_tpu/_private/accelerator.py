@@ -1,8 +1,9 @@
 """Who may touch the TPU, decided in one place.
 
-A chip belongs to one process at a time: the first process that
-initialises a JAX TPU backend takes every chip it can see, and any later
-process fails, hangs, or comes up on the CPU. So the node agent decides
+A chip belongs to one process at a time, *and a dead one's for some
+seconds more*: the first process that initialises a JAX TPU backend
+takes every chip it can see, and any later process fails, hangs, or
+comes up on the CPU. So the node agent decides
 at SPAWN time, from the ``TPU`` amount of the work the worker is for
 (:func:`worker_env`): a worker with a grant sees exactly its chips and
 must come up on them, every other worker is pinned to the CPU platform.
@@ -12,6 +13,17 @@ a platform in code. Processes that compile for the chip call
 when a granted process is not on the TPU and places the persistent
 compile cache; :func:`device_report` is what their reports carry.
 
+A process in exit keeps its chips while the kernel closes them: the
+worker of a four-chip job stood 13-21 s as a zombie leader (``Zl``, no
+fd table left to read) after its ``benchmark.run`` had returned, and the
+next job's worker, which reaches ``open(/dev/vfio/N)`` some 13 s after
+it starts, died of ``Device or resource busy`` (``PERF.md`` section
+7(e)). Both ends wait for it, bounded by :data:`CHIP_WAIT_S` and polled
+every 50 ms: :func:`claim_device` before it initialises (a granted
+process; :func:`node_busy` is how it learns), and the agent's ``stop``
+for the workers it gave chips to. Whoever waited over 50 ms says so: a
+``chip_wait`` mark of kind ``accel`` and one line on stderr.
+
 The driver is the user's process: the agent never spawned it, so it is
 the user's call whether it computes (then nothing else on the node may
 hold a chip) or only orchestrates (then it should stay off JAX or set
@@ -20,9 +32,12 @@ hold a chip) or only orchestrates (then it should stay off JAX or set
 
 from __future__ import annotations
 
+import errno
 import glob
 import math
 import os
+import sys
+import time
 
 # chips handed to this worker by its node agent ("0" or "2,3"); unset or
 # empty on a grantless worker
@@ -30,6 +45,12 @@ GRANT_ENV = "RAY_TPU_GRANTED_CHIPS"
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
+
+# how long a chip that a process in exit still holds is waited for (by a
+# claim, by the agent's stop; ``benchmark/cluster.py``'s own figure), and
+# how often the waiter looks
+CHIP_WAIT_S = 60.0
+CHIP_POLL_S = 0.05
 
 # libtpu's chip bounds for a process that owns a SUBSET of the host's
 # chips (a whole-host grant keeps the host's own topology variables)
@@ -94,6 +115,61 @@ def granted_chips() -> tuple[int, ...]:
     return tuple(int(c) for c in raw.split(",") if c)
 
 
+def granted_nodes() -> list[str]:
+    """The device nodes of :func:`granted_chips`, by index into
+    :func:`chip_device_paths` (``TPU_VISIBLE_CHIPS`` counts the same
+    way: chip 2 is ``/dev/vfio/2``)."""
+    chips = granted_chips()
+    nodes = chip_device_paths() if chips else []
+    return [nodes[c] for c in chips if c < len(nodes)]
+
+
+def node_busy(path: str) -> bool:
+    """Whether some process holds this chip's node: opened read-write
+    and closed at once. A vfio group opens once, so ``EBUSY`` is "held",
+    by a process in exit too, which no ``/proc/<pid>/fd`` shows. Any
+    other error (no such node, no right to it) counts as free: a box
+    without chips behaves as it did."""
+    try:
+        os.close(os.open(path, os.O_RDWR | os.O_CLOEXEC))
+    except OSError as e:
+        return e.errno == errno.EBUSY
+    return False
+
+
+def report_chip_wait(t0: float, nodes: list[str], **attrs) -> float:
+    """Tells of a wait for ``nodes`` that began at ``t0`` (monotonic), if
+    it was over one poll: the ``chip_wait`` mark and a line on stderr.
+    -> the milliseconds waited."""
+    waited_ms = (time.monotonic() - t0) * 1e3
+    if waited_ms > CHIP_POLL_S * 1e3:
+        from ray_tpu._private import flight_recorder
+
+        flight_recorder.mark("accel", "chip_wait", attrs={
+            "waited_ms": round(waited_ms, 1), "nodes": ",".join(nodes),
+            **attrs})
+        print(f"[accel] pid {os.getpid()} waited {waited_ms:.0f} ms for "
+              f"{' '.join(nodes)}" + "".join(
+                  f" {k}={v}" for k, v in attrs.items()),
+              file=sys.stderr, flush=True)
+    return waited_ms
+
+
+def wait_nodes_free(nodes: list[str]) -> list[str]:
+    """Returns once none of ``nodes`` is busy, within a poll of the last
+    one's release, or after :data:`CHIP_WAIT_S`. -> those still busy."""
+    t0 = time.monotonic()
+    busy = [n for n in nodes if node_busy(n)]
+    if not busy:
+        return []
+    waited_for = busy
+    while busy and time.monotonic() - t0 < CHIP_WAIT_S:
+        time.sleep(CHIP_POLL_S)
+        busy = [n for n in busy if node_busy(n)]
+    report_chip_wait(t0, waited_for)
+    return busy
+
+
 _compile_stats: dict | None = None
 
 
@@ -140,12 +216,27 @@ def claim_device() -> dict:
     computes with JAX on a worker: initialises the backend this process
     was spawned for and returns what it got. A process that was granted
     a chip and is not on the TPU raises — there is no CPU path to fall
-    back to silently."""
+    back to silently. A granted chip that another process still holds
+    (the worker of the job before, in exit) is waited for first: a busy
+    device node is fatal to the backend's initialisation."""
     global _claim
     import jax
 
     _place_compile_cache()
-    devices = jax.devices()
+    busy, nodes = [], granted_nodes()
+    if nodes:
+        # (not its own: where this process has touched the device
+        # already, they read busy for as long as it lives)
+        mine = _held_nodes(os.getpid(), set(nodes))
+        busy = wait_nodes_free([n for n in nodes if n not in mine])
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        if not busy:
+            raise
+        raise RuntimeError(
+            f"worker {os.getpid()}: {' '.join(busy)} still held by another "
+            f"process after {CHIP_WAIT_S:.0f} s: {e}") from e
     _claim = {
         "pid": os.getpid(),
         "platform": devices[0].platform,
@@ -201,10 +292,17 @@ def _held_nodes(pid: int | str, nodes: set[str]) -> list[str]:
 def chip_holders() -> dict[int, list[str]]:
     """pid -> chip device nodes it holds open, from ``/proc`` (needs no
     JAX, so a process that must stay off the chip can still check who is
-    on it)."""
+    on it). A process in exit has no fd table to read and still holds
+    its chips until the kernel has closed them: a node that is busy
+    while no table shows it is reported under pid 0."""
     nodes = set(chip_device_paths())
     if not nodes:
         return {}
     holders = {int(pid): _held_nodes(pid, nodes)
                for pid in os.listdir("/proc") if pid.isdigit()}
-    return {pid: held for pid, held in holders.items() if held}
+    holders = {pid: held for pid, held in holders.items() if held}
+    shown = {n for held in holders.values() for n in held}
+    in_exit = sorted(n for n in nodes - shown if node_busy(n))
+    if in_exit:
+        holders[0] = in_exit
+    return holders

@@ -247,8 +247,13 @@ class LocalCluster:
         self.agent_port = self.io.run(self.agent.start())
 
     def stop(self):
+        from ray_tpu._private import accelerator
+
         try:
-            self.io.run(self.agent.stop(), timeout=10)
+            # (the agent waits for the workers it gave chips to: they
+            # keep them for some seconds after they died)
+            self.io.run(self.agent.stop(),
+                        timeout=accelerator.CHIP_WAIT_S + 10)
             self.io.run(self.cp.stop(), timeout=10)
         except Exception:
             pass

@@ -133,6 +133,10 @@ def _env_hash(runtime_env: dict | None):
     ).hexdigest()
 
 
+# what a worker gets between SIGTERM and SIGKILL
+_TERM_GRACE_S = 2.0
+
+
 class WorkerHandle:
     def __init__(self, worker_id: bytes, proc: subprocess.Popen):
         self.worker_id = worker_id
@@ -325,6 +329,12 @@ class NodeAgent:
         return port
 
     async def stop(self):
+        """Kills every worker and returns once those that were given a
+        chip are GONE (reaped: a dead worker keeps its chips while the
+        kernel closes them, 13-21 s for four, and the next job on this
+        host would die of a busy device node), bounded by
+        ``accelerator.CHIP_WAIT_S``. Workers without chips are killed
+        and not waited for."""
         self._dead = True
         for t in self._bg:
             t.cancel()
@@ -338,12 +348,49 @@ class NodeAgent:
             for t in pending:
                 t.cancel()
             await asyncio.gather(*pending, return_exceptions=True)
+        await self._chip_workers_gone()
         if self.head is not None:
             await self.head.close()
         for c in self._peer_clients.values():
             await c.close()
         await self.server.stop()
         self.store.close()
+
+    async def _chip_workers_gone(self) -> None:
+        """Reaps the processes in ``_chip_procs`` off the loop: a zombie
+        leader (``Zl``) is reaped only when its last thread has closed
+        its files, the chips' nodes among them."""
+        held = {}  # process -> its chips
+        for c, proc in self._chip_procs.items():
+            if proc is not None and proc.returncode is None:
+                held.setdefault(proc, []).append(c)
+        if not held:
+            return
+
+        t0 = time.monotonic()
+        bound = accelerator.CHIP_WAIT_S
+
+        def reap(proc):
+            # (stop() cancels the escalation of a worker it has only just
+            # signalled before that task ran at all: the SIGKILL is here)
+            try:
+                return proc.wait(timeout=min(_TERM_GRACE_S, bound))
+            except subprocess.TimeoutExpired:
+                proc.kill()
+            try:
+                proc.wait(timeout=max(0.0, t0 + bound - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                logger.warning("worker %s still holds its chips %.0f s after "
+                               "it was killed", proc.pid, bound)
+
+        loop = asyncio.get_running_loop()
+        await asyncio.gather(*(loop.run_in_executor(None, reap, proc)
+                               for proc in held))
+        nodes = accelerator.chip_device_paths()
+        accelerator.report_chip_wait(
+            t0, [nodes[c] if c < len(nodes) else f"chip{c}"
+                 for chips in held.values() for c in sorted(chips)],
+            leaving=True)
 
     def _on_node_dead_push(self, payload):
         nid = payload["node_id"]
@@ -1066,7 +1113,7 @@ class NodeAgent:
                 # shutdown) lands promptly, and kill in ``finally`` so a
                 # cancelled escalation still never leaks the process.
                 try:
-                    deadline = time.monotonic() + 2.0
+                    deadline = time.monotonic() + _TERM_GRACE_S
                     while time.monotonic() < deadline:
                         if proc.poll() is not None:
                             return

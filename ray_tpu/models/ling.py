@@ -17,11 +17,14 @@ are ever sliced out of a stack.
   float32 state ``S [H, dk, dv]`` a stream: ``S <- Diag(a) S``,
   ``S <- S + beta k (v - S^T k)^T``, ``o = S^T q``; a head-wise RMS norm
   gated by ``sigmoid(x W_g)``. A decode step is that recurrence
-  (:func:`kda_step`); prefill is its chunkwise form (:func:`kda_chunked`,
-  chunks of ``kda_chunk``) and leaves the same ``S`` and the same last
-  ``conv_kernel - 1`` convolution inputs. No position encoding. A
-  second block runs this code at 64 heads (``models/solar.py``:
-  :func:`kda_qkv`, :func:`kda_chunked`, ``_kda_out``, ``ops/kda_step``);
+  (:func:`kda_step`); prefill is its chunkwise form
+  (``ops/kda_chunk.py``, chunks of ``kda_chunk``: on a TPU where a head
+  is whole lanes ONE Pallas kernel a layer, ``kda_chunk``; elsewhere,
+  and where it is differentiated, the XLA body :func:`kda_chunked`) and
+  leaves the same ``S`` and the same last ``conv_kernel - 1``
+  convolution inputs. No position encoding. A second block runs this
+  code at 64 heads (``models/solar.py``: :func:`kda_qkv`, ``_kda_out``,
+  ``ops/kda_chunk``, ``ops/kda_step``);
   the decay's two forms are told apart in the blocks' ``_kda_inputs``
   (this one's bounded by ``kda_lower_bound``, that one's ``-exp(A_log)
   softplus``, unbounded below), never in the shared functions.
@@ -58,7 +61,6 @@ serves it.
 from __future__ import annotations
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -69,13 +71,14 @@ from ray_tpu.models.moe import draw as _draw
 from ray_tpu.models.moe import moe, prefill_loads, routing_counts
 from ray_tpu.models.moe import route  # noqa: F401 — the name the tests know
 from ray_tpu.models.moe import swiglu as _swiglu
+from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
+from ray_tpu.ops.kda_chunk import kda_chunked  # noqa: F401 — the chunkwise
+# form's XLA body (the tests' second opinion), under this module's name
 from ray_tpu.ops.kda_step import kda_recurrence  # noqa: F401 — the
 # recurrence kda_chunked is the chunkwise form of, under this module's name
 from ray_tpu.ops.kda_step import kda_step as _kda_step
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rotary, rotary_embedding
-
-_HI = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -334,79 +337,6 @@ def kda_step(cfg: LingConfig, p, x, state, active):
     return _kda_out(cfg, p, o[:, None], gate), new
 
 
-def _unit_lower_inverse(n):
-    """(I + n)^-1 for strictly lower-triangular n [..., C, C], by
-    forward substitution a row at a time in float32 elementwise
-    arithmetic (C steps, each over every chunk and head at once)."""
-    c = n.shape[-1]
-    eye = jnp.broadcast_to(jnp.eye(c, dtype=n.dtype), n.shape)
-
-    def row(i, t):
-        n_i = jax.lax.dynamic_index_in_dim(n, i, axis=-2, keepdims=False)
-        r = jax.lax.dynamic_index_in_dim(eye, i, axis=-2, keepdims=False) \
-            - jnp.sum(n_i[..., :, None] * t, axis=-2)
-        return jax.lax.dynamic_update_index_in_dim(t, r, i, axis=-2)
-
-    return jax.lax.fori_loop(1, c, row, eye)
-
-
-def kda_chunked(cfg, q, k, v, g, beta, s0):
-    """The chunkwise form of :func:`kda_recurrence` over T tokens (T a
-    multiple of ``kda_chunk``). q, k, g [B, T, H, dk], v [B, T, H, dv],
-    beta [B, T, H], all float32; s0 [B, H, dk, dv]. A token with
-    ``beta`` 0 and ``g`` 0 leaves the state as it was (padding).
-    -> (o [B, T, H, dv], the state after the last token).
-
-    Inside a chunk, with G the running sum of g from the chunk's start:
-    the pseudo-values U solve (I + Diag(beta) A) U = Diag(beta) (V -
-    (K * e^G) S0) with A[i, j] = sum_c k_i k_j e^(G_i - G_j) for j < i;
-    O = (Q * e^G) S0 + B U with B[i, j] = sum_c q_i k_j e^(G_i - G_j)
-    for j <= i; S' = Diag(e^G_C) S0 + (K * e^(G_C - G))^T U. The decays
-    are taken pairwise, e^(G_i - G_j) <= 1, never as e^-G_j, which
-    overflows float32 within a chunk at this model's lower bound."""
-    b, t, h, dk = q.shape
-    c = cfg.kda_chunk
-    nc = t // c
-
-    def chunks(a):  # [B, T, H, ...] -> [NC, B, H, C, ...]
-        a = a.reshape(b, nc, c, h, *a.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
-
-    q, k, v, g, beta = (chunks(a) for a in (q, k, v, g, beta))
-    gc = jnp.cumsum(g, axis=-2)  # [NC, B, H, C, dk]
-    lower = jnp.tril(jnp.ones((c, c), bool))
-
-    def pairwise(xs):
-        q_, k_, g_, beta_ = xs
-        diff = g_[..., :, None, :] - g_[..., None, :, :]  # [B,H,C,C,dk]
-        decay = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
-        kd = k_[..., None, :, :] * decay
-        a_ = jnp.sum(k_[..., :, None, :] * kd, -1)
-        b_ = jnp.sum(q_[..., :, None, :] * kd, -1)
-        # strictly lower for the solve: the diagonal pairs i with itself
-        n_ = beta_[..., None] * jnp.where(jnp.tril(lower, -1), a_, 0.0)
-        return n_, b_
-
-    n, bm = jax.lax.map(pairwise, (q, k, gc, beta))
-    tinv = _unit_lower_inverse(n)  # [NC, B, H, C, C]
-    g_end = gc[..., -1:, :]
-    kg, qg = k * jnp.exp(gc), q * jnp.exp(gc)
-    k_end = k * jnp.exp(g_end - gc)
-
-    def one(s, xs):
-        kg_, qg_, k_end_, v_, beta_, tinv_, bm_, g_end_ = xs
-        mm = functools.partial(jnp.matmul, precision=_HI)
-        u = mm(tinv_, beta_[..., None] * (v_ - mm(kg_, s)))
-        o = mm(qg_, s) + mm(bm_, u)
-        s = jnp.exp(g_end_)[..., 0, :, None] * s \
-            + mm(jnp.swapaxes(k_end_, -1, -2), u)
-        return s, o
-
-    s, o = jax.lax.scan(one, s0, (kg, qg, k_end, v, beta, tinv, bm, g_end))
-    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [B, NC, C, H, dv]
-    return o.reshape(b, t, h, -1), s
-
-
 def kda_prefill(cfg: LingConfig, p, x, true_lens):
     """A KDA layer over whole prompts from an empty state. x [B, T, D]
     (normed, right-padded; ``true_lens`` [B] real). -> ([B, T, D], the
@@ -425,8 +355,9 @@ def kda_prefill(cfg: LingConfig, p, x, true_lens):
             q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
                                         + ((0, 0),) * (a.ndim - 2))
                                 for a in (q, k, v, g, beta))
-        o, s = kda_chunked(cfg, q, k, v, g, beta,
-                           jnp.zeros((b, h, dk, dk), jnp.float32))
+        o, s = _kda_chunk(q, k, v, g, beta,
+                          jnp.zeros((b, h, dk, dk), jnp.float32),
+                          chunk=cfg.kda_chunk)
     with jax.named_scope("cache"):
         # the last K-1 projection rows of each prompt: u's rows
         # true_len .. true_len + K-2 (u starts K-1 rows before the prompt)

@@ -13,7 +13,7 @@ leaves and the programs unroll them.
 
 - **KDA** (Kimi Delta Attention, arXiv:2510.26692; ``fla/layers/kda.py``):
   what ``models/ling.py`` runs, shared with it (the convolution and the
-  norms ``ling.kda_qkv``, the chunkwise form ``ling.kda_chunked``, the
+  norms ``ling.kda_qkv``, the chunkwise form ``ops/kda_chunk.py``, the
   output ``ling._kda_out``, the decode step ``ops/kda_step.py``), in
   Kimi Linear's own parametrisation: log decay a channel ``g =
   -exp(A_log_h) * softplus(x W_f_down W_f_up + dt_bias)``, unbounded
@@ -77,6 +77,7 @@ from ray_tpu.models.decode_engine import _sample_from_logits
 from ray_tpu.models.moe import draw, moe, prefill_loads, routing_counts
 from ray_tpu.ops import decode_attention as _da
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
 from ray_tpu.ops.kda_step import kda_step as _kda_step
 from ray_tpu.ops.norms import rms_norm
 
@@ -346,7 +347,10 @@ def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
     before them left ({"s", "conv"}: zeros at a prompt's start). A
     padding row has beta 0 and g 0 and leaves ``S`` as it was, and the
     convolution rows kept are the last K-1 REAL ones: the state after a
-    prompt's last segment is the state after its last real token.
+    prompt's last segment is the state after its last real token. The
+    chunkwise delta rule is ``ops.kda_chunk``: on a TPU one kernel call
+    a segment that carries ``S`` on the chip from the segment's first
+    chunk to its last; the XLA body ``kda_chunked`` elsewhere.
     -> ([B, T, D], state)."""
     t = x.shape[1]
     kw = cfg.conv_kernel - 1
@@ -360,7 +364,8 @@ def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
             q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
                                         + ((0, 0),) * (a.ndim - 2))
                                 for a in (q, k, v, g, beta))
-        o, s = ling.kda_chunked(cfg, q, k, v, g, beta, state["s"])
+        o, s = _kda_chunk(q, k, v, g, beta, state["s"],
+                          chunk=cfg.kda_chunk)
     with jax.named_scope("cache"):
         # u's row j is position start - (K-1) + j: the last K-1 real
         # rows are j = n .. n + K-2 for n = the real rows in or before

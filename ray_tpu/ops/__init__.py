@@ -1,7 +1,9 @@
-"""TPU compute ops: norms, rotary embeddings, attention, losses.
+"""TPU compute ops: norms, rotary embeddings, attention, the delta
+rule's two forms, losses.
 
-The hot paths (attention) have pallas TPU kernels with jnp reference
-implementations used for CPU testing and as autodiff/numerics oracles.
+The hot paths (attention, the KDA layers' decode step and chunkwise
+prefill) have pallas TPU kernels with jnp reference implementations
+used for CPU testing and as autodiff/numerics oracles.
 """
 
 from ray_tpu.ops.norms import rms_norm  # noqa: F401

@@ -185,3 +185,170 @@ def test_who_holds_a_chip_is_read_from_proc(tmp_path, monkeypatch):
             str(node)]
         assert accelerator.chip_holders() == {os.getpid(): [str(node)]}
     assert accelerator.chip_holders() == {}
+
+
+class _Tpu:
+    """What ``jax.devices()`` gives a granted process on a chip."""
+    platform, device_kind, id = "tpu", "TPU v5 lite", 0
+
+
+@pytest.fixture
+def granted_node(tmp_path, monkeypatch):
+    """A process granted chip 0 of a host whose one device node is a
+    temp file (a plain file cannot be busy: the tests patch
+    ``node_busy``), JAX's answer a TPU's, the marks collected."""
+    import jax
+
+    from ray_tpu._private import flight_recorder
+
+    node = tmp_path / "vfio0"
+    node.write_bytes(b"")
+    marks = []
+    monkeypatch.setattr(accelerator, "chip_device_paths",
+                        lambda: [str(node)])
+    monkeypatch.setattr(accelerator, "_claim", None)
+    monkeypatch.setenv(accelerator.GRANT_ENV, "0")
+    monkeypatch.setattr(jax, "devices", lambda: [_Tpu()])
+    monkeypatch.setattr(
+        flight_recorder, "mark",
+        lambda kind, name, attrs=None, **kw: marks.append(
+            (kind, name, attrs)))
+    return str(node), marks
+
+
+def test_a_granted_claim_waits_for_a_chip_a_dying_process_holds(
+        granted_node, monkeypatch, capsys):
+    """The node reads busy for 0.3 s (a worker in exit still holds it):
+    the claim goes on within a poll of its release, and says how long it
+    waited; a free node costs one probe and says nothing."""
+    import threading
+    import time
+
+    node, marks = granted_node
+    probed = []
+    freed = threading.Event()
+
+    def busy(path):
+        probed.append(path)
+        return not freed.is_set()
+
+    monkeypatch.setattr(accelerator, "node_busy", busy)
+    threading.Timer(0.3, freed.set).start()
+    t0 = time.monotonic()
+    claim = accelerator.claim_device()
+    waited = time.monotonic() - t0
+    assert claim["platform"] == "tpu" and claim["granted_chips"] == [0]
+    assert 0.3 <= waited < 0.3 + 4 * accelerator.CHIP_POLL_S
+    assert set(probed) == {node} and len(probed) >= 4
+    (kind, name, attrs), = marks
+    assert (kind, name) == ("accel", "chip_wait")
+    assert attrs["nodes"] == node and "leaving" not in attrs
+    assert 300 <= attrs["waited_ms"] < 300 + 4e3 * accelerator.CHIP_POLL_S
+    assert f"for {node}" in capsys.readouterr().err
+
+    del probed[:], marks[:]
+    monkeypatch.setattr(accelerator, "_claim", None)
+    assert accelerator.claim_device()["platform"] == "tpu"
+    assert probed == [node] and marks == []
+    assert capsys.readouterr().err == ""
+
+
+def test_a_chip_busy_past_the_bound_fails_with_its_node_named(
+        granted_node, monkeypatch):
+    """Past the bound the backend initialises as it always did, and what
+    it raises of a busy node carries the node's name and the wait."""
+    import jax
+
+    node, marks = granted_node
+
+    def refused():
+        raise RuntimeError("Unable to initialize backend 'tpu': open(): "
+                           "Device or resource busy")
+
+    monkeypatch.setattr(accelerator, "CHIP_WAIT_S", 0.2)
+    monkeypatch.setattr(accelerator, "node_busy", lambda path: True)
+    monkeypatch.setattr(jax, "devices", refused)
+    with pytest.raises(RuntimeError) as e:
+        accelerator.claim_device()
+    assert node in str(e.value) and "still held" in str(e.value)
+    assert "Device or resource busy" in str(e.value)
+    assert marks[0][2]["waited_ms"] >= 200
+    # a refusal that is not about a held node is raised as it came
+    monkeypatch.setattr(accelerator, "node_busy", lambda path: False)
+    with pytest.raises(RuntimeError, match="^Unable to initialize"):
+        accelerator.claim_device()
+
+
+def test_a_grantless_claim_probes_nothing(monkeypatch):
+    """No grant: no node is opened, nothing is listed."""
+    def never(*a):
+        raise AssertionError("a grantless process looked at a chip")
+
+    monkeypatch.setattr(accelerator, "_claim", None)
+    monkeypatch.delenv(accelerator.GRANT_ENV, raising=False)
+    monkeypatch.setattr(accelerator, "node_busy", never)
+    monkeypatch.setattr(accelerator, "chip_device_paths", never)
+    assert accelerator.claim_device()["platform"] == "cpu"
+
+
+def test_a_claim_does_not_wait_for_a_node_it_holds_itself(
+        granted_node, monkeypatch):
+    """A process that touched the device before its claim holds its own
+    node: that one is not probed (it would read busy for ever)."""
+    node, marks = granted_node
+    monkeypatch.setattr(accelerator, "node_busy", lambda path: True)
+    monkeypatch.setattr(accelerator, "CHIP_WAIT_S", 0.2)
+    with open(node):
+        assert accelerator.claim_device()["platform"] == "tpu"
+    assert marks == []
+
+
+def test_a_busy_node_that_no_fd_table_shows_is_held_by_pid_0(
+        tmp_path, monkeypatch):
+    """A process in exit has no fd table and still holds its chips:
+    ``chip_holders`` reports the node, and nothing once it is free; a
+    node some table shows is that process's and is not probed."""
+    nodes = [str(tmp_path / f"vfio{i}") for i in range(2)]
+    for n in nodes:
+        open(n, "wb").close()
+    busy = {nodes[1]}
+    probed = []
+    monkeypatch.setattr(accelerator, "chip_device_paths", lambda: nodes)
+    monkeypatch.setattr(accelerator, "node_busy",
+                        lambda n: probed.append(n) or n in busy)
+    assert accelerator.chip_holders() == {0: [nodes[1]]}
+    with open(nodes[0]):
+        del probed[:]
+        assert accelerator.chip_holders() == {os.getpid(): [nodes[0]],
+                                              0: [nodes[1]]}
+        assert probed == [nodes[1]]
+    busy.clear()
+    assert accelerator.chip_holders() == {}
+
+
+def test_only_ebusy_reads_as_busy(tmp_path, monkeypatch):
+    """The probe opens the node read-write and closes it at once. A node
+    that does not exist or may not be opened counts as free (a box
+    without chips or rights behaves as it did); ``EBUSY`` alone is
+    "held"."""
+    import errno
+
+    node = tmp_path / "vfio0"
+    assert accelerator.node_busy(str(node)) is False  # no such node
+    node.write_bytes(b"")
+    before = set(os.listdir("/proc/self/fd"))
+    assert accelerator.node_busy(str(node)) is False
+    assert set(os.listdir("/proc/self/fd")) == before  # closed again
+    opened = []
+
+    def refuse(code):
+        def _open(path, flags):
+            opened.append((path, flags & os.O_ACCMODE))
+            raise OSError(code, os.strerror(code))
+        return _open
+
+    monkeypatch.setattr(os, "open", refuse(errno.EACCES))
+    assert accelerator.node_busy(str(node)) is False
+    monkeypatch.setattr(os, "open", refuse(errno.EBUSY))
+    assert accelerator.node_busy(str(node)) is True
+    assert opened == [(str(node), os.O_RDWR)] * 2

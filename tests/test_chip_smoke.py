@@ -208,6 +208,15 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys):
     assert max(kda["rel_err"].values()) <= chip_smoke.KDA_KERNEL_TOLERANCE
     assert kda["inactive_kept"] is True and kda["in_program"] is False
     assert kda["steps"] == TINY.kda_steps == 5
+    # the chunkwise delta rule's kernel (interpreted) against the XLA
+    # body and the recurrence, two calls with S carried
+    chunk = facts["kda_chunk"]
+    assert set(chunk["rel_err"]) == {"kernel_body", "kernel_recurrence",
+                                     "body_recurrence"}
+    assert max(v for pair in chunk["rel_err"].values()
+               for v in pair.values()) <= chip_smoke.KDA_CHUNK_TOLERANCE
+    assert chunk["in_program"] is False
+    assert chunk["rows"] == TINY.kda_chunk_rows == 32
     # the fourth block's gated MLA layer: the rows the two forms keep
     # are the same rows, the outputs agree within the tolerance
     assert set(facts["latent"]) == {"9", "21"}
@@ -267,6 +276,25 @@ def test_kda_kernel_check_rehearses_on_the_cpu():
     assert found["in_program"] is False
     same = chip_smoke.kda_kernel_check("tiny", 2, TINY.seed)
     assert same["rel_err"] == {"state": 0.0, "out": 0.0}
+
+
+def test_kda_chunk_check_rehearses_on_the_cpu():
+    """What ``hybrid_phase`` asks of the chunkwise delta rule's kernel
+    on the chip, at tiny widths (four heads, chunks of 8) with the kernel
+    in the Pallas interpreter: 32 rows in two calls, S carried, against
+    the XLA body and the recurrence a token at a time; and with nobody
+    asking for the kernel the path IS the XLA body."""
+    found = chip_smoke.kda_chunk_check("tiny", TINY.kda_chunk_rows,
+                                       TINY.seed, interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert set(found["rel_err"]) == {"kernel_body", "kernel_recurrence",
+                                     "body_recurrence"}
+    for pair in found["rel_err"].values():
+        assert set(pair) == {"out", "state"}
+        assert 0 < max(pair.values()) <= chip_smoke.KDA_CHUNK_TOLERANCE
+    assert found["in_program"] is False and found["heads"] == 4
+    same = chip_smoke.kda_chunk_check("tiny", 16, TINY.seed)
+    assert same["rel_err"]["kernel_body"] == {"out": 0.0, "state": 0.0}
 
 
 @pytest.mark.slow

@@ -202,7 +202,7 @@ def test_the_chunkwise_form_survives_a_decay_that_underflows_in_a_chunk():
     beta = jax.random.uniform(key[5], shape[:3], minval=1.5, maxval=2.0)
     s0 = jax.random.normal(key[0], (2, cfg.n_heads, 16, 16))
     assert float(jnp.cumsum(g, 1)[:, 7].min()) < -80
-    o, s = ling.kda_chunked(cfg, q, k, v, g, beta, s0)
+    o, s = ling.kda_chunked(q, k, v, g, beta, s0, chunk=cfg.kda_chunk)
     o_ref, s_ref = REF.kda_recurrence(q, k, v, g, beta, s0)
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(
         np.asarray(s)).all()

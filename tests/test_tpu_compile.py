@@ -208,6 +208,44 @@ def _whole_layer_ops(text: str, cfg, slots: int, rows: int) -> list:
     return found
 
 
+def _kda_chunk_calls(text: str, prefetched_ok: bool = False) -> list:
+    """The ``kda_chunk`` kernel's calls in a compiled prefill program
+    (``ops/kda_chunk.py``: one a KDA layer, inside the segment scan
+    where the prefill has one), checked for what the kernel is for: no
+    float32 array with a ``64, 64, 128`` tail is left (the pairwise
+    decays of a chunk's rows, which the XLA body makes whole), and XLA
+    added nothing that moves an operand on the call's account (a
+    ``copy``, an asynchronous copy or slice between memories: the
+    arrays are taken as the projections leave them, ``S`` in the buffer
+    the scan carries). ``prefetched_ok``: an operand of a few MB that
+    XLA's memory-space assignment brings into VMEM ahead of the call
+    (an asynchronous copy into ``S(1)``, its own choice for an array
+    that fits there, not a relayout) is let through."""
+    lines = text.splitlines()
+    calls = [ln for ln in lines
+             if KERNEL in ln and "kda_chunk" in ln.split(" = ")[0]]
+    assert not re.findall(r"f32\[[\d,]*64,64,128\]", text)
+    made_by = {m.group(1): m.group(2) for m in (
+        re.match(r"\s*(%[\w.\-]+) = .*?\s([\w\-]+)\(", ln) for ln in lines)
+        if m}
+    for call in calls:
+        assert re.search(r"attn/attn_linear/(jit\(_kda_chunk\)/)?kda_chunk/"
+                         "pallas_call", call), call[:300]
+        operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+        operands = re.findall(r"%[\w.\-]+", operands)
+        assert len(operands) == 6, operands
+        moved = {o: made_by.get(o) for o in operands if made_by.get(o) in (
+            "copy", "copy-done", "slice-done", "dynamic-slice-done",
+            "async-done", "transpose")}
+        if prefetched_ok:
+            moved = {o: op for o, op in moved.items() if not (
+                op == "copy-done" and re.search(
+                    re.escape(o) + r" = f32\[[\d,]+\]\{[^}]*S\(1\)\}", text))}
+        assert not moved, moved
+        assert "output_to_operand_aliasing={{1}: (5, {})}" in call, call[:600]
+    return calls
+
+
 def test_decode_chunk_compiles_at_1b_widths(topo):
     cfg = _serve_cfg()
     chip = SingleDeviceSharding(topo.devices[0])
@@ -461,6 +499,34 @@ def test_serving_programs_hold_no_cast_of_a_weight(topo, monkeypatch,
 # ---- the hybrid block (models/ling.py) at the reason cell's sizes ----
 
 
+def _ling_cell(topo, monkeypatch):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s model, engine
+    shape and arguments on one described chip, the kernels asked for by
+    name (the dispatches would read the CPU backend here)."""
+    from benchmark import manifest
+    from ray_tpu.models import ling
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_step", functools.partial(
+        ling._kda_step, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_chunk", functools.partial(
+        ling._kda_chunk, use_kernel=True))
+    with open("benchmark/traffic/reason-saturated.json") as f:
+        eng = json.load(f)["engine"]
+    fam, m = manifest.model("ling-3.0-flash-vl-ep4-1chip")
+    prog = fam.build(m, max_seq_len=eng["max_len"], remat=False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(prog.init_params,
+                                      jax.random.PRNGKey(0)))
+    state = _on(chip, jax.eval_shape(lambda: ling.SLOTS.init_state(
+        prog.cfg, eng["slots"], eng["max_len"])))
+    vec = lambda dt, n=eng["slots"]: jax.ShapeDtypeStruct(  # noqa: E731
+        (n,), dt, sharding=chip)
+    return fam, m, prog.cfg, eng, params, state, vec
+
+
 def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
         topo, monkeypatch):
     """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s decode program
@@ -470,27 +536,10 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
     the latent rows ``[32,3088,512]``); no matrix exists in float32 (the tree arrives in
     the serving types: a cast of one 250 M expert stack is 1 GB); and
     arguments and temporaries stay under 13 GiB of the chip's 16."""
-    from benchmark import manifest
     from ray_tpu.models import ling
-    from ray_tpu.ops import grouped_matmul as gm
 
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, use_kernel=True))
-    monkeypatch.setattr(ling, "_kda_step", functools.partial(
-        ling._kda_step, use_kernel=True))
-    with open("benchmark/traffic/reason-saturated.json") as f:
-        eng = json.load(f)["engine"]
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
     slots, max_len = eng["slots"], eng["max_len"]
-    fam, m = manifest.model("ling-3.0-flash-vl-ep4-1chip")
-    prog = fam.build(m, max_seq_len=max_len, remat=False)
-    cfg = prog.cfg
-    chip = SingleDeviceSharding(topo.devices[0])
-    params = _on(chip, jax.eval_shape(prog.init_params,
-                                      jax.random.PRNGKey(0)))
-    state = _on(chip, jax.eval_shape(
-        lambda: ling.SLOTS.init_state(cfg, slots, max_len)))
-    vec = lambda dt: jax.ShapeDtypeStruct(  # noqa: E731
-        (slots,), dt, sharding=chip)
     compiled = de.decode_chunk.lower(
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
@@ -536,6 +585,56 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
     print(f"\nling decode chunk: {_mem(compiled)}")
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 13 * 1024 * MIB), _mem(compiled)
+
+
+@pytest.mark.parametrize("bucket", [256, 512, 1024])
+def test_ling_prefill_holds_one_kda_chunk_call_a_kda_layer(
+        topo, monkeypatch, bucket):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s cold prefill
+    call at each of its buckets (32 heads, 4 to 16 chunks): the
+    chunkwise delta rule is one ``kda_chunk`` call a KDA layer, six a
+    program, on the arrays as the projections leave them
+    (``_kda_chunk_calls``); arguments and temporaries stay under 13 GiB
+    of the chip's 16."""
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
+    assert tuple(eng["prompt_buckets"]) == (256, 512, 1024)
+    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, bucket,
+                              (params, state, vec)).compile()
+    # (at 256 and 512 rows XLA prefetches a layer's 4 to 8 MB ``g`` into
+    # VMEM ahead of two of the calls; at 1,024 nothing moves)
+    calls = _kda_chunk_calls(compiled.as_text(), prefetched_ok=bucket < 1024)
+    assert len(calls) == sum(
+        cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
+    assert all(f"f32[1,{bucket},4096]" in c for c in calls), calls[0][:300]
+    mem = compiled.memory_analysis()
+    print(f"\nling prefill 1 x {bucket}: {_mem(compiled)}")
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 13 * 1024 * MIB), _mem(compiled)
+
+
+def test_lowering_lings_prefill_traces_the_kda_chunk_kernel_once(
+        topo, monkeypatch):
+    """What the kernel costs a process's start is its trace
+    (``ops/kda_chunk.py``: a thousand lines of columns, seconds each):
+    the call is jitted by itself, so lowering the 1,024-row prefill with
+    its six KDA layers runs the kernel's body ONCE, not once a layer,
+    and the lowered module holds one copy of the kernel that the six
+    layers call. (Traced a layer, Ling's set-up read 120-160 s for the
+    parent's 80-88: ``PERF.md`` §6, PRs 45-47.)"""
+    from ray_tpu.ops import kda_chunk as kc
+
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
+    traced = []
+    body = kc._kernel
+    monkeypatch.setattr(kc, "_kernel", lambda *a, **kw: (
+        traced.append(kw), body(*a, **kw))[1])
+    jax.clear_caches()  # (an earlier test's trace of this shape)
+    lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 1024,
+                             (params, state, vec))
+    assert traced == [{"hb": 16}], len(traced)
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @_kda_chunk\w*\(", text)) == 1
+    assert len(re.findall(r"call @_kda_chunk\w*\(", text)) == 6
 
 
 # ---- the block with window layers beside full ones (models/exaone.py)
@@ -772,6 +871,8 @@ def _solar_cell(topo, monkeypatch):
         da.decode_attention, use_kernel=True))
     monkeypatch.setattr(solar, "_kda_step", functools.partial(
         solar._kda_step, use_kernel=True))
+    monkeypatch.setattr(solar, "_kda_chunk", functools.partial(
+        solar._kda_chunk, use_kernel=True))
     with open("benchmark/traffic/longreason-saturated.json") as f:
         eng = json.load(f)["engine"]
     fam, m = manifest.model("solar-open2-250b-ep8-1chip")
@@ -853,7 +954,9 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     ``32768 x 32768`` scores, no ``[P, vocabulary]`` logits exist; the
     donated state is updated in place; beside 32 slots the call fits
     the chip's 16 GiB (temporaries 3,213 MiB: the stream in and out of
-    a layer and the GQA layer's q and o in both layouts, 537 MB each)."""
+    a layer and the GQA layer's q and o in both layouts, 537 MB each);
+    the chunkwise delta rule is ONE ``kda_chunk`` call a KDA layer
+    inside its segment scan (``_kda_chunk_calls``)."""
     from ray_tpu.models import solar
 
     fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
@@ -863,6 +966,11 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
                               (params, state, vec)).compile()
     text = compiled.as_text()
     assert text.count("flash_fwd") >= cfg.full_layers and "moe_gmm" in text
+    # the chunkwise delta rule: one kernel call a KDA layer, in the scan
+    calls = _kda_chunk_calls(text)
+    assert len(calls) == cfg.kda_layers == 3
+    assert all("/while/body/" in c and "f32[1,2048,8192]" in c
+               for c in calls), calls[0][:300]
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))
               for dt, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
                                          text)}
@@ -876,7 +984,8 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(
         solar.SLOTS.state_bytes(state).values()), _mem(compiled)
-    print(f"\nsolar prefill 1 x 32768: {_mem(compiled)}")
+    print(f"\nsolar prefill 1 x 32768: {_mem(compiled)} (temporaries "
+          "with the XLA body, PR 42: 3,213 MiB)")
     assert mem.temp_size_in_bytes < 3584 * MIB, _mem(compiled)
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 14.5 * 1024 * MIB), _mem(compiled)
@@ -1180,9 +1289,13 @@ def dump_serving_programs(out_dir: str) -> None:
     from ray_tpu.models import ling
     if hasattr(ling, "_kda_step"):  # (a parent before PR 41)
         ling._kda_step = functools.partial(ling._kda_step, use_kernel=True)
+    if hasattr(ling, "_kda_chunk"):  # (a parent before PR 47)
+        ling._kda_chunk = functools.partial(ling._kda_chunk, use_kernel=True)
     try:
         from ray_tpu.models import solar
         solar._kda_step = ling._kda_step
+        if hasattr(ling, "_kda_chunk"):
+            solar._kda_chunk = ling._kda_chunk
     except ImportError:  # (a parent before PR 42)
         pass
     topo = topologies.get_topology_desc(
