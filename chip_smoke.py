@@ -592,6 +592,7 @@ def prefill_parting(model: dict, kv_heads: list, seed: int, slots: int,
 
     from ray_tpu._private import accelerator
     from ray_tpu.models import decode_engine as de
+    from ray_tpu.models import llama_slots
     from ray_tpu.models import llama
     from ray_tpu.ops import flash_attention as fa
 
@@ -620,7 +621,7 @@ def prefill_parting(model: dict, kv_heads: list, seed: int, slots: int,
                     params, row, np.array([len(prompt)], np.int32),
                     np.array([slot], np.int32), np.zeros(1, np.uint32),
                     np.zeros(1, np.float32), np.ones(1, np.float32),
-                    de.init_ragged_cache(cfg, slots, max_len),
+                    llama_slots.init_ragged_cache(cfg, slots, max_len),
                     jnp.zeros((slots,), jnp.int32), cfg)
                 toks.append([int(tok0[0])])
                 rows.append(np.asarray(

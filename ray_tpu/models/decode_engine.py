@@ -15,11 +15,14 @@ boundaries — continuous batching at chunk granularity.
 Prefill runs per stream at a bucketed prompt length (one compile per
 bucket) into a temp slot-1 cache, then scatters into the slot's rows.
 
-What a slot HOLDS is the model's (:func:`slot_model`): for the Llama
-block rows of k and v, for a model with recurrent or latent layers
-whatever its own module says. The engine carries that state, donates
-it to its two programs and reads ``state["pos"]``; it looks at nothing
-else.
+What a slot HOLDS is the model's (:func:`slot_model`,
+``models/slots.py``): for the Llama block rows of k and v
+(``models/llama_slots.py``), for a model with recurrent or latent
+layers whatever its own module says. The engine carries that state,
+donates it to its two programs and reads ``state["pos"]``; it looks at
+nothing else, but in the three mechanisms that need a state of rows
+(:func:`require_rows`: :func:`decode_chunk_spec`, :func:`prefill_kv`,
+:func:`_adopt_kv_into_slot`), which are the Llama block's alone.
 """
 
 from __future__ import annotations
@@ -35,10 +38,11 @@ import numpy as np
 
 from ray_tpu._private import flight_recorder as _fr
 from ray_tpu._private import trace as _trace
-from ray_tpu.models import llama, mlp
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.ops import decode_attention as _da
-from ray_tpu.ops.decode_attention import attend_ragged as _attend_ragged  # noqa: F401 — the XLA body, under the name the tests know
+# (the Llama block's half, for the three mechanisms that need a state of
+# rows: decode_chunk_spec, prefill_kv, _adopt_kv_into_slot; nothing else
+# here knows a block)
+from ray_tpu.models import llama_slots, mlp
+from ray_tpu.ops.sampling import sample_from_logits
 
 
 _metrics = None
@@ -72,11 +76,9 @@ def _get_metrics():
 
 
 def slot_model(cfg):
-    """The model's half of the engine, found from its configuration: a
-    configuration that is no ``LlamaConfig`` carries its own as
-    ``cfg.slot_model``; the Llama block's is :class:`_LlamaSlots`, whose
-    docstring is the protocol."""
-    return getattr(cfg, "slot_model", _LlamaSlots)
+    """The model's half of the engine (``models/slots.py`` is the
+    protocol), which every block's configuration carries."""
+    return cfg.slot_model
 
 
 def require_rows(cfg, mechanism: str) -> None:
@@ -91,197 +93,9 @@ def require_rows(cfg, mechanism: str) -> None:
             "layers, or a ring of rows) and cannot be cut at a position")
 
 
-def init_ragged_cache(cfg: LlamaConfig, slots: int, max_len: int) -> dict:
-    """The Llama block's slot state: k and v stacks [L, slots, max_len,
-    Hkv * hd] in the compute dtype, a row the position's kv heads laid
-    end to end (the layout ``ops/decode_attention.py`` reads in place:
-    a head is whole lanes of a block of rows), and each slot's filled
-    length."""
-    shape = (cfg.n_layers, slots, max_len, cfg.n_kv_heads * cfg.head_dim)
-    cdt = cfg.compute_dtype
-    return {
-        "k": jnp.zeros(shape, cdt),
-        "v": jnp.zeros(shape, cdt),
-        "pos": jnp.zeros((slots,), jnp.int32),  # per-slot filled length
-    }
-
-
-def _kv_rows(rows):
-    """k or v rows [..., Hkv, hd] as the stack holds them:
-    [..., Hkv * hd]."""
-    return rows.reshape(*rows.shape[:-2], -1)
-
-
-def _layer_ragged(cfg: LlamaConfig, h, p, sin, cos, k, v, layer, pos,
-                  lengths, plan, aux: dict | None = None):
-    """One layer over T rows a slot at PER-SLOT positions, on the STACKED
-    cache. h: [B, T, D] (T == 1: a decode step; T == K+1: the
-    speculative verify, the current token plus the K drafted ones);
-    k/v: [L, B, S, Hkv * hd], the whole cache; pos: [B], each slot's
-    base position; lengths: [B], the rows of a slot that hold something
-    once this layer's are written (pos + T; 0: the slot is inactive),
-    and ``plan`` the kernel's visits for them (made once a step).
-    The layer writes its B x T new rows at [layer, slot, pos..pos+T-1]
-    into the stack it was given (a scatter of rows: nothing else of the
-    cache moves) and attends over the stack's ``layer`` in place, each
-    slot up to its own length with a per-query causal mask
-    (``ops.decode_attention``: on a TPU the ``decode_attn`` kernel,
-    which reads only blocks that hold a row; elsewhere the XLA body
-    over ``stack[layer]``), so a T-wide pass computes exactly T
-    sequential one-row steps in one layer sweep. Returns (h, k, v), the
-    stacks updated."""
-    b, t, _ = h.shape
-    q, k_new, v_new = llama._qkv(cfg, p, h, sin, cos)  # [B, T, H*, hd]
-    with jax.named_scope("cache"):
-        rows = jnp.arange(b)[:, None]
-        cols = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-        k = k.at[layer, rows, cols].set(_kv_rows(k_new))
-        v = v.at[layer, rows, cols].set(_kv_rows(v_new))
-    with jax.named_scope("attn"):
-        o = _da.decode_attention(q, k, v, layer, lengths, plan=plan)
-    return llama._attn_out_and_mlp(cfg, p, h, o, aux), k, v
-
-
-def _layers_ragged(cfg: LlamaConfig, layers, attach, h, sin, cos, k, v,
-                   pos, active=None):
-    """The one layer loop of the chunk programs, and how the cache
-    travels through it: the stacked k and v are loop STATE beside h and
-    the layer's index, and only the layer parameters (``layers``, as
-    ``llama.split_layers`` gives them with ``attach``) are scanned. As a
-    scan's xs and ys each layer's [B, S, Hkv, hd] would be sliced out of
-    the stack and written back whole around B new rows (two copies a
-    layer and step); as state the stack stays where it lies
-    (:func:`_layer_ragged`). The loop runs as many layers as ``layers``
-    holds (the draft's: the first few). With ``active`` [B], an inactive
-    slot's rows are not attended over (its length is 0, its attention
-    output zeros), and a model that reports its routing also returns
-    ``experts_touched`` [L] (see ``_experts_touched``). Returns
-    (h, k, v, *touched)."""
-    routed = active is not None and llama.reports_routing(cfg)
-    # the same for every layer of the step: made here, not in the body
-    with jax.named_scope("attn"):
-        lengths = pos + h.shape[1]
-        if active is not None:
-            lengths = jnp.where(active, lengths, 0)
-        plan = _da.visits(lengths, k.shape[2])
-
-    def body(carry, p_):
-        h_, k_, v_, layer = carry
-        aux = {} if routed else None
-        h_, k_, v_ = _layer_ragged(
-            cfg, h_, attach(p_), sin, cos, k_, v_, layer, pos, lengths,
-            plan, aux)
-        return (h_, k_, v_, layer + 1), _experts_touched(cfg, aux, active)
-
-    (h, k, v, _), touched = jax.lax.scan(
-        body, (h, k, v, jnp.int32(0)), layers)
-    return h, k, v, *touched
-
-
-@jax.named_scope("moe_router")
-def _experts_touched(cfg: LlamaConfig, aux: dict | None, active) -> tuple:
-    """What a layer adds to its scan's outputs for the routing counters:
-    ``()`` for a model that reports no routing (its program is the one
-    it was), else the number of distinct experts that got a row from an
-    ACTIVE slot in this layer (an int32 scalar)."""
-    if aux is None:
-        return ()
-    hit = jax.nn.one_hot(aux["expert_ids"], cfg.n_experts, dtype=jnp.bool_)
-    hit = hit & active[:, None, None, None]  # ids are [B, T, top_k]
-    return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),)
-
-
-@jax.named_scope("sample")
-def _sample_from_logits(logits, seeds, pos, temps, top_ps):
-    """Per-slot stateless sampling lane: the RNG key for the token
-    emitted from position `pos` of a stream is
-    fold_in(PRNGKey(seed), pos) — a pure function of (request seed,
-    sequence position), independent of slot index, batch composition,
-    and admission timing. That independence is what makes seed-replay
-    bit-exact: a replica-death failover re-decodes the same prompt with
-    the same seed on ANY replica and reproduces the identical token
-    sequence, so the pool's emitted-offset dedup survives sampling.
-
-    logits [B, V] f32; seeds [B] uint32; pos/temps/top_ps [B].
-    temperature == 0 selects the greedy token (bit-identical to the
-    legacy argmax path); its logprob is reported under the unscaled
-    distribution. Returns ([B] int32 tokens, [B] f32 logprobs under the
-    ACTUAL sampling distribution — temperature-scaled and
-    top-p-renormalized — i.e. the behavior policy an RL learner must
-    importance-correct against)."""
-    keys = jax.vmap(
-        lambda s, p: jax.random.fold_in(jax.random.PRNGKey(s), p)
-    )(seeds, pos)
-
-    def one(key, row, temp, top_p):
-        greedy = jnp.argmax(row)
-        greedy_lp = jax.nn.log_softmax(row)[greedy]
-        scaled = row / jnp.maximum(temp, 1e-6)
-        order = jnp.argsort(-scaled)
-        srt = scaled[order]
-        probs = jax.nn.softmax(srt)
-        cum = jnp.cumsum(probs)
-        # smallest set of tokens whose mass reaches top_p (the exclusive
-        # cumsum keeps at least the top token even for tiny top_p)
-        keep_sorted = (cum - probs) < top_p
-        keep = jnp.zeros_like(keep_sorted).at[order].set(keep_sorted)
-        filt = jnp.where(keep, scaled, -jnp.inf)
-        # TOKEN-space Gumbel-argmax (categorical's own construction,
-        # unsorted): the noise attached to token id v is a pure function
-        # of (key, v). The speculative draft (decode_chunk_spec) samples
-        # its proposal on the SAME lane key as the verify's token, so
-        # shared noise makes them agree whenever the two distributions
-        # are close — sampling over the SORTED vector would attach noise
-        # to ranks instead and decouple the draft whenever the orderings
-        # differ, collapsing the acceptance rate.
-        g = jax.random.gumbel(key, filt.shape)
-        sampled = jnp.argmax(filt + g)
-        lp = jax.nn.log_softmax(filt)[sampled]
-        use = temp > 0.0
-        return (jnp.where(use, sampled, greedy).astype(jnp.int32),
-                jnp.where(use, lp, greedy_lp))
-
-    return jax.vmap(one)(keys, logits, temps, top_ps)
-
-
-def _split_model(cfg: LlamaConfig, params):
-    """What a chunk program prepares once: the layers as the layer loop
-    scans them (``llama.split_layers``: (layers, attach)) and the
-    unembedding in the compute dtype (as it lies in the engine's serving
-    tree; a program handed f32 masters casts it here)."""
-    w_out = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(cfg.compute_dtype)
-    return *llama.split_layers(cfg, params["layers"]), w_out
-
-
-def _step_logits(cfg: LlamaConfig, params, layers, attach, w_out, toks, k,
-                 v, pos, qpos, active=None, before_norm=None):
-    """The one model step of the chunk programs: T tokens a slot through
-    ``layers`` (all of them, or the draft's first few) on the stacked
-    cache. toks: [B, T] at positions qpos [B, T], where
-    qpos[:, 0] == pos [B], the slots' base positions; ``before_norm``
-    is applied between the layers and the final norm (the draft's
-    adapter head). Returns (float32 logits [B, T, V], k, v, *touched):
-    see :func:`_layers_ragged` for the stacks and ``active``."""
-    with jax.named_scope("qkv"):
-        sin, cos = llama.rotary_embedding(qpos, cfg.head_dim,
-                                          cfg.rope_theta)
-    with jax.named_scope("embed"):
-        h = params["embed"].astype(cfg.compute_dtype)[toks]  # [B, T, D]
-    h, k, v, *touched = _layers_ragged(
-        cfg, layers, attach, h, sin, cos, k, v, pos, active)
-    with jax.named_scope("lm_head"):
-        if before_norm is not None:
-            h = before_norm(h)
-        h = llama.rms_norm(h, params["final_norm"], cfg.rms_eps)
-        return (h @ w_out).astype(jnp.float32), k, v, *touched
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "chunk"),
                    donate_argnames=("cache", "tok"))
-def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
-                 chunk: int):
+def decode_chunk(params, cache, tok, active, lanes, cfg, chunk: int):
     """Advance every ACTIVE slot `chunk` tokens inside one jit.
 
     tok: [B] current token per slot; active: [B] bool. ``lanes`` is
@@ -294,7 +108,7 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
     a later prefill overwrites). The donated cache (the model's slot
     state, :func:`slot_model`) is loop state of the step loop and, for
     the Llama block, inside it of the layer loop
-    (:func:`_layers_ragged`): a step writes B rows a layer into the
+    (``llama_slots._layers_ragged``): a step writes B rows a layer into the
     stack and reads, of that layer, each active slot's rows up to its
     own length (``ops.decode_attention``); no layer's cache is sliced
     out or written back, and none is repeated for its query group.
@@ -302,7 +116,7 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
     ([B, chunk] tokens, [B, chunk] f32 logprobs or without lanes
     ``None``, new cache, [B] last token) and, for a model that reports
     its routing, its step counters, [chunk, L] each (the Llama block:
-    ``experts_touched``, see ``_experts_touched``)."""
+    ``experts_touched``, see ``llama_slots._experts_touched``)."""
     model = slot_model(cfg)
     max_len = model.max_len(cache)
     prepared = model.split(cfg, params)
@@ -316,8 +130,8 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
                 nxt, lp = jnp.argmax(logits, axis=-1).astype(t.dtype), None
             else:
                 seeds, temps, top_ps = lanes
-                nxt, lp = _sample_from_logits(logits, seeds, pos, temps,
-                                              top_ps)
+                nxt, lp = sample_from_logits(logits, seeds, pos, temps,
+                                             top_ps)
             nxt = jnp.where(active, nxt, t)  # frozen slots hold their token
         # clamp: a slot that exhausts its cache rows mid-chunk (pump()
         # only frees slots at chunk boundaries) must keep scattering
@@ -341,7 +155,7 @@ def decode_chunk(params, cache, tok, active, lanes, cfg: LlamaConfig,
                                     "draft_layers"),
                    donate_argnames=("cache", "tok"))
 def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
-                      temps, top_ps, cfg: LlamaConfig, rounds: int,
+                      temps, top_ps, cfg, rounds: int,
                       depth: int, draft_layers: int):
     """Speculative chunk: `rounds` rounds of (K sequential DRAFT steps +
     ONE K+1-wide VERIFY forward), all inside one jit — one dispatch per
@@ -379,7 +193,7 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
     b = tok.shape[0]
     t_wide = depth + 1
     rows = jnp.arange(b)
-    layers, attach, w_out = _split_model(cfg, params)
+    layers, attach, w_out = llama_slots._split_model(cfg, params)
     # the draft scans the first layers of the same stack
     dlayers = jax.tree_util.tree_map(lambda a: a[:draft_layers], layers)
 
@@ -389,7 +203,7 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         # -- draft: K sequential 1-wide steps over the trunk layers --
         def draft_step(dc, _):
             dt, kd, vd, dpos = dc
-            logits, kd, vd = _step_logits(
+            logits, kd, vd = llama_slots._step_logits(
                 cfg, params, dlayers, attach, w_out, dt[:, None], kd, vd,
                 dpos, dpos[:, None],
                 before_norm=lambda h: mlp.apply_draft_head(draft_head, h))
@@ -398,8 +212,8 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
             # predecessor, so under sampling the draft and target draw
             # with shared Gumbel noise (agreement is higher than the
             # argmax overlap of their distributions)
-            d, _ = _sample_from_logits(logits[:, 0], seeds, dpos, temps,
-                                       top_ps)
+            d, _ = sample_from_logits(logits[:, 0], seeds, dpos, temps,
+                                      top_ps)
             dpos = jnp.minimum(dpos + 1, max_len - 1)
             return (d, kd, vd, dpos), d
 
@@ -412,10 +226,10 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
         # -- verify: ONE wide forward over the K+1 positions --
         xs = jnp.concatenate([t[:, None], drafts], axis=1)  # [B, T]
         qpos = pos[:, None] + jnp.arange(t_wide, dtype=jnp.int32)
-        logits, k, v, *touched = _step_logits(
+        logits, k, v, *touched = llama_slots._step_logits(
             cfg, params, layers, attach, w_out, xs, k, v, pos, qpos,
             active)
-        y, lp = _sample_from_logits(
+        y, lp = sample_from_logits(
             logits.reshape(b * t_wide, -1),
             jnp.repeat(seeds, t_wide), qpos.reshape(-1),
             jnp.repeat(temps, t_wide), jnp.repeat(top_ps, t_wide))
@@ -439,54 +253,13 @@ def decode_chunk_spec(params, draft_head, cache, tok, active, seeds,
             last, *touched)
 
 
-def _prefill_core(params, prompts, true_lens, seeds, temps, top_ps,
-                  cfg: LlamaConfig, prefix=None):
-    """The one prefill: [F, P] RIGHT-padded tokens (one shared bucket P,
-    ``true_lens`` [F] of them real). Its keys are the rows the call was
-    given plus the rows it makes (``llama.prefill``). ``prefix`` is
-    ``None``: the tokens are whole prompts, and the work is the
-    bucket's, P rows a layer whatever the slot's length (attention over
-    the prompt's own rows, the flash kernel on a TPU: one block up to
-    its 1,024 rows, a wider bucket whole blocks, or ``attention`` raises
-    when the program is traced); or ``(k, v, n_prefix)``: rows
-    [L, F, S, Hkv, D] filled up to the scalar ``n_prefix`` (the prefix
-    cache's), behind which the tokens (the prompts' suffixes) are
-    written. The final norm and the head see the TRUE last prompt
-    position alone, and the first token comes from it on the (seed,
-    position) lane of the chunk programs (seeds/temps/top_ps [F];
-    temperature 0 = greedy), so a failover replay reproduces it
-    whichever prefill path (inline, suffix, disaggregated) the
-    replacement replica takes. Returns (k, v [L, F, P or S, Hkv * D]:
-    rows as the stack holds them, [F] whole prompt lengths, [F] first
-    tokens, [F] their logprobs) and, for a model that reports its
-    routing, ``expert_tokens`` [L, E].
-
-    Right-padding is safe without a pad mask: causal attention means
-    real tokens (a prefix) never see the pad garbage, and each later
-    decode step overwrites a pad cache row at its position before the
-    growing per-slot mask can expose it."""
-    if prefix is None:
-        given, full_lens = None, true_lens
-    else:
-        k, v, n_prefix = prefix
-        given = (_kv_rows(k), _kv_rows(v), n_prefix)
-        full_lens = n_prefix + true_lens
-    aux = {}
-    last_logits, k, v = llama.prefill(
-        params, prompts, true_lens - 1, cfg, given, aux)
-    toks0, logp0 = _sample_from_logits(
-        last_logits, seeds, full_lens - 1, temps, top_ps)
-    return (k, v, full_lens, toks0, logp0,
-            *_expert_tokens(cfg, aux, true_lens))
-
-
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache", "cur_tok"))
 def _prefill_batch_into_slots(params, prompts, true_lens, slots,
                               seeds, temps, top_ps,
-                              cache, cur_tok, cfg: LlamaConfig,
-                              prefix=None):
-    """Prefill streams (:func:`_prefill_core`) into their slots of the
+                              cache, cur_tok, cfg, prefix=None):
+    """Prefill streams (the model's ``prefill``; the Llama block's is
+    ``llama_slots._prefill_core``) into their slots of the
     shared ragged cache: prefill, k/v scatters, pos and first-token
     updates in ONE dispatch. The engine calls it with F = 1, one prompt
     a call, so one program per bucket: at F = `slots` the padding rows
@@ -520,95 +293,10 @@ def _prefill_batch_into_slots(params, prompts, true_lens, slots,
                 *expert_tokens)
 
 
-@jax.named_scope("moe_router")
-def _expert_tokens(cfg: LlamaConfig, aux: dict, true_lens) -> tuple:
-    """``()`` for a model that reports no routing, else ([L, E] int32,):
-    the assignments each expert got in each layer from the REAL
-    positions of the prompts (``true_lens`` masks the bucket's padding)."""
-    if "expert_ids" not in aux:
-        return ()
-    ids = aux["expert_ids"]  # [L, F, P, top_k]
-    real = jnp.arange(ids.shape[2])[None, :] < true_lens[:, None]  # [F, P]
-    hit = jax.nn.one_hot(ids, cfg.n_experts, dtype=jnp.int32)
-    return (jnp.sum(hit * real[None, :, :, None, None], axis=(1, 2, 3)),)
-
-
-class _LlamaSlots:
-    """The Llama block's half of the engine, and the protocol a model
-    with a slot state of its own implements (``models/ling.py``):
-
-    - ``rows_state``: whether a slot's state is rows of positions that
-      can be cut, copied and rewound at any position (what the prefix
-      cache, speculative decoding and the prefill workers need);
-    - ``serving_params(cfg, params)``: the tree a replica holds;
-    - ``init_state(cfg, slots, max_len)``: every slot's state, a dict
-      with ``pos`` [slots]; ``max_len(state)``; ``state_bytes(state)``
-      by kind; ``row_kinds(cfg)``: for a model whose layers keep rows of
-      several kinds, {kind: (layers, the most rows a slot keeps in one,
-      ``None`` = ``max_len``)}, else {} (what ``_count_rows`` counts by);
-    - ``split(cfg, params)``: what a chunk prepares once;
-      ``step(cfg, params, prepared, tok, state, pos, active)``: one
-      token a slot on ``state`` (the dict without ``pos``) -> (float32
-      logits [B, V], state, *step counters named by ``step_counters``);
-    - ``prefill(params, prompts, true_lens, seeds, temps, top_ps, cfg,
-      slot_len, prefix)`` -> (the streams' state, whole prompt lengths,
-      first tokens, their logprobs, *per-expert assignment counts);
-      ``scatter(state, slots, streams, full_lens)``: that state into
-      its slots, so that a reused slot shows nothing of its last stream
-      (the Llama block: the rows a stream can read are its own, see
-      ``_prefill_batch_into_slots``; a state that is no rows is
-      replaced whole); ``prefill_segments(cfg, bucket)``: in how many
-      segments of rows a ``bucket``-row call runs its tokenwise work
-      (1: whole, every block's but ``models/solar.py``'s);
-    - ``reports_routing(cfg)``."""
-
-    rows_state = True
-    step_counters = ("experts_touched",)
-    row_kinds = staticmethod(lambda cfg: {})
-    prefill_segments = staticmethod(lambda cfg, bucket: 1)
-    serving_params = staticmethod(llama.serving_params)
-    reports_routing = staticmethod(llama.reports_routing)
-    init_state = staticmethod(init_ragged_cache)
-    split = staticmethod(_split_model)
-
-    @staticmethod
-    def max_len(state: dict) -> int:
-        return state["k"].shape[2]
-
-    @staticmethod
-    def state_bytes(state: dict) -> dict:
-        return {"kv": state["k"].nbytes + state["v"].nbytes}
-
-    @staticmethod
-    def step(cfg, params, prepared, tok, state, pos, active):
-        logits, k, v, *touched = _step_logits(
-            cfg, params, *prepared, tok[:, None], state["k"], state["v"],
-            pos, pos[:, None], active)
-        return logits[:, 0], {"k": k, "v": v}, *touched
-
-    @staticmethod
-    def prefill(params, prompts, true_lens, seeds, temps, top_ps, cfg,
-                slot_len, prefix=None):
-        k, v, *rest = _prefill_core(
-            params, prompts, true_lens, seeds, temps, top_ps, cfg, prefix)
-        return {"k": k, "v": v}, *rest
-
-    @staticmethod
-    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
-        # k/v: [L, F, R, Hkv * D], the R rows the prefill made, onto the
-        # first R rows of their slots
-        rows = streams["k"].shape[2]
-        return {
-            "k": state["k"].at[:, slots, :rows].set(streams["k"]),
-            "v": state["v"].at[:, slots, :rows].set(streams["v"]),
-            "pos": state["pos"].at[slots].set(full_lens),
-        }
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "slot_len"))
 def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
-               cfg: LlamaConfig, slot_len: int):
-    """Prefill WITHOUT a slot (a cold :func:`_prefill_core`): the raw
+               cfg, slot_len: int):
+    """Prefill WITHOUT a slot (a cold ``llama_slots._prefill_core``): the raw
     KV rows, the first tokens and their behavior logprobs
     ((k, v) [L, F, S, Hkv, D], the prompt's rows with zeros behind them
     up to ``slot_len``, the payload ``submit_prefilled`` checks; toks0
@@ -619,7 +307,7 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
     lane as an inline one, so the adopted stream is bit-identical to an
     inline-prefilled one, greedy or sampled."""
     require_rows(cfg, "disaggregated prefill (prefill_kv)")
-    k, v, _, toks0, logp0, *_ = _prefill_core(
+    k, v, _, toks0, logp0, *_ = llama_slots._prefill_core(
         params, prompts, true_lens, seeds, temps, top_ps, cfg)
 
     def payload(rows):  # [L, F, P, Hkv * D] -> [L, F, S, Hkv, D]
@@ -634,14 +322,14 @@ def prefill_kv(params, prompts, true_lens, seeds, temps, top_ps,
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache", "cur_tok"))
 def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
-                        cur_tok, cfg: LlamaConfig):
+                        cur_tok, cfg):
     """Scatter externally-prefilled KV rows ([L, S, Hkv, D] as
     ``prefill_kv`` hands them out, S == the slot cache length) into
     `slot` and seed its current token."""
     with jax.named_scope("cache"):
         cache = {
-            "k": cache["k"].at[:, slot].set(_kv_rows(k_rows)),
-            "v": cache["v"].at[:, slot].set(_kv_rows(v_rows)),
+            "k": cache["k"].at[:, slot].set(llama_slots._kv_rows(k_rows)),
+            "v": cache["v"].at[:, slot].set(llama_slots._kv_rows(v_rows)),
             "pos": cache["pos"].at[slot].set(true_len),
         }
         return cache, cur_tok.at[slot].set(tok0)
@@ -745,8 +433,7 @@ class RaggedDecoder:
                  chunk_delay_s: float = 0.0, weights_version: int = 0,
                  spec_depth: int = 0, spec_draft_layers: int = 0,
                  spec_draft_head=None):
-        # ``cfg`` is the model's own configuration (a ``LlamaConfig``,
-        # or one that carries its ``slot_model``); the model's half of
+        # ``cfg`` is the model's own configuration; the model's half of
         # the engine is found from it
         self.model = slot_model(cfg)
         if prefix_cache is not None:

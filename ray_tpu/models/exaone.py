@@ -51,13 +51,13 @@ leaf by leaf, in blocks (``moe.draw``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
-from ray_tpu.models.decode_engine import _sample_from_logits
-from ray_tpu.models.moe import draw, moe, prefill_loads, routing_counts, swiglu
+from ray_tpu.models import moe
+from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rotary, rotary_embedding
@@ -68,7 +68,7 @@ _NEG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
-class ExaoneConfig:
+class ExaoneConfig(moe.HeldExperts):
     vocab_size: int = 153600
     d_model: int = 6144
     n_layers: int = 48
@@ -114,14 +114,6 @@ class ExaoneConfig:
                 f"{SPARSE!r}, not {attn} and {mlp}")
         object.__setattr__(self, "layer_types", attn)
         object.__setattr__(self, "mlp_layer_types", mlp)
-
-    @property
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def held(self) -> tuple:
-        return self.held_experts or (0, self.n_experts)
 
     @property
     def kv_width(self) -> int:
@@ -171,11 +163,6 @@ class ExaoneConfig:
 # Parameters
 # --------------------------------------------------------------------------
 
-# leaves the model paths consume in float32
-_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm",
-               "router_bias")
-
-
 def init_params(cfg: ExaoneConfig, key):
     """The tree in the SERVING types (module docstring). Matrices are
     normal / sqrt(fan_in), and every ``w_down`` (the MLPs' writes into
@@ -196,18 +183,9 @@ def init_params(cfg: ExaoneConfig, key):
     reads must not hang on the seed. The norm scales are drawn around 1
     and the router's bias away from 0, so that a part left out of a path
     shows against the reference."""
-    cdt = cfg.compute_dtype
     d, hd = cfg.d_model, cfg.head_dim
-    _, count = cfg.held
     keys = iter(jax.random.split(key, 16 * (cfg.n_layers + 1)))
-    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
-
-    def mat(*shape, out=False):
-        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
-        return draw(next(keys), shape, scale, cdt)
-
-    def around_one(*shape):
-        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+    mat, around_one = moe.makers(cfg, keys)
 
     def attention():
         return {
@@ -216,43 +194,13 @@ def init_params(cfg: ExaoneConfig, key):
             "wo": mat(cfg.n_heads * hd, d),
         }
 
-    def dense():
-        f = cfg.dense_d_ff
-        return {"w_gate": mat(d, f), "w_up": mat(d, f),
-                "w_down": mat(f, d, out=True)}
-
-    def experts():
-        f, fs = cfg.d_ff, cfg.shared_d_ff
-        return {
-            "router": mat(d, cfg.n_experts),
-            # (small against the scores' spread: ``ling.init_params``)
-            "router_bias": 0.01 * jax.random.normal(
-                next(keys), (cfg.n_experts,), jnp.float32),
-            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
-            "w_down": mat(count, f, d, out=True),
-            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
-            "shared_down": mat(fs, d, out=True),
-        }
-
     layers = [{
         "attn_norm": around_one(d), "attn": attention(),
         "mlp_norm": around_one(d),
-        "mlp": dense() if kind == DENSE else experts(),
+        "mlp": moe.init_dense(cfg, mat) if kind == DENSE
+        else moe.init_experts(cfg, mat, keys),
     } for kind in cfg.mlp_layer_types]
-    return {
-        "embed": draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
-        "layers": layers,
-        "final_norm": around_one(d),
-        "lm_head": mat(d, cfg.vocab_size),
-    }
-
-
-def serving_params(cfg: ExaoneConfig, params):
-    """The tree a serving process holds (``llama.serving_params`` with
-    this block's float32 leaves): :func:`init_params` makes that tree
-    already, and it comes back itself; a published tree of another type
-    is cast once, here."""
-    return llama.serving_params(cfg, params, _F32_LEAVES)
+    return moe.init_model(cfg, mat, around_one, keys, layers)
 
 
 # --------------------------------------------------------------------------
@@ -314,29 +262,6 @@ def ring_rows(rows, true_lens, window: int):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
-def _mlp(cfg: ExaoneConfig, i: int, p, h, aux: dict | None = None):
-    """Layer ``i``'s MLP with its norm, added to ``h`` [B, T, D]: the
-    dense SwiGLU (scope ``mlp``) or the expert layer (``moe_router``,
-    the norm with it, ``moe_experts``, ``moe_shared``, the residual
-    with it)."""
-    if cfg.mlp_layer_types[i] == DENSE:
-        with jax.named_scope("mlp"):
-            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-            return h + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                              p["mlp"]["w_down"])
-    with jax.named_scope("moe_router"):
-        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-    y = moe(cfg, p["mlp"], x, aux)
-    with jax.named_scope("moe_shared"):
-        return h + y
-
-
-@jax.named_scope("lm_head")
-def _logits(cfg: ExaoneConfig, params, h):
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
-
-
 def _attn_scope(cfg: ExaoneConfig, i: int):
     """Attention proper of layer ``i``: ``attn``, its kind beneath."""
     return jax.named_scope(
@@ -370,7 +295,8 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
             h = h + o.reshape(b, t, -1) @ p["attn"]["wo"]
         rows.append((k.reshape(b, t, -1), v.reshape(b, t, -1)))
         layer_aux = {} if aux is not None else None
-        h = _mlp(cfg, i, p, h, layer_aux)
+        h = moe.mlp_layer(cfg, cfg.mlp_layer_types[i] == SPARSE, p, h,
+                          layer_aux)
         if layer_aux:
             ids.append(layer_aux["expert_ids"])
     if ids:
@@ -381,22 +307,10 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
 def forward(params, tokens, cfg: ExaoneConfig):
     """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
     band mask in the sliding layers."""
-    return _logits(cfg, params, prefill(params, tokens, cfg)[0])
+    return moe.logits(cfg, params, prefill(params, tokens, cfg)[0])
 
 
-def loss_fn(params, batch, cfg: ExaoneConfig):
-    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
-    (or inputs / targets). No cell trains this block: the forward is
-    the serving one, in the serving types."""
-    from ray_tpu.ops.losses import softmax_cross_entropy
-
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
-                                    mask=batch.get("mask"))
-    return loss, {"loss": loss, "tokens": n}
+loss_fn = moe.loss_fn(forward)
 
 
 def step(cfg: ExaoneConfig, params, tok, state, pos, active):
@@ -452,32 +366,25 @@ def step(cfg: ExaoneConfig, params, tok, state, pos, active):
                                      lengths, plan=plan)
         with jax.named_scope("attn_out"):
             h = h + o.reshape(b, 1, -1) @ p["attn"]["wo"]
-        aux = {} if cfg.mlp_layer_types[i] == SPARSE else None
-        h = _mlp(cfg, i, p, h, aux)
+        sparse = cfg.mlp_layer_types[i] == SPARSE
+        aux = {} if sparse else None
+        h = moe.mlp_layer(cfg, sparse, p, h, aux)
         if aux:
-            counts.append(routing_counts(cfg, aux["expert_ids"], active))
+            counts.append(moe.routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
-    return _logits(cfg, params, h)[:, 0], state, *counters
+    return moe.logits(cfg, params, h)[:, 0], state, *counters
 
 
 # --------------------------------------------------------------------------
-# The serving engine's half (decode_engine.slot_model's protocol)
+# The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots:
-    """What ``models/decode_engine.py`` asks of a model whose slot state
-    is its own. The engine carries the state, donates it to its two
-    programs and reads ``state["pos"]``; it looks at nothing else."""
+class _Slots(Slots):
+    """Two pairs of stacks: the full layers' rows and the sliding
+    layers' rings, which cannot be cut or rewound at a position."""
 
-    # a ring cannot be cut or rewound at a position
-    rows_state = False
-    step_counters = ("experts_touched", "assignments", "held_assignments")
-    serving_params = staticmethod(serving_params)
-    prefill_segments = staticmethod(lambda cfg, bucket: 1)
-
-    @staticmethod
-    def reports_routing(cfg: ExaoneConfig) -> bool:
-        return cfg.moe_layers > 0
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "k_norm",
+                  "router_bias")
 
     @staticmethod
     def row_kinds(cfg: ExaoneConfig) -> dict:
@@ -506,10 +413,6 @@ class _Slots:
         return {"window": both("win"), "full": both("full")}
 
     @staticmethod
-    def split(cfg: ExaoneConfig, params):
-        return None
-
-    @staticmethod
     def step(cfg: ExaoneConfig, params, prepared, tok, state, pos, active):
         return step(cfg, params, tok, state, pos, active)
 
@@ -524,18 +427,14 @@ class _Slots:
         kind, [F] prompt lengths, [F] first tokens, [F] their logprobs,
         the held experts' assignments from the real positions [L_moe,
         count])."""
-        if prefix is not None:
-            raise ValueError(
-                "a prefix of cached rows cannot seed this model's slot: "
-                "its sliding layers keep a ring, not a prompt's rows")
+        Slots.refuse_prefix(cfg, prefix)
         aux = {} if cfg.moe_layers else None
         h, rows = prefill(params, prompts, cfg, aux)
+        toks0, logp0 = Slots.first_token(
+            functools.partial(moe.logits, cfg), params, h, true_lens,
+            seeds, temps, top_ps)
         f = prompts.shape[0]
-        with jax.named_scope("lm_head"):  # (the last real row alone)
-            last = _logits(cfg, params,
-                           h[jnp.arange(f), true_lens - 1][:, None])
-        toks0, logp0 = _sample_from_logits(
-            last[:, 0], seeds, true_lens - 1, temps, top_ps)
+
         def stack(parts, rows_each):  # (no layer of a kind: no rows)
             return jnp.stack(parts) if parts else jnp.zeros(
                 (0, f, rows_each, cfg.kv_width), cfg.compute_dtype)
@@ -550,7 +449,7 @@ class _Slots:
                 streams[name + "_win"] = stack(
                     [ring_rows(r[j], true_lens, w)
                      for i, r in enumerate(rows) if cfg.windowed(i)], w)
-        loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \
+        loads = (moe.prefill_loads(cfg, aux["expert_ids"], true_lens),) \
             if aux else ()
         return streams, true_lens, toks0, logp0, *loads
 

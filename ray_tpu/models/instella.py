@@ -56,13 +56,13 @@ those types leaf by leaf, in blocks (``moe.draw``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
-from ray_tpu.models.decode_engine import _sample_from_logits
-from ray_tpu.models.moe import draw, moe, prefill_loads, routing_counts, swiglu
+from ray_tpu.models import moe
+from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rms_norm
@@ -73,7 +73,7 @@ _LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
-class InstellaConfig:
+class InstellaConfig(moe.HeldExperts):
     vocab_size: int = 128896
     d_model: int = 2048
     n_layers: int = 27
@@ -116,14 +116,6 @@ class InstellaConfig:
     published_layers: int = 0
 
     @property
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def held(self) -> tuple:
-        return self.held_experts or (0, self.n_experts)
-
-    @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
@@ -164,11 +156,6 @@ class InstellaConfig:
 # Parameters
 # --------------------------------------------------------------------------
 
-# leaves the model paths consume in float32
-_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "kv_norm",
-               "router_bias")
-
-
 def init_params(cfg: InstellaConfig, key):
     """The tree in the SERVING types (module docstring). Matrices are
     normal / sqrt(fan_in); every ``w_down`` (the MLPs' writes into the
@@ -179,18 +166,9 @@ def init_params(cfg: InstellaConfig, key):
     greedy decoding fall into cycles that the slots share). The norm
     scales are drawn around 1 and the router's bias away from 0, so that
     a part left out of a path shows against the reference."""
-    cdt = cfg.compute_dtype
     d, h = cfg.d_model, cfg.n_heads
-    _, count = cfg.held
     keys = iter(jax.random.split(key, 24 * (cfg.n_layers + 1)))
-    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
-
-    def mat(*shape, out=False):
-        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
-        return draw(next(keys), shape, scale, cdt)
-
-    def around_one(*shape):
-        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+    mat, around_one = moe.makers(cfg, keys)
 
     def mla():
         r, dv = cfg.kv_lora_rank, cfg.v_head_dim
@@ -206,43 +184,13 @@ def init_params(cfg: InstellaConfig, key):
             p["w_gate"] = mat(d, h * dv)
         return p
 
-    def dense():
-        f = cfg.dense_d_ff
-        return {"w_gate": mat(d, f), "w_up": mat(d, f),
-                "w_down": mat(f, d, out=True)}
-
-    def experts():
-        f, fs = cfg.d_ff, cfg.shared_d_ff
-        return {
-            "router": mat(d, cfg.n_experts),
-            # (small against the scores' spread: ``ling.init_params``)
-            "router_bias": 0.01 * jax.random.normal(
-                next(keys), (cfg.n_experts,), jnp.float32),
-            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
-            "w_down": mat(count, f, d, out=True),
-            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
-            "shared_down": mat(fs, d, out=True),
-        }
-
     layers = [{
         "attn_norm": around_one(d), "attn": mla(),
         "mlp_norm": around_one(d),
-        "mlp": experts() if cfg.sparse(i) else dense(),
+        "mlp": moe.init_experts(cfg, mat, keys) if cfg.sparse(i)
+        else moe.init_dense(cfg, mat),
     } for i in range(cfg.n_layers)]
-    return {
-        "embed": draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
-        "layers": layers,
-        "final_norm": around_one(d),
-        "lm_head": mat(d, cfg.vocab_size),
-    }
-
-
-def serving_params(cfg: InstellaConfig, params):
-    """The tree a serving process holds (``llama.serving_params`` with
-    this block's float32 leaves): :func:`init_params` makes that tree
-    already, and it comes back itself; a published tree of another type
-    is cast once, here."""
-    return llama.serving_params(cfg, params, _F32_LEAVES)
+    return moe.init_model(cfg, mat, around_one, keys, layers)
 
 
 # --------------------------------------------------------------------------
@@ -381,20 +329,14 @@ def _layer(cfg: InstellaConfig, i: int, p, s, behind, attend,
     with jax.named_scope("moe_router" if sparse else "mlp"):
         x = rms_norm(s if cfg.farskip else seen, p["mlp_norm"], cfg.rms_eps)
     if sparse:
-        m = moe(cfg, p["mlp"], x, aux)
+        m = moe.moe(cfg, p["mlp"], x, aux)
         with jax.named_scope("moe_shared"):
             s = seen + m
     else:
         with jax.named_scope("mlp"):
-            s = seen + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                              p["mlp"]["w_down"])
+            s = seen + moe.swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                                  p["mlp"]["w_down"])
     return s, seen if cfg.farskip else s
-
-
-@jax.named_scope("lm_head")
-def _logits(cfg: InstellaConfig, params, h):
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
 
 
 def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None):
@@ -429,22 +371,10 @@ def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None):
 def forward(params, tokens, cfg: InstellaConfig):
     """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
     unabsorbed attention."""
-    return _logits(cfg, params, prefill(params, tokens, cfg)[0])
+    return moe.logits(cfg, params, prefill(params, tokens, cfg)[0])
 
 
-def loss_fn(params, batch, cfg: InstellaConfig):
-    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
-    (or inputs / targets). No cell trains this block: the forward is
-    the serving one, in the serving types."""
-    from ray_tpu.ops.losses import softmax_cross_entropy
-
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
-                                    mask=batch.get("mask"))
-    return loss, {"loss": loss, "tokens": n}
+loss_fn = moe.loss_fn(forward)
 
 
 def step(cfg: InstellaConfig, params, tok, cache, pos, active):
@@ -476,30 +406,22 @@ def step(cfg: InstellaConfig, params, tok, cache, pos, active):
         aux = {} if cfg.sparse(i) else None
         s, behind = _layer(cfg, i, p, s, behind, attend, aux)
         if aux:
-            counts.append(routing_counts(cfg, aux["expert_ids"], active))
+            counts.append(moe.routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
-    return _logits(cfg, params, s)[:, 0], cache, *counters
+    return moe.logits(cfg, params, s)[:, 0], cache, *counters
 
 
 # --------------------------------------------------------------------------
-# The serving engine's half (decode_engine.slot_model's protocol)
+# The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots:
-    """What ``models/decode_engine.py`` asks of a model whose slot state
-    is its own. The engine carries the state, donates it to its two
-    programs and reads ``state["pos"]``; it looks at nothing else."""
+class _Slots(Slots):
+    """One stack of latent rows: rows of positions, but not the [L, S,
+    Hkv, D] pairs of k and v that the prefix cache, speculation and the
+    prefill workers carry (so ``rows_state`` stays False)."""
 
-    # rows of positions, but not the [L, S, Hkv, D] pairs of k and v
-    # that the prefix cache, speculation and the prefill workers carry
-    rows_state = False
-    step_counters = ("experts_touched", "assignments", "held_assignments")
-    serving_params = staticmethod(serving_params)
-    prefill_segments = staticmethod(lambda cfg, bucket: 1)
-
-    @staticmethod
-    def reports_routing(cfg: InstellaConfig) -> bool:
-        return cfg.moe_layers > 0
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "kv_norm",
+                  "router_bias")
 
     @staticmethod
     def row_kinds(cfg: InstellaConfig) -> dict:
@@ -522,10 +444,6 @@ class _Slots:
         return {"latent": rows.size * rows.dtype.itemsize}
 
     @staticmethod
-    def split(cfg: InstellaConfig, params):
-        return None
-
-    @staticmethod
     def step(cfg: InstellaConfig, params, prepared, tok, state, pos, active):
         logits, rows, *counters = step(
             cfg, params, tok, state["rows"], pos, active)
@@ -538,19 +456,13 @@ class _Slots:
         [L, F, P, row_width]}, the bucket's padding among them, [F]
         prompt lengths, [F] first tokens, [F] their logprobs, the held
         experts' assignments from the real positions [L_moe, count])."""
-        if prefix is not None:
-            raise ValueError(
-                "a prefix of cached rows cannot seed this model's slot: "
-                "its rows are latents, not the k and v a prefix carries")
+        Slots.refuse_prefix(cfg, prefix)
         aux = {} if cfg.moe_layers else None
         s, rows = prefill(params, prompts, cfg, aux)
-        f = prompts.shape[0]
-        with jax.named_scope("lm_head"):  # (the last real row alone)
-            last = _logits(cfg, params,
-                           s[jnp.arange(f), true_lens - 1][:, None])
-        toks0, logp0 = _sample_from_logits(
-            last[:, 0], seeds, true_lens - 1, temps, top_ps)
-        loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \
+        toks0, logp0 = Slots.first_token(
+            functools.partial(moe.logits, cfg), params, s, true_lens,
+            seeds, temps, top_ps)
+        loads = (moe.prefill_loads(cfg, aux["expert_ids"], true_lens),) \
             if aux else ()
         return {"rows": rows}, true_lens, toks0, logp0, *loads
 

@@ -61,16 +61,13 @@ serves it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
-from ray_tpu.models.decode_engine import _sample_from_logits
-from ray_tpu.models.moe import draw as _draw
-from ray_tpu.models.moe import moe, prefill_loads, routing_counts
-from ray_tpu.models.moe import route  # noqa: F401 — the name the tests know
-from ray_tpu.models.moe import swiglu as _swiglu
+from ray_tpu.models import moe
+from ray_tpu.models.slots import Slots
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
 from ray_tpu.ops.kda_chunk import kda_chunked  # noqa: F401 — the chunkwise
 # form's XLA body (the tests' second opinion), under this module's name
@@ -82,7 +79,7 @@ from ray_tpu.ops.rope import apply_rotary, rotary_embedding
 
 
 @dataclasses.dataclass(frozen=True)
-class LingConfig:
+class LingConfig(moe.HeldExperts):
     vocab_size: int = 157184
     d_model: int = 2560
     n_layers: int = 42
@@ -118,14 +115,6 @@ class LingConfig:
     # n_layers. A configuration cut in depth names its model's own.
     published_layers: int = 0
 
-    @property
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def held(self) -> tuple:
-        return self.held_experts or (0, self.n_experts)
-
     def attn_kind(self, i: int) -> str:
         return "mla" if (i + 1) % self.layer_group_size == 0 else "kda"
 
@@ -158,11 +147,6 @@ class LingConfig:
 # Parameters
 # --------------------------------------------------------------------------
 
-# leaves the model paths consume in float32
-_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "q_norm",
-               "kv_norm", "a_log", "dt_bias", "router_bias")
-
-
 def init_params(cfg: LingConfig, key):
     """The tree in the SERVING types (module docstring). Matrices are
     normal / sqrt(fan_in), and those that write into the residual stream
@@ -179,23 +163,14 @@ def init_params(cfg: LingConfig, key):
     cdt = cfg.compute_dtype
     d, h = cfg.d_model, cfg.n_heads
     dk = cfg.kda_head_dim
-    first, count = cfg.held
     keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
-
-    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
-
-    def mat(*shape, out=False):
-        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
-        return _draw(next(keys), shape, scale, cdt)
-
-    def around_one(*shape):
-        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+    mat, around_one = moe.makers(cfg, keys)
 
     def kda():
         return {
             "w_qkv": mat(d, 3 * h * dk),
-            "conv": _draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
-                          cfg.conv_kernel ** -0.5, cdt),
+            "conv": moe.draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
+                             cfg.conv_kernel ** -0.5, cdt),
             "w_f": mat(d, h * dk),
             "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
             "a_log": jnp.log(jax.random.uniform(
@@ -219,48 +194,16 @@ def init_params(cfg: LingConfig, key):
             "wo": mat(h * cfg.v_head_dim, d, out=True),
         }
 
-    def dense():
-        f = cfg.dense_d_ff
-        return {"w_gate": mat(d, f), "w_up": mat(d, f),
-                "w_down": mat(f, d, out=True)}
-
-    def experts():
-        f, fs = cfg.d_ff, cfg.shared_d_ff
-        return {
-            "router": mat(d, cfg.n_experts),
-            # (small against the scores' spread of 0.2: the top 3% of
-            # sigmoids lie where a bias of 0.1 is a standard deviation
-            # of the logits, and one expert in eight took most rows)
-            "router_bias": 0.01 * jax.random.normal(
-                next(keys), (cfg.n_experts,), jnp.float32),
-            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
-            "w_down": mat(count, f, d, out=True),
-            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
-            "shared_down": mat(fs, d, out=True),
-        }
-
     layers = []
     for i in range(cfg.n_layers):
         layers.append({
             "attn_norm": around_one(d),
             "attn": kda() if cfg.attn_kind(i) == "kda" else mla(),
             "mlp_norm": around_one(d),
-            "mlp": dense() if cfg.mlp_kind(i) == "dense" else experts(),
+            "mlp": moe.init_dense(cfg, mat) if cfg.mlp_kind(i) == "dense"
+            else moe.init_experts(cfg, mat, keys),
         })
-    return {
-        "embed": _draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
-        "layers": layers,
-        "final_norm": around_one(d),
-        "lm_head": mat(d, cfg.vocab_size),
-    }
-
-
-def serving_params(cfg: LingConfig, params):
-    """The tree a serving process holds (``llama.serving_params`` with
-    this block's float32 leaves): :func:`init_params` makes that tree
-    already, and it comes back itself; a published tree of another type
-    is cast once, here."""
-    return llama.serving_params(cfg, params, _F32_LEAVES)
+    return moe.init_model(cfg, mat, around_one, keys, layers)
 
 
 # --------------------------------------------------------------------------
@@ -462,35 +405,8 @@ def mla_step(cfg: LingConfig, p, x, cache, pos):
 
 
 # --------------------------------------------------------------------------
-# MLPs
-# --------------------------------------------------------------------------
-
-def _mlp(cfg: LingConfig, i: int, p, h, aux: dict | None = None):
-    """Layer ``i``'s MLP with its norm, added to ``h`` [B, T, D]: the
-    dense SwiGLU (scope ``mlp``) or the expert layer (``moe_router``,
-    the norm with it, ``moe_experts``, ``moe_shared``, the residual
-    with it)."""
-    if cfg.mlp_kind(i) == "dense":
-        with jax.named_scope("mlp"):
-            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-            return h + _swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                               p["mlp"]["w_down"])
-    with jax.named_scope("moe_router"):
-        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-    y = moe(cfg, p["mlp"], x, aux)
-    with jax.named_scope("moe_shared"):
-        return h + y
-
-
-# --------------------------------------------------------------------------
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
-
-@jax.named_scope("lm_head")
-def _logits(cfg: LingConfig, params, h):
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
-
 
 def prefill(params, tokens, true_lens, cfg: LingConfig,
             aux: dict | None = None):
@@ -514,7 +430,7 @@ def prefill(params, tokens, true_lens, cfg: LingConfig,
             h = h + y
         state.append(st)
         layer_aux = {} if aux is not None else None
-        h = _mlp(cfg, i, p, h, layer_aux)
+        h = moe.mlp_layer(cfg, cfg.mlp_kind(i) == "moe", p, h, layer_aux)
         if layer_aux:
             ids.append(layer_aux["expert_ids"])
     if ids:
@@ -527,22 +443,10 @@ def forward(params, tokens, cfg: LingConfig):
     chunkwise KDA and the unabsorbed MLA."""
     b, t = tokens.shape
     h, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
-    return _logits(cfg, params, h)
+    return moe.logits(cfg, params, h)
 
 
-def loss_fn(params, batch, cfg: LingConfig):
-    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
-    (or inputs / targets). No cell trains this block: the forward is
-    the serving one, in the serving types."""
-    from ray_tpu.ops.losses import softmax_cross_entropy
-
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
-                                    mask=batch.get("mask"))
-    return loss, {"loss": loss, "tokens": n}
+loss_fn = moe.loss_fn(forward)
 
 
 def step(cfg: LingConfig, params, tok, layers_state, pos, active):
@@ -565,33 +469,26 @@ def step(cfg: LingConfig, params, tok, layers_state, pos, active):
         with jax.named_scope("attn_out"):
             h = h + y
         new_state.append(st)
-        aux = {} if cfg.mlp_kind(i) == "moe" else None
-        h = _mlp(cfg, i, p, h, aux)
+        sparse = cfg.mlp_kind(i) == "moe"
+        aux = {} if sparse else None
+        h = moe.mlp_layer(cfg, sparse, p, h, aux)
         if aux:
-            counts.append(routing_counts(cfg, aux["expert_ids"], active))
+            counts.append(moe.routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
-    return _logits(cfg, params, h)[:, 0], new_state, *counters
+    return moe.logits(cfg, params, h)[:, 0], new_state, *counters
 
 
 # --------------------------------------------------------------------------
-# The serving engine's half (decode_engine.slot_model's protocol)
+# The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots:
-    """What ``models/decode_engine.py`` asks of a model whose slot state
-    is its own. The engine carries the state, donates it to its two
-    programs and reads ``state["pos"]``; it looks at nothing else."""
+class _Slots(Slots):
+    """A recurrent state (``S`` and the convolution rows) a KDA layer,
+    ``max_len`` rows of latents an MLA layer: not rows that can be cut
+    at a position. (No ``row_kinds``: one layer in six keeps rows.)"""
 
-    # the state is not rows that can be cut at a position
-    rows_state = False
-    step_counters = ("experts_touched", "assignments", "held_assignments")
-    row_kinds = staticmethod(lambda cfg: {})  # one layer in six keeps rows
-    serving_params = staticmethod(serving_params)
-    prefill_segments = staticmethod(lambda cfg, bucket: 1)
-
-    @staticmethod
-    def reports_routing(cfg: LingConfig) -> bool:
-        return cfg.moe_layers > 0
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "q_norm",
+                  "kv_norm", "a_log", "dt_bias", "router_bias")
 
     @staticmethod
     def init_state(cfg: LingConfig, slots: int, max_len: int) -> dict:
@@ -629,10 +526,6 @@ class _Slots:
         return by_kind
 
     @staticmethod
-    def split(cfg: LingConfig, params):
-        return None
-
-    @staticmethod
     def step(cfg: LingConfig, params, prepared, tok, state, pos, active):
         logits, layers, *counters = step(
             cfg, params, tok, state["layers"], pos, active)
@@ -646,19 +539,13 @@ class _Slots:
         replaces the slot's whole). -> (the streams' state, [F] prompt
         lengths, [F] first tokens, [F] their logprobs, the held experts'
         assignments from the real positions [L_moe, count])."""
-        if prefix is not None:
-            raise ValueError(
-                "a prefix of cached rows cannot seed this model's slot: "
-                "its state is recurrent (KDA), not rows")
+        Slots.refuse_prefix(cfg, prefix)
         aux = {} if cfg.moe_layers else None
         h, layers = prefill(params, prompts, true_lens, cfg, aux)
-        f = prompts.shape[0]
-        with jax.named_scope("lm_head"):  # (the last real row alone)
-            last = _logits(cfg, params,
-                           h[jnp.arange(f), true_lens - 1][:, None])
-        toks0, logp0 = _sample_from_logits(
-            last[:, 0], seeds, true_lens - 1, temps, top_ps)
-        loads = (prefill_loads(cfg, aux["expert_ids"], true_lens),) \
+        toks0, logp0 = Slots.first_token(
+            functools.partial(moe.logits, cfg), params, h, true_lens,
+            seeds, temps, top_ps)
+        loads = (moe.prefill_loads(cfg, aux["expert_ids"], true_lens),) \
             if aux else ()
         return {"layers": layers}, true_lens, toks0, logp0, *loads
 

@@ -90,6 +90,13 @@ class LlamaConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def slot_model(self):
+        """This block's half of the serving engine (``models/slots.py``;
+        imported here: ``models/llama_slots.py`` imports this module)."""
+        from ray_tpu.models.llama_slots import SLOTS
+        return SLOTS
+
     def num_params(self) -> int:
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
         hd = self.head_dim

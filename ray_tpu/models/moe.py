@@ -1,6 +1,8 @@
-"""The expert layer of a device that holds a part of its experts, and
-the router in front of it (DeepSeek-V3's routing): what ``models/ling.py``
-and ``models/exaone.py`` share, one copy.
+"""What the blocks that unroll their layers share, one copy
+(``models/ling.py``, ``exaone.py``, ``instella.py``, ``solar.py``): the
+expert layer of a device that holds a part of its experts with the
+router in front of it (DeepSeek-V3's routing), a layer's MLP around it,
+the head, the loss, and how the leaves these read are drawn.
 
 Sigmoid scores in float32, a bias added for selection only,
 ``topk_group`` of ``n_group`` groups kept by the sum of their two best,
@@ -12,9 +14,12 @@ the others would add is left out.
 
 ``cfg`` is any configuration with the fields read here: ``n_experts``,
 ``n_group``, ``topk_group``, ``top_k``, ``routed_scaling_factor``,
-``held`` and ``compute_dtype``. :func:`draw` makes a leaf in its serving
-type block by block: a model that holds billions of parameters as bf16
-cannot make them as float32 masters first.
+``held``, ``compute_dtype`` and ``rms_eps``; the makers of leaves read
+``d_model``, ``d_ff``, ``shared_d_ff``, ``dense_d_ff`` (a block with a
+dense MLP), ``vocab_size``, ``n_layers`` and ``published_layers`` too.
+:func:`draw` makes a leaf in its serving type block by block: a model
+that holds billions of parameters as bf16 cannot make them as float32
+masters first.
 """
 
 from __future__ import annotations
@@ -25,7 +30,26 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.losses import softmax_cross_entropy
+from ray_tpu.ops.norms import rms_norm
+
 _BLOCK_ELEMS = 1 << 22  # a leaf is drawn in float32 blocks of this many
+
+
+class HeldExperts:
+    """What the functions here read of a configuration besides its
+    fields, derived from them. The blocks' frozen dataclasses inherit
+    it; it has no field, so their hash and equality as a static
+    argument are their own fields'."""
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def held(self) -> tuple:
+        """(first, count): the experts this device holds."""
+        return self.held_experts or (0, self.n_experts)
 
 
 def draw(key, shape, scale: float, dtype):
@@ -49,6 +73,68 @@ def draw(key, shape, scale: float, dtype):
                    * scale).astype(dtype),
         jax.random.split(key, n // rows))
     return blocks.reshape(shape)
+
+
+def makers(cfg, keys):
+    """-> (``mat``, ``around_one``), the two ways a block's
+    ``init_params`` draws a leaf, each from the next of ``keys`` (the
+    iterator the block draws its own leaves from too: the calls' order
+    is the draws'). ``mat(*shape, out=False)``: a matrix in the compute
+    dtype, normal / sqrt(fan_in), and with ``out`` (it writes into the
+    residual stream) scaled by (2 x depth)^-1/2 besides, depth being
+    the model's own (``published_layers``) where the configuration is
+    cut in depth (``ling.init_params`` says what for).
+    ``around_one(*shape)``: a norm's scale, float32, drawn around 1."""
+    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
+
+    def mat(*shape, out=False):
+        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
+        return draw(next(keys), shape, scale, cfg.compute_dtype)
+
+    def around_one(*shape):
+        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    return mat, around_one
+
+
+def init_dense(cfg, mat) -> dict:
+    """A dense SwiGLU's leaves, as :func:`mlp_layer` reads them."""
+    d, f = cfg.d_model, cfg.dense_d_ff
+    return {"w_gate": mat(d, f), "w_up": mat(d, f),
+            "w_down": mat(f, d, out=True)}
+
+
+def init_experts(cfg, mat, keys) -> dict:
+    """An expert layer's leaves, as :func:`moe` reads them: the router
+    over ALL experts, the held experts' matrices, the shared expert."""
+    d, f, fs = cfg.d_model, cfg.d_ff, cfg.shared_d_ff
+    _, count = cfg.held
+    return {
+        "router": mat(d, cfg.n_experts),
+        # (small against the scores' spread of 0.2: the top 3% of
+        # sigmoids lie where a bias of 0.1 is a standard deviation
+        # of the logits, and one expert in eight took most rows)
+        "router_bias": 0.01 * jax.random.normal(
+            next(keys), (cfg.n_experts,), jnp.float32),
+        "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
+        "w_down": mat(count, f, d, out=True),
+        "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
+        "shared_down": mat(fs, d, out=True),
+    }
+
+
+def init_model(cfg, mat, around_one, keys, layers: list) -> dict:
+    """The whole tree round a block's ``layers`` (drawn before it is
+    called): the embedding, the final norm and the head, as
+    :func:`logits` reads them."""
+    d = cfg.d_model
+    return {
+        "embed": draw(next(keys), (cfg.vocab_size, d), 1.0,
+                      cfg.compute_dtype),
+        "layers": layers,
+        "final_norm": around_one(d),
+        "lm_head": mat(d, cfg.vocab_size),
+    }
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -126,6 +212,48 @@ def moe(cfg, p, x, aux: dict | None = None):
         out = out.astype(cdt) + swiglu(
             xf, p["shared_gate"], p["shared_up"], p["shared_down"])
     return out.reshape(b, t, d)
+
+
+def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None):
+    """A layer's MLP with its norm, added to ``h`` [B, T, D]: the dense
+    SwiGLU (scope ``mlp``) or, where the layer is ``sparse``, the expert
+    layer (``moe_router``, the norm with it, ``moe_experts``,
+    ``moe_shared``, the residual with it)."""
+    if not sparse:
+        with jax.named_scope("mlp"):
+            x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+            return h + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                              p["mlp"]["w_down"])
+    with jax.named_scope("moe_router"):
+        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
+    y = moe(cfg, p["mlp"], x, aux)
+    with jax.named_scope("moe_shared"):
+        return h + y
+
+
+@jax.named_scope("lm_head")
+def logits(cfg, params, h):
+    """h [..., D] before the final norm -> float32 logits [..., V]."""
+    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
+    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
+
+
+def loss_fn(forward):
+    """-> ``loss_fn(params, batch, cfg)`` of a block whose whole-sequence
+    logits are ``forward(params, tokens, cfg)``: the mean next-token
+    cross-entropy over ``batch["tokens"]`` [B, T+1] (or inputs /
+    targets). No cell trains these blocks: the forward is the serving
+    one, in the serving types."""
+    def loss_fn(params, batch, cfg):
+        if "inputs" in batch:
+            inputs, targets = batch["inputs"], batch["targets"]
+        else:
+            inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+        loss, n = softmax_cross_entropy(
+            forward(params, inputs, cfg), targets, mask=batch.get("mask"))
+        return loss, {"loss": loss, "tokens": n}
+
+    return loss_fn
 
 
 @jax.named_scope("moe_router")

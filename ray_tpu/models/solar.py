@@ -68,13 +68,13 @@ router scores, softmax statistics and ``S`` float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import ling, llama
-from ray_tpu.models.decode_engine import _sample_from_logits
-from ray_tpu.models.moe import draw, moe, prefill_loads, routing_counts
+from ray_tpu.models import ling, moe
+from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
@@ -87,7 +87,7 @@ SEGMENT_ROWS = 2048
 
 
 @dataclasses.dataclass(frozen=True)
-class SolarConfig:
+class SolarConfig(moe.HeldExperts):
     vocab_size: int = 196608
     d_model: int = 4096
     n_layers: int = 48
@@ -131,14 +131,6 @@ class SolarConfig:
             raise ValueError(f"gqa_layers {full} name a layer that "
                              f"{self.n_layers} layers do not have")
         object.__setattr__(self, "gqa_layers", full)
-
-    @property
-    def compute_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def held(self) -> tuple:
-        return self.held_experts or (0, self.n_experts)
 
     @property
     def kv_width(self) -> int:
@@ -198,11 +190,6 @@ def segment_rows(cfg: SolarConfig, t: int) -> int:
 # Parameters
 # --------------------------------------------------------------------------
 
-# leaves the model paths consume in float32
-_F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "a_log",
-               "dt_bias", "router_bias")
-
-
 def init_params(cfg: SolarConfig, key):
     """The tree in the SERVING types (module docstring), leaf by leaf in
     blocks (``moe.draw``). Matrices are normal / sqrt(fan_in), and those
@@ -216,22 +203,14 @@ def init_params(cfg: SolarConfig, key):
     cdt = cfg.compute_dtype
     d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
     dk, r = cfg.kda_head_dim, cfg.kda_rank
-    _, count = cfg.held
     keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
-    out_scale = (2 * (cfg.published_layers or cfg.n_layers)) ** -0.5
-
-    def mat(*shape, out=False):
-        scale = shape[-2] ** -0.5 * (out_scale if out else 1.0)
-        return draw(next(keys), shape, scale, cdt)
-
-    def around_one(*shape):
-        return 1.0 + 0.25 * jax.random.normal(next(keys), shape, jnp.float32)
+    mat, around_one = moe.makers(cfg, keys)
 
     def kda():
         return {
             "w_qkv": mat(d, 3 * h * dk),
-            "conv": draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
-                         cfg.conv_kernel ** -0.5, cdt),
+            "conv": moe.draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
+                             cfg.conv_kernel ** -0.5, cdt),
             "w_f_down": mat(d, r), "w_f_up": mat(r, h * dk),
             "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
             "a_log": jnp.log(jax.random.uniform(
@@ -249,38 +228,12 @@ def init_params(cfg: SolarConfig, key):
             "wo": mat(h * hd, d, out=True),
         }
 
-    def experts():
-        f, fs = cfg.d_ff, cfg.shared_d_ff
-        return {
-            "router": mat(d, cfg.n_experts),
-            # (small against the scores' spread: ``ling.init_params``)
-            "router_bias": 0.01 * jax.random.normal(
-                next(keys), (cfg.n_experts,), jnp.float32),
-            "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
-            "w_down": mat(count, f, d, out=True),
-            "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
-            "shared_down": mat(fs, d, out=True),
-        }
-
     layers = [{
         "attn_norm": around_one(d),
         "attn": gqa() if cfg.full(i) else kda(),
-        "mlp_norm": around_one(d), "mlp": experts(),
+        "mlp_norm": around_one(d), "mlp": moe.init_experts(cfg, mat, keys),
     } for i in range(cfg.n_layers)]
-    return {
-        "embed": draw(next(keys), (cfg.vocab_size, d), 1.0, cdt),
-        "layers": layers,
-        "final_norm": around_one(d),
-        "lm_head": mat(d, cfg.vocab_size),
-    }
-
-
-def serving_params(cfg: SolarConfig, params):
-    """The tree a serving process holds (``llama.serving_params`` with
-    this block's float32 leaves): :func:`init_params` makes that tree
-    already, and it comes back itself; a published tree of another type
-    is cast once, here."""
-    return llama.serving_params(cfg, params, _F32_LEAVES)
+    return moe.init_model(cfg, mat, around_one, keys, layers)
 
 
 # --------------------------------------------------------------------------
@@ -407,23 +360,6 @@ def _gqa_out(cfg: SolarConfig, p, x, o):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
-def _mlp(cfg: SolarConfig, p, h, aux: dict | None = None):
-    """A layer's experts with their norm, added to ``h`` [B, T, D]
-    (``moe_router``, the norm with it, ``moe_experts``, ``moe_shared``,
-    the residual with it)."""
-    with jax.named_scope("moe_router"):
-        x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-    y = moe(cfg, p["mlp"], x, aux)
-    with jax.named_scope("moe_shared"):
-        return h + y
-
-
-@jax.named_scope("lm_head")
-def _logits(cfg: SolarConfig, params, h):
-    h = rms_norm(h, params["final_norm"], cfg.rms_eps)
-    return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
-
-
 def _in_segments(body, carry, xs, seg: int):
     """``body(carry, (segment's first row, the segment's rows of xs)) ->
     (carry, outputs with a leading [B, seg])`` over the segments of
@@ -461,9 +397,10 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
 
     def experts(p, h_seg, start):
         aux = {} if loads else None
-        h_seg = _mlp(cfg, p, h_seg, aux)
-        return h_seg, (prefill_loads(cfg, aux["expert_ids"][None],
-                                     true_lens - start)[0] if loads else ())
+        h_seg = moe.mlp_layer(cfg, True, p, h_seg, aux)
+        return h_seg, (moe.prefill_loads(cfg, aux["expert_ids"][None],
+                                         true_lens - start)[0]
+                       if loads else ())
 
     for i, p in enumerate(params["layers"]):
         def norm(h_seg, p=p):
@@ -527,22 +464,10 @@ def forward(params, tokens, cfg: SolarConfig):
     chunkwise KDA and the prompt's attention."""
     b, t = tokens.shape
     h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
-    return _logits(cfg, params, h)
+    return moe.logits(cfg, params, h)
 
 
-def loss_fn(params, batch, cfg: SolarConfig):
-    """Mean next-token cross-entropy over ``batch["tokens"]`` [B, T+1]
-    (or inputs / targets). No cell trains this block: the forward is
-    the serving one, in the serving types."""
-    from ray_tpu.ops.losses import softmax_cross_entropy
-
-    if "inputs" in batch:
-        inputs, targets = batch["inputs"], batch["targets"]
-    else:
-        inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    loss, n = softmax_cross_entropy(forward(params, inputs, cfg), targets,
-                                    mask=batch.get("mask"))
-    return loss, {"loss": loss, "tokens": n}
+loss_fn = moe.loss_fn(forward)
 
 
 def step(cfg: SolarConfig, params, tok, state, pos, active):
@@ -583,27 +508,23 @@ def step(cfg: SolarConfig, params, tok, state, pos, active):
         with jax.named_scope("attn_out"):
             h = h + y
         aux = {}
-        h = _mlp(cfg, p, h, aux)
-        counts.append(routing_counts(cfg, aux["expert_ids"], active))
+        h = moe.mlp_layer(cfg, True, p, h, aux)
+        counts.append(moe.routing_counts(cfg, aux["expert_ids"], active))
     counters = tuple(jnp.stack(c) for c in zip(*counts))
     state = {"kda": kda, "k_full": kf, "v_full": vf}
-    return _logits(cfg, params, h)[:, 0], state, *counters
+    return moe.logits(cfg, params, h)[:, 0], state, *counters
 
 
 # --------------------------------------------------------------------------
-# The serving engine's half (decode_engine.slot_model's protocol)
+# The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots:
-    """What ``models/decode_engine.py`` asks of a model whose slot state
-    is its own. The engine carries the state, donates it to its two
-    programs and reads ``state["pos"]``; it looks at nothing else."""
+class _Slots(Slots):
+    """A recurrent state a KDA layer, which cannot be cut or rewound at
+    a position, beside the GQA layers' stacks of rows."""
 
-    # a recurrent state cannot be cut or rewound at a position
-    rows_state = False
-    step_counters = ("experts_touched", "assignments", "held_assignments")
-    serving_params = staticmethod(serving_params)
-    reports_routing = staticmethod(lambda cfg: True)
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "o_norm", "a_log",
+                  "dt_bias", "router_bias")
 
     @staticmethod
     def row_kinds(cfg: SolarConfig) -> dict:
@@ -638,10 +559,6 @@ class _Slots:
                 "full": size(state["k_full"]) + size(state["v_full"])}
 
     @staticmethod
-    def split(cfg: SolarConfig, params):
-        return None
-
-    @staticmethod
     def step(cfg: SolarConfig, params, prepared, tok, state, pos, active):
         return step(cfg, params, tok, state, pos, active)
 
@@ -652,18 +569,12 @@ class _Slots:
         zero ``S`` and zero convolution rows). -> (the streams' state,
         [F] prompt lengths, [F] first tokens, [F] their logprobs, the
         held experts' assignments from the real positions [L, count])."""
-        if prefix is not None:
-            raise ValueError(
-                "a prefix of cached rows cannot seed this model's slot: "
-                "its KDA layers' state is recurrent, not rows")
+        Slots.refuse_prefix(cfg, prefix)
         h, streams, loads = prefill(params, prompts, true_lens, cfg,
                                     loads=True)
-        f = prompts.shape[0]
-        with jax.named_scope("lm_head"):  # (the last real row alone)
-            last = _logits(cfg, params,
-                           h[jnp.arange(f), true_lens - 1][:, None])
-        toks0, logp0 = _sample_from_logits(
-            last[:, 0], seeds, true_lens - 1, temps, top_ps)
+        toks0, logp0 = Slots.first_token(
+            functools.partial(moe.logits, cfg), params, h, true_lens,
+            seeds, temps, top_ps)
         return streams, true_lens, toks0, logp0, loads
 
     @staticmethod

@@ -82,8 +82,8 @@ a strip below it needs no mask); of those 2.19 the diagonal blocks were
 of 8 rows 2.21 and of 32 rows 2.48 for 16's 2.12. At 32 heads (PR 47):
 1,024 rows 3.10 -> 0.80, 256 rows 3.45 -> 1.67 (the host's dispatch
 holds both sides there): the faster form at every shape a cell has.
-The prefill program around the call (``tests/test_tpu_compile.py
--k "solar or ling"``): the four arrays come out of the producers'
+The prefill program around the call (``tests/test_tpu_compile_ling.py``,
+``_solar.py``): the four arrays come out of the producers'
 fusions in the kernel's layout, ``S`` out of the scan's carry, no
 ``copy`` of an operand; XLA keeps the output ``o`` of a segment in VMEM
 (``S(1)``) for the output norm behind it and, at Ling's two smaller
@@ -97,7 +97,7 @@ the chip's host: Ling's set-up read 120-160 s for the parent's 80-88
 (18 traces). So the column step is a jitted function of its own (traced
 twice) and the kernel's call is jitted by itself (one trace a shape:
 three in Ling's cell, one in Solar-Open2's, whose segments are all
-2,048 rows; ``tests/test_tpu_compile.py -k lowering_lings`` counts
+2,048 rows; ``tests/test_tpu_compile_ling.py -k lowering_lings`` counts
 them). ``PERF.md`` section 6, PR 47, has both cells' set-up on both
 sides. (That builder also read the columns as a ``fori_loop``: 1.62 us a row.)
 """

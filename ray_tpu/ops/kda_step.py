@@ -62,7 +62,7 @@ and the chunk waited for the copies instead: 153.3 ms for the XLA
 body's 154.7, 2,923 / 2,939 tokens/s for 2,906 / 2,919. Without the
 estimate no state moves but through the kernel: 213.7 us a call, a
 chunk of 142.4 ms, 3,099-3,125 tokens/s for 2,878-2,897 (my chip
-runs, PR 41; ``tests/test_tpu_compile.py -k ling`` pins the text).
+runs, PR 41; ``tests/test_tpu_compile_ling.py`` pins the text).
 """
 
 from __future__ import annotations

@@ -22,6 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 from _oracle import greedy_tokens  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models import llama_slots  # noqa: E402
 from ray_tpu.ops import decode_attention as da  # noqa: E402
 from ray_tpu.ops.attention import _repeat_kv  # noqa: E402
 
@@ -55,14 +56,14 @@ def test_grouped_contraction_matches_repeated_kv(group, t):
     # cache's edge
     pos = jnp.array([0, 7, s - t], jnp.int32)
     qpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    got = de._attend_ragged(q, ck, cv, qpos)
+    got = da.attend_ragged(q, ck, cv, qpos)
     want = _attend_repeated(q, ck, cv, qpos)
     assert got.shape == (b, t, hkv * group, hd)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
     # rows past a query's position do not reach its output
     junk = ck.at[0, t:].set(1e3), cv.at[0, t:].set(1e3)
     np.testing.assert_array_equal(
-        de._attend_ragged(q, *junk, qpos)[0], got[0])
+        da.attend_ragged(q, *junk, qpos)[0], got[0])
 
 
 @pytest.mark.parametrize("group", [1, 2, 4])
@@ -80,7 +81,7 @@ def test_decode_chunk_returns_the_greedy_tokens(group):
     prompts = np.zeros((slots, bucket), np.int32)
     for i, n in enumerate(lens):
         prompts[i, :n] = rng.randint(1, 250, n)
-    cache = de.init_ragged_cache(cfg, slots, max_len)
+    cache = llama_slots.init_ragged_cache(cfg, slots, max_len)
     cache, tok, toks0, _ = de._prefill_batch_into_slots(
         params, prompts, np.array(lens, np.int32),
         np.arange(slots, dtype=np.int32), np.zeros(slots, np.uint32),
@@ -132,7 +133,7 @@ def test_kernel_reads_each_slot_up_to_its_own_length(group, t, length):
     # the XLA body and the oracle, over every row of the layer
     qpos = (lengths - t)[:, None] + jnp.arange(t, dtype=jnp.int32)
     heads = (b, ROWS, hkv, hd)
-    for reference in (de._attend_ragged, _attend_repeated):
+    for reference in (da.attend_ragged, _attend_repeated):
         want = reference(q, k[1].reshape(heads), v[1].reshape(heads),
                          jnp.maximum(qpos, 0))
         np.testing.assert_allclose(np.asarray(got)[live],
@@ -180,7 +181,7 @@ def test_decode_chunk_through_the_kernel_returns_the_greedy_tokens(
     prompts = np.zeros((slots, bucket), np.int32)
     for i, n in enumerate(lens):
         prompts[i, :n] = rng.randint(1, 250, n)
-    cache = de.init_ragged_cache(cfg, slots, max_len)
+    cache = llama_slots.init_ragged_cache(cfg, slots, max_len)
     cache, tok, toks0, _ = de._prefill_batch_into_slots(
         params, prompts, np.array(lens, np.int32),
         np.arange(slots, dtype=np.int32), np.zeros(slots, np.uint32),

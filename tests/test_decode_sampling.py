@@ -22,6 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 from _oracle import greedy_tokens  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models import llama_slots  # noqa: E402
 from ray_tpu.models.decode_engine import (  # noqa: E402
     RaggedDecoder,
     prefill_kv,
@@ -244,7 +245,7 @@ def test_chunk_program_with_lanes_at_temperature_zero_is_the_greedy_one(
         return de._prefill_batch_into_slots(
             params, prompts, np.array(lens, np.int32),
             np.arange(slots, dtype=np.int32), *_lanes(slots),
-            de.init_ragged_cache(cfg, slots, max_len),
+            llama_slots.init_ragged_cache(cfg, slots, max_len),
             jnp.zeros((slots,), jnp.int32), cfg)
 
     active = np.ones(slots, bool)
@@ -283,7 +284,7 @@ def test_the_prefill_entries_agree(temp):
         row[0, :len(tokens)] = tokens
         cache, cur, tok0, lp0 = de._prefill_batch_into_slots(
             params, row, np.array([len(tokens)], np.int32), slot, *lane,
-            de.init_ragged_cache(cfg, slots, max_len),
+            llama_slots.init_ragged_cache(cfg, slots, max_len),
             jnp.zeros((slots,), jnp.int32), cfg, prefix)
         assert int(cur[1]) == int(tok0[0])
         return cache, int(tok0[0]), float(lp0[0])
@@ -307,7 +308,7 @@ def test_the_prefill_entries_agree(temp):
         params, row, np.array([n], np.int32), *lane, cfg, max_len)
     adopted, cur = de._adopt_kv_into_slot(
         k[:, 0], v[:, 0], np.int32(n), tok_r[0], np.int32(1),
-        de.init_ragged_cache(cfg, slots, max_len),
+        llama_slots.init_ragged_cache(cfg, slots, max_len),
         jnp.zeros((slots,), jnp.int32), cfg)
     assert int(cur[1]) == int(tok_r[0])
 

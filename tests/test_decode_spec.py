@@ -194,7 +194,7 @@ def test_spec_and_plain_widths_agree_bit_for_bit_on_the_cpu(dtype):
     the 1-wide step give the same tokens bit for bit: in f32 because
     that is what speculation promises, and on a CPU in bf16 too — the
     dtype every named size serves in — because both widths are one
-    helper (``decode_engine._step_logits``) and a CPU rounds a bf16
+    helper (``llama_slots._step_logits``) and a CPU rounds a bf16
     product once whatever its width. (While the plain step's head was a
     2-D product and the verify's a 3-D one, the CPU's bf16 parted as the
     chip's does, PR 21.) The chip's MXU rounds the two widths

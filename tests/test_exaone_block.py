@@ -66,7 +66,7 @@ def _cut(params, bits: int):
     drop = 23 - bits
 
     def cut(path, a):
-        if getattr(path[-1], "key", None) in exaone._F32_LEAVES:
+        if getattr(path[-1], "key", None) in exaone.SLOTS.F32_LEAVES:
             return a
         raw = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
         raw = (raw + jnp.uint32(1 << (drop - 1))) & jnp.uint32(
@@ -262,24 +262,6 @@ def test_submit_and_pump_serve_the_references_tokens(dtype):
     assert 0 < by_kind["window"] < by_kind["full"]
 
 
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(model):
-    """One slot, three streams one after another: each starts from its
-    own prompt's rings and rows, whatever the last stream left (a short
-    prompt after a long one leaves part of the ring zero, not stale)."""
-    cfg, params = model
-    rng = np.random.RandomState(2)
-    prompts = [rng.randint(1, 256, n).astype(np.int32) for n in (30, 3, 17)]
-    kw = dict(slots=1, max_len=64, chunk_tokens=4, prompt_buckets=(8, 32))
-    eng = RaggedDecoder(params, cfg, **kw)
-    sids = [eng.submit(p, 9) for p in prompts]
-    eng.drain()
-    for sid, p in zip(sids, prompts):
-        fresh = RaggedDecoder(params, cfg, **kw)
-        one = fresh.submit(p, 9)
-        fresh.drain()
-        assert eng.finished[sid].tokens == fresh.finished[one].tokens
-
-
 def test_spans_carry_the_state_and_the_rows_by_kind(model):
     from ray_tpu._private import flight_recorder as fr
 
@@ -374,72 +356,13 @@ def test_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(moe.moe(whole, p, x), want, atol=5e-5)
 
 
-def test_both_models_call_the_one_expert_layer():
-    from ray_tpu.models import ling
-
-    assert ling.moe is moe.moe and ling.route is moe.route
-    assert exaone.moe is moe.moe
-    # and the engine names neither: it finds a model's half from its
-    # configuration
-    with open(de.__file__) as f:
-        assert "exaone" not in f.read().lower()
-
-
-# ------------------------------------------------------ the refusals
-
-
-def test_the_prefix_cache_refuses_a_ring(model):
-    from ray_tpu.models.kv_prefix_cache import PrefixCache
-
-    cfg, params = model
-    with pytest.raises(ValueError, match="prefix cache.*ExaoneConfig"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64,
-                      prefix_cache=PrefixCache(block=8))
-    with pytest.raises(ValueError, match="ring"):
-        exaone.SLOTS.prefill(params, np.ones((1, 8), np.int32), None, None,
-                             None, None, cfg, 64, prefix=(0, 0, 0))
-
-
-def test_speculative_decoding_refuses_a_ring(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="speculative decoding.*Exaone"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
-    state = exaone.SLOTS.init_state(cfg, 2, 64)
-    vec = jnp.zeros((2,), jnp.int32)
-    with pytest.raises(ValueError, match="speculative decoding"):
-        de.decode_chunk_spec(params, None, state, vec, vec > 0,
-                             vec.astype(jnp.uint32), vec * 0.0, vec + 1.0,
-                             cfg, 2, 2, 1)
-
-
-def test_disaggregated_prefill_refuses_a_ring(model, monkeypatch):
-    from ray_tpu.serve import llm_pool
-
-    cfg, params = model
-    one = np.zeros((1,), np.int32)
-    with pytest.raises(ValueError, match="prefill_kv.*ExaoneConfig"):
-        de.prefill_kv(params, np.ones((1, 8), np.int32), one + 8,
-                      one.astype(np.uint32), one * 0.0, one + 1.0, cfg, 64)
-    eng = RaggedDecoder(params, cfg, slots=2, max_len=64,
-                        prompt_buckets=(8,))
-    with pytest.raises(ValueError, match="submit_prefilled"):
-        eng.submit_prefilled([1, 2, 3], 4, {"k": 0, "v": 0})
-    monkeypatch.setattr(llm_pool, "build_model",
-                        lambda *a, **k: (params, cfg))
-    with pytest.raises(ValueError, match="PrefillWorker.*ExaoneConfig"):
-        llm_pool.PrefillWorker("exaone")
-
-
-def test_init_params_makes_the_serving_types_in_blocks(monkeypatch):
-    """bf16 matrices, float32 norm vectors and router bias, and a leaf
-    larger than a block drawn block by block."""
+def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
+    """The attention's and the experts' shapes, a leaf larger than a
+    block drawn block by block, the published count of parameters (the
+    types: ``tests/test_slot_protocol.py``)."""
     monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
     cfg = _cfg(dtype="bfloat16")
     params = exaone.init_params(cfg, jax.random.PRNGKey(0))
-    assert exaone.serving_params(cfg, params) is params
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        f32 = path[-1].key in exaone._F32_LEAVES
-        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
     attn = params["layers"][3]["attn"]
     assert attn["w_qkv"].shape == (48, (8 + 2 * 2) * 16)
     assert attn["wo"].shape == (128, 48)

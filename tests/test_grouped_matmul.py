@@ -3,7 +3,7 @@ against ``jax.lax.ragged_dot`` (what the same function is off the TPU):
 forward, both gradients, empty groups, rows that do not fill a tile,
 and the stacked form a serving program's layer scan uses. That the
 kernel compiles for the chip at OLMoE's shapes is
-``tests/test_tpu_compile.py``'s."""
+``tests/test_tpu_compile_llama.py``'s."""
 
 import jax
 import jax.numpy as jnp

@@ -70,7 +70,7 @@ def _cut(params, bits: int):
     drop = 23 - bits
 
     def cut(path, a):
-        if getattr(path[-1], "key", None) in instella._F32_LEAVES:
+        if getattr(path[-1], "key", None) in instella.SLOTS.F32_LEAVES:
             return a
         raw = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
         raw = (raw + jnp.uint32(1 << (drop - 1))) & jnp.uint32(
@@ -315,24 +315,6 @@ def test_submit_and_pump_serve_the_references_tokens(dtype):
     assert st["attn_live_rows_by_kind"] == {"latent": st["attn_live_rows"]}
 
 
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(model):
-    """One slot, three streams one after another: a prefill writes its
-    bucket's rows and leaves what the last stream wrote behind them, and
-    no reader looks there (a short prompt after a long one)."""
-    cfg, params = model
-    rng = np.random.RandomState(2)
-    prompts = [rng.randint(1, 256, n).astype(np.int32) for n in (30, 3, 17)]
-    kw = dict(slots=1, max_len=64, chunk_tokens=4, prompt_buckets=(8, 32))
-    eng = RaggedDecoder(params, cfg, **kw)
-    sids = [eng.submit(p, 9) for p in prompts]
-    eng.drain()
-    for sid, p in zip(sids, prompts):
-        fresh = RaggedDecoder(params, cfg, **kw)
-        one = fresh.submit(p, 9)
-        fresh.drain()
-        assert eng.finished[sid].tokens == fresh.finished[one].tokens
-
-
 def test_spans_carry_the_latent_state_and_the_routing(model):
     from ray_tpu._private import flight_recorder as fr
 
@@ -363,58 +345,13 @@ def test_spans_carry_the_latent_state_and_the_routing(model):
     assert backs[-1]["experts_touched"] == M["top_k"]
 
 
-def test_the_engine_and_the_serving_tier_name_no_model():
-    from ray_tpu.serve import llm, llm_pool
-
-    for mod in (de, llm, llm_pool):
-        with open(mod.__file__) as f:
-            assert "instella" not in f.read().lower(), mod.__name__
-    assert de.slot_model(_cfg()) is instella.SLOTS
-    from ray_tpu.models import exaone
-
-    assert instella.moe is moe.moe is exaone.moe
-
-
-# ------------------------------------------------------ the refusals
-
-
-def test_the_prefix_cache_refuses_latent_rows(model):
-    from ray_tpu.models.kv_prefix_cache import PrefixCache
-
-    cfg, params = model
-    with pytest.raises(ValueError, match="prefix cache.*InstellaConfig"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64,
-                      prefix_cache=PrefixCache(block=8))
-    with pytest.raises(ValueError, match="latents"):
-        instella.SLOTS.prefill(params, np.ones((1, 8), np.int32), None, None,
-                               None, None, cfg, 64, prefix=(0, 0, 0))
-
-
-def test_speculation_and_disaggregated_prefill_refuse_latent_rows(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="speculative decoding.*Instella"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
-    one = np.zeros((1,), np.int32)
-    with pytest.raises(ValueError, match="prefill_kv.*InstellaConfig"):
-        de.prefill_kv(params, np.ones((1, 8), np.int32), one + 8,
-                      one.astype(np.uint32), one * 0.0, one + 1.0, cfg, 64)
-    eng = RaggedDecoder(params, cfg, slots=2, max_len=64,
-                        prompt_buckets=(8,))
-    with pytest.raises(ValueError, match="submit_prefilled"):
-        eng.submit_prefilled([1, 2, 3], 4, {"k": 0, "v": 0})
-
-
-def test_init_params_makes_the_serving_types_in_blocks(monkeypatch):
-    """bf16 matrices, float32 norm vectors and router bias, a leaf
-    larger than a block drawn block by block, ``w_down`` scaled for the
-    published depth and ``wo`` not."""
+def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
+    """The attention's and the experts' shapes, a leaf larger than a
+    block drawn block by block, ``w_down`` scaled for the published
+    depth and ``wo`` not (the types: ``tests/test_slot_protocol.py``)."""
     monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
     cfg = _cfg(dtype="bfloat16")
     params = instella.init_params(cfg, jax.random.PRNGKey(0))
-    assert instella.serving_params(cfg, params) is params
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        f32 = path[-1].key in instella._F32_LEAVES
-        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
     attn = params["layers"][2]["attn"]
     assert attn["wq"].shape == (64, 4 * 32)
     assert attn["w_kva"].shape == (64, 32 + 8)

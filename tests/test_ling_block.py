@@ -4,8 +4,9 @@ CPU, seeded: the chunkwise KDA prefill = stepping = the reference's
 recurrence; the absorbed MLA decode through the latent cache = the
 reference's unabsorbed forward; the router; the four shares of one expert
 layer add up to the uncut layer; the whole model through
-``RaggedDecoder`` at ragged positions, logits; a reused slot; and the
-three mechanisms that refuse a state that is not rows.
+``RaggedDecoder`` at ragged positions, logits. (A reused slot, the three
+mechanisms that refuse a state that is not rows and the serving types:
+``tests/test_slot_protocol.py``, every block's.)
 
 Tolerances (readings of ``test_prefill_then_ragged_decode...``'s own
 comparison, logits that spread by 1, this CPU). In float32 both sides
@@ -29,7 +30,7 @@ import pytest
 
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
-from ray_tpu.models import ling
+from ray_tpu.models import ling, moe
 from ray_tpu.models.decode_engine import RaggedDecoder
 
 F32_TOL = 1e-4
@@ -51,7 +52,7 @@ def _cut(params, bits: int):
     drop = 23 - bits
 
     def cut(path, a):
-        if getattr(path[-1], "key", None) in ling._F32_LEAVES:
+        if getattr(path[-1], "key", None) in ling.SLOTS.F32_LEAVES:
             return a
         raw = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
         raw = (raw + jnp.uint32(1 << (drop - 1))) & jnp.uint32(
@@ -145,7 +146,7 @@ def test_mla_absorbed_decode_over_the_latent_cache_is_the_reference():
 
 def _route_both(scores, bias, **kw):
     cfg, m = _cfg(**kw), {**M, **kw}
-    weights, ids = ling.route(cfg, scores, bias)
+    weights, ids = moe.route(cfg, scores, bias)
     gates, chosen = REF.router(m, scores, bias)
     got = jnp.sum(jax.nn.one_hot(ids, cfg.n_experts) * weights[..., None], -2)
     return np.asarray(weights), np.asarray(ids), np.asarray(got), \
@@ -220,7 +221,7 @@ def test_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         share = {**p, **{w: p[w][first:first + 8]
                          for w in ("w_gate", "w_up", "w_down")}}
         aux = {}
-        part = ling.moe(_cfg(held_experts=(first, 8)), share, x, aux)
+        part = moe.moe(_cfg(held_experts=(first, 8)), share, x, aux)
         assert aux["expert_ids"].shape == (2, 9, M["top_k"])
         with jax.default_matmul_precision("highest"):
             np.testing.assert_allclose(
@@ -229,7 +230,7 @@ def test_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         total = total + (part - shared)
     np.testing.assert_allclose(total + shared, want, atol=5e-5)
     # the uncut program layer is the uncut reference layer too
-    np.testing.assert_allclose(ling.moe(whole, p, x), want, atol=5e-5)
+    np.testing.assert_allclose(moe.moe(whole, p, x), want, atol=5e-5)
 
 
 # ------------------------------------- the model, through the engine
@@ -330,23 +331,6 @@ def test_submit_and_pump_serve_the_references_tokens(dtype):
     assert st["moe_assignments"] > 0 and st["moe_touched_expert_steps"] > 0
 
 
-def test_a_reused_slot_gives_the_tokens_of_a_fresh_engine(model):
-    """One slot, three streams one after another: each starts from a
-    zero S and zero convolution rows, whatever the last stream left."""
-    cfg, params = model
-    rng = np.random.RandomState(2)
-    prompts = [rng.randint(1, 256, n).astype(np.int32) for n in (30, 6, 17)]
-    kw = dict(slots=1, max_len=64, chunk_tokens=4, prompt_buckets=(8, 32))
-    eng = RaggedDecoder(params, cfg, **kw)
-    sids = [eng.submit(p, 9) for p in prompts]
-    eng.drain()
-    for sid, p in zip(sids, prompts):
-        fresh = RaggedDecoder(params, cfg, **kw)
-        one = fresh.submit(p, 9)
-        fresh.drain()
-        assert eng.finished[sid].tokens == fresh.finished[one].tokens
-
-
 def test_readback_counts_the_held_share_and_weights_can_be_swapped(model):
     from ray_tpu._private import flight_recorder as fr
 
@@ -379,73 +363,13 @@ def test_readback_counts_the_held_share_and_weights_can_be_swapped(model):
     assert list(eng.finished[sid3].tokens) == first
 
 
-# ------------------------------------------------------ the refusals
-
-
-def test_the_prefix_cache_refuses_a_state_that_is_not_rows(model):
-    from ray_tpu.models.kv_prefix_cache import PrefixCache
-
-    cfg, params = model
-    with pytest.raises(ValueError, match="prefix cache"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64,
-                      prefix_cache=PrefixCache(block=8))
-
-
-def test_speculative_decoding_refuses_a_state_that_is_not_rows(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="speculative decoding"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
-    state = ling.SLOTS.init_state(cfg, 2, 64)
-    vec = jnp.zeros((2,), jnp.int32)
-    with pytest.raises(ValueError, match="speculative decoding"):
-        de.decode_chunk_spec(params, None, state, vec, vec > 0,
-                             vec.astype(jnp.uint32), vec * 0.0, vec + 1.0,
-                             cfg, 2, 2, 1)
-
-
-def test_disaggregated_prefill_refuses_a_state_that_is_not_rows(
-        model, monkeypatch):
-    from ray_tpu.serve import llm_pool
-
-    cfg, params = model
-    one = np.zeros((1,), np.int32)
-    with pytest.raises(ValueError, match="prefill_kv"):
-        de.prefill_kv(params, np.ones((1, 8), np.int32), one + 8,
-                      one.astype(np.uint32), one * 0.0, one + 1.0, cfg, 64)
-    eng = RaggedDecoder(params, cfg, slots=2, max_len=64,
-                        prompt_buckets=(8,))
-    with pytest.raises(ValueError, match="submit_prefilled"):
-        eng.submit_prefilled([1, 2, 3], 4, {"k": 0, "v": 0})
-    monkeypatch.setattr(llm_pool, "build_model",
-                        lambda *a, **k: (params, cfg))
-    with pytest.raises(ValueError, match="PrefillWorker"):
-        llm_pool.PrefillWorker("ling")
-    # the Llama block's state is rows: nothing is refused there
-    from ray_tpu.models import llama
-
-    de.require_rows(llama.LlamaConfig.tiny(), "anything")
-
-
-def test_init_params_makes_the_serving_types_in_blocks(monkeypatch):
-    """bf16 matrices, float32 norm vectors and decay parameters, and a
-    leaf larger than a block drawn block by block (the same values
-    whatever the block size would be a different draw: only types,
-    shapes and statistics are held)."""
-    from ray_tpu.models import moe  # (where the blocks are drawn)
-
+def test_init_params_draws_a_large_leaf_in_blocks(monkeypatch):
+    """A leaf larger than a block drawn block by block (the same values
+    whatever the block size would be a different draw: only shapes and
+    statistics are held; the types: ``tests/test_slot_protocol.py``)."""
     monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
     cfg = _cfg(dtype="bfloat16")
     params = ling.init_params(cfg, jax.random.PRNGKey(0))
-    assert ling.serving_params(cfg, params) is params
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-    for path, leaf in flat:
-        f32 = path[-1].key in ling._F32_LEAVES
-        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
     w = np.asarray(params["layers"][1]["mlp"]["w_gate"], np.float32)
     assert w.shape == (8, 64, 32) and abs(w.std() * 8 - 1) < 0.1
     assert len(np.unique(w[0])) > 100 and not np.array_equal(w[0], w[1])
-    # a float32 tree is cast once, on adoption
-    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
-    cast = ling.serving_params(cfg, f32)
-    assert cast["layers"][0]["attn"]["w_qkv"].dtype == jnp.bfloat16
-    assert cast["layers"][0]["attn"]["a_log"].dtype == jnp.float32

@@ -23,6 +23,7 @@ from _oracle import greedy_tokens
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import llama
+from ray_tpu.models import llama_slots
 
 TOL = 1e-4
 FAMILY = manifest.family("olmoe")
@@ -113,7 +114,7 @@ def test_loss_and_gradients_match_the_reference(ref, cfg, params):
 def _prefill(cfg, params, prompts, bucket, slots, max_len):
     """Each prompt through the engine's own one-row prefill call into
     its slot -> (cache, cur_tok, first tokens, first logprobs, loads)."""
-    cache = de.init_ragged_cache(cfg, slots, max_len)
+    cache = llama_slots.init_ragged_cache(cfg, slots, max_len)
     cur = jnp.zeros((slots,), jnp.int32)
     toks0, lps0, loads = [], [], []
     for slot, prompt in enumerate(prompts):
@@ -247,7 +248,7 @@ def test_a_dense_model_is_the_program_it_was(cfg):
     traced = jax.make_jaxpr(lambda p, t: llama.forward(p, t, dense))(
         shapes, jnp.zeros((2, 16), jnp.int32))
     assert count(traced.jaxpr) == 122
-    cache = jax.eval_shape(lambda: de.init_ragged_cache(dense, 2, 32))
+    cache = jax.eval_shape(lambda: llama_slots.init_ragged_cache(dense, 2, 32))
     out = jax.eval_shape(
         lambda p, c: de.decode_chunk(p, c, jnp.zeros(2, jnp.int32),
                                      jnp.ones(2, bool), None, dense, 4),

@@ -1,6 +1,6 @@
 """A cold prefill does its prompt's work, not its slot's (ISSUE 35).
 
-``decode_engine._prefill_core`` is sized by the prompts' bucket P: each
+``llama_slots._prefill_core`` is sized by the prompts' bucket P: each
 layer attends over the prompt's own P rows (``ops.attention``: the flash
 kernel on a TPU, here the reference product or the kernel interpreted),
 the final norm and the head see the last real position alone, and P rows
@@ -25,6 +25,7 @@ import jax.numpy as jnp  # noqa: E402
 from _oracle import greedy_tokens  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models import llama_slots  # noqa: E402
 from ray_tpu.models.kv_prefix_cache import PrefixCache  # noqa: E402
 
 SLOTS, MAX_LEN = 3, 48
@@ -58,7 +59,7 @@ def _prefill(cfg, params, prompt, width, slot, cache=None):
     row = np.zeros((1, width), np.int32)
     row[0, :len(prompt)] = prompt
     if cache is None:
-        cache = de.init_ragged_cache(cfg, SLOTS, MAX_LEN)
+        cache = llama_slots.init_ragged_cache(cfg, SLOTS, MAX_LEN)
     return de._prefill_batch_into_slots(
         params, row, np.array([len(prompt)], np.int32),
         np.array([slot], np.int32), *_lanes(), cache,
@@ -85,7 +86,7 @@ def test_cold_prefill_is_the_uncached_forwards(name, bucket, attn,
         cfg = dataclasses.replace(cfg, use_flash=True)
     n, slot = BUCKETS[bucket], 1
     prompt = _prompt(n)
-    lay = de.init_ragged_cache(cfg, SLOTS, MAX_LEN)
+    lay = llama_slots.init_ragged_cache(cfg, SLOTS, MAX_LEN)
     lay = {**lay, "k": lay["k"] + 7.0, "v": lay["v"] - 7.0}
     was = {kv: np.asarray(lay[kv]) for kv in "kv"}  # (lay is donated)
     cache, cur, tok0, lp0, *loads = _prefill(
@@ -134,7 +135,7 @@ def test_cold_prefill_is_sized_by_its_bucket(name, bucket):
     text = de._prefill_batch_into_slots.lower(
         params, jax.ShapeDtypeStruct((1, bucket), jnp.int32),
         vec(jnp.int32), vec(jnp.int32), vec(jnp.uint32), vec(jnp.float32),
-        vec(jnp.float32), de.init_ragged_cache(cfg, SLOTS, MAX_LEN),
+        vec(jnp.float32), llama_slots.init_ragged_cache(cfg, SLOTS, MAX_LEN),
         jnp.zeros((SLOTS,), jnp.int32), cfg=cfg).as_text()
     shapes = {tuple(int(d) for d in dims[:-1].split("x"))
               for dims in re.findall(r"tensor<((?:\d+x)+)", text)}

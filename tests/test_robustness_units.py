@@ -350,7 +350,8 @@ def test_decode_chunk_clamps_pos_at_cache_edge(chunk):
     running past the cache."""
     jax = pytest.importorskip("jax")
     from ray_tpu.models import llama
-    from ray_tpu.models.decode_engine import decode_chunk, init_ragged_cache
+    from ray_tpu.models.decode_engine import decode_chunk
+    from ray_tpu.models.llama_slots import init_ragged_cache
 
     cfg = llama.LlamaConfig(
         vocab_size=64, d_model=16, n_layers=1, n_heads=2, n_kv_heads=2,

@@ -28,6 +28,7 @@ from _oracle import greedy_tokens
 from ray_tpu._private import flight_recorder as fr
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import llama, mlp
+from ray_tpu.models import llama_slots
 from ray_tpu.models.decode_engine import RaggedDecoder
 
 _DENSE = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
@@ -82,7 +83,7 @@ def _prefilled(cfg, tree, lanes=None):
     return de._prefill_batch_into_slots(
         tree, _prompts(), np.array(LENS, np.int32),
         np.arange(SLOTS, dtype=np.int32), *(lanes or _lanes(SLOTS)),
-        de.init_ragged_cache(cfg, SLOTS, MAX_LEN),
+        llama_slots.init_ragged_cache(cfg, SLOTS, MAX_LEN),
         jnp.zeros((SLOTS,), jnp.int32), cfg)
 
 
@@ -118,7 +119,7 @@ def _prefill_prefix(cfg, tree):
     return de._prefill_batch_into_slots(
         tree, suffix, np.array(LENS, np.int32) - n_pref,
         np.arange(SLOTS, dtype=np.int32), *_lanes(SLOTS, seed=7, temp=0.5),
-        de.init_ragged_cache(cfg, SLOTS, MAX_LEN),
+        llama_slots.init_ragged_cache(cfg, SLOTS, MAX_LEN),
         jnp.zeros((SLOTS,), jnp.int32), cfg,
         (pref["k"], pref["v"], np.int32(n_pref)))
 

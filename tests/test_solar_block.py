@@ -68,7 +68,7 @@ def _cut(params, bits: int):
     drop = 23 - bits
 
     def cut(path, a):
-        if getattr(path[-1], "key", None) in solar._F32_LEAVES:
+        if getattr(path[-1], "key", None) in solar.SLOTS.F32_LEAVES:
             return a
         raw = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
         raw = (raw + jnp.uint32(1 << (drop - 1))) & jnp.uint32(
@@ -452,14 +452,14 @@ def test_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     for first in range(0, 16, 2):
         share = {**p, **{w: p[w][first:first + 2]
                          for w in ("w_gate", "w_up", "w_down")}}
-        part = solar.moe(_cfg(held_experts=(first, 2)), share, x)
+        part = moe.moe(_cfg(held_experts=(first, 2)), share, x)
         with jax.default_matmul_precision("highest"):
             np.testing.assert_allclose(
                 part, REF.moe_layer(M, share, x, held=(first, 2)),
                 atol=2e-5)
         total = total + (part - shared)
     np.testing.assert_allclose(total + shared, want, atol=5e-5)
-    np.testing.assert_allclose(solar.moe(whole, p, x), want, atol=5e-5)
+    np.testing.assert_allclose(moe.moe(whole, p, x), want, atol=5e-5)
 
 
 # ------------------------------------------------------ RaggedDecoder
@@ -492,24 +492,6 @@ def test_submit_and_pump_serve_the_references_tokens(dtype):
         kind: 3 * n for kind, n in
         FAM.state_bytes_per_slot(M, 96, jnp.dtype(dtype).itemsize).items()}
     assert st["moe_assignments"] > 0 and st["moe_touched_expert_steps"] > 0
-
-
-def test_a_reused_slot_shows_nothing_of_its_last_stream(model):
-    """One slot, three streams one after another, the second and third
-    shorter than the first: each starts from a zero S and zero
-    convolution rows and reads no row of k / v past its own length,
-    whatever the last stream left: a fresh engine's tokens."""
-    cfg, params = model
-    prompts = _prompts(2, (30, 6, 17))
-    kw = dict(slots=1, max_len=64, chunk_tokens=4, prompt_buckets=(8, 32))
-    eng = RaggedDecoder(params, cfg, **kw)
-    sids = [eng.submit(p, 9) for p in prompts]
-    eng.drain()
-    for sid, p in zip(sids, prompts):
-        fresh = RaggedDecoder(params, cfg, **kw)
-        one = fresh.submit(p, 9)
-        fresh.drain()
-        assert eng.finished[sid].tokens == fresh.finished[one].tokens
 
 
 def test_the_slots_do_not_fall_into_one_cycle(model):
@@ -557,67 +539,18 @@ def test_spans_carry_both_kinds_of_state_the_segments_and_the_routing(
         <= M["top_k"]
 
 
-def test_the_engine_and_the_serving_tier_name_no_model():
-    from ray_tpu.models import exaone, instella
-    from ray_tpu.serve import llm, llm_pool
-
-    for mod in (de, llm, llm_pool):
-        with open(mod.__file__) as f:
-            text = f.read().lower()
-        # (the protocol's docstring says which block runs in segments)
-        assert "import solar" not in text and "solarconfig" not in text \
-            and "solar_open" not in text, mod.__name__
-    assert de.slot_model(_cfg()) is solar.SLOTS
-    assert solar.moe is moe.moe is exaone.moe is instella.moe is ling.moe
+def test_both_kda_blocks_call_the_one_step_kernel():
     assert solar._kda_step is ling._kda_step is ks.kda_step
 
 
-# ------------------------------------------------------ the refusals
-
-
-def test_the_prefix_cache_and_speculation_refuse_a_recurrent_state(model):
-    from ray_tpu.models.kv_prefix_cache import PrefixCache
-
-    cfg, params = model
-    with pytest.raises(ValueError, match="prefix cache"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64,
-                      prefix_cache=PrefixCache(block=8))
-    with pytest.raises(ValueError, match="speculative decoding"):
-        RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
-    with pytest.raises(ValueError, match="recurrent"):
-        solar.SLOTS.prefill(params, None, None, None, None, None, cfg, 64,
-                            prefix={"k": 0})
-
-
-def test_disaggregated_prefill_refuses_a_recurrent_state(model, monkeypatch):
-    from ray_tpu.serve import llm_pool
-
-    cfg, params = model
-    one = np.zeros((1,), np.int32)
-    with pytest.raises(ValueError, match="prefill_kv"):
-        de.prefill_kv(params, np.ones((1, 8), np.int32), one + 8,
-                      one.astype(np.uint32), one * 0.0, one + 1.0, cfg, 64)
-    eng = RaggedDecoder(params, cfg, slots=2, max_len=64,
-                        prompt_buckets=(8,))
-    with pytest.raises(ValueError, match="submit_prefilled"):
-        eng.submit_prefilled([1, 2, 3], 4, {"k": 0, "v": 0})
-    monkeypatch.setattr(llm_pool, "build_model",
-                        lambda *a, **k: (params, cfg))
-    with pytest.raises(ValueError, match="PrefillWorker"):
-        llm_pool.PrefillWorker("solar")
-
-
-def test_init_params_makes_the_serving_types_in_blocks(monkeypatch):
-    """bf16 matrices, float32 norm vectors, decay parameters and router
-    bias; a leaf larger than a block drawn block by block; the matrices
-    that write into the stream scaled for the published depth."""
+def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
+    """Both kinds of layer's leaves and shapes; a leaf larger than a
+    block drawn block by block; the matrices that write into the stream
+    scaled for the published depth (the types:
+    ``tests/test_slot_protocol.py``)."""
     monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
     cfg = _cfg(dtype="bfloat16")
     params = solar.init_params(cfg, jax.random.PRNGKey(0))
-    assert solar.serving_params(cfg, params) is params
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        f32 = path[-1].key in solar._F32_LEAVES
-        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
     kda, gqa = params["layers"][1]["attn"], params["layers"][0]["attn"]
     assert set(kda) == {"w_qkv", "conv", "w_f_down", "w_f_up", "dt_bias",
                         "a_log", "w_beta", "w_g_down", "w_g_up", "o_norm",

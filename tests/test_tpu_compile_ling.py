@@ -1,0 +1,155 @@
+"""The hybrid block (``models/ling.py``) at the reason cell's sizes,
+compiled for a described v5e (``tests/_tpu_compile.py`` says how and
+why): its decode chunk and its one-row prefill.
+"""
+
+import functools
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from _tpu_compile import (  # noqa: F401 (topo: a fixture)
+    _kda_chunk_calls, KERNEL, _lower_prefill, _mem, MIB, _on, topo)
+from ray_tpu.models import decode_engine as de
+
+
+def _ling_cell(topo, monkeypatch):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s model, engine
+    shape and arguments on one described chip, the kernels asked for by
+    name (the dispatches would read the CPU backend here)."""
+    from benchmark import manifest
+    from ray_tpu.models import ling
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_step", functools.partial(
+        ling._kda_step, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_chunk", functools.partial(
+        ling._kda_chunk, use_kernel=True))
+    with open("benchmark/traffic/reason-saturated.json") as f:
+        eng = json.load(f)["engine"]
+    fam, m = manifest.model("ling-3.0-flash-vl-ep4-1chip")
+    prog = fam.build(m, max_seq_len=eng["max_len"], remat=False)
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(prog.init_params,
+                                      jax.random.PRNGKey(0)))
+    state = _on(chip, jax.eval_shape(lambda: ling.SLOTS.init_state(
+        prog.cfg, eng["slots"], eng["max_len"])))
+    vec = lambda dt, n=eng["slots"]: jax.ShapeDtypeStruct(  # noqa: E731
+        (n,), dt, sharding=chip)
+    return fam, m, prog.cfg, eng, params, state, vec
+
+
+def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
+        topo, monkeypatch):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s decode program
+    (7 layers, 128 of 512 experts held, 32 slots x 3088 rows): three
+    kernel calls an expert layer; the donated state is updated in place
+    and never copied (the six float32 ``[32,32,128,128]`` KDA states,
+    the latent rows ``[32,3088,512]``); no matrix exists in float32 (the tree arrives in
+    the serving types: a cast of one 250 M expert stack is 1 GB); and
+    arguments and temporaries stay under 13 GiB of the chip's 16."""
+    from ray_tpu.models import ling
+
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
+    slots, max_len = eng["slots"], eng["max_len"]
+    compiled = de.decode_chunk.lower(
+        params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+        chunk=eng["chunk_tokens"]).compile()
+    text = compiled.as_text()
+    kda_calls = [line for line in text.splitlines()
+                 if KERNEL in line and "kda_step" in line.split(" = ")[0]]
+    assert len(kda_calls) == sum(
+        cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
+    assert text.count(KERNEL) == 3 * cfg.moe_layers + len(kda_calls) == 24
+    for dims in (f"f32[{slots},32,128,128]", f"bf16[{slots},{max_len},512]"):
+        assert dims in text
+        assert not re.search(re.escape(dims) + r"\S* copy\(", text), dims
+    # a KDA layer's step is one call that takes its state as operand 3
+    # (behind ``active``, the vectors and v) and returns it in that
+    # buffer; nothing else moves a state, whole or a quarter of it (the
+    # XLA body's slices between memories; and with the kernel's operand
+    # left to the compiler, its own: it brought four layers' states into
+    # VMEM in quarters before the call and copied them back behind it)
+    for line in kda_calls:
+        assert line.split(" = ")[1].startswith(
+            f"(f32[{slots},32,128,128]"), line
+        assert "output_to_operand_aliasing={{0}: (3, {})}" in line, line
+    moves = re.compile(r"\s*%(copy|copy-start|slice-start|async-start|"
+                       r"dynamic-slice-start)[.\d]* = ")
+    moved = [line[:160] for line in text.splitlines() if moves.match(line)
+             and re.search(rf"f32\[({slots}|{slots // 4}),32,128,128\]", line)]
+    assert not moved, moved[:3]
+    # (the 64-wide rotated keys, 2% of the state, change their layout
+    # once a chunk on the way in and out of the step loop: XLA's choice
+    # for a minor dimension of half a lane tile, outside the loop)
+    assert len(re.findall(rf"bf16\[{slots},{max_len},64\]\S* copy\(",
+                          text)) <= 2
+    matrices = {a.shape for a in jax.tree_util.tree_leaves(params)
+                if a.dtype == jnp.bfloat16 and a.size > 1 << 20}
+    assert (128, 2560, 768) in matrices and (2560, 12288) in matrices
+    for shape in matrices:
+        assert f"f32[{','.join(map(str, shape))}]" not in text, shape
+    mem = compiled.memory_analysis()
+    state_bytes = sum(ling.SLOTS.state_bytes(state).values())
+    assert state_bytes == slots * sum(
+        fam.state_bytes_per_slot(m, max_len).values())
+    assert mem.alias_size_in_bytes >= state_bytes, _mem(compiled)
+    print(f"\nling decode chunk: {_mem(compiled)}")
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 13 * 1024 * MIB), _mem(compiled)
+
+
+@pytest.mark.parametrize("bucket", [256, 512, 1024])
+def test_ling_prefill_holds_one_kda_chunk_call_a_kda_layer(
+        topo, monkeypatch, bucket):
+    """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s cold prefill
+    call at each of its buckets (32 heads, 4 to 16 chunks): the
+    chunkwise delta rule is one ``kda_chunk`` call a KDA layer, six a
+    program, on the arrays as the projections leave them
+    (``_kda_chunk_calls``); arguments and temporaries stay under 13 GiB
+    of the chip's 16."""
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
+    assert tuple(eng["prompt_buckets"]) == (256, 512, 1024)
+    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, bucket,
+                              (params, state, vec)).compile()
+    # (at 256 and 512 rows XLA prefetches a layer's 4 to 8 MB ``g`` into
+    # VMEM ahead of two of the calls; at 1,024 nothing moves)
+    calls = _kda_chunk_calls(compiled.as_text(), prefetched_ok=bucket < 1024)
+    assert len(calls) == sum(
+        cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
+    assert all(f"f32[1,{bucket},4096]" in c for c in calls), calls[0][:300]
+    mem = compiled.memory_analysis()
+    print(f"\nling prefill 1 x {bucket}: {_mem(compiled)}")
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 13 * 1024 * MIB), _mem(compiled)
+
+
+def test_lowering_lings_prefill_traces_the_kda_chunk_kernel_once(
+        topo, monkeypatch):
+    """What the kernel costs a process's start is its trace
+    (``ops/kda_chunk.py``: a thousand lines of columns, seconds each):
+    the call is jitted by itself, so lowering the 1,024-row prefill with
+    its six KDA layers runs the kernel's body ONCE, not once a layer,
+    and the lowered module holds one copy of the kernel that the six
+    layers call. (Traced a layer, Ling's set-up read 120-160 s for the
+    parent's 80-88: ``PERF.md`` §6, PRs 45-47.)"""
+    from ray_tpu.ops import kda_chunk as kc
+
+    fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
+    traced = []
+    body = kc._kernel
+    monkeypatch.setattr(kc, "_kernel", lambda *a, **kw: (
+        traced.append(kw), body(*a, **kw))[1])
+    jax.clear_caches()  # (an earlier test's trace of this shape)
+    lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 1024,
+                             (params, state, vec))
+    assert traced == [{"hb": 16}], len(traced)
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @_kda_chunk\w*\(", text)) == 1
+    assert len(re.findall(r"call @_kda_chunk\w*\(", text)) == 6
