@@ -1394,7 +1394,7 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu._private import accelerator
-    from ray_tpu.models import solar
+    from ray_tpu.models import moe, solar
     from ray_tpu.ops import decode_attention as da
     from ray_tpu.ops.attention import attention
 
@@ -1411,10 +1411,10 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
         xs = jnp.moveaxis(x, 1, 0)[:, :, None]  # [T, 1, 1, D]
         on, lens_ = jnp.ones((1,), bool), jnp.array([t])
         empty = solar.kda_empty(cfg, 1)
-        st, y_kda = solar._in_segments(
+        st, y_kda = moe.in_segments(
             lambda state, seg: solar.kda_segment(
                 cfg, kda, seg[1], state, seg[0], lens_)[::-1],
-            empty, x, solar.segment_rows(cfg, t))
+            empty, x, moe.segment_rows(t, cfg.kda_chunk))
         st_step, y_kda_step = jax.lax.scan(
             lambda s, x_t: solar.kda_step(cfg, kda, x_t, s, on)[::-1],
             empty, xs)

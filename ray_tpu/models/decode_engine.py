@@ -532,15 +532,22 @@ class RaggedDecoder:
         """``engine.state_init`` (ring-only, and an instant event on the
         profiler's host line): what the slots hold, ``<kind>_bytes`` for
         each kind of state the model keeps and, where its layers keep
-        rows of several kinds, ``<kind>_layers``. Once an engine, and again
-        where a trace starts (``LLMServer.start_trace``), so that a
-        trace says what engine it is of."""
+        rows of several kinds, ``<kind>_layers`` and ``<kind>_row_bytes``
+        (one position's bytes in one layer of a kind that keeps rows:
+        the kind's bytes over its layers, slots and rows a slot). Once
+        an engine, and again where a trace starts
+        (``LLMServer.start_trace``), so that a trace says what engine it
+        is of."""
+        kinds = {kind: (n, self.max_len if most is None else most)
+                 for kind, (n, most) in self.row_kinds.items()}
         _fr.mark("serve", "engine.state_init", flush=False, attrs={
             "engine": self.name, "slots": self.slots,
             "max_len": self.max_len,
             **{f"{kind}_bytes": n for kind, n in self.state_bytes.items()},
-            **{f"{kind}_layers": n
-               for kind, (n, _) in self.row_kinds.items()}})
+            **{f"{kind}_layers": n for kind, (n, _) in kinds.items()},
+            **{f"{kind}_row_bytes":
+               self.state_bytes[kind] // (n * self.slots * rows)
+               for kind, (n, rows) in kinds.items() if n * rows}})
 
     def program_parts(self) -> dict:
         """{program, as a trace's ``XLA Modules`` line names it: [{"what":

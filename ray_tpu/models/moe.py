@@ -2,12 +2,14 @@
 (``models/ling.py``, ``exaone.py``, ``instella.py``, ``solar.py``): the
 expert layer of a device that holds a part of its experts with the
 router in front of it (DeepSeek-V3's routing), a layer's MLP around it,
-the head, the loss, and how the leaves these read are drawn.
+the head, the loss, how the leaves these read are drawn, and how a
+long prompt's tokenwise work is cut into row segments.
 
 Sigmoid scores in float32, a bias added for selection only,
 ``topk_group`` of ``n_group`` groups kept by the sum of their two best,
 the ``top_k`` best of those chosen, their unbiased scores renormalised
-and scaled; a shared expert beside them. ``held`` = (first, count) tells
+and scaled; a shared expert beside them (``shared_d_ff`` 0: none, no
+leaves and no work). ``held`` = (first, count) tells
 the layer which experts live here: it routes over all of them and
 computes the part of the result that its own give (:func:`moe`); what
 the others would add is left out.
@@ -34,6 +36,9 @@ from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 
 _BLOCK_ELEMS = 1 << 22  # a leaf is drawn in float32 blocks of this many
+# the most rows of a prompt whose tokenwise work is done at once (a
+# float32 ``[rows, 64, 128]`` array is then 67 MB): segment_rows
+SEGMENT_ROWS = 2048
 
 
 class HeldExperts:
@@ -106,10 +111,11 @@ def init_dense(cfg, mat) -> dict:
 
 def init_experts(cfg, mat, keys) -> dict:
     """An expert layer's leaves, as :func:`moe` reads them: the router
-    over ALL experts, the held experts' matrices, the shared expert."""
+    over ALL experts, the held experts' matrices, the shared expert
+    (where the model has one: ``shared_d_ff`` > 0)."""
     d, f, fs = cfg.d_model, cfg.d_ff, cfg.shared_d_ff
     _, count = cfg.held
-    return {
+    leaves = {
         "router": mat(d, cfg.n_experts),
         # (small against the scores' spread of 0.2: the top 3% of
         # sigmoids lie where a bias of 0.1 is a standard deviation
@@ -118,9 +124,11 @@ def init_experts(cfg, mat, keys) -> dict:
             next(keys), (cfg.n_experts,), jnp.float32),
         "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
         "w_down": mat(count, f, d, out=True),
-        "shared_gate": mat(d, fs), "shared_up": mat(d, fs),
-        "shared_down": mat(fs, d, out=True),
     }
+    if fs:
+        leaves.update({"shared_gate": mat(d, fs), "shared_up": mat(d, fs),
+                       "shared_down": mat(fs, d, out=True)})
+    return leaves
 
 
 def init_model(cfg, mat, around_one, keys, layers: list) -> dict:
@@ -173,8 +181,8 @@ def moe(cfg, p, x, aux: dict | None = None):
     belong to no group, which the grouped matmul never visits
     (``ops/grouped_matmul.py``: its grid covers the groups' rows only;
     off the TPU ``ragged_dot`` leaves such rows zero), its gather reads
-    row 0 and its part of the sum is masked. The shared expert is
-    computed in full. On one device the layer runs without an exchange:
+    row 0 and its part of the sum is masked. The shared expert, where
+    ``p`` has one, is computed in full. On one device the layer runs without an exchange:
     the other devices' partial sums are not here and nothing stands in
     for them. With ``aux`` the chosen ids [B, T, top_k] are left in
     ``aux["expert_ids"]``."""
@@ -208,6 +216,8 @@ def moe(cfg, p, x, aux: dict | None = None):
             jnp.arange(order.shape[0], dtype=order.dtype))
         y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
         out = jnp.sum(y.reshape(b * t, kk, d) * weights[..., None], axis=1)
+    if "shared_gate" not in p:
+        return out.astype(cdt).reshape(b, t, d)
     with jax.named_scope("moe_shared"):
         out = out.astype(cdt) + swiglu(
             xf, p["shared_gate"], p["shared_up"], p["shared_down"])
@@ -218,7 +228,7 @@ def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None):
     """A layer's MLP with its norm, added to ``h`` [B, T, D]: the dense
     SwiGLU (scope ``mlp``) or, where the layer is ``sparse``, the expert
     layer (``moe_router``, the norm with it, ``moe_experts``,
-    ``moe_shared``, the residual with it)."""
+    ``moe_shared``, the residual with the last of them)."""
     if not sparse:
         with jax.named_scope("mlp"):
             x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
@@ -227,8 +237,42 @@ def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None):
     with jax.named_scope("moe_router"):
         x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
     y = moe(cfg, p["mlp"], x, aux)
-    with jax.named_scope("moe_shared"):
+    with jax.named_scope("moe_shared" if "shared_gate" in p["mlp"]
+                         else "moe_experts"):
         return h + y
+
+
+def segment_rows(t: int, whole: int = 1) -> int:
+    """The rows of one segment of a ``t``-row prefill: ``t`` itself up to
+    :data:`SEGMENT_ROWS`, else the equal segments of at most that many
+    rows, each a multiple of ``whole`` (a block's chunk)."""
+    n = -(-t // SEGMENT_ROWS)
+    if t % n or (n > 1 and (t // n) % whole):
+        raise ValueError(
+            f"a prefill of {t} rows is run in {n} segments of at most "
+            f"{SEGMENT_ROWS} rows: {t} must divide into {n} equal "
+            f"segments of whole {whole}-row chunks")
+    return t // n
+
+
+def in_segments(body, carry, xs, seg: int):
+    """``body(carry, (segment's first row, the segment's rows of xs)) ->
+    (carry, outputs with a leading [B, seg])`` over the segments of
+    ``xs`` (a tree of [B, T, ...] arrays) in order -> (carry, the
+    outputs [B, T, ...]). One segment is one plain call."""
+    b, t = jax.tree_util.tree_leaves(xs)[0].shape[:2]
+    n = t // seg
+    if n == 1:
+        return body(carry, (jnp.int32(0), xs))
+
+    def rows(a):  # [B, T, ...] -> [n, B, seg, ...]
+        return jnp.moveaxis(a.reshape(b, n, seg, *a.shape[2:]), 1, 0)
+
+    carry, outs = jax.lax.scan(body, carry, (
+        jnp.arange(n, dtype=jnp.int32) * seg,
+        jax.tree_util.tree_map(rows, xs)))
+    return carry, jax.tree_util.tree_map(
+        lambda a: jnp.moveaxis(a, 0, 1).reshape(b, t, *a.shape[3:]), outs)
 
 
 @jax.named_scope("lm_head")

@@ -42,11 +42,11 @@ does not fit as whole arrays (q, k, v, g of ``[T, 64, 128]`` float32 are
 So everything that is a function of a row and a carried state (norms,
 projections, the convolution, the chunkwise delta rule, gates and
 ``W_o``, router and experts) runs over segments of at most
-:data:`SEGMENT_ROWS` rows under one ``lax.scan`` a layer, a KDA layer's
+``moe.SEGMENT_ROWS`` rows under one ``lax.scan`` a layer, a KDA layer's
 ``S`` and last three projection rows carried from segment to segment;
 only what needs the whole prompt is whole: the GQA layer's q, k and v
 (bf16) and one flash call over them. The bucket decides: a bucket of at
-most ``SEGMENT_ROWS`` is one segment (:func:`segment_rows`). No option
+most ``moe.SEGMENT_ROWS`` is one segment (``moe.segment_rows``). No option
 chooses it.
 
 A slot's state in the serving engine is this model's own
@@ -80,10 +80,6 @@ from ray_tpu.ops.attention import attention
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
 from ray_tpu.ops.kda_step import kda_step as _kda_step
 from ray_tpu.ops.norms import rms_norm
-
-# the most rows of a prompt whose tokenwise work is done at once (every
-# float32 ``[rows, 64, 128]`` array is then 67 MB); module docstring
-SEGMENT_ROWS = 2048
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,19 +167,6 @@ class SolarConfig(moe.HeldExperts):
             dtype="float32")
         base.update(kw)
         return SolarConfig(**base)
-
-
-def segment_rows(cfg: SolarConfig, t: int) -> int:
-    """The rows of one segment of a ``t``-row prefill: ``t`` itself up to
-    :data:`SEGMENT_ROWS`, else the equal segments of at most that many
-    rows, which must be whole KDA chunks."""
-    n = -(-t // SEGMENT_ROWS)
-    if t % n or (n > 1 and (t // n) % cfg.kda_chunk):
-        raise ValueError(
-            f"a prefill of {t} rows is run in {n} segments of at most "
-            f"{SEGMENT_ROWS} rows: {t} must divide into {n} equal "
-            f"segments of whole {cfg.kda_chunk}-row chunks")
-    return t // n
 
 
 # --------------------------------------------------------------------------
@@ -360,37 +343,17 @@ def _gqa_out(cfg: SolarConfig, p, x, o):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
-def _in_segments(body, carry, xs, seg: int):
-    """``body(carry, (segment's first row, the segment's rows of xs)) ->
-    (carry, outputs with a leading [B, seg])`` over the segments of
-    ``xs`` (a tree of [B, T, ...] arrays) in order -> (carry, the
-    outputs [B, T, ...]). One segment is one plain call."""
-    b, t = jax.tree_util.tree_leaves(xs)[0].shape[:2]
-    n = t // seg
-    if n == 1:
-        return body(carry, (jnp.int32(0), xs))
-
-    def rows(a):  # [B, T, ...] -> [n, B, seg, ...]
-        return jnp.moveaxis(a.reshape(b, n, seg, *a.shape[2:]), 1, 0)
-
-    carry, outs = jax.lax.scan(body, carry, (
-        jnp.arange(n, dtype=jnp.int32) * seg,
-        jax.tree_util.tree_map(rows, xs)))
-    return carry, jax.tree_util.tree_map(
-        lambda a: jnp.moveaxis(a, 0, 1).reshape(b, t, *a.shape[3:]), outs)
-
-
 def prefill(params, tokens, true_lens, cfg: SolarConfig,
             loads: bool = False):
     """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
-    state, the tokenwise parts in segments of :func:`segment_rows` rows
+    state, the tokenwise parts in segments of ``moe.segment_rows`` rows
     (module docstring) -> (h [B, T, D] before the final norm, the
     streams' state {"kda": a list of {"s", "conv"} a KDA layer,
     "k_full", "v_full" [L_full, B, T, Hkv * hd]: the GQA layers' rows,
     padding's among them}, and with ``loads`` the held experts'
     assignments from the real positions [L, count] int32, else None)."""
     b, t = tokens.shape
-    seg = segment_rows(cfg, t)
+    seg = moe.segment_rows(t, cfg.kda_chunk)
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
     kda, k_rows, v_rows, counts = [], [], [], []
@@ -411,7 +374,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
             def project(_, xs, p=p):
                 return (), _qkv(cfg, p["attn"], norm(xs[1]))
 
-            _, (q, k, v) = _in_segments(project, (), h, seg)
+            _, (q, k, v) = moe.in_segments(project, (), h, seg)
             with jax.named_scope("attn/attn_full"):
                 o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
 
@@ -426,7 +389,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
             with jax.named_scope("cache"):
                 k_rows.append(k.reshape(b, t, -1))
                 v_rows.append(v.reshape(b, t, -1))
-            count, h = _in_segments(rest, _zero_loads(cfg, loads), (h, o),
+            count, h = moe.in_segments(rest, _zero_loads(cfg, loads), (h, o),
                                     seg)
         else:
             def layer(carry, xs, p=p):
@@ -440,7 +403,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
                 return (state, jax.tree_util.tree_map(jnp.add, count, n)), \
                     h_seg
 
-            (state, count), h = _in_segments(
+            (state, count), h = moe.in_segments(
                 layer, (kda_empty(cfg, b), _zero_loads(cfg, loads)), h, seg)
             kda.append(state)
         counts.append(count)
@@ -534,7 +497,7 @@ class _Slots(Slots):
 
     @staticmethod
     def prefill_segments(cfg: SolarConfig, bucket: int) -> int:
-        return bucket // segment_rows(cfg, bucket)
+        return bucket // moe.segment_rows(bucket, cfg.kda_chunk)
 
     @staticmethod
     def init_state(cfg: SolarConfig, slots: int, max_len: int) -> dict:

@@ -56,6 +56,51 @@ def attention_reference(q, k, v, *, causal: bool = True, logits_dtype=jnp.float3
     return out.astype(q.dtype)
 
 
+def softmax_with_sink(logits, sink):
+    """Softmax over the last axis with one more logit in the denominator
+    that takes no value: ``sink`` broadcasts against ``logits[..., :1]``.
+    float32 in, float32 out; the probabilities sum to less than 1."""
+    m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), sink)
+    e = jnp.exp(logits - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def attend_rows(q, k, v, *, offset, window: int | None = None, sink=None,
+                use_flash: bool | None = None):
+    """Rows of a prompt over the rows written so far, forward only, in
+    the flash kernel's layout: q [B, Hq, T, d_qk] at positions
+    ``offset`` .. ``offset + T - 1`` (traced or not) over k [B, Hkv, S,
+    d_qk], v [B, Hkv, S, d_v] of positions 0 .. S - 1 -> [B, Hq, T,
+    d_v]. Row i sees keys <= i + offset, in a band (``window``) the last
+    ``window`` of them; ``sink`` [Hq] float32: a logit a head that joins
+    its softmax's denominator and takes no value. ``use_flash`` as
+    :func:`attention`'s: the kernel (``flash_attention.flash_fwd``) on a
+    TPU, else the XLA body below, which forms the [T, S] scores whole."""
+    if use_flash is None:
+        use_flash = jax.default_backend() == "tpu"
+    if use_flash:
+        from ray_tpu.ops.flash_attention import flash_fwd
+
+        return flash_fwd(q, k, v, offset=offset, window=window, sink=sink)
+    b, hq, t, d = q.shape
+    hkv, s = k.shape[1:3]
+    qg = q.reshape(b, hkv, hq // hkv, t, d)
+    logits = jnp.einsum("bkgtd,bksd->bkgts", qg, k,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    at = jnp.arange(t)[:, None] + offset  # the query's position
+    key = jnp.arange(s)[None, :]
+    seen = key <= at
+    if window is not None:
+        seen &= key > at - window
+    logits = jnp.where(seen, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1) if sink is None \
+        else softmax_with_sink(logits, sink.astype(jnp.float32).reshape(
+            hkv, hq // hkv)[None, :, :, None, None])
+    o = jnp.einsum("bkgts,bksd->bkgtd", probs.astype(q.dtype), v,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    return o.reshape(b, hq, t, v.shape[-1])
+
+
 def attention(q, k, v, *, causal: bool = True, use_flash: bool | None = None):
     """Dispatching attention entry point.
 

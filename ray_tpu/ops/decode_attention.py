@@ -49,6 +49,23 @@ bf16, hd 128; 8 slots of 1,296 rows at lengths 268...1,108, mean 683):
     costs about 5 us before its first block is there.
 So: the fewest equal blocks of at most ``_BLOCK_ROWS`` rows
 (``block_rows``): 3 x 432 for 1,296 rows, one block for 512.
+
+**Keys and values of different widths, a head that is not whole lanes,
+a sink (PR 50).** k and v are two stacks and their rows need not be as
+wide: ``q [B, T, Hq, dk]`` over ``k [L, B, S, Hkv * dk]`` and ``v [L,
+B, S, Hkv * dv]`` gives ``[B, T, Hq, dv]``. A key head of 192 is one
+and a half lane tiles; heads laid end to end would start every second
+one in the middle of a tile. A row of such keys is stored **packed**
+(:func:`pack_heads`): every head's whole lanes first (``main`` = 128 of
+its 192), end to end, then every head's remainder (64), end to end, so
+that two remainders share a tile and nothing is padded: the row is
+``Hkv * dk`` numbers, as unpacked. The kernel contracts a head's main
+part against its own lanes and its remainder, laid into a zeroed tile
+at its place, against the tile that holds it: two aligned products,
+the one and a half tiles a 192-wide contraction takes anyway. With
+``sink`` [Hq] float32 a learned logit a query head joins the softmax's
+denominator and takes no value: the running max starts at the sink and
+the running sum at 1 (``exp(sink - sink)``), nothing else changes.
 """
 
 from __future__ import annotations
@@ -79,17 +96,50 @@ def block_rows(s: int, most: int = _BLOCK_ROWS) -> int:
     return _cdiv(_cdiv(s, n), 16) * 16
 
 
-def attend_ragged(q, ck, cv, qpos):
+def _split(hd: int) -> tuple:
+    """(main, rem): a head's whole lanes and what is left of it (a
+    head under one tile is taken whole: nothing to pack)."""
+    return (hd // 128 * 128, hd % 128) if hd > 128 else (hd, 0)
+
+
+def pack_heads(x):
+    """[..., H, hd] -> [..., H * hd], a row as the kernel reads it: the
+    heads end to end where a head is whole lanes, else every head's
+    whole lanes end to end and then every head's remainder (module
+    docstring)."""
+    *lead, h, hd = x.shape
+    main, rem = _split(hd)
+    if not rem:
+        return x.reshape(*lead, h * hd)
+    return jnp.concatenate([x[..., :main].reshape(*lead, h * main),
+                            x[..., main:].reshape(*lead, h * rem)], axis=-1)
+
+
+def unpack_heads(rows, h: int):
+    """:func:`pack_heads` undone: [..., H * hd] -> [..., H, hd]."""
+    *lead, width = rows.shape
+    hd = width // h
+    main, rem = _split(hd)
+    if not rem:
+        return rows.reshape(*lead, h, hd)
+    return jnp.concatenate(
+        [rows[..., :h * main].reshape(*lead, h, main),
+         rows[..., h * main:].reshape(*lead, h, rem)], axis=-1)
+
+
+def attend_ragged(q, ck, cv, qpos, sink=None):
     """The XLA body: attention of T query rows a slot over ONE layer of
     the cache, every row of it. q: [B, T, Hq, hd]; ck/cv:
-    [B, S, Hkv, hd]; qpos: [B, T], the position of each query row; row t
+    [B, S, Hkv, hd] (cv's heads may be of another width); qpos: [B, T],
+    the position of each query row; row t
     of slot b sees k_pos <= qpos[b, t]. The query heads are grouped by
     the kv head they share (head h = kv * group + r, the order a repeat
     of the kv heads would give) and each group contracts against its one
     kv head: no repeated copy of the cache is made, and the cache is
     read once in its own dtype. Products accumulate in float32, the
-    softmax is float32, the probabilities are cast to q's dtype. Returns
-    [B, T, Hq, hd]."""
+    softmax is float32 (with ``sink`` [Hq] a head's logit joins its
+    denominator), the probabilities are cast to q's dtype. Returns
+    [B, T, Hq, cv's head width]."""
     b, t, hq, hd = q.shape
     s, hkv = ck.shape[1:3]
     qg = q.reshape(b, t, hkv, hq // hkv, hd)
@@ -99,11 +149,17 @@ def attend_ragged(q, ck, cv, qpos):
     k_pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]  # [1, 1, S]
     live = k_pos <= qpos[:, :, None]  # [B, T, S]
     logits = jnp.where(live[:, None, None], logits, _NEG)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    else:
+        from ray_tpu.ops.attention import softmax_with_sink
+
+        probs = softmax_with_sink(logits, sink.astype(jnp.float32).reshape(
+            hkv, hq // hkv)[None, :, :, None, None]).astype(q.dtype)
     o = jnp.einsum(
         "bkgts,bskd->btkgd", probs, cv, preferred_element_type=jnp.float32
     ).astype(q.dtype)
-    return o.reshape(b, t, hq, hd)
+    return o.reshape(b, t, hq, cv.shape[-1])
 
 
 def visits(lengths, s: int, bs: int | None = None):
@@ -126,8 +182,11 @@ def visits(lengths, s: int, bs: int | None = None):
 
 
 def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
-            o_ref, m_scr, l_scr, acc_scr, *, s: int, bs: int, t: int,
-            group: int, hkv: int, hd: int, scale: float):
+            *rest, s: int, bs: int, t: int, group: int, hkv: int, hd: int,
+            dv: int, scale: float):
+    # (with a sink its rows [Hkv, _ROW_PAD, 128] come before the output)
+    *sink_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    main, rem = _split(hd)
     step = pl.program_id(0)
     slot, j = slot_ids[step], block_ids[step]
     length = lengths[slot]
@@ -135,8 +194,12 @@ def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink_ref:  # exp(sink - sink) = 1 is in the sum already
+            m_scr[...] = sink_ref[0][...]
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, _NEG)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # row r of a head's [_ROW_PAD, hd] is query row t = r // group (the
@@ -148,19 +211,29 @@ def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
     if s % bs:  # the cache ends inside the last block
         seen &= k_pos < s
         inside = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (bs, hd), 0) < s
+            jnp.int32, (bs, dv), 0) < s
     for h in range(hkv):
-        lanes = pl.ds(h * hd, hd)
-        logits = jax.lax.dot_general(
-            q_ref[h], k_ref[:, lanes], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [_ROW_PAD, bs]
+        if not rem:
+            logits = jax.lax.dot_general(
+                q_ref[h], k_ref[:, pl.ds(h * hd, hd)],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [_ROW_PAD, bs]
+        else:  # packed: the head's whole lanes, then its remainder's tile
+            tile = (hkv * main + h * rem) // 128 * 128
+            logits = (jax.lax.dot_general(
+                q_ref[h, :, :main], k_ref[:, pl.ds(h * main, main)],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) + jax.lax.dot_general(
+                q_ref[h, :, main:], k_ref[:, pl.ds(tile, 128)],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)) * scale
         logits = jnp.where(seen, logits, _NEG)
         m_prev = m_scr[h, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
         p = jnp.exp(logits - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_new = corr * l_scr[h, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[:, lanes]
+        v = v_ref[:, pl.ds(h * dv, dv)]
         if s % bs:  # what lies past the cache is not zero, nor finite
             v = jnp.where(inside, v, jnp.zeros_like(v))
         acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
@@ -176,21 +249,38 @@ def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
 
 
 def _decode_attn(q, k, v, layer, lengths, plan, *, bs: int,
-                 interpret: bool):
-    """The kernel's call: q [B, T, Hq, hd] -> [B, T, Hq, hd]; ``plan``
+                 interpret: bool, sink=None):
+    """The kernel's call: q [B, T, Hq, hd] -> [B, T, Hq, dv]; ``plan``
     is ``visits(lengths, s, bs)``."""
     b, t, hq, hd = q.shape
     s = k.shape[2]
     hkv = k.shape[3] // hd
+    dv = v.shape[3] // hkv
     group = hq // hkv
     rows = t * group
     if rows > _ROW_PAD:
         raise ValueError(
             f"{t} query rows x {group} heads a kv head exceed {_ROW_PAD}")
+    main, rem = _split(hd)
+    if rem:
+        # q as an array of its own before it is laid out for the kernel:
+        # fused into the rotation that made q, the lay-out below gave
+        # the rings' calls wrong rows on the chip (outputs off by 0.2 of
+        # 0.5 inside the model's step, right on a q that was an argument;
+        # my chip runs, PR 50, PERF.md section 7)
+        q = jax.lax.optimization_barrier(q)
     # [B, Hkv, T x group (padded), hd]: a kv head's query rows together
     qh = q.reshape(b, t, hkv, group, hd).transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, rows, hd)
     qh = jnp.pad(qh, ((0, 0), (0, 0), (0, _ROW_PAD - rows), (0, 0)))
+    if rem:  # a head's remainder at its place in a zeroed tile of its own
+        # (static pads: as a gather this took 8 ms a call on the chip)
+        place = [(hkv * main + h * rem) % 128 for h in range(hkv)]
+        tail = jnp.stack([jnp.pad(
+            qh[:, h, :, main:], ((0, 0), (0, 0), (at, 128 - rem - at)))
+            for h, at in enumerate(place)], axis=1)
+        qh = jnp.concatenate([qh[..., :main], tail], axis=-1)
+    width = qh.shape[-1]
     meta, steps = plan
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
@@ -201,51 +291,66 @@ def _decode_attn(q, k, v, layer, lengths, plan, *, bs: int,
         return layer_ref[0], slot_ids[step], block_ids[step], 0
 
     kernel = functools.partial(
-        _kernel, s=s, bs=bs, t=t, group=group, hkv=hkv, hd=hd,
+        _kernel, s=s, bs=bs, t=t, group=group, hkv=hkv, hd=hd, dv=dv,
         scale=hd ** -0.5)
-    kv_block = pl.BlockSpec((None, None, bs, hkv * hd), kv_index)
-    q_block = pl.BlockSpec((None, hkv, _ROW_PAD, hd), q_index)
+    q_block = pl.BlockSpec((None, hkv, _ROW_PAD, width), q_index)
+    operands, in_specs = [qh, k, v], [
+        q_block, pl.BlockSpec((None, None, bs, hkv * hd), kv_index),
+        pl.BlockSpec((None, None, bs, hkv * dv), kv_index)]
+    if sink is not None:  # row r of kv head h is query head h * group + r % group
+        by_row = jnp.tile(sink.astype(jnp.float32).reshape(hkv, group),
+                          (1, t))
+        by_row = jnp.pad(by_row, ((0, 0), (0, _ROW_PAD - rows)))
+        operands.append(jnp.broadcast_to(by_row[..., None],
+                                         (hkv, _ROW_PAD, 128)))
+        in_specs.append(pl.BlockSpec(
+            (hkv, _ROW_PAD, 128), lambda step, *_: (0, 0, 0)))
     itemsize = k.dtype.itemsize
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, _ROW_PAD, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            in_specs=[q_block, kv_block, kv_block],
-            out_specs=q_block,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, hkv, _ROW_PAD, dv), q_index),
             grid=(steps,),
             scratch_shapes=[
                 pltpu.VMEM((hkv, _ROW_PAD, 128), jnp.float32),  # max
                 pltpu.VMEM((hkv, _ROW_PAD, 128), jnp.float32),  # sum
-                pltpu.VMEM((hkv, _ROW_PAD, hd), jnp.float32),
+                pltpu.VMEM((hkv, _ROW_PAD, dv), jnp.float32),
             ],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * _ROW_PAD * s * hkv * hd,
+            flops=2 * b * _ROW_PAD * s * hkv * (hd + dv),
             transcendentals=b * _ROW_PAD * s * hkv,
-            bytes_accessed=2 * b * s * hkv * hd * itemsize),
+            bytes_accessed=b * s * hkv * (hd + dv) * itemsize),
         interpret=interpret,
         name="decode_attn",
-    )(*meta, lengths, layer, qh, k, v)
+    )(*meta, lengths, layer, *operands)
     # a slot without a row was never visited: what its block of the
     # output holds is whatever the buffer held
     out = jnp.where((lengths > 0)[:, None, None, None], out[:, :, :rows], 0)
-    return out.reshape(b, hkv, t, group, hd).transpose(
-        0, 2, 1, 3, 4).reshape(b, t, hq, hd)
+    return out.reshape(b, hkv, t, group, dv).transpose(
+        0, 2, 1, 3, 4).reshape(b, t, hq, dv)
 
 
 def decode_attention(q, k, v, layer, lengths, *, plan=None,
                      use_kernel: bool | None = None,
-                     interpret: bool = False, rows: int | None = None):
+                     interpret: bool = False, rows: int | None = None,
+                     sink=None):
     """q [B, T, Hq, hd] over ``k[layer]``, ``v[layer]`` of the stacks
-    [L, B, S, Hkv * hd] up to ``lengths`` [B] (``pos + T``; 0: the slot
-    is inactive and its output zeros) -> [B, T, Hq, hd] in q's dtype.
+    [L, B, S, Hkv * hd] and [L, B, S, Hkv * dv] (rows as
+    :func:`pack_heads` lays them) up to ``lengths`` [B] (``pos + T``; 0:
+    the slot is inactive and its output zeros) -> [B, T, Hq, dv] in q's
+    dtype. ``sink`` [Hq] float32: a logit a query head that joins its
+    softmax's denominator and takes no value.
 
     ``use_kernel=None`` takes the backend's: the Pallas kernel on a TPU
-    (where a head fills whole lanes), ``attend_ragged`` elsewhere, which
+    (where a value head fills whole lanes and a key head's remainder
+    divides a tile), ``attend_ragged`` elsewhere, which
     reads every row of the layer and leaves an inactive slot's output to
     its frozen position. ``interpret=True`` runs the kernel in the
     Pallas interpreter (never inferred). ``rows`` overrides the block's
@@ -256,21 +361,25 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
     there, every layer)."""
     b, t, hq, hd = q.shape
     s = k.shape[2]
+    hkv = k.shape[3] // hd
     if use_kernel is None:
+        rem = hd % 128  # (off a test, a head is a tile or more)
         use_kernel = interpret or (
-            jax.default_backend() == "tpu" and hd % 128 == 0
-            and t * (hq * hd // k.shape[3]) <= _ROW_PAD)
+            jax.default_backend() == "tpu"
+            and (v.shape[3] // hkv) % 128 == 0
+            and (not rem or (hd > 128 and 128 % rem == 0
+                             and hkv * rem % 128 == 0))
+            and t * (hq // hkv) <= _ROW_PAD)
     if not use_kernel:
-        shape = (b, s, k.shape[3] // hd, hd)
         qpos = (lengths - t)[:, None] + jnp.arange(t, dtype=jnp.int32)
-        o = attend_ragged(q, k[layer].reshape(shape),
-                          v[layer].reshape(shape), qpos)
+        o = attend_ragged(q, unpack_heads(k[layer], hkv),
+                          unpack_heads(v[layer], hkv), qpos, sink)
         return jnp.where((lengths > 0)[:, None, None, None], o, 0)
     bs = rows or block_rows(s)
     lengths = lengths.astype(jnp.int32)
     return _decode_attn(q, k, v, layer, lengths,
                         plan or visits(lengths, s, bs), bs=bs,
-                        interpret=interpret)
+                        interpret=interpret, sink=sink)
 
 
 # --------------------------------------------------------------------------

@@ -14,6 +14,18 @@ the per-row logsumexp ([B, H, T] f32); the backward recomputes
 p = exp(s - lse) per tile and runs two kernels — dq with
 the k dimension innermost, dk/dv with the q dimension innermost — so memory
 stays O(block²) and nothing [T, S]-shaped ever materializes.
+
+Forward only (:func:`flash_fwd`, PR 50): keys wider than values (``d_qk``
+192 beside ``d_v`` 128: the output and the accumulator take v's width),
+the query rows at a TRACED ``offset`` behind the keys' first row (a
+segment of a prompt against the rows written so far: the grid covers
+every k block and an index map that stops at the last live one keeps
+the dead steps off the HBM), a band (``window``: row i sees keys i +
+offset - window + 1 ... i + offset, and the grid's last dimension is
+the two or three k blocks a q block's band touches, shown in a trace as
+``flash_fwd_window``), and a learned sink (a logit a head that joins
+the denominator at the finalize and takes no value). With none of these
+the call is the parent's, text for text.
 """
 
 from __future__ import annotations
@@ -63,11 +75,28 @@ def _vmem_limit() -> int:
     return int(_cfg.get("flash_vmem_limit_mb")) * 1024 * 1024
 
 
-def _causal_mask(s, q_start, k_start, offset):
-    """End-aligned causal mask: query row i attends keys <= i + offset."""
+def _causal_mask(s, q_start, k_start, offset, window=None):
+    """End-aligned causal mask: query row i attends keys <= i + offset
+    (and, in a band, the last ``window`` of them)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
-    return jnp.where(rows + offset >= cols, s, _NEG_INF)
+    seen = rows + offset >= cols
+    if window is not None:
+        seen &= cols > rows + offset - window
+    return jnp.where(seen, s, _NEG_INF)
+
+
+def _k_blocks(q_start, block_q, block_k, offset, window, nk):
+    """(first, last) k block that rows ``q_start`` .. of a q block see,
+    of ``nk``: up to the diagonal's, and in a band from its lower
+    edge's."""
+    # (lax.div truncates: both numerators are >= 0)
+    last = jnp.minimum(
+        jax.lax.div(q_start + block_q - 1 + offset, block_k), nk - 1)
+    if window is None:
+        return 0, last
+    return jax.lax.div(
+        jnp.maximum(q_start + offset - window + 1, 0), block_k), last
 
 
 def _block_live(causal, q_start, k_start, block_q, offset):
@@ -85,10 +114,20 @@ def _straddles(q_start, k_start, block_k, offset):
     return k_start + block_k - 1 > q_start + offset
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, causal, scale, block_q, block_k, offset):
+def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
+                window=None, sink=False):
     """offset = S - T: the causal mask is end-aligned (query row i attends
     keys <= i + offset), matching attention_reference's tril(k=S-T) so decode
-    (T=1 against a long cache) sees the whole prefix."""
+    (T=1 against a long cache) sees the whole prefix.
+
+    The forward-only form (``nk_all``: the k blocks there are): ``offset``
+    is the first, scalar-prefetched operand; grid step ``ik`` is k block
+    ``first + ik`` of :func:`_k_blocks`, dead past the last; with ``sink``
+    a head's [1, 128] logit comes after v."""
+    if nk_all is not None:
+        offset_ref, *refs = refs
+        offset = offset_ref[0]
+    q_ref, k_ref, v_ref, *sink_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -100,7 +139,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, c
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = iq * block_q
-    k_start = ik * block_k
+    if nk_all is None:
+        k_start = ik * block_k
+    else:
+        first, last = _k_blocks(q_start, block_q, block_k, offset, window,
+                                nk_all)
+        k_start = (first + ik) * block_k
 
     def _compute(masked: bool):
         # Matmul operands stay in the input dtype (bf16 hits the MXU's native
@@ -114,7 +158,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, c
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * (scale * _LOG2E)  # [bq, bk], log2 domain
         if masked:
-            s = _causal_mask(s, q_start, k_start, offset)
+            s = _causal_mask(s, q_start, k_start, offset, window)
 
         m_prev = m_scr[:, :1]  # [bq, 1] (lanes replicated)
         m_cur = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
@@ -140,8 +184,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, c
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    live = _block_live(causal, q_start, k_start, block_q, offset)
-    if causal:
+    if nk_all is not None:
+        live = first + ik <= last
+    else:
+        live = _block_live(causal, q_start, k_start, block_q, offset)
+    if window is not None:  # (two of a band's two or three blocks straddle)
+        pl.when(live)(lambda: _compute(masked=True))
+    elif causal:
         straddle = _straddles(q_start, k_start, block_k, offset)
         pl.when(jnp.logical_and(live, straddle))(
             lambda: _compute(masked=True)
@@ -155,8 +204,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, c
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_scr[:, :1]
+        if sink_ref:  # the sink's term joins the sum; its value is nothing
+            sink2 = sink_ref[0][0, :, :1] * _LOG2E  # [1, 1], log2 domain
+            m_all = jnp.maximum(m_scr[:, :1], sink2)
+            shrink = jnp.exp2(m_scr[:, :1] - m_all)
+            l = l * shrink + jnp.exp2(sink2 - m_all)
+            m_scr[:] = jnp.broadcast_to(m_all, m_scr.shape)
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        acc = acc_scr[:]
+        if sink_ref:
+            acc = acc * shrink
+        o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
         # lse is exposed in NATURAL log (public residual contract); the
         # kernel's m statistic is log2-domain, so convert: ln Z =
         # (m2 + log2 l) * ln2. Rows that attend nothing (only possible
@@ -212,12 +270,18 @@ def _fwd_kernel_1pass(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
         _one_head(h, masked=causal)
 
 
-def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
+               offset=None, window=None, sink=None):
+    """With ``offset`` (an int32 scalar, traced or not) the forward-only
+    form (module docstring; :func:`flash_fwd` is its caller): the tiled
+    kernel with ``offset`` scalar-prefetched, ``window`` and ``sink``
+    [Hq] float32 where given."""
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
+    dv = v.shape[-1]
     group = hq // hkv
     block_q = min(block_q, t)
-    if s <= _FULL_INNER_MAX:
+    if s <= _FULL_INNER_MAX and offset is None:
         block_k = s  # one k tile per q row: no dead-block grid/DMA overhead
     else:
         block_k = min(block_k, s)
@@ -229,6 +293,11 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     scale = d ** -0.5
     nk = cdiv(s, block_k)
 
+    if offset is not None:
+        return _flash_fwd_at(
+            q, k, v, jnp.asarray(offset, jnp.int32).reshape(1), sink,
+            scale=scale, block_q=block_q, block_k=block_k, window=window,
+            interpret=interpret)
     if nk == 1:
         hb = _heads_per_block("flash_heads_per_block", hq, group)
         kernel = functools.partial(
@@ -249,10 +318,10 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         in_specs = [
             pl.BlockSpec((1, hb, block_q, d), q_idx),
             pl.BlockSpec((1, hb, block_k, d), kv_idx),
-            pl.BlockSpec((1, hb, block_k, d), kv_idx),
+            pl.BlockSpec((1, hb, block_k, dv), kv_idx),
         ]
         out_specs = [
-            pl.BlockSpec((1, hb, block_q, d), q_idx),
+            pl.BlockSpec((1, hb, block_q, dv), q_idx),
             pl.BlockSpec((1, hb, block_q, 8), q_idx),
         ]
         dims = ("parallel", "parallel", "parallel")
@@ -265,7 +334,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         scratch = [
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom l
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
         ]
 
         def q_idx4(bi, hi, qi, ki):
@@ -277,7 +346,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         in_specs = [
             pl.BlockSpec((1, 1, block_q, d), q_idx4),
             pl.BlockSpec((1, 1, block_k, d), kv_idx4),
-            pl.BlockSpec((1, 1, block_k, d), kv_idx4),
+            pl.BlockSpec((1, 1, block_k, dv), kv_idx4),
         ]
         # lse is written 8-lane-replicated: mosaic requires the last
         # block dim be a multiple of 128 or the full array dim, so a
@@ -286,7 +355,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         # major [8, bq] tile measured WORSE (the in-kernel sublane->
         # lane transpose outcosts the narrow DMA).
         out_specs = [
-            pl.BlockSpec((1, 1, block_q, d), q_idx4),
+            pl.BlockSpec((1, 1, block_q, dv), q_idx4),
             pl.BlockSpec((1, 1, block_q, 8), q_idx4),
         ]
         dims = ("parallel", "parallel", "parallel", "arbitrary")
@@ -297,7 +366,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, hq, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, t, 8), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -311,6 +380,104 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
         name="flash_fwd",
     )(q, k, v)
     return out, lse4[..., 0]  # lse: [B, H, T] f32
+
+
+def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
+                  window, interpret):
+    """The forward-only call (``_flash_fwd`` says when): ``offset`` [1]
+    int32 is scalar-prefetched; the last grid dimension is every k
+    block, or in a band the most a q block's band touches; the k / v
+    index map stops at a q block's last live block, so that a dead
+    step asks for the block that is there already."""
+    b, hq, t, d = q.shape
+    _, hkv, s, dv = v.shape
+    group = hq // hkv
+    nk = cdiv(s, block_k)
+    steps = nk if window is None else min(
+        nk, cdiv(block_q + window - 1, block_k) + 1)
+
+    def q_idx(bi, hi, qi, ki, off):
+        return (bi, hi, qi, 0)
+
+    def kv_idx(bi, hi, qi, ki, off):
+        first, last = _k_blocks(qi * block_q, block_q, block_k, off[0],
+                                window, nk)
+        return (bi, hi // group, jnp.minimum(first + ki, last), 0)
+
+    operands, in_specs = [q, k, v], [
+        pl.BlockSpec((1, 1, block_q, d), q_idx),
+        pl.BlockSpec((1, 1, block_k, d), kv_idx),
+        pl.BlockSpec((1, 1, block_k, dv), kv_idx)]
+    if sink is not None:
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (hq, 1, 128)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 128), lambda bi, hi, qi, ki, off: (hi, 0, 0)))
+    out, lse4 = pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, causal=True, scale=scale, block_q=block_q,
+            block_k=block_k, offset=None, nk_all=nk, window=window,
+            sink=sink is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hq, cdiv(t, block_q), steps),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, block_q, dv), q_idx),
+                       pl.BlockSpec((1, 1, block_q, 8), q_idx)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
+                pltpu.VMEM((block_q, 128), jnp.float32),  # running denom l
+                pltpu.VMEM((block_q, dv), jnp.float32),  # accumulator
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hq, t, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, t, 8), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(),
+        ),
+        interpret=interpret,
+        name="flash_fwd" if window is None else "flash_fwd_window",
+    )(offset, *operands)
+    return out, lse4[..., 0]
+
+
+def flash_fwd(q, k, v, *, offset=None, window: int | None = None, sink=None,
+              block_q: int | None = None, block_k: int | None = None,
+              interpret: bool = False):
+    """The forward kernel alone, in ITS layout: q [B, Hq, T, d_qk] at
+    positions ``offset`` .. ``offset + T - 1`` (``None``: S - T; may be
+    traced) over k [B, Hkv, S, d_qk], v [B, Hkv, S, d_v] of positions 0
+    .. S - 1 -> [B, Hq, T, d_v]. Row i sees keys <= i + offset, with
+    ``window`` the last ``window`` of them; ``sink`` [Hq] float32 joins
+    each head's denominator (module docstring). Forward only: a band, a
+    sink, an offset and k wider than v have no backward kernel, and a
+    differentiated call raises (training such a block: ROADMAP A3)."""
+    if block_q is None or block_k is None:
+        from ray_tpu._private import config as _cfg
+
+        block_q = block_q or _cfg.get("flash_block_q")
+        block_k = block_k or _cfg.get("flash_block_k")
+
+    if offset is None:
+        offset = k.shape[2] - q.shape[2]
+
+    @jax.custom_vjp
+    def forward(q, k, v, offset, sink):
+        return _flash_fwd(q, k, v, causal=True, block_q=block_q,
+                          block_k=block_k, interpret=interpret,
+                          offset=offset, window=window, sink=sink)[0]
+
+    def refuse(*_):
+        raise NotImplementedError(
+            "flash_fwd is forward only: a band (window), a sink, a traced "
+            "offset and keys wider than values have no backward kernel "
+            "(ops/flash_attention.py; training such a block is ROADMAP A3)")
+
+    forward.defvjp(refuse, refuse)
+    return forward(q, k, v, offset, sink)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -822,6 +989,11 @@ def flash_attention(
     should be on a TPU and is not must fail to lower, not serve from the
     interpreter.
     """
+    if v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"flash_attention is differentiable and needs values as wide "
+            f"as keys ({q.shape[-1]}), not {v.shape[-1]}: flash_fwd is the "
+            "forward alone")
     if block_q is None or block_k is None:
         from ray_tpu._private import config as _cfg
 

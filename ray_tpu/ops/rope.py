@@ -74,6 +74,15 @@ def apply_rotary(x, sin, cos):
     return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
 
 
+def apply_rotary_leading(x, sin, cos):
+    """Rotate the LEADING ``2 * sin.shape[-1]`` numbers of every head
+    (rotate-half among themselves) and pass the rest through
+    (``partial_rotary_factor``). x, sin, cos as :func:`apply_rotary`."""
+    r = 2 * sin.shape[-1]
+    return jnp.concatenate(
+        [apply_rotary(x[..., :r], sin, cos), x[..., r:]], axis=-1)
+
+
 def apply_rotary_interleaved(x, sin, cos):
     """Rotate the INTERLEAVED pairs (x[..., 2i], x[..., 2i + 1]) by pair
     i's angle (``rope_interleave``). The rotated pairs come back apart,

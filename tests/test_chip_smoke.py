@@ -236,9 +236,9 @@ def test_segment_check_rehearses_on_the_cpu(monkeypatch):
     four segments, the state carried, and agree with stepping; the gated
     GQA layer's prompt attention agrees with its steps over the rows,
     which are the same rows."""
-    from ray_tpu.models import solar
+    from ray_tpu.models import moe
 
-    monkeypatch.setattr(solar, "SEGMENT_ROWS", 8)
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 8)
     found = chip_smoke.segment_check("tiny", [16, 32], TINY.seed)
     assert found["device"].items() >= CPU.items()
     assert found["segments"] == {"16": 2, "32": 4}

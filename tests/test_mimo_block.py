@@ -1,0 +1,430 @@
+"""The sixth block, ``models/mimo.py`` (MiMo-V2.5's language model: window
+layers with a learned sink beside full layers, kv heads by kind, keys
+wider than values, a part of a head rotated, no shared expert), against
+its plain float32 reference (``benchmark/families/mimo_v2.reference.py``)
+on seeded weights at a tiny size, on the CPU:
+
+- whole sequences (``forward``) and the engine's path (the segmented
+  prefill into a slot's four stacks, then ragged steps past a wrapped
+  ring and across a segment boundary) give the reference's logits;
+- each mechanism the configuration names is load-bearing: the same
+  comparison FAILS with the sink, the value scale, the partial rotation,
+  the window kind's own theta or its own kv heads left out;
+- sixteen shares of an expert layer add up to the uncut layer (there is
+  no shared expert to count once);
+- both attention kernels in the Pallas interpreter at the published head
+  widths (keys 192, values 128) with a sink, against their XLA bodies.
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import manifest
+from ray_tpu.models import decode_engine as de
+from ray_tpu.models import mimo, moe
+from ray_tpu.models.decode_engine import RaggedDecoder
+from ray_tpu.ops import decode_attention as da
+from ray_tpu.ops.attention import attend_rows
+from ray_tpu.ops.flash_attention import flash_attention, flash_fwd
+
+F32_TOL = 1e-4
+
+FAM = manifest.family("mimo_v2")
+REF = manifest.reference(FAM)
+M = dict(FAM.TINY_FIELDS)
+W = M["sliding_window"]
+
+
+def _cfg(**kw):
+    m = {**M, **kw}
+    held = m.pop("held_experts")
+    return mimo.MimoConfig(**{
+        **m, "held_experts": held and tuple(held),
+        "layer_pattern": tuple(m["layer_pattern"]),
+        "moe_pattern": tuple(m["moe_pattern"])}, max_seq_len=256)
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = _cfg()
+    return cfg, mimo.init_params(cfg, jax.random.PRNGKey(7))
+
+
+# ------------------------------------------------------- configuration
+
+
+def test_the_configuration_carries_the_pattern_as_data():
+    cfg = _cfg()
+    assert (cfg.window_layers, cfg.full_layers, cfg.moe_layers) == (3, 2, 4)
+    assert [cfg.stack_index(i) for i in range(5)] == [0, 0, 1, 1, 2]
+    assert (cfg.row_widths(False), cfg.row_widths(True)) \
+        == ((2 * 24, 2 * 16), (4 * 24, 4 * 16))
+    # the published pattern is the default: layer 0 full, four window
+    # layers, a full one, then five window layers to one full
+    whole = mimo.MimoConfig()
+    assert whole.layer_pattern[:12] == (0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 0)
+    assert (whole.window_layers, whole.full_layers) == (39, 9)
+    assert whole.moe_pattern == (0,) + (1,) * 47
+    assert (whole.row_widths(False), whole.row_widths(True)) \
+        == ((768, 512), (1536, 1024))
+    with pytest.raises(ValueError, match="layer_pattern"):
+        mimo.MimoConfig(n_layers=3, layer_pattern=(0, 1))
+    with pytest.raises(ValueError, match="layer_pattern"):
+        mimo.MimoConfig(n_layers=1, layer_pattern=(2,))
+
+
+def test_init_params_draws_this_blocks_leaves(model):
+    """The two kinds' fused projections at their own widths, a sink a
+    head in the window layers alone, no shared expert's leaves, and the
+    family's count of parameters."""
+    cfg, params = model
+    full, win = params["layers"][0]["attn"], params["layers"][1]["attn"]
+    assert full["w_qkv"].shape == (48, 8 * 24 + 2 * 24 + 2 * 16)
+    assert win["w_qkv"].shape == (48, 8 * 24 + 4 * 24 + 4 * 16)
+    assert full["wo"].shape == win["wo"].shape == (8 * 16, 48)
+    assert "sink" not in full and win["sink"].shape == (8,)
+    assert win["sink"].dtype == jnp.float32
+    assert set(params["layers"][1]["mlp"]) == {
+        "router", "router_bias", "w_gate", "w_up", "w_down"}
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == FAM.num_params(M)
+
+
+# ------------------------------------------- the model, whole sequences
+
+
+def test_forward_is_the_references_logits(model):
+    cfg, params = model
+    toks = np.random.RandomState(3).randint(1, 256, (2, 40)).astype(np.int32)
+    got = mimo.forward(params, jnp.asarray(toks), cfg)
+    want = REF.forward(params, jnp.asarray(toks), M)
+    assert float(jnp.abs(got - want).max()) < F32_TOL
+
+
+def _without(what: str, cfg, params):
+    """The program with one of the configuration's mechanisms left out."""
+    if what == "sink":
+        layers = [{**p, "attn": {k: v for k, v in p["attn"].items()
+                                 if k != "sink"}} for p in params["layers"]]
+        return cfg, {**params, "layers": layers}
+    if what == "value_scale":
+        return dataclasses.replace(cfg, value_scale=1.0), params
+    if what == "partial_rotation":  # the whole head rotated
+        return dataclasses.replace(cfg, rotary_dim=cfg.head_dim), params
+    if what == "window_theta":  # the full layers' theta everywhere
+        return dataclasses.replace(
+            cfg, window_rope_theta=cfg.rope_theta), params
+    assert what == "window_kv_heads"
+    # the window layers read as if they had the full layers' kv heads:
+    # their first n_kv_heads key and value heads, shared by twice as
+    # many query heads each
+    hq, hkv, hw = cfg.n_heads, cfg.n_kv_heads, cfg.window_kv_heads
+    dk, dv = cfg.head_dim, cfg.v_head_dim
+    cols = np.r_[0:hq * dk + hkv * dk,
+                 (hq + hw) * dk:(hq + hw) * dk + hkv * dv]
+    layers = [{**p, "attn": {**p["attn"], "w_qkv": p["attn"]["w_qkv"][
+        :, cols]}} if cfg.windowed(i) else p
+        for i, p in enumerate(params["layers"])]
+    return dataclasses.replace(cfg, window_kv_heads=hkv), \
+        {**params, "layers": layers}
+
+
+@pytest.mark.parametrize("what", ["sink", "value_scale", "partial_rotation",
+                                  "window_theta", "window_kv_heads"])
+def test_the_comparison_fails_without(what, model):
+    """Each mechanism moves the logits by far more than the tolerance
+    that the whole program meets."""
+    cfg, params = model
+    toks = jnp.asarray(np.random.RandomState(4).randint(
+        1, 256, (1, 40)).astype(np.int32))
+    want = REF.forward(params, toks, M)
+    assert float(jnp.abs(mimo.forward(params, toks, cfg) - want).max()) \
+        < F32_TOL
+    cfg_off, params_off = _without(what, cfg, params)
+    off = mimo.forward(params_off, toks, cfg_off)
+    assert float(jnp.abs(off - want).max()) > 100 * F32_TOL, what
+
+
+# ------------------------------------- the model, through the engine
+
+
+def _ragged_logits(cfg, params, prompts, steps, spare_slot: int = 1):
+    """Prompts of different lengths prefilled by the engine's own
+    program into slots of one state (``spare_slot`` stays empty and
+    inactive), then ``steps`` greedy steps of the model's ragged step
+    with every slot at its own position. -> for each prompt (its tokens
+    followed by the generated ones, float32 logits [steps, V] from the
+    last prompt position on)."""
+    slots, max_len = len(prompts) + 1, 96
+    state = mimo.SLOTS.init_state(cfg, slots, max_len)
+    cur = jnp.zeros((slots,), jnp.int32)
+    seqs, rows = {}, {}
+    free = [s for s in range(slots) if s != spare_slot]
+    for slot, p in zip(free[::-1], prompts):
+        bucket = 16 if len(p) <= 16 else 64
+        row = np.zeros((1, bucket), np.int32)
+        row[0, :len(p)] = p
+        state, cur, *_ = de._prefill_batch_into_slots(
+            params, row, np.array([len(p)], np.int32),
+            np.array([slot], np.int32), np.array([0], np.uint32),
+            np.array([0.0], np.float32), np.array([1.0], np.float32),
+            state, cur, cfg)
+        seqs[slot], rows[slot] = list(p), []
+    active = jnp.asarray([s in seqs for s in range(slots)])
+    step = jax.jit(functools.partial(mimo.SLOTS.step, cfg, params, None))
+    tok = cur
+    for _ in range(steps):
+        for slot in seqs:
+            seqs[slot].append(int(tok[slot]))
+        rest = {k: v for k, v in state.items() if k != "pos"}
+        logits, rest, *_ = step(tok, rest, state["pos"], active)
+        state = {**rest, "pos": state["pos"] + active}
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        for slot in seqs:
+            rows[slot].append(np.asarray(logits[slot]))
+    assert int(state["pos"][spare_slot]) == 0
+    return [(seqs[s], np.stack(rows[s])) for s in seqs]
+
+
+# prompts shorter than the window (5), equal to it (8), longer (23, 41)
+PROMPTS = (5, 8, 23, 41)
+
+
+@pytest.mark.parametrize("segment", [2048, 16])
+def test_prefill_then_ragged_decode_is_the_references_forward(
+        segment, monkeypatch):
+    """Four slots at different positions and a fifth inactive among
+    them, 20 decoded positions each (a window of 8: two wraps of every
+    ring and more); with ``segment`` 16 the 64-row bucket's prefill runs
+    in four segments, so the 23- and 41-token prompts' attention crosses
+    segment boundaries (a window layer's band and a full layer's earlier
+    rows both reach into the segment before). The logits of every
+    decoded position are the reference's full forward over prompt +
+    tokens."""
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", segment)
+    cfg = _cfg()
+    assert mimo.SLOTS.prefill_segments(cfg, 64) == 64 // min(segment, 64)
+    params = mimo.init_params(cfg, jax.random.PRNGKey(7))
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(1, 256, n).astype(np.int32) for n in PROMPTS]
+    steps = 20
+    assert steps > 2 * W
+    for (seq, got), p in zip(_ragged_logits(cfg, params, prompts, steps),
+                             prompts):
+        want = np.asarray(REF.forward(params, jnp.asarray([seq]), M)[0])
+        # step j's logits are the position's after len(p) + j tokens
+        assert np.abs(got - want[len(p):len(p) + len(got)]).max() < F32_TOL
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_submit_and_pump_serve_the_references_tokens(dtype):
+    """``RaggedDecoder`` (submit -> pump) on the model: five streams
+    over three slots, so slots are reused and streams sit at ragged
+    positions, every one decoded past two wraps of its rings; every
+    stream's tokens pass the reference's ``check_served_tokens`` and, in
+    float32, are its argmax outright."""
+    cfg = _cfg(dtype=dtype)
+    params = mimo.init_params(cfg, jax.random.PRNGKey(8))
+    eng = RaggedDecoder(params, cfg, slots=3, max_len=96, chunk_tokens=4,
+                        prompt_buckets=(8, 16, 64))
+    rng = np.random.RandomState(1)
+    asked = [(rng.randint(1, 256, n).astype(np.int32), out)
+             for n, out in ((13, 19), (7, 22), (40, 18), (3, 24), (8, 20))]
+    sids = [eng.submit(p, out) for p, out in asked]
+    eng.drain()
+    for sid, (p, out) in zip(sids, asked):
+        toks = list(eng.finished[sid].tokens)
+        assert len(toks) == out
+        check = REF.check_served_tokens(params, list(p), toks, M)
+        assert check["wrong"] == 0, check
+        if dtype == "float32":
+            assert check["agree"] == out, check
+    st = eng.stats()
+    assert st["state_bytes"] == {
+        kind: 3 * n for kind, n in
+        FAM.state_bytes_per_slot(M, 96, jnp.dtype(dtype).itemsize).items()}
+    assert st["moe_assignments"] > 0 and st["moe_touched_expert_steps"] > 0
+    by_kind = st["attn_live_rows_by_kind"]
+    assert 0 < by_kind["window"] < by_kind["full"]
+    with pytest.raises(ValueError, match="ring of rows"):
+        RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
+
+
+def test_spans_carry_the_state_the_row_bytes_and_the_rows_by_kind(
+        model, monkeypatch):
+    from ray_tpu._private import flight_recorder as fr
+
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 8)
+    cfg, params = model
+    eng = RaggedDecoder(params, cfg, slots=2, max_len=64, chunk_tokens=4,
+                        prompt_buckets=(16,), name="mimo-test")
+    eng.submit(np.arange(1, 13, dtype=np.int32), 8)
+    eng.drain()
+    spans = [s for s in fr._get().ring if s["attrs"].get("engine")
+             == "mimo-test" or s["name"] in ("engine.readback",
+                                             "engine.prefill")]
+    init = [s for s in spans if s["name"] == "engine.state_init"][-1]["attrs"]
+    assert (init["slots"], init["max_len"]) == (2, 64)
+    assert (init["window_layers"], init["full_layers"]) == (3, 2)
+    per_slot = FAM.state_bytes_per_slot(M, 64, 4)
+    assert init["window_bytes"] == 2 * per_slot["window"]
+    assert init["full_bytes"] == 2 * per_slot["full"]
+    # a row's bytes by kind: kv heads x (24 + 16) x 4 B
+    row = FAM.kv_row_bytes(M, 4)
+    assert (init["window_row_bytes"], init["full_row_bytes"]) \
+        == (row["window"], row["full"]) == (640, 320)
+    assert [s["attrs"]["segments"] for s in spans
+            if s["name"] == "engine.prefill"][-1] == 2
+    backs = [s["attrs"] for s in spans if s["name"] == "engine.readback"
+             and "live_rows_window" in s["attrs"]][-2:]
+    assert [b["live_rows_full"] for b in backs] == [16, 20]
+    assert [b["live_rows_window"] for b in backs] == [W, W]
+    assert backs[-1]["assignments"] == M["top_k"]
+
+
+# ------------------------------------------------------- the shares
+
+
+def test_sixteen_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
+    """The guide's section 4: the layer cut over sixteen chips by index
+    of expert. Each share routes over all 16 experts and computes its
+    own one; the sixteen partial results add up to the reference's layer
+    with every expert held. There is no shared expert to count once."""
+    whole = _cfg(held_experts=None)
+    p = mimo.init_params(whole, jax.random.PRNGKey(5))["layers"][1]["mlp"]
+    assert p["w_gate"].shape[0] == 16 and "shared_gate" not in p
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 9, whole.d_model))
+    with jax.default_matmul_precision("highest"):
+        want = REF.moe_layer(M, p, x, held=(0, 16))
+    total = jnp.zeros_like(x)
+    for first in range(16):
+        share = {**p, **{w: p[w][first:first + 1]
+                         for w in ("w_gate", "w_up", "w_down")}}
+        part = moe.moe(_cfg(held_experts=(first, 1)), share, x)
+        with jax.default_matmul_precision("highest"):
+            np.testing.assert_allclose(
+                part, REF.moe_layer(M, share, x, held=(first, 1)),
+                atol=2e-5)
+        total = total + part
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    np.testing.assert_allclose(moe.moe(whole, p, x), want, atol=5e-5)
+
+
+# ------------------------------------------------------- the kernels
+
+
+def test_packed_rows_unpack_to_the_heads():
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 4, 192))
+    rows = da.pack_heads(x)
+    assert rows.shape == (3, 5, 768)
+    # every head's first 128 end to end, then every head's last 64
+    np.testing.assert_array_equal(rows[..., 128:256], x[..., 1, :128])
+    np.testing.assert_array_equal(rows[..., 512 + 64:512 + 128],
+                                  x[..., 1, 128:])
+    np.testing.assert_array_equal(da.unpack_heads(rows, 4), x)
+    whole = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 128))
+    np.testing.assert_array_equal(da.pack_heads(whole),
+                                  whole.reshape(2, 1024))
+
+
+@pytest.mark.parametrize("hkv, s, lengths", [
+    # a ring of 128 rows, 8 query rows a kv head: under, at the window
+    (8, 128, (0, 1, 77, 128)),
+    # a full stack, 16 query rows a kv head: under, at and over it
+    (4, 400, (5, 128, 0, 393))])
+def test_decode_attn_at_the_published_head_widths_with_a_sink(
+        hkv, s, lengths):
+    """The kernel in the interpreter against its XLA body: keys 192 wide
+    (packed rows) and values 128, 64 query heads, a sink a head; a slot
+    of length 0 gives zeros. ``s`` 400 ends inside the last block."""
+    ks = jax.random.split(jax.random.PRNGKey(hkv), 4)
+    b, layers = len(lengths), 2
+    q = jax.random.normal(ks[0], (b, 1, 64, 192))
+    k = da.pack_heads(jax.random.normal(ks[1], (layers, b, s, hkv, 192)))
+    v = jax.random.normal(ks[2], (layers, b, s, hkv * 128))
+    sink = 2.0 + jax.random.normal(ks[3], (64,))
+    n = jnp.asarray(lengths, jnp.int32)
+    for snk in (sink, None):
+        want = da.decode_attention(q, k, v, 1, n, use_kernel=False, sink=snk)
+        got = da.decode_attention(q, k, v, 1, n, interpret=True, rows=128,
+                                  sink=snk)
+        assert got.shape == (b, 1, 64, 128)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        assert not np.asarray(got)[np.asarray(lengths) == 0].any()
+    # the sink takes its share: with it the output is smaller
+    sunk = da.decode_attention(q, k, v, 1, n, use_kernel=False, sink=sink)
+    assert float(jnp.abs(sunk - want).max()) > 1e-3
+
+
+@pytest.mark.parametrize("t, offset", [(64, 0), (128, 0), (256, 0),
+                                       (128, 256)])
+def test_banded_flash_fwd_at_the_published_head_widths_with_a_sink(
+        t, offset):
+    """``flash_fwd`` in the interpreter against ``attend_rows``' XLA
+    body: d_qk 192 beside d_v 128, a band of 128 and a sink, prompts
+    under, at and over the window, and a segment at a traced offset
+    behind 256 earlier rows; the full layers' call (no band, no sink)
+    at the same shapes."""
+    ks = jax.random.split(jax.random.PRNGKey(t + offset), 4)
+    s = offset + t
+    q = jax.random.normal(ks[0], (1, 8, t, 192))
+    k = jax.random.normal(ks[1], (1, 2, s, 192))
+    v = jax.random.normal(ks[2], (1, 2, s, 128))
+    sink = 2.0 + jax.random.normal(ks[3], (8,))
+    for window, snk in ((128, sink), (None, None)):
+        want = attend_rows(q, k, v, offset=offset, window=window, sink=snk,
+                           use_flash=False)
+        got = jax.jit(lambda q, k, v, o: flash_fwd(
+            q, k, v, offset=o, window=window, sink=snk, block_q=64,
+            block_k=64, interpret=True))(q, k, v, jnp.int32(offset))
+        assert got.shape == (1, 8, t, 128)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_a_differentiated_band_or_sink_raises_by_name():
+    q = jnp.ones((1, 2, 64, 192))
+    k, v = jnp.ones((1, 1, 64, 192)), jnp.ones((1, 1, 64, 128))
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: flash_fwd(q, k, v, window=16, block_q=64,
+                                     block_k=64, interpret=True).sum())(q)
+    with pytest.raises(ValueError, match="values as wide as keys"):
+        flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                        v.transpose(0, 2, 1, 3), interpret=True)
+
+
+# ------------------------------------- who knows the block is there
+
+
+def test_nothing_of_the_program_imports_or_names_the_block():
+    """The block is found through a configuration that names it
+    (``cfg.slot_model``; ``benchmark/families/mimo_v2.py: build``): no
+    module of the program imports it, so a process that serves another
+    configuration never loads it, and neither the engine, the serving
+    tier nor a kernel carries its name."""
+    import ast
+    import glob
+    import os
+
+    import ray_tpu
+
+    root = ray_tpu.__path__[0]
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        if path.endswith(os.path.join("models", "mimo.py")):
+            continue
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            words = []
+            if isinstance(node, (ast.Import, ast.ImportFrom)):
+                words = [a.name for a in node.names] + [
+                    getattr(node, "module", None) or ""]
+            elif isinstance(node, ast.Name):
+                words = [node.id]
+            elif isinstance(node, ast.Attribute):
+                words = [node.attr]
+            assert not any("mimo" in w.lower() for w in words), path

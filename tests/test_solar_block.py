@@ -110,15 +110,15 @@ def test_the_configuration_carries_the_published_sizes():
 
 def test_a_bucket_is_cut_into_equal_segments_of_whole_chunks(monkeypatch):
     cfg = _cfg(kda_chunk=64)
-    assert solar.SEGMENT_ROWS == 2048
-    assert [solar.segment_rows(cfg, t) for t in (7, 1024, 2048, 8192, 32768)] \
+    assert moe.SEGMENT_ROWS == 2048
+    assert [moe.segment_rows(t, cfg.kda_chunk) for t in (7, 1024, 2048, 8192, 32768)] \
         == [7, 1024, 2048, 2048, 2048]
     assert solar.SLOTS.prefill_segments(cfg, 32768) == 16
     assert solar.SLOTS.prefill_segments(cfg, 1024) == 1
     with pytest.raises(ValueError, match="segments"):
-        solar.segment_rows(cfg, 2049)  # two segments of 1024.5 rows
+        moe.segment_rows(2049, cfg.kda_chunk)  # two segments of 1024.5 rows
     with pytest.raises(ValueError, match="chunks"):
-        solar.segment_rows(cfg, 4160)  # three of 1386.67; 4,160 = 65 x 64
+        moe.segment_rows(4160, cfg.kda_chunk)  # three of 1386.67; 4,160 = 65 x 64
 
 
 # ------------------------------------------------------------------ KDA
@@ -243,10 +243,10 @@ def test_prefill_in_eight_segments_is_prefill_in_one(monkeypatch, model):
     cfg, params = model
     toks = jax.random.randint(jax.random.PRNGKey(5), (2, 128), 1, 256)
     lens = jnp.array([128, 77])
-    monkeypatch.setattr(solar, "SEGMENT_ROWS", 128)
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 128)
     assert solar.SLOTS.prefill_segments(cfg, 128) == 1
     h1, st1, loads1 = solar.prefill(params, toks, lens, cfg, loads=True)
-    monkeypatch.setattr(solar, "SEGMENT_ROWS", 16)
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 16)
     assert solar.SLOTS.prefill_segments(cfg, 128) == 8
     h8, st8, loads8 = solar.prefill(params, toks, lens, cfg, loads=True)
     np.testing.assert_allclose(h8[0], h1[0], atol=1e-5)
@@ -344,7 +344,7 @@ def test_prefill_then_ragged_decode_is_the_references_forward(
 
 @pytest.fixture
 def segments_of_16(monkeypatch):
-    monkeypatch.setattr(solar, "SEGMENT_ROWS", 16)
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 16)
     jax.clear_caches()  # (the engine's programs are cached by cfg alone)
     yield
     jax.clear_caches()

@@ -37,7 +37,8 @@ SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("ling-3.0-flash-vl-ep4-1chip", "reason-saturated"),
                  ("k-exaone-236b-a23b-ep8-1chip", "reason-long-saturated"),
                  ("instella-moe-16b-a3b-pp4-1chip", "longdoc-saturated"),
-                 ("solar-open2-250b-ep8-1chip", "longreason-saturated"))
+                 ("solar-open2-250b-ep8-1chip", "longreason-saturated"),
+                 ("mimo-v2.5-ep16-1chip", "longreason-saturated"))
 TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
                ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 

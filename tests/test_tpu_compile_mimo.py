@@ -1,0 +1,144 @@
+"""The block with sink-carrying window layers beside full ones, keys wider
+than values (``models/mimo.py``) at the longreason cell's sizes, compiled
+for a described v5e (``tests/_tpu_compile.py`` says how and why): both
+attention kernels at the published head widths, the decode chunk and the
+32,768-row prefill.
+"""
+
+import functools
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import SingleDeviceSharding
+
+from _tpu_compile import (  # noqa: F401 (topo: a fixture)
+    KERNEL, MIB, _lower_prefill, _mem, _on, topo)
+from ray_tpu.models import decode_engine as de
+
+
+def _mimo_cell(topo, monkeypatch):
+    """``mimo-v2.5-ep16-1chip.longreason-saturated``'s model, engine
+    shape and arguments on one described chip, the kernels asked for by
+    name (the dispatches would read the CPU backend here)."""
+    import dataclasses
+
+    from benchmark import manifest
+    from ray_tpu.models import mimo
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, use_kernel=True))
+    with open("benchmark/traffic/longreason-saturated.json") as f:
+        eng = json.load(f)["engine"]
+    fam, m = manifest.model("mimo-v2.5-ep16-1chip")
+    prog = fam.build(m, max_seq_len=eng["max_len"], remat=False)
+    cfg = dataclasses.replace(prog.cfg, use_flash=True)
+    chip = SingleDeviceSharding(topo.devices[0])
+    params = _on(chip, jax.eval_shape(prog.init_params,
+                                      jax.random.PRNGKey(0)))
+    state = _on(chip, jax.eval_shape(lambda: mimo.SLOTS.init_state(
+        cfg, eng["slots"], eng["max_len"])))
+    vec = lambda dt, n=eng["slots"]: jax.ShapeDtypeStruct(  # noqa: E731
+        (n,), dt, sharding=chip)
+    return fam, m, cfg, eng, params, state, vec
+
+
+def _kernel_calls(text: str) -> list:
+    return [line.split(" = ")[0].strip() for line in text.splitlines()
+            if KERNEL in line]
+
+
+def test_mimo_decode_chunk_reads_four_stacks_in_place(topo, monkeypatch):
+    """The cell's decode program (7 layers, 16 of 256 experts held, 32
+    slots: two full stacks of 34,832 rows of 768 + 512 numbers, five
+    rings of 128 rows of 1,536 + 1,024): a step calls ``decode_attn``
+    once a layer (16 query rows a kv head on the full stacks, 8 and the
+    sink on the rings; never the XLA body, which would read all 34,832
+    rows of every slot) and ``moe_gmm`` three times an expert layer at
+    4096 x 2048; the donated stacks are updated in place, never copied;
+    no matrix exists in float32; arguments and temporaries stay under
+    13.5 GiB of the chip's 16."""
+    from ray_tpu.models import mimo
+
+    fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
+    slots, max_len = eng["slots"], eng["max_len"]
+    compiled = de.decode_chunk.lower(
+        params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+        chunk=eng["chunk_tokens"]).compile()
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    assert sum("decode_attn" in c for c in calls) == cfg.n_layers == 7
+    assert len(calls) == cfg.n_layers + 3 * cfg.moe_layers == 25
+    for dims in (f"bf16[2,{slots},{max_len},768]",
+                 f"bf16[2,{slots},{max_len},512]",
+                 f"bf16[5,{slots},128,1536]", f"bf16[5,{slots},128,1024]"):
+        assert dims in text
+        assert not re.search(re.escape(dims) + r"\S* copy\(", text), dims
+    for shape in {a.shape for a in jax.tree_util.tree_leaves(params)
+                  if a.dtype == jnp.bfloat16 and a.size > 1 << 20}:
+        assert f"f32[{','.join(map(str, shape))}]" not in text, shape
+    mem = compiled.memory_analysis()
+    state_bytes = sum(mimo.SLOTS.state_bytes(state).values())
+    assert state_bytes == slots * sum(
+        fam.state_bytes_per_slot(m, max_len).values()) == 32 * 181_616_640
+    assert mem.alias_size_in_bytes >= state_bytes, _mem(compiled)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 2 * fam.num_params(m)) < 1 << 20  # (f32 leaves)
+    print(f"\nmimo decode chunk: {_mem(compiled)}")
+    assert mem.temp_size_in_bytes < 256 * MIB, _mem(compiled)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 13.5 * 1024 * MIB), _mem(compiled)
+
+
+def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
+        topo, monkeypatch):
+    """The cell's cold prefill call at its widest bucket, one prompt of
+    32,768 rows in 16 segments of 2,048, every layer one scan:
+    ``flash_fwd`` in the two full layers (d_qk 192, d_v 128, a segment's
+    rows against the rows so far) and ``flash_fwd_window`` in the five
+    window layers (the band's blocks alone, the sink at the finalize),
+    ``moe_gmm`` three times an expert layer; no ``[32768, 32768]``
+    scores, no whole ``[32768, 16384]`` gate or up of the dense layer in
+    either type, no ``[P, vocabulary]`` logits; the donated state is
+    updated in place; beside 32 slots the call fits the chip's 16 GiB
+    (temporaries 3,284 MiB beside 12,084 MiB of arguments: the stream in
+    and out of a layer, a layer's k and v so far, a segment's expert
+    rows; on the chip the cell's peak reads 12.83 GB)."""
+    from ray_tpu.models import mimo
+
+    fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
+    assert eng["prompt_buckets"][-1] == 32768
+    assert mimo.SLOTS.prefill_segments(cfg, 32768) == 16
+    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, 32768,
+                              (params, state, vec)).compile()
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    assert sum(bool(re.match(r"%flash_fwd_window\b", c)) for c in calls) \
+        == cfg.window_layers == 5
+    assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
+        == cfg.full_layers == 2
+    assert sum("moe_gmm" in c for c in calls) == 3 * cfg.moe_layers
+    arrays = {(dt, tuple(int(d) for d in dims.split(",")))
+              for dt, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
+                                         text)}
+    assert "32768,32768" not in text and "2048,32768]" not in text
+    assert not [a for a in arrays if 16384 in a[1] and 32768 in a[1]]
+    assert not [a for a in arrays if cfg.vocab_size in a[1]
+                and (32768 in a[1] or 2048 in a[1])]
+    whole = [a for a in arrays if a[0] == "f32"
+             and np.prod(a[1]) >= 32768 * 4096]
+    assert not whole, whole[:4]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        mimo.SLOTS.state_bytes(state).values()), _mem(compiled)
+    print(f"\nmimo prefill 1 x 32768: {_mem(compiled)}")
+    assert mem.temp_size_in_bytes < 3400 * MIB, _mem(compiled)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.25 * 1024 * MIB), _mem(compiled)
