@@ -777,13 +777,17 @@ class RaggedDecoder:
     def _prefill_cold(self, slot: int, s: _Stream) -> None:
         """One call of the prefill program for one prompt, at one row of
         its bucket's width (``prefill_calls`` in stats(), one
-        ``engine.prefill`` span a call)."""
+        ``engine.prefill`` span a call: ``segments`` is the BUCKET's,
+        ``live_segments`` those of them that hold a row of the prompt,
+        which are the ones a segmented block runs)."""
         n = len(s.prompt)
         s.bucket = pb = self._bucket(n)
         self.prefill_calls += 1
+        segments = self.model.prefill_segments(self.cfg, pb)
         with _fr.span("serve", "engine.prefill", flush=False, attrs={
                 "bucket": pb, "prompts": 1, "rows": 1, "tokens": n,
-                "segments": self.model.prefill_segments(self.cfg, pb)}):
+                "segments": segments,
+                "live_segments": -(-n // (pb // segments))}):
             self._prefill_into_slot(slot, s, s.prompt, pb)
 
     def _prefill_into_slot(self, slot: int, s: _Stream, tokens, width: int,

@@ -44,13 +44,15 @@ an earlier position's rows: the prefix cache, speculation and the
 prefill workers refuse this model by name (``rows_state``).
 
 **Prefill** is one call a cold prompt, sized by its bucket, and every
-layer one ``lax.scan`` over segments of ``moe.SEGMENT_ROWS`` rows: a
-segment's norm, ``W_qkv``, rotation, its k and v written into the
-layer's rows so far (carried in the flash kernel's layout), its q rows
-against those rows (``ops.attention.attend_rows``: the flash kernel at
-a traced offset, a band and the sink in a window layer), ``W_o`` and
-the MLP. Nothing of a layer is whole but its k and v rows and the
-stream; no ``[P, P]`` scores and no ``[P, dense_d_ff]`` array exist.
+layer one ``lax.scan`` over segments of ``moe.SEGMENT_ROWS`` rows
+(``moe.in_segments``; the bucket's segments past the prompt's last real
+row are dead and are not run): a segment's norm, ``W_qkv``, rotation,
+its k and v written into the layer's rows so far (carried in the flash
+kernel's layout), its q rows against those rows
+(``ops.attention.attend_rows``: the flash kernel at a traced offset, a
+band and the sink in a window layer), ``W_o`` and the MLP. Nothing of a
+layer is whole but its k and v rows and the stream; no ``[P, P]`` scores
+and no ``[P, dense_d_ff]`` array exist.
 
 Types: matrices in ``dtype`` (bf16), products accumulated in float32;
 norm vectors, sinks and the router's bias float32; router scores and
@@ -250,7 +252,7 @@ def _attn_scope(windowed: bool):
 # --------------------------------------------------------------------------
 
 def prefill(params, tokens, true_lens, cfg: MimoConfig,
-            loads: bool = False):
+            loads: bool = False, live=None):
     """tokens [B, T] from position 0 (right-padded, ``true_lens`` [B]
     real; padding sees nothing real behind it: causal), every layer in
     segments of ``moe.segment_rows`` rows (module docstring) -> (h [B,
@@ -260,7 +262,15 @@ def prefill(params, tokens, true_lens, cfg: MimoConfig,
     real rows (``exaone.ring_rows``; its other rows die with the layer:
     five layers' would be 0.8 GB), and with ``loads`` the held experts'
     assignments from the real positions [L_moe, count] int32, else
-    None)."""
+    None).
+
+    ``live`` (``jnp.max(true_lens)``, traced: the serving call's) leaves
+    the DEAD segments out of every layer's scan (``moe.in_segments``:
+    those that begin past the longest prompt's last real row, a call's
+    last and for a full layer its dearest): their rows of h and of a
+    full layer's k and v stay zeros, which nothing reads, and a ring is
+    cut from the last REAL rows whatever ran. ``None`` runs every
+    segment: the whole sequences of ``forward``."""
     b, t = tokens.shape
     seg = moe.segment_rows(t)
     dk, dv = cfg.head_dim, cfg.v_head_dim
@@ -309,7 +319,8 @@ def prefill(params, tokens, true_lens, cfg: MimoConfig,
         empty = (jnp.zeros((b, hkv, t, dk), cdt),
                  jnp.zeros((b, hkv, t, dv), cdt),
                  jnp.zeros((cfg.held[1],), jnp.int32) if count_loads else ())
-        (k_all, v_all, count), h = moe.in_segments(layer, empty, h, seg)
+        (k_all, v_all, count), h = moe.in_segments(layer, empty, h, seg,
+                                                   live)
         with jax.named_scope("cache"):
             rows.append(tuple(_cache_rows(
                 cfg, a, true_lens if windowed else None)
@@ -464,7 +475,8 @@ class _Slots(Slots):
         [L_moe, count])."""
         Slots.refuse_prefix(cfg, prefix)
         h, rows, loads = prefill(params, prompts, true_lens, cfg,
-                                 loads=cfg.moe_layers > 0)
+                                 loads=cfg.moe_layers > 0,
+                                 live=jnp.max(true_lens))
         toks0, logp0 = Slots.first_token(
             functools.partial(moe.logits, cfg), params, h, true_lens,
             seeds, temps, top_ps)

@@ -255,20 +255,54 @@ def segment_rows(t: int, whole: int = 1) -> int:
     return t // n
 
 
-def in_segments(body, carry, xs, seg: int):
+def in_segments(body, carry, xs, seg: int, live=None):
     """``body(carry, (segment's first row, the segment's rows of xs)) ->
     (carry, outputs with a leading [B, seg])`` over the segments of
-    ``xs`` (a tree of [B, T, ...] arrays) in order -> (carry, the
-    outputs [B, T, ...]). One segment is one plain call."""
+    ``xs`` (a tree of [B, T, ...] arrays) in order, one ``lax.scan`` ->
+    (carry, the outputs [B, T, ...]). One segment is one plain call.
+
+    ``live`` (a traced int32 scalar: the rows of the call's longest
+    prompt) leaves out the segments behind the last one that holds a
+    real row. A segment whose first row is ``>= live`` is DEAD: every
+    row of it is padding, ``body`` is not run for it (the scan's step is
+    ``lax.cond(start < live, body, skip)``), the carry passes through
+    unchanged and its rows of the outputs are zeros. A live segment's
+    arithmetic is the scan's own: on the chip the results are the same
+    bits with ``live`` and without (``PERF.md`` §6 PR 51). ``live=None``
+    runs ``body`` on every segment: whole sequences (``forward``,
+    ``loss_fn``)."""
     b, t = jax.tree_util.tree_leaves(xs)[0].shape[:2]
     n = t // seg
     if n == 1:
         return body(carry, (jnp.int32(0), xs))
+    step = body
+    if live is not None:
+        # ``body`` is traced ONCE, as the scan alone traces it, for the
+        # dead branch's shapes and for the live branch, which binds its
+        # equations: a second trace of four buckets' layers was 28 s of
+        # a cell's set-up (PERF.md §6 PR 51)
+        traced, (_, first) = jax.make_jaxpr(body, return_shape=True)(
+            carry, (jnp.int32(0), jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct((b, seg, *a.shape[2:]),
+                                               a.dtype), xs)))
+        shape = jax.tree_util.tree_structure((carry, first))
+
+        def run(carry, x):
+            return jax.tree_util.tree_unflatten(shape, jax.core.eval_jaxpr(
+                traced.jaxpr, traced.consts,
+                *jax.tree_util.tree_leaves((carry, x))))
+
+        def skip(carry, x):
+            return carry, jax.tree_util.tree_map(
+                lambda y: jnp.zeros(y.shape, y.dtype), first)
+
+        def step(carry, x):
+            return jax.lax.cond(x[0] < live, run, skip, carry, x)
 
     def rows(a):  # [B, T, ...] -> [n, B, seg, ...]
         return jnp.moveaxis(a.reshape(b, n, seg, *a.shape[2:]), 1, 0)
 
-    carry, outs = jax.lax.scan(body, carry, (
+    carry, outs = jax.lax.scan(step, carry, (
         jnp.arange(n, dtype=jnp.int32) * seg,
         jax.tree_util.tree_map(rows, xs)))
     return carry, jax.tree_util.tree_map(

@@ -49,8 +49,12 @@ class Slots:
     - ``row_kinds(cfg)``: for a model whose layers keep rows of several
       kinds, {kind: (layers, the most rows a slot keeps in one, ``None``
       = ``max_len``)} (what ``_count_rows`` counts by);
-    - ``prefill_segments(cfg, bucket)``: in how many segments of rows a
-      ``bucket``-row call runs its tokenwise work;
+    - ``prefill_segments(cfg, bucket)``: into how many segments of rows
+      a ``bucket``-row call cuts its tokenwise work. The BUCKET's count:
+      a call runs those of them that hold a row of its longest prompt
+      and leaves the dead ones behind it out (``moe.in_segments``;
+      ``engine.prefill`` carries both, ``segments`` and
+      ``live_segments``);
     - ``split(cfg, params)``: what a chunk prepares once, ``step``'s
       ``prepared``; ``reports_routing(cfg)``;
     - ``F32_LEAVES``: the leaves its model paths consume in float32,

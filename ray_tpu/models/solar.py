@@ -42,12 +42,14 @@ does not fit as whole arrays (q, k, v, g of ``[T, 64, 128]`` float32 are
 So everything that is a function of a row and a carried state (norms,
 projections, the convolution, the chunkwise delta rule, gates and
 ``W_o``, router and experts) runs over segments of at most
-``moe.SEGMENT_ROWS`` rows under one ``lax.scan`` a layer, a KDA layer's
-``S`` and last three projection rows carried from segment to segment;
-only what needs the whole prompt is whole: the GQA layer's q, k and v
-(bf16) and one flash call over them. The bucket decides: a bucket of at
-most ``moe.SEGMENT_ROWS`` is one segment (``moe.segment_rows``). No option
-chooses it.
+``moe.SEGMENT_ROWS`` rows under one ``lax.scan`` a layer (``moe.in_segments``),
+a KDA layer's ``S`` and last three projection rows carried from segment
+to segment; only what needs the whole prompt is whole: the GQA layer's
+q, k and v (bf16) and one flash call over them. The bucket decides: a
+bucket of at most ``moe.SEGMENT_ROWS`` is one segment
+(``moe.segment_rows``). A serving call's scans skip the segments behind
+the last one that holds a real row of its longest prompt: they are dead
+and are not run. No option chooses either.
 
 A slot's state in the serving engine is this model's own
 (:data:`SLOTS`, found through ``SolarConfig.slot_model``), of two kinds
@@ -344,14 +346,24 @@ def _gqa_out(cfg: SolarConfig, p, x, o):
 # --------------------------------------------------------------------------
 
 def prefill(params, tokens, true_lens, cfg: SolarConfig,
-            loads: bool = False):
+            loads: bool = False, live=None):
     """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
     state, the tokenwise parts in segments of ``moe.segment_rows`` rows
     (module docstring) -> (h [B, T, D] before the final norm, the
     streams' state {"kda": a list of {"s", "conv"} a KDA layer,
     "k_full", "v_full" [L_full, B, T, Hkv * hd]: the GQA layers' rows,
     padding's among them}, and with ``loads`` the held experts'
-    assignments from the real positions [L, count] int32, else None)."""
+    assignments from the real positions [L, count] int32, else None).
+
+    ``live`` (``jnp.max(true_lens)``, traced: the serving call's) leaves
+    the DEAD segments out of every layer's scans (``moe.in_segments``:
+    those that begin past the longest prompt's last real row): a KDA
+    layer's state after the last live segment is the state after the
+    last real token, and a dead segment's rows of h, k and v are zeros,
+    which nothing reads (the GQA layer's one flash call over the bucket
+    takes them as it took the padding's: causal, behind every real
+    row). ``None`` runs every segment: the whole sequences of
+    ``forward``."""
     b, t = tokens.shape
     seg = moe.segment_rows(t, cfg.kda_chunk)
     with jax.named_scope("embed"):
@@ -374,7 +386,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
             def project(_, xs, p=p):
                 return (), _qkv(cfg, p["attn"], norm(xs[1]))
 
-            _, (q, k, v) = moe.in_segments(project, (), h, seg)
+            _, (q, k, v) = moe.in_segments(project, (), h, seg, live)
             with jax.named_scope("attn/attn_full"):
                 o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
 
@@ -390,7 +402,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
                 k_rows.append(k.reshape(b, t, -1))
                 v_rows.append(v.reshape(b, t, -1))
             count, h = moe.in_segments(rest, _zero_loads(cfg, loads), (h, o),
-                                    seg)
+                                       seg, live)
         else:
             def layer(carry, xs, p=p):
                 state, count = carry
@@ -404,7 +416,8 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
                     h_seg
 
             (state, count), h = moe.in_segments(
-                layer, (kda_empty(cfg, b), _zero_loads(cfg, loads)), h, seg)
+                layer, (kda_empty(cfg, b), _zero_loads(cfg, loads)), h, seg,
+                live)
             kda.append(state)
         counts.append(count)
 
@@ -534,7 +547,7 @@ class _Slots(Slots):
         held experts' assignments from the real positions [L, count])."""
         Slots.refuse_prefix(cfg, prefix)
         h, streams, loads = prefill(params, prompts, true_lens, cfg,
-                                    loads=True)
+                                    loads=True, live=jnp.max(true_lens))
         toks0, logp0 = Slots.first_token(
             functools.partial(moe.logits, cfg), params, h, true_lens,
             seeds, temps, top_ps)

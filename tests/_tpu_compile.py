@@ -40,6 +40,7 @@ from jax.sharding import Mesh, SingleDeviceSharding  # noqa: E402
 from ray_tpu.models import decode_engine as de  # noqa: E402
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.models import llama_slots  # noqa: E402
+from ray_tpu.models import program_parts  # noqa: E402
 from ray_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from ray_tpu.parallel import AXES, MeshConfig, use_mesh  # noqa: E402
 from ray_tpu.train import batch_sharding, make_train_step  # noqa: E402
@@ -209,6 +210,57 @@ def _kda_chunk_calls(text: str, prefetched_ok: bool = False) -> list:
         assert not moved, moved
         assert "output_to_operand_aliasing={{1}: (5, {})}" in call, call[:600]
     return calls
+
+
+def _segment_branches(text: str) -> list:
+    """The ``conditional``s that stand in a ``while``'s body of a compiled
+    prefill program (``moe.in_segments`` with ``live``: a scan whose step
+    runs a segment or skips it) as (the body's lines, the DEAD branch's
+    lines, the live branch's lines); the dead branch is the one of fewer
+    instructions."""
+    computations, lines = {}, None
+    for line in text.splitlines():
+        m = program_parts._COMPUTATION.match(line)
+        if m:
+            lines = computations.setdefault(m[1], [])
+        elif lines is not None:
+            lines.append(line)
+    found = []
+    for body in re.findall(r" while\(.*?body=%([\w.\-]+)", text):
+        for ln in computations[body]:
+            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          ln)
+            if m:
+                dead, live = sorted(
+                    (computations[c.strip().lstrip("%")]
+                     for c in m[1].split(",")), key=len)
+                found.append((computations[body], dead, live))
+    return found
+
+
+def _dead_branch_hands_on_and_makes_zeros(dead: list) -> None:
+    """A dead segment's branch: the carry's elements as they came, and
+    zeros for the segment's rows of the outputs."""
+    made = [m[1] for ln in dead
+            if (m := re.search(r" = \S+ ([\w\-]+)\(", ln))]
+    assert set(made) <= {"parameter", "get-tuple-element", "constant",
+                         "broadcast", "tuple"}, made
+
+
+def _loops_add_nothing_unscoped(text: str) -> None:
+    """The segment loops' own arithmetic, the branch and a dead
+    segment's zeros land in ``loop`` in the device-time table's map, not
+    in ``unscoped`` (three instructions of the parent's prefill program,
+    ``PERF.md`` §6 PR 51), and ``loop`` stays a small part of the map:
+    the ``cond`` and ``branch_1_fun`` of an ``op_name`` hide no scope
+    (what XLA moves out of the branch for it, the prefetches of a
+    layer's weights into VMEM, has the ``conditional`` for its user and
+    is the loop's: 175 of Solar-Open2's 1,283 instructions for 90 of
+    1,194 on the parent)."""
+    parts = program_parts.parts_of(text)
+    unscoped = {k: v for k, v in parts.items() if v.startswith("unscoped")}
+    assert len(unscoped) <= 3, unscoped
+    assert sum(v.startswith("loop") for v in parts.values()) < len(parts) / 6
 
 
 # InternLM2-1.8B's widths, two layers deep

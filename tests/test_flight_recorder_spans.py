@@ -328,7 +328,8 @@ def test_replica_pump_spans_and_poll_pickup_marks(tmp_path):
             == len(new["engine.deliver"]) == 2
         assert len(new["engine.admit"]) == len(new["engine.prefill"]) == 1
         assert new["engine.prefill"][0]["attrs"] == {
-            "bucket": 8, "prompts": 1, "rows": 1, "tokens": 7, "segments": 1}
+            "bucket": 8, "prompts": 1, "rows": 1, "tokens": 7, "segments": 1,
+            "live_segments": 1}
         assert new["engine.admit"][0]["attrs"] == {
             "admitted": 1, "prefilled": 0, "cold": 1, "warm": 0}
         assert [s["attrs"]["delivered"] for s in new["engine.deliver"]] \

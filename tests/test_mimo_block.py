@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _segments import (  # noqa: F401 (segments_of_16: a fixture)
+    segments_of_16, short_prompt_in_a_reused_slot)
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import mimo, moe
@@ -221,6 +223,59 @@ def test_prefill_then_ragged_decode_is_the_references_forward(
         assert np.abs(got - want[len(p):len(p) + len(got)]).max() < F32_TOL
 
 
+@pytest.mark.parametrize("lens, live_segments", [((40, 70), 5), ((64,), 4),
+                                                 ((128, 3), 8)])
+def test_a_call_without_its_dead_segments_leaves_what_is_read_bit_for_bit(
+        lens, live_segments, segments_of_16, model):
+    """A 128-row bucket in eight segments of 16 with ``live`` = the
+    longest prompt's rows (traced, as ``SLOTS.prefill`` passes it)
+    against the same call with every segment run: the first tokens and
+    their logprobs, every window layer's ring, each prompt's own rows of
+    the full layers, the stream's real rows and the loads are the same
+    bits; a full layer's rows and the stream's in the segments not run
+    are zeros."""
+    cfg, params = model
+    toks = jax.random.randint(jax.random.PRNGKey(5), (len(lens), 128), 1, 256)
+    lens = jnp.array(lens, jnp.int32)
+    f = len(lens)
+
+    def call(live):
+        h, rows, loads = mimo.prefill(params, toks, lens, cfg, loads=True,
+                                      live=live)
+        return h, rows, loads, mimo.SLOTS.first_token(
+            functools.partial(moe.logits, cfg), params, h, lens,
+            jnp.zeros((f,), jnp.uint32), jnp.zeros((f,), jnp.float32),
+            jnp.ones((f,), jnp.float32))
+
+    h0, rows0, loads0, first0 = jax.jit(lambda: call(None))()
+    h1, rows1, loads1, first1 = jax.jit(call)(jnp.max(lens))
+    np.testing.assert_array_equal(first1[0], first0[0])
+    np.testing.assert_array_equal(first1[1], first0[1])
+    np.testing.assert_array_equal(loads1, loads0)
+    assert int(loads0.sum()) > 0
+    run = live_segments * 16
+    for i, (kv0, kv1) in enumerate(zip(rows0, rows1)):
+        for a, b in zip(kv0, kv1):
+            if cfg.windowed(i):
+                assert a.shape[1] == W
+                np.testing.assert_array_equal(b, a)
+                continue
+            for j, n in enumerate(np.asarray(lens)):
+                np.testing.assert_array_equal(b[j, :n], a[j, :n])
+            assert not b[:, run:].any() and b[:, :run].any()
+    for j, n in enumerate(np.asarray(lens)):
+        np.testing.assert_array_equal(h1[j, :n], h0[j, :n])
+    assert not h1[:, run:].any() and h0[:, 112:].any()
+
+
+def test_a_short_prompt_in_a_long_bucket_is_the_reference_in_a_reused_slot(
+        segments_of_16, model):
+    cfg, params = model
+    short_prompt_in_a_reused_slot(
+        mimo.SLOTS, cfg, params, lambda tokens: REF.forward(
+            params, jnp.asarray([tokens]), M)[0], F32_TOL)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_submit_and_pump_serve_the_references_tokens(dtype):
     """``RaggedDecoder`` (submit -> pump) on the model: five streams
@@ -278,8 +333,8 @@ def test_spans_carry_the_state_the_row_bytes_and_the_rows_by_kind(
     row = FAM.kv_row_bytes(M, 4)
     assert (init["window_row_bytes"], init["full_row_bytes"]) \
         == (row["window"], row["full"]) == (640, 320)
-    assert [s["attrs"]["segments"] for s in spans
-            if s["name"] == "engine.prefill"][-1] == 2
+    pre = [s["attrs"] for s in spans if s["name"] == "engine.prefill"][-1]
+    assert (pre["segments"], pre["live_segments"]) == (2, 2)
     backs = [s["attrs"] for s in spans if s["name"] == "engine.readback"
              and "live_rows_window" in s["attrs"]][-2:]
     assert [b["live_rows_full"] for b in backs] == [16, 20]

@@ -101,7 +101,7 @@ def test_one_pump_prefills_each_prompt_in_a_call_of_its_own(n_prompts):
     assert eng.stats()["prefill_calls"] == n_prompts
     assert _prefill_spans()[n_spans:] == [  # in queue order
         {"bucket": 8 if n <= 8 else 16, "prompts": 1, "rows": 1,
-         "tokens": n, "segments": 1} for n in lens]
+         "tokens": n, "segments": 1, "live_segments": 1} for n in lens]
     eng.drain()
     alone = RaggedDecoder(params, TINY, **kw)
     for sid, p in zip(sids, prompts):

@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _segments import (  # noqa: F401 (segments_of_16: a fixture)
+    segments_of_16, short_prompt_in_a_reused_slot)
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import ling, moe, solar
@@ -342,14 +344,6 @@ def test_prefill_then_ragged_decode_is_the_references_forward(
     assert control > tol, (control, tol)
 
 
-@pytest.fixture
-def segments_of_16(monkeypatch):
-    monkeypatch.setattr(moe, "SEGMENT_ROWS", 16)
-    jax.clear_caches()  # (the engine's programs are cached by cfg alone)
-    yield
-    jax.clear_caches()
-
-
 def test_the_engines_prefill_in_segments_is_the_references_forward(
         segments_of_16, model):
     """The same comparison with the 64-row buckets run in four segments
@@ -358,6 +352,61 @@ def test_the_engines_prefill_in_segments_is_the_references_forward(
     cfg, params = model
     assert solar.SLOTS.prefill_segments(cfg, 64) == 4
     assert _worst(cfg, params, _prompts(1), 8, np.max) < F32_TOL
+
+
+def _first_tokens(cfg, params, h, lens):
+    f = len(lens)
+    return solar.SLOTS.first_token(
+        functools.partial(moe.logits, cfg), params, h, lens,
+        jnp.zeros((f,), jnp.uint32), jnp.zeros((f,), jnp.float32),
+        jnp.ones((f,), jnp.float32))
+
+
+@pytest.mark.parametrize("lens, live_segments", [((40, 70), 5), ((64,), 4),
+                                                 ((128, 3), 8)])
+def test_a_call_without_its_dead_segments_leaves_what_is_read_bit_for_bit(
+        lens, live_segments, segments_of_16, model):
+    """A 128-row bucket in eight segments of 16 with ``live`` = the
+    longest prompt's rows (traced, as ``SLOTS.prefill`` passes it)
+    against the same call with every segment run: the first tokens and
+    their logprobs, every KDA layer's ``S`` and convolution rows, each
+    prompt's own k / v rows, the stream's real rows and the loads are
+    the same bits; the rows of the segments not run are zeros."""
+    cfg, params = model
+    toks = jax.random.randint(jax.random.PRNGKey(5), (len(lens), 128), 1, 256)
+    lens = jnp.array(lens, jnp.int32)
+
+    def call(live):
+        h, st, loads = solar.prefill(params, toks, lens, cfg, loads=True,
+                                     live=live)
+        return h, st, loads, _first_tokens(cfg, params, h, lens)
+
+    h0, st0, loads0, first0 = jax.jit(lambda: call(None))()
+    h1, st1, loads1, first1 = jax.jit(call)(jnp.max(lens))
+    np.testing.assert_array_equal(first1[0], first0[0])
+    np.testing.assert_array_equal(first1[1], first0[1])
+    np.testing.assert_array_equal(loads1, loads0)
+    assert len(st1["kda"]) == 3
+    for a, b in zip(jax.tree_util.tree_leaves(st0["kda"]),
+                    jax.tree_util.tree_leaves(st1["kda"])):
+        np.testing.assert_array_equal(b, a)
+    run = live_segments * 16
+    for i, n in enumerate(np.asarray(lens)):
+        np.testing.assert_array_equal(h1[i, :n], h0[i, :n])
+        for name in ("k_full", "v_full"):
+            np.testing.assert_array_equal(st1[name][:, i, :n],
+                                          st0[name][:, i, :n])
+    for a in (h1, st1["k_full"][0], st1["v_full"][1]):
+        assert not a[:, run:].any() and a[:, :run].any()
+    assert h0[:, 112:].any()  # (run whole, the padding's rows are not)
+
+
+def test_a_short_prompt_in_a_long_bucket_is_the_reference_in_a_reused_slot(
+        segments_of_16, model):
+    cfg, params = model
+    short_prompt_in_a_reused_slot(
+        solar.SLOTS, cfg, params, lambda tokens: REF.forward(
+            params, jnp.asarray([tokens]), M)[0], F32_TOL)
 
 
 @pytest.mark.parametrize("left_out", ["beta_doubled", "gqa_gate", "no_rotary",
@@ -528,7 +577,7 @@ def test_spans_carry_both_kinds_of_state_the_segments_and_the_routing(
     assert (init["slots"], init["max_len"]) == (2, 96)
     pre = [s["attrs"] for s in ring if s["name"] == "engine.prefill"][-1]
     assert pre == {"bucket": 64, "prompts": 1, "rows": 1, "tokens": 39,
-                   "segments": 4}
+                   "segments": 4, "live_segments": 3}
     back = [s["attrs"] for s in ring if s["name"] == "engine.readback"
             and "held_assignments" in s["attrs"]][-1]
     # one occupied slot, 39 + 8 positions at the last chunk's end
@@ -537,6 +586,26 @@ def test_spans_carry_both_kinds_of_state_the_segments_and_the_routing(
     assert back["assignments"] == M["top_k"]
     assert 0 <= back["experts_touched"] <= back["held_assignments"] \
         <= M["top_k"]
+
+
+@pytest.mark.parametrize("tokens, live", [(78, 5), (64, 4), (128, 8)])
+def test_the_prefill_span_counts_the_live_segments_beside_the_buckets(
+        tokens, live, segments_of_16, model):
+    """The 16-row rehearsal of a 9,984-token prompt in the 16,384-row
+    bucket (78 in 128: 4.875 segments): ``segments`` stays the bucket's
+    8, ``live_segments`` is the 5 that hold a row of the prompt."""
+    from ray_tpu._private import flight_recorder as fr
+
+    cfg, params = model
+    eng = RaggedDecoder(params, cfg, slots=2, max_len=160, chunk_tokens=4,
+                        prompt_buckets=(128,), name="solar-live")
+    sid = eng.submit(np.arange(1, tokens + 1, dtype=np.int32) % 255 + 1, 4)
+    eng.drain()
+    assert len(eng.finished[sid].tokens) == 4
+    pre = [s["attrs"] for s in fr._get().ring
+           if s["name"] == "engine.prefill"][-1]
+    assert pre == {"bucket": 128, "prompts": 1, "rows": 1, "tokens": tokens,
+                   "segments": 8, "live_segments": live}
 
 
 def test_both_kda_blocks_call_the_one_step_kernel():

@@ -15,7 +15,9 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, _lower_prefill, _mem, _on, topo)
+    KERNEL, MIB, _dead_branch_hands_on_and_makes_zeros,
+    _loops_add_nothing_unscoped, _lower_prefill, _mem, _on,
+    _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -142,3 +144,34 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
     assert mem.temp_size_in_bytes < 3400 * MIB, _mem(compiled)
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.25 * 1024 * MIB), _mem(compiled)
+
+
+def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
+        topo, monkeypatch):
+    """The cell's cold prefill call at 16,384 rows (eight segments): a
+    layer's one loop holds one ``conditional`` on a segment's first row
+    against the prompt's rows, which the program reads from
+    ``true_lens`` (``moe.in_segments`` with ``live``). The dead branch
+    hands the k and v rows it carries on (``[1, Hkv, 16384, 192 / 128]``,
+    25 to 100 MB a layer) and makes zeros, nothing else: no kernel, no
+    fusion, no copy, and no loop copies them either (written a segment
+    at a time in place); each layer's flash kernel is called in the live
+    branch; the branch lands nothing in ``unscoped`` (three instructions
+    on the parent, ``PERF.md`` §6 PR 51)."""
+    from ray_tpu.models import mimo
+
+    fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
+    assert mimo.SLOTS.prefill_segments(cfg, 16384) == 8
+    text = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
+                          (params, state, vec)).compile().as_text()
+    branches = _segment_branches(text)
+    assert len(branches) == text.count(" while(") \
+        == text.count(" conditional(") == cfg.n_layers == 7
+    for loop, dead, live in branches:
+        _dead_branch_hands_on_and_makes_zeros(dead)
+        copied = [ln[:160] for ln in loop + live if re.search(
+            r"= bf16\[1,[48],16384,(192|128)\]\S* (copy|copy-start)\(", ln)]
+        assert not copied, copied
+        assert sum(KERNEL in ln and "flash_fwd" in ln.split(" = ")[0]
+                   for ln in live) == 1
+    _loops_add_nothing_unscoped(text)

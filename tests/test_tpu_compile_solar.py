@@ -14,7 +14,9 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _kda_chunk_calls, KERNEL, _lower_prefill, _mem, MIB, _on, topo)
+    _dead_branch_hands_on_and_makes_zeros, _kda_chunk_calls, KERNEL,
+    _lower_prefill, _mem, MIB, _on,
+    _loops_add_nothing_unscoped, _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -153,3 +155,45 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     assert mem.temp_size_in_bytes < 3584 * MIB, _mem(compiled)
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 14.5 * 1024 * MIB), _mem(compiled)
+
+
+def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
+        topo, monkeypatch):
+    """The cell's cold prefill call at 16,384 rows (eight segments): each
+    of a layer's loops (the GQA layer's projections and its rest, a KDA
+    layer's one) holds one ``conditional`` on a segment's first row
+    against the prompt's rows, which the program reads from
+    ``true_lens`` (``moe.in_segments`` with ``live``). The dead branch
+    hands the carry on and makes zeros, nothing else: no kernel, no
+    fusion, no copy of the ``S`` it carries, and no loop copies it
+    either; ``kda_chunk`` is called once a KDA layer in the live branch
+    and its body is traced once for the three; the branch lands nothing
+    in ``unscoped`` (three instructions on the parent, ``PERF.md`` §6 PR
+    51)."""
+    from ray_tpu.models import solar
+    from ray_tpu.ops import kda_chunk as kc
+
+    fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
+    assert solar.SLOTS.prefill_segments(cfg, 16384) == 8
+    traced = []
+    body = kc._kernel
+    monkeypatch.setattr(kc, "_kernel", lambda *a, **kw: (
+        traced.append(kw), body(*a, **kw))[1])
+    jax.clear_caches()  # (an earlier test's trace of this shape)
+    lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
+                             (params, state, vec))
+    assert len(traced) == 1, traced
+    text = lowered.compile().as_text()
+    branches = _segment_branches(text)
+    assert len(branches) == text.count(" while(") \
+        == text.count(" conditional(") \
+        == 2 * cfg.full_layers + cfg.kda_layers == 5
+    for loop, dead, _ in branches:
+        _dead_branch_hands_on_and_makes_zeros(dead)
+        copied = [ln[:160] for ln in loop if re.search(
+            r"= f32\[1,64,128,128\]\S* (copy|copy-start)\(", ln)]
+        assert not copied, copied
+    calls = _kda_chunk_calls(text)
+    assert len(calls) == 3 and all(
+        "/while/body/closed_call/cond/" in c for c in calls)
+    _loops_add_nothing_unscoped(text)
