@@ -494,8 +494,10 @@ class RaggedDecoder:
         # and, for a model with rows of several kinds, the live rows of
         # one layer of each kind
         self.attn_live_rows_by_kind = dict.fromkeys(self.row_kinds, 0)
-        # [L, E] device counts of the prefill calls since the last
-        # read-back, fetched with it
+        # the device counts of the prefill calls since the last
+        # read-back, fetched with it: one tuple a call, ([L, E] loads
+        # and, from a block whose expert layer has a compact branch,
+        # [2] calls)
         self._pending_expert_tokens: list = []
         self.slot_stream: list[_Stream | None] = [None] * slots
         self.queue: collections.deque[_Stream] = collections.deque()
@@ -810,7 +812,8 @@ class RaggedDecoder:
         # single device_get (a per-admission sync would stall the
         # host until the prefill finished)
         self._pending_first.append((s, tok0[0], logp0[0]))
-        self._pending_expert_tokens.extend(expert_tokens)
+        if expert_tokens:
+            self._pending_expert_tokens.append(expert_tokens)
         self.slot_stream[slot] = s
 
     def _set_lane(self, slot: int, s: _Stream) -> None:
@@ -994,25 +997,33 @@ class RaggedDecoder:
     def _count_routing(self, sp: dict, touched: list, loads: list) -> None:
         """The routing counters a read-back brought: ``touched`` holds
         the chunk's [steps, L] step counters in the order of the model's
-        ``step_counters`` (or nothing), ``loads`` one [L, E] array of
-        assignments per prefill call since the last read-back (a model
-        that holds a part of its experts counts those it holds). Span
-        attrs: each step counter's mean over the chunk's steps and
+        ``step_counters`` (or nothing), ``loads`` one tuple per prefill
+        call since the last read-back: an [L, E] array of assignments (a
+        model that holds a part of its experts counts those it holds)
+        and, from a block whose expert layer has the compact branch
+        (``moe.moe``), a [2] array, its expert-layer calls that had the
+        branch and those that took it. Span attrs: each step counter's mean over the chunk's steps and
         layers (``experts_touched``; with held experts ``assignments``
         and ``held_assignments`` too) and, with a prefill's counts,
         ``expert_load_max`` / ``expert_load_mean`` (assignments on the
         fullest expert and the mean over experts, of one call's layers;
-        means over the calls where there were several)."""
+        means over the calls where there were several) and, with the
+        calls, their sums ``moe_expert_calls`` / ``moe_compact_calls``."""
         for name, t in zip(self.model.step_counters, touched):
             sp[name] = float(t.mean())
             if name == "experts_touched":
                 self.moe_touched_expert_steps += int(t.sum())
         if loads:
+            calls = [c[1] for c in loads if len(c) > 1]
+            loads = [c[0] for c in loads]
             self.moe_assignments += int(sum(a.sum() for a in loads))
             sp["expert_load_max"] = float(
                 np.mean([a.max() for a in loads]))
             sp["expert_load_mean"] = float(
                 np.mean([a.mean() for a in loads]))
+            if calls:
+                sp["moe_expert_calls"], sp["moe_compact_calls"] = (
+                    int(n) for n in np.sum(calls, axis=0))
 
     def _deliver(self, slot: int, s: _Stream, toks: list, lps: list,
                  t_now: float, pos: int) -> int:

@@ -273,7 +273,9 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
     behind it: causal) -> (h [B, T, D] before the final norm, every
     layer's (k, v) rows [B, T, Hkv * hd], k rotated where the layer
     rotates). With ``aux`` every expert layer's ids are left in
-    ``aux["expert_ids"]`` [L_moe, B, T, top_k]."""
+    ``aux["expert_ids"]`` [L_moe, B, T, top_k] and the layers' calls
+    and compact calls in ``aux["compact_calls"]`` [2]
+    (``moe.compact_calls``)."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
@@ -281,7 +283,7 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
         rotation = rotary_embedding(
             jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t)),
             cfg.head_dim, cfg.rope_theta)
-    rows, ids = [], []
+    rows, auxes = [], []
     for i, p in enumerate(params["layers"]):
         windowed = cfg.windowed(i)
         with jax.named_scope("qkv"):
@@ -298,9 +300,10 @@ def prefill(params, tokens, cfg: ExaoneConfig, aux: dict | None = None):
         h = moe.mlp_layer(cfg, cfg.mlp_layer_types[i] == SPARSE, p, h,
                           layer_aux)
         if layer_aux:
-            ids.append(layer_aux["expert_ids"])
-    if ids:
-        aux["expert_ids"] = jnp.stack(ids)
+            auxes.append(layer_aux)
+    if auxes:
+        aux["expert_ids"] = jnp.stack([a["expert_ids"] for a in auxes])
+        aux["compact_calls"] = moe.compact_calls(auxes)
     return h, rows
 
 
@@ -426,7 +429,7 @@ class _Slots(Slots):
         their ring offsets (:func:`ring_rows`). -> (the streams' rows by
         kind, [F] prompt lengths, [F] first tokens, [F] their logprobs,
         the held experts' assignments from the real positions [L_moe,
-        count])."""
+        count], the expert layer's calls and compact calls [2])."""
         Slots.refuse_prefix(cfg, prefix)
         aux = {} if cfg.moe_layers else None
         h, rows = prefill(params, prompts, cfg, aux)
@@ -449,8 +452,8 @@ class _Slots(Slots):
                 streams[name + "_win"] = stack(
                     [ring_rows(r[j], true_lens, w)
                      for i, r in enumerate(rows) if cfg.windowed(i)], w)
-        loads = (moe.prefill_loads(cfg, aux["expert_ids"], true_lens),) \
-            if aux else ()
+        loads = (moe.prefill_loads(cfg, aux["expert_ids"], true_lens),
+                 aux["compact_calls"]) if aux else ()
         return streams, true_lens, toks0, logp0, *loads
 
     @staticmethod

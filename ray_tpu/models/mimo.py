@@ -260,9 +260,10 @@ def prefill(params, tokens, true_lens, cfg: MimoConfig,
     keeps them, k rotated and packed: a full layer's [B, T, Hkv * dk] /
     [B, T, Hkv * dv], a window layer's ring [B, window, ..] of the last
     real rows (``exaone.ring_rows``; its other rows die with the layer:
-    five layers' would be 0.8 GB), and with ``loads`` the held experts'
-    assignments from the real positions [L_moe, count] int32, else
-    None).
+    five layers' would be 0.8 GB), and with ``loads`` (the held experts'
+    assignments from the real positions [L_moe, count] int32, the
+    expert layer's calls and those that took its compact branch [2]:
+    ``moe.compact_calls``), else None).
 
     ``live`` (``jnp.max(true_lens)``, traced: the serving call's) leaves
     the DEAD segments out of every layer's scan (``moe.in_segments``:
@@ -311,14 +312,17 @@ def prefill(params, tokens, true_lens, cfg: MimoConfig,
             aux = {} if count_loads else None
             h_seg = moe.mlp_layer(cfg, sparse, p, h_seg, aux)
             if count_loads:
-                count = count + moe.prefill_loads(
-                    cfg, aux["expert_ids"][None], true_lens - start)[0]
+                count = jax.tree_util.tree_map(jnp.add, count, (
+                    moe.prefill_loads(cfg, aux["expert_ids"][None],
+                                      true_lens - start)[0],
+                    moe.compact_calls([aux])))
             return (k_all, v_all, count), h_seg
 
         cdt = cfg.compute_dtype
         empty = (jnp.zeros((b, hkv, t, dk), cdt),
                  jnp.zeros((b, hkv, t, dv), cdt),
-                 jnp.zeros((cfg.held[1],), jnp.int32) if count_loads else ())
+                 (jnp.zeros((cfg.held[1],), jnp.int32),
+                  jnp.zeros((2,), jnp.int32)) if count_loads else ())
         (k_all, v_all, count), h = moe.in_segments(layer, empty, h, seg,
                                                    live)
         with jax.named_scope("cache"):
@@ -327,7 +331,7 @@ def prefill(params, tokens, true_lens, cfg: MimoConfig,
                 for a in (k_all, v_all)))
         if count_loads:
             counts.append(count)
-    return h, rows, jnp.stack(counts) if counts else None
+    return h, rows, moe.prefill_counts(counts) if counts else None
 
 
 def _cache_rows(cfg: MimoConfig, rows, true_lens):
@@ -472,7 +476,8 @@ class _Slots(Slots):
         their ring offsets (:func:`prefill` made them). -> (the streams'
         rows by kind, [F] prompt lengths, [F] first tokens, [F] their
         logprobs, the held experts' assignments from the real positions
-        [L_moe, count])."""
+        [L_moe, count], the expert layer's calls and compact calls
+        [2])."""
         Slots.refuse_prefix(cfg, prefix)
         h, rows, loads = prefill(params, prompts, true_lens, cfg,
                                  loads=cfg.moe_layers > 0,
@@ -486,8 +491,7 @@ class _Slots(Slots):
             r[j] for i, r in enumerate(rows) if cfg.windowed(i) == windowed]
             for windowed, kind in ((False, "full"), (True, "win"))
             for j, name in enumerate("kv")}
-        return streams, true_lens, toks0, logp0, \
-            *(() if loads is None else (loads,))
+        return streams, true_lens, toks0, logp0, *(loads or ())
 
     @staticmethod
     def scatter(state: dict, slots, streams: dict, full_lens) -> dict:

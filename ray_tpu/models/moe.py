@@ -12,7 +12,15 @@ and scaled; a shared expert beside them (``shared_d_ff`` 0: none, no
 leaves and no work). ``held`` = (first, count) tells
 the layer which experts live here: it routes over all of them and
 computes the part of the result that its own give (:func:`moe`); what
-the others would add is left out.
+the others would add is left out. Where it holds an eighth of the
+experts or less and the call is a prompt's, it counts the assignments
+its own got and, when they fit a capacity that the shapes give (twice
+its uniform share, :func:`compact_rows`), gathers and multiplies those
+rows alone before it sums a token's experts; when they do not fit it
+works at every assignment's row, as a decode step and a device that
+holds a quarter or all of the experts always do. The capacity chooses
+the lines that compute the result, never what is in it: nothing is
+dropped, and the bits are the same either way.
 
 ``cfg`` is any configuration with the fields read here: ``n_experts``,
 ``n_group``, ``topk_group``, ``top_k``, ``routed_scaling_factor``,
@@ -173,6 +181,75 @@ def route(cfg, scores, bias):
     return weights, ids
 
 
+def compact_rows(cfg, n: int) -> int | None:
+    """The capacity C of the compact branch of :func:`moe` for ``n``
+    assignments (tokens x ``top_k``), from shapes alone: twice the
+    share a uniform router sends the ``count`` held of ``n_experts``
+    experts, in whole row tiles of the grouped matmul; None (no branch:
+    the layer works at ``n`` rows) unless C is at most a quarter of
+    ``n``. A device that holds an eighth of the experts or less has it
+    at a prompt's rows; none has it at a decode step's (one tile is more
+    than a quarter of 256 or 512 assignments), nor one that holds a
+    quarter of its experts, or all."""
+    from ray_tpu.ops.grouped_matmul import TILE_M
+
+    _, count = cfg.held
+    c = -(-2 * n * count // (cfg.n_experts * TILE_M)) * TILE_M
+    return c if 4 * c <= n else None
+
+
+def held_first(cfg, ids):
+    """The chosen ids [tokens, top_k] sorted by expert with the held
+    ones first -> (held [N] bool by assignment, order [N]: the sort's
+    permutation, stable, group_sizes [count]: the assignments each held
+    expert got; their sum is how many rows of the sort are held)."""
+    first, count = cfg.held
+    local = ids.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)  # the others sort last
+    order = jnp.argsort(key, stable=True)
+    group_sizes = jnp.sum(
+        jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
+    return held, order, group_sizes
+
+
+def _held_part(cfg, matmul, c, w_gate, w_up, w_down, xf, weights, held,
+               order, group_sizes):
+    """The held experts' part of the layer's result, [tokens, D], from
+    the first ``c`` rows of the sort (``None``: from every assignment's
+    row). The rows gathered in the sort's order (a foreign assignment's
+    reads row 0), the three products over the held groups, each
+    assignment's row put back at its place (a foreign one's masked to
+    0) and a token's ``top_k`` summed in float32.
+
+    With ``c`` the gather, the products and the elementwise work
+    between them are ``c`` rows and not N: right when no more than ``c``
+    assignments are held, for the held ones sort first. The groups lie
+    at the same offsets, the put-back and the sum are the same lines, so
+    the result is the one ``c = None`` gives, bit for bit."""
+    kk = cfg.top_k
+    first = order if c is None else order[:c]
+    rows = xf[jnp.where(held[first], first // kk, 0)]
+    experts = functools.partial(matmul, group_sizes=group_sizes)
+    gate = experts(rows, w_gate)
+    up = experts(rows, w_up)
+    y = experts(jax.nn.silu(gate) * up, w_down)
+    unsort = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    if c is not None:  # (a foreign assignment's place lies behind them)
+        unsort = jnp.minimum(unsort, c - 1)
+    y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
+    out = jnp.sum(y.reshape(-1, kk, xf.shape[1]) * weights[..., None],
+                  axis=1)
+    return out.astype(cfg.compute_dtype)
+
+
+# (jitted by itself where the branch stands: a program traces the
+# kernel's calls once a shape, not twice a layer and bucket; ``matmul``
+# is static so that a test's patched dispatch is a trace of its own)
+_held_part_once = jax.jit(_held_part, static_argnums=(0, 1, 2))
+
+
 def moe(cfg, p, x, aux: dict | None = None):
     """The expert layer of a device that holds ``cfg.held`` = (first,
     count) of the experts. x [B, T, D]. Every token is routed over ALL
@@ -181,17 +258,28 @@ def moe(cfg, p, x, aux: dict | None = None):
     belong to no group, which the grouped matmul never visits
     (``ops/grouped_matmul.py``: its grid covers the groups' rows only;
     off the TPU ``ragged_dot`` leaves such rows zero), its gather reads
-    row 0 and its part of the sum is masked. The shared expert, where
+    row 0 and its part of the sum is masked.
+
+    Where the shapes give a capacity C (:func:`compact_rows`: a prompt's
+    rows on a device that holds an eighth of the experts or less), the
+    device counts its held assignments and, when they are at most C,
+    gathers and multiplies the first C rows of the sort alone; with more
+    it works at every assignment's row, as it does where there is no C
+    (:func:`_held_part`, with C and without). One ``lax.cond`` on the
+    count: nothing is dropped, the capacity decides which lines compute
+    the result and never what it holds, and either branch gives the
+    same bits.
+
+    The shared expert, where
     ``p`` has one, is computed in full. On one device the layer runs without an exchange:
     the other devices' partial sums are not here and nothing stands in
     for them. With ``aux`` the chosen ids [B, T, top_k] are left in
-    ``aux["expert_ids"]``."""
+    ``aux["expert_ids"]`` and, where the branch stands, whether this
+    call took the compact one in ``aux["compact"]`` (int32 0 or 1)."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
 
-    cdt = cfg.compute_dtype
     b, t, d = x.shape
     kk = cfg.top_k
-    first, count = cfg.held
     xf = x.reshape(b * t, d)
     with jax.named_scope("moe_router"):
         scores = jax.nn.sigmoid(jnp.dot(
@@ -200,26 +288,24 @@ def moe(cfg, p, x, aux: dict | None = None):
         if aux is not None:
             aux["expert_ids"] = ids.reshape(b, t, kk)
     with jax.named_scope("moe_experts"):
-        local = ids.reshape(-1) - first
-        held = (local >= 0) & (local < count)
-        key = jnp.where(held, local, count)  # the others sort last
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.sum(
-            jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
-        rows = xf[jnp.where(held[order], order // kk, 0)]
-        experts = functools.partial(grouped_matmul,
-                                    group_sizes=group_sizes)
-        gate = experts(rows, p["w_gate"])
-        up = experts(rows, p["w_up"])
-        y = experts(jax.nn.silu(gate) * up, p["w_down"])
-        unsort = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
-        out = jnp.sum(y.reshape(b * t, kk, d) * weights[..., None], axis=1)
+        held, order, group_sizes = held_first(cfg, ids)
+        args = (p["w_gate"], p["w_up"], p["w_down"], xf, weights, held,
+                order, group_sizes)
+        c = compact_rows(cfg, b * t * kk)
+        if c is None:
+            out = _held_part(cfg, grouped_matmul, None, *args)
+        else:
+            fits = jnp.sum(group_sizes) <= c
+            out = jax.lax.cond(
+                fits,
+                lambda: _held_part_once(cfg, grouped_matmul, c, *args),
+                lambda: _held_part_once(cfg, grouped_matmul, None, *args))
+            if aux is not None:
+                aux["compact"] = fits.astype(jnp.int32)
     if "shared_gate" not in p:
-        return out.astype(cdt).reshape(b, t, d)
+        return out.reshape(b, t, d)
     with jax.named_scope("moe_shared"):
-        out = out.astype(cdt) + swiglu(
+        out = out + swiglu(
             xf, p["shared_gate"], p["shared_up"], p["shared_down"])
     return out.reshape(b, t, d)
 
@@ -345,6 +431,26 @@ def routing_counts(cfg, ids, active) -> tuple:
     return (jnp.sum(jnp.any(hit, axis=(0, 1, 2)), dtype=jnp.int32),
             jnp.sum(active, dtype=jnp.int32) * ids.shape[-1],
             jnp.sum(hit, dtype=jnp.int32))
+
+
+@jax.named_scope("moe_router")
+def compact_calls(auxes: list):
+    """What a prefill's expert-layer calls left in their ``aux`` -> [2]
+    int32: how many of them had the branch of :func:`moe` (none where
+    the shapes give no capacity), how many of those took the compact
+    one."""
+    took = [aux["compact"] for aux in auxes if "compact" in aux]
+    return jnp.stack([jnp.int32(len(took)), sum(took, jnp.int32(0))])
+
+
+@jax.named_scope("moe_router")
+def prefill_counts(layers: list) -> tuple:
+    """A segmented prefill's (:func:`prefill_loads` row [count],
+    :func:`compact_calls` [2]) of each expert layer, summed over its
+    live segments -> what the call hands the read-back: (loads [L_moe,
+    count], calls [2])."""
+    return (jnp.stack([loads for loads, _ in layers]),
+            sum(calls for _, calls in layers))
 
 
 @jax.named_scope("moe_router")

@@ -30,7 +30,12 @@ class Slots:
       logits [B, V], state, *step counters named by ``step_counters``);
     - ``prefill(params, prompts, true_lens, seeds, temps, top_ps, cfg,
       slot_len, prefix)`` -> (the streams' state, whole prompt lengths,
-      first tokens, their logprobs, *per-expert assignment counts); a
+      first tokens, their logprobs, *counts for the read-back: the
+      per-expert assignment counts [L, E] (``expert_load_max`` /
+      ``expert_load_mean`` on ``engine.readback``) and, from a block
+      whose expert layer has the compact branch (``moe.moe``), [2]
+      int32, its expert-layer calls that had the branch and those that
+      took it (``moe_expert_calls`` / ``moe_compact_calls``)); a
       state that is no rows starts with :meth:`refuse_prefix`, and
       every one ends with :meth:`first_token`;
     - ``scatter(state, slots, streams, full_lens)``: that state into

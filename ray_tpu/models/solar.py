@@ -352,8 +352,10 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
     (module docstring) -> (h [B, T, D] before the final norm, the
     streams' state {"kda": a list of {"s", "conv"} a KDA layer,
     "k_full", "v_full" [L_full, B, T, Hkv * hd]: the GQA layers' rows,
-    padding's among them}, and with ``loads`` the held experts'
-    assignments from the real positions [L, count] int32, else None).
+    padding's among them}, and with ``loads`` (the held experts'
+    assignments from the real positions [L, count] int32, the expert
+    layer's calls and those that took its compact branch [2]:
+    ``moe.compact_calls``), else None).
 
     ``live`` (``jnp.max(true_lens)``, traced: the serving call's) leaves
     the DEAD segments out of every layer's scans (``moe.in_segments``:
@@ -373,9 +375,9 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
     def experts(p, h_seg, start):
         aux = {} if loads else None
         h_seg = moe.mlp_layer(cfg, True, p, h_seg, aux)
-        return h_seg, (moe.prefill_loads(cfg, aux["expert_ids"][None],
-                                         true_lens - start)[0]
-                       if loads else ())
+        return h_seg, ((moe.prefill_loads(cfg, aux["expert_ids"][None],
+                                          true_lens - start)[0],
+                        moe.compact_calls([aux])) if loads else ())
 
     for i, p in enumerate(params["layers"]):
         def norm(h_seg, p=p):
@@ -428,11 +430,12 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
     with jax.named_scope("cache"):
         state = {"kda": kda, "k_full": stack(k_rows),
                  "v_full": stack(v_rows)}
-    return h, state, jnp.stack(counts) if loads else None
+    return h, state, moe.prefill_counts(counts) if loads else None
 
 
 def _zero_loads(cfg: SolarConfig, loads: bool):
-    return jnp.zeros((cfg.held[1],), jnp.int32) if loads else ()
+    return (jnp.zeros((cfg.held[1],), jnp.int32),
+            jnp.zeros((2,), jnp.int32)) if loads else ()
 
 
 def forward(params, tokens, cfg: SolarConfig):
@@ -544,14 +547,15 @@ class _Slots(Slots):
         """Whole prompts from EMPTY state (a reused slot starts from a
         zero ``S`` and zero convolution rows). -> (the streams' state,
         [F] prompt lengths, [F] first tokens, [F] their logprobs, the
-        held experts' assignments from the real positions [L, count])."""
+        held experts' assignments from the real positions [L, count],
+        the expert layer's calls and compact calls [2])."""
         Slots.refuse_prefix(cfg, prefix)
         h, streams, loads = prefill(params, prompts, true_lens, cfg,
                                     loads=True, live=jnp.max(true_lens))
         toks0, logp0 = Slots.first_token(
             functools.partial(moe.logits, cfg), params, h, true_lens,
             seeds, temps, top_ps)
-        return streams, true_lens, toks0, logp0, loads
+        return streams, true_lens, toks0, logp0, *loads
 
     @staticmethod
     def scatter(state: dict, slots, streams: dict, full_lens) -> dict:

@@ -212,12 +212,8 @@ def _kda_chunk_calls(text: str, prefetched_ok: bool = False) -> list:
     return calls
 
 
-def _segment_branches(text: str) -> list:
-    """The ``conditional``s that stand in a ``while``'s body of a compiled
-    prefill program (``moe.in_segments`` with ``live``: a scan whose step
-    runs a segment or skips it) as (the body's lines, the DEAD branch's
-    lines, the live branch's lines); the dead branch is the one of fewer
-    instructions."""
+def _computations(text: str) -> dict:
+    """A compiled program's text -> {computation: its lines}."""
     computations, lines = {}, None
     for line in text.splitlines():
         m = program_parts._COMPUTATION.match(line)
@@ -225,6 +221,16 @@ def _segment_branches(text: str) -> list:
             lines = computations.setdefault(m[1], [])
         elif lines is not None:
             lines.append(line)
+    return computations
+
+
+def _segment_branches(text: str) -> list:
+    """The ``conditional``s that stand in a ``while``'s body of a compiled
+    prefill program (``moe.in_segments`` with ``live``: a scan whose step
+    runs a segment or skips it) as (the body's lines, the DEAD branch's
+    lines, the live branch's lines); the dead branch is the one of fewer
+    instructions."""
+    computations = _computations(text)
     found = []
     for body in re.findall(r" while\(.*?body=%([\w.\-]+)", text):
         for ln in computations[body]:
@@ -235,6 +241,42 @@ def _segment_branches(text: str) -> list:
                     (computations[c.strip().lstrip("%")]
                      for c in m[1].split(",")), key=len)
                 found.append((computations[body], dead, live))
+    return found
+
+
+def _expert_branches(text: str) -> list:
+    """The ``conditional``s of a compiled prefill program whose two
+    branches both call ``moe_gmm`` (``moe.moe`` where the shapes give a
+    capacity) as (the COMPACT branch's lines, the fall-back's), a
+    branch's lines with those of every computation it calls (its
+    fusions); the fall-back is the one of more instructions."""
+    computations = _computations(text)
+
+    def called(line):
+        names = re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                           line)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            names += [c.strip().lstrip("%") for c in group.split(",")]
+        return names
+
+    def closure(name, seen):
+        if name in seen:
+            return []
+        seen.add(name)
+        return computations[name] + [
+            ln for line in computations[name] for c in called(line)
+            for ln in closure(c, seen)]
+
+    found = []
+    for inside in computations.values():
+        for ln in inside:
+            if " conditional(" not in ln:
+                continue
+            branches = sorted((closure(c, set()) for c in called(ln)),
+                              key=len)
+            if all(any(KERNEL in x and "moe_gmm" in x.split(" = ")[0]
+                       for x in b) for b in branches):
+                found.append(tuple(branches))
     return found
 
 

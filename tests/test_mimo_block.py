@@ -240,8 +240,8 @@ def test_a_call_without_its_dead_segments_leaves_what_is_read_bit_for_bit(
     f = len(lens)
 
     def call(live):
-        h, rows, loads = mimo.prefill(params, toks, lens, cfg, loads=True,
-                                      live=live)
+        h, rows, (loads, _) = mimo.prefill(params, toks, lens, cfg,
+                                           loads=True, live=live)
         return h, rows, loads, mimo.SLOTS.first_token(
             functools.partial(moe.logits, cfg), params, h, lens,
             jnp.zeros((f,), jnp.uint32), jnp.zeros((f,), jnp.float32),

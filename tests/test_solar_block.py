@@ -247,10 +247,12 @@ def test_prefill_in_eight_segments_is_prefill_in_one(monkeypatch, model):
     lens = jnp.array([128, 77])
     monkeypatch.setattr(moe, "SEGMENT_ROWS", 128)
     assert solar.SLOTS.prefill_segments(cfg, 128) == 1
-    h1, st1, loads1 = solar.prefill(params, toks, lens, cfg, loads=True)
+    h1, st1, (loads1, _) = solar.prefill(params, toks, lens, cfg,
+                                           loads=True)
     monkeypatch.setattr(moe, "SEGMENT_ROWS", 16)
     assert solar.SLOTS.prefill_segments(cfg, 128) == 8
-    h8, st8, loads8 = solar.prefill(params, toks, lens, cfg, loads=True)
+    h8, st8, (loads8, _) = solar.prefill(params, toks, lens, cfg,
+                                           loads=True)
     np.testing.assert_allclose(h8[0], h1[0], atol=1e-5)
     np.testing.assert_allclose(h8[1, :77], h1[1, :77], atol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(st1["kda"]),
@@ -377,8 +379,8 @@ def test_a_call_without_its_dead_segments_leaves_what_is_read_bit_for_bit(
     lens = jnp.array(lens, jnp.int32)
 
     def call(live):
-        h, st, loads = solar.prefill(params, toks, lens, cfg, loads=True,
-                                     live=live)
+        h, st, (loads, _) = solar.prefill(params, toks, lens, cfg,
+                                          loads=True, live=live)
         return h, st, loads, _first_tokens(cfg, params, h, lens)
 
     h0, st0, loads0, first0 = jax.jit(lambda: call(None))()

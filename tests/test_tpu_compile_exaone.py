@@ -69,6 +69,9 @@ def test_exaone_decode_chunk_reads_both_stacks_in_place(topo, monkeypatch):
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
     text = compiled.as_text()
+    # (a step's assignments give the expert layer no capacity,
+    # ``moe.compact_rows``: its lines are the parent's, no branch)
+    assert " conditional(" not in text
     assert text.count("decode_attn") >= cfg.n_layers
     assert text.count(KERNEL) == cfg.n_layers + 3 * cfg.moe_layers == 17
     for dims in (f"bf16[1,{slots},{max_len},1024]",
@@ -107,7 +110,10 @@ def test_exaone_one_row_prefill_compiles(topo, monkeypatch):
         vec(jnp.uint32, 1), vec(jnp.float32, 1), vec(jnp.float32, 1),
         state, vec(jnp.int32), cfg=cfg).compile()
     text = compiled.as_text()
-    assert text.count(KERNEL) == 3 * cfg.moe_layers and "moe_gmm" in text
+    # (three in either branch of an expert layer, ``moe.moe``: the
+    # compact one at C = 2,048 of 8,192 assignments, and its fall-back)
+    assert text.count(KERNEL) == 2 * 3 * cfg.moe_layers and "moe_gmm" in text
+    assert text.count(" conditional(") == cfg.moe_layers
     # (the one row's logits are a fused multiply and reduce over the
     # head, which converts it on the fly inside the fusion: no copy)
     _no_f32_matrix(text, params, but=[(6144, 19200)])

@@ -63,6 +63,9 @@ def test_instella_decode_chunk_reads_the_latent_stack_in_place(
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
     text = compiled.as_text()
+    # (a step's assignments give the expert layer no capacity,
+    # ``moe.compact_rows``: its lines are the parent's, no branch)
+    assert " conditional(" not in text
     assert text.count("decode_attn_latent") >= cfg.n_layers
     assert "decode_attn." not in text.replace("decode_attn_latent", "")
     assert text.count(KERNEL) == cfg.n_layers + 3 * cfg.moe_layers == 25

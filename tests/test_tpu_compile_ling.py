@@ -62,6 +62,9 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
     text = compiled.as_text()
+    # (a step's assignments give the expert layer no capacity,
+    # ``moe.compact_rows``: its lines are the parent's, no branch)
+    assert " conditional(" not in text
     kda_calls = [line for line in text.splitlines()
                  if KERNEL in line and "kda_step" in line.split(" = ")[0]]
     assert len(kda_calls) == sum(

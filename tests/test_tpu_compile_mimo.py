@@ -15,7 +15,7 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, _dead_branch_hands_on_and_makes_zeros,
+    KERNEL, MIB, _dead_branch_hands_on_and_makes_zeros, _expert_branches,
     _loops_add_nothing_unscoped, _lower_prefill, _mem, _on,
     _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
@@ -63,8 +63,9 @@ def test_mimo_decode_chunk_reads_four_stacks_in_place(topo, monkeypatch):
     once a layer (16 query rows a kv head on the full stacks, 8 and the
     sink on the rings; never the XLA body, which would read all 34,832
     rows of every slot) and ``moe_gmm`` three times an expert layer at
-    4096 x 2048; the donated stacks are updated in place, never copied;
-    no matrix exists in float32; arguments and temporaries stay under
+    4096 x 2048, with no ``conditional`` (256 assignments give the
+    expert layer no capacity: ``moe.compact_rows``); the donated stacks
+    are updated in place, never copied; no matrix exists in float32; arguments and temporaries stay under
     13.5 GiB of the chip's 16."""
     from ray_tpu.models import mimo
 
@@ -77,6 +78,7 @@ def test_mimo_decode_chunk_reads_four_stacks_in_place(topo, monkeypatch):
     calls = _kernel_calls(text)
     assert sum("decode_attn" in c for c in calls) == cfg.n_layers == 7
     assert len(calls) == cfg.n_layers + 3 * cfg.moe_layers == 25
+    assert " conditional(" not in text
     for dims in (f"bf16[2,{slots},{max_len},768]",
                  f"bf16[2,{slots},{max_len},512]",
                  f"bf16[5,{slots},128,1536]", f"bf16[5,{slots},128,1024]"):
@@ -106,7 +108,8 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
     ``flash_fwd`` in the two full layers (d_qk 192, d_v 128, a segment's
     rows against the rows so far) and ``flash_fwd_window`` in the five
     window layers (the band's blocks alone, the sink at the finalize),
-    ``moe_gmm`` three times an expert layer; no ``[32768, 32768]``
+    ``moe_gmm`` three times in either branch of an expert layer (the
+    compact one and its fall-back, ``moe.moe``); no ``[32768, 32768]``
     scores, no whole ``[32768, 16384]`` gate or up of the dense layer in
     either type, no ``[P, vocabulary]`` logits; the donated state is
     updated in place; beside 32 slots the call fits the chip's 16 GiB
@@ -126,7 +129,7 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
         == cfg.window_layers == 5
     assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
         == cfg.full_layers == 2
-    assert sum("moe_gmm" in c for c in calls) == 3 * cfg.moe_layers
+    assert sum("moe_gmm" in c for c in calls) == 2 * 3 * cfg.moe_layers
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))
               for dt, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
                                          text)}
@@ -151,7 +154,8 @@ def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     """The cell's cold prefill call at 16,384 rows (eight segments): a
     layer's one loop holds one ``conditional`` on a segment's first row
     against the prompt's rows, which the program reads from
-    ``true_lens`` (``moe.in_segments`` with ``live``). The dead branch
+    ``true_lens`` (``moe.in_segments`` with ``live``), and an expert
+    layer's live branch one more (``moe.moe``'s). The dead branch
     hands the k and v rows it carries on (``[1, Hkv, 16384, 192 / 128]``,
     25 to 100 MB a layer) and makes zeros, nothing else: no kernel, no
     fusion, no copy, and no loop copies them either (written a segment
@@ -165,8 +169,8 @@ def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     text = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
                           (params, state, vec)).compile().as_text()
     branches = _segment_branches(text)
-    assert len(branches) == text.count(" while(") \
-        == text.count(" conditional(") == cfg.n_layers == 7
+    assert len(branches) == text.count(" while(") == cfg.n_layers == 7
+    assert text.count(" conditional(") == cfg.n_layers + cfg.moe_layers
     for loop, dead, live in branches:
         _dead_branch_hands_on_and_makes_zeros(dead)
         copied = [ln[:160] for ln in loop + live if re.search(
@@ -175,3 +179,47 @@ def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
         assert sum(KERNEL in ln and "flash_fwd" in ln.split(" = ")[0]
                    for ln in live) == 1
     _loops_add_nothing_unscoped(text)
+
+
+def test_mimo_segments_expert_layer_works_on_the_rows_its_experts_got(
+        topo, monkeypatch):
+    """The cell's cold prefill call at 8,192 rows (four segments of
+    2,048: N = 16,384 assignments a segment, C = 2,048): every expert
+    layer's live segment holds ONE ``conditional`` of two branches that
+    each call ``moe_gmm`` three times. The compact branch multiplies
+    ``[2048, 4096]`` and ``[2048, 2048]`` operands and makes ONE array
+    of 16,384 rows, y put back at every assignment's place for the sum
+    over ``top_k`` (the lines that keep the sum's order and bits); the
+    other N-row arrays of the expert layer (the gathered rows, gate, up,
+    their product, y) live in the fall-back's computation and nowhere
+    else in the program."""
+    fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
+    text = _lower_prefill(cfg, vec(jnp.int32).sharding, 8192,
+                          (params, state, vec)).compile().as_text()
+    found = _expert_branches(text)
+    assert len(found) == cfg.moe_layers == 6
+    wide = re.compile(r"= \(?(?:bf16|f32)\[(?:\d+,)*16384,\d{3,}\]")
+
+    def made(lines):  # (not a fusion's view of its operand)
+        return [ln for ln in lines
+                if wide.search(ln) and " parameter(" not in ln]
+
+    in_branches = set()
+    for compact, fallback in found:
+        for branch in (compact, fallback):
+            calls = [ln for ln in branch
+                     if KERNEL in ln and "moe_gmm" in ln.split(" = ")[0]]
+            assert len(calls) == 3, len(calls)
+        assert any("bf16[2048,4096]" in ln.split("custom-call(")[1]
+                   for ln in compact if KERNEL in ln)
+        put_back = made(compact)  # (one fusion and what it holds)
+        assert sum(" fusion(" in ln for ln in put_back) == 1 and all(
+            "bf16[16384,4096]" in ln and "gather" in ln
+            for ln in put_back), [ln[:160] for ln in put_back]
+        assert len(made(fallback)) >= 5
+        in_branches.update(compact, fallback)
+    # (the dense layer's ``w_down`` is a ``[16384, 4096]`` array too:
+    # the expert layer's are the ones its scope names)
+    outside = [ln[:160] for ln in text.splitlines() if wide.search(ln)
+               and "moe_experts" in ln and ln not in in_branches]
+    assert not outside, outside[:4]

@@ -72,6 +72,9 @@ def test_solar_decode_chunk_keeps_both_kinds_of_state_where_they_lie(
         params, state, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
         chunk=eng["chunk_tokens"]).compile()
     text = compiled.as_text()
+    # (a step's assignments give the expert layer no capacity,
+    # ``moe.compact_rows``: its lines are the parent's, no branch)
+    assert " conditional(" not in text
     calls = [line for line in text.splitlines() if KERNEL in line]
     kda_calls = [c for c in calls if "kda_step" in c.split(" = ")[0]]
     assert len(kda_calls) == cfg.kda_layers == 3
@@ -186,8 +189,9 @@ def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     text = lowered.compile().as_text()
     branches = _segment_branches(text)
     assert len(branches) == text.count(" while(") \
-        == text.count(" conditional(") \
         == 2 * cfg.full_layers + cfg.kda_layers == 5
+    # (and one more a layer: the expert layer's, ``moe.moe``)
+    assert text.count(" conditional(") == 5 + cfg.n_layers
     for loop, dead, _ in branches:
         _dead_branch_hands_on_and_makes_zeros(dead)
         copied = [ln[:160] for ln in loop if re.search(
