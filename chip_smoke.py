@@ -104,6 +104,9 @@ class Plan:
     # block's 64 heads against the XLA body and the recurrence, this
     # many rows in two calls, S carried
     kda_chunk_rows: int = 4096
+    # and one Mamba-2 layer of models/granite.py at the published widths
+    # (128 heads of 64 x 128): a prompt in one segment and in two
+    ssm_lens: tuple = (200, 4096)
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -113,6 +116,7 @@ class Plan:
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
                     kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21),
+                    ssm_lens=(9, 21),
                     kda_chunk_rows=32)
         return Plan(**{**base, **kw})
 
@@ -1452,6 +1456,96 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
                          for t in lens}}
 
 
+# Kernel and XLA body are the same float32 arithmetic; the output's sum
+# over the state's 128 lanes is taken in another order.
+SSD_KERNEL_TOLERANCE = 1e-5
+
+
+def ssm_check(widths: str, lens: list, seed: int,
+              interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: one Mamba-2 layer of the
+    seventh block (``models/granite.py``) at the published widths, in
+    the compute type, for prompts of ``lens`` tokens: its prefill in row
+    segments, ``H`` and the convolution rows carried (the chunked scan,
+    ``ops/ssd_chunk.py``), against its own stepping (on a TPU the
+    ``ssd_step`` kernel in place; with ``interpret`` the kernel in the
+    Pallas interpreter); and six slots' step through the kernel against
+    the XLA body with two slots inactive. -> relative errors by length,
+    the kernel's against the body, whether the inactive slots' state
+    came back bit for bit, whether the layer's step compiles to a
+    program with the kernel in it, and each length's segments."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import granite, moe
+    from ray_tpu.ops import ssd_step as ss
+
+    accelerator.claim_device()
+    kw = dict(n_layers=1, layer_types=("mamba",), vocab_size=1024,
+              n_experts=8, top_k=2)
+    cfg = granite.GraniteConfig.tiny(**kw, dtype="bfloat16") \
+        if widths == "tiny" else granite.GraniteConfig(**kw)
+    p = granite.init_params(cfg, jax.random.PRNGKey(seed))["layers"][0][
+        "attn"]
+    kernel = functools.partial(ss.ssd_step, **(
+        {"interpret": True} if interpret else {}))
+
+    @jax.jit
+    def both(x):
+        t = x.shape[1]
+        xs = jnp.moveaxis(x, 1, 0)[:, :, None]  # [T, 1, 1, D]
+        on, lens_ = jnp.ones((1,), bool), jnp.array([t])
+        empty = granite.ssm_empty(cfg, 1)
+        st, y = moe.in_segments(
+            lambda state, seg: granite.ssm_segment(
+                cfg, p, seg[1], state, seg[0], lens_)[::-1],
+            empty, x, moe.segment_rows(t, cfg.ssm_chunk))
+        st_step, y_step = jax.lax.scan(
+            lambda s, x_t: granite.ssm_step(cfg, p, x_t, s, on)[::-1],
+            empty, xs)
+        return {"out": (y, jnp.moveaxis(y_step[:, :, 0], 0, 1)),
+                "state": (st["h"], st_step["h"]),
+                "conv": (st["conv"], st_step["conv"])}
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    errs = {}
+    for t in lens:
+        x = jax.random.normal(jax.random.PRNGKey(seed + t),
+                              (1, t, cfg.d_model), cfg.compute_dtype)
+        errs[str(t)] = {k: rel(a, b) for k, (a, b) in both(x).items()}
+    slots, h, hd, n = 6, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    active = jnp.arange(slots) % 3 != 1
+    key = jax.random.split(jax.random.PRNGKey(seed + 1), 5)
+    h0 = ss.pack(jax.random.normal(key[0], (slots, h, hd, n), jnp.float32))
+    vectors = (0.1 * jax.random.normal(key[1], (slots, h, hd)),
+               jax.random.uniform(key[2], (slots, h)),
+               jax.random.normal(key[3], (slots, n)),
+               jax.random.normal(key[4], (slots, n)), active)
+    h_kernel, y_kernel = jax.jit(kernel)(h0, *vectors)
+    h_body, y_body = ss.ssd_step(h0, *vectors, use_kernel=False)
+    state = granite.ssm_empty(cfg, slots)
+    text = jax.jit(functools.partial(granite.ssm_step, cfg, p)).lower(
+        jnp.zeros((slots, 1, cfg.d_model), cfg.compute_dtype), state,
+        active).compile().as_text()
+    return {"rel_err": errs,
+            "kernel": {"state": rel(h_kernel, h_body),
+                       "out": rel(y_kernel, y_body)},
+            "inactive_kept": bool(jnp.array_equal(h_kernel[~active],
+                                                  h0[~active])),
+            "in_program": any(
+                KERNEL in line and "ssd_step" in line.split(" = ")[0]
+                for line in text.splitlines()),
+            "segments": {str(t): granite.SLOTS.prefill_segments(cfg, t)
+                         for t in lens},
+            "device": accelerator.device_report()}
+
+
 def hybrid_phase(plan: Plan) -> dict:
     out = chip_child(plan, "hybrid_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
@@ -1509,8 +1603,22 @@ def hybrid_phase(plan: Plan) -> dict:
           "form (KDA in carried segments / stepping at 64 heads, gated "
           "GQA through the flash kernel / over the slot's rows)",
           got=segment["rel_err"], tolerance=HYBRID_TOLERANCE)
+    ssm = chip_child(plan, "ssm_check", {
+        "widths": plan.hybrid_widths, "lens": list(plan.ssm_lens),
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, ssm["device"], 1, "ssm child")
+    check(max(v for by_len in ssm["rel_err"].values()
+              for v in by_len.values()) <= HYBRID_TOLERANCE
+          and max(ssm["kernel"].values()) <= SSD_KERNEL_TOLERANCE
+          and ssm["inactive_kept"] and ssm["in_program"] == plan.on_tpu,
+          "a Mamba-2 layer's chunked scan in carried segments parts from "
+          "its stepping, the ssd_step kernel from the XLA body, an "
+          "inactive slot's state moved, or the layer's step holds no "
+          "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
+            "ssm": {k: ssm[k] for k in ("rel_err", "kernel", "segments",
+                                        "inactive_kept", "in_program")},
             "segment": {k: segment[k] for k in ("rel_err", "segments")},
             "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
             "latent": latent["rel_err"],

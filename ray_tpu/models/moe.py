@@ -1,11 +1,13 @@
 """What the blocks that unroll their layers share, one copy
-(``models/ling.py``, ``exaone.py``, ``instella.py``, ``solar.py``): the
+(``models/ling.py``, ``exaone.py``, ``instella.py``, ``solar.py``,
+``mimo.py``, ``granite.py``): the
 expert layer of a device that holds a part of its experts with the
 router in front of it (DeepSeek-V3's routing), a layer's MLP around it,
 the head, the loss, how the leaves these read are drawn, and how a
 long prompt's tokenwise work is cut into row segments.
 
-Sigmoid scores in float32, a bias added for selection only,
+Sigmoid scores in float32 (softmax scores for a block whose
+configuration says ``router_softmax``), a bias added for selection only,
 ``topk_group`` of ``n_group`` groups kept by the sum of their two best,
 the ``top_k`` best of those chosen, their unbiased scores renormalised
 and scaled; a shared expert beside them (``shared_d_ff`` 0: none, no
@@ -54,6 +56,13 @@ class HeldExperts:
     fields, derived from them. The blocks' frozen dataclasses inherit
     it; it has no field, so their hash and equality as a static
     argument are their own fields'."""
+
+    # what :func:`moe` makes the scores of its router's logits by:
+    # DeepSeek-V3's sigmoid, or for a block that says so a softmax over
+    # all experts (``models/granite.py``: with no bias, one group and a
+    # scaling of 1, :func:`route` then gives the ``top_k`` of the logits
+    # and a softmax over the chosen)
+    router_softmax = False
 
     @property
     def compute_dtype(self):
@@ -119,20 +128,20 @@ def init_dense(cfg, mat) -> dict:
 
 def init_experts(cfg, mat, keys) -> dict:
     """An expert layer's leaves, as :func:`moe` reads them: the router
-    over ALL experts, the held experts' matrices, the shared expert
-    (where the model has one: ``shared_d_ff`` > 0)."""
+    over ALL experts (with its selection bias where the scores are
+    sigmoids: a softmax router has none), the held experts' matrices,
+    the shared expert (where the model has one: ``shared_d_ff`` > 0)."""
     d, f, fs = cfg.d_model, cfg.d_ff, cfg.shared_d_ff
     _, count = cfg.held
-    leaves = {
-        "router": mat(d, cfg.n_experts),
+    leaves = {"router": mat(d, cfg.n_experts)}
+    if not cfg.router_softmax:
         # (small against the scores' spread of 0.2: the top 3% of
         # sigmoids lie where a bias of 0.1 is a standard deviation
         # of the logits, and one expert in eight took most rows)
-        "router_bias": 0.01 * jax.random.normal(
-            next(keys), (cfg.n_experts,), jnp.float32),
-        "w_gate": mat(count, d, f), "w_up": mat(count, d, f),
-        "w_down": mat(count, f, d, out=True),
-    }
+        leaves["router_bias"] = 0.01 * jax.random.normal(
+            next(keys), (cfg.n_experts,), jnp.float32)
+    leaves.update({"w_gate": mat(count, d, f), "w_up": mat(count, d, f),
+                   "w_down": mat(count, f, d, out=True)})
     if fs:
         leaves.update({"shared_gate": mat(d, fs), "shared_up": mat(d, fs),
                        "shared_down": mat(fs, d, out=True)})
@@ -282,9 +291,11 @@ def moe(cfg, p, x, aux: dict | None = None):
     kk = cfg.top_k
     xf = x.reshape(b * t, d)
     with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        score = jax.nn.softmax if cfg.router_softmax else jax.nn.sigmoid
+        scores = score(jnp.dot(
             xf, p["router"], preferred_element_type=jnp.float32))
-        weights, ids = route(cfg, scores, p["router_bias"])
+        # (a router without a bias: the scores choose as they are)
+        weights, ids = route(cfg, scores, p.get("router_bias", 0.0))
         if aux is not None:
             aux["expert_ids"] = ids.reshape(b, t, kk)
     with jax.named_scope("moe_experts"):

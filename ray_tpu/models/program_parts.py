@@ -36,7 +36,8 @@ VOCABULARY = (
     "loss", "optimizer",  # the train step's
 )
 # the kinds of attention, a second level under ``attn``
-ATTN_KINDS = ("attn_window", "attn_full", "attn_latent", "attn_linear")
+ATTN_KINDS = ("attn_window", "attn_full", "attn_latent", "attn_linear",
+              "attn_ssm")
 LOOP, UNSCOPED = "loop", "unscoped"
 FILE = "program_parts.json"
 

@@ -48,12 +48,19 @@ class Slots:
     - ``rows_state``: whether a slot's state is rows of positions that
       can be cut, copied and rewound at any position (what the prefix
       cache, speculative decoding and the prefill workers need:
-      ``decode_engine.require_rows``);
+      ``decode_engine.require_rows``). A recurrent state is not: the
+      delta rule's ``S`` (``ling.py``, ``solar.py``: ``ops/kda_*.py``)
+      and the SSD recurrence's ``H`` (``granite.py``:
+      ``ops/ssd_*.py``), each a float32 array a layer that its step
+      kernel updates in the buffer it came in, with the last rows of
+      the layer's convolution beside it;
     - ``step_counters``: here those of a block that holds a part of its
       experts (``moe.routing_counts``);
     - ``row_kinds(cfg)``: for a model whose layers keep rows of several
       kinds, {kind: (layers, the most rows a slot keeps in one, ``None``
-      = ``max_len``)} (what ``_count_rows`` counts by);
+      = ``max_len``)} (what ``_count_rows`` counts by; a recurrent kind
+      keeps 0: Solar-Open2's ``{recurrent: (3, 0), full: (1, None)}``,
+      Granite's ``{recurrent: (9, 0), full: (1, None)}``);
     - ``prefill_segments(cfg, bucket)``: into how many segments of rows
       a ``bucket``-row call cuts its tokenwise work. The BUCKET's count:
       a call runs those of them that hold a row of its longest prompt

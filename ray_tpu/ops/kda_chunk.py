@@ -1,9 +1,9 @@
 """The chunkwise delta rule of a KDA prefill: T tokens onto a state.
 
-The prefill of a KDA layer (``models/ling.py`` at 32 heads, a decay
-bounded below; ``models/solar.py`` at 64, ``beta`` in (0, 2) and a log
-decay ``g`` unbounded below: ``g`` and ``beta`` are taken as given) is
-the chunkwise form of :func:`ray_tpu.ops.kda_step.kda_recurrence`.
+The prefill of a KDA layer (``models/ling.py``, 32 heads, a decay bounded
+below; ``models/solar.py``, 64, ``beta`` in (0, 2), ``g`` unbounded: both
+taken as given; Granite's Mamba-2 layers run the OTHER recurrence, SSD:
+``ops/ssd_chunk.py``) is the chunkwise form of ``kda_step.kda_recurrence``.
 Inside a chunk of C rows, with G the running sum of g from the chunk's
 start::
 

@@ -1,9 +1,9 @@
 """One token of the delta rule on every slot's state, in place.
 
-(Which form of the delta rule runs where: a decode step is this file's
-recurrence, one token on every slot; a prefill is its chunkwise form
-over a prompt's rows, ``ops/kda_chunk.py``. Each has a Pallas kernel on
-a TPU where a head is whole lanes and an XLA body elsewhere.)
+(Two recurrences run in the tree. The delta rule: Ling's and Solar-
+Open2's KDA layers, a decode step here, a prefill's chunkwise form in
+``ops/kda_chunk.py``, each a Pallas kernel on a TPU where a head is whole
+lanes, an XLA body elsewhere. SSD: Granite's Mamba-2, ``ops/ssd_*.py``.)
 
 A KDA layer (``models/ling.py`` at 32 heads; ``models/solar.py`` at 64,
 with ``beta`` in (0, 2) and a log decay ``g`` unbounded below: the

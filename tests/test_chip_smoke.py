@@ -249,6 +249,28 @@ def test_segment_check_rehearses_on_the_cpu(monkeypatch):
         assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
 
 
+def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
+    """What ``hybrid_phase`` asks of the block of state-space layers
+    (``models/granite.py``) on the chip, at tiny widths with segments of
+    8 rows and the step kernel in the Pallas interpreter: prompts of 16
+    and 32 tokens run their Mamba layer in two and four segments, the
+    state carried, and agree with stepping; the kernel agrees with the
+    XLA body and hands an inactive slot's state back bit for bit."""
+    from ray_tpu.models import moe
+
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 8)
+    found = chip_smoke.ssm_check("tiny", [16, 32], TINY.seed,
+                                 interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert found["segments"] == {"16": 2, "32": 4}
+    for errs in found["rel_err"].values():
+        assert set(errs) == {"out", "state", "conv"}
+        assert errs["conv"] == 0.0
+        assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    assert max(found["kernel"].values()) <= chip_smoke.SSD_KERNEL_TOLERANCE
+    assert found["inactive_kept"] and found["in_program"] is False
+
+
 def test_ring_check_rehearses_on_the_cpu():
     """What ``hybrid_phase`` asks of a sliding-window layer's ring on the
     chip, at tiny widths (a window of 8) with the kernel in the Pallas

@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decode_engine as de
-from ray_tpu.models import exaone, instella, ling, llama, mimo, moe, solar
+from ray_tpu.models import (exaone, granite, instella, ling, llama, mimo,
+                            moe, solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -34,6 +35,7 @@ BLOCKS = {
     "instella": (instella, instella.InstellaConfig.tiny),
     "solar": (solar, solar.SolarConfig.tiny),
     "mimo": (mimo, mimo.MimoConfig.tiny),
+    "granite": (granite, granite.GraniteConfig.tiny),
 }
 ROWS = [name for name in BLOCKS if name.startswith("llama")]
 OWN = [name for name in BLOCKS if name not in ROWS]
@@ -194,7 +196,8 @@ def test_the_unrolled_blocks_share_one_copy(name):
 # ------------------------------------------- who knows which block
 
 
-_BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo"}
+_BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo",
+                "granite"}
 
 
 def _names_a_block(word: str) -> bool:
@@ -245,7 +248,7 @@ def test_no_block_imports_the_engine():
     import ray_tpu
 
     for name in ("llama", "llama_slots", "slots", "moe", "ling", "exaone",
-                 "instella", "solar", "mimo"):
+                 "instella", "solar", "mimo", "granite"):
         with open(f"{ray_tpu.__path__[0]}/models/{name}.py") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):

@@ -38,7 +38,8 @@ SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("k-exaone-236b-a23b-ep8-1chip", "reason-long-saturated"),
                  ("instella-moe-16b-a3b-pp4-1chip", "longdoc-saturated"),
                  ("solar-open2-250b-ep8-1chip", "longreason-saturated"),
-                 ("mimo-v2.5-ep16-1chip", "longreason-saturated"))
+                 ("mimo-v2.5-ep16-1chip", "longreason-saturated"),
+                 ("granite-4.0-h-small-ep4-1chip", "sessions-saturated"))
 TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
                ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 
@@ -101,6 +102,12 @@ def dump_serving_programs(out_dir: str) -> None:
         if hasattr(ling, "_kda_chunk"):
             solar._kda_chunk = ling._kda_chunk
     except ImportError:  # (a parent before PR 42)
+        pass
+    try:
+        from ray_tpu.models import granite
+        granite._ssd_step = functools.partial(granite._ssd_step,
+                                              use_kernel=True)
+    except ImportError:  # (a parent before PR 54)
         pass
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")

@@ -276,6 +276,11 @@ def _toy_block(block: str):
 
         return solar.SolarConfig.tiny(max_seq_len=64), \
             _ALWAYS | _MOE | {"moe_shared"}
+    if block == "granite":
+        from ray_tpu.models import granite
+
+        return granite.GraniteConfig.tiny(max_seq_len=64), \
+            _ALWAYS | _MOE | {"moe_shared"}
     from ray_tpu.models import exaone
 
     return exaone.ExaoneConfig.tiny(max_seq_len=64), \
@@ -284,7 +289,7 @@ def _toy_block(block: str):
 
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill"])
 @pytest.mark.parametrize("block", ["llama", "olmoe", "ling", "exaone",
-                                   "instella", "solar"])
+                                   "instella", "solar", "granite"])
 def test_every_part_of_a_block_is_in_its_programs_map(topo, block, program):
     """The map a capture is read through, from the text the TPU compiler
     leaves: every part the block should have is there, the second level
@@ -322,7 +327,8 @@ def test_every_part_of_a_block_is_in_its_programs_map(topo, block, program):
     kinds = {"ling": {"attn/attn_linear", "attn/attn_latent"},
              "exaone": {"attn/attn_window", "attn/attn_full"},
              "instella": {"attn/attn_latent"},
-             "solar": {"attn/attn_linear", "attn/attn_full"}}.get(
+             "solar": {"attn/attn_linear", "attn/attn_full"},
+             "granite": {"attn/attn_ssm", "attn/attn_full"}}.get(
         block, set())
     assert kinds <= found, sorted(kinds - found)
     if program == "decode_chunk":
