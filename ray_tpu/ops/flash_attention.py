@@ -20,12 +20,19 @@ Forward only (:func:`flash_fwd`, PR 50): keys wider than values (``d_qk``
 the query rows at a TRACED ``offset`` behind the keys' first row (a
 segment of a prompt against the rows written so far: the grid covers
 every k block and an index map that stops at the last live one keeps
-the dead steps off the HBM), a band (``window``: row i sees keys i +
-offset - window + 1 ... i + offset, and the grid's last dimension is
-the two or three k blocks a q block's band touches, shown in a trace as
-``flash_fwd_window``), and a learned sink (a logit a head that joins
-the denominator at the finalize and takes no value). With none of these
-the call is the parent's, text for text.
+the dead steps off the HBM), and a learned sink (a logit a head that
+joins the denominator and takes no value). With none of these the call
+is the parent's, text for text.
+
+A band (``window``: row i sees keys i + offset - window + 1 ... i +
+offset) is a kernel of its own (:func:`_window_kernel`, PR 55, shown in
+a trace as ``flash_fwd_window``) and shares no branch with the others.
+Its cell is ONE KV HEAD'S WHOLE GROUP of query heads over one q block:
+the group's rows are one ``[group * block_q, d_qk]`` operand against the
+two or three k blocks the q block's band touches, each an operand of the
+one grid step, so k and v are fetched once a group, the softmax is plain
+(no scratch, no running max) and the blocks come from the window
+(:func:`_window_blocks`), not from ``flash_block_q`` / ``_k``.
 """
 
 from __future__ import annotations
@@ -53,9 +60,11 @@ _LOG2E = 1.4426950408889634
 
 
 def _heads_per_block(flag: str, hq: int, group: int) -> int:
-    """Clamped heads-per-grid-cell for `flag`: must divide hq, MHA only
-    (the kv-group remap inside a multi-head block isn't worth the edge
-    cases — MHA is the bench-critical shape). One helper so the forward
+    """Clamped heads-per-grid-cell for `flag` in the single-pass forward
+    and the fused backward: must divide hq, and with grouped kv heads
+    (``group > 1``) it is one, since those kernels' cells pair head h of
+    q with head h of k (only the band's kernel, :func:`_window_kernel`,
+    puts a kv head's whole group in a cell). One helper so the forward
     and fused-backward eligibility rules can't diverge."""
     from ray_tpu._private import config as _cfg
 
@@ -75,28 +84,29 @@ def _vmem_limit() -> int:
     return int(_cfg.get("flash_vmem_limit_mb")) * 1024 * 1024
 
 
-def _causal_mask(s, q_start, k_start, offset, window=None):
-    """End-aligned causal mask: query row i attends keys <= i + offset
-    (and, in a band, the last ``window`` of them)."""
+def _causal_mask(s, q_start, k_start, offset):
+    """End-aligned causal mask: query row i attends keys <= i + offset."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + q_start
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k_start
-    seen = rows + offset >= cols
-    if window is not None:
-        seen &= cols > rows + offset - window
-    return jnp.where(seen, s, _NEG_INF)
+    return jnp.where(rows + offset >= cols, s, _NEG_INF)
 
 
-def _k_blocks(q_start, block_q, block_k, offset, window, nk):
+def _k_blocks(q_start, block_q, block_k, offset, nk):
     """(first, last) k block that rows ``q_start`` .. of a q block see,
-    of ``nk``: up to the diagonal's, and in a band from its lower
-    edge's."""
-    # (lax.div truncates: both numerators are >= 0)
-    last = jnp.minimum(
+    of ``nk``: from the first (a 0 that its callers add: the full
+    kernel's program text is held to its parent's) up to the
+    diagonal's."""
+    # (lax.div truncates: the numerator is >= 0)
+    return 0, jnp.minimum(
         jax.lax.div(q_start + block_q - 1 + offset, block_k), nk - 1)
-    if window is None:
-        return 0, last
+
+
+def _band_blocks(q_start, block_q, block_k, offset, window, nk):
+    """(first, last) k block that a q block's band touches, of ``nk``:
+    from its lower edge's up to the diagonal's."""
     return jax.lax.div(
-        jnp.maximum(q_start + offset - window + 1, 0), block_k), last
+        jnp.maximum(q_start + offset - window + 1, 0), block_k), _k_blocks(
+            q_start, block_q, block_k, offset, nk)[1]
 
 
 def _block_live(causal, q_start, k_start, block_q, offset):
@@ -115,7 +125,7 @@ def _straddles(q_start, k_start, block_k, offset):
 
 
 def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
-                window=None, sink=False):
+                sink=False):
     """offset = S - T: the causal mask is end-aligned (query row i attends
     keys <= i + offset), matching attention_reference's tril(k=S-T) so decode
     (T=1 against a long cache) sees the whole prefix.
@@ -142,8 +152,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
     if nk_all is None:
         k_start = ik * block_k
     else:
-        first, last = _k_blocks(q_start, block_q, block_k, offset, window,
-                                nk_all)
+        first, last = _k_blocks(q_start, block_q, block_k, offset, nk_all)
         k_start = (first + ik) * block_k
 
     def _compute(masked: bool):
@@ -158,7 +167,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * (scale * _LOG2E)  # [bq, bk], log2 domain
         if masked:
-            s = _causal_mask(s, q_start, k_start, offset, window)
+            s = _causal_mask(s, q_start, k_start, offset)
 
         m_prev = m_scr[:, :1]  # [bq, 1] (lanes replicated)
         m_cur = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
@@ -188,9 +197,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
         live = first + ik <= last
     else:
         live = _block_live(causal, q_start, k_start, block_q, offset)
-    if window is not None:  # (two of a band's two or three blocks straddle)
-        pl.when(live)(lambda: _compute(masked=True))
-    elif causal:
+    if causal:
         straddle = _straddles(q_start, k_start, block_k, offset)
         pl.when(jnp.logical_and(live, straddle))(
             lambda: _compute(masked=True)
@@ -271,11 +278,11 @@ def _fwd_kernel_1pass(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
 
 
 def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
-               offset=None, window=None, sink=None):
+               offset=None, sink=None):
     """With ``offset`` (an int32 scalar, traced or not) the forward-only
     form (module docstring; :func:`flash_fwd` is its caller): the tiled
-    kernel with ``offset`` scalar-prefetched, ``window`` and ``sink``
-    [Hq] float32 where given."""
+    kernel with ``offset`` scalar-prefetched, and ``sink`` [Hq] float32
+    where given."""
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
     dv = v.shape[-1]
@@ -296,7 +303,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
     if offset is not None:
         return _flash_fwd_at(
             q, k, v, jnp.asarray(offset, jnp.int32).reshape(1), sink,
-            scale=scale, block_q=block_q, block_k=block_k, window=window,
+            scale=scale, block_q=block_q, block_k=block_k,
             interpret=interpret)
     if nk == 1:
         hb = _heads_per_block("flash_heads_per_block", hq, group)
@@ -383,25 +390,21 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
 
 
 def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
-                  window, interpret):
+                  interpret):
     """The forward-only call (``_flash_fwd`` says when): ``offset`` [1]
     int32 is scalar-prefetched; the last grid dimension is every k
-    block, or in a band the most a q block's band touches; the k / v
-    index map stops at a q block's last live block, so that a dead
-    step asks for the block that is there already."""
+    block; the k / v index map stops at a q block's last live block, so
+    that a dead step asks for the block that is there already."""
     b, hq, t, d = q.shape
     _, hkv, s, dv = v.shape
     group = hq // hkv
     nk = cdiv(s, block_k)
-    steps = nk if window is None else min(
-        nk, cdiv(block_q + window - 1, block_k) + 1)
 
     def q_idx(bi, hi, qi, ki, off):
         return (bi, hi, qi, 0)
 
     def kv_idx(bi, hi, qi, ki, off):
-        first, last = _k_blocks(qi * block_q, block_q, block_k, off[0],
-                                window, nk)
+        first, last = _k_blocks(qi * block_q, block_q, block_k, off[0], nk)
         return (bi, hi // group, jnp.minimum(first + ki, last), 0)
 
     operands, in_specs = [q, k, v], [
@@ -416,11 +419,11 @@ def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
     out, lse4 = pl.pallas_call(
         functools.partial(
             _fwd_kernel, causal=True, scale=scale, block_q=block_q,
-            block_k=block_k, offset=None, nk_all=nk, window=window,
+            block_k=block_k, offset=None, nk_all=nk,
             sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, hq, cdiv(t, block_q), steps),
+            grid=(b, hq, cdiv(t, block_q), nk),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, 1, block_q, dv), q_idx),
                        pl.BlockSpec((1, 1, block_q, 8), q_idx)],
@@ -439,9 +442,170 @@ def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
             vmem_limit_bytes=_vmem_limit(),
         ),
         interpret=interpret,
-        name="flash_fwd" if window is None else "flash_fwd_window",
+        name="flash_fwd",
     )(offset, *operands)
     return out, lse4[..., 0]
+
+
+def _window_blocks(window: int, group: int):
+    """The band's (block_q, block_k) where the caller gives none: a k
+    block is the window in whole 128-lane tiles; a q block is as long
+    (the fewest scores outside the band that whole blocks allow: two k
+    blocks a q block), or the multiple of it that gives a cell's
+    products 512 rows where the group is small. Read on the chip at
+    groups of 8, 4 and 1 (``PERF.md`` §6 PR 55)."""
+    block_k = cdiv(window, 128) * 128
+    return block_k * max(1, 512 // (group * block_k)), block_k
+
+
+def _window_kernel(offset_ref, q_ref, *refs, scale, block_q, block_k, window,
+                   nk_all, plain, spare, sink):
+    """One kv head's group of query heads over one q block's band: q_ref
+    [1, group, block_q, d_qk] is one [group * block_q, d_qk] operand of
+    the score product and of ``p @ v``; ``refs`` are ``plain + spare``
+    k blocks, as many v blocks, the group's [1, 1, group * block_q] sink
+    logits (a head's, once a row) with ``sink``, and o [1, group,
+    block_q, d_v]. Operand j is k block ``first + j`` of
+    :func:`_band_blocks`.
+
+    The scores are formed TRANSPOSED, ``k @ q^T`` [block_k, rows]: keys
+    down the sublanes, the group's rows along the lanes, so that a row's
+    max and sum are elementwise over registers and its statistics whole
+    lanes (a ``[rows, 1]`` statistic is one lane a register, and a pass
+    over it costs what a pass over a whole tile costs: ``PERF.md`` §6
+    PR 55); the output ``v^T @ p^T`` is turned once, at the end.
+
+    The first ``plain`` blocks hold the whole band wherever its lower
+    edge begins a block (the served case: ``offset`` a multiple of the
+    segment): one plain softmax over them, statistics in float32 in the
+    log2 domain as :func:`_fwd_kernel`'s. At any other offset the band
+    reaches one block further (``spare``), and that block joins by the
+    online update, under a ``pl.when`` that the served case never
+    enters."""
+    *kv_refs, o_ref = refs
+    blocks = plain + spare
+    k_refs, v_refs = kv_refs[:blocks], kv_refs[blocks:2 * blocks]
+    group, _, d = q_ref.shape[1:]
+    rows = group * block_q
+    q_start = pl.program_id(2) * block_q
+    offset = offset_ref[0]
+    first, last = _band_blocks(q_start, block_q, block_k, offset, window,
+                               nk_all)
+    q = q_ref[0].reshape(rows, d)
+    # the mask is one head's [block_k, block_q], the same for the group
+    key = jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+    at = jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 1) + (q_start + offset)
+
+    def scores(j):
+        s = jax.lax.dot_general(
+            k_refs[j][0, 0], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * (scale * _LOG2E)  # [bk, rows], log2 domain
+        key_j = key + (first + j) * block_k
+        # (a block past the last is the last one again: nothing of it)
+        seen = (key_j <= at) & (key_j > at - window) & (first + j <= last)
+        unseen = jnp.where(seen, 0.0, _NEG_INF)  # s + it: _NEG_INF itself
+        return s + jnp.concatenate([unseen] * group, axis=1)
+
+    def weighted(p, j):
+        v = v_refs[j][0, 0]
+        return jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [dv, rows]
+
+    def finite(m):
+        # a row that sees nothing keeps _NEG_INF: exp2(s - 0) is 0 there
+        return jnp.where(m > _NEG_INF * 0.5, m, 0.0)
+
+    def write(l, acc):
+        o = (acc / jnp.where(l == 0.0, 1.0, l)).T  # [rows, dv]
+        o_ref[0] = o.reshape(group, block_q, -1).astype(o_ref.dtype)
+
+    s = [scores(j) for j in range(plain)]
+    m = functools.reduce(
+        jnp.maximum, [jnp.max(x, axis=0, keepdims=True) for x in s])
+    if sink:  # the sink's term joins the sum; its value is nothing
+        sink2 = kv_refs[-1][0] * _LOG2E  # [1, rows]
+        m = jnp.maximum(m, sink2)
+    p = [jnp.exp2(x - (m if sink else finite(m))) for x in s]
+    l = functools.reduce(
+        jnp.add, [jnp.sum(x, axis=0, keepdims=True) for x in p])
+    if sink:
+        l = l + jnp.exp2(sink2 - m)
+    acc = functools.reduce(
+        jnp.add, [weighted(x, j) for j, x in enumerate(p)])
+    write(l, acc)
+
+    def unaligned():
+        s = scores(plain)
+        m_new = finite(jnp.maximum(m, jnp.max(s, axis=0, keepdims=True)))
+        shrink = jnp.exp2(m - m_new)
+        p = jnp.exp2(s - m_new)
+        write(l * shrink + jnp.sum(p, axis=0, keepdims=True),
+              acc * shrink + weighted(p, plain))
+
+    if spare:
+        pl.when(first + plain <= last)(unaligned)
+
+
+def _flash_fwd_window(q, k, v, offset, sink, *, window, block_q, block_k,
+                      interpret):
+    """The band's call: grid (batch, kv head, q block), ``offset`` [1]
+    int32 scalar-prefetched, k and v handed over once a k block the band
+    may touch (``cdiv(block_q + window - 1, block_k)``, and one more
+    where the offset is no multiple of a block), each with an index map
+    of its own that stops at the band's last block. No lse: the call is
+    forward only and nothing reads one."""
+    b, hq, t, d = q.shape
+    _, hkv, s, dv = v.shape
+    group = hq // hkv
+    if t % block_q or s % block_k:
+        raise ValueError(
+            f"flash_fwd: T={t} / S={s} must be multiples of the band's "
+            f"blocks ({block_q}, {block_k}); pad inputs or pass blocks.")
+    nk = s // block_k
+    plain = min(nk, cdiv(block_q + window - 1, block_k))
+    spare = int(plain < nk)
+
+    def q_idx(bi, hi, qi, off):
+        return (bi, hi, qi, 0)
+
+    def kv_spec(j, width):
+        def idx(bi, hi, qi, off):
+            first, last = _band_blocks(qi * block_q, block_q, block_k,
+                                       off[0], window, nk)
+            return (bi, hi, jnp.minimum(first + j, last), 0)
+        return pl.BlockSpec((1, 1, block_k, width), idx)
+
+    blocks = plain + spare
+    operands = [q] + [k] * blocks + [v] * blocks
+    in_specs = [pl.BlockSpec((1, group, block_q, d), q_idx)] \
+        + [kv_spec(j, d) for j in range(blocks)] \
+        + [kv_spec(j, dv) for j in range(blocks)]
+    if sink is not None:
+        operands.append(jnp.repeat(
+            sink.astype(jnp.float32), block_q).reshape(hkv, 1, -1))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, group * block_q), lambda bi, hi, qi, off: (hi, 0, 0)))
+    return pl.pallas_call(
+        functools.partial(
+            _window_kernel, scale=d ** -0.5, block_q=block_q,
+            block_k=block_k, window=window, nk_all=nk, plain=plain,
+            spare=spare, sink=sink is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, t // block_q),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, group, block_q, dv), q_idx)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_vmem_limit(),
+        ),
+        interpret=interpret,
+        name="flash_fwd_window",
+    )(offset, *operands)
 
 
 def flash_fwd(q, k, v, *, offset=None, window: int | None = None, sink=None,
@@ -451,11 +615,17 @@ def flash_fwd(q, k, v, *, offset=None, window: int | None = None, sink=None,
     positions ``offset`` .. ``offset + T - 1`` (``None``: S - T; may be
     traced) over k [B, Hkv, S, d_qk], v [B, Hkv, S, d_v] of positions 0
     .. S - 1 -> [B, Hq, T, d_v]. Row i sees keys <= i + offset, with
-    ``window`` the last ``window`` of them; ``sink`` [Hq] float32 joins
-    each head's denominator (module docstring). Forward only: a band, a
-    sink, an offset and k wider than v have no backward kernel, and a
-    differentiated call raises (training such a block: ROADMAP A3)."""
-    if block_q is None or block_k is None:
+    ``window`` the last ``window`` of them (the band's own kernel, whose
+    blocks come from the window where none are given); ``sink`` [Hq]
+    float32 joins each head's denominator (module docstring). Forward
+    only: a band, a sink, an offset and k wider than v have no backward
+    kernel, and a differentiated call raises (training such a block:
+    ROADMAP A3)."""
+    if window is not None:
+        wq, wk = _window_blocks(window, q.shape[1] // k.shape[1])
+        block_q = min(block_q or wq, q.shape[2])
+        block_k = min(block_k or wk, k.shape[2])
+    elif block_q is None or block_k is None:
         from ray_tpu._private import config as _cfg
 
         block_q = block_q or _cfg.get("flash_block_q")
@@ -466,9 +636,14 @@ def flash_fwd(q, k, v, *, offset=None, window: int | None = None, sink=None,
 
     @jax.custom_vjp
     def forward(q, k, v, offset, sink):
+        if window is not None:
+            return _flash_fwd_window(
+                q, k, v, jnp.asarray(offset, jnp.int32).reshape(1), sink,
+                window=window, block_q=block_q, block_k=block_k,
+                interpret=interpret)
         return _flash_fwd(q, k, v, causal=True, block_q=block_q,
                           block_k=block_k, interpret=interpret,
-                          offset=offset, window=window, sink=sink)[0]
+                          offset=offset, sink=sink)[0]
 
     def refuse(*_):
         raise NotImplementedError(

@@ -49,6 +49,29 @@ from ray_tpu.train.step import train_state_shardings  # noqa: E402
 
 KERNEL = "tpu_custom_call"
 MIB = 2**20
+# a kernel call's Mosaic module in a compiled program's text
+MOSAIC_BODY = re.compile(r'\\?"body\\?": ?\\?"([A-Za-z0-9+/=]+)\\?"')
+
+
+def _mosaic_text(encoded: str) -> str:
+    """A Mosaic module (``MOSAIC_BODY``'s group) as text without
+    locations: they carry the checkout's paths and line numbers."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    with mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        return ir.Module.parse(base64.b64decode(encoded)) \
+            .operation.get_asm(enable_debug_info=False)
+
+
+def _made_by(lines) -> dict:
+    """{instruction: the operation that makes it} of a program's lines."""
+    return {m.group(1): m.group(2) for m in (
+        re.match(r"\s*(%[\w.\-]+) = .*?\s([\w\-]+)\(", ln) for ln in lines)
+        if m}
 
 
 @pytest.fixture(scope="module")
@@ -191,9 +214,7 @@ def _kda_chunk_calls(text: str, prefetched_ok: bool = False) -> list:
     calls = [ln for ln in lines
              if KERNEL in ln and "kda_chunk" in ln.split(" = ")[0]]
     assert not re.findall(r"f32\[[\d,]*64,64,128\]", text)
-    made_by = {m.group(1): m.group(2) for m in (
-        re.match(r"\s*(%[\w.\-]+) = .*?\s([\w\-]+)\(", ln) for ln in lines)
-        if m}
+    made_by = _made_by(lines)
     for call in calls:
         assert re.search(r"attn/attn_linear/(jit\(_kda_chunk\)/)?kda_chunk/"
                          "pallas_call", call), call[:300]

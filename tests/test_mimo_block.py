@@ -416,29 +416,62 @@ def test_decode_attn_at_the_published_head_widths_with_a_sink(
     assert float(jnp.abs(sunk - want).max()) > 1e-3
 
 
-@pytest.mark.parametrize("t, offset", [(64, 0), (128, 0), (256, 0),
-                                       (128, 256)])
+# (heads, kv heads, T, offset, window, a sink, blocks or the kernel's own)
+_BAND_CASES = [
+    # the tests' tiny shapes: explicit 64 x 64 blocks
+    (8, 2, 64, 0, 128, True, 64), (8, 2, 128, 0, 128, True, 64),
+    (8, 2, 256, 0, 128, True, 64), (8, 2, 128, 256, 128, True, 64),
+    (8, 2, 128, 256, 16, False, 64), (8, 2, 128, 200, 16, True, 64),
+    # the published heads, the blocks the window gives: rows with fewer
+    # than ``window`` keys behind them, an offset of whole blocks, and
+    # one that is not (the band then touches three blocks)
+    (64, 8, 256, 0, 128, True, None), (64, 8, 256, 0, 128, False, None),
+    (64, 8, 256, 256, 128, True, None), (64, 8, 256, 256, 128, False, None),
+    (64, 8, 256, 200, 128, True, None), (64, 8, 256, 200, 128, False, None),
+    # T smaller than a block, at the start and behind other rows
+    (64, 8, 64, 0, 128, True, None), (64, 8, 64, 64, 128, False, None),
+    # a band over MHA (a group of one), aligned and not
+    (4, 4, 512, 512, 128, True, None), (4, 4, 512, 72, 128, False, None),
+]
+
+
+@pytest.mark.parametrize("hq, hkv, t, offset, window, sunk, block",
+                         _BAND_CASES)
 def test_banded_flash_fwd_at_the_published_head_widths_with_a_sink(
-        t, offset):
+        hq, hkv, t, offset, window, sunk, block):
     """``flash_fwd`` in the interpreter against ``attend_rows``' XLA
-    body: d_qk 192 beside d_v 128, a band of 128 and a sink, prompts
-    under, at and over the window, and a segment at a traced offset
-    behind 256 earlier rows; the full layers' call (no band, no sink)
-    at the same shapes."""
+    body: d_qk 192 beside d_v 128, a band with a sink and without,
+    prompts under, at and over the window, a segment at a traced offset
+    behind earlier rows (whole blocks of them, or not), a kv head's
+    group of 8, 4 and 1 query heads in a cell; the full layers' call (no
+    band, no sink) at the tiny shapes."""
     ks = jax.random.split(jax.random.PRNGKey(t + offset), 4)
-    s = offset + t
-    q = jax.random.normal(ks[0], (1, 8, t, 192))
-    k = jax.random.normal(ks[1], (1, 2, s, 192))
-    v = jax.random.normal(ks[2], (1, 2, s, 128))
-    sink = 2.0 + jax.random.normal(ks[3], (8,))
-    for window, snk in ((128, sink), (None, None)):
+    s = -(-(offset + t) // 128) * 128  # (the keys are whole blocks)
+    q = jax.random.normal(ks[0], (1, hq, t, 192))
+    k = jax.random.normal(ks[1], (1, hkv, s, 192))
+    v = jax.random.normal(ks[2], (1, hkv, s, 128))
+    sink = 2.0 + jax.random.normal(ks[3], (hq,)) if sunk else None
+    for window, snk in [(window, sink)] + [(None, None)] * bool(block):
         want = attend_rows(q, k, v, offset=offset, window=window, sink=snk,
                            use_flash=False)
         got = jax.jit(lambda q, k, v, o: flash_fwd(
-            q, k, v, offset=o, window=window, sink=snk, block_q=64,
-            block_k=64, interpret=True))(q, k, v, jnp.int32(offset))
-        assert got.shape == (1, 8, t, 128)
+            q, k, v, offset=o, window=window, sink=snk, block_q=block,
+            block_k=block, interpret=True))(q, k, v, jnp.int32(offset))
+        assert got.shape == (1, hq, t, 128)
         np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_bands_blocks_come_from_the_window_and_the_group():
+    """``_window_blocks``: a k block is the window in whole lanes, a q
+    block as long where the group's rows fill a cell (512 of them) and a
+    multiple where they do not."""
+    from ray_tpu.ops.flash_attention import _window_blocks
+
+    assert _window_blocks(128, 8) == _window_blocks(128, 4) == (128, 128)
+    assert _window_blocks(128, 2) == (256, 128)
+    assert _window_blocks(128, 1) == (512, 128)
+    assert _window_blocks(100, 8) == (128, 128)
+    assert _window_blocks(200, 8) == (256, 256)
 
 
 def test_a_differentiated_band_or_sink_raises_by_name():

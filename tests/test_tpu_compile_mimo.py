@@ -15,9 +15,9 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, _dead_branch_hands_on_and_makes_zeros, _expert_branches,
-    _loops_add_nothing_unscoped, _lower_prefill, _mem, _on,
-    _segment_branches, topo)
+    KERNEL, MIB, MOSAIC_BODY, _dead_branch_hands_on_and_makes_zeros,
+    _expert_branches, _loops_add_nothing_unscoped, _lower_prefill, _made_by,
+    _mem, _mosaic_text, _on, _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -107,13 +107,17 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
     32,768 rows in 16 segments of 2,048, every layer one scan:
     ``flash_fwd`` in the two full layers (d_qk 192, d_v 128, a segment's
     rows against the rows so far) and ``flash_fwd_window`` in the five
-    window layers (the band's blocks alone, the sink at the finalize),
+    window layers (a cell a kv head's eight query heads, ``[1, 8, 128,
+    192]`` of q over three ``[1, 1, 128, ...]`` blocks of k and of v, the
+    band's at most; the scores ``[128, 1024]``, keys by the group's rows,
+    and nothing ``1024 x 1024`` in the body; k and v taken as the scan
+    carries them, never copied for the call),
     ``moe_gmm`` three times in either branch of an expert layer (the
     compact one and its fall-back, ``moe.moe``); no ``[32768, 32768]``
     scores, no whole ``[32768, 16384]`` gate or up of the dense layer in
     either type, no ``[P, vocabulary]`` logits; the donated state is
     updated in place; beside 32 slots the call fits the chip's 16 GiB
-    (temporaries 3,284 MiB beside 12,084 MiB of arguments: the stream in
+    (temporaries 3,317 MiB beside 12,084 MiB of arguments: the stream in
     and out of a layer, a layer's k and v so far, a segment's expert
     rows; on the chip the cell's peak reads 12.83 GB)."""
     from ray_tpu.models import mimo
@@ -129,6 +133,30 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
         == cfg.window_layers == 5
     assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
         == cfg.full_layers == 2
+    lines = text.splitlines()
+    made_by = _made_by(lines)
+    group = cfg.n_heads // cfg.kv_heads(True)
+    bands = [ln for ln in lines
+             if KERNEL in ln and "flash_fwd_window" in ln.split(" = ")[0]]
+    assert len(bands) == cfg.window_layers
+    for call in bands:
+        operands = re.findall(
+            r"%[\w.\-]+", re.search(r"custom-call\(([^)]*)\)", call).group(1))
+        # offset, q, three blocks of k and of v, the sink
+        assert len(operands) == 9 and len(set(operands)) == 5, operands
+        moved = {o: made_by.get(o) for o in operands if made_by.get(o) in (
+            "copy", "copy-done", "transpose")}
+        assert not moved, moved
+        body = _mosaic_text(MOSAIC_BODY.search(call).group(1))
+        args = body[:body.index("\n", body.index("^bb0"))]
+        assert group == 8 and (
+            f"memref<1x{group}x128x{cfg.head_dim}xbf16" in args
+            and f"memref<1x{group}x128x{cfg.v_head_dim}xbf16" in args
+            and args.count(f"memref<1x1x128x{cfg.head_dim}xbf16") == 3
+            and args.count(f"memref<1x1x128x{cfg.v_head_dim}xbf16") == 3
+        ), args
+        assert f"vector<128x{group * 128}xf32>" in body
+        assert not re.search(r"1024x1024x", body)
     assert sum("moe_gmm" in c for c in calls) == 2 * 3 * cfg.moe_layers
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))
               for dt, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
