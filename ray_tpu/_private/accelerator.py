@@ -32,11 +32,13 @@ hold a chip) or only orchestrates (then it should stay off JAX or set
 
 from __future__ import annotations
 
+import collections
 import errno
 import glob
 import math
 import os
 import sys
+import threading
 import time
 
 # chips handed to this worker by its node agent ("0" or "2,3"); unset or
@@ -171,6 +173,87 @@ def wait_nodes_free(nodes: list[str]) -> list[str]:
 
 
 _compile_stats: dict | None = None
+_listening = False
+
+# jax's three timed stages of a jitted function's first call, as
+# ``jax.monitoring`` names them (``jax/_src/dispatch.py``)
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_HIT = "/jax/compilation_cache/cache_hits"
+_BEGAN = "began"
+_STAGES = (_TRACE, _LOWER, _BACKEND)
+_TOTAL_KEY = {_TRACE: "trace_seconds", _LOWER: "lower_seconds"}
+_TALLY_KEEP = 1024  # entries of a thread's tally kept, at least
+
+
+class _ThreadTally(threading.local):
+    """What jax compiled ON THIS THREAD, as a log: jax calls its
+    listeners on the thread that compiles, so a caller that reads ``n``
+    before a call and finds it moved afterwards owns the entries between
+    (:func:`compile_mark`, :func:`compile_since`), whatever another
+    thread compiles meanwhile. A stage that runs inside another (a
+    jitted body traced inside its caller's trace, a small program
+    compiled while something is traced) is that one's time already:
+    ``depth`` counts the stages open, and only the outermost is logged."""
+
+    def __init__(self):
+        self.n = 0  # entries ever logged here: a caller's mark
+        self.log: list = []  # the newest of them, (what, value)
+        self.depth = 0
+        self.outer = ""  # the stage that is open at depth 1
+
+    def add(self, what: str, value: float) -> None:
+        self.log.append((what, value))
+        self.n += 1
+        if len(self.log) > 2 * _TALLY_KEEP:
+            del self.log[:_TALLY_KEEP]
+
+
+tally = _ThreadTally()  # ``tally.n``: what :func:`compile_mark` returns
+
+
+def _on_stage_begin(event: str, value: float, **_kw) -> None:
+    # (jax says when a stage begins with a scalar, its start on
+    # ``time.time()``'s clock: nothing here reads a clock)
+    if event in _STAGES:
+        t = tally
+        t.depth += 1
+        if t.depth == 1:
+            t.outer = event
+            t.add(_BEGAN, value)
+
+
+def _total(key: str, value: float) -> None:
+    if _compile_stats is not None:
+        _compile_stats[key] += value
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    t = tally
+    if event == _CACHE_READ:
+        # fired inside the backend stage, whose time holds it
+        if t.depth == 1 and t.outer == _BACKEND:
+            _total("cache_read_seconds", duration)
+            t.add(event, duration)
+        return
+    if event not in _STAGES:
+        return
+    if event == _BACKEND:
+        _total("seconds", duration)  # every one, as since PR 21
+    t.depth = max(0, t.depth - 1)
+    if t.depth == 0:
+        if event != _BACKEND:
+            _total(_TOTAL_KEY[event], duration)
+        t.add(event, duration)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event in (_REQUEST, _HIT):
+        _total("requests" if event == _REQUEST else "hits", 1)
+        tally.add(event, 1)
 
 
 def _place_compile_cache() -> dict:
@@ -178,9 +261,12 @@ def _place_compile_cache() -> dict:
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX's own handling of it stands
     and no directory is set in code; otherwise one fixed path inside the
     checkout (the path is part of the cache key's home, so it must never
-    move with a pid, a timestamp or a temp dir). Also counts cache
-    requests/hits and backend-compile seconds for this process."""
-    global _compile_stats
+    move with a pid, a timestamp or a temp dir). Also counts, for this
+    process, cache requests / hits and backend-compile seconds (every
+    one, a cache read included) and, outermost stages only, the seconds
+    of tracing, of lowering and of reading the cache; the same events go
+    to the compiling thread's own tally (:class:`_ThreadTally`)."""
+    global _compile_stats, _listening
     if _compile_stats is not None:
         return _compile_stats
     import jax
@@ -189,23 +275,63 @@ def _place_compile_cache() -> dict:
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not env_dir:
         jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
-    stats = _compile_stats = {
+    _compile_stats = {
         "dir": env_dir or COMPILE_CACHE_DIR, "requests": 0, "hits": 0,
-        "seconds": 0.0}
+        "seconds": 0.0, "trace_seconds": 0.0, "lower_seconds": 0.0,
+        "cache_read_seconds": 0.0}
+    if not _listening:  # once a process: the stages are counted by depth
+        _listening = True
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_scalar_listener(_on_stage_begin)
+    return _compile_stats
 
-    def _on_event(event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            stats["requests"] += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            stats["hits"] += 1
 
-    def _on_duration(event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            stats["seconds"] += duration
+def compile_report() -> dict:
+    """This process's compile counters, seconds rounded to the ms."""
+    return {k: round(v, 3) if isinstance(v, float) else v
+            for k, v in _place_compile_cache().items()}
 
-    monitoring.register_event_listener(_on_event)
-    monitoring.register_event_duration_secs_listener(_on_duration)
-    return stats
+
+def compile_mark() -> int:
+    """Where this thread's compile tally stands: read before a call and
+    compared after it, it tells whether the call compiled anything. (A
+    caller on a request path reads ``tally.n`` itself: an attribute of a
+    thread-local, no call.)"""
+    return tally.n
+
+
+def _since(mark: int) -> list:
+    t = tally
+    return t.log[max(0, len(t.log) - (t.n - mark)):] if t.n > mark else []
+
+
+def compile_since(mark: int) -> dict | None:
+    """What this thread traced, lowered and compiled since ``mark``, or
+    None where it did nothing of the kind: ``requests`` / ``hits`` of the
+    persistent cache, and the milliseconds of the outermost stages,
+    which add up to no more than the time they took: ``trace_ms``
+    (Python: the function to a jaxpr), ``lower_ms`` (the jaxpr to a
+    module), ``cache_read_ms`` (a hit's read) and ``compile_ms`` (the
+    rest of the backend stage: the cache key, XLA's compile where the
+    cache missed, the write)."""
+    entries = _since(mark)
+    if not entries:
+        return None
+    sums = collections.Counter()
+    for what, value in entries:
+        sums[what] += value
+    ms = lambda s: round(1e3 * s, 3)  # noqa: E731
+    return {"requests": int(sums[_REQUEST]), "hits": int(sums[_HIT]),
+            "trace_ms": ms(sums[_TRACE]), "lower_ms": ms(sums[_LOWER]),
+            "compile_ms": ms(sums[_BACKEND] - sums[_CACHE_READ]),
+            "cache_read_ms": ms(sums[_CACHE_READ])}
+
+
+def compile_began(mark: int) -> float | None:
+    """When the first stage since ``mark`` began on this thread, on
+    ``time.time()``'s clock (jax's own stamp), or None."""
+    return next((v for what, v in _since(mark) if what == _BEGAN), None)
 
 
 _claim: dict | None = None
@@ -218,17 +344,20 @@ def claim_device() -> dict:
     a chip and is not on the TPU raises — there is no CPU path to fall
     back to silently. A granted chip that another process still holds
     (the worker of the job before, in exit) is waited for first: a busy
-    device node is fatal to the backend's initialisation."""
+    device node is fatal to the backend's initialisation
+    (``waited_ms``: that wait, 0 where it was under a poll)."""
     global _claim
     import jax
 
     _place_compile_cache()
-    busy, nodes = [], granted_nodes()
+    busy, nodes, t0 = [], granted_nodes(), time.monotonic()
     if nodes:
         # (not its own: where this process has touched the device
         # already, they read busy for as long as it lives)
         mine = _held_nodes(os.getpid(), set(nodes))
         busy = wait_nodes_free([n for n in nodes if n not in mine])
+    # what the ``chip_wait`` mark says, by its rule: one probe is no wait
+    waited_ms = 1e3 * (time.monotonic() - t0)
     try:
         devices = jax.devices()
     except RuntimeError as e:
@@ -244,6 +373,8 @@ def claim_device() -> dict:
         "count": len(devices),
         "ids": [d.id for d in devices],
         "granted_chips": list(granted_chips()),
+        "waited_ms": round(waited_ms, 1)
+        if waited_ms > 1e3 * CHIP_POLL_S else 0.0,
     }
     if _claim["granted_chips"] and _claim["platform"] != "tpu":
         raise RuntimeError(
@@ -256,7 +387,9 @@ def claim_device() -> dict:
 
 def device_report() -> dict:
     """What :func:`claim_device` found, plus this process's compile
-    counters ({dir, requests, hits, seconds}) and each device's peak
+    counters ({dir, requests, hits, seconds} and, of the outermost
+    stages, {trace_seconds, lower_seconds, cache_read_seconds}:
+    :func:`_place_compile_cache`) and each device's peak
     bytes in use (None where the backend keeps no memory stats) — the
     facts a replica's ``stats()`` or a train worker's report carries."""
     import jax
@@ -266,8 +399,7 @@ def device_report() -> dict:
     # the device nodes it holds open tell the chips apart. Read now, not
     # at the claim: libtpu opens them when the device is first used.
     report["nodes"] = _held_nodes(os.getpid(), set(chip_device_paths()))
-    report["compile"] = {**_compile_stats,
-                         "seconds": round(_compile_stats["seconds"], 3)}
+    report["compile"] = compile_report()
     report["peak_bytes_in_use"] = [
         (d.memory_stats() or {}).get("peak_bytes_in_use")
         for d in jax.local_devices()]

@@ -53,6 +53,21 @@ _MAX_BUNDLES = 20
 # spans stay ring-only (still visible postmortem) instead of growing RSS
 _MAX_PENDING = 20_000
 _FLUSH_BATCH = 1000
+_IMPORTED = time.monotonic()  # as early as this module knows of the process
+
+
+def process_age_s() -> float:
+    """How old this process is: its start time in ``/proc/self/stat``
+    (field 22, clock ticks since boot) against the boot clock, so spawn,
+    interpreter and imports are in it; where a platform gives neither,
+    the time since this module was imported."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return time.monotonic() - _IMPORTED
 
 
 class _Recorder:

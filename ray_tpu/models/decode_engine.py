@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import accelerator as _acc
 from ray_tpu._private import flight_recorder as _fr
 from ray_tpu._private import trace as _trace
 # (the Llama block's half, for the three mechanisms that need a state of
@@ -335,6 +336,13 @@ def _adopt_kv_into_slot(k_rows, v_rows, true_len, tok0, slot, cache,
         return cache, cur_tok.at[slot].set(tok0)
 
 
+# the engine's programs as a trace's ``XLA Modules`` line names them
+_CHUNK = f"jit_{decode_chunk.__name__}"
+_CHUNK_SPEC = f"jit_{decode_chunk_spec.__name__}"
+_PREFILL = f"jit_{_prefill_batch_into_slots.__name__}"
+PREFILL_KV = f"jit_{prefill_kv.__name__}"
+
+
 def _nbytes(tree) -> int:
     return sum(a.nbytes for a in jax.tree_util.tree_leaves(tree))
 
@@ -352,6 +360,49 @@ def adopt_weights(cfg, params, version: int):
             slot_model(cfg).serving_params(cfg, params))
         sp["bytes_out"] = _nbytes(serving)
     return serving
+
+
+# What ``engine.compiled`` marks add up to, an engine (``compiled`` in
+# :class:`RaggedDecoder`; the replica's ``stats()["setup"]`` carries it)
+_COMPILED_SUMS = ("trace_ms", "lower_ms", "compile_ms", "cache_read_ms")
+# the calling thread's compile tally: ``_TALLY.n`` read before a jitted
+# call and compared after it is all a call that compiled nothing pays
+_TALLY = _acc.tally
+_CAST = "jit__cast_leaves"  # the slot protocol's ``serving_params``' program
+
+
+def note_compiled(program: str, bucket: int, mark: int, *, name: str,
+                  ready: float | None = None,
+                  totals: dict | None = None) -> None:
+    """``engine.compiled`` (a flushed mark, so also an instant event on
+    the profiler's host line and a row of ``ray_tpu.timeline()``): the
+    call of ``program`` that has just returned traced, lowered or
+    compiled on this thread (the caller took ``mark`` =
+    ``accelerator.compile_mark()`` before it and found it moved; a call
+    that compiled nothing never comes here). Attrs: ``program`` as a
+    trace's ``XLA Modules`` line names it, ``bucket`` (a prefill's; 0 for
+    a chunk), the thread tally's six numbers
+    (``accelerator.compile_since``), ``call_ms`` from the call's first
+    stage (jax's own stamp) to now, the call being asynchronous, and
+    ``since_ready_ms`` (0 before the engine was). ``totals``, where
+    given, takes the sums."""
+    now, found = time.monotonic(), _acc.compile_since(mark)
+    if found is None:
+        return
+    began = _acc.compile_began(mark)
+    call_ms = round(1e3 * (time.time() - began), 3) if began else 0.0
+    _fr.mark("serve", "engine.compiled", attrs={
+        "engine": name, "program": program, "bucket": int(bucket),
+        "call_ms": call_ms, **found,
+        "since_ready_ms": round(1e3 * (now - ready), 1) if ready else 0.0})
+    if totals is not None:
+        totals["first_calls"] += 1
+        totals["first_call_ms"] = round(totals["first_call_ms"] + call_ms, 3)
+        totals["compile_requests"] += found["requests"]
+        totals["cache_hits"] += found["hits"]
+        for k in _COMPILED_SUMS:
+            totals[k] = round(totals[k] + found[k], 3)
+        totals["last_compile_mono_ns"] = int(now * 1e9)
 
 
 # Birth stamps a request may carry, in the order they are taken, and the
@@ -440,9 +491,17 @@ class RaggedDecoder:
             require_rows(cfg, "the prefix cache (kv_prefix_cache)")
         if int(spec_depth) > 0:
             require_rows(cfg, "speculative decoding (spec_depth > 0)")
+        self.name = name
+        # sums over this engine's ``engine.compiled`` marks, and when it
+        # stood ready (``time.monotonic``; None while it is built)
+        self.compiled = {
+            "first_calls": 0, **dict.fromkeys(_COMPILED_SUMS, 0.0),
+            "first_call_ms": 0.0, "compile_requests": 0, "cache_hits": 0,
+            "last_compile_mono_ns": 0}
+        self.ready_mono: float | None = None
         # the serving tree (the model's serving_params), the only weights
         # the engine holds: the caller's f32 masters are not kept
-        self.params = adopt_weights(cfg, params, weights_version)
+        self.params = self._adopt(cfg, params, weights_version)
         # Emulated per-chunk device time for exercising the SERVING
         # tier on hosts without an accelerator: on a TPU each chunk
         # waits on the device, time that overlaps across replicas — a
@@ -508,7 +567,6 @@ class RaggedDecoder:
         # sid -> stream for every not-yet-purged stream (streaming reads)
         self._by_sid: dict[int, _Stream] = {}
         self.prefix_cache = prefix_cache  # models.kv_prefix_cache or None
-        self.name = name
         # speculative decoding (decode_chunk_spec): depth K drafts per
         # verify round; 0 = off. The live config knobs
         # serve_spec_enabled / serve_spec_depth are consulted at every
@@ -529,6 +587,22 @@ class RaggedDecoder:
         # (stamp, n_tokens) per pump for the tokens/s scaling signal
         self._rate_window: collections.deque = collections.deque()
         self.mark_state()
+        self.ready_mono = time.monotonic()
+
+    def _adopt(self, cfg, params, version: int):
+        """:func:`adopt_weights` for this engine: what the cast took
+        (``weights_cast_ms``, the newest adoption's) and, where its
+        program was new to the process, an ``engine.compiled``."""
+        mark, t0 = _TALLY.n, time.monotonic()
+        serving = adopt_weights(cfg, params, version)
+        self.weights_cast_ms = round(1e3 * (time.monotonic() - t0), 3)
+        if _TALLY.n != mark:
+            self._note_compiled(_CAST, 0, mark)
+        return serving
+
+    def _note_compiled(self, program: str, bucket: int, mark: int) -> None:
+        note_compiled(program, bucket, mark, name=self.name,
+                      ready=self.ready_mono, totals=self.compiled)
 
     def mark_state(self) -> None:
         """``engine.state_init`` (ring-only, and an instant event on the
@@ -800,6 +874,7 @@ class RaggedDecoder:
         n = len(tokens)
         row = np.zeros((1, width), np.int32)
         row[0, :n] = tokens
+        mark = _TALLY.n  # (a thread-local's attribute: no call)
         (self.cache, self.cur_tok, tok0, logp0,
          *expert_tokens) = _prefill_batch_into_slots(
             self.params, row, np.array([n], np.int32),
@@ -808,6 +883,8 @@ class RaggedDecoder:
             np.array([s.temperature], np.float32),
             np.array([s.top_p], np.float32),
             self.cache, self.cur_tok, self.cfg, prefix)
+        if _TALLY.n != mark:  # the bucket's first call
+            self._note_compiled(_PREFILL, width, mark)
         # NO host sync here: first tokens ride the next chunk's
         # single device_get (a per-admission sync would stall the
         # host until the prefill finished)
@@ -902,10 +979,13 @@ class RaggedDecoder:
         with _fr.span("serve", "engine.decode_dispatch", flush=False,
                       attrs={"active": n_active, "chunk": self.chunk,
                              "depth": 0}):
+            lanes = self._lanes() if self._sampling_seen else None
+            mark = _TALLY.n
             toks, lps, self.cache, self.cur_tok, *touched = decode_chunk(
-                self.params, self.cache, self.cur_tok, active_mask,
-                self._lanes() if self._sampling_seen else None,
+                self.params, self.cache, self.cur_tok, active_mask, lanes,
                 self.cfg, self.chunk)
+            if _TALLY.n != mark:
+                self._note_compiled(_CHUNK, 0, mark)
         toks, lps, pos_np, firsts = self._readback((toks, lps), touched)
         self._account(*self._deliver_chunk(firsts, pos_np, {
             slot: (toks[slot].tolist(),
@@ -1098,12 +1178,16 @@ class RaggedDecoder:
             with _fr.span("serve", "engine.decode_dispatch", flush=False,
                           attrs={"active": n_active, "chunk": self.chunk,
                                  "depth": depth}):
+                lanes = self._lanes()
+                mark = _TALLY.n
                 toks, lps, counts, self.cache, self.cur_tok, *touched = \
                     decode_chunk_spec(
                         self.params, self.spec_draft_head, self.cache,
-                        self.cur_tok, active_mask, *self._lanes(),
+                        self.cur_tok, active_mask, *lanes,
                         self.cfg, self.chunk, depth,
                         self.spec_draft_layers)
+                if _TALLY.n != mark:
+                    self._note_compiled(_CHUNK_SPEC, 0, mark)
             toks, lps, counts, pos_np, firsts = self._readback(
                 (toks, lps, counts), touched)
             emitted = {}
@@ -1157,7 +1241,7 @@ class RaggedDecoder:
         # the old tree goes first: beside it, the incoming masters and
         # their cast do not fit a chip that holds a 1.9 B model
         self.params = None
-        self.params = adopt_weights(self.cfg, params, version)
+        self.params = self._adopt(self.cfg, params, version)
         self.weights_version = int(version)
         if self.prefix_cache is not None:
             self.prefix_cache.clear()

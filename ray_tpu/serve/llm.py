@@ -137,53 +137,105 @@ class LLMServer:
                  spec_draft_head: bool = False):
         import os
 
+        import jax
+
         from ray_tpu._private import accelerator
-        from ray_tpu.models.decode_engine import RaggedDecoder
+        from ray_tpu.models.decode_engine import RaggedDecoder, _nbytes
 
-        # before the first compile: a replica granted a chip must be on
-        # it (raises otherwise), and the compile cache gets its place
-        accelerator.claim_device()
-        params, cfg = build_model(
-            model_size, max_len=max_len, vocab_size=vocab_size,
-            seed=seed, params_blob=params_blob)
-        prefix_cache = None
-        if prefix_cache_block > 0:
-            from ray_tpu.models.kv_prefix_cache import PrefixCache
+        # the bring-up in spans, flushed (once a replica's life):
+        # ``serve.replica_start`` from here to the pump thread's start,
+        # its ``process_age_ms`` everything before this line (spawn,
+        # interpreter, imports, the actor's creation)
+        t_init = time.monotonic()
+        with _fr.span("serve", "serve.replica_start", attrs={
+                "process_age_ms": round(1e3 * _fr.process_age_s(), 1)}) as rs:
+            # before the first compile: a replica granted a chip must be on
+            # it (raises otherwise), and the compile cache gets its place
+            with _fr.span("serve", "serve.claim_device") as sp:
+                claim = accelerator.claim_device()
+                sp.update({k: claim[k]
+                           for k in ("waited_ms", "platform", "count")})
+            t_claimed, mark = time.monotonic(), accelerator.compile_mark()
+            with _fr.span("serve", "serve.weights_build") as sp:
+                # (waited for: a draw still running on the device would be
+                # charged to the engine's cast, which waits next)
+                params, cfg = build_model(
+                    model_size, max_len=max_len, vocab_size=vocab_size,
+                    seed=seed, params_blob=params_blob)
+                sp["bytes"] = _nbytes(jax.block_until_ready(params))
+                sp.update(accelerator.compile_since(mark) or {})
+            t_built = time.monotonic()
+            prefix_cache = None
+            if prefix_cache_block > 0:
+                from ray_tpu.models.kv_prefix_cache import PrefixCache
 
-            prefix_cache = PrefixCache(
-                block=prefix_cache_block,
-                max_bytes=prefix_cache_mb * 2**20)
-        draft_layers, draft_head = build_spec_draft(
-            cfg, draft_layers=spec_draft_layers,
-            draft_head=spec_draft_head, seed=seed)
-        self.engine = RaggedDecoder(
-            params, cfg, slots=slots, max_len=max_len,
-            chunk_tokens=chunk_tokens, prompt_buckets=prompt_buckets,
-            prefix_cache=prefix_cache, chunk_delay_s=chunk_delay_s,
-            name=engine_name or f"llm-{os.getpid()}",
-            weights_version=weights_version,
-            spec_depth=spec_depth, spec_draft_layers=draft_layers,
-            spec_draft_head=draft_head)
-        del params  # the engine holds its serving cast, nobody the masters
-        # (host params tree, version) staged by update_weights(); the
-        # pump thread adopts it at the next chunk boundary — engine
-        # params are touched only by the pump owner
-        self._pending_weights: tuple | None = None
-        self._trace_dir: str | None = None  # a capture's, while it runs
-        self._lock = threading.Lock()
-        self._done_events: dict[int, threading.Event] = {}
-        # sids being consumed via poll_stream: the pump must NOT purge
-        # their finished entries (no _done_events waiter is registered)
-        self._stream_sids: dict[int, float] = {}  # sid -> last poll
-        # poll RPCs served (single + batched): the batching test's
-        # falsifiability counter — N streams should NOT mean N RPCs/tick
-        self._poll_rpcs = 0
-        self._stop = False
-        self._draining = False
-        self._pump_thread = threading.Thread(
-            target=self._pump_loop, daemon=True,
-            name="llm-decode-pump")
-        self._pump_thread.start()
+                prefix_cache = PrefixCache(
+                    block=prefix_cache_block,
+                    max_bytes=prefix_cache_mb * 2**20)
+            draft_layers, draft_head = build_spec_draft(
+                cfg, draft_layers=spec_draft_layers,
+                draft_head=spec_draft_head, seed=seed)
+            self.engine = RaggedDecoder(
+                params, cfg, slots=slots, max_len=max_len,
+                chunk_tokens=chunk_tokens, prompt_buckets=prompt_buckets,
+                prefix_cache=prefix_cache, chunk_delay_s=chunk_delay_s,
+                name=engine_name or f"llm-{os.getpid()}",
+                weights_version=weights_version,
+                spec_depth=spec_depth, spec_draft_layers=draft_layers,
+                spec_draft_head=draft_head)
+            del params  # the engine holds its serving cast, nobody the masters
+            # (host params tree, version) staged by update_weights(); the
+            # pump thread adopts it at the next chunk boundary — engine
+            # params are touched only by the pump owner
+            self._pending_weights: tuple | None = None
+            self._trace_dir: str | None = None  # a capture's, while it runs
+            self._lock = threading.Lock()
+            self._done_events: dict[int, threading.Event] = {}
+            # sids being consumed via poll_stream: the pump must NOT purge
+            # their finished entries (no _done_events waiter is registered)
+            self._stream_sids: dict[int, float] = {}  # sid -> last poll
+            # poll RPCs served (single + batched): the batching test's
+            # falsifiability counter — N streams should NOT mean N RPCs/tick
+            self._poll_rpcs = 0
+            self._stop = False
+            self._draining = False
+            self._pump_thread = threading.Thread(
+                target=self._pump_loop, daemon=True,
+                name="llm-decode-pump")
+            self._pump_thread.start()
+            rs["engine"] = self.engine.name
+        # what the bring-up cost, kept for ``stats()["setup"]`` and the
+        # ``serve.setup`` mark (:meth:`setup_record`); the stamps are
+        # ``time.monotonic`` in ns, one clock for every process of a
+        # Linux machine
+        t_ready = time.monotonic()
+        ms = lambda a, b: round(1e3 * (b - a), 3)  # noqa: E731
+        self._setup = {
+            "process_age_ms": rs["process_age_ms"],
+            "claim_ms": ms(t_init, t_claimed),
+            "chip_wait_ms": claim["waited_ms"],
+            "weights_build_ms": ms(t_claimed, t_built),
+            "weights_cast_ms": self.engine.weights_cast_ms,
+            "replica_start_ms": ms(t_init, t_ready),
+            "init_mono_ns": int(t_init * 1e9),
+            "ready_mono_ns": int(t_ready * 1e9)}
+
+    def setup_record(self) -> dict:
+        """What this replica's bring-up cost and what its programs' first
+        calls have cost so far, all plain numbers: the parts of
+        ``serve.replica_start`` (``__init__``), the sums over the
+        engine's ``engine.compiled`` marks (``first_calls``, ``trace_ms``,
+        ``lower_ms``, ``compile_ms``, ``cache_read_ms``, ``first_call_ms``,
+        ``compile_requests``, ``cache_hits``, ``last_compile_mono_ns``)
+        and the process's own cache counters
+        (``proc_compile_requests``, ``proc_cache_hits``): whether a cold
+        replica's programs came from the persistent cache."""
+        from ray_tpu._private import accelerator
+
+        proc = accelerator.compile_report()
+        return {**self._setup, **self.engine.compiled,
+                "proc_compile_requests": proc["requests"],
+                "proc_cache_hits": proc["hits"]}
 
     def _pump_loop(self):
         # engine state is touched ONLY by this thread; handlers interact
@@ -508,6 +560,9 @@ class LLMServer:
         jax.profiler.start_trace(log_dir)
         self._trace_dir = log_dir
         self.engine.mark_state()  # the trace says what engine it is of
+        # ... and what its bring-up cost, minutes before the capture
+        _fr.mark("serve", "serve.setup", flush=False,
+                 attrs=self.setup_record())
         return True
 
     def stop_trace(self) -> bool:
@@ -547,6 +602,7 @@ class LLMServer:
         # platform/kind/count as THIS process sees them, its compile
         # counters and peak device memory
         st["device"] = accelerator.device_report()
+        st["setup"] = self.setup_record()
         return st
 
     def health(self) -> bool:

@@ -106,8 +106,10 @@ class PrefillWorker:
         import jax.numpy as jnp
         import numpy as np
 
+        from ray_tpu._private import accelerator
         from ray_tpu._private import fault_injection as _fi
-        from ray_tpu.models.decode_engine import prefill_kv
+        from ray_tpu.models.decode_engine import (PREFILL_KV, note_compiled,
+                                                  prefill_kv)
 
         # chaos site: prefill-worker death / stall mid-prefill
         _fi.fire("serve.prefill", worker=self.name)
@@ -120,13 +122,15 @@ class PrefillWorker:
                 f"bucket {self.buckets[-1]}")
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(prompt)] = prompt
-        k, v, toks0, logp0 = prefill_kv(
-            self.params, jnp.asarray(padded),
-            jnp.asarray([len(prompt)], jnp.int32),
-            jnp.asarray([int(seed) & 0xFFFFFFFF], jnp.uint32),
-            jnp.asarray([float(temperature)], jnp.float32),
-            jnp.asarray([float(top_p)], jnp.float32), self.cfg,
-            self.max_len)
+        args = (jnp.asarray(padded), jnp.asarray([len(prompt)], jnp.int32),
+                jnp.asarray([int(seed) & 0xFFFFFFFF], jnp.uint32),
+                jnp.asarray([float(temperature)], jnp.float32),
+                jnp.asarray([float(top_p)], jnp.float32))
+        mark = accelerator.tally.n  # (a thread-local's attribute: no call)
+        k, v, toks0, logp0 = prefill_kv(self.params, *args, self.cfg,
+                                        self.max_len)
+        if accelerator.tally.n != mark:  # the bucket's first call
+            note_compiled(PREFILL_KV, bucket, mark, name=self.name)
         k, v, tok0, lp0 = jax.device_get(
             (k[:, 0], v[:, 0], toks0[0], logp0[0]))
         kv_bytes = int(k.nbytes + v.nbytes)

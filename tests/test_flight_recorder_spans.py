@@ -240,9 +240,13 @@ def test_pool_stamps_reach_the_replica_and_survive_failover(cluster):
     from ray_tpu._private import trace as _trace
     from ray_tpu.serve.llm_pool import LLMPool
 
+    # (a chunk takes 30 ms: the poll that finds the first tokens must
+    # find the stream unfinished, or there is nothing to fail over; six
+    # bare chunks of the tiny model fit between two polls one time in
+    # five)
     pool = LLMPool(model_size="tiny", slots=2, max_len=96, chunk_tokens=4,
                    prompt_buckets=(8, 16), min_replicas=2, max_replicas=2,
-                   prefill_workers=0, autoscale=False)
+                   prefill_workers=0, autoscale=False, chunk_delay_s=0.03)
     try:
         t_proxy = fr.wall(time.monotonic()) - 0.050
         sub = pool.submit_stream({
@@ -292,6 +296,61 @@ def test_pool_stamps_reach_the_replica_and_survive_failover(cluster):
         assert {"admission_wait_ms", "pool_to_replica_ms",
                 "upstream_ms"} <= set(span["attrs"])
         assert "proxy_to_pool_ms" not in span["attrs"]
+    finally:
+        pool.shutdown()
+
+
+def test_a_compile_after_the_warm_up_is_a_named_mark_in_the_timeline(cluster):
+    """What a benchmark's window calls "N compilation(s)": a prompt of a
+    bucket the warm-up left out compiles that bucket's prefill program,
+    and the replica's ``engine.compiled`` mark names it in
+    ``ray_tpu.timeline()`` beside the bring-up's ``serve.replica_start``
+    (ISSUE-56); with no new shape, no mark."""
+    import ray_tpu
+    from ray_tpu.serve.llm_pool import LLMPool
+
+    def compiled():
+        return [e["args"] for e in ray_tpu.timeline()
+                if e.get("name") == "engine.compiled"
+                and e["args"].get("engine") == "decode-1"
+                and e["ts"] >= t_start_us]
+
+    def wait_for(n, timeout=30.0):
+        deadline = time.time() + timeout
+        while len(compiled()) < n and time.time() < deadline:
+            time.sleep(0.25)
+        return compiled()
+
+    t_start_us = 1e6 * fr.wall(time.monotonic())
+    # (shapes of its own: a reused worker process that had run the other
+    # tests' programs would compile nothing)
+    pool = LLMPool(model_size="tiny", slots=2, max_len=88, chunk_tokens=3,
+                   prompt_buckets=(8, 24), min_replicas=1, max_replicas=1,
+                   prefill_workers=0, autoscale=False)
+    try:
+        pool.generate(list(range(1, 6)), 5)  # the warm-up: bucket 8
+        warm = wait_for(2)
+        assert sorted((a["program"], a["bucket"]) for a in warm) == [
+            ("jit__prefill_batch_into_slots", 8), ("jit_decode_chunk", 0)]
+        pool.generate(list(range(1, 7)), 5)  # the same shapes: nothing
+        pool.generate(list(range(1, 12)), 5)  # "inside the window"
+        marks = wait_for(3)
+        assert len(marks) == 3, marks
+        late = [a for a in marks if a not in warm]
+        assert [(a["program"], a["bucket"]) for a in late] == [
+            ("jit__prefill_batch_into_slots", 24)]
+        assert late[0]["since_ready_ms"] > max(
+            a["since_ready_ms"] for a in warm)
+        # the mark count is the number of programs the replica ran
+        rec = next(iter(pool.stats()["per_replica"].values()))["setup"]
+        assert rec["first_calls"] == 3
+        starts = [e for e in ray_tpu.timeline()
+                  if e.get("name") == "serve.replica_start"
+                  and e["args"].get("engine") == "decode-1"
+                  and e["ts"] >= t_start_us]
+        assert len(starts) == 1
+        assert starts[0]["dur"] == pytest.approx(
+            1e3 * rec["replica_start_ms"], rel=0.05)
     finally:
         pool.shutdown()
 
@@ -365,8 +424,10 @@ def test_trace_replicas_captures_device_and_spans_in_one_file(
         cluster, tmp_path):
     from ray_tpu.serve.llm_pool import LLMPool
 
-    pool = LLMPool(model_size="tiny", slots=2, max_len=96, chunk_tokens=4,
-                   prompt_buckets=(8, 16), min_replicas=1, max_replicas=1,
+    # (shapes of its own: a reused worker process that had run the other
+    # tests' programs would compile nothing)
+    pool = LLMPool(model_size="tiny", slots=2, max_len=88, chunk_tokens=3,
+                   prompt_buckets=(8, 24), min_replicas=1, max_replicas=1,
                    prefill_workers=0, autoscale=False)
     try:
         pool.generate(list(range(1, 6)), 5)  # compile outside the capture

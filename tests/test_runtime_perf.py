@@ -339,19 +339,48 @@ def test_recorded_obs_family_floors():
 
 def test_live_span_record_throughput_floor():
     """Ring-only record() (the per-chunk hot-path form) must stay
-    cheap: >= 50k spans/s live, ~16x under the recorded dev-box rate."""
+    cheap: at most RECORD_OVER_BARE times what it is made of, the dict
+    build and the ``deque.append`` under a lock, both timed in this
+    process as the best of five repeats, so that a loaded box (six
+    xdist workers) moves both and not their ratio. Measured 2.5 on the
+    dev box and 2.5 on the chip machine's host (PR 56); a trace-context
+    lookup or a second lock on the path would show as 4 and more."""
+    import collections
+    import threading
     import time as _time
 
     from ray_tpu._private import flight_recorder as fr
 
+    RECORD_OVER_BARE = 8.0
     n = 20_000
     t = _time.monotonic()
     fr.record("bench", "warm", t, t, flush=False)
-    t0 = _time.perf_counter()
-    for _ in range(n):
-        fr.record("bench", "floor", t, t, flush=False)
-    dt = _time.perf_counter() - t0
-    assert n / dt >= 50_000, f"{n / dt:.0f} spans/s"
+
+    def recorded():
+        for _ in range(n):
+            fr.record("bench", "floor", t, t, flush=False)
+
+    ring, lock = collections.deque(maxlen=fr.stats()["ring_cap"]), \
+        threading.Lock()
+
+    def bare():
+        for _ in range(n):
+            span = {"kind": "bench", "name": "floor", "start_s": t + 1.0,
+                    "end_s": t + 1.0, "trace": None, "attrs": {}}
+            with lock:
+                ring.append(span)
+
+    def best_of_five(fn):
+        best = float("inf")
+        for _ in range(5):
+            t0 = _time.perf_counter()
+            fn()
+            best = min(best, _time.perf_counter() - t0)
+        return best
+
+    ratio = best_of_five(recorded) / best_of_five(bare)
+    print(f"record() over its bare parts: {ratio:.2f}")
+    assert ratio <= RECORD_OVER_BARE, f"record() costs {ratio:.1f}x"
     # ring stays bounded regardless of volume
     st = fr.stats()
     assert st["ring_len"] <= st["ring_cap"]
