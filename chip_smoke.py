@@ -1167,12 +1167,13 @@ def kda_kernel_check(widths: str, steps: int, seed: int,
     def both(s0, xs):
         def one(carry, x_t):
             s_kernel, s_body, conv = carry
-            q, k, v, g, beta, _, u = ling._kda_inputs(cfg, p, x_t, conv)
+            q, k, v, g, beta, _, proj = ling._kda_inputs(cfg, p, x_t, conv)
             vectors = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                        active)
             s_kernel, o_kernel = kernel(s_kernel, *vectors)
             s_body, o_body = ks.kda_step(s_body, *vectors, use_kernel=False)
-            return (s_kernel, s_body, u[:, 1:]), (o_kernel, o_body)
+            conv = jnp.concatenate([conv, proj], axis=1)[:, 1:]
+            return (s_kernel, s_body, conv), (o_kernel, o_body)
 
         (s_kernel, s_body, _), (o_kernel, o_body) = jax.lax.scan(
             one, (s0, s0, conv0), xs)
@@ -1213,10 +1214,13 @@ def kda_chunk_check(widths: str, rows: int, seed: int,
     ``kda_chunk`` kernel; with ``interpret`` the kernel in the Pallas
     interpreter), against the XLA body in the same two calls and
     against the recurrence a token at a time. -> the largest relative
-    errors of o and S between each two of the three, and whether the
+    errors of o and S between each two of the three, whether the
     layer's own segment (``solar.kda_segment``) compiles to a program
     with the kernel in it (``in_program``: the backend's and the
-    shape's choice)."""
+    shape's choice), and the same two facts of what makes q, k, v and g
+    (``inputs_rel_err``: ``ops.kda_inputs`` as the backend gives it, on
+    a TPU the ``kda_inputs`` kernel, against its XLA body;
+    ``inputs_in_program``)."""
     import functools
 
     import jax
@@ -1257,16 +1261,34 @@ def kda_chunk_check(widths: str, rows: int, seed: int,
         return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
 
     found = three(x)
+
+    def made():  # (jitted anew: the dispatch is read when it is traced)
+        return jax.jit(lambda x: solar._kda_inputs(
+            cfg, p, x, state["conv"])[:4])(x)
+
+    given = made()
+    as_given = solar._kda_qkvg
+    solar._kda_qkvg = functools.partial(as_given, use_kernel=False)
+    try:
+        from_body = made()
+    finally:
+        solar._kda_qkvg = as_given
     text = jax.jit(functools.partial(solar.kda_segment, cfg, p)).lower(
         x[:, :rows // 2], state, 0, jnp.array([rows])).compile().as_text()
+
+    def in_program(kernel):
+        return any(KERNEL in line and kernel in line.split(" = ")[0]
+                   for line in text.splitlines())
+
     return {"rel_err": {
                 f"{a}_{b}": {"out": rel(found[a][0], found[b][0]),
                              "state": rel(found[a][1], found[b][1])}
                 for a, b in (("kernel", "body"), ("kernel", "recurrence"),
                              ("body", "recurrence"))},
-            "in_program": any(
-                KERNEL in line and "kda_chunk" in line.split(" = ")[0]
-                for line in text.splitlines()),
+            "in_program": in_program("kda_chunk"),
+            "inputs_rel_err": max(rel(a, b)
+                                  for a, b in zip(given, from_body)),
+            "inputs_in_program": in_program("kda_inputs"),
             "rows": rows, "heads": cfg.n_heads,
             "device": accelerator.device_report()}
 
@@ -1580,10 +1602,13 @@ def hybrid_phase(plan: Plan) -> dict:
     check_device(plan, chunk["device"], 1, "kda chunk child")
     check(max(v for pair in chunk["rel_err"].values()
               for v in pair.values()) <= KDA_CHUNK_TOLERANCE
-          and chunk["in_program"] == plan.on_tpu,
+          and chunk["in_program"] == plan.on_tpu
+          and chunk["inputs_rel_err"] <= KDA_CHUNK_TOLERANCE
+          and chunk["inputs_in_program"] == plan.on_tpu,
           "the kda_chunk kernel, the XLA body and the recurrence part on "
-          "a KDA layer's rows, or the layer's segment holds no kernel on "
-          "the chip", got=chunk, tolerance=KDA_CHUNK_TOLERANCE)
+          "a KDA layer's rows, the kda_inputs kernel and its XLA body on "
+          "q, k, v and g, or the layer's segment lacks a kernel on the "
+          "chip", got=chunk, tolerance=KDA_CHUNK_TOLERANCE)
     latent = chip_child(plan, "latent_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.latent_lens),
         "seed": plan.seed})

@@ -23,8 +23,8 @@ are ever sliced out of a stack.
   and where it is differentiated, the XLA body :func:`kda_chunked`) and
   leaves the same ``S`` and the same last ``conv_kernel - 1``
   convolution inputs. No position encoding. A second block runs this
-  code at 64 heads (``models/solar.py``: :func:`kda_qkv`, ``_kda_out``,
-  ``ops/kda_chunk``, ``ops/kda_step``);
+  code at 64 heads (``models/solar.py``: ``ops/kda_inputs``,
+  ``_kda_out``, ``ops/kda_chunk``, ``ops/kda_step``);
   the decay's two forms are told apart in the blocks' ``_kda_inputs``
   (this one's bounded by ``kda_lower_bound``, that one's ``-exp(A_log)
   softplus``, unbounded below), never in the shared functions.
@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.slots import Slots
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
+from ray_tpu.ops.kda_inputs import kda_inputs as _kda_qkvg, kept_rows
 from ray_tpu.ops.kda_chunk import kda_chunked  # noqa: F401 — the chunkwise
 # form's XLA body (the tests' second opinion), under this module's name
 from ray_tpu.ops.kda_step import kda_recurrence  # noqa: F401 — the
@@ -210,48 +211,34 @@ def init_params(cfg: LingConfig, key):
 # KDA
 # --------------------------------------------------------------------------
 
-def kda_qkv(cfg, p, x, conv_rows):
-    """What every KDA layer's q, k and v go through, this block's and
-    ``models/solar.py``'s (``cfg``: ``n_heads``, ``kda_head_dim``,
-    ``conv_kernel``): the projection, the causal depthwise convolution
-    over it and the rows before it, SiLU, the L2 norms. x [B, T, D]
-    (normed); ``conv_rows`` [B, K-1, 3*H*dk]. -> (q, k, v [B, T, H, dk]
-    float32, the projection rows [B, K-1+T, 3*H*dk] whose tail is the
-    next ``conv_rows``)."""
-    b, t, _ = x.shape
-    h, dk = cfg.n_heads, cfg.kda_head_dim
-    f32 = jnp.float32
-    u = jnp.concatenate([conv_rows, x @ p["w_qkv"]], axis=1)
-    w = p["conv"].astype(f32)
-    y = sum(w[i] * u[:, i:i + t].astype(f32)
-            for i in range(cfg.conv_kernel))
-    q, k, v = (a.reshape(b, t, h, dk)
-               for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
-    q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
-        * dk ** -0.5
-    k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-    return q, k, v, u
-
-
 @jax.named_scope("qkv")
-def _kda_inputs(cfg: LingConfig, p, x, conv_rows):
+def _kda_inputs(cfg: LingConfig, p, x, conv_rows, real_rows=None):
     """What both forms of KDA start from. x: [B, T, D] (normed);
     ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
-    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk],
-    beta [B, T, H], the output gate [B, T, H, dk], the projection rows
-    [B, K-1+T, 3*H*dk] whose tail is the next ``conv_rows``)."""
+    first; ``real_rows`` [B]: rows from that index on are padding (g 0).
+    -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk],
+    beta [B, T, H], the output gate [B, T, H, dk], the projection's
+    rows [B, T, 3*H*dk]: behind ``conv_rows`` they hold the next
+    ``conv_rows``). The convolution, the norms and the decay are
+    ``ops.kda_inputs``: one kernel over a prefill's rows on a TPU, the
+    XLA body elsewhere and for a step."""
     b, t, _ = x.shape
     h, dk = cfg.n_heads, cfg.kda_head_dim
     f32 = jnp.float32
-    q, k, v, u = kda_qkv(cfg, p, x, conv_rows)
-    f = jnp.dot(x, p["w_f"], preferred_element_type=f32) + p["dt_bias"]
-    g = cfg.kda_lower_bound * jax.nn.sigmoid(
-        jnp.exp(p["a_log"])[:, None] * f.reshape(b, t, h, dk))
+    proj = x @ p["w_qkv"]
+
+    def f():
+        return jnp.dot(x, p["w_f"], preferred_element_type=f32) \
+            + p["dt_bias"]
+
+    q, k, v, g = _kda_qkvg(proj, conv_rows, p["conv"], f, p["a_log"],
+                           lower_bound=cfg.kda_lower_bound,
+                           real_rows=real_rows)
     beta = jax.nn.sigmoid(jnp.dot(x, p["w_beta"],
                                   preferred_element_type=f32))
     gate = jax.nn.sigmoid(jnp.dot(
         x, p["w_g"], preferred_element_type=f32)).reshape(b, t, h, dk)
-    return q, k, v, g, beta, gate, u
+    return q, k, v, g, beta, gate, proj
 
 
 @jax.named_scope("attn_out")
@@ -270,12 +257,13 @@ def kda_step(cfg: LingConfig, p, x, state, active):
     recurrence is ``ops.kda_step``: on a TPU one kernel that touches
     ``s`` once, in the buffer it lies in; :func:`kda_recurrence` and a
     ``where`` elsewhere."""
-    q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, state["conv"])
+    q, k, v, g, beta, gate, proj = _kda_inputs(cfg, p, x, state["conv"])
     with jax.named_scope("attn/attn_linear"):
         s, o = _kda_step(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                          beta[:, 0], active)
     with jax.named_scope("cache"):
-        new = {"s": s, "conv": jnp.where(active[:, None, None], u[:, 1:],
+        rows = jnp.concatenate([state["conv"], proj], axis=1)[:, 1:]
+        new = {"s": s, "conv": jnp.where(active[:, None, None], rows,
                                          state["conv"])}
     return _kda_out(cfg, p, o[:, None], gate), new
 
@@ -288,10 +276,9 @@ def kda_prefill(cfg: LingConfig, p, x, true_lens):
     h, dk = cfg.n_heads, cfg.kda_head_dim
     kw = cfg.conv_kernel - 1
     zeros = jnp.zeros((b, kw, 3 * h * dk), cfg.compute_dtype)
-    q, k, v, g, beta, gate, u = _kda_inputs(cfg, p, x, zeros)
+    q, k, v, g, beta, gate, proj = _kda_inputs(cfg, p, x, zeros, true_lens)
     with jax.named_scope("attn/attn_linear"):
         real = jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
-        g = jnp.where(real[..., None, None], g, 0.0)
         beta = jnp.where(real[..., None], beta, 0.0)
         pad = -t % cfg.kda_chunk
         if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
@@ -302,10 +289,9 @@ def kda_prefill(cfg: LingConfig, p, x, true_lens):
                           jnp.zeros((b, h, dk, dk), jnp.float32),
                           chunk=cfg.kda_chunk)
     with jax.named_scope("cache"):
-        # the last K-1 projection rows of each prompt: u's rows
-        # true_len .. true_len + K-2 (u starts K-1 rows before the prompt)
-        rows = true_lens[:, None] + jnp.arange(kw)[None, :]
-        conv = jnp.take_along_axis(u, rows[..., None], axis=1)
+        # the last K-1 projection rows of each prompt: rows true_len ..
+        # true_len + K-2 of the K-1 rows before the prompt and its own
+        conv = kept_rows(zeros, proj, true_lens)
     return _kda_out(cfg, p, o[:, :t], gate), {"s": s, "conv": conv}
 
 

@@ -12,9 +12,10 @@ The layers are NOT a stack scanned by one loop: each is its own dict of
 leaves and the programs unroll them.
 
 - **KDA** (Kimi Delta Attention, arXiv:2510.26692; ``fla/layers/kda.py``):
-  what ``models/ling.py`` runs, shared with it (the convolution and the
-  norms ``ling.kda_qkv``, the chunkwise form ``ops/kda_chunk.py``, the
-  output ``ling._kda_out``, the decode step ``ops/kda_step.py``), in
+  what ``models/ling.py`` runs, shared with it (the convolution, the
+  norms and the decay ``ops/kda_inputs.py``, the chunkwise form
+  ``ops/kda_chunk.py``, the output ``ling._kda_out``, the decode step
+  ``ops/kda_step.py``), in
   Kimi Linear's own parametrisation: log decay a channel ``g =
   -exp(A_log_h) * softplus(x W_f_down W_f_up + dt_bias)``, unbounded
   below (Ling's is bounded by its ``kda_lower_bound``: no such key
@@ -80,6 +81,7 @@ from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
+from ray_tpu.ops.kda_inputs import kda_inputs as _kda_qkvg, kept_rows
 from ray_tpu.ops.kda_step import kda_step as _kda_step
 from ray_tpu.ops.norms import rms_norm
 
@@ -226,24 +228,28 @@ def init_params(cfg: SolarConfig, key):
 # --------------------------------------------------------------------------
 
 @jax.named_scope("qkv")
-def _kda_inputs(cfg: SolarConfig, p, x, conv_rows):
+def _kda_inputs(cfg: SolarConfig, p, x, conv_rows, real_rows=None):
     """What both forms of KDA start from. x [B, T, D] (normed);
     ``conv_rows`` [B, K-1, 3*H*dk]: the projection rows before x's
-    first. -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk]
+    first; ``real_rows`` [B]: rows from that index on are padding (g
+    0). -> (q, k, v [B, T, H, dk] float32, log decay g [B, T, H, dk]
     (Kimi Linear's: no lower bound), beta [B, T, H] in (0, 2), the
-    projection rows [B, K-1+T, 3*H*dk] whose tail is the next
-    ``conv_rows``)."""
-    b, t, _ = x.shape
-    h, dk = cfg.n_heads, cfg.kda_head_dim
+    projection's rows [B, T, 3*H*dk]: behind ``conv_rows`` they hold
+    the next ``conv_rows``). The convolution, the norms and the decay
+    are ``ops.kda_inputs``, shared with Ling's block: one kernel over a
+    segment's rows on a TPU, the XLA body elsewhere and for a step."""
     f32 = jnp.float32
-    q, k, v, u = ling.kda_qkv(cfg, p, x, conv_rows)
-    f = jnp.dot(x @ p["w_f_down"], p["w_f_up"],
-                preferred_element_type=f32) + p["dt_bias"]
-    g = -jnp.exp(p["a_log"])[:, None] * jax.nn.softplus(
-        f.reshape(b, t, h, dk))
+    proj = x @ p["w_qkv"]
+
+    def f():
+        return jnp.dot(x @ p["w_f_down"], p["w_f_up"],
+                       preferred_element_type=f32) + p["dt_bias"]
+
+    q, k, v, g = _kda_qkvg(proj, conv_rows, p["conv"], f, p["a_log"],
+                           real_rows=real_rows)
     beta = 2.0 * jax.nn.sigmoid(jnp.dot(x, p["w_beta"],
                                         preferred_element_type=f32))
-    return q, k, v, g, beta, u
+    return q, k, v, g, beta, proj
 
 
 def _kda_out(cfg: SolarConfig, p, x, o):
@@ -268,12 +274,13 @@ def kda_step(cfg: SolarConfig, p, x, state, active):
     """A decode step of a KDA layer. x [B, 1, D] (normed); ``state``
     {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
     is not ``active`` keeps its state. -> ([B, 1, D], state)."""
-    q, k, v, g, beta, u = _kda_inputs(cfg, p, x, state["conv"])
+    q, k, v, g, beta, proj = _kda_inputs(cfg, p, x, state["conv"])
     with jax.named_scope("attn/attn_linear"):
         s, o = _kda_step(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                          beta[:, 0], active)
     with jax.named_scope("cache"):
-        new = {"s": s, "conv": jnp.where(active[:, None, None], u[:, 1:],
+        rows = jnp.concatenate([state["conv"], proj], axis=1)[:, 1:]
+        new = {"s": s, "conv": jnp.where(active[:, None, None], rows,
                                          state["conv"])}
     return _kda_out(cfg, p, x, o[:, None]), new
 
@@ -291,11 +298,10 @@ def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
     chunk to its last; the XLA body ``kda_chunked`` elsewhere.
     -> ([B, T, D], state)."""
     t = x.shape[1]
-    kw = cfg.conv_kernel - 1
-    q, k, v, g, beta, u = _kda_inputs(cfg, p, x, state["conv"])
+    q, k, v, g, beta, proj = _kda_inputs(cfg, p, x, state["conv"],
+                                         true_lens - start)
     with jax.named_scope("attn/attn_linear"):
         real = start + jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
-        g = jnp.where(real[..., None, None], g, 0.0)
         beta = jnp.where(real[..., None], beta, 0.0)
         pad = -t % cfg.kda_chunk
         if pad:  # (a bucket narrower than a chunk: the CPU rehearsal's)
@@ -305,12 +311,12 @@ def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
         o, s = _kda_chunk(q, k, v, g, beta, state["s"],
                           chunk=cfg.kda_chunk)
     with jax.named_scope("cache"):
-        # u's row j is position start - (K-1) + j: the last K-1 real
-        # rows are j = n .. n + K-2 for n = the real rows in or before
-        # this segment; a prompt that ended earlier keeps what it had
-        n = jnp.clip(true_lens - start, 0, t)
-        rows = n[:, None] + jnp.arange(kw)[None, :]
-        conv = jnp.take_along_axis(u, rows[..., None], axis=1)
+        # row j of the K-1 rows before the segment and its own is
+        # position start - (K-1) + j: the last K-1 real rows are j = n
+        # .. n + K-2 for n = the real rows in or before this segment; a
+        # prompt that ended earlier keeps what it had
+        conv = kept_rows(state["conv"], proj,
+                         jnp.clip(true_lens - start, 0, t))
     return _kda_out(cfg, p, x, o[:, :t]), {"s": s, "conv": conv}
 
 

@@ -233,6 +233,38 @@ def _kda_chunk_calls(text: str, prefetched_ok: bool = False) -> list:
     return calls
 
 
+def _kda_inputs_calls(text: str, kda_chunk_calls: list) -> list:
+    """The ``kda_inputs`` kernel's calls in a compiled prefill program
+    (``ops/kda_inputs.py``: one a KDA layer, beside ``kda_chunk`` and
+    feeding it), checked for what the kernel is for: each stands under
+    the ``qkv`` scope (where ``part_reduce`` charges it), takes the
+    projection's rows three times over as they come out of the product
+    (no ``copy`` of them, no concatenation with the rows before),
+    and its four results go into a ``kda_chunk`` call as they are."""
+    lines = text.splitlines()
+    calls = [ln for ln in lines
+             if KERNEL in ln and "kda_inputs" in ln.split(" = ")[0]]
+    made_by = _made_by(lines)
+    fed = set()
+    for call in kda_chunk_calls:
+        operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+        for o in re.findall(r"%[\w.\-]+", operands)[:4]:
+            assert made_by.get(o) == "get-tuple-element", (o, made_by.get(o))
+            fed.add(re.search(re.escape(o) + r" = \S+ get-tuple-element\("
+                              r"(%[\w.\-]+)\)", text).group(1))
+    for call in calls:
+        assert re.search(r"/qkv/(jit\(_kda_inputs\)/)?kda_inputs/"
+                         "pallas_call", call), call[:300]
+        operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+        operands = re.findall(r"%[\w.\-]+", operands)
+        assert len(operands) == 8, operands
+        moved = {o: made_by.get(o) for o in operands[1:4] if made_by.get(o)
+                 in ("copy", "concatenate", "transpose", "pad")}
+        assert not moved, moved
+        assert re.match(r"\s*(%[\w.\-]+) = ", call).group(1) in fed
+    return calls
+
+
 def _computations(text: str) -> dict:
     """A compiled program's text -> {computation: its lines}."""
     computations, lines = {}, None

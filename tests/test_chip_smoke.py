@@ -315,6 +315,9 @@ def test_kda_chunk_check_rehearses_on_the_cpu():
         assert set(pair) == {"out", "state"}
         assert 0 < max(pair.values()) <= chip_smoke.KDA_CHUNK_TOLERANCE
     assert found["in_program"] is False and found["heads"] == 4
+    # (narrow heads off a TPU: what makes q, k, v and g is its XLA body)
+    assert found["inputs_rel_err"] == 0.0
+    assert found["inputs_in_program"] is False
     same = chip_smoke.kda_chunk_check("tiny", 16, TINY.seed)
     assert same["rel_err"]["kernel_body"] == {"out": 0.0, "state": 0.0}
 

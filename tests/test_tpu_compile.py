@@ -90,11 +90,15 @@ def dump_serving_programs(out_dir: str) -> None:
         ling._kda_step = functools.partial(ling._kda_step, use_kernel=True)
     if hasattr(ling, "_kda_chunk"):  # (a parent before PR 47)
         ling._kda_chunk = functools.partial(ling._kda_chunk, use_kernel=True)
+    if hasattr(ling, "_kda_qkvg"):  # (a parent before PR 57)
+        ling._kda_qkvg = functools.partial(ling._kda_qkvg, use_kernel=True)
     try:
         from ray_tpu.models import solar
         solar._kda_step = ling._kda_step
         if hasattr(ling, "_kda_chunk"):
             solar._kda_chunk = ling._kda_chunk
+        if hasattr(ling, "_kda_qkvg"):
+            solar._kda_qkvg = ling._kda_qkvg
     except ImportError:  # (a parent before PR 42)
         pass
     try:

@@ -13,7 +13,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _kda_chunk_calls, KERNEL, _lower_prefill, _mem, MIB, _on, topo)
+    _kda_chunk_calls, _kda_inputs_calls, KERNEL, _lower_prefill, _mem, MIB,
+    _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -31,6 +32,8 @@ def _ling_cell(topo, monkeypatch):
         ling._kda_step, use_kernel=True))
     monkeypatch.setattr(ling, "_kda_chunk", functools.partial(
         ling._kda_chunk, use_kernel=True))
+    monkeypatch.setattr(ling, "_kda_qkvg", functools.partial(
+        ling._kda_qkvg, use_kernel=True))
     with open("benchmark/traffic/reason-saturated.json") as f:
         eng = json.load(f)["engine"]
     fam, m = manifest.model("ling-3.0-flash-vl-ep4-1chip")
@@ -70,6 +73,9 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
     assert len(kda_calls) == sum(
         cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
     assert text.count(KERNEL) == 3 * cfg.moe_layers + len(kda_calls) == 24
+    # (a step's one row takes the XLA body of ``ops.kda_inputs`` even
+    # where the kernel is asked for: the chunk's text is the parent's)
+    assert not re.search(r"%\S*kda_inputs\S* = ", text)
     for dims in (f"f32[{slots},32,128,128]", f"bf16[{slots},{max_len},512]"):
         assert dims in text
         assert not re.search(re.escape(dims) + r"\S* copy\(", text), dims
@@ -123,17 +129,23 @@ def test_ling_prefill_holds_one_kda_chunk_call_a_kda_layer(
                               (params, state, vec)).compile()
     # (at 256 and 512 rows XLA prefetches a layer's 4 to 8 MB ``g`` into
     # VMEM ahead of two of the calls; at 1,024 nothing moves)
-    calls = _kda_chunk_calls(compiled.as_text(), prefetched_ok=bucket < 1024)
+    text = compiled.as_text()
+    calls = _kda_chunk_calls(text, prefetched_ok=bucket < 1024)
     assert len(calls) == sum(
         cfg.attn_kind(i) == "kda" for i in range(cfg.n_layers)) == 6
     assert all(f"f32[1,{bucket},4096]" in c for c in calls), calls[0][:300]
+    # each fed by one ``kda_inputs`` call on the product's rows
+    inputs = _kda_inputs_calls(text, calls)
+    assert len(inputs) == 6
+    assert all(f"bf16[1,{bucket},12288]" in c for c in inputs), \
+        inputs[0][:300]
     mem = compiled.memory_analysis()
     print(f"\nling prefill 1 x {bucket}: {_mem(compiled)}")
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 13 * 1024 * MIB), _mem(compiled)
 
 
-def test_lowering_lings_prefill_traces_the_kda_chunk_kernel_once(
+def test_lowering_lings_prefill_traces_the_kda_kernels_once(
         topo, monkeypatch):
     """What the kernel costs a process's start is its trace
     (``ops/kda_chunk.py``: a thousand lines of columns, seconds each):
@@ -143,16 +155,21 @@ def test_lowering_lings_prefill_traces_the_kda_chunk_kernel_once(
     layers call. (Traced a layer, Ling's set-up read 120-160 s for the
     parent's 80-88: ``PERF.md`` §6, PRs 45-47.)"""
     from ray_tpu.ops import kda_chunk as kc
+    from ray_tpu.ops import kda_inputs as ki
 
     fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
     traced = []
-    body = kc._kernel
-    monkeypatch.setattr(kc, "_kernel", lambda *a, **kw: (
-        traced.append(kw), body(*a, **kw))[1])
+    for module in (kc, ki):
+        monkeypatch.setattr(module, "_kernel", lambda *a, _body=module._kernel,
+                            **kw: (traced.append(kw), _body(*a, **kw))[1])
     jax.clear_caches()  # (an earlier test's trace of this shape)
     lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 1024,
                              (params, state, vec))
-    assert traced == [{"hb": 16}], len(traced)
+    assert sorted(traced, key=len) == [
+        {"hb": 16}, {"hb": 8, "dk": 128, "lower_bound": cfg.kda_lower_bound}]
     text = lowered.as_text()
-    assert len(re.findall(r"func\.func private @_kda_chunk\w*\(", text)) == 1
-    assert len(re.findall(r"call @_kda_chunk\w*\(", text)) == 6
+    # (``ops/kda_inputs.py``'s call is jitted by itself for the same
+    # reason: one private function, which the six layers call)
+    for fn in ("_kda_chunk", "_kda_inputs"):
+        assert len(re.findall(rf"func\.func private @{fn}\w*\(", text)) == 1
+        assert len(re.findall(rf"call @{fn}\w*\(", text)) == 6

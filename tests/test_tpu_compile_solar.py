@@ -14,7 +14,8 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _dead_branch_hands_on_and_makes_zeros, _kda_chunk_calls, KERNEL,
+    _dead_branch_hands_on_and_makes_zeros, _kda_chunk_calls,
+    _kda_inputs_calls, KERNEL,
     _lower_prefill, _mem, MIB, _on,
     _loops_add_nothing_unscoped, _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
@@ -39,6 +40,8 @@ def _solar_cell(topo, monkeypatch):
         solar._kda_step, use_kernel=True))
     monkeypatch.setattr(solar, "_kda_chunk", functools.partial(
         solar._kda_chunk, use_kernel=True))
+    monkeypatch.setattr(solar, "_kda_qkvg", functools.partial(
+        solar._kda_qkvg, use_kernel=True))
     with open("benchmark/traffic/longreason-saturated.json") as f:
         eng = json.load(f)["engine"]
     fam, m = manifest.model("solar-open2-250b-ep8-1chip")
@@ -81,6 +84,9 @@ def test_solar_decode_chunk_keeps_both_kinds_of_state_where_they_lie(
     assert sum("decode_attn" in c.split(" = ")[0] for c in calls) \
         == cfg.full_layers == 1
     assert len(calls) == 3 + 1 + 3 * cfg.n_layers == 16
+    # (a step's one row takes the XLA body of ``ops.kda_inputs`` even
+    # where the kernel is asked for: the chunk's text is the parent's)
+    assert not re.search(r"%\S*kda_inputs\S* = ", text)
     s_dims = f"f32[{slots},64,128,128]"
     for line in kda_calls:
         assert line.split(" = ")[1].startswith(f"({s_dims}"), line
@@ -125,7 +131,8 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     the chip's 16 GiB (temporaries 3,213 MiB: the stream in and out of
     a layer and the GQA layer's q and o in both layouts, 537 MB each);
     the chunkwise delta rule is ONE ``kda_chunk`` call a KDA layer
-    inside its segment scan (``_kda_chunk_calls``)."""
+    inside its segment scan (``_kda_chunk_calls``), fed by ONE
+    ``kda_inputs`` call (``_kda_inputs_calls``)."""
     from ray_tpu.models import solar
 
     fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
@@ -140,6 +147,15 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     assert len(calls) == cfg.kda_layers == 3
     assert all("/while/body/" in c and "f32[1,2048,8192]" in c
                for c in calls), calls[0][:300]
+    # and what makes its q, k, v and g ONE ``kda_inputs`` call before it,
+    # on the product's rows as they lie: no row array of a segment with
+    # the three rows before it laid in front exists
+    inputs = _kda_inputs_calls(text, calls)
+    assert len(inputs) == cfg.kda_layers == 3
+    assert all("/while/body/" in c and "bf16[1,2048,24576]" in c
+               and c.split(" = ")[1].startswith("(f32[1,2048,8192]")
+               for c in inputs), inputs[0][:300]
+    assert "2051,24576" not in text
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))
               for dt, dims in re.findall(r"\b(f32|bf16|s32)\[([\d,]+)\]",
                                          text)}
@@ -155,9 +171,11 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
         solar.SLOTS.state_bytes(state).values()), _mem(compiled)
     print(f"\nsolar prefill 1 x 32768: {_mem(compiled)} (temporaries "
           "with the XLA body, PR 42: 3,213 MiB)")
-    assert mem.temp_size_in_bytes < 3584 * MIB, _mem(compiled)
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            < 14.5 * 1024 * MIB), _mem(compiled)
+    # (no larger than before the ``kda_inputs`` kernel: 3,215.4 MiB of
+    # temporaries beside 11,061.9 MiB of arguments on PR 56's tree,
+    # 3,213.4 with it)
+    assert mem.temp_size_in_bytes <= 3216 * MIB, _mem(compiled)
+    assert mem.argument_size_in_bytes <= 11062 * MIB, _mem(compiled)
 
 
 def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
@@ -169,23 +187,25 @@ def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     ``true_lens`` (``moe.in_segments`` with ``live``). The dead branch
     hands the carry on and makes zeros, nothing else: no kernel, no
     fusion, no copy of the ``S`` it carries, and no loop copies it
-    either; ``kda_chunk`` is called once a KDA layer in the live branch
-    and its body is traced once for the three; the branch lands nothing
+    either; ``kda_chunk`` and ``kda_inputs`` are called once a KDA layer
+    in the live branch and each body is traced once for the three; the branch lands nothing
     in ``unscoped`` (three instructions on the parent, ``PERF.md`` §6 PR
     51)."""
     from ray_tpu.models import solar
     from ray_tpu.ops import kda_chunk as kc
+    from ray_tpu.ops import kda_inputs as ki
 
     fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
     assert solar.SLOTS.prefill_segments(cfg, 16384) == 8
     traced = []
-    body = kc._kernel
-    monkeypatch.setattr(kc, "_kernel", lambda *a, **kw: (
-        traced.append(kw), body(*a, **kw))[1])
+    for module in (kc, ki):
+        monkeypatch.setattr(module, "_kernel", lambda *a, _body=module._kernel,
+                            _of=module.__name__, **kw: (
+            traced.append(_of), _body(*a, **kw))[1])
     jax.clear_caches()  # (an earlier test's trace of this shape)
     lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
                              (params, state, vec))
-    assert len(traced) == 1, traced
+    assert sorted(traced) == [kc.__name__, ki.__name__], traced
     text = lowered.compile().as_text()
     branches = _segment_branches(text)
     assert len(branches) == text.count(" while(") \
@@ -200,4 +220,7 @@ def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     calls = _kda_chunk_calls(text)
     assert len(calls) == 3 and all(
         "/while/body/closed_call/cond/" in c for c in calls)
+    inputs = _kda_inputs_calls(text, calls)
+    assert len(inputs) == 3 and all(
+        "/while/body/closed_call/cond/" in c for c in inputs)
     _loops_add_nothing_unscoped(text)
