@@ -107,6 +107,10 @@ class Plan:
     # and one Mamba-2 layer of models/granite.py at the published widths
     # (128 heads of 64 x 128): a prompt in one segment and in two
     ssm_lens: tuple = (200, 4096)
+    # and the four kernels of ops/dsa.py at the published widths of
+    # models/dots.py (64 index heads of 128, 128 heads of 128 + 64 / 128
+    # over latent rows of 640): this many query rows over twice the keys
+    dsa_rows: int = 2048
 
     @staticmethod
     def tiny(**kw) -> "Plan":
@@ -116,7 +120,7 @@ class Plan:
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
                     kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21),
-                    ssm_lens=(9, 21),
+                    ssm_lens=(9, 21), dsa_rows=128,
                     kda_chunk_rows=32)
         return Plan(**{**base, **kw})
 
@@ -1568,6 +1572,87 @@ def ssm_check(widths: str, lens: list, seed: int,
             "device": accelerator.device_report()}
 
 
+DSA_KERNEL_TOLERANCE = 2e-2  # bf16 outputs of either form, relative
+
+
+def dsa_check(widths: str, rows: int, seed: int,
+              interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: the four kernels of
+    ``ops/dsa.py`` at the eighth block's widths (``models/dots.py``), in
+    the compute type, each against its XLA body: ``rows`` query rows at
+    offset ``rows`` over ``2 * rows`` keys through ``dsa_index`` (every
+    causal score), ``dsa_kth`` (the selected SETS must be equal: the
+    selection is exact) and ``dsa_attn`` (the masked flash kernel over
+    eight heads), and four slots' decode step over ``2 * rows`` latent
+    rows through ``dsa_decode_attn``, one slot inactive (zeros, bit for
+    bit). With ``interpret`` the kernels run in the Pallas interpreter.
+    -> relative errors, whether the sets are equal, the rows chosen."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import dots
+    from ray_tpu.ops import dsa
+
+    accelerator.claim_device()
+    cfg = dots.DotsConfig.tiny(dtype="bfloat16") if widths == "tiny" \
+        else dots.DotsConfig()
+    k = cfg.kind(False)
+    hi, di, dt = cfg.index_heads, cfg.index_head_dim, cfg.compute_dtype
+    top = min(cfg.index_topk, rows)
+    keys, blocks = 2 * rows, min(128, rows)
+    how = {"interpret": True, "block_q": blocks, "block_k": blocks} \
+        if interpret else {"use_kernel": True}
+    rng = iter(jax.random.split(jax.random.PRNGKey(seed), 12))
+
+    def rel(a, b, where=True):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.where(where, jnp.abs(a - b), 0))
+                     / jnp.max(jnp.abs(b)))
+
+    q_i = jax.random.normal(next(rng), (1, rows, hi, di), dt)
+    w = jax.random.normal(next(rng), (1, rows, hi), jnp.float32)
+    k_i = jax.random.normal(next(rng), (1, keys, di), dt)
+    causal = (jnp.arange(keys)[None, :]
+              <= jnp.arange(rows)[:, None] + rows)[None]
+    scores = jax.jit(lambda *a: dsa.index_scores(*a, rows, **how))(
+        q_i, w, k_i)
+    body = dsa.index_scores_xla(q_i, w, k_i)
+    select = {"interpret": True} if interpret else {"use_kernel": True}
+    chosen = jax.jit(lambda s: dsa.select(s, causal, top, **select))(body)
+    sets_equal = bool(jnp.array_equal(
+        chosen, dsa.select(body, causal, top, use_kernel=False)))
+    bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+    qkv = (jax.random.normal(next(rng), (1, 8, rows, k.dn), dt),
+           jax.random.normal(next(rng), (1, 8, rows, k.dr), dt),
+           jax.random.normal(next(rng), (1, 8, keys, k.dn), dt),
+           jax.random.normal(next(rng), (1, keys, k.dr), dt),
+           jax.random.normal(next(rng), (1, 8, keys, k.dv), dt), bias)
+    scale = (k.dn + k.dr) ** -0.5
+    attn = jax.jit(lambda *a: dsa.masked_attention(
+        *a, rows, scale=scale, **how))(*qkv)
+    lengths = jnp.array([keys, 0, keys - 5, 3], jnp.int32)
+    valid = jnp.arange(keys)[None, :] < lengths[:, None]
+    step_bias = jnp.where(dsa.select(
+        jax.random.normal(next(rng), (4, keys)), valid, top,
+        use_kernel=False), 0.0, dsa.NEG).astype(jnp.bfloat16)
+    stack = jax.random.normal(next(rng), (2, 4, keys, k.row_width), dt)
+    q_row = jax.random.normal(next(rng), (4, k.heads, k.row_width), dt)
+    step = jax.jit(lambda *a: dsa.decode_attention_masked(
+        *a, dv=k.kv_lora, scale=scale, block=min(1024, keys), **(
+            {"interpret": True} if interpret else {"use_kernel": True})))(
+        q_row, stack, 1, lengths, step_bias)
+    step_body = dsa.attend_latent_masked(q_row, stack[1], lengths,
+                                         step_bias, k.kv_lora, scale)
+    return {"rel_err": {
+                "dsa_index": rel(scores, body, causal),
+                "dsa_attn": rel(attn, dsa.masked_attention_xla(*qkv, scale)),
+                "dsa_decode_attn": rel(step, step_body)},
+            "sets_equal": sets_equal, "chosen": int(chosen.sum()),
+            "inactive_zero": not bool(jnp.any(step[1] != 0)),
+            "rows": rows, "device": accelerator.device_report()}
+
+
 def hybrid_phase(plan: Plan) -> dict:
     out = chip_child(plan, "hybrid_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.hybrid_lens),
@@ -1640,8 +1725,20 @@ def hybrid_phase(plan: Plan) -> dict:
           "its stepping, the ssd_step kernel from the XLA body, an "
           "inactive slot's state moved, or the layer's step holds no "
           "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
+    sparse = chip_child(plan, "dsa_check", {
+        "widths": plan.hybrid_widths, "rows": plan.dsa_rows,
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, sparse["device"], 1, "dsa child")
+    check(max(sparse["rel_err"].values()) <= DSA_KERNEL_TOLERANCE
+          and sparse["sets_equal"] and sparse["inactive_zero"],
+          "a kernel of ops/dsa.py parts from its XLA body, the selection's "
+          "kernel chose another set than the counting passes, or an "
+          "inactive slot's output is not zeros", got=sparse,
+          tolerance=DSA_KERNEL_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
+            "dsa": {k: sparse[k] for k in ("rel_err", "sets_equal",
+                                           "chosen", "rows")},
             "ssm": {k: ssm[k] for k in ("rel_err", "kernel", "segments",
                                         "inactive_kept", "in_program")},
             "segment": {k: segment[k] for k in ("rel_err", "segments")},

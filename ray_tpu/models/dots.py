@@ -1,0 +1,729 @@
+"""A decoder whose every layer attends through a latent (MLA) with a
+gate a head, in two kinds: FULL layers, where a learned indexer chooses
+the ``index_topk`` rows a query reads (DeepSeek-V3.2-Exp's sparse
+attention), and WINDOW layers with latents, heads and theta of their
+own that see the last ``sliding_window`` rows; a dense MLP first, then a
+sigmoid router over experts of which this device holds a part beside a
+shared one. The language model of dots3-note-prev (``model_type``
+``dots3_note``) as its ``config.json`` gives it; the eighth block.
+
+``layer_pattern[i]`` is 1 for a window layer and 0 for a full one
+(published: layers 0 and 1 full, then three window layers to one full).
+``x`` is a layer's normed input, ``N`` a learned RMS norm:
+
+- **A layer's MLA** (both kinds, each with its own widths): ``c_q = r_q
+  N(x W_qa)``, ``q = c_q W_qb`` as heads of ``[q_n | q_r]``; ``[c | k_r]
+  = x W_kva``, ``c <- r_kv N(c)``; ``q_r``, ``k_r`` rotated (interleaved
+  pairs, the kind's theta), ``k_r`` one for all heads; ``[k_n | v]_h = c
+  W_kvb,h``; scores ``(q_n k_n + q_r k_r) (dn + dr)^-1/2``, float32
+  softmax; ``o_h <- sigmoid(x W_g)_h o_h`` (one gate a head); ``W_o``.
+  ``r_q = (d / q_lora)^1/2`` and ``r_kv = (d / kv_lora)^1/2`` where
+  ``lora_rescale``, carried in the norms' float32 scales.
+- **The indexer** (full layers): ``q_I = c_q W_Iq`` as ``index_heads``
+  heads, ``k_I = LayerNorm(x W_Ik)`` one for all heads, the leading
+  ``qk_rope_head_dim`` numbers of each rotated; ``w = x W_Iw *
+  index_heads^-1/2 * index_head_dim^-1/2``; ``I[t, s] = sum_j w[t, j]
+  relu(q_I[t, j] . k_I[s])`` in float32; query t reads the ``min(index_
+  topk, t + 1)`` positions ``s <= t`` of largest ``I`` (a tie to the
+  earlier): exactly (``ops/dsa.py: select``), no approximation. Until a
+  stream holds more rows than ``index_topk`` the layer is plain causal
+  MLA.
+- **A window layer**: key ``s`` is seen by query ``t`` iff ``0 <= t - s
+  < sliding_window``; no indexer.
+- **MLP**: the dense SwiGLU in the first ``first_k_dense`` layers, then
+  ``models/moe.py``'s expert layer (sigmoid scores, a selection-only
+  bias, one group, ``top_k`` renormalised; the shared expert whole).
+
+A slot's state (:data:`SLOTS`), three stacks of rows: ``lat [L_full,
+slots, max_len, 640]``, a full layer's ``[c | k_r | zeros]`` as
+Instella-MoE's; ``idx [L_full, slots, max_len, index_head_dim]``, the
+indexer's keys, which an indexer reads and nobody attends; ``ring
+[L_win, slots, sliding_window, 1152]``, a window layer's ``[c | k_r |
+zeros]`` written at ``pos % sliding_window`` (the ring holds exactly
+the window: attention over a set of rows does not care for their
+order). A decode step attends ABSORBED: a full layer scores the slot's
+index keys, selects, and attends over the slot's live latent rows with
+the unchosen masked (``ops/dsa.py: decode_attention_masked``, the
+kernel ``dsa_decode_attn``; a gather of the chosen rows was measured
+first and is not the form: ``ops/dsa.py`` says why); a window layer over
+its ring (``decode_attention.attend_latent``).
+
+**Prefill** is one call a cold prompt and every layer one ``lax.scan``
+over segments of ``moe.SEGMENT_ROWS`` rows (``moe.in_segments``; dead
+segments are not run). A full layer's segment writes its latent rows
+and index keys into the layer's rows so far, scores its rows against
+every index key (``dsa.index_scores``), selects, and attends UNABSORBED
+over every earlier key with the unchosen masked (``dsa.
+masked_attention``), ``prefill_head_groups`` groups of heads one after
+another, each group's k and v made from the latents for that call alone
+(128 heads' k and v of 32,768 rows would be 2.7 GB). A window layer's
+segment makes k and v of the rows its band can reach (the segment and
+the one before it) and attends through ``flash_fwd``'s band. Nothing
+``[P, P]`` exists; a segment's ``[rows, P]`` float32 scores do.
+
+Types: matrices in ``dtype`` (bf16), products accumulated in float32;
+norm vectors, the router's bias, the index scores and their selection,
+router scores and softmax statistics float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import moe
+from ray_tpu.models.exaone import ring_rows
+from ray_tpu.models.slots import Slots
+from ray_tpu.ops import decode_attention as _da
+from ray_tpu.ops import dsa
+from ray_tpu.ops.attention import attend_rows
+from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.rope import apply_rotary_interleaved, rotary_embedding
+
+_LANES = 128
+_WINDOW_BLOCK = 512  # the band's flash blocks: 513 is no whole block
+
+
+@dataclasses.dataclass(frozen=True)
+class DotsConfig(moe.HeldExperts):
+    vocab_size: int = 152064
+    d_model: int = 5120
+    n_layers: int = 46
+    # 1 = a window layer, 0 = a full one; () = the published pattern
+    layer_pattern: tuple = ()
+    first_k_dense: int = 1
+    dense_d_ff: int = 13824
+    # mixture of experts: d_ff is ONE expert's width
+    d_ff: int = 1536
+    shared_d_ff: int = 1536
+    n_experts: int = 256
+    top_k: int = 8
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # (first, count): the experts this device holds; None = all of them
+    held_experts: tuple | None = None
+    # a full layer's MLA
+    n_heads: int = 128
+    q_lora_rank: int = 1024
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 8e7
+    # its indexer
+    index_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    index_norm_eps: float = 1e-6
+    # a window layer's MLA
+    window_heads: int = 64
+    window_q_lora_rank: int = 1024
+    window_kv_lora_rank: int = 1024
+    window_qk_nope_head_dim: int = 192
+    window_qk_rope_head_dim: int = 64
+    window_v_head_dim: int = 128
+    window_rope_theta: float = 5e4
+    sliding_window: int = 513
+    lora_rescale: bool = True
+    gated_attention: bool = True  # one sigmoid gate a head
+    rms_eps: float = 1e-5
+    max_seq_len: int = 4096
+    dtype: str = "bfloat16"
+    # None: the backend's choice (the kernels on a TPU)
+    use_flash: bool | None = None
+    # groups of heads a full layer's prefill attends one after another
+    prefill_head_groups: int = 4
+    # the depth the weights are initialised for (init_params); 0 =
+    # n_layers. A configuration cut in depth names its model's own.
+    published_layers: int = 0
+
+    def __post_init__(self):
+        n = self.n_layers
+        attn = tuple(self.layer_pattern) or tuple(
+            int(i > 0 and i % 4 != 1) for i in range(n))
+        if len(attn) != n or set(attn) - {0, 1}:
+            raise ValueError(f"{n} layers need {n} entries of 0 / 1 in "
+                             f"layer_pattern, not {attn}")
+        object.__setattr__(self, "layer_pattern", attn)
+
+    def windowed(self, i: int) -> bool:
+        return bool(self.layer_pattern[i])
+
+    def sparse(self, i: int) -> bool:
+        return i >= self.first_k_dense
+
+    def kind(self, windowed: bool) -> "_Kind":
+        """The widths of a layer of that kind."""
+        if windowed:
+            return _Kind(self.window_heads, self.window_q_lora_rank,
+                         self.window_kv_lora_rank,
+                         self.window_qk_nope_head_dim,
+                         self.window_qk_rope_head_dim,
+                         self.window_v_head_dim, self.window_rope_theta)
+        return _Kind(self.n_heads, self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim, self.rope_theta)
+
+    def stack_index(self, i: int) -> int:
+        """Layer ``i``'s place in the stack of its kind."""
+        return self.layer_pattern[:i].count(self.layer_pattern[i])
+
+    @property
+    def window_layers(self) -> int:
+        return sum(self.layer_pattern)
+
+    @property
+    def full_layers(self) -> int:
+        return self.n_layers - self.window_layers
+
+    @property
+    def moe_layers(self) -> int:
+        return self.n_layers - min(self.first_k_dense, self.n_layers)
+
+    @property
+    def slot_model(self):
+        return SLOTS
+
+    @staticmethod
+    def tiny(**kw) -> "DotsConfig":
+        """Test-size config: both kinds of layer and of MLP, a selection
+        that bites (16 rows of the sequences' hundred) and a window
+        smaller than them; runs on the CPU."""
+        base = dict(
+            vocab_size=256, d_model=64, n_layers=5,
+            layer_pattern=(0, 0, 1, 1, 1), dense_d_ff=160, d_ff=32,
+            shared_d_ff=32, n_experts=16, top_k=4, n_heads=4,
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, rope_theta=1e4,
+            index_heads=4, index_head_dim=16, index_topk=16,
+            window_heads=2, window_q_lora_rank=32, window_kv_lora_rank=32,
+            window_qk_nope_head_dim=24, window_qk_rope_head_dim=8,
+            window_v_head_dim=16, window_rope_theta=1e3, sliding_window=9,
+            prefill_head_groups=2, max_seq_len=256, dtype="float32")
+        base.update(kw)
+        return DotsConfig(**base)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Kind:
+    heads: int
+    q_lora: int
+    kv_lora: int
+    dn: int
+    dr: int
+    dv: int
+    theta: float
+
+    @property
+    def row_width(self) -> int:
+        """What a cache row holds: latent | rotated key, to whole lanes."""
+        return -(-(self.kv_lora + self.dr) // _LANES) * _LANES
+
+
+# --------------------------------------------------------------------------
+# Parameters
+# --------------------------------------------------------------------------
+
+def init_params(cfg: DotsConfig, key):
+    """The tree in the SERVING types, leaf by leaf in blocks
+    (``moe.draw``). K-EXAONE's initialisation and for its reasons
+    (``exaone.init_params``): matrices normal / sqrt(fan_in), every
+    ``w_down`` scaled by (2 x depth)^-1/2 besides (depth is
+    ``published_layers``), the attention's ``wo`` not. The norm scales
+    are drawn around 1, the index key's bias and the router's bias away
+    from 0 and ``w_iw`` as any matrix (its weights of both signs), so
+    that a part left out of a path shows against the reference. The
+    matrices that READ a rescaled latent (``w_qb``, ``w_iq``, ``w_kvb``)
+    are drawn for an input of that RMS, normal / (r sqrt(fan_in)): what
+    they give has the spread every other product's has, as a trained
+    model's would (drawn for a unit input, a full layer's attention
+    logits spread by 6 and its softmax is one row's)."""
+    d = cfg.d_model
+    keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
+    mat, around_one = moe.makers(cfg, keys)
+
+    def reads(rank: int, width: int):  # (a rescaled latent's reader)
+        r2 = d / rank if cfg.lora_rescale else 1.0
+        return moe.draw(next(keys), (rank, width), (rank * r2) ** -0.5,
+                        cfg.compute_dtype)
+
+    def attention(windowed: bool):
+        k = cfg.kind(windowed)
+        p = {"w_qa": mat(d, k.q_lora), "q_norm": around_one(k.q_lora),
+             "w_qb": reads(k.q_lora, k.heads * (k.dn + k.dr)),
+             "w_kva": mat(d, k.kv_lora + k.dr),
+             "kv_norm": around_one(k.kv_lora),
+             "w_kvb": reads(k.kv_lora, k.heads * (k.dn + k.dv)),
+             "wo": mat(k.heads * k.dv, d)}
+        if cfg.gated_attention:
+            p["w_gate"] = mat(d, k.heads)
+        if not windowed:
+            di = cfg.index_head_dim
+            p.update({
+                "w_iq": reads(k.q_lora, cfg.index_heads * di),
+                "w_ik": mat(d, di), "ik_norm": around_one(di),
+                "ik_bias": 0.1 * jax.random.normal(
+                    next(keys), (di,), jnp.float32),
+                "w_iw": mat(d, cfg.index_heads)})
+        return p
+
+    layers = [{
+        "attn_norm": around_one(d), "attn": attention(cfg.windowed(i)),
+        "mlp_norm": around_one(d),
+        "mlp": moe.init_experts(cfg, mat, keys) if cfg.sparse(i)
+        else moe.init_dense(cfg, mat),
+    } for i in range(cfg.n_layers)]
+    return moe.init_model(cfg, mat, around_one, keys, layers)
+
+
+# --------------------------------------------------------------------------
+# A layer's inputs and output
+# --------------------------------------------------------------------------
+
+def _rotation(cfg: DotsConfig, positions, windowed: bool):
+    """(sin, cos) of ``positions`` [B, T] for the kind's rotated part."""
+    k = cfg.kind(windowed)
+    return rotary_embedding(positions, k.dr, k.theta)
+
+
+@jax.named_scope("qkv")
+def _mla_inputs(cfg: DotsConfig, k: _Kind, p, x, rotation):
+    """x [B, T, D] (normed) -> (q_nope [B, T, H, dn], q_rope [B, T, H,
+    dr] rotated, the latent [B, T, r] normalised and rescaled, k_rope
+    [B, T, dr] rotated, the gate [B, T, H] float32 or None, c_q [B, T,
+    q_lora]: what the indexer's queries are made of)."""
+    b, t, d = x.shape
+    r_q = (d / k.q_lora) ** 0.5 if cfg.lora_rescale else 1.0
+    r_kv = (d / k.kv_lora) ** 0.5 if cfg.lora_rescale else 1.0
+    c_q = rms_norm(x @ p["w_qa"], p["q_norm"] * r_q, cfg.rms_eps)
+    q = (c_q @ p["w_qb"]).reshape(b, t, k.heads, k.dn + k.dr)
+    q_rope = apply_rotary_interleaved(q[..., k.dn:], *rotation)
+    kva = x @ p["w_kva"]
+    latent = rms_norm(kva[..., :k.kv_lora], p["kv_norm"] * r_kv,
+                      cfg.rms_eps)
+    k_rope = apply_rotary_interleaved(kva[..., None, k.kv_lora:], *rotation)
+    gate = jax.nn.sigmoid(jnp.dot(
+        x, p["w_gate"], preferred_element_type=jnp.float32)) \
+        if cfg.gated_attention else None
+    return q[..., :k.dn], q_rope, latent, k_rope[..., 0, :], gate, c_q
+
+
+@jax.named_scope("qkv")
+def _index_inputs(cfg: DotsConfig, p, x, c_q, rotation):
+    """-> (q_I [B, T, Hi, di], k_I [B, T, di], w [B, T, Hi] float32):
+    the leading ``qk_rope_head_dim`` numbers of every index query and of
+    the key rotated, the key through a LayerNorm with a bias."""
+    b, t, _ = x.shape
+    hi, di, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    f32 = jnp.float32
+
+    def rotated(a):  # [B, T, H, di]
+        return jnp.concatenate([apply_rotary_interleaved(
+            a[..., :dr], *rotation), a[..., dr:]], axis=-1)
+
+    q_i = rotated((c_q @ p["w_iq"]).reshape(b, t, hi, di))
+    k = jnp.dot(x, p["w_ik"], preferred_element_type=f32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                          + cfg.index_norm_eps)
+    k = (k * p["ik_norm"] + p["ik_bias"]).astype(x.dtype)
+    k_i = rotated(k[..., None, :])[..., 0, :]
+    w = jnp.dot(x, p["w_iw"], preferred_element_type=f32) \
+        * (hi ** -0.5 * di ** -0.5)
+    return q_i, k_i, w
+
+
+def _cache_rows(k: _Kind, latent, k_rope):
+    """[..., r] and [..., dr] -> the rows a slot keeps [..., row_width]."""
+    pad = k.row_width - k.kv_lora - k.dr
+    return jnp.concatenate(
+        [latent, k_rope, jnp.zeros((*latent.shape[:-1], pad), latent.dtype)],
+        axis=-1)
+
+
+@jax.named_scope("attn_out")
+def _mla_out(cfg: DotsConfig, p, o, gate):
+    """o [B, T, H, dv], gate [B, T, H] -> [B, T, D]: the gate a head,
+    then ``W_o``."""
+    if gate is not None:
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(
+            cfg.compute_dtype)
+    return o.reshape(*o.shape[:2], -1) @ p["wo"]
+
+
+def _unabsorbed(k: _Kind, p, rows):
+    """Cache rows [B, S, row_width] -> (k [B, H, S, dn + dr], v [B, H, S,
+    dv]) in the flash kernels' layout: every head's k_nope and v out of
+    the latent, the one rotated key beside each head's k_nope."""
+    b, s, _ = rows.shape
+    w_kvb = p["w_kvb"].reshape(k.kv_lora, k.heads, k.dn + k.dv)
+    kv = jnp.einsum("bsr,rhd->bhsd", rows[..., :k.kv_lora], w_kvb,
+                    preferred_element_type=jnp.float32).astype(rows.dtype)
+    k_rope = jnp.broadcast_to(
+        rows[:, None, :, k.kv_lora:k.kv_lora + k.dr], (b, k.heads, s, k.dr))
+    return jnp.concatenate([kv[..., :k.dn], k_rope], axis=-1), kv[..., k.dn:]
+
+
+def _window_attend(cfg: DotsConfig, q, k, v, offset):
+    """The band: q [B, H, T, d] at positions ``offset`` .. of the keys'
+    own numbering, k / v [B, H, S, .]. On a TPU ``flash_fwd``'s band
+    with blocks given (the window is no whole block), else the XLA
+    body."""
+    use_flash = cfg.use_flash
+    if use_flash is None:
+        use_flash = jax.default_backend() == "tpu"
+    if use_flash and q.shape[2] % _WINDOW_BLOCK == 0 \
+            and k.shape[2] % _WINDOW_BLOCK == 0:
+        from ray_tpu.ops.flash_attention import flash_fwd
+
+        return flash_fwd(q, k, v, offset=offset, window=cfg.sliding_window,
+                         block_q=_WINDOW_BLOCK, block_k=_WINDOW_BLOCK)
+    return attend_rows(q, k, v, offset=offset, window=cfg.sliding_window,
+                       use_flash=False)
+
+
+# --------------------------------------------------------------------------
+# The model: whole sequences, prefill into a slot's state, a ragged step
+# --------------------------------------------------------------------------
+
+def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
+    """A full layer's attention on a segment's normed rows x [B, seg, D]
+    at positions ``start`` .. -> ([B, seg, D], the layer's rows so far
+    with the segment's written)."""
+    k = cfg.kind(False)
+    b, seg, _ = x.shape
+    t = lat_all.shape[1]
+    at = start + jnp.arange(seg, dtype=jnp.int32)
+    rotation = _rotation(cfg, jnp.broadcast_to(at, (b, seg)), False)
+    q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
+        cfg, k, p, x, rotation)
+    q_i, k_i, w = _index_inputs(cfg, p, x, c_q, rotation)
+    with jax.named_scope("cache"):
+        lat_all = jax.lax.dynamic_update_slice(
+            lat_all, _cache_rows(k, latent, k_rope), (0, start, 0))
+        idx_all = jax.lax.dynamic_update_slice(idx_all, k_i, (0, start, 0))
+    with jax.named_scope("attn/attn_index"):
+        scores = dsa.index_scores(q_i, w, idx_all, start,
+                                  use_kernel=cfg.use_flash)
+        valid = jnp.arange(t, dtype=jnp.int32)[None, :] <= at[:, None]
+        chosen = dsa.select(scores, valid[None], min(cfg.index_topk, t),
+                            use_kernel=cfg.use_flash)
+        bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+    groups = cfg.prefill_head_groups
+    hg = k.heads // groups
+    with jax.named_scope("qkv"):  # (groups of heads first, heads outermost)
+        def grouped(q):  # [B, seg, H, d] -> [G, B, hg, seg, d]
+            return q.reshape(b, seg, groups, hg, -1).transpose(2, 0, 3, 1, 4)
+
+        w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
+            k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
+        lat = lat_all[..., :k.kv_lora]
+        k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
+
+    def group(_, xs):
+        qn_g, qr_g, w_g = xs
+        with jax.named_scope("qkv"):  # (k and v out of the latents)
+            k_g, v_g = (jnp.einsum(
+                "bsr,rhd->bhsd", lat, w, preferred_element_type=jnp.float32
+            ).astype(lat.dtype) for w in (w_g[..., :k.dn], w_g[..., k.dn:]))
+        with jax.named_scope("attn/attn_sparse"):
+            return None, dsa.masked_attention(
+                qn_g, qr_g, k_g, k_r, v_g, bias, start,
+                scale=(k.dn + k.dr) ** -0.5, use_kernel=cfg.use_flash)
+
+    _, o = jax.lax.scan(group, None, (grouped(q_nope), grouped(q_rope),
+                                      w_kvb))
+    with jax.named_scope("attn_out"):  # [G, B, hg, seg, dv] -> [B, seg, H, dv]
+        o = o.transpose(1, 3, 0, 2, 4).reshape(b, seg, k.heads, k.dv)
+    return _mla_out(cfg, p, o, gate), lat_all, idx_all
+
+
+def _window_segment(cfg: DotsConfig, p, x, start, lat_all):
+    """A window layer's attention on a segment's normed rows: k and v of
+    the rows its band can reach (this segment and what the window needs
+    of those before it), attended through the band."""
+    k = cfg.kind(True)
+    b, seg, _ = x.shape
+    t = lat_all.shape[1]
+    at = start + jnp.arange(seg, dtype=jnp.int32)
+    rotation = _rotation(cfg, jnp.broadcast_to(at, (b, seg)), True)
+    q_nope, q_rope, latent, k_rope, gate, _ = _mla_inputs(
+        cfg, k, p, x, rotation)
+    with jax.named_scope("cache"):
+        lat_all = jax.lax.dynamic_update_slice(
+            lat_all, _cache_rows(k, latent, k_rope), (0, start, 0))
+    back = -(-(cfg.sliding_window - 1) // seg) * seg
+    reach = min(t, seg + back)
+    with jax.named_scope("qkv"):
+        lo = jnp.clip(start - back, 0, t - reach)
+        k_b, v_b = _unabsorbed(k, p, jax.lax.dynamic_slice(
+            lat_all, (0, lo, 0), (b, reach, lat_all.shape[2])))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+    with jax.named_scope("attn/attn_window"):
+        o = _window_attend(cfg, q, k_b, v_b, start - lo)
+    return _mla_out(cfg, p, o.transpose(0, 2, 1, 3), gate), lat_all
+
+
+def prefill(params, tokens, true_lens, cfg: DotsConfig, loads: bool = False,
+            live=None):
+    """tokens [B, T] from position 0 (right-padded, ``true_lens`` [B]
+    real), every layer in segments of ``moe.segment_rows`` rows (module
+    docstring) -> (h [B, T, D] before the final norm, every layer's rows
+    as the cache keeps them: a full layer's (latent rows [B, T, 640],
+    index keys [B, T, di]), a window layer's (ring [B, window, 1152] of
+    the last real rows,), and with ``loads`` (the held experts'
+    assignments from the real positions [L_moe, count], the expert
+    layer's calls and compact calls [2]), else None). ``live`` as
+    ``mimo.prefill``'s: the dead segments are not run."""
+    b, t = tokens.shape
+    seg = moe.segment_rows(t)
+    cdt = cfg.compute_dtype
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
+    rows, counts = [], []
+    for i, p in enumerate(params["layers"]):
+        windowed, sparse = cfg.windowed(i), cfg.sparse(i)
+        count_loads = loads and sparse
+
+        def layer(carry, xs, p=p, windowed=windowed, sparse=sparse,
+                  count_loads=count_loads):
+            *kept, count = carry
+            start, h_seg = xs
+            with jax.named_scope("qkv"):
+                x = rms_norm(h_seg, p["attn_norm"], cfg.rms_eps)
+            if windowed:
+                a, *kept = _window_segment(cfg, p["attn"], x, start, *kept)
+            else:
+                a, *kept = _full_segment(cfg, p["attn"], x, start, *kept)
+            with jax.named_scope("attn_out"):
+                h_seg = h_seg + a
+            aux = {} if count_loads else None
+            h_seg = moe.mlp_layer(cfg, sparse, p, h_seg, aux)
+            if count_loads:
+                count = jax.tree_util.tree_map(jnp.add, count, (
+                    moe.prefill_loads(cfg, aux["expert_ids"][None],
+                                      true_lens - start)[0],
+                    moe.compact_calls([aux])))
+            return (*kept, count), h_seg
+
+        widths = (cfg.kind(True).row_width,) if windowed \
+            else (cfg.kind(False).row_width, cfg.index_head_dim)
+        empty = (*(jnp.zeros((b, t, w), cdt) for w in widths),
+                 (jnp.zeros((cfg.held[1],), jnp.int32),
+                  jnp.zeros((2,), jnp.int32)) if count_loads else ())
+        (*kept, count), h = moe.in_segments(layer, empty, h, seg, live)
+        with jax.named_scope("cache"):
+            rows.append((ring_rows(kept[0], true_lens,
+                                   cfg.sliding_window),)
+                        if windowed else tuple(kept))
+        if count_loads:
+            counts.append(count)
+    return h, rows, moe.prefill_counts(counts) if counts else None
+
+
+def forward(params, tokens, cfg: DotsConfig):
+    """tokens [B, T] -> float32 logits [B, T, V]: whole sequences."""
+    b, t = tokens.shape
+    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
+    return moe.logits(cfg, params, h)
+
+
+loss_fn = moe.loss_fn(forward)
+
+
+def _absorbed(k: _Kind, p, q_nope, q_rope):
+    """A step's queries [B, H, .] carried into the row's space -> (q_row
+    [B, H, row_width], the value half of ``w_kvb`` [r, H, dv])."""
+    w_kvb = p["w_kvb"].reshape(k.kv_lora, k.heads, k.dn + k.dv)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_kvb[..., :k.dn],
+                       preferred_element_type=jnp.float32).astype(
+                           q_nope.dtype)
+    return _cache_rows(k, q_lat, q_rope), w_kvb[..., k.dn:]
+
+
+def step(cfg: DotsConfig, params, tok, state, pos, active):
+    """One token a slot at PER-SLOT positions. tok, pos, active [B];
+    ``state`` the three stacks (:meth:`_Slots.init_state`, without
+    ``pos``). A full layer writes its latent row and index key at
+    ``[layer, slot, pos]``, scores the slot's ``pos + 1`` index keys,
+    selects ``min(index_topk, pos + 1)`` of them and attends over those
+    latent rows alone (the others masked); a window layer writes at
+    ``[layer, slot,
+    pos % window]`` and attends over the ring's ``min(pos + 1, window)``
+    rows; an inactive slot attends over nothing. -> (float32 logits [B,
+    V], the state updated, three [L_moe] int32 counters of the ACTIVE
+    slots' routing, and [1] int32: the rows the full layers selected,
+    summed over active slots and layers)."""
+    b = tok.shape[0]
+    w = cfg.sliding_window
+    slots = jnp.arange(b)
+    size = state["lat"].shape[2]
+    top = min(cfg.index_topk, size)
+    with jax.named_scope("embed"):
+        h = params["embed"][tok][:, None]  # [B, 1, D]
+    with jax.named_scope("attn"):
+        lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
+        ring_lengths = jnp.minimum(lengths, w)
+        valid = jnp.arange(size, dtype=jnp.int32)[None, :] < lengths[:, None]
+        # the masked read's visits, made once a step, before the layers
+        # (whole lanes of the bias: 34,832 rows are 34 blocks and 16 rows)
+        block = min(_da.LATENT_BLOCK_ROWS, -(-size // 128) * 128)
+        plan = _da.visits(lengths, size, block)
+    with jax.named_scope("qkv"):
+        rotations = {windowed: _rotation(cfg, pos[:, None], windowed)
+                     for windowed in (False, True)}
+    state = dict(state)
+    counts, selected = [], jnp.int32(0)
+    for i, p in enumerate(params["layers"]):
+        windowed = cfg.windowed(i)
+        k, a, layer = cfg.kind(windowed), p["attn"], cfg.stack_index(i)
+        with jax.named_scope("qkv"):
+            x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
+        q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
+            cfg, k, a, x, rotations[windowed])
+        with jax.named_scope("qkv"):  # (q into the row's space)
+            q_row, w_v = _absorbed(k, a, q_nope[:, 0], q_rope[:, 0])
+        row = _cache_rows(k, latent[:, 0], k_rope[:, 0])
+        if windowed:
+            with jax.named_scope("cache"):
+                state["ring"] = state["ring"].at[layer, slots, pos % w].set(
+                    row)
+            with jax.named_scope("attn/attn_window"):
+                o_lat = _da.attend_latent(
+                    q_row, state["ring"][layer], ring_lengths, k.kv_lora,
+                    (k.dn + k.dr) ** -0.5)
+        else:
+            q_i, k_i, w_i = _index_inputs(cfg, a, x, c_q, rotations[False])
+            with jax.named_scope("cache"):
+                state["lat"] = state["lat"].at[layer, slots, pos].set(row)
+                state["idx"] = state["idx"].at[layer, slots, pos].set(
+                    k_i[:, 0])
+            with jax.named_scope("attn/attn_index"):
+                scores = dsa.index_scores_xla(q_i, w_i, state["idx"][layer])
+                chosen = dsa.select(scores[:, 0], valid, top,
+                                    use_kernel=cfg.use_flash)
+                selected = selected + jnp.sum(chosen, dtype=jnp.int32)
+                bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+            with jax.named_scope("attn/attn_sparse"):
+                o_lat = dsa.decode_attention_masked(
+                    q_row, state["lat"], layer, lengths, bias,
+                    dv=k.kv_lora, scale=(k.dn + k.dr) ** -0.5, plan=plan,
+                    block=block, use_kernel=cfg.use_flash)
+        with jax.named_scope("attn_out"):  # (and back out of it)
+            o = jnp.einsum("bhr,rhd->bhd", o_lat, w_v,
+                           preferred_element_type=jnp.float32).astype(h.dtype)
+            h = h + _mla_out(cfg, a, o[:, None], gate)
+        aux = {} if cfg.sparse(i) else None
+        h = moe.mlp_layer(cfg, cfg.sparse(i), p, h, aux)
+        if aux:
+            counts.append(moe.routing_counts(cfg, aux["expert_ids"], active))
+    counters = tuple(jnp.stack(c) for c in zip(*counts))
+    return (moe.logits(cfg, params, h)[:, 0], state, *counters,
+            selected[None])
+
+
+# --------------------------------------------------------------------------
+# The serving engine's half (the protocol: models/slots.py)
+# --------------------------------------------------------------------------
+
+class _Slots(Slots):
+    """Three stacks of rows: the full layers' latent rows, the indexer's
+    keys beside them (read by the indexer, attended by nobody) and the
+    window layers' rings of latent rows, which cannot be cut or rewound
+    at a position."""
+
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "kv_norm",
+                  "ik_norm", "ik_bias", "router_bias")
+    step_counters = (*Slots.step_counters, "selected_rows")
+
+    @staticmethod
+    def row_kinds(cfg: DotsConfig) -> dict:
+        return {"full": (cfg.full_layers, None),
+                "index": (cfg.full_layers, None),
+                "ring": (cfg.window_layers, cfg.sliding_window)}
+
+    @staticmethod
+    def prefill_segments(cfg: DotsConfig, bucket: int) -> int:
+        return bucket // moe.segment_rows(bucket)
+
+    @staticmethod
+    def init_state(cfg: DotsConfig, slots: int, max_len: int) -> dict:
+        cdt = cfg.compute_dtype
+        lf, lw = cfg.full_layers, cfg.window_layers
+        return {
+            "lat": jnp.zeros((lf, slots, max_len,
+                              cfg.kind(False).row_width), cdt),
+            "idx": jnp.zeros((lf, slots, max_len, cfg.index_head_dim), cdt),
+            "ring": jnp.zeros((lw, slots, cfg.sliding_window,
+                               cfg.kind(True).row_width), cdt),
+            "pos": jnp.zeros((slots,), jnp.int32)}
+
+    @staticmethod
+    def max_len(state: dict) -> int:
+        return state["lat"].shape[2]
+
+    @staticmethod
+    def state_bytes(state: dict) -> dict:
+        # (by shape: the state may be described only)
+        return {kind: state[name].size * state[name].dtype.itemsize
+                for kind, name in (("full", "lat"), ("index", "idx"),
+                                   ("ring", "ring"))}
+
+    @staticmethod
+    def step(cfg: DotsConfig, params, prepared, tok, state, pos, active):
+        return step(cfg, params, tok, state, pos, active)
+
+    @staticmethod
+    def prefill(params, prompts, true_lens, seeds, temps, top_ps,
+                cfg: DotsConfig, slot_len: int, prefix=None):
+        """Whole prompts from position 0. Of a prompt's rows the full
+        layers keep all, latent rows and index keys (the bucket's
+        padding among them: a decode step overwrites a pad row at its
+        position before a length can expose it), the window layers the
+        last ``window`` real ones at their ring offsets. -> (the
+        streams' rows by stack, [F] prompt lengths, [F] first tokens,
+        [F] their logprobs, the held experts' assignments from the real
+        positions [L_moe, count], the expert layer's calls and compact
+        calls [2])."""
+        Slots.refuse_prefix(cfg, prefix)
+        h, rows, loads = prefill(params, prompts, true_lens, cfg,
+                                 loads=cfg.moe_layers > 0,
+                                 live=jnp.max(true_lens))
+        toks0, logp0 = Slots.first_token(
+            functools.partial(moe.logits, cfg), params, h, true_lens,
+            seeds, temps, top_ps)
+        # a list a stack, one entry a layer of its kind
+        full = [r for i, r in enumerate(rows) if not cfg.windowed(i)]
+        streams = {"lat": [r[0] for r in full], "idx": [r[1] for r in full],
+                   "ring": [r[0] for i, r in enumerate(rows)
+                            if cfg.windowed(i)]}
+        return streams, true_lens, toks0, logp0, *(loads or ())
+
+    @staticmethod
+    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
+        """The prefilled streams' rows into their slots: a ring replaced
+        whole (a prompt shorter than the window leaves zeros), a full
+        layer's P latent rows and index keys onto the first P rows of
+        the slot. What the slot's last stream wrote behind them stays:
+        no reader looks past a slot's own length (the selection's
+        ``valid``, the ring's length)."""
+        def put(all_, layers):  # [L, slots, S, C] <- L x [F, P <= S, C]
+            # a layer and a stream at a time, each an update in place
+            # (``mimo._Slots.scatter`` says why)
+            for layer, new in enumerate(layers):
+                for f in range(new.shape[0]):
+                    all_ = jax.lax.dynamic_update_slice(
+                        all_, new[None, f:f + 1].astype(all_.dtype),
+                        (layer, slots[f], 0, 0))
+            return all_
+
+        return {**{name: put(state[name], new)
+                   for name, new in streams.items()},
+                "pos": state["pos"].at[slots].set(full_lens)}
+
+
+SLOTS = _Slots

@@ -40,12 +40,12 @@ learned RMS norm:
   the next attention reads) and never subtract. ``farskip=False`` is
   the plain pre-norm block: the MLP reads ``s_l + a_l``.
 
-A slot's state in the serving engine is this model's own
-(:data:`SLOTS`): ONE stack of rows ``[L, slots, max_len, row_width]``,
-a row ``[c ‖ k_r ‖ zeros to whole lanes]`` (``row_width``: 640 for 512 +
-32; ``decode_attention.py`` says why one array). ``rows_state`` stays
-``False``: the prefix cache, speculation and the prefill workers carry
-rows as ``[L, S, Hkv, D]`` pairs of k and v (ROADMAP A1).
+A slot's state (:data:`SLOTS`): ONE stack of rows ``[L, slots, max_len,
+row_width]``, a row ``[c ‖ k_r ‖ zeros to whole lanes]`` (640 for 512 +
+32; ``decode_attention.py`` says why one array; Ling's MLA layers and
+``dots.py``'s full layers keep the same stack of 640, its window layers
+a ring of 1,152). ``rows_state`` stays ``False``: the prefix cache and
+the prefill workers carry ``[L, S, Hkv, D]`` pairs of k, v (ROADMAP A1).
 
 Types: matrices in ``dtype`` (bf16 as published), products accumulated
 in float32; norm vectors and the router's bias float32; router scores

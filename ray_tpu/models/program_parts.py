@@ -37,7 +37,7 @@ VOCABULARY = (
 )
 # the kinds of attention, a second level under ``attn``
 ATTN_KINDS = ("attn_window", "attn_full", "attn_latent", "attn_linear",
-              "attn_ssm")
+              "attn_ssm", "attn_index", "attn_sparse")
 LOOP, UNSCOPED = "loop", "unscoped"
 FILE = "program_parts.json"
 

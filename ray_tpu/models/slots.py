@@ -60,7 +60,12 @@ class Slots:
       kinds, {kind: (layers, the most rows a slot keeps in one, ``None``
       = ``max_len``)} (what ``_count_rows`` counts by; a recurrent kind
       keeps 0: Solar-Open2's ``{recurrent: (3, 0), full: (1, None)}``,
-      Granite's ``{recurrent: (9, 0), full: (1, None)}``);
+      Granite's ``{recurrent: (9, 0), full: (1, None)}``). A kind need
+      not be attended: dots3's ``{full: (2, None), index: (2, None),
+      ring: (3, 513)}`` names the indexer's keys, which an indexer reads
+      and nobody attends, beside the latent rows it chooses among. Latent
+      rows live in a stack of 640 numbers a position (Instella-MoE, Ling,
+      dots3's full layers) or in a ring of 1,152 (dots3's window layers);
     - ``prefill_segments(cfg, bucket)``: into how many segments of rows
       a ``bucket``-row call cuts its tokenwise work. The BUCKET's count:
       a call runs those of them that hold a row of its longest prompt

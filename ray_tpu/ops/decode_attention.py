@@ -386,12 +386,12 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
 # Latent rows (MLA's absorbed step): a row is key AND value
 # --------------------------------------------------------------------------
 #
-# A slot's row of a latent layer is [the normalised latent (``dv`` wide)
-# ‖ the one rotated key all heads share ‖ zeros up to whole lanes]: every
-# query head contracts its ``[q into the latent's space ‖ q_rope ‖ 0]``
-# against the whole row, and the probabilities weigh the row's first
-# ``dv`` numbers. One "kv head" whose value is a lane-aligned view of its
-# key, so a block is fetched once and passes the matrix unit twice.
+# A latent row is [the normalised latent (``dv``) ‖ the one rotated key
+# ‖ zeros to whole lanes]: a STACK of 640 (Instella-MoE, Ling, dots3's
+# full layers, whose masked read is ``ops/dsa.py``'s) or a RING of 1,152
+# (dots3's window layers: ``attend_latent``). A head contracts ``[q in
+# the latent's space ‖ q_rope ‖ 0]`` against the whole row; the first
+# ``dv`` numbers are the value: a block passes the matrix unit twice.
 #
 # The layout and the block's rows, read before they were fixed (PR 39).
 # Layout (compile for a described v5e): a rotated key of 32 kept apart

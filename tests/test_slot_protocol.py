@@ -197,7 +197,7 @@ def test_the_unrolled_blocks_share_one_copy(name):
 
 
 _BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo",
-                "granite"}
+                "granite", "dots"}
 
 
 def _names_a_block(word: str) -> bool:
@@ -248,7 +248,7 @@ def test_no_block_imports_the_engine():
     import ray_tpu
 
     for name in ("llama", "llama_slots", "slots", "moe", "ling", "exaone",
-                 "instella", "solar", "mimo", "granite"):
+                 "instella", "solar", "mimo", "granite", "dots"):
         with open(f"{ray_tpu.__path__[0]}/models/{name}.py") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):
