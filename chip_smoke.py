@@ -109,7 +109,8 @@ class Plan:
     ssm_lens: tuple = (200, 4096)
     # and the four kernels of ops/dsa.py at the published widths of
     # models/dots.py (64 index heads of 128, 128 heads of 128 + 64 / 128
-    # over latent rows of 640): this many query rows over twice the keys
+    # over latent rows of 640) and of models/glm_dsa.py (32 index heads,
+    # 64 heads of 192 + 64 / 256): this many query rows over twice the keys
     dsa_rows: int = 2048
 
     @staticmethod
@@ -1576,10 +1577,13 @@ DSA_KERNEL_TOLERANCE = 2e-2  # bf16 outputs of either form, relative
 
 
 def dsa_check(widths: str, rows: int, seed: int,
-              interpret: bool = False) -> dict:
+              interpret: bool = False, block: str = "dots") -> dict:
     """Runs in a child that holds the chip: the four kernels of
-    ``ops/dsa.py`` at the eighth block's widths (``models/dots.py``), in
-    the compute type, each against its XLA body: ``rows`` query rows at
+    ``ops/dsa.py`` at the eighth block's widths (``models/dots.py``) or,
+    with ``block="glm_dsa"``, at the ninth's (``models/glm_dsa.py``: 32
+    index heads, heads of 192 + 64 beside values of 256, 64 heads a
+    step), in the compute type, each against its XLA body: ``rows`` query
+    rows at
     offset ``rows`` over ``2 * rows`` keys through ``dsa_index`` (every
     causal score), ``dsa_kth`` (the selected SETS must be equal: the
     selection is exact) and ``dsa_attn`` (the masked flash kernel over
@@ -1591,13 +1595,13 @@ def dsa_check(widths: str, rows: int, seed: int,
     import jax.numpy as jnp
 
     from ray_tpu._private import accelerator
-    from ray_tpu.models import dots
+    from ray_tpu.models import dots, glm_dsa
     from ray_tpu.ops import dsa
 
     accelerator.claim_device()
-    cfg = dots.DotsConfig.tiny(dtype="bfloat16") if widths == "tiny" \
-        else dots.DotsConfig()
-    k = cfg.kind(False)
+    config = {"dots": dots.DotsConfig, "glm_dsa": glm_dsa.GlmDsaConfig}[block]
+    cfg = config.tiny(dtype="bfloat16") if widths == "tiny" else config()
+    k = cfg.mla if block == "glm_dsa" else cfg.kind(False)
     hi, di, dt = cfg.index_heads, cfg.index_head_dim, cfg.compute_dtype
     top = min(cfg.index_topk, rows)
     keys, blocks = 2 * rows, min(128, rows)
@@ -1725,20 +1729,24 @@ def hybrid_phase(plan: Plan) -> dict:
           "its stepping, the ssd_step kernel from the XLA body, an "
           "inactive slot's state moved, or the layer's step holds no "
           "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
-    sparse = chip_child(plan, "dsa_check", {
-        "widths": plan.hybrid_widths, "rows": plan.dsa_rows,
-        "seed": plan.seed, "interpret": not plan.on_tpu})
-    check_device(plan, sparse["device"], 1, "dsa child")
-    check(max(sparse["rel_err"].values()) <= DSA_KERNEL_TOLERANCE
-          and sparse["sets_equal"] and sparse["inactive_zero"],
-          "a kernel of ops/dsa.py parts from its XLA body, the selection's "
-          "kernel chose another set than the counting passes, or an "
-          "inactive slot's output is not zeros", got=sparse,
-          tolerance=DSA_KERNEL_TOLERANCE)
+    sparse = {}
+    for block in ("dots", "glm_dsa"):
+        sparse[block] = found = chip_child(plan, "dsa_check", {
+            "widths": plan.hybrid_widths, "rows": plan.dsa_rows,
+            "seed": plan.seed, "interpret": not plan.on_tpu, "block": block})
+        check_device(plan, found["device"], 1, "dsa child")
+        check(max(found["rel_err"].values()) <= DSA_KERNEL_TOLERANCE
+              and found["sets_equal"] and found["inactive_zero"],
+              f"at the widths of models/{block}.py a kernel of ops/dsa.py "
+              "parts from its XLA body, the selection's kernel chose "
+              "another set than the counting passes, or an inactive "
+              "slot's output is not zeros", got=found,
+              tolerance=DSA_KERNEL_TOLERANCE)
     return {"device": check_device(plan, out["device"], 1, "hybrid child"),
             "lens": list(plan.hybrid_lens), "rel_err": out["rel_err"],
-            "dsa": {k: sparse[k] for k in ("rel_err", "sets_equal",
-                                           "chosen", "rows")},
+            "dsa": {block: {k: found[k] for k in (
+                "rel_err", "sets_equal", "chosen", "rows")}
+                for block, found in sparse.items()},
             "ssm": {k: ssm[k] for k in ("rel_err", "kernel", "segments",
                                         "inactive_kept", "in_program")},
             "segment": {k: segment[k] for k in ("rel_err", "segments")},

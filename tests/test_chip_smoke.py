@@ -271,19 +271,22 @@ def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
     assert found["inactive_kept"] and found["in_program"] is False
 
 
-def test_dsa_check_rehearses_on_the_cpu():
-    """What ``hybrid_phase`` asks of the eighth block's kernels
-    (``ops/dsa.py``) on the chip, at tiny widths in the Pallas
-    interpreter: 128 query rows over 256 keys, 16 chosen a row; each
+@pytest.mark.parametrize("block, chosen", [("dots", 16), ("glm_dsa", 8)])
+def test_dsa_check_rehearses_on_the_cpu(block, chosen):
+    """What ``hybrid_phase`` asks of the kernels of ``ops/dsa.py`` on the
+    chip at the eighth block's widths and at the ninth's (heads of 24 +
+    8 beside values of 32 at tiny widths), in the Pallas interpreter:
+    128 query rows over 256 keys, ``index_topk`` chosen a row; each
     kernel agrees with its XLA body, the selection's kernel chooses the
     counting passes' sets, an inactive slot's step gives zeros."""
-    found = chip_smoke.dsa_check("tiny", 128, TINY.seed, interpret=True)
+    found = chip_smoke.dsa_check("tiny", 128, TINY.seed, interpret=True,
+                                 block=block)
     assert found["device"].items() >= CPU.items()
     assert set(found["rel_err"]) == {"dsa_index", "dsa_attn",
                                      "dsa_decode_attn"}
     assert max(found["rel_err"].values()) <= chip_smoke.DSA_KERNEL_TOLERANCE
     assert found["sets_equal"] and found["inactive_zero"]
-    assert found["chosen"] == 128 * 16
+    assert found["chosen"] == 128 * chosen
 
 
 def test_ring_check_rehearses_on_the_cpu():

@@ -239,7 +239,7 @@ def test_submit_and_pump_serve_the_references_tokens_in_bf16(model):
     m = {**M, "index_topk": 32}
     cfg = _cfg(dtype="bfloat16", index_topk=32)
     params = dots.init_params(cfg, jax.random.PRNGKey(8))
-    seen = len(fr._get().ring)
+    seen = fr._get().recorded  # (the ring is bounded: count, not place)
     eng = RaggedDecoder(params, cfg, slots=2, max_len=96, chunk_tokens=4,
                         prompt_buckets=(64,), name="dots-test")
     asked = [(_tokens(20 + n, n), 64 - n) for n in (13, 40, 24)]
@@ -257,7 +257,7 @@ def test_submit_and_pump_serve_the_references_tokens_in_bf16(model):
     assert 0 < by_kind["ring"] < by_kind["full"] == by_kind["index"]
     with pytest.raises(ValueError, match="ring of rows"):
         RaggedDecoder(params, cfg, slots=2, max_len=64, spec_depth=2)
-    spans = list(fr._get().ring)[seen:]
+    spans = list(fr._get().ring)[seen - fr._get().recorded:]
     init = [s["attrs"] for s in spans if s["name"] == "engine.state_init"
             and s["attrs"].get("engine") == "dots-test"][-1]
     rows = FAM.row_bytes(M, 2)

@@ -233,14 +233,14 @@ def test_an_engines_prefills_count_their_compact_calls(family, monkeypatch):
                         name=f"compact-{family}")
     rng = np.random.RandomState(2)
     asked = [rng.randint(1, 256, n).astype(np.int32) for n in (100, 200)]
-    mark = len(fr._get().ring)
+    mark = fr._get().recorded  # (the ring is bounded: count, not place)
     sids = [eng.submit(p, 6) for p in asked]
     eng.drain()
     for sid, p in zip(sids, asked):
         check = ref.check_served_tokens(
             params, list(p), list(eng.finished[sid].tokens), m)
         assert check["wrong"] == 0 and check["agree"] == 6, check
-    backs = [s["attrs"] for s in list(fr._get().ring)[mark:]
+    backs = [s["attrs"] for s in list(fr._get().ring)[mark - fr._get().recorded:]
              if s["name"] == "engine.readback"
              and "moe_expert_calls" in s["attrs"]]
     # 100 rows: one segment of its 128-row bucket; 200: both of 256's

@@ -20,8 +20,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decode_engine as de
-from ray_tpu.models import (exaone, granite, instella, ling, llama, mimo,
-                            moe, solar)
+from ray_tpu.models import (exaone, glm_dsa, granite, instella, ling, llama,
+                            mimo, moe, solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -36,7 +36,11 @@ BLOCKS = {
     "solar": (solar, solar.SolarConfig.tiny),
     "mimo": (mimo, mimo.MimoConfig.tiny),
     "granite": (granite, granite.GraniteConfig.tiny),
+    "glm_dsa": (glm_dsa, glm_dsa.GlmDsaConfig.tiny),
 }
+# the block whose step counts what its indexers chose besides the
+# routing: it states ``step_counters`` of its own
+SELECTS = ("glm_dsa",)
 ROWS = [name for name in BLOCKS if name.startswith("llama")]
 OWN = [name for name in BLOCKS if name not in ROWS]
 
@@ -190,6 +194,9 @@ def test_the_unrolled_blocks_share_one_copy(name):
         is Slots.serving_params.__func__
     for member in ("rows_state", "step_counters", "split", "first_token",
                    "refuse_prefix", "reports_routing"):
+        if member == "step_counters" and name in SELECTS:
+            assert mod.SLOTS.step_counters[:3] == Slots.step_counters
+            continue
         assert member not in vars(mod.SLOTS), member
 
 
@@ -197,7 +204,7 @@ def test_the_unrolled_blocks_share_one_copy(name):
 
 
 _BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo",
-                "granite", "dots"}
+                "granite", "dots", "glm", "glmdsa"}
 
 
 def _names_a_block(word: str) -> bool:
@@ -248,7 +255,8 @@ def test_no_block_imports_the_engine():
     import ray_tpu
 
     for name in ("llama", "llama_slots", "slots", "moe", "ling", "exaone",
-                 "instella", "solar", "mimo", "granite", "dots"):
+                 "instella", "solar", "mimo", "granite", "dots",
+                 "glm_dsa"):
         with open(f"{ray_tpu.__path__[0]}/models/{name}.py") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):

@@ -40,7 +40,8 @@ SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("solar-open2-250b-ep8-1chip", "longreason-saturated"),
                  ("mimo-v2.5-ep16-1chip", "longreason-saturated"),
                  ("granite-4.0-h-small-ep4-1chip", "sessions-saturated"),
-                 ("dots3-note-prev-ep8-1chip", "longreason-saturated-24"))
+                 ("dots3-note-prev-ep8-1chip", "longreason-saturated-24"),
+                 ("glm-5.2-ep16-1chip", "longreason-saturated-16"))
 TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
                ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 
