@@ -55,8 +55,9 @@ and index keys into the layer's rows so far, scores its rows against
 every index key (``dsa.index_scores``), selects, and attends UNABSORBED
 over every earlier key with the unchosen masked (``dsa.
 masked_attention``), ``prefill_head_groups`` groups of heads one after
-another, each group's k and v made from the latents for that call alone
-(128 heads' k and v of 32,768 rows would be 2.7 GB). A window layer's
+another, each group's k and v made for that call alone (128 heads' k and
+v of 32,768 rows would be 2.7 GB) and of the rows the segment can see
+alone (:func:`_live_kv`: the rows behind them are not made). A window layer's
 segment makes k and v of the rows its band can reach (the segment and
 the one before it) and attends through ``flash_fwd``'s band. Nothing
 ``[P, P]`` exists; a segment's ``[rows, P]`` float32 scores do.
@@ -73,6 +74,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu.models import moe
 from ray_tpu.models.exaone import ring_rows
@@ -368,6 +370,61 @@ def _unabsorbed(k: _Kind, p, rows):
     return jnp.concatenate([kv[..., :k.dn], k_rope], axis=-1), kv[..., k.dn:]
 
 
+def _kv_buffers(k: _Kind, b: int, heads: int, t: int, dtype):
+    """The pair :func:`_live_kv` writes a group of ``heads`` heads' k_nope
+    [B, heads, T, dn] and v [B, heads, T, dv] into: zeros, made once a
+    layer's segment and carried through its groups of heads."""
+    return (jnp.zeros((b, heads, t, k.dn), dtype),
+            jnp.zeros((b, heads, t, k.dv), dtype))
+
+
+def _live_kv(k: _Kind, lat_all, w_g, start, seg: int, bufs):
+    """A group of heads' k_nope and v for the rows a segment at ``start``
+    .. can see: the latents of rows ``0 .. start + seg - 1`` of lat_all
+    [B, T, row_width], ``seg`` rows at a time (a loop whose trip count is
+    the traced ``start // seg + 1``), through the group's w_kvb w_g [r,
+    hg, dn + dv] into ``bufs`` (:func:`_kv_buffers`) at their rows ->
+    the pair, which is what ``dsa.masked_attention`` takes as k and v.
+
+    The rows behind ``start + seg`` are NOT made, and what the pair holds
+    there is stale: the segment's zeros, or the finite k and v an earlier
+    group of heads left. That is harmless: ``dsa_attn`` fetches no block
+    past ``_last_block``, and inside the last block and in
+    ``masked_attention_xla`` every key past ``t + start`` carries
+    ``dsa.NEG``, so its weight is exactly 0 in float32 and 0 x finite
+    adds nothing.
+
+    The pair is held row-major, the layout ``dsa_attn`` takes (left to
+    itself the compiler lays a loop's carry out as the product inside
+    likes it, rows minor, and turns all of it before every kernel call),
+    and the loop sees it as ``[B, hg, chunks, seg, d]``: a chunk is then
+    one index of an axis and lands at a place the compiler knows to be
+    whole tiles, so the write is part of the product's fusion (at a
+    traced ROW it was an operation of its own for keys 192 wide, slower
+    than the product: ``PERF.md`` §6 PR 61)."""
+    b, hg, t = bufs[0].shape[:3]
+
+    def products(rows):  # [B, S, r] -> k_nope [B, hg, S, dn], v [.., dv]
+        return (jnp.einsum(
+            "bsr,rhd->bhsd", rows, w, preferred_element_type=jnp.float32
+        ).astype(bufs[0].dtype) for w in (w_g[..., :k.dn], w_g[..., k.dn:]))
+
+    if t == seg:  # one segment: one chunk, no loop
+        return tuple(products(lat_all[..., :k.kv_lora]))
+    row_major = Layout(major_to_minor=(0, 1, 2, 3, 4))
+
+    def chunk(c, bufs):
+        rows = jax.lax.dynamic_slice(lat_all, (0, c * seg, 0),
+                                     (b, seg, k.kv_lora))
+        return tuple(with_layout_constraint(jax.lax.dynamic_update_slice(
+            buf, new[:, :, None], (0, 0, c, 0, 0)), row_major)
+            for buf, new in zip(bufs, products(rows)))
+
+    bufs = jax.lax.fori_loop(0, start // seg + 1, chunk, tuple(
+        buf.reshape(b, hg, t // seg, seg, -1) for buf in bufs))
+    return tuple(buf.reshape(b, hg, t, -1) for buf in bufs)
+
+
 def _window_attend(cfg: DotsConfig, q, k, v, offset):
     """The band: q [B, H, T, d] at positions ``offset`` .. of the keys'
     own numbering, k / v [B, H, S, .]. On a TPU ``flash_fwd``'s band
@@ -421,21 +478,19 @@ def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
 
         w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
             k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
-        lat = lat_all[..., :k.kv_lora]
         k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
+        bufs = _kv_buffers(k, b, hg, t, lat_all.dtype)
 
-    def group(_, xs):
+    def group(bufs, xs):
         qn_g, qr_g, w_g = xs
-        with jax.named_scope("qkv"):  # (k and v out of the latents)
-            k_g, v_g = (jnp.einsum(
-                "bsr,rhd->bhsd", lat, w, preferred_element_type=jnp.float32
-            ).astype(lat.dtype) for w in (w_g[..., :k.dn], w_g[..., k.dn:]))
+        with jax.named_scope("qkv"):  # (k and v out of the live latents)
+            k_g, v_g = bufs = _live_kv(k, lat_all, w_g, start, seg, bufs)
         with jax.named_scope("attn/attn_sparse"):
-            return None, dsa.masked_attention(
+            return bufs, dsa.masked_attention(
                 qn_g, qr_g, k_g, k_r, v_g, bias, start,
                 scale=(k.dn + k.dr) ** -0.5, use_kernel=cfg.use_flash)
 
-    _, o = jax.lax.scan(group, None, (grouped(q_nope), grouped(q_rope),
+    _, o = jax.lax.scan(group, bufs, (grouped(q_nope), grouped(q_rope),
                                       w_kvb))
     with jax.named_scope("attn_out"):  # [G, B, hg, seg, dv] -> [B, seg, H, dv]
         o = o.transpose(1, 3, 0, 2, 4).reshape(b, seg, k.heads, k.dv)

@@ -276,21 +276,19 @@ def _segment(cfg: GlmDsaConfig, p, x, start, lat_all, idx_all, bias):
 
         w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
             k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
-        lat = lat_all[..., :k.kv_lora]
         k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
+        bufs = dots._kv_buffers(k, b, hg, lat_all.shape[1], lat_all.dtype)
 
-    def group(_, xs):
+    def group(bufs, xs):
         qn_g, qr_g, w_g = xs
-        with jax.named_scope("qkv"):  # (k and v out of the latents)
-            k_g, v_g = (jnp.einsum(
-                "bsr,rhd->bhsd", lat, w, preferred_element_type=jnp.float32
-            ).astype(lat.dtype) for w in (w_g[..., :k.dn], w_g[..., k.dn:]))
+        with jax.named_scope("qkv"):  # (k and v out of the live latents)
+            k_g, v_g = bufs = dots._live_kv(k, lat_all, w_g, start, seg, bufs)
         with jax.named_scope("attn/attn_sparse"):
-            return None, dsa.masked_attention(
+            return bufs, dsa.masked_attention(
                 qn_g, qr_g, k_g, k_r, v_g, bias, start,
                 scale=(k.dn + k.dr) ** -0.5, use_kernel=cfg.use_flash)
 
-    _, o = jax.lax.scan(group, None, (grouped(q_nope), grouped(q_rope),
+    _, o = jax.lax.scan(group, bufs, (grouped(q_nope), grouped(q_rope),
                                       w_kvb))
     with jax.named_scope("attn_out"):  # [G, B, hg, seg, dv] -> [B, seg, H, dv]
         o = o.transpose(1, 3, 0, 2, 4).reshape(b, seg, k.heads, k.dv)

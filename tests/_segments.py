@@ -1,11 +1,14 @@
-"""What the tests of the two segmented blocks (``test_solar_block.py``,
-``test_mimo_block.py``) share: buckets cut into segments of 16 rows, one
-prompt through the engine's prefill program into a slot, greedy steps of
-that slot alone, and the comparison of a short prompt in a long bucket
-(dead segments behind it, ``moe.in_segments``) with the reference.
+"""What the tests of the segmented blocks (``test_solar_block.py``,
+``test_mimo_block.py``, ``test_dots_block.py``, ``test_glm_dsa_block.py``)
+share: buckets cut into segments of 16 rows, one prompt through the
+engine's prefill program into a slot, greedy steps of that slot alone,
+the comparison of a short prompt in a long bucket (dead segments behind
+it, ``moe.in_segments``) with the reference, and what holds a sparse
+layer's k and v to the rows its segment can see (:func:`live_kv_case`).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -81,3 +84,79 @@ def short_prompt_in_a_reused_slot(slots, cfg, params, forward, tol):
     assert np.abs(logits - want[40:]).max() < tol
     assert fed_reused == fed
     np.testing.assert_array_equal(logits_reused, logits)
+
+
+# ------------------------------------ a sparse layer's k and v (PR 61)
+
+def whole_bucket_kv(k, lat_all, w_g, start, seg, bufs):
+    """``dots._live_kv``'s place as the blocks filled it before PR 61:
+    a group's k_nope and v of EVERY row of the bucket, each segment."""
+    lat = lat_all[..., :k.kv_lora]
+    return tuple(jnp.einsum(
+        "bsr,rhd->bhsd", lat, w, preferred_element_type=jnp.float32
+    ).astype(lat.dtype) for w in (w_g[..., :k.dn], w_g[..., k.dn:]))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def live_kv_case(case: str, monkeypatch, block, kind, layers: int, cfg,
+                 params, tokens):
+    """One prompt of 40 rows in a 64-row bucket of 16-row segments (four
+    segments, ``live`` ends inside the third) through ``block.prefill``,
+    ``layers`` of whose layers attend through ``kind``'s sparse MLA:
+
+    - ``whole_bucket``: h and every layer's rows are what the parent's
+      form gives (:func:`whole_bucket_kv` in the helper's place) to
+      float32 rounding: XLA's CPU backend picks a product's loop by its
+      shape, so a chunk's 16 rows of k are not the bits of the same rows
+      of a 64-row product (2e-6 apart, read here; h then by 2.4e-6 on
+      values of 3; a chunk left out moves h by 1e-2 and more);
+    - ``stale_1e4``: bit for bit what it gives with the pair full of 1e4
+      before each segment instead of zeros: nothing behind ``start +
+      seg`` is read with weight;
+    - ``lowered``: every product with a group's ``w_kvb`` (k_nope's
+      half or v's) in the traced program has one segment's 16 rows of
+      latents as its other operand, none the bucket's 64."""
+    from ray_tpu.models import dots
+
+    lens, live = jnp.array([40], jnp.int32), jnp.int32(40)
+
+    def run(p, t):
+        return block.prefill(p, t, lens, cfg, live=live)[:2]
+
+    def prefill():  # (traced anew: the patches are read at trace time)
+        return jax.jit(run)(params, tokens)
+
+    if case == "lowered":
+        hg = kind.heads // math.gcd(cfg.prefill_head_groups, kind.heads)
+        halves = {(kind.kv_lora, hg, kind.dn), (kind.kv_lora, hg, kind.dv)}
+        others = [
+            tuple(v.aval.shape for v in eqn.invars if v.aval.shape not in
+                  halves)
+            for eqn in _equations(jax.make_jaxpr(run)(params, tokens).jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and halves & {v.aval.shape for v in eqn.invars}]
+        assert len(others) == 2 * layers, others  # (k_nope's and v's)
+        assert set(others) == {((1, 16, kind.kv_lora),)}, others
+        return
+    want = prefill()
+    assert np.asarray(want[0][:, :40]).any()
+    assert not np.asarray(want[0][:, 48:]).any()  # (the dead segment)
+    if case == "stale_1e4":
+        monkeypatch.setattr(dots, "_kv_buffers", lambda k, b, h, t, dt: (
+            jnp.full((b, h, t, k.dn), 1e4, dt),
+            jnp.full((b, h, t, k.dv), 1e4, dt)))
+        same = np.testing.assert_array_equal
+    else:
+        monkeypatch.setattr(dots, "_live_kv", whole_bucket_kv)
+        same = functools.partial(np.testing.assert_allclose, rtol=0,
+                                 atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(prefill()),
+                    jax.tree_util.tree_leaves(want), strict=True):
+        same(np.asarray(a), np.asarray(b))

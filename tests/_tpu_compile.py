@@ -74,6 +74,36 @@ def _made_by(lines) -> dict:
         if m}
 
 
+def _moved_operands(lines, operands) -> dict:
+    """{operand: the operation that MOVES it} for those of ``operands``
+    (instruction names) that a ``copy``, an asynchronous copy, a
+    ``transpose``, a ``pad`` or a ``concatenate`` makes, looked for
+    behind the bitcasts between it and its user."""
+    made_by = _made_by(lines)
+    first = {m.group(1): m.group(2) for m in (
+        re.match(r"\s*(%[\w.\-]+) = .*?\s[\w\-]+\((%[\w.\-]+)", ln)
+        for ln in lines) if m}
+    moved = {}
+    for name in operands:
+        at = name
+        while made_by.get(at) == "bitcast":
+            at = first[at]
+        if made_by.get(at) in ("copy", "copy-done", "transpose", "pad",
+                               "concatenate"):
+            moved[name] = made_by[at]
+    return moved
+
+
+def _live_kv_products(lines) -> list:
+    """The result shapes of the products ``dots._live_kv``'s loop makes
+    in a compiled prefill program (a group of heads' k_nope or v out of
+    one chunk of latents, float32 before the cast), as lists of ints."""
+    return [[int(d) for d in m.group(1).split(",")] for m in (
+        re.search(r" = f32\[([\d,]+)\]\S* convolution\(.*"
+                  r"qkv/while/body/bsr,rhd->bhsd/dot_general", ln)
+        for ln in lines) if m]
+
+
 @pytest.fixture(scope="module")
 def topo():
     """The described v5e 2x2 host. The persistent compile cache is off

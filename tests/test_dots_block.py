@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _segments import decode_from, prefill_slot
+from _segments import decode_from, live_kv_case, prefill_slot
 from benchmark import manifest
 from ray_tpu.models import dots, moe
 from ray_tpu.models.decode_engine import RaggedDecoder
@@ -222,6 +222,16 @@ def test_a_reused_slot_shows_nothing_of_its_last_stream(model, served):
     assert int(state["pos"][0]) == 0
     _, reused = decode_from(dots.SLOTS, cfg, params, state, cur, 1, 12)
     np.testing.assert_array_equal(reused, fresh[:12])
+
+
+@pytest.mark.parametrize("case", ["whole_bucket", "stale_1e4", "lowered"])
+def test_a_full_layers_k_and_v_are_made_for_the_live_rows_alone(
+        model, monkeypatch, case):
+    """``dots._live_kv`` in the two full layers' prefill
+    (``_segments.live_kv_case`` says what each case holds)."""
+    cfg, params = model
+    live_kv_case(case, monkeypatch, dots, cfg.kind(False), cfg.full_layers,
+                 cfg, params, _tokens(11, 1, 64))
 
 
 def test_submit_and_pump_serve_the_references_tokens_in_bf16(model):

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, MOSAIC_BODY, _lower_prefill, _made_by, _mem, _mosaic_text,
-    _on, topo)
+    KERNEL, MIB, MOSAIC_BODY, _live_kv_products, _lower_prefill, _mem,
+    _mosaic_text, _moved_operands, _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -120,9 +120,14 @@ def test_dots_32768_row_prefill_is_segments_and_four_kernels(
     layer ``flash_fwd_window`` at blocks of 512; ``moe_gmm`` three times
     in either branch of an expert layer; no ``[32768, 32768]`` array, no
     float32 ``[64, 2048, 32768]`` scores a head, no whole ``[32768,
-    13824]`` of the dense layer, no 128 heads' k or v of 32,768 rows;
-    beside 24 slots the call fits the chip (temporaries 2.2 GiB beside
-    10.1 GiB of arguments)."""
+    13824]`` of the dense layer, no 128 heads' k or v of 32,768 rows. A
+    group of 32 heads' k and v are a pair of ``[1, 32, 32768, 128]``
+    buffers zeroed once a segment, which ``dots._live_kv``'s loop writes
+    a chunk of 2,048 rows at a time (the product and the update one
+    fusion, in place) and ``dsa_attn`` takes as they are: no product is
+    made of the bucket's ``[32768, 512]`` latents. Beside 24 slots the
+    call fits the chip (temporaries 2.1 GiB beside 10.1 GiB of
+    arguments)."""
     from ray_tpu.models import dots
     from ray_tpu.ops import dsa
 
@@ -141,13 +146,15 @@ def test_dots_32768_row_prefill_is_segments_and_four_kernels(
     for dims in ("[32768,32768]", "f32[64,2048,32768]",
                  "f32[1,64,2048,32768]", "[32768,13824]",
                  "bf16[1,128,32768,192]", "bf16[1,128,32768,128]",
-                 "[32768,19008]"):
+                 "[32768,19008]", "[1,32768,512]"):
         assert dims not in text, dims
     assert "f32[1,2048,32768]" in text  # a segment's scores: they may
     assert "bf16[1,32,32768,128]" in text  # a group of heads' k and v
     assert "approx" not in text.lower()
     lines = text.splitlines()
-    made_by = _made_by(lines)
+    # k_nope's and v's product a full layer, each of one chunk's rows
+    assert _live_kv_products(lines) == 2 * cfg.full_layers * [
+        [32, 128, 2048]], _live_kv_products(lines)
     k = cfg.kind(False)
     heads = k.heads // cfg.prefill_head_groups
     bq, bk, cell = dsa._ATTN_BLOCKS  # (a segment is 2,048 rows)
@@ -160,8 +167,7 @@ def test_dots_32768_row_prefill_is_segments_and_four_kernels(
             r"%[\w.\-]+", re.search(r"custom-call\(([^)]*)\)", call).group(1))
         # offset, q_n, q_r, k_n, the one rotated key, v, the bias
         assert len(operands) == 7, operands
-        moved = {o: made_by.get(o) for o in operands[3:6] if made_by.get(o)
-                 in ("copy", "copy-done", "transpose", "pad", "concatenate")}
+        moved = _moved_operands(lines, operands[3:6])
         assert not moved, moved
         body = _mosaic_text(MOSAIC_BODY.search(call).group(1))
         args = body[:body.index("\n", body.index("^bb0"))]

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, MOSAIC_BODY, _lower_prefill, _mem, _mosaic_text, _on, topo)
+    KERNEL, MIB, MOSAIC_BODY, _live_kv_products, _lower_prefill, _mem,
+    _mosaic_text, _moved_operands, _on, topo)
 from ray_tpu.models import decode_engine as de
 
 GB = 10 ** 9
@@ -146,8 +147,13 @@ def test_glm_32768_row_prefill_hands_a_segments_selection_to_four_layers(
     array with an extent of 32,768 is the stream itself (384 MiB; a
     segment's float32 scores are 256). No float32
     scores a head, no whole ``[32768, 12288]`` of the dense layer, no 64
-    heads' k or v of 32,768 rows; arguments and temporaries under 15.0
-    GB of the chip's 16 GiB."""
+    heads' k or v of 32,768 rows. A group of 8 heads' k and v are a
+    pair of ``[1, 8, 32768, 192]`` / ``[.., 256]`` buffers zeroed once a
+    layer's segment, which ``dots._live_kv``'s loop writes a chunk of
+    2,048 rows at a time and ``dsa_attn`` takes as they are (no copy,
+    transpose, pad or concatenate before it): no product is made of the
+    bucket's ``[32768, 512]`` latents. Arguments and temporaries under
+    15.0 GB of the chip's 16 GiB."""
     from ray_tpu.models import glm_dsa
     from ray_tpu.ops import dsa
 
@@ -166,25 +172,36 @@ def test_glm_32768_row_prefill_hands_a_segments_selection_to_four_layers(
     for dims in ("[32768,32768]", "f32[32,2048,32768]",
                  "f32[1,32,2048,32768]", "[32768,12288]",
                  "bf16[1,64,32768,192]", "bf16[1,64,32768,256]",
-                 "[32768,19360]"):
+                 "[32768,19360]", "[1,32768,512]"):
         assert dims not in text, dims
     assert "f32[1,2048,32768]" in text  # a segment's scores: they may
     assert "bf16[1,2048,32768]" in text  # and its bias, for four layers
     assert "bf16[1,8,32768,256]" in text  # a group of heads' v
     assert "approx" not in text.lower()
+    lines = text.splitlines()
+    # k_nope's and v's product a layer, each of one chunk's rows
+    k = cfg.mla
+    assert sorted(_live_kv_products(lines)) == cfg.n_layers * [
+        [8, k.dn, 2048]] + cfg.n_layers * [[8, k.dv, 2048]], \
+        _live_kv_products(lines)
     # nothing that spans the prompt is larger than the stream itself
     # (bf16 [32768, 6144], 384 MiB: one and a half times a segment's
     # float32 scores against the prompt, a fifth of a [P, P] bf16 bias)
     size, shape = _largest_with(text, 32768)
     assert size <= 32768 * cfg.d_model * 2, (size, shape)
-    k = cfg.mla
     heads = k.heads // cfg.prefill_head_groups
     bq, bk, cell = dsa._ATTN_BLOCKS  # (a segment is 2,048 rows)
     assert bq != bk and heads % cell == 0
-    masked = [ln for ln in text.splitlines()
+    masked = [ln for ln in lines
               if KERNEL in ln and re.match(r"\s*%dsa_attn\b", ln)]
     assert len(masked) == cfg.n_layers
     for call in masked:
+        operands = re.findall(
+            r"%[\w.\-]+", re.search(r"custom-call\(([^)]*)\)", call).group(1))
+        # offset, q_n, q_r, k_n, the one rotated key, v, the bias
+        assert len(operands) == 7, operands
+        moved = _moved_operands(lines, operands[3:6])
+        assert not moved, moved
         body = _mosaic_text(MOSAIC_BODY.search(call).group(1))
         args = body[:body.index("\n", body.index("^bb0"))]
         assert (f"memref<1x{cell}x{bq}x{k.dn}xbf16" in args
