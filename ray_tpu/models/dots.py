@@ -69,8 +69,10 @@ router scores and softmax statistics float32.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -158,17 +160,16 @@ class DotsConfig(moe.HeldExperts):
     def sparse(self, i: int) -> bool:
         return i >= self.first_k_dense
 
-    def kind(self, windowed: bool) -> "_Kind":
+    def kind(self, windowed: bool) -> "Kind":
         """The widths of a layer of that kind."""
-        if windowed:
-            return _Kind(self.window_heads, self.window_q_lora_rank,
-                         self.window_kv_lora_rank,
-                         self.window_qk_nope_head_dim,
-                         self.window_qk_rope_head_dim,
-                         self.window_v_head_dim, self.window_rope_theta)
-        return _Kind(self.n_heads, self.q_lora_rank, self.kv_lora_rank,
-                     self.qk_nope_head_dim, self.qk_rope_head_dim,
-                     self.v_head_dim, self.rope_theta)
+        own = (self.window_heads, self.window_q_lora_rank,
+               self.window_kv_lora_rank, self.window_qk_nope_head_dim,
+               self.window_qk_rope_head_dim, self.window_v_head_dim,
+               self.window_rope_theta) if windowed else (
+            self.n_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta)
+        return Kind(*own, self.lora_rescale, self.gated_attention)
 
     def stack_index(self, i: int) -> int:
         """Layer ``i``'s place in the stack of its kind."""
@@ -211,7 +212,11 @@ class DotsConfig(moe.HeldExperts):
 
 
 @dataclasses.dataclass(frozen=True)
-class _Kind:
+class Kind:
+    """A latent-attention layer's widths and what is done to its latents
+    and heads: all the functions below ask of a LAYER beside the
+    configuration's ``rms_eps``, types, ``use_flash``, indexer widths and
+    ``prefill_head_groups`` (``models/glm_dsa.py``'s run through them)."""
     heads: int
     q_lora: int
     kv_lora: int
@@ -219,20 +224,27 @@ class _Kind:
     dr: int
     dv: int
     theta: float
+    rescale: bool  # r_q, r_kv in the norms' scales (module docstring)
+    gated: bool  # one sigmoid gate a head
 
     @property
     def row_width(self) -> int:
         """What a cache row holds: latent | rotated key, to whole lanes."""
         return -(-(self.kv_lora + self.dr) // _LANES) * _LANES
 
+    def rotation(self, positions):
+        """(sin, cos) of ``positions`` [B, T] for the rotated part."""
+        return rotary_embedding(positions, self.dr, self.theta)
+
 
 # --------------------------------------------------------------------------
 # Parameters
 # --------------------------------------------------------------------------
 
-def init_params(cfg: DotsConfig, key):
+def init_layers(cfg, key, kinds):
     """The tree in the SERVING types, leaf by leaf in blocks
-    (``moe.draw``). K-EXAONE's initialisation and for its reasons
+    (``moe.draw``); ``kinds`` every layer's (:class:`Kind`, whether it
+    owns an indexer). K-EXAONE's initialisation and for its reasons
     (``exaone.init_params``): matrices normal / sqrt(fan_in), every
     ``w_down`` scaled by (2 x depth)^-1/2 besides (depth is
     ``published_layers``), the attention's ``wo`` not. The norm scales
@@ -248,22 +260,21 @@ def init_params(cfg: DotsConfig, key):
     keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
     mat, around_one = moe.makers(cfg, keys)
 
-    def reads(rank: int, width: int):  # (a rescaled latent's reader)
-        r2 = d / rank if cfg.lora_rescale else 1.0
-        return moe.draw(next(keys), (rank, width), (rank * r2) ** -0.5,
-                        cfg.compute_dtype)
+    def attention(k: Kind, indexes: bool):
+        def reads(rank: int, width: int):  # (a rescaled latent's reader)
+            r2 = d / rank if k.rescale else 1.0
+            return moe.draw(next(keys), (rank, width), (rank * r2) ** -0.5,
+                            cfg.compute_dtype)
 
-    def attention(windowed: bool):
-        k = cfg.kind(windowed)
         p = {"w_qa": mat(d, k.q_lora), "q_norm": around_one(k.q_lora),
              "w_qb": reads(k.q_lora, k.heads * (k.dn + k.dr)),
              "w_kva": mat(d, k.kv_lora + k.dr),
              "kv_norm": around_one(k.kv_lora),
              "w_kvb": reads(k.kv_lora, k.heads * (k.dn + k.dv)),
              "wo": mat(k.heads * k.dv, d)}
-        if cfg.gated_attention:
+        if k.gated:
             p["w_gate"] = mat(d, k.heads)
-        if not windowed:
+        if indexes:
             di = cfg.index_head_dim
             p.update({
                 "w_iq": reads(k.q_lora, cfg.index_heads * di),
@@ -274,7 +285,7 @@ def init_params(cfg: DotsConfig, key):
         return p
 
     layers = [{
-        "attn_norm": around_one(d), "attn": attention(cfg.windowed(i)),
+        "attn_norm": around_one(d), "attn": attention(*kinds[i]),
         "mlp_norm": around_one(d),
         "mlp": moe.init_experts(cfg, mat, keys) if cfg.sparse(i)
         else moe.init_dense(cfg, mat),
@@ -282,25 +293,31 @@ def init_params(cfg: DotsConfig, key):
     return moe.init_model(cfg, mat, around_one, keys, layers)
 
 
+def init_params(cfg: DotsConfig, key):
+    """:func:`init_layers`: an indexer in every full layer."""
+    return init_layers(cfg, key, [(cfg.kind(bool(w)), not w)
+                                  for w in cfg.layer_pattern])
+
+
 # --------------------------------------------------------------------------
 # A layer's inputs and output
 # --------------------------------------------------------------------------
 
-def _rotation(cfg: DotsConfig, positions, windowed: bool):
-    """(sin, cos) of ``positions`` [B, T] for the kind's rotated part."""
-    k = cfg.kind(windowed)
-    return rotary_embedding(positions, k.dr, k.theta)
+def segment_positions(x, start):
+    """The positions [B, seg] of a segment's rows x [B, seg, D]."""
+    return jnp.broadcast_to(
+        start + jnp.arange(x.shape[1], dtype=jnp.int32), x.shape[:2])
 
 
 @jax.named_scope("qkv")
-def _mla_inputs(cfg: DotsConfig, k: _Kind, p, x, rotation):
+def _mla_inputs(cfg, k: Kind, p, x, rotation):
     """x [B, T, D] (normed) -> (q_nope [B, T, H, dn], q_rope [B, T, H,
     dr] rotated, the latent [B, T, r] normalised and rescaled, k_rope
     [B, T, dr] rotated, the gate [B, T, H] float32 or None, c_q [B, T,
     q_lora]: what the indexer's queries are made of)."""
     b, t, d = x.shape
-    r_q = (d / k.q_lora) ** 0.5 if cfg.lora_rescale else 1.0
-    r_kv = (d / k.kv_lora) ** 0.5 if cfg.lora_rescale else 1.0
+    r_q = (d / k.q_lora) ** 0.5 if k.rescale else 1.0
+    r_kv = (d / k.kv_lora) ** 0.5 if k.rescale else 1.0
     c_q = rms_norm(x @ p["w_qa"], p["q_norm"] * r_q, cfg.rms_eps)
     q = (c_q @ p["w_qb"]).reshape(b, t, k.heads, k.dn + k.dr)
     q_rope = apply_rotary_interleaved(q[..., k.dn:], *rotation)
@@ -310,12 +327,12 @@ def _mla_inputs(cfg: DotsConfig, k: _Kind, p, x, rotation):
     k_rope = apply_rotary_interleaved(kva[..., None, k.kv_lora:], *rotation)
     gate = jax.nn.sigmoid(jnp.dot(
         x, p["w_gate"], preferred_element_type=jnp.float32)) \
-        if cfg.gated_attention else None
+        if k.gated else None
     return q[..., :k.dn], q_rope, latent, k_rope[..., 0, :], gate, c_q
 
 
 @jax.named_scope("qkv")
-def _index_inputs(cfg: DotsConfig, p, x, c_q, rotation):
+def _index_inputs(cfg, p, x, c_q, rotation):
     """-> (q_I [B, T, Hi, di], k_I [B, T, di], w [B, T, Hi] float32):
     the leading ``qk_rope_head_dim`` numbers of every index query and of
     the key rotated, the key through a LayerNorm with a bias."""
@@ -339,7 +356,7 @@ def _index_inputs(cfg: DotsConfig, p, x, c_q, rotation):
     return q_i, k_i, w
 
 
-def _cache_rows(k: _Kind, latent, k_rope):
+def _cache_rows(k: Kind, latent, k_rope):
     """[..., r] and [..., dr] -> the rows a slot keeps [..., row_width]."""
     pad = k.row_width - k.kv_lora - k.dr
     return jnp.concatenate(
@@ -348,7 +365,7 @@ def _cache_rows(k: _Kind, latent, k_rope):
 
 
 @jax.named_scope("attn_out")
-def _mla_out(cfg: DotsConfig, p, o, gate):
+def _mla_out(cfg, p, o, gate):
     """o [B, T, H, dv], gate [B, T, H] -> [B, T, D]: the gate a head,
     then ``W_o``."""
     if gate is not None:
@@ -357,7 +374,7 @@ def _mla_out(cfg: DotsConfig, p, o, gate):
     return o.reshape(*o.shape[:2], -1) @ p["wo"]
 
 
-def _unabsorbed(k: _Kind, p, rows):
+def _unabsorbed(k: Kind, p, rows):
     """Cache rows [B, S, row_width] -> (k [B, H, S, dn + dr], v [B, H, S,
     dv]) in the flash kernels' layout: every head's k_nope and v out of
     the latent, the one rotated key beside each head's k_nope."""
@@ -370,7 +387,7 @@ def _unabsorbed(k: _Kind, p, rows):
     return jnp.concatenate([kv[..., :k.dn], k_rope], axis=-1), kv[..., k.dn:]
 
 
-def _kv_buffers(k: _Kind, b: int, heads: int, t: int, dtype):
+def _kv_buffers(k: Kind, b: int, heads: int, t: int, dtype):
     """The pair :func:`_live_kv` writes a group of ``heads`` heads' k_nope
     [B, heads, T, dn] and v [B, heads, T, dv] into: zeros, made once a
     layer's segment and carried through its groups of heads."""
@@ -378,7 +395,7 @@ def _kv_buffers(k: _Kind, b: int, heads: int, t: int, dtype):
             jnp.zeros((b, heads, t, k.dv), dtype))
 
 
-def _live_kv(k: _Kind, lat_all, w_g, start, seg: int, bufs):
+def _live_kv(k: Kind, lat_all, w_g, start, seg: int, bufs):
     """A group of heads' k_nope and v for the rows a segment at ``start``
     .. can see: the latents of rows ``0 .. start + seg - 1`` of lat_all
     [B, T, row_width], ``seg`` rows at a time (a loop whose trip count is
@@ -447,21 +464,15 @@ def _window_attend(cfg: DotsConfig, q, k, v, offset):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
-def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
-    """A full layer's attention on a segment's normed rows x [B, seg, D]
-    at positions ``start`` .. -> ([B, seg, D], the layer's rows so far
-    with the segment's written)."""
-    k = cfg.kind(False)
-    b, seg, _ = x.shape
-    t = lat_all.shape[1]
+def _selection(cfg, p, x, c_q, rotation, start, idx_all):
+    """An indexer's choice for a segment's rows x [B, seg, D] at
+    positions ``start`` .. -> (the bias [B, seg, T] bfloat16: 0 where
+    the row reads the key, ``dsa.NEG`` where not, the layer's index keys
+    so far with the segment's written)."""
+    seg, t = x.shape[1], idx_all.shape[1]
     at = start + jnp.arange(seg, dtype=jnp.int32)
-    rotation = _rotation(cfg, jnp.broadcast_to(at, (b, seg)), False)
-    q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
-        cfg, k, p, x, rotation)
     q_i, k_i, w = _index_inputs(cfg, p, x, c_q, rotation)
     with jax.named_scope("cache"):
-        lat_all = jax.lax.dynamic_update_slice(
-            lat_all, _cache_rows(k, latent, k_rope), (0, start, 0))
         idx_all = jax.lax.dynamic_update_slice(idx_all, k_i, (0, start, 0))
     with jax.named_scope("attn/attn_index"):
         scores = dsa.index_scores(q_i, w, idx_all, start,
@@ -470,7 +481,27 @@ def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
         chosen = dsa.select(scores, valid[None], min(cfg.index_topk, t),
                             use_kernel=cfg.use_flash)
         bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
-    groups = cfg.prefill_head_groups
+    return bias, idx_all
+
+
+def sparse_segment(cfg, k: Kind, p, x, rotation, start, lat_all, idx_all,
+                   bias):
+    """A sparse layer's attention on a segment's normed rows x [B, seg,
+    D] at positions ``start`` .., ``rotation`` theirs; ``idx_all`` the
+    layer's index keys so far (it owns an indexer: it selects, ``bias``
+    is not read) or None (it owns none: it attends over ``bias``, the
+    selection an indexer layer before it made for these rows). -> ([B,
+    seg, D], the layer's latent rows so far with the segment's written,
+    ``idx_all``, the bias the layer attended over)."""
+    b, seg, _ = x.shape
+    q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
+        cfg, k, p, x, rotation)
+    with jax.named_scope("cache"):
+        lat_all = jax.lax.dynamic_update_slice(
+            lat_all, _cache_rows(k, latent, k_rope), (0, start, 0))
+    if idx_all is not None:
+        bias, idx_all = _selection(cfg, p, x, c_q, rotation, start, idx_all)
+    groups = math.gcd(cfg.prefill_head_groups, k.heads)
     hg = k.heads // groups
     with jax.named_scope("qkv"):  # (groups of heads first, heads outermost)
         def grouped(q):  # [B, seg, H, d] -> [G, B, hg, seg, d]
@@ -479,7 +510,7 @@ def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
         w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
             k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
         k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
-        bufs = _kv_buffers(k, b, hg, t, lat_all.dtype)
+        bufs = _kv_buffers(k, b, hg, lat_all.shape[1], lat_all.dtype)
 
     def group(bufs, xs):
         qn_g, qr_g, w_g = xs
@@ -494,7 +525,7 @@ def _full_segment(cfg: DotsConfig, p, x, start, lat_all, idx_all):
                                       w_kvb))
     with jax.named_scope("attn_out"):  # [G, B, hg, seg, dv] -> [B, seg, H, dv]
         o = o.transpose(1, 3, 0, 2, 4).reshape(b, seg, k.heads, k.dv)
-    return _mla_out(cfg, p, o, gate), lat_all, idx_all
+    return _mla_out(cfg, p, o, gate), lat_all, idx_all, bias
 
 
 def _window_segment(cfg: DotsConfig, p, x, start, lat_all):
@@ -504,10 +535,8 @@ def _window_segment(cfg: DotsConfig, p, x, start, lat_all):
     k = cfg.kind(True)
     b, seg, _ = x.shape
     t = lat_all.shape[1]
-    at = start + jnp.arange(seg, dtype=jnp.int32)
-    rotation = _rotation(cfg, jnp.broadcast_to(at, (b, seg)), True)
     q_nope, q_rope, latent, k_rope, gate, _ = _mla_inputs(
-        cfg, k, p, x, rotation)
+        cfg, k, p, x, k.rotation(segment_positions(x, start)))
     with jax.named_scope("cache"):
         lat_all = jax.lax.dynamic_update_slice(
             lat_all, _cache_rows(k, latent, k_rope), (0, start, 0))
@@ -552,8 +581,11 @@ def prefill(params, tokens, true_lens, cfg: DotsConfig, loads: bool = False,
                 x = rms_norm(h_seg, p["attn_norm"], cfg.rms_eps)
             if windowed:
                 a, *kept = _window_segment(cfg, p["attn"], x, start, *kept)
-            else:
-                a, *kept = _full_segment(cfg, p["attn"], x, start, *kept)
+            else:  # (every full layer selects: no bias is handed in)
+                k = cfg.kind(False)
+                a, *kept, _ = sparse_segment(
+                    cfg, k, p["attn"], x, k.rotation(segment_positions(
+                        x, start)), start, *kept, None)
             with jax.named_scope("attn_out"):
                 h_seg = h_seg + a
             aux = {} if count_loads else None
@@ -590,46 +622,99 @@ def forward(params, tokens, cfg: DotsConfig):
 loss_fn = moe.loss_fn(forward)
 
 
-def _absorbed(k: _Kind, p, q_nope, q_rope):
-    """A step's queries [B, H, .] carried into the row's space -> (q_row
-    [B, H, row_width], the value half of ``w_kvb`` [r, H, dv])."""
-    w_kvb = p["w_kvb"].reshape(k.kv_lora, k.heads, k.dn + k.dv)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_kvb[..., :k.dn],
-                       preferred_element_type=jnp.float32).astype(
-                           q_nope.dtype)
-    return _cache_rows(k, q_lat, q_rope), w_kvb[..., k.dn:]
+def _step_inputs(cfg, k: Kind, p, x, rotation):
+    """A step's normed x [B, 1, D] -> (its queries carried into the
+    row's space q_row [B, H, row_width], the value half of ``w_kvb`` [r,
+    H, dv], the row the slot keeps [B, row_width], the gate, c_q)."""
+    q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
+        cfg, k, p, x, rotation)
+    with jax.named_scope("qkv"):  # (q into the row's space)
+        w_kvb = p["w_kvb"].reshape(k.kv_lora, k.heads, k.dn + k.dv)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :k.dn],
+                           preferred_element_type=jnp.float32)
+        q_row = _cache_rows(k, q_lat.astype(x.dtype), q_rope[:, 0])
+    return (q_row, w_kvb[..., k.dn:],
+            _cache_rows(k, latent[:, 0], k_rope[:, 0]), gate, c_q)
+
+
+def _step_out(cfg, p, o_lat, w_v, gate):
+    """What the heads read in the row's space [B, H, r] -> [B, 1, D]."""
+    with jax.named_scope("attn_out"):  # (and back out of it)
+        o = jnp.einsum("bhr,rhd->bhd", o_lat, w_v,
+                       preferred_element_type=jnp.float32).astype(w_v.dtype)
+        return _mla_out(cfg, p, o[:, None], gate)
+
+
+StepPlan = collections.namedtuple(
+    "StepPlan", "slots pos lengths valid block visits")
+
+
+def step_plan(rows: int, pos, active) -> StepPlan:
+    """What a decode step knows before its layers, of pos, active [B]:
+    slots [B] 0 .. B - 1; pos; lengths [B] int32, 0 if inactive; valid [B,
+    rows]: row < lengths; the masked read's rows a visit; its visits."""
+    with jax.named_scope("attn"):
+        lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
+        valid = jnp.arange(rows, dtype=jnp.int32)[None, :] < lengths[:, None]
+        # the masked read's visits, made once a step, before the layers
+        # (whole lanes of the bias: 34,832 rows are 34 blocks and 16 rows)
+        block = min(_da.LATENT_BLOCK_ROWS, -(-rows // 128) * 128)
+        return StepPlan(jnp.arange(pos.shape[0]), pos, lengths, valid, block,
+                        _da.visits(lengths, rows, block))
+
+
+def sparse_step_layer(cfg, k: Kind, p, x, rotation, plan: StepPlan, state,
+                      layer: int, index_layer, bias):
+    """A sparse layer's attention in a decode step on x [B, 1, D] normed:
+    it writes its latent row at ``state["lat"][layer, slot, pos]``; with
+    an indexer (``index_layer``: its place in ``state["idx"]``) it writes
+    its index key beside it, scores the slot's ``pos + 1`` index keys and
+    selects ``min(index_topk, pos + 1)`` of them, without (None) it reads
+    ``bias``, the newest selection before it; it attends ABSORBED over
+    the slot's latent rows, the unchosen masked. -> ([B, 1, D], the state
+    with the rows written, the bias [B, rows] bfloat16 it attended over (0
+    chosen, ``dsa.NEG`` not), the rows it selected, int32, or None)."""
+    state, selected = dict(state), None
+    q_row, w_v, row, gate, c_q = _step_inputs(cfg, k, p, x, rotation)
+    with jax.named_scope("cache"):
+        state["lat"] = state["lat"].at[layer, plan.slots, plan.pos].set(row)
+    if index_layer is not None:
+        q_i, k_i, w_i = _index_inputs(cfg, p, x, c_q, rotation)
+        with jax.named_scope("cache"):
+            state["idx"] = state["idx"].at[
+                index_layer, plan.slots, plan.pos].set(k_i[:, 0])
+        with jax.named_scope("attn/attn_index"):
+            scores = dsa.index_scores_xla(q_i, w_i, state["idx"][index_layer])
+            chosen = dsa.select(scores[:, 0], plan.valid, min(
+                cfg.index_topk, plan.valid.shape[1]), use_kernel=cfg.use_flash)
+            selected = jnp.sum(chosen, dtype=jnp.int32)
+            bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+    with jax.named_scope("attn/attn_sparse"):
+        o_lat = dsa.decode_attention_masked(
+            q_row, state["lat"], layer, plan.lengths, bias, dv=k.kv_lora,
+            scale=(k.dn + k.dr) ** -0.5, plan=plan.visits, block=plan.block,
+            use_kernel=cfg.use_flash)
+    return _step_out(cfg, p, o_lat, w_v, gate), state, bias, selected
 
 
 def step(cfg: DotsConfig, params, tok, state, pos, active):
     """One token a slot at PER-SLOT positions. tok, pos, active [B];
     ``state`` the three stacks (:meth:`_Slots.init_state`, without
-    ``pos``). A full layer writes its latent row and index key at
-    ``[layer, slot, pos]``, scores the slot's ``pos + 1`` index keys,
-    selects ``min(index_topk, pos + 1)`` of them and attends over those
-    latent rows alone (the others masked); a window layer writes at
-    ``[layer, slot,
-    pos % window]`` and attends over the ring's ``min(pos + 1, window)``
-    rows; an inactive slot attends over nothing. -> (float32 logits [B,
-    V], the state updated, three [L_moe] int32 counters of the ACTIVE
-    slots' routing, and [1] int32: the rows the full layers selected,
-    summed over active slots and layers)."""
-    b = tok.shape[0]
+    ``pos``). A full layer is :func:`sparse_step_layer` with an indexer
+    of its own; a window layer writes at ``[layer, slot, pos % window]``
+    and attends over the ring's ``min(pos + 1, window)`` rows; an
+    inactive slot attends over nothing. -> (float32 logits [B, V], the
+    state updated, three [L_moe] int32 counters of the ACTIVE slots'
+    routing, and [1] int32: the rows the full layers selected, summed
+    over active slots and layers)."""
     w = cfg.sliding_window
-    slots = jnp.arange(b)
-    size = state["lat"].shape[2]
-    top = min(cfg.index_topk, size)
     with jax.named_scope("embed"):
         h = params["embed"][tok][:, None]  # [B, 1, D]
+    plan = step_plan(state["lat"].shape[2], pos, active)
     with jax.named_scope("attn"):
-        lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
-        ring_lengths = jnp.minimum(lengths, w)
-        valid = jnp.arange(size, dtype=jnp.int32)[None, :] < lengths[:, None]
-        # the masked read's visits, made once a step, before the layers
-        # (whole lanes of the bias: 34,832 rows are 34 blocks and 16 rows)
-        block = min(_da.LATENT_BLOCK_ROWS, -(-size // 128) * 128)
-        plan = _da.visits(lengths, size, block)
+        ring_lengths = jnp.minimum(plan.lengths, w)
     with jax.named_scope("qkv"):
-        rotations = {windowed: _rotation(cfg, pos[:, None], windowed)
+        rotations = {windowed: cfg.kind(windowed).rotation(pos[:, None])
                      for windowed in (False, True)}
     state = dict(state)
     counts, selected = [], jnp.int32(0)
@@ -638,40 +723,25 @@ def step(cfg: DotsConfig, params, tok, state, pos, active):
         k, a, layer = cfg.kind(windowed), p["attn"], cfg.stack_index(i)
         with jax.named_scope("qkv"):
             x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-        q_nope, q_rope, latent, k_rope, gate, c_q = _mla_inputs(
-            cfg, k, a, x, rotations[windowed])
-        with jax.named_scope("qkv"):  # (q into the row's space)
-            q_row, w_v = _absorbed(k, a, q_nope[:, 0], q_rope[:, 0])
-        row = _cache_rows(k, latent[:, 0], k_rope[:, 0])
         if windowed:
+            q_row, w_v, row, gate, _ = _step_inputs(
+                cfg, k, a, x, rotations[True])
             with jax.named_scope("cache"):
-                state["ring"] = state["ring"].at[layer, slots, pos % w].set(
-                    row)
+                state["ring"] = state["ring"].at[
+                    layer, plan.slots, pos % w].set(row)
             with jax.named_scope("attn/attn_window"):
                 o_lat = _da.attend_latent(
                     q_row, state["ring"][layer], ring_lengths, k.kv_lora,
                     (k.dn + k.dr) ** -0.5)
-        else:
-            q_i, k_i, w_i = _index_inputs(cfg, a, x, c_q, rotations[False])
-            with jax.named_scope("cache"):
-                state["lat"] = state["lat"].at[layer, slots, pos].set(row)
-                state["idx"] = state["idx"].at[layer, slots, pos].set(
-                    k_i[:, 0])
+            out = _step_out(cfg, a, o_lat, w_v, gate)
+        else:  # (an indexer of its own: no selection is handed in)
+            out, state, _, rows = sparse_step_layer(
+                cfg, k, a, x, rotations[False], plan, state, layer, layer,
+                None)
             with jax.named_scope("attn/attn_index"):
-                scores = dsa.index_scores_xla(q_i, w_i, state["idx"][layer])
-                chosen = dsa.select(scores[:, 0], valid, top,
-                                    use_kernel=cfg.use_flash)
-                selected = selected + jnp.sum(chosen, dtype=jnp.int32)
-                bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
-            with jax.named_scope("attn/attn_sparse"):
-                o_lat = dsa.decode_attention_masked(
-                    q_row, state["lat"], layer, lengths, bias,
-                    dv=k.kv_lora, scale=(k.dn + k.dr) ** -0.5, plan=plan,
-                    block=block, use_kernel=cfg.use_flash)
-        with jax.named_scope("attn_out"):  # (and back out of it)
-            o = jnp.einsum("bhr,rhd->bhd", o_lat, w_v,
-                           preferred_element_type=jnp.float32).astype(h.dtype)
-            h = h + _mla_out(cfg, a, o[:, None], gate)
+                selected = selected + rows
+        with jax.named_scope("attn_out"):
+            h = h + out
         aux = {} if cfg.sparse(i) else None
         h = moe.mlp_layer(cfg, cfg.sparse(i), p, h, aux)
         if aux:
@@ -685,25 +755,67 @@ def step(cfg: DotsConfig, params, tok, state, pos, active):
 # The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots(Slots):
+class SparseSlots(Slots):
+    """What the slots of a block of sparse latent layers do whatever
+    their stacks (this block's, ``models/glm_dsa.py``'s): a state of named
+    stacks ``[layers, slots, rows, width]`` beside ``pos``, ``lat`` of
+    ``max_len`` rows; segments; float32 leaves; the selected rows."""
+
+    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "kv_norm",
+                  "ik_norm", "ik_bias", "router_bias")
+    step_counters = (*Slots.step_counters, "selected_rows")
+    STACKS: dict = {}  # a kind of row (``row_kinds``') -> the stack of them
+
+    @staticmethod
+    def prefill_segments(cfg, bucket: int) -> int:
+        return bucket // moe.segment_rows(bucket)
+
+    @staticmethod
+    def max_len(state: dict) -> int:
+        return state["lat"].shape[2]
+
+    @classmethod
+    def state_bytes(cls, state: dict) -> dict:
+        # (by shape: the state may be described only)
+        return {kind: state[name].size * state[name].dtype.itemsize
+                for kind, name in cls.STACKS.items()}
+
+    @staticmethod
+    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
+        """The prefilled streams' rows into their slots, stack by stack:
+        a stream's P rows onto the first P rows of the slot (a ring is
+        all of its rows: replaced whole, and a prompt shorter than the
+        window leaves zeros). What the slot's last stream wrote behind
+        them stays: no reader looks past a slot's own length (the
+        selection's ``valid``, the ring's length)."""
+        def put(all_, layers):  # [L, slots, S, C] <- L x [F, P <= S, C]
+            # a layer and a stream at a time, each an update in place
+            # (``mimo._Slots.scatter`` says why)
+            for layer, new in enumerate(layers):
+                for f in range(new.shape[0]):
+                    all_ = jax.lax.dynamic_update_slice(
+                        all_, new[None, f:f + 1].astype(all_.dtype),
+                        (layer, slots[f], 0, 0))
+            return all_
+
+        return {**{name: put(state[name], new)
+                   for name, new in streams.items()},
+                "pos": state["pos"].at[slots].set(full_lens)}
+
+
+class _Slots(SparseSlots):
     """Three stacks of rows: the full layers' latent rows, the indexer's
     keys beside them (read by the indexer, attended by nobody) and the
     window layers' rings of latent rows, which cannot be cut or rewound
     at a position."""
 
-    F32_LEAVES = ("attn_norm", "mlp_norm", "final_norm", "q_norm", "kv_norm",
-                  "ik_norm", "ik_bias", "router_bias")
-    step_counters = (*Slots.step_counters, "selected_rows")
+    STACKS = {"full": "lat", "index": "idx", "ring": "ring"}
 
     @staticmethod
     def row_kinds(cfg: DotsConfig) -> dict:
         return {"full": (cfg.full_layers, None),
                 "index": (cfg.full_layers, None),
                 "ring": (cfg.window_layers, cfg.sliding_window)}
-
-    @staticmethod
-    def prefill_segments(cfg: DotsConfig, bucket: int) -> int:
-        return bucket // moe.segment_rows(bucket)
 
     @staticmethod
     def init_state(cfg: DotsConfig, slots: int, max_len: int) -> dict:
@@ -716,17 +828,6 @@ class _Slots(Slots):
             "ring": jnp.zeros((lw, slots, cfg.sliding_window,
                                cfg.kind(True).row_width), cdt),
             "pos": jnp.zeros((slots,), jnp.int32)}
-
-    @staticmethod
-    def max_len(state: dict) -> int:
-        return state["lat"].shape[2]
-
-    @staticmethod
-    def state_bytes(state: dict) -> dict:
-        # (by shape: the state may be described only)
-        return {kind: state[name].size * state[name].dtype.itemsize
-                for kind, name in (("full", "lat"), ("index", "idx"),
-                                   ("ring", "ring"))}
 
     @staticmethod
     def step(cfg: DotsConfig, params, prepared, tok, state, pos, active):
@@ -757,28 +858,6 @@ class _Slots(Slots):
                    "ring": [r[0] for i, r in enumerate(rows)
                             if cfg.windowed(i)]}
         return streams, true_lens, toks0, logp0, *(loads or ())
-
-    @staticmethod
-    def scatter(state: dict, slots, streams: dict, full_lens) -> dict:
-        """The prefilled streams' rows into their slots: a ring replaced
-        whole (a prompt shorter than the window leaves zeros), a full
-        layer's P latent rows and index keys onto the first P rows of
-        the slot. What the slot's last stream wrote behind them stays:
-        no reader looks past a slot's own length (the selection's
-        ``valid``, the ring's length)."""
-        def put(all_, layers):  # [L, slots, S, C] <- L x [F, P <= S, C]
-            # a layer and a stream at a time, each an update in place
-            # (``mimo._Slots.scatter`` says why)
-            for layer, new in enumerate(layers):
-                for f in range(new.shape[0]):
-                    all_ = jax.lax.dynamic_update_slice(
-                        all_, new[None, f:f + 1].astype(all_.dtype),
-                        (layer, slots[f], 0, 0))
-            return all_
-
-        return {**{name: put(state[name], new)
-                   for name, new in streams.items()},
-                "pos": state["pos"].at[slots].set(full_lens)}
 
 
 SLOTS = _Slots

@@ -11,14 +11,15 @@ it; the ninth block.
 
 ``x`` is a layer's normed input, ``N`` a learned RMS norm:
 
-- **A layer's MLA** (``models/dots.py``'s full layer without the gate
-  and the rescale, whose functions it is): ``c_q = N(x W_qa)``, ``q =
-  c_q W_qb`` as heads of ``[q_n | q_r]``; ``[c | k_r] = x W_kva``, ``c <-
-  N(c)``; ``q_r``, ``k_r`` rotated (interleaved pairs), ``k_r`` one for
-  all heads; ``[k_n | v]_h = c W_kvb,h``; scores ``(q_n k_n + q_r k_r)
-  (dn + dr)^-1/2`` over the CHOSEN keys, float32 softmax; ``W_o``.
-- **The indexer** (indexer layers): ``dots._index_inputs`` and
-  ``ops/dsa.py``: ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``
+- **A layer's MLA** (``models/dots.py``'s sparse layer at a ``Kind``
+  without gate and rescale: ``dots.sparse_segment`` in a prefill, ``dots.
+  sparse_step_layer`` in a step): ``c_q = N(x W_qa)``, ``q = c_q W_qb``
+  as heads of ``[q_n | q_r]``; ``[c | k_r] = x W_kva``, ``c <- N(c)``;
+  ``q_r``, ``k_r`` rotated (interleaved pairs), ``k_r`` one for all
+  heads; ``[k_n | v]_h = c W_kvb,h``; scores ``(q_n k_n + q_r k_r) (dn +
+  dr)^-1/2`` over the CHOSEN keys, float32 softmax; ``W_o``.
+- **The indexer** (indexer layers), the eighth block's and
+  ``ops/dsa.py``'s: ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``
   in float32; query t reads the ``min(index_topk, t + 1)`` positions ``s
   <= t`` of largest ``I``, a tie to the earlier, exactly. Until a stream
   holds more rows than ``index_topk`` every layer is plain causal MLA.
@@ -52,16 +53,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import dots, moe
-from ray_tpu.ops import decode_attention as _da
-from ray_tpu.ops import dsa
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.rope import rotary_embedding
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,11 +106,6 @@ class GlmDsaConfig(moe.HeldExperts):
     # n_layers. A configuration cut in depth names its model's own.
     published_layers: int = 0
 
-    # what ``dots._mla_inputs`` / ``_mla_out`` ask of a configuration
-    # and this model has not: no rescale of the latents, no gate a head
-    lora_rescale = False
-    gated_attention = False
-
     def __post_init__(self):
         n = self.n_layers
         own = tuple(self.indexer_layers) or tuple(
@@ -132,15 +124,17 @@ class GlmDsaConfig(moe.HeldExperts):
         return i >= self.first_k_dense
 
     @property
-    def mla(self) -> dots._Kind:
-        """The widths of a layer's attention."""
-        return dots._Kind(self.n_heads, self.q_lora_rank, self.kv_lora_rank,
-                          self.qk_nope_head_dim, self.qk_rope_head_dim,
-                          self.v_head_dim, self.rope_theta)
+    def mla(self) -> dots.Kind:
+        """The widths of a layer's attention: no rescale of the latents,
+        no gate a head."""
+        return dots.Kind(self.n_heads, self.q_lora_rank, self.kv_lora_rank,
+                         self.qk_nope_head_dim, self.qk_rope_head_dim,
+                         self.v_head_dim, self.rope_theta, False, False)
 
-    def index_stack(self, i: int) -> int:
-        """Indexer layer ``i``'s place in the stack of index keys."""
-        return sum(self.indexer_layers[:i])
+    def index_stack(self, i: int) -> int | None:
+        """Indexer layer ``i``'s place in the stack of index keys; None
+        for a layer that owns no indexer."""
+        return sum(self.indexer_layers[:i]) if self.indexes(i) else None
 
     @property
     def share_groups(self) -> tuple:
@@ -187,113 +181,16 @@ class GlmDsaConfig(moe.HeldExperts):
 # --------------------------------------------------------------------------
 
 def init_params(cfg: GlmDsaConfig, key):
-    """The tree in the SERVING types, leaf by leaf in blocks
-    (``moe.draw``): the eighth block's initialisation without its
-    rescale (``dots.init_params``): matrices normal / sqrt(fan_in),
-    every ``w_down`` scaled by (2 x depth)^-1/2 besides (depth is
-    ``published_layers``), ``wo`` not; norm scales around 1, the index
-    key's bias and the router's bias away from 0. A SHARED layer's
-    ``attn`` holds no ``w_iq``, ``w_ik``, ``ik_norm``, ``ik_bias``,
-    ``w_iw``."""
-    d, k = cfg.d_model, cfg.mla
-    keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
-    mat, around_one = moe.makers(cfg, keys)
-
-    def attention(indexes: bool):
-        p = {"w_qa": mat(d, k.q_lora), "q_norm": around_one(k.q_lora),
-             "w_qb": mat(k.q_lora, k.heads * (k.dn + k.dr)),
-             "w_kva": mat(d, k.kv_lora + k.dr),
-             "kv_norm": around_one(k.kv_lora),
-             "w_kvb": mat(k.kv_lora, k.heads * (k.dn + k.dv)),
-             "wo": mat(k.heads * k.dv, d)}
-        if indexes:
-            di = cfg.index_head_dim
-            p.update({
-                "w_iq": mat(k.q_lora, cfg.index_heads * di),
-                "w_ik": mat(d, di), "ik_norm": around_one(di),
-                "ik_bias": 0.1 * jax.random.normal(
-                    next(keys), (di,), jnp.float32),
-                "w_iw": mat(d, cfg.index_heads)})
-        return p
-
-    layers = [{
-        "attn_norm": around_one(d), "attn": attention(cfg.indexes(i)),
-        "mlp_norm": around_one(d),
-        "mlp": moe.init_experts(cfg, mat, keys) if cfg.sparse(i)
-        else moe.init_dense(cfg, mat),
-    } for i in range(cfg.n_layers)]
-    return moe.init_model(cfg, mat, around_one, keys, layers)
+    """The eighth block's initialisation (``dots.init_layers``) without
+    its rescale. A SHARED layer's ``attn`` holds no ``w_iq``, ``w_ik``,
+    ``ik_norm``, ``ik_bias``, ``w_iw``."""
+    return dots.init_layers(cfg, key, [(cfg.mla, bool(own))
+                                       for own in cfg.indexer_layers])
 
 
 # --------------------------------------------------------------------------
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
-
-def _selection(cfg: GlmDsaConfig, p, x, c_q, rotation, start, idx_all):
-    """An indexer layer's choice for a segment's rows x [B, seg, D] at
-    positions ``start`` .. -> (the bias [B, seg, T] bfloat16: 0 where
-    the row reads the key, ``dsa.NEG`` where not, the layer's index keys
-    so far with the segment's written)."""
-    seg, t = x.shape[1], idx_all.shape[1]
-    at = start + jnp.arange(seg, dtype=jnp.int32)
-    q_i, k_i, w = dots._index_inputs(cfg, p, x, c_q, rotation)
-    with jax.named_scope("cache"):
-        idx_all = jax.lax.dynamic_update_slice(idx_all, k_i, (0, start, 0))
-    with jax.named_scope("attn/attn_index"):
-        scores = dsa.index_scores(q_i, w, idx_all, start,
-                                  use_kernel=cfg.use_flash)
-        valid = jnp.arange(t, dtype=jnp.int32)[None, :] <= at[:, None]
-        chosen = dsa.select(scores, valid[None], min(cfg.index_topk, t),
-                            use_kernel=cfg.use_flash)
-        bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
-    return bias, idx_all
-
-
-def _segment(cfg: GlmDsaConfig, p, x, start, lat_all, idx_all, bias):
-    """A layer's attention on a segment's normed rows x [B, seg, D] at
-    positions ``start`` ..; ``idx_all`` the layer's index keys so far
-    (an indexer layer: it selects, ``bias`` is not read) or None (a
-    shared layer: it attends over ``bias``, the selection of the
-    group's indexer layer for these rows). -> ([B, seg, D], the layer's
-    latent rows so far with the segment's written, ``idx_all``, the
-    bias the layer attended over)."""
-    k = cfg.mla
-    b, seg, _ = x.shape
-    at = start + jnp.arange(seg, dtype=jnp.int32)
-    rotation = rotary_embedding(jnp.broadcast_to(at, (b, seg)), k.dr, k.theta)
-    q_nope, q_rope, latent, k_rope, _, c_q = dots._mla_inputs(
-        cfg, k, p, x, rotation)
-    with jax.named_scope("cache"):
-        lat_all = jax.lax.dynamic_update_slice(
-            lat_all, dots._cache_rows(k, latent, k_rope), (0, start, 0))
-    if idx_all is not None:
-        bias, idx_all = _selection(cfg, p, x, c_q, rotation, start, idx_all)
-    groups = math.gcd(cfg.prefill_head_groups, k.heads)
-    hg = k.heads // groups
-    with jax.named_scope("qkv"):  # (groups of heads first, heads outermost)
-        def grouped(q):  # [B, seg, H, d] -> [G, B, hg, seg, d]
-            return q.reshape(b, seg, groups, hg, -1).transpose(2, 0, 3, 1, 4)
-
-        w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
-            k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
-        k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
-        bufs = dots._kv_buffers(k, b, hg, lat_all.shape[1], lat_all.dtype)
-
-    def group(bufs, xs):
-        qn_g, qr_g, w_g = xs
-        with jax.named_scope("qkv"):  # (k and v out of the live latents)
-            k_g, v_g = bufs = dots._live_kv(k, lat_all, w_g, start, seg, bufs)
-        with jax.named_scope("attn/attn_sparse"):
-            return bufs, dsa.masked_attention(
-                qn_g, qr_g, k_g, k_r, v_g, bias, start,
-                scale=(k.dn + k.dr) ** -0.5, use_kernel=cfg.use_flash)
-
-    _, o = jax.lax.scan(group, bufs, (grouped(q_nope), grouped(q_rope),
-                                      w_kvb))
-    with jax.named_scope("attn_out"):  # [G, B, hg, seg, dv] -> [B, seg, H, dv]
-        o = o.transpose(1, 3, 0, 2, 4).reshape(b, seg, k.heads, k.dv)
-    return dots._mla_out(cfg, p, o, None), lat_all, idx_all, bias
-
 
 def prefill(params, tokens, true_lens, cfg: GlmDsaConfig,
             loads: bool = False, live=None):
@@ -319,13 +216,14 @@ def prefill(params, tokens, true_lens, cfg: GlmDsaConfig,
             lats, idx_all, count = carry
             start, h_seg = xs
             lats, count = list(lats), list(count)
+            rotation = cfg.mla.rotation(dots.segment_positions(h_seg, start))
             bias = None
             for n, i in enumerate(layers):
                 p = params["layers"][i]
                 with jax.named_scope("qkv"):
                     x = rms_norm(h_seg, p["attn_norm"], cfg.rms_eps)
-                a, lats[n], kept, bias = _segment(
-                    cfg, p["attn"], x, start, lats[n],
+                a, lats[n], kept, bias = dots.sparse_segment(
+                    cfg, cfg.mla, p["attn"], x, rotation, start, lats[n],
                     idx_all if n == 0 else None, bias)
                 if n == 0:
                     idx_all = kept
@@ -366,67 +264,36 @@ loss_fn = moe.loss_fn(forward)
 def step(cfg: GlmDsaConfig, params, tok, state, pos, active):
     """One token a slot at PER-SLOT positions. tok, pos, active [B];
     ``state`` the two stacks (:meth:`_Slots.init_state`, without
-    ``pos``). Every layer writes its latent row at ``[layer, slot,
-    pos]``; an indexer layer writes its index key beside it, scores the
-    slot's ``pos + 1`` index keys and selects ``min(index_topk, pos +
-    1)`` of them; every layer attends over the latent rows of the
-    newest selection alone (the others masked); an inactive slot attends
-    over nothing. -> (float32 logits [B, V], the state updated, three
-    [L_moe] int32 counters of the ACTIVE slots' routing, and two [1]
-    int32: the rows the INDEXER layers selected, summed over active
-    slots and those layers, and the chosen rows the attentions of ALL
-    layers were handed, summed over active slots and layers)."""
-    b = tok.shape[0]
+    ``pos``). Every layer is ``dots.sparse_step_layer``: an indexer
+    layer selects, and every layer attends over the latent rows of the
+    newest selection alone; an inactive slot attends over nothing. ->
+    (float32 logits [B, V], the state updated, three [L_moe] int32
+    counters of the ACTIVE slots' routing, and two [1] int32: the rows
+    the INDEXER layers selected, summed over active slots and those
+    layers, and the chosen rows the attentions of ALL layers were
+    handed, summed over active slots and layers)."""
     k = cfg.mla
-    slots = jnp.arange(b)
-    size = state["lat"].shape[2]
-    top = min(cfg.index_topk, size)
     with jax.named_scope("embed"):
         h = params["embed"][tok][:, None]  # [B, 1, D]
-    with jax.named_scope("attn"):
-        lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
-        valid = jnp.arange(size, dtype=jnp.int32)[None, :] < lengths[:, None]
-        # the masked read's visits, made once a step, before the layers
-        block = min(_da.LATENT_BLOCK_ROWS, -(-size // 128) * 128)
-        plan = _da.visits(lengths, size, block)
+    plan = dots.step_plan(state["lat"].shape[2], pos, active)
     with jax.named_scope("qkv"):
-        rotation = rotary_embedding(pos[:, None], k.dr, k.theta)
-    state = dict(state)
+        rotation = k.rotation(pos[:, None])
     counts, selected, attended = [], jnp.int32(0), jnp.int32(0)
     bias = chosen_rows = None
     for i, p in enumerate(params["layers"]):
-        a = p["attn"]
         with jax.named_scope("qkv"):
             x = rms_norm(h, p["attn_norm"], cfg.rms_eps)
-        q_nope, q_rope, latent, k_rope, _, c_q = dots._mla_inputs(
-            cfg, k, a, x, rotation)
-        with jax.named_scope("qkv"):  # (q into the row's space)
-            q_row, w_v = dots._absorbed(k, a, q_nope[:, 0], q_rope[:, 0])
-        with jax.named_scope("cache"):
-            state["lat"] = state["lat"].at[i, slots, pos].set(
-                dots._cache_rows(k, latent[:, 0], k_rope[:, 0]))
-        if cfg.indexes(i):
-            at = cfg.index_stack(i)
-            q_i, k_i, w_i = dots._index_inputs(cfg, a, x, c_q, rotation)
-            with jax.named_scope("cache"):
-                state["idx"] = state["idx"].at[at, slots, pos].set(k_i[:, 0])
+        a, state, bias, rows = dots.sparse_step_layer(
+            cfg, k, p["attn"], x, rotation, plan, state, i,
+            cfg.index_stack(i), bias)
+        if rows is not None:
+            chosen_rows = rows
             with jax.named_scope("attn/attn_index"):
-                scores = dsa.index_scores_xla(q_i, w_i, state["idx"][at])
-                chosen = dsa.select(scores[:, 0], valid, top,
-                                    use_kernel=cfg.use_flash)
-                chosen_rows = jnp.sum(chosen, dtype=jnp.int32)
-                selected = selected + chosen_rows
-                bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+                selected = selected + rows
         with jax.named_scope("attn/attn_sparse"):
             attended = attended + chosen_rows
-            o_lat = dsa.decode_attention_masked(
-                q_row, state["lat"], i, lengths, bias, dv=k.kv_lora,
-                scale=(k.dn + k.dr) ** -0.5, plan=plan, block=block,
-                use_kernel=cfg.use_flash)
-        with jax.named_scope("attn_out"):  # (and back out of it)
-            o = jnp.einsum("bhr,rhd->bhd", o_lat, w_v,
-                           preferred_element_type=jnp.float32).astype(h.dtype)
-            h = h + dots._mla_out(cfg, a, o[:, None], None)
+        with jax.named_scope("attn_out"):
+            h = h + a
         aux = {} if cfg.sparse(i) else None
         h = moe.mlp_layer(cfg, cfg.sparse(i), p, h, aux)
         if aux:
@@ -440,14 +307,13 @@ def step(cfg: GlmDsaConfig, params, tok, state, pos, active):
 # The serving engine's half (the protocol: models/slots.py)
 # --------------------------------------------------------------------------
 
-class _Slots(dots._Slots):
+class _Slots(dots.SparseSlots):
     """Two stacks of rows with different layer counts: every layer's
     latent rows, and the indexer layers' keys (read by the indexer,
-    attended by nobody). What the eighth block's slots do with a stack
-    whatever its name (``scatter``, ``max_len``, the prefill's
-    segments, the float32 leaves) is theirs."""
+    attended by nobody)."""
 
-    step_counters = (*dots._Slots.step_counters, "attended_rows")
+    step_counters = (*dots.SparseSlots.step_counters, "attended_rows")
+    STACKS = {"latent": "lat", "index": "idx"}
 
     @staticmethod
     def row_kinds(cfg: GlmDsaConfig) -> dict:
@@ -465,12 +331,6 @@ class _Slots(dots._Slots):
             "pos": jnp.zeros((slots,), jnp.int32)}
 
     @staticmethod
-    def state_bytes(state: dict) -> dict:
-        # (by shape: the state may be described only)
-        return {kind: state[name].size * state[name].dtype.itemsize
-                for kind, name in (("latent", "lat"), ("index", "idx"))}
-
-    @staticmethod
     def step(cfg: GlmDsaConfig, params, prepared, tok, state, pos, active):
         return step(cfg, params, tok, state, pos, active)
 
@@ -484,11 +344,11 @@ class _Slots(dots._Slots):
         first tokens, [F] their logprobs, the held experts' assignments
         from the real positions [L_moe, count], the expert layer's calls
         and compact calls [2])."""
-        dots._Slots.refuse_prefix(cfg, prefix)
+        _Slots.refuse_prefix(cfg, prefix)
         h, rows, loads = prefill(params, prompts, true_lens, cfg,
                                  loads=cfg.moe_layers > 0,
                                  live=jnp.max(true_lens))
-        toks0, logp0 = dots._Slots.first_token(
+        toks0, logp0 = _Slots.first_token(
             functools.partial(moe.logits, cfg), params, h, true_lens,
             seeds, temps, top_ps)
         # a list a stack, one entry a layer that keeps such rows

@@ -1,7 +1,7 @@
 """What the tests of the segmented blocks (``test_solar_block.py``,
-``test_mimo_block.py``, ``test_dots_block.py``, ``test_glm_dsa_block.py``)
-share: buckets cut into segments of 16 rows, one prompt through the
-engine's prefill program into a slot, greedy steps of that slot alone,
+``test_mimo_block.py``, ``test_sparse_blocks.py``) share: buckets cut
+into segments of 16 rows, one prompt through the engine's prefill
+program into a slot, greedy steps of that slot alone,
 the comparison of a short prompt in a long bucket (dead segments behind
 it, ``moe.in_segments``) with the reference, and what holds a sparse
 layer's k and v to the rows its segment can see (:func:`live_kv_case`).

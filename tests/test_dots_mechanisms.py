@@ -138,16 +138,12 @@ def test_a_ring_reads_exactly_its_window(model):
         """The step's attention at row 30 over a ring cut from ``rows``
         (positions 0..29), row 30 written by the step itself."""
         ring = dots.ring_rows(rows[:, :30], jnp.array([30]), W)
-        rot = dots._rotation(cfg, jnp.array([[30]]), True)
-        q_n, q_r, latent, k_r, gate, _ = dots._mla_inputs(
-            cfg, k, p, x[:, 30:], rot)
-        ring = ring.at[0, 30 % W].set(
-            dots._cache_rows(k, latent[0, 0], k_r[0, 0]))
-        q_row, w_v = dots._absorbed(k, p, q_n[:, 0], q_r[:, 0])
+        q_row, w_v, row, gate, _ = dots._step_inputs(
+            cfg, k, p, x[:, 30:], k.rotation(jnp.array([[30]])))
+        ring = ring.at[0, 30 % W].set(row[0])
         o = dots._da.attend_latent(q_row, ring, jnp.array([W]), k.kv_lora,
                                    (k.dn + k.dr) ** -0.5)
-        o = jnp.einsum("bhr,rhd->bhd", o, w_v)
-        return dots._mla_out(cfg, p, o[:, None], gate)[0, 0]
+        return dots._step_out(cfg, p, o, w_v, gate)[0, 0]
 
     base = step_at_30(lat)
     assert float(jnp.abs(base - y[0, 30]).max()) < F32_TOL

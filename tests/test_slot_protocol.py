@@ -8,7 +8,8 @@ the serving tier name no block; and a block that states nothing but what
 is its own is served.
 
 What is one block's alone (its layers against its reference, its state's
-bytes, its spans) is in ``tests/test_<block>_block.py``.
+bytes, its spans) is in ``tests/test_<block>_block.py``; the two sparse
+blocks' in ``tests/test_sparse_blocks.py``.
 """
 
 import ast
@@ -20,8 +21,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decode_engine as de
-from ray_tpu.models import (exaone, glm_dsa, granite, instella, ling, llama,
-                            mimo, moe, solar)
+from ray_tpu.models import (dots, exaone, glm_dsa, granite, instella, ling,
+                            llama, mimo, moe, solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -36,11 +37,13 @@ BLOCKS = {
     "solar": (solar, solar.SolarConfig.tiny),
     "mimo": (mimo, mimo.MimoConfig.tiny),
     "granite": (granite, granite.GraniteConfig.tiny),
+    "dots": (dots, dots.DotsConfig.tiny),
     "glm_dsa": (glm_dsa, glm_dsa.GlmDsaConfig.tiny),
 }
-# the block whose step counts what its indexers chose besides the
-# routing: it states ``step_counters`` of its own
-SELECTS = ("glm_dsa",)
+# the blocks whose step counts what its indexers chose besides the
+# routing: their slots state ``step_counters`` of their own
+# (``dots.SparseSlots``, and GLM-5.2's ``attended_rows`` behind it)
+SELECTS = ("dots", "glm_dsa")
 ROWS = [name for name in BLOCKS if name.startswith("llama")]
 OWN = [name for name in BLOCKS if name not in ROWS]
 
@@ -249,6 +252,24 @@ def test_the_engine_and_the_serving_tier_name_no_block(module, allowed):
         tree.body = [n for n in tree.body
                      if getattr(n, "name", "") != "build_model"]
     assert _block_names_in(tree) == allowed
+
+
+def test_the_ninth_block_reaches_no_private_name_of_the_eighth():
+    """``models/glm_dsa.py`` runs the sparse layer that ``models/dots.py``
+    owns through that module's public names alone, and its configuration
+    states no field whose only reader is another module's function
+    (whether a layer's latents are rescaled and its heads gated is the
+    ``dots.Kind``'s to say)."""
+    with open(glm_dsa.__file__) as f:
+        tree = ast.parse(f.read())
+    reached = {node.attr for node in ast.walk(tree)
+               if isinstance(node, ast.Attribute) and node.attr[0] == "_"
+               and isinstance(node.value, ast.Name) and node.value.id == "dots"}
+    assert not reached, sorted(reached)
+    cfg = glm_dsa.GlmDsaConfig.tiny()
+    for field in ("lora_rescale", "gated_attention"):
+        assert not hasattr(cfg, field), field
+    assert (cfg.mla.rescale, cfg.mla.gated) == (False, False)
 
 
 def test_no_block_imports_the_engine():
