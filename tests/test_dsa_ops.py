@@ -57,10 +57,39 @@ def test_the_index_kernel_in_the_interpreter_is_its_xla_body(heads, offset,
                          block_q=128)
 
 
-# (heads of a call, the unrotated part, values): dots3's full layer, 4 of
-# its heads; GLM-5.2's, a group of 8 (192: one and a half lane tiles;
-# 256: two)
-_HEAD = {"dots3": (4, 128, 128), "glm": (8, 192, 256)}
+# (heads of a call, the unrotated part, the rotated part, values): dots3's
+# full layer, 4 of its heads; GLM-5.2's, a group of 8 (192: one and a half
+# lane tiles; 256: two); and widths no block has, which the rule must
+# judge by themselves: both parts whole tiles, and both half a tile
+_HEAD = {"dots3": (4, 128, 64, 128), "glm": (8, 192, 64, 256),
+         "whole": (2, 128, 128, 128), "halves": (2, 64, 64, 128)}
+
+
+@pytest.mark.parametrize("dn, dr, one", [
+    pytest.param(192, 64, True, id="192+64-one-product"),
+    pytest.param(128, 64, False, id="128+64-two-products"),
+    pytest.param(128, 128, False, id="128+128-two-products"),
+    pytest.param(64, 64, True, id="64+64-one-product"),
+    pytest.param(320, 64, True, id="320+64-one-product"),
+    pytest.param(256, 64, False, id="256+64-two-products")])
+def test_the_rule_names_the_body_a_pair_of_widths_takes(dn, dr, one):
+    """``dsa._one_product``: a head's score tile is one product where
+    the two parts fill fewer 128-lane tiles together than apart (GLM-5.2
+    192 + 64: two for three), two where joining them spares the matrix
+    unit nothing (dots3 128 + 64, 128 + 128): the widths alone decide,
+    and ``masked_attention``'s kernel holds the join's scratch exactly
+    there."""
+    assert dsa._one_product(dn, dr) is one
+    text = str(jax.make_jaxpr(functools.partial(
+        dsa.masked_attention, scale=1.0, interpret=True, block_q=128,
+        block_k=128, heads=1))(
+        jnp.zeros((1, 1, 128, dn)), jnp.zeros((1, 1, 128, dr)),
+        jnp.zeros((1, 1, 128, dn)), jnp.zeros((1, 128, dr)),
+        jnp.zeros((1, 1, 128, 256)), jnp.zeros((1, 128, 128), jnp.bfloat16),
+        0))
+    # the score tile's product(s) and ``v^T @ p``
+    assert text.count("dot_general") == (2 if one else 3)
+    assert (f"Ref<vmem>{{f32[1,128,{dn + dr}]}}" in text) is one, text
 
 
 @pytest.mark.parametrize("widths, offset, heads, dtype", [
@@ -79,6 +108,18 @@ _HEAD = {"dots3": (4, 128, 128), "glm": (8, 192, 256)}
     pytest.param("glm", 0, 4, jnp.float32,
                  id="glm-offset-0-every-causal-key-chosen"),
     pytest.param("glm", 256, 4, jnp.bfloat16, id="glm-bfloat16-operands"),
+    pytest.param("glm", 200, 8, jnp.float32,
+                 id="glm-eight-heads-offset-200-cuts-the-last-block"),
+    pytest.param("glm", 0, 1, jnp.float32, id="glm-one-head-a-cell-offset-0"),
+    pytest.param("glm", 200, 2, jnp.bfloat16,
+                 id="glm-bfloat16-offset-200-cuts-the-last-block"),
+    pytest.param("whole", 200, 2, jnp.float32,
+                 id="whole-128+128-offset-200-cuts-the-last-block"),
+    pytest.param("halves", 200, 2, jnp.float32,
+                 id="halves-64+64-offset-200-cuts-the-last-block"),
+    pytest.param("halves", 0, 1, jnp.float32, id="halves-64+64-offset-0"),
+    pytest.param("halves", 256, 2, jnp.bfloat16,
+                 id="halves-64+64-bfloat16-operands"),
 ])
 def test_the_masked_flash_kernel_in_the_interpreter_is_its_xla_body(
         widths, offset, heads, dtype):
@@ -97,14 +138,18 @@ def test_the_masked_flash_kernel_in_the_interpreter_is_its_xla_body(
     1e-3 that the chip showed at these widths (``PERF.md`` §6 PR 58, PR
     59; 3.4e-4 here), at the worst to a bfloat16's spacing at the
     largest output (the planted rows' are values of order 3, and the
-    output is rounded to bfloat16)."""
-    h, dn, dv = _HEAD[widths]
-    scale = (dn + 64) ** -0.5
+    output is rounded to bfloat16). The body is the one the widths ask
+    for (``dsa._one_product``): a ``glm`` or ``halves`` cell's score
+    tile is ONE product a head over the joined operands (a float32
+    accumulation over ``dn + dr`` for the XLA body's sum of two), a
+    ``dots3`` or ``whole`` cell's two."""
+    h, dn, dr, dv = _HEAD[widths]
+    scale = (dn + dr) ** -0.5
     ks = jax.random.split(jax.random.PRNGKey(1), 6)
     q_n = jax.random.normal(ks[0], (1, h, 256, dn), dtype)
-    q_r = jax.random.normal(ks[1], (1, h, 256, 64), dtype)
+    q_r = jax.random.normal(ks[1], (1, h, 256, dr), dtype)
     k_n = jax.random.normal(ks[2], (1, h, 512, dn), dtype)
-    k_r = jax.random.normal(ks[3], (1, 512, 64), dtype)
+    k_r = jax.random.normal(ks[3], (1, 512, dr), dtype)
     v = jax.random.normal(ks[4], (1, h, 512, dv), dtype)
     scores = jax.random.normal(ks[5], (1, 256, 512), jnp.float32)
     at = jnp.arange(256)[:, None] + offset
@@ -119,10 +164,14 @@ def test_the_masked_flash_kernel_in_the_interpreter_is_its_xla_body(
     args = (q_n, q_r, k_n, k_r, v)
     want = dsa.masked_attention_xla(
         *(a.astype(jnp.float32) for a in args), bias, scale)
-    got = dsa.masked_attention(*args, bias, jnp.int32(offset), scale=scale,
-                               interpret=True, block_q=128, block_k=128,
-                               heads=heads)
+    kernel = functools.partial(
+        dsa.masked_attention, scale=scale, interpret=True, block_q=128,
+        block_k=128, heads=heads)
+    got = kernel(*args, bias, jnp.int32(offset))
     assert got.dtype == dtype and got.shape == (1, h, 256, dv)
+    products = str(jax.make_jaxpr(kernel)(*args, bias, offset)).count(
+        "dot_general")
+    assert products == heads * (2 if widths in ("glm", "halves") else 3)
     err = float(jnp.abs(got.astype(jnp.float32) - want).max())
     if dtype == jnp.float32:
         assert err < 1e-5

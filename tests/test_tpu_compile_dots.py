@@ -182,6 +182,12 @@ def test_dots_32768_row_prefill_is_segments_and_four_kernels(
         assert args.count(f"memref<{cell}x1x{bq}xf32") == 2, args
         assert f"memref<{cell}x{k.dv}x{bq}xf32" in args
         assert not re.search(rf"memref<(\d+x)*{bq}x128xf32", body)
+        # the parent's operands and body: at 128 + 64 one product would
+        # spare the matrix unit nothing (``dsa._one_product``), so no
+        # joined scratch and two score products and ``v^T @ p`` a head
+        assert not dsa._one_product(k.dn, k.dr)
+        assert f"x{k.dn + k.dr}xbf16" not in body
+        assert len(re.findall(r"\btpu\.matmul\b", body)) == 3 * cell
     mem = compiled.memory_analysis()
     print(f"\ndots 32768-row prefill: {_mem(compiled)}")
     assert mem.alias_size_in_bytes >= sum(

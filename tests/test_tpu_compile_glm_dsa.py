@@ -212,6 +212,13 @@ def test_glm_32768_row_prefill_hands_a_segments_selection_to_four_layers(
                 and f"memref<1x{bq}x{bk}xbf16" in args), args
         assert f"vector<{bk}x{bq}xf32>" in body
         assert f"memref<{cell}x{k.dv}x{bq}xf32" in args
+        # ONE product a head's score tile (192 + 64: two lane tiles of
+        # the contraction together, three apart: ``dsa._one_product``):
+        # the scratch [q_n | q_r] is joined in, and two products a head
+        assert dsa._one_product(k.dn, k.dr)
+        assert f"memref<{cell}x{bq}x{k.dn + k.dr}xbf16" in args, args
+        assert len(re.findall(r"\btpu\.matmul\b", body)) == 2 * cell
+        assert f"vector<{bk}x{k.dn + k.dr}xbf16>" in body
     mem = compiled.memory_analysis()
     print(f"\nglm 32768-row prefill: {_mem(compiled)}; largest array "
           f"across the prompt {shape} = {size / MIB:.0f} MiB")
