@@ -1576,13 +1576,68 @@ def ssm_check(widths: str, lens: list, seed: int,
 DSA_KERNEL_TOLERANCE = 2e-2  # bf16 outputs of either form, relative
 
 
+def _mhc_parting(cfg, rows: int, key) -> float:
+    """The tenth block's residual streams round one sublayer
+    (``glm_next.hc_read`` / ``hc_write``: streams in the compute type,
+    coefficients float32 with the streams' axes leading, the mixes
+    written out) against a float32 ``jax.numpy`` body (the paper's
+    lines, einsums at the highest precision) on ``rows`` rows of seeded
+    streams and leaves: the larger relative error of what the sublayer
+    reads and of the streams it leaves."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import glm_next
+
+    n, d, f32 = cfg.hc_mult, cfg.d_model, jnp.float32
+    ks = jax.random.split(key, 5)
+    x = jax.random.normal(ks[0], (2, rows, n, d), cfg.compute_dtype)
+    y = jax.random.normal(ks[1], (2, rows, d), cfg.compute_dtype)
+    p = {"hc_phi": jax.random.normal(ks[2], (n * d, 2 * n + n * n), f32)
+         * (n * d) ** -0.5,
+         "hc_b": jax.random.normal(ks[3], (2 * n + n * n,), f32),
+         "hc_alpha": 1.0 + 0.25 * jax.random.normal(ks[4], (3,), f32)}
+
+    @jax.jit
+    def program(x, y):
+        u, mix = glm_next.hc_read(cfg, p, x)
+        return u, glm_next.hc_write(cfg, x, y, mix)
+
+    with jax.default_matmul_precision("highest"):
+        xs, ys = x.astype(f32), y.astype(f32)
+        flat = xs.reshape(2, rows, -1)
+        c = (flat * jax.lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True)
+                                  + cfg.hc_eps)) @ p["hc_phi"]
+        a, b = p["hc_alpha"], p["hc_b"]
+        pre = jax.nn.sigmoid(a[0] * c[..., :n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * c[..., n:2 * n] + b[n:2 * n])
+        res = jnp.exp(a[2] * c[..., 2 * n:] + b[2 * n:]).reshape(
+            2, rows, n, n)
+        for _ in range(cfg.hc_sinkhorn_iters):
+            res = res / (res.sum(-1, keepdims=True) + cfg.hc_eps)
+            res = res / (res.sum(-2, keepdims=True) + cfg.hc_eps)
+        want_u = jnp.einsum("btn,btnd->btd", pre, xs)
+        want_x = jnp.einsum("btij,btjd->btid", res, xs) \
+            + post[..., None] * ys[:, :, None]
+    u, new = program(x, y)
+    return max(float(jnp.max(jnp.abs(got.astype(f32) - want))
+                     / jnp.max(jnp.abs(want)))
+               for got, want in ((u, want_u), (new, want_x)))
+
+
 def dsa_check(widths: str, rows: int, seed: int,
               interpret: bool = False, block: str = "dots") -> dict:
     """Runs in a child that holds the chip: the four kernels of
     ``ops/dsa.py`` at the eighth block's widths (``models/dots.py``) or,
     with ``block="glm_dsa"``, at the ninth's (``models/glm_dsa.py``: 32
     index heads, heads of 192 + 64 beside values of 256, 64 heads a
-    step), in the compute type, each against its XLA body: ``rows`` query
+    step) or, with ``block="glm_next"``, at the tenth's
+    (``models/glm_next.py``: heads of 256 + 0, NO rotated part, latent
+    rows of 512; the index over keys POOLED four rows a block, whole
+    blocks chosen and the open block read besides; and the streams'
+    coefficients and mixes, ``glm_next.hc_read`` / ``hc_write``, against
+    a float32 ``jax.numpy`` body: ``rel_err["mhc"]``), in the compute
+    type, each against its XLA body: ``rows`` query
     rows at
     offset ``rows`` over ``2 * rows`` keys through ``dsa_index`` (every
     causal score), ``dsa_kth`` (the selected SETS must be equal: the
@@ -1595,15 +1650,17 @@ def dsa_check(widths: str, rows: int, seed: int,
     import jax.numpy as jnp
 
     from ray_tpu._private import accelerator
-    from ray_tpu.models import dots, glm_dsa
+    from ray_tpu.models import dots, glm_dsa, glm_next
     from ray_tpu.ops import dsa
 
     accelerator.claim_device()
-    config = {"dots": dots.DotsConfig, "glm_dsa": glm_dsa.GlmDsaConfig}[block]
+    config = {"dots": dots.DotsConfig, "glm_dsa": glm_dsa.GlmDsaConfig,
+              "glm_next": glm_next.GlmNextConfig}[block]
     cfg = config.tiny(dtype="bfloat16") if widths == "tiny" else config()
-    k = cfg.mla if block == "glm_dsa" else cfg.kind(False)
+    k = cfg.kind(False) if block == "dots" else cfg.mla
     hi, di, dt = cfg.index_heads, cfg.index_head_dim, cfg.compute_dtype
-    top = min(cfg.index_topk, rows)
+    pool = dots.index_pool(cfg)  # (1: a key a row)
+    top = min(cfg.index_topk, rows) // pool
     keys, blocks = 2 * rows, min(128, rows)
     how = {"interpret": True, "block_q": blocks, "block_k": blocks} \
         if interpret else {"use_kernel": True}
@@ -1617,29 +1674,40 @@ def dsa_check(widths: str, rows: int, seed: int,
     q_i = jax.random.normal(next(rng), (1, rows, hi, di), dt)
     w = jax.random.normal(next(rng), (1, rows, hi), jnp.float32)
     k_i = jax.random.normal(next(rng), (1, keys, di), dt)
-    causal = (jnp.arange(keys)[None, :]
-              <= jnp.arange(rows)[:, None] + rows)[None]
-    scores = jax.jit(lambda *a: dsa.index_scores(*a, rows, **how))(
-        q_i, w, k_i)
+    at = jnp.arange(rows)[:, None] + rows  # the query rows' positions
+    if pool > 1:  # (a key a block of ``pool`` rows; whole blocks alone)
+        k_i = dots.pooled_keys(k_i, pool)
+    causal = (pool * jnp.arange(keys // pool)[None, :] + pool - 1 <= at)[None]
+    scores = jax.jit(lambda *a: dsa.index_scores(
+        *a, rows, pool=pool, **how))(q_i, w, k_i)
     body = dsa.index_scores_xla(q_i, w, k_i)
     select = {"interpret": True} if interpret else {"use_kernel": True}
     chosen = jax.jit(lambda s: dsa.select(s, causal, top, **select))(body)
     sets_equal = bool(jnp.array_equal(
         chosen, dsa.select(body, causal, top, use_kernel=False)))
-    bias = jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+
+    def read(chosen, at):  # -> the bias a query at ``at`` attends over
+        if pool > 1:
+            return dots.pooled_bias(chosen, at, pool, keys)[0]
+        return jnp.where(chosen, 0.0, dsa.NEG).astype(jnp.bfloat16)
+
+    def rotated(*shape):  # (a kind without a rotated part hands None)
+        return jax.random.normal(next(rng), shape, dt) if k.dr else None
+
+    bias = read(chosen, at)
     qkv = (jax.random.normal(next(rng), (1, 8, rows, k.dn), dt),
-           jax.random.normal(next(rng), (1, 8, rows, k.dr), dt),
+           rotated(1, 8, rows, k.dr),
            jax.random.normal(next(rng), (1, 8, keys, k.dn), dt),
-           jax.random.normal(next(rng), (1, keys, k.dr), dt),
+           rotated(1, keys, k.dr),
            jax.random.normal(next(rng), (1, 8, keys, k.dv), dt), bias)
     scale = (k.dn + k.dr) ** -0.5
     attn = jax.jit(lambda *a: dsa.masked_attention(
         *a, rows, scale=scale, **how))(*qkv)
     lengths = jnp.array([keys, 0, keys - 5, 3], jnp.int32)
-    valid = jnp.arange(keys)[None, :] < lengths[:, None]
-    step_bias = jnp.where(dsa.select(
-        jax.random.normal(next(rng), (4, keys)), valid, top,
-        use_kernel=False), 0.0, dsa.NEG).astype(jnp.bfloat16)
+    valid = jnp.arange(keys // pool)[None, :] < (lengths // pool)[:, None]
+    step_bias = read(dsa.select(
+        jax.random.normal(next(rng), (4, keys // pool)), valid, top,
+        use_kernel=False), (lengths - 1)[:, None])
     stack = jax.random.normal(next(rng), (2, 4, keys, k.row_width), dt)
     q_row = jax.random.normal(next(rng), (4, k.heads, k.row_width), dt)
     step = jax.jit(lambda *a: dsa.decode_attention_masked(
@@ -1648,10 +1716,13 @@ def dsa_check(widths: str, rows: int, seed: int,
         q_row, stack, 1, lengths, step_bias)
     step_body = dsa.attend_latent_masked(q_row, stack[1], lengths,
                                          step_bias, k.kv_lora, scale)
+    streams = {}
+    if block == "glm_next":
+        streams["mhc"] = _mhc_parting(cfg, rows, next(rng))
     return {"rel_err": {
                 "dsa_index": rel(scores, body, causal),
                 "dsa_attn": rel(attn, dsa.masked_attention_xla(*qkv, scale)),
-                "dsa_decode_attn": rel(step, step_body)},
+                "dsa_decode_attn": rel(step, step_body), **streams},
             "sets_equal": sets_equal, "chosen": int(chosen.sum()),
             "inactive_zero": not bool(jnp.any(step[1] != 0)),
             "rows": rows, "device": accelerator.device_report()}
@@ -1730,7 +1801,7 @@ def hybrid_phase(plan: Plan) -> dict:
           "inactive slot's state moved, or the layer's step holds no "
           "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
     sparse = {}
-    for block in ("dots", "glm_dsa"):
+    for block in ("dots", "glm_dsa", "glm_next"):
         sparse[block] = found = chip_child(plan, "dsa_check", {
             "widths": plan.hybrid_widths, "rows": plan.dsa_rows,
             "seed": plan.seed, "interpret": not plan.on_tpu, "block": block})

@@ -233,7 +233,10 @@ class Kind:
         return -(-(self.kv_lora + self.dr) // _LANES) * _LANES
 
     def rotation(self, positions):
-        """(sin, cos) of ``positions`` [B, T] for the rotated part."""
+        """(sin, cos) of ``positions`` [B, T] for the rotated part; None
+        for heads that have none (``dr`` 0: no position encoding)."""
+        if not self.dr:
+            return None
         return rotary_embedding(positions, self.dr, self.theta)
 
 
@@ -314,21 +317,25 @@ def _mla_inputs(cfg, k: Kind, p, x, rotation):
     """x [B, T, D] (normed) -> (q_nope [B, T, H, dn], q_rope [B, T, H,
     dr] rotated, the latent [B, T, r] normalised and rescaled, k_rope
     [B, T, dr] rotated, the gate [B, T, H] float32 or None, c_q [B, T,
-    q_lora]: what the indexer's queries are made of)."""
+    q_lora]: what the indexer's queries are made of). A kind without a
+    rotated part (``dr`` 0): q_rope and k_rope None."""
     b, t, d = x.shape
     r_q = (d / k.q_lora) ** 0.5 if k.rescale else 1.0
     r_kv = (d / k.kv_lora) ** 0.5 if k.rescale else 1.0
     c_q = rms_norm(x @ p["w_qa"], p["q_norm"] * r_q, cfg.rms_eps)
     q = (c_q @ p["w_qb"]).reshape(b, t, k.heads, k.dn + k.dr)
-    q_rope = apply_rotary_interleaved(q[..., k.dn:], *rotation)
+    q_rope = apply_rotary_interleaved(q[..., k.dn:], *rotation) \
+        if k.dr else None
     kva = x @ p["w_kva"]
     latent = rms_norm(kva[..., :k.kv_lora], p["kv_norm"] * r_kv,
                       cfg.rms_eps)
-    k_rope = apply_rotary_interleaved(kva[..., None, k.kv_lora:], *rotation)
+    k_rope = apply_rotary_interleaved(
+        kva[..., None, k.kv_lora:], *rotation) if k.dr else None
     gate = jax.nn.sigmoid(jnp.dot(
         x, p["w_gate"], preferred_element_type=jnp.float32)) \
         if k.gated else None
-    return q[..., :k.dn], q_rope, latent, k_rope[..., 0, :], gate, c_q
+    return (q[..., :k.dn], q_rope, latent,
+            k_rope[..., 0, :] if k.dr else None, gate, c_q)
 
 
 @jax.named_scope("qkv")
@@ -341,6 +348,8 @@ def _index_inputs(cfg, p, x, c_q, rotation):
     f32 = jnp.float32
 
     def rotated(a):  # [B, T, H, di]
+        if not dr:  # (no rotated part anywhere in the model)
+            return a
         return jnp.concatenate([apply_rotary_interleaved(
             a[..., :dr], *rotation), a[..., dr:]], axis=-1)
 
@@ -359,9 +368,11 @@ def _index_inputs(cfg, p, x, c_q, rotation):
 def _cache_rows(k: Kind, latent, k_rope):
     """[..., r] and [..., dr] -> the rows a slot keeps [..., row_width]."""
     pad = k.row_width - k.kv_lora - k.dr
+    if k_rope is None and not pad:  # (the latent alone, whole lanes)
+        return latent
     return jnp.concatenate(
-        [latent, k_rope, jnp.zeros((*latent.shape[:-1], pad), latent.dtype)],
-        axis=-1)
+        [latent, *([] if k_rope is None else [k_rope]),
+         jnp.zeros((*latent.shape[:-1], pad), latent.dtype)], axis=-1)
 
 
 @jax.named_scope("attn_out")
@@ -464,16 +475,66 @@ def _window_attend(cfg: DotsConfig, q, k, v, offset):
 # The model: whole sequences, prefill into a slot's state, a ragged step
 # --------------------------------------------------------------------------
 
+def index_pool(cfg) -> int:
+    """How many positions one key of the indexer stands for: 1, or a
+    configuration's own ``index_pool`` (blocks of that many rows are
+    chosen by the mean of their keys)."""
+    return getattr(cfg, "index_pool", 1)
+
+
+def pooled_keys(k_i, pool: int):
+    """Index keys [B, T, di] -> [B, ceil(T / pool), di]: block ``j``'s
+    key is the mean of the keys of positions ``pool * j ..``, taken in
+    float32 and rounded to the keys' type (the sum of four bfloat16
+    numbers is exact in float32 whatever its order: a decode step that
+    closes a block from the raw keys it kept gives the same bits). A
+    last block short of ``pool`` rows is padded with zeros: it is open
+    and nobody reads its key."""
+    b, t, di = k_i.shape
+    k_i = jnp.pad(k_i, ((0, 0), (0, -t % pool), (0, 0)))
+    return jnp.mean(k_i.reshape(b, -1, pool, di).astype(jnp.float32),
+                    axis=2).astype(k_i.dtype)
+
+
+def pooled_bias(chosen, at, pool: int, rows: int):
+    """The rows a query reads where its indexer chooses BLOCKS: chosen
+    [..., S] bool over pooled keys (whole blocks alone) and the query's
+    position ``at`` [..., 1] -> (bias [..., rows] bfloat16: 0 on the rows
+    of the chosen blocks and on the TAIL, the open block's rows ``pool *
+    ((at + 1) // pool) .. at``, which is always read; ``dsa.NEG``
+    elsewhere, whether a row is read [..., rows] bool)."""
+    s = jnp.arange(rows, dtype=jnp.int32)
+    tail = (s >= (at + 1) // pool * pool) & (s <= at)
+    reads = jnp.repeat(chosen, pool, axis=-1)[..., :rows] | tail
+    return jnp.where(reads, 0.0, dsa.NEG).astype(jnp.bfloat16), reads
+
+
 def _selection(cfg, p, x, c_q, rotation, start, idx_all):
     """An indexer's choice for a segment's rows x [B, seg, D] at
     positions ``start`` .. -> (the bias [B, seg, T] bfloat16: 0 where
     the row reads the key, ``dsa.NEG`` where not, the layer's index keys
-    so far with the segment's written)."""
+    so far with the segment's written). With ``index_pool`` > 1 the
+    scores are taken against the mean key of each block of that many
+    rows, the ``index_topk / pool`` best WHOLE blocks (those that end at
+    or before the row) are chosen and the open block behind them is read
+    besides (:func:`pooled_bias`)."""
     seg, t = x.shape[1], idx_all.shape[1]
     at = start + jnp.arange(seg, dtype=jnp.int32)
     q_i, k_i, w = _index_inputs(cfg, p, x, c_q, rotation)
     with jax.named_scope("cache"):
         idx_all = jax.lax.dynamic_update_slice(idx_all, k_i, (0, start, 0))
+    pool = index_pool(cfg)
+    if pool > 1:
+        with jax.named_scope("attn/attn_index"):
+            keys = pooled_keys(idx_all, pool)
+            scores = dsa.index_scores(q_i, w, keys, start, pool=pool,
+                                      use_kernel=cfg.use_flash)
+            whole = pool * jnp.arange(keys.shape[1], dtype=jnp.int32)[
+                None, :] + pool - 1 <= at[:, None]
+            chosen = dsa.select(scores, whole[None], min(
+                cfg.index_topk // pool, keys.shape[1]),
+                use_kernel=cfg.use_flash)
+            return pooled_bias(chosen, at[:, None], pool, t)[0], idx_all
     with jax.named_scope("attn/attn_index"):
         scores = dsa.index_scores(q_i, w, idx_all, start,
                                   use_kernel=cfg.use_flash)
@@ -505,11 +566,13 @@ def sparse_segment(cfg, k: Kind, p, x, rotation, start, lat_all, idx_all,
     hg = k.heads // groups
     with jax.named_scope("qkv"):  # (groups of heads first, heads outermost)
         def grouped(q):  # [B, seg, H, d] -> [G, B, hg, seg, d]
+            if q is None:  # (no rotated part)
+                return None
             return q.reshape(b, seg, groups, hg, -1).transpose(2, 0, 3, 1, 4)
 
         w_kvb = jnp.moveaxis(p["w_kvb"].reshape(
             k.kv_lora, groups, hg, k.dn + k.dv), 1, 0)
-        k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr]
+        k_r = lat_all[..., k.kv_lora:k.kv_lora + k.dr] if k.dr else None
         bufs = _kv_buffers(k, b, hg, lat_all.shape[1], lat_all.dtype)
 
     def group(bufs, xs):
@@ -632,9 +695,11 @@ def _step_inputs(cfg, k: Kind, p, x, rotation):
         w_kvb = p["w_kvb"].reshape(k.kv_lora, k.heads, k.dn + k.dv)
         q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :k.dn],
                            preferred_element_type=jnp.float32)
-        q_row = _cache_rows(k, q_lat.astype(x.dtype), q_rope[:, 0])
+        q_row = _cache_rows(k, q_lat.astype(x.dtype),
+                            None if q_rope is None else q_rope[:, 0])
     return (q_row, w_kvb[..., k.dn:],
-            _cache_rows(k, latent[:, 0], k_rope[:, 0]), gate, c_q)
+            _cache_rows(k, latent[:, 0],
+                        None if k_rope is None else k_rope[:, 0]), gate, c_q)
 
 
 def _step_out(cfg, p, o_lat, w_v, gate):
@@ -663,6 +728,42 @@ def step_plan(rows: int, pos, active) -> StepPlan:
                         _da.visits(lengths, rows, block))
 
 
+def _pooled_step_selection(cfg, plan: StepPlan, state, index_layer, q_i,
+                           k_i, w_i):
+    """A decode step's selection over POOLED keys (``index_pool`` > 1).
+    ``state["idx"]`` [L, slots, ceil(max_len / pool), di] holds the mean
+    key of every whole block, ``state["tail"]`` [L, slots, pool - 1, di]
+    the raw keys of the open block. The step's key k_i [B, 1, di] at
+    ``pos`` either joins the tail (``pos % pool < pool - 1``) or closes
+    its block, whose mean is written (:func:`pooled_keys`' arithmetic).
+    The query scores the ``(pos + 1) // pool`` whole blocks, chooses
+    ``index_topk / pool`` of them and reads the open block besides. ->
+    (state, bias [B, rows] bfloat16, the rows read, int32)."""
+    pool, rows = index_pool(cfg), plan.valid.shape[1]
+    at, part = plan.pos // pool, plan.pos % pool
+    with jax.named_scope("cache"):
+        tail = state["tail"][index_layer]  # [B, pool - 1, di]
+        block = pooled_keys(jnp.concatenate([tail, k_i], axis=1), pool)
+        closes = (part == pool - 1)[:, None]
+        keys = state["idx"][index_layer]
+        state["idx"] = state["idx"].at[index_layer, plan.slots, at].set(
+            jnp.where(closes, block[:, 0], keys[plan.slots, at]))
+        place = jnp.minimum(part, pool - 2)
+        state["tail"] = state["tail"].at[
+            index_layer, plan.slots, place].set(
+            jnp.where(closes, tail[plan.slots, place], k_i[:, 0]))
+    with jax.named_scope("attn/attn_index"):
+        keys = state["idx"][index_layer]
+        scores = dsa.index_scores_xla(q_i, w_i, keys)
+        whole = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :] \
+            < (plan.lengths // pool)[:, None]
+        chosen = dsa.select(scores[:, 0], whole, min(
+            cfg.index_topk // pool, keys.shape[1]), use_kernel=cfg.use_flash)
+        bias, reads = pooled_bias(chosen, (plan.lengths - 1)[:, None], pool,
+                                  rows)
+        return state, bias, jnp.sum(reads, dtype=jnp.int32)
+
+
 def sparse_step_layer(cfg, k: Kind, p, x, rotation, plan: StepPlan, state,
                       layer: int, index_layer, bias):
     """A sparse layer's attention in a decode step on x [B, 1, D] normed:
@@ -680,6 +781,10 @@ def sparse_step_layer(cfg, k: Kind, p, x, rotation, plan: StepPlan, state,
         state["lat"] = state["lat"].at[layer, plan.slots, plan.pos].set(row)
     if index_layer is not None:
         q_i, k_i, w_i = _index_inputs(cfg, p, x, c_q, rotation)
+    if index_layer is not None and index_pool(cfg) > 1:
+        state, bias, selected = _pooled_step_selection(
+            cfg, plan, state, index_layer, q_i, k_i, w_i)
+    elif index_layer is not None:
         with jax.named_scope("cache"):
             state["idx"] = state["idx"].at[
                 index_layer, plan.slots, plan.pos].set(k_i[:, 0])

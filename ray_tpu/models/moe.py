@@ -162,8 +162,22 @@ def init_model(cfg, mat, around_one, keys, layers: list) -> dict:
     }
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+def gated(gate, up, limit=None):
+    """A SwiGLU's middle: ``silu(gate) * up``, and where the model
+    states a ``swiglu_limit`` ``silu(min(gate, limit)) * clip(up, -limit,
+    limit)``."""
+    if limit is not None:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def swiglu(x, w_gate, w_up, w_down, limit=None):
+    return gated(x @ w_gate, x @ w_up, limit) @ w_down
+
+
+def swiglu_limit(cfg):
+    """The clamp a configuration states for every SwiGLU, or None."""
+    return getattr(cfg, "swiglu_limit", None)
 
 
 def route(cfg, scores, bias):
@@ -242,7 +256,7 @@ def _held_part(cfg, matmul, c, w_gate, w_up, w_down, xf, weights, held,
     experts = functools.partial(matmul, group_sizes=group_sizes)
     gate = experts(rows, w_gate)
     up = experts(rows, w_up)
-    y = experts(jax.nn.silu(gate) * up, w_down)
+    y = experts(gated(gate, up, swiglu_limit(cfg)), w_down)
     unsort = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
     if c is not None:  # (a foreign assignment's place lies behind them)
@@ -316,24 +330,30 @@ def moe(cfg, p, x, aux: dict | None = None):
     if "shared_gate" not in p:
         return out.reshape(b, t, d)
     with jax.named_scope("moe_shared"):
-        out = out + swiglu(
-            xf, p["shared_gate"], p["shared_up"], p["shared_down"])
+        out = out + swiglu(xf, p["shared_gate"], p["shared_up"],
+                           p["shared_down"], swiglu_limit(cfg))
     return out.reshape(b, t, d)
 
 
-def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None):
+def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None,
+              residual: bool = True):
     """A layer's MLP with its norm, added to ``h`` [B, T, D]: the dense
     SwiGLU (scope ``mlp``) or, where the layer is ``sparse``, the expert
     layer (``moe_router``, the norm with it, ``moe_experts``,
-    ``moe_shared``, the residual with the last of them)."""
+    ``moe_shared``, the residual with the last of them). Without
+    ``residual`` the sublayer's output alone: a block whose residual
+    path is its own (several streams) adds it itself."""
     if not sparse:
         with jax.named_scope("mlp"):
             x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
-            return h + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                              p["mlp"]["w_down"])
+            y = swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                       p["mlp"]["w_down"], swiglu_limit(cfg))
+            return h + y if residual else y
     with jax.named_scope("moe_router"):
         x = rms_norm(h, p["mlp_norm"], cfg.rms_eps)
     y = moe(cfg, p["mlp"], x, aux)
+    if not residual:
+        return y
     with jax.named_scope("moe_shared" if "shared_gate" in p["mlp"]
                          else "moe_experts"):
         return h + y

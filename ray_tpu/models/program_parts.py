@@ -30,6 +30,10 @@ VOCABULARY = (
     "attn",         # attention proper and nothing else
     "attn_out",     # wo
     "mlp",          # the dense SwiGLU with its norm
+    "mhc",          # several residual streams' coefficients (the norm
+                    # over all of them, the product with phi, the
+                    # Sinkhorn rounds) and their three mixes round every
+                    # sublayer (models/glm_next.py)
     "moe_router", "moe_experts", "moe_shared",
     "lm_head",      # final norm and head
     "sample",       # _sample_from_logits, the greedy argmax

@@ -187,26 +187,9 @@ def init_params(cfg: SolarConfig, key):
     are drawn around 1, the decay parameters and the router's bias away
     from 0, so that a part left out of a path shows against the
     reference."""
-    cdt = cfg.compute_dtype
     d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
-    dk, r = cfg.kda_head_dim, cfg.kda_rank
     keys = iter(jax.random.split(key, 32 * (cfg.n_layers + 1)))
     mat, around_one = moe.makers(cfg, keys)
-
-    def kda():
-        return {
-            "w_qkv": mat(d, 3 * h * dk),
-            "conv": moe.draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
-                             cfg.conv_kernel ** -0.5, cdt),
-            "w_f_down": mat(d, r), "w_f_up": mat(r, h * dk),
-            "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
-            "a_log": jnp.log(jax.random.uniform(
-                next(keys), (h,), jnp.float32, 0.5, 4.0)),
-            "w_beta": mat(d, h),
-            "w_g_down": mat(d, r), "w_g_up": mat(r, h * dk),
-            "o_norm": around_one(dk),
-            "wo": mat(h * dk, d, out=True),
-        }
 
     def gqa():
         return {
@@ -217,10 +200,31 @@ def init_params(cfg: SolarConfig, key):
 
     layers = [{
         "attn_norm": around_one(d),
-        "attn": gqa() if cfg.full(i) else kda(),
+        "attn": gqa() if cfg.full(i) else init_kda(cfg, mat, around_one,
+                                                   keys),
         "mlp_norm": around_one(d), "mlp": moe.init_experts(cfg, mat, keys),
     } for i in range(cfg.n_layers)]
     return moe.init_model(cfg, mat, around_one, keys, layers)
+
+
+def init_kda(cfg, mat, around_one, keys) -> dict:
+    """A KDA layer's attention leaves (low-rank decay and gate), drawn
+    with a block's own makers (``moe.makers``) from its iterator of
+    keys: this block's and ``models/glm_next.py``'s."""
+    d, h, dk, r = cfg.d_model, cfg.n_heads, cfg.kda_head_dim, cfg.kda_rank
+    return {
+        "w_qkv": mat(d, 3 * h * dk),
+        "conv": moe.draw(next(keys), (cfg.conv_kernel, 3 * h * dk),
+                         cfg.conv_kernel ** -0.5, cfg.compute_dtype),
+        "w_f_down": mat(d, r), "w_f_up": mat(r, h * dk),
+        "dt_bias": jax.random.normal(next(keys), (h * dk,), jnp.float32),
+        "a_log": jnp.log(jax.random.uniform(
+            next(keys), (h,), jnp.float32, 0.5, 4.0)),
+        "w_beta": mat(d, h),
+        "w_g_down": mat(d, r), "w_g_up": mat(r, h * dk),
+        "o_norm": around_one(dk),
+        "wo": mat(h * dk, d, out=True),
+    }
 
 
 # --------------------------------------------------------------------------
@@ -270,11 +274,14 @@ def kda_empty(cfg: SolarConfig, b: int) -> dict:
                               cfg.compute_dtype)}
 
 
-def kda_step(cfg: SolarConfig, p, x, state, active):
+def kda_step(cfg, p, x, state, active, inputs=None):
     """A decode step of a KDA layer. x [B, 1, D] (normed); ``state``
     {"s" [B, H, dk, dv] float32, "conv" [B, K-1, 3*H*dk]}. A slot that
-    is not ``active`` keeps its state. -> ([B, 1, D], state)."""
-    q, k, v, g, beta, proj = _kda_inputs(cfg, p, x, state["conv"])
+    is not ``active`` keeps its state. ``inputs``: another block's
+    ``_kda_inputs`` (its decay's and beta's form; this block's where
+    None). -> ([B, 1, D], state)."""
+    q, k, v, g, beta, proj = (inputs or _kda_inputs)(
+        cfg, p, x, state["conv"])
     with jax.named_scope("attn/attn_linear"):
         s, o = _kda_step(state["s"], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                          beta[:, 0], active)
@@ -285,7 +292,7 @@ def kda_step(cfg: SolarConfig, p, x, state, active):
     return _kda_out(cfg, p, x, o[:, None]), new
 
 
-def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
+def kda_segment(cfg, p, x, state, start, true_lens, inputs=None):
     """A KDA layer over one segment of whole prompts: rows ``start`` ..
     ``start + T - 1`` of x [B, T, D] (normed, right-padded: ``true_lens``
     [B] rows of each prompt are real), from the ``state`` the rows
@@ -296,10 +303,10 @@ def kda_segment(cfg: SolarConfig, p, x, state, start, true_lens):
     chunkwise delta rule is ``ops.kda_chunk``: on a TPU one kernel call
     a segment that carries ``S`` on the chip from the segment's first
     chunk to its last; the XLA body ``kda_chunked`` elsewhere.
-    -> ([B, T, D], state)."""
+    ``inputs`` as :func:`kda_step`'s. -> ([B, T, D], state)."""
     t = x.shape[1]
-    q, k, v, g, beta, proj = _kda_inputs(cfg, p, x, state["conv"],
-                                         true_lens - start)
+    q, k, v, g, beta, proj = (inputs or _kda_inputs)(
+        cfg, p, x, state["conv"], true_lens - start)
     with jax.named_scope("attn/attn_linear"):
         real = start + jnp.arange(t)[None, :] < true_lens[:, None]  # [B, T]
         beta = jnp.where(real[..., None], beta, 0.0)

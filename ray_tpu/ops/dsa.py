@@ -82,9 +82,11 @@ def index_scores_xla(q, w, k):
 
 
 def _index_kernel(offset_ref, q_ref, w_ref, k_ref, o_ref, *, block_q: int,
-                  block_k: int):
+                  block_k: int, pool: int = 1):
     q_start = pl.program_id(1) * block_q
     k_start = pl.program_id(2) * block_k
+    if pool != 1:  # (key j stands for positions pool * j ..)
+        k_start = k_start * pool
 
     @pl.when(k_start <= q_start + block_q - 1 + offset_ref[0])
     def _live():
@@ -101,11 +103,14 @@ def _index_kernel(offset_ref, q_ref, w_ref, k_ref, o_ref, *, block_q: int,
 
 def index_scores(q, w, k, offset, *, use_kernel: bool | None = None,
                  interpret: bool = False, block_q: int = 256,
-                 block_k: int = 512):
+                 block_k: int = 512, pool: int = 1):
     """A segment's index scores: q [B, T, Hi, d] at positions ``offset``
     .. (traced or not), w [B, T, Hi] float32, k [B, S, d] of positions
     0 .. S - 1 -> [B, T, S] float32. Only ``s <= t + offset`` means
     anything: the kernel leaves the tiles above the diagonal unwritten.
+    With ``pool`` > 1 key ``j`` is a POOLED key that stands for positions
+    ``pool * j .. pool * j + pool - 1`` (the sum is the same; the
+    diagonal lies where ``pool * j <= t + offset``).
 
     ``use_kernel=None``: the Pallas kernel on a TPU where the rows and
     keys are whole blocks, the XLA body elsewhere; ``interpret=True``
@@ -124,7 +129,8 @@ def index_scores(q, w, k, offset, *, use_kernel: bool | None = None,
                          f"the blocks ({block_q}, {block_k})")
     offset = jnp.asarray(offset, jnp.int32).reshape(1)
     return pl.pallas_call(
-        functools.partial(_index_kernel, block_q=block_q, block_k=block_k),
+        functools.partial(_index_kernel, block_q=block_q, block_k=block_k,
+                          pool=pool),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, t // block_q, s // block_k),
@@ -269,11 +275,14 @@ def masked_attention_xla(q_n, q_r, k_n, k_r, v, bias, scale: float):
     """q_n [B, H, T, dn], q_r [B, H, T, dr] over k_n [B, H, S, dn], the
     one rotated key of all heads k_r [B, S, dr] and v [B, H, S, dv]; bias
     [B, T, S] (0 where query t sees key s, ``NEG`` where not) -> [B, H,
-    T, dv]: the scores formed whole, float32 softmax."""
-    s = (jnp.einsum("bhtd,bhsd->bhts", q_n, k_n,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhtd,bsd->bhts", q_r, k_r,
-                      preferred_element_type=jnp.float32)) * scale
+    T, dv]: the scores formed whole, float32 softmax. ``q_r`` and ``k_r``
+    None: a head without a rotated part, one product."""
+    s = jnp.einsum("bhtd,bhsd->bhts", q_n, k_n,
+                   preferred_element_type=jnp.float32)
+    if q_r is not None:
+        s = s + jnp.einsum("bhtd,bsd->bhts", q_r, k_r,
+                           preferred_element_type=jnp.float32)
+    s = s * scale
     probs = jax.nn.softmax(s + bias[:, None].astype(jnp.float32), axis=-1)
     return jnp.einsum("bhts,bhsd->bhtd", probs.astype(q_n.dtype), v,
                       preferred_element_type=jnp.float32).astype(q_n.dtype)
@@ -343,7 +352,9 @@ def _attn_kernel(offset_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref,
     (:func:`masked_attention` says what the other form cost). With a
     scratch ``q_scr`` [heads, block_q, dn + dr] (:func:`_one_product`)
     the tile is one product: ``[q_n | q_r]`` is joined there once a q
-    block, the one rotated key laid behind each head's k_n a step."""
+    block, the one rotated key laid behind each head's k_n a step.
+    ``qr_ref`` and ``kr_ref`` None (:func:`_attn_kernel_nope`): a head
+    has no rotated part and its tile is k_n's product alone."""
     ik = pl.program_id(3)
     heads, dn = qn_ref.shape[1], qn_ref.shape[3]
 
@@ -364,9 +375,12 @@ def _attn_kernel(offset_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref,
     def _live():
         # (the tile is turned here, once for the cell's heads)
         bias = bias_ref[0].astype(jnp.float32).T  # [bk, bq]
-        k_r = kr_ref[0]  # [bk, dr]: the one rotated key of all heads
+        # [bk, dr]: the one rotated key of all heads
+        k_r = None if kr_ref is None else kr_ref[0]
         for h in range(heads):
-            if q_scr is not None:
+            if kr_ref is None:
+                s = _k_qt(kn_ref[0, h], qn_ref[0, h])
+            elif q_scr is not None:
                 s = _k_qt(jnp.concatenate([kn_ref[0, h], k_r], axis=1),
                           q_scr[h])
             else:
@@ -395,6 +409,14 @@ def _attn_kernel(offset_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref,
                 o_ref.dtype)
 
 
+def _attn_kernel_nope(offset_ref, qn_ref, kn_ref, v_ref, bias_ref, o_ref,
+                      m_scr, l_scr, acc_scr, **kw):
+    """:func:`_attn_kernel` of heads without a rotated part (``dr`` 0: a
+    block of no width is nothing a ``pallas_call`` can be handed)."""
+    _attn_kernel(offset_ref, qn_ref, None, kn_ref, None, v_ref, bias_ref,
+                 o_ref, m_scr, l_scr, acc_scr, **kw)
+
+
 def masked_attention(q_n, q_r, k_n, k_r, v, bias, offset, *, scale: float,
                      use_kernel: bool | None = None,
                      interpret: bool = False, block_q: int | None = None,
@@ -405,7 +427,9 @@ def masked_attention(q_n, q_r, k_n, k_r, v, bias, offset, *, scale: float,
     heads k_r [B, S, dr] (never repeated a head: the scores are two
     products) and v [B, H, S, dv] of positions 0 .. S - 1, bias [B, T,
     S] (0 or ``NEG``; everything past ``t + offset`` must be ``NEG``) ->
-    [B, H, T, dv]. A row with no key seen gives zeros.
+    [B, H, T, dv]. A row with no key seen gives zeros. ``q_r`` and
+    ``k_r`` None: heads without a rotated part (NoPE latent attention);
+    the kernel then has five operands and a tile is one product.
 
     ``use_kernel=None``: the Pallas kernel (``dsa_attn``) on a TPU where
     rows and keys are whole blocks, the XLA body elsewhere;
@@ -471,7 +495,7 @@ def masked_attention(q_n, q_r, k_n, k_r, v, bias, offset, *, scale: float,
     four passes at the matrix unit's peak 1.37: the products are 81% of
     the body, and only walking fewer pairs takes more."""
     b, h, t, dn = q_n.shape
-    dr = q_r.shape[3]
+    dr = 0 if q_r is None else q_r.shape[3]
     s, dv = v.shape[2:]
     block_q = min(block_q or _ATTN_BLOCKS[0], t)
     block_k = min(block_k or _ATTN_BLOCKS[1], s)
@@ -508,18 +532,24 @@ def masked_attention(q_n, q_r, k_n, k_r, v, bias, offset, *, scale: float,
         return bi, jnp.minimum(ki, _last_block(
             qi * block_q, block_q, block_k, off[0], nk)), 0
 
+    in_specs = [pl.BlockSpec((1, heads, block_q, dn), q_idx),
+                pl.BlockSpec((1, heads, block_q, dr), q_idx),
+                pl.BlockSpec((1, heads, block_k, dn), kv_idx),
+                pl.BlockSpec((1, block_k, dr), kr_idx),
+                pl.BlockSpec((1, heads, block_k, dv), kv_idx),
+                pl.BlockSpec((1, block_q, block_k), bias_idx)]
+    operands = [q_n, q_r, k_n, k_r, v, bias]
+    if not dr:  # (no rotated part: neither its operands nor their specs)
+        in_specs, operands = ([a[i] for i in (0, 2, 4, 5)]
+                              for a in (in_specs, operands))
     return pl.pallas_call(
-        functools.partial(_attn_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k, nk=nk),
+        functools.partial(_attn_kernel if dr else _attn_kernel_nope,
+                          scale=scale, block_q=block_q, block_k=block_k,
+                          nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, h // heads, t // block_q, nk),
-            in_specs=[pl.BlockSpec((1, heads, block_q, dn), q_idx),
-                      pl.BlockSpec((1, heads, block_q, dr), q_idx),
-                      pl.BlockSpec((1, heads, block_k, dn), kv_idx),
-                      pl.BlockSpec((1, block_k, dr), kr_idx),
-                      pl.BlockSpec((1, heads, block_k, dv), kv_idx),
-                      pl.BlockSpec((1, block_q, block_k), bias_idx)],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec((1, heads, block_q, dv), q_idx),
             scratch_shapes=[
                 pltpu.VMEM((heads, 1, block_q), jnp.float32),  # max
@@ -533,7 +563,7 @@ def masked_attention(q_n, q_r, k_n, k_r, v, bias, offset, *, scale: float,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="dsa_attn",
-    )(offset, q_n, q_r, k_n, k_r, v, bias)
+    )(offset, *operands)
 
 
 # --------------------------------------------------------------------------

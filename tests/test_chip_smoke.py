@@ -271,11 +271,15 @@ def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
     assert found["inactive_kept"] and found["in_program"] is False
 
 
-@pytest.mark.parametrize("block, chosen", [("dots", 16), ("glm_dsa", 8)])
+@pytest.mark.parametrize("block, chosen", [("dots", 16), ("glm_dsa", 8),
+                                           ("glm_next", 2)])
 def test_dsa_check_rehearses_on_the_cpu(block, chosen):
     """What ``hybrid_phase`` asks of the kernels of ``ops/dsa.py`` on the
-    chip at the eighth block's widths and at the ninth's (heads of 24 +
-    8 beside values of 32 at tiny widths), in the Pallas interpreter:
+    chip at the eighth block's widths, at the ninth's (heads of 24 +
+    8 beside values of 32 at tiny widths) and at the tenth's (heads of 24
+    + 0: no rotated part; keys pooled four rows a block, 2 whole blocks
+    chosen a row and the open block read; the streams' mixes against a
+    ``jax.numpy`` body), in the Pallas interpreter:
     128 query rows over 256 keys, ``index_topk`` chosen a row; each
     kernel agrees with its XLA body, the selection's kernel chooses the
     counting passes' sets, an inactive slot's step gives zeros."""
@@ -283,7 +287,8 @@ def test_dsa_check_rehearses_on_the_cpu(block, chosen):
                                  block=block)
     assert found["device"].items() >= CPU.items()
     assert set(found["rel_err"]) == {"dsa_index", "dsa_attn",
-                                     "dsa_decode_attn"}
+                                     "dsa_decode_attn"} | (
+        {"mhc"} if block == "glm_next" else set())
     assert max(found["rel_err"].values()) <= chip_smoke.DSA_KERNEL_TOLERANCE
     assert found["sets_equal"] and found["inactive_zero"]
     assert found["chosen"] == 128 * chosen

@@ -21,8 +21,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import decode_engine as de
-from ray_tpu.models import (dots, exaone, glm_dsa, granite, instella, ling,
-                            llama, mimo, moe, solar)
+from ray_tpu.models import (dots, exaone, glm_dsa, glm_next, granite,
+                            instella, ling, llama, mimo, moe, solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -39,11 +39,13 @@ BLOCKS = {
     "granite": (granite, granite.GraniteConfig.tiny),
     "dots": (dots, dots.DotsConfig.tiny),
     "glm_dsa": (glm_dsa, glm_dsa.GlmDsaConfig.tiny),
+    "glm_next": (glm_next, glm_next.GlmNextConfig.tiny),
 }
 # the blocks whose step counts what its indexers chose besides the
 # routing: their slots state ``step_counters`` of their own
-# (``dots.SparseSlots``, and GLM-5.2's ``attended_rows`` behind it)
-SELECTS = ("dots", "glm_dsa")
+# (``dots.SparseSlots``, GLM-5.2's ``attended_rows`` behind it, and
+# behind that GLM-5.3-Flash's ``index_keys_scored``)
+SELECTS = ("dots", "glm_dsa", "glm_next")
 ROWS = [name for name in BLOCKS if name.startswith("llama")]
 OWN = [name for name in BLOCKS if name not in ROWS]
 
@@ -272,12 +274,42 @@ def test_the_ninth_block_reaches_no_private_name_of_the_eighth():
     assert (cfg.mla.rescale, cfg.mla.gated) == (False, False)
 
 
+def test_the_tenth_block_reaches_no_private_name_of_the_blocks_it_runs():
+    """``models/glm_next.py`` runs ``models/dots.py``'s sparse layer and
+    ``models/solar.py``'s KDA layer through those modules' public names
+    alone (the decay's form is its own ``_kda_inputs``, handed in), and
+    its slot holds the three kinds the docstring names: a recurrent
+    state a KDA layer, latent rows WITHOUT a rotated key, index keys
+    pooled a block of rows with the open block's raw keys."""
+    with open(glm_next.__file__) as f:
+        tree = ast.parse(f.read())
+    reached = {f"{node.value.id}.{node.attr}" for node in ast.walk(tree)
+               if isinstance(node, ast.Attribute) and node.attr[0] == "_"
+               and isinstance(node.value, ast.Name)
+               and node.value.id in ("dots", "solar", "ling")}
+    assert not reached, sorted(reached)
+    cfg = glm_next.GlmNextConfig.tiny()
+    assert (cfg.mla.dr, cfg.mla.rescale, cfg.mla.gated) == (0, False, False)
+    assert cfg.mla.row_width == -(-cfg.kv_lora_rank // 128) * 128
+    state = glm_next.SLOTS.init_state(cfg, 3, 50)
+    assert state["lat"].shape == (1, 3, 50, cfg.mla.row_width)
+    assert state["idx"].shape == (1, 3, 13, cfg.index_head_dim)  # ceil(50/4)
+    assert state["tail"].shape == (1, 3, 3, cfg.index_head_dim)
+    assert len(state["kda"]) == 4 and state["kda"][0]["s"].dtype == jnp.float32
+    assert glm_next.SLOTS.row_kinds(cfg) == {
+        "recurrent": (4, 0), "latent": (1, None), "index": (1, None)}
+    assert set(glm_next.SLOTS.state_bytes(state)) == {
+        "recurrent", "latent", "index"}
+    assert glm_next.SLOTS.step_counters[3:] == (
+        "selected_rows", "attended_rows", "index_keys_scored")
+
+
 def test_no_block_imports_the_engine():
     import ray_tpu
 
     for name in ("llama", "llama_slots", "slots", "moe", "ling", "exaone",
                  "instella", "solar", "mimo", "granite", "dots",
-                 "glm_dsa"):
+                 "glm_dsa", "glm_next"):
         with open(f"{ray_tpu.__path__[0]}/models/{name}.py") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):

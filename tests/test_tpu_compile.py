@@ -41,7 +41,8 @@ SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("mimo-v2.5-ep16-1chip", "longreason-saturated"),
                  ("granite-4.0-h-small-ep4-1chip", "sessions-saturated"),
                  ("dots3-note-prev-ep8-1chip", "longreason-saturated-24"),
-                 ("glm-5.2-ep16-1chip", "longreason-saturated-16"))
+                 ("glm-5.2-ep16-1chip", "longreason-saturated-16"),
+                 ("glm-5.3-flash-ep8-1chip", "longreason-saturated-24"))
 TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
                ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 
@@ -65,13 +66,14 @@ def _comparable(compiled) -> str:
         m.group(0), f".n{len(names)}"), text)
 
 
-def dump_serving_programs(out_dir: str) -> None:
+def dump_serving_programs(out_dir: str, only: tuple = ()) -> None:
     """Every program the benchmark's cells run, compiled for a described
     v5e: ``decode_chunk(lanes=None)`` and the one-row
     ``_prefill_batch_into_slots`` at every bucket, at each serving
     configuration's own fields and each engine shape of its cells
     (``benchmark/``; ``chat-bursty``'s is ``chat-steady``'s), and both
-    cells' train steps on their meshes."""
+    cells' train steps on their meshes. ``only``: the configurations
+    whose name holds one of these words (none: every one)."""
     from jax.experimental import topologies
 
     from benchmark import manifest
@@ -104,6 +106,11 @@ def dump_serving_programs(out_dir: str) -> None:
     except ImportError:  # (a parent before PR 42)
         pass
     try:
+        from ray_tpu.models import glm_next
+        glm_next._kda_qkvg = ling._kda_qkvg
+    except ImportError:  # (a parent before PR 65)
+        pass
+    try:
         from ray_tpu.models import granite
         granite._ssd_step = functools.partial(granite._ssd_step,
                                               use_kernel=True)
@@ -123,8 +130,12 @@ def dump_serving_programs(out_dir: str) -> None:
         with open(f"benchmark/traffic/{name}.json") as f:
             return json.load(f)
 
+    def wanted(config):
+        return not only or any(word in config for word in only)
+
     for config, cell in SERVING_CELLS:
-        if not os.path.isfile(f"benchmark/configs/{config}.json"):
+        if not os.path.isfile(f"benchmark/configs/{config}.json") \
+                or not wanted(config):
             continue  # (a parent that lacks the configuration)
         eng = traffic(cell)["engine"]
         slots, max_len = eng["slots"], eng["max_len"]
@@ -152,6 +163,8 @@ def dump_serving_programs(out_dir: str) -> None:
         for name, lowered in programs.items():
             write(f"{config}.{slots}x{max_len}.{name}", lowered.compile())
     for config, cell in TRAIN_CELLS:
+        if not wanted(config):
+            continue
         tr = traffic(cell)
         fam, m = manifest.model(config)
         cfg = dataclasses.replace(
@@ -162,4 +175,4 @@ def dump_serving_programs(out_dir: str) -> None:
 
 
 if __name__ == "__main__":
-    dump_serving_programs(sys.argv[1])
+    dump_serving_programs(sys.argv[1], tuple(sys.argv[2:]))
