@@ -6,4 +6,5 @@ the same data as JSON plus a Prometheus /metrics endpoint, which is what
 the reference's Grafana integration actually scrapes.
 """
 
-from ray_tpu.dashboard.head import DashboardHead, start_dashboard  # noqa: F401
+from ray_tpu.dashboard.head import (  # noqa: F401
+    DashboardHead, start_dashboard, stop_dashboard)

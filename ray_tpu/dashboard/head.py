@@ -19,7 +19,9 @@ modules/reporter (node stats + stack dumps). Endpoints:
 
 Runs inside the driver (or any process with cluster access) on a
 background thread; `ray_tpu.scripts start --head` can host it next to
-the control plane.
+the control plane. :func:`stop_dashboard` ends the threads of every
+head this process started (a test's teardown: a process that hosts the
+dashboard for its lifetime has no need of it).
 """
 
 from __future__ import annotations
@@ -153,8 +155,10 @@ class DashboardHead:
         self.host = host
         self.port = port
         self._ready = threading.Event()
-        threading.Thread(target=self._drive, daemon=True,
-                         name="ray_tpu-dashboard").start()
+        self._loop = self._server = None
+        self._thread = threading.Thread(target=self._drive, daemon=True,
+                                        name="ray_tpu-dashboard")
+        self._thread.start()
 
     # -- state access (all through the connected worker's head client) --
 
@@ -387,10 +391,29 @@ class DashboardHead:
                 self._serve_conn, self.host, self.port
             )
             self.port = server.sockets[0].getsockname()[1]
+            self._loop, self._server = loop, server
             self._ready.set()
 
         loop.run_until_complete(boot())
         loop.run_forever()
+        loop.close()
+
+    def stop(self, timeout: float = 5.0) -> None:
+        """Closes the listening socket, ends the threads that served
+        requests (the loop's default executor) and the loop's own."""
+        import asyncio
+
+        loop = self._loop
+        if loop is None or not self._thread.is_alive():
+            return
+
+        async def close():
+            self._server.close()
+            await loop.shutdown_default_executor()
+            loop.stop()
+
+        asyncio.run_coroutine_threadsafe(close(), loop)
+        self._thread.join(timeout)
 
     def wait_ready(self, timeout: float = 30.0) -> tuple[str, int]:
         if not self._ready.wait(timeout):
@@ -487,9 +510,20 @@ def _jsonable(o):
     return repr(o)
 
 
+_started: list[DashboardHead] = []
+
+
 def start_dashboard(host: str = "127.0.0.1",
                     port: int = 0) -> tuple[str, int]:
     """Start the dashboard in this (cluster-connected) process; returns
     its (host, port)."""
     d = DashboardHead(host, port)
+    _started.append(d)
     return d.wait_ready()
+
+
+def stop_dashboard() -> None:
+    """Stops every head :func:`start_dashboard` started in this
+    process."""
+    while _started:
+        _started.pop().stop()

@@ -5,10 +5,17 @@ program into a slot, greedy steps of that slot alone,
 the comparison of a short prompt in a long bucket (dead segments behind
 it, ``moe.in_segments``) with the reference, and what holds a sparse
 layer's k and v to the rows its segment can see (:func:`live_kv_case`).
+
+A test that changes what a program reads when it is traced (a module's
+constant, a function of a block) calls :func:`forget_programs` and not
+``jax.clear_caches()``: the latter also throws away every operation
+the reference has compiled, and the rest of the file pays for them
+again.
 """
 
 import functools
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +26,25 @@ from ray_tpu.models import decode_engine as de
 from ray_tpu.models import moe
 
 
+def forget_programs():
+    """Drops the traces of this repo's own jitted functions (the
+    engine's programs, cached by cfg alone, and the few of
+    ``ray_tpu.models`` and ``ray_tpu.ops``): the next call of each
+    reads the modules as they now are. A ``jax.jit`` a test makes is
+    new each time and has nothing to forget."""
+    for name, module in list(sys.modules.items()):
+        if name.startswith("ray_tpu."):
+            for fn in list(vars(module).values()):
+                if callable(getattr(fn, "clear_cache", None)):
+                    fn.clear_cache()
+
+
 @pytest.fixture
 def segments_of_16(monkeypatch):
     monkeypatch.setattr(moe, "SEGMENT_ROWS", 16)
-    jax.clear_caches()  # (the engine's programs are cached by cfg alone)
+    forget_programs()
     yield
-    jax.clear_caches()
+    forget_programs()
 
 
 def prefill_slot(cfg, params, state, cur, slot, prompt, bucket=128):

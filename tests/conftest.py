@@ -10,6 +10,20 @@ placement and the sys.modules guard.
 """
 
 import os
+import sys
+import tempfile
+
+# Every process this suite starts (a cluster's workers, a script's
+# children: hundreds a run) imports jax, and where the environment
+# forbids bytecode files each compiles it from source again: 2.2 s a
+# process for 0.7, 107 s for 58 over four of the runtime's files (PR 66).
+# They get a bytecode cache under the run's temporary directory, so
+# nothing is written into the checkout or beside the installation.
+os.environ.pop("PYTHONDONTWRITEBYTECODE", None)
+sys.pycache_prefix = os.environ.setdefault(
+    "PYTHONPYCACHEPREFIX", os.path.join(
+        tempfile.gettempdir(), f"ray_tpu_tests_pycache_{os.getuid()}"))
+sys.dont_write_bytecode = False
 
 # jax may already be imported (pytest plugins) with its config snapshotted from
 # the env, so set both the env var and the live config; backends init lazily.
@@ -20,9 +34,14 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        xla_flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
+    xla_flags += " --xla_force_host_platform_device_count=8"
+# the suite's time is XLA's CPU compile of tiny programs: compile them
+# unoptimised. Both sides of every comparison are compiled alike, and
+# the CPU's code generation is not what ships.
+if "xla_backend_optimization_level" not in xla_flags:
+    xla_flags += (" --xla_backend_optimization_level=0"
+                  " --xla_llvm_disable_expensive_passes=true")
+os.environ["XLA_FLAGS"] = xla_flags.strip()
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -49,10 +68,13 @@ def pytest_configure(config):
 @pytest.fixture(autouse=True)
 def _hang_watchdog():
     """Convert silent suite wedges into diagnosed failures: if any single
-    test runs >10min, faulthandler dumps EVERY thread's stack and the
-    process exits — a monolithic `pytest tests/` run must never sit
-    stalled for an hour with idle leaked workers (observed in r4: a
-    cross-file hang wedged the suite >44min with zero output).
+    test runs over five minutes (four times the longest of the block and
+    compile tests on the driver's box, 73 s, and over twice the longest
+    rehearsal under ``benchmark_suite/``, 127 s: PR 65's junit file),
+    faulthandler dumps EVERY thread's stack and the process exits — a
+    monolithic `pytest tests/` run must never sit stalled for an hour
+    with idle leaked workers (observed in r4: a cross-file hang wedged
+    the suite >44min with zero output).
 
     The dump goes to a FILE (ray_tpu_hang_dump.log under the system
     temp dir), not stderr: pytest's default fd-level capture dup2s
@@ -62,7 +84,7 @@ def _hang_watchdog():
     silent rc=1. A plain file survives the hard _exit."""
     import faulthandler
 
-    faulthandler.dump_traceback_later(600, exit=True,
+    faulthandler.dump_traceback_later(300, exit=True,
                                       file=_watchdog_log())
     yield
     faulthandler.cancel_dump_traceback_later()
@@ -74,8 +96,6 @@ _WATCHDOG_FH = None
 def _watchdog_log():
     global _WATCHDOG_FH
     if _WATCHDOG_FH is None:
-        import tempfile
-
         path = os.path.join(tempfile.gettempdir(),
                             "ray_tpu_hang_dump.log")
         _WATCHDOG_FH = open(path, "a")  # noqa: SIM115 — must outlive tests
@@ -111,9 +131,15 @@ def _kill_orphan_workers():
 @pytest.fixture(scope="module", autouse=True)
 def _reap_leaked_workers():
     """Cross-file process hygiene (instantiated before, finalized after,
-    every module-scoped cluster fixture)."""
+    every module-scoped cluster fixture): the worker processes a module
+    left, and the threads of the dashboard heads it started (a head
+    outlived its test and its cluster, with its loop's thread pool: PR
+    65's run lost a worker that still ran one, ``PERF.md`` §7)."""
     yield
     _kill_orphan_workers()
+    dashboard = sys.modules.get("ray_tpu.dashboard.head")
+    if dashboard is not None:
+        dashboard.stop_dashboard()
 
 
 @pytest.fixture(scope="session")

@@ -4,10 +4,16 @@ The script's defaults are its contract (1B widths on the TPU) and its
 command line has no way round them; here its phase functions run with
 ``Plan.tiny()`` — test-sized widths, the CPU platform — on one small
 shared cluster. This finds wrong paths, arguments and control flow
-before a chip call does; it says nothing about the chip. The train and
-kernels phases are tier-1; the serve phase (two pool starts, ~30 s) and
-the four-chip phases (~90 s) are ``-m slow`` — run them before a chip
-call that changes what they drive.
+before a chip call does; it says nothing about the chip. The train,
+kernels and hybrid phases are tier-1; the serve phase (two pool starts,
+~30 s) and the four-chip phases (~90 s) are ``-m slow`` — run them
+before a chip call that changes what they drive.
+
+A check that ``hybrid_phase`` hands a child is run once a module, in
+this process (``_answer``): the test of the check and the test of the
+phase read the one result. That a child starts with the platform in
+its environment and answers is ``kernels_phase``'s rehearsal, whose
+``chip_child`` is the script's own.
 """
 
 import json
@@ -26,6 +32,23 @@ def cluster():
     ray_tpu.init(num_cpus=8)
     yield
     ray_tpu.shutdown()
+
+
+_ANSWERS = {}
+
+
+def _answer(call, **args):
+    """``chip_smoke.<call>(**args)`` as ``chip_child`` hands it back
+    (through JSON), made in this process and once a module."""
+    key = call, json.dumps(args, sort_keys=True)
+    if key not in _ANSWERS:
+        _ANSWERS[key] = json.loads(json.dumps(
+            getattr(chip_smoke, call)(**args)))
+    return _ANSWERS[key]
+
+
+def _hybrid_args(**more):
+    return dict(widths=TINY.hybrid_widths, seed=TINY.seed, **more)
 
 
 def _run(capsys, plan, phases=None):
@@ -185,50 +208,6 @@ def test_kernels_phase_rehearses_on_the_cpu():
                for t in facts["flash_fwd_ms_at_batch_1"].values())
 
 
-def test_hybrid_phase_rehearses_on_the_cpu(capsys):
-    """The KDA / MLA block's two forms of each layer at tiny widths, in
-    bf16: prompts of 9 and 21 tokens cross the tiny chunk of 8. The
-    convolution rows and the latent rows agree exactly; the state and
-    the outputs within the phase's tolerance (a few 1e-3 on the CPU)."""
-    rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[3:])
-    assert rc == 0
-    _check_lines(lines, ["hybrid"])
-    facts = lines[0]["checked"]
-    assert set(facts["rel_err"]) == {"9", "21"}
-    for errs in facts["rel_err"].values():
-        assert set(errs) == {"kda_out", "kda_state", "kda_conv", "mla_out",
-                             "mla_rows"}
-        assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
-        assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
-    # the KDA step's kernel (interpreted here) against the XLA body:
-    # the same float32 lines, an inactive slot's state untouched, and no
-    # kernel in the layer's own program off the TPU
-    kda = facts["kda_kernel"]
-    assert set(kda["rel_err"]) == {"state", "out"}
-    assert max(kda["rel_err"].values()) <= chip_smoke.KDA_KERNEL_TOLERANCE
-    assert kda["inactive_kept"] is True and kda["in_program"] is False
-    assert kda["steps"] == TINY.kda_steps == 5
-    # the chunkwise delta rule's kernel (interpreted) against the XLA
-    # body and the recurrence, two calls with S carried
-    chunk = facts["kda_chunk"]
-    assert set(chunk["rel_err"]) == {"kernel_body", "kernel_recurrence",
-                                     "body_recurrence"}
-    assert max(v for pair in chunk["rel_err"].values()
-               for v in pair.values()) <= chip_smoke.KDA_CHUNK_TOLERANCE
-    assert chunk["in_program"] is False
-    assert chunk["rows"] == TINY.kda_chunk_rows == 32
-    # the fourth block's gated MLA layer: the rows the two forms keep
-    # are the same rows, the outputs agree within the tolerance
-    assert set(facts["latent"]) == {"9", "21"}
-    for errs in facts["latent"].values():
-        assert errs["latent_rows"] == 0.0
-        assert 0 < errs["latent_out"] <= chip_smoke.HYBRID_TOLERANCE
-    # the fifth block's two kinds of layer, each in one segment here
-    assert facts["segment"]["segments"] == {"9": 1, "21": 1}
-    assert max(v for errs in facts["segment"]["rel_err"].values()
-               for v in errs.values()) <= chip_smoke.HYBRID_TOLERANCE
-
-
 def test_segment_check_rehearses_on_the_cpu(monkeypatch):
     """What ``hybrid_phase`` asks of the block without positions
     (``models/solar.py``) on the chip, at tiny widths with segments of 8
@@ -283,8 +262,9 @@ def test_dsa_check_rehearses_on_the_cpu(block, chosen):
     128 query rows over 256 keys, ``index_topk`` chosen a row; each
     kernel agrees with its XLA body, the selection's kernel chooses the
     counting passes' sets, an inactive slot's step gives zeros."""
-    found = chip_smoke.dsa_check("tiny", 128, TINY.seed, interpret=True,
-                                 block=block)
+    found = _answer("dsa_check", **_hybrid_args(
+        rows=TINY.dsa_rows, interpret=True, block=block))
+    assert TINY.dsa_rows == 128
     assert found["device"].items() >= CPU.items()
     assert set(found["rel_err"]) == {"dsa_index", "dsa_attn",
                                      "dsa_decode_attn"} | (
@@ -300,8 +280,8 @@ def test_ring_check_rehearses_on_the_cpu():
     interpreter: 20 positions, two wraps, four slots at different
     positions and one inactive; the kernel on the ring and the XLA body
     agree at every step."""
-    found = chip_smoke.ring_check("tiny", TINY.ring_steps, TINY.seed,
-                                  interpret=True)
+    found = _answer("ring_check", **_hybrid_args(
+        steps=TINY.ring_steps, interpret=True))
     assert found["device"].items() >= CPU.items()
     assert (found["window"], found["wraps"]) == (8, 2)
     assert 0 <= found["rel_err"] <= chip_smoke.HYBRID_TOLERANCE
@@ -312,8 +292,8 @@ def test_kda_kernel_check_rehearses_on_the_cpu():
     chip, at tiny widths with the kernel in the Pallas interpreter: five
     tokens over six slots, two of them inactive; and with nobody asking
     for the kernel the same check compares the XLA body with itself."""
-    found = chip_smoke.kda_kernel_check("tiny", TINY.kda_steps, TINY.seed,
-                                        interpret=True)
+    found = _answer("kda_kernel_check", **_hybrid_args(
+        steps=TINY.kda_steps, interpret=True))
     assert found["device"].items() >= CPU.items()
     assert 0 <= max(found["rel_err"].values()) \
         <= chip_smoke.KDA_KERNEL_TOLERANCE
@@ -329,8 +309,8 @@ def test_kda_chunk_check_rehearses_on_the_cpu():
     in the Pallas interpreter: 32 rows in two calls, S carried, against
     the XLA body and the recurrence a token at a time; and with nobody
     asking for the kernel the path IS the XLA body."""
-    found = chip_smoke.kda_chunk_check("tiny", TINY.kda_chunk_rows,
-                                       TINY.seed, interpret=True)
+    found = _answer("kda_chunk_check", **_hybrid_args(
+        rows=TINY.kda_chunk_rows, interpret=True))
     assert found["device"].items() >= CPU.items()
     assert set(found["rel_err"]) == {"kernel_body", "kernel_recurrence",
                                      "body_recurrence"}
@@ -343,6 +323,140 @@ def test_kda_chunk_check_rehearses_on_the_cpu():
     assert found["inputs_in_program"] is False
     same = chip_smoke.kda_chunk_check("tiny", 16, TINY.seed)
     assert same["rel_err"]["kernel_body"] == {"out": 0.0, "state": 0.0}
+
+
+def test_hybrid_check_rehearses_on_the_cpu():
+    """What ``hybrid_phase`` asks first of the KDA / MLA block's two
+    forms of each layer, at tiny widths in bf16: prompts of 9 and 21
+    tokens cross the tiny chunk of 8. The convolution rows and the
+    latent rows agree exactly; the state and the outputs within the
+    phase's tolerance (a few 1e-3 on the CPU)."""
+    found = _answer("hybrid_check", **_hybrid_args(
+        lens=list(TINY.hybrid_lens)))
+    assert found["device"].items() >= CPU.items()
+    assert found["device"]["compile"]["seconds"] >= 0
+    assert set(found["rel_err"]) == {"9", "21"}
+    for errs in found["rel_err"].values():
+        assert set(errs) == {"kda_out", "kda_state", "kda_conv", "mla_out",
+                             "mla_rows"}
+        assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
+        assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+
+
+def test_latent_check_rehearses_on_the_cpu():
+    """What ``hybrid_phase`` asks of the fourth block's gated MLA layer
+    (``models/instella.py``), at tiny widths: the rows the two forms
+    keep are the same rows, the outputs agree within the tolerance."""
+    found = _answer("latent_check", **_hybrid_args(
+        lens=list(TINY.latent_lens)))
+    assert found["device"].items() >= CPU.items()
+    assert set(found["rel_err"]) == {"9", "21"}
+    for errs in found["rel_err"].values():
+        assert errs["latent_rows"] == 0.0
+        assert 0 < errs["latent_out"] <= chip_smoke.HYBRID_TOLERANCE
+
+
+@pytest.mark.parametrize("call, more", [("segment_check", {}),
+                                        ("ssm_check", {"interpret": True})])
+def test_the_phases_own_prompts_run_in_one_segment(call, more):
+    """``segment_check`` and ``ssm_check`` with the prompts the tiny
+    plan gives ``hybrid_phase`` (9 and 21 tokens under segments of
+    2,048 rows: one segment each; the tests above cut segments of 8)."""
+    found = _answer(call, **_hybrid_args(
+        lens=list(getattr(TINY, call.replace("check", "lens"))), **more))
+    assert found["device"].items() >= CPU.items()
+    assert found["segments"] == {"9": 1, "21": 1}
+    assert 0 < max(v for errs in found["rel_err"].values()
+                   for v in errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+
+
+def _answered_in_process(monkeypatch, spoil=None):
+    """``chip_smoke.chip_child`` answered by ``_answer`` (-> the calls
+    it got); ``spoil`` (a check's name -> what to lay over its answer)
+    makes one child report something else."""
+    calls = []
+
+    def child(plan, call, args):
+        assert plan is TINY
+        calls.append(call)
+        return {**_answer(call, **args), **(spoil or {}).get(call, {})}
+
+    monkeypatch.setattr(chip_smoke, "chip_child", child)
+    return calls
+
+
+def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
+    """The phase asks its ten children (the seven checks above and
+    ``dsa_check`` a sparse block) with the plan's own arguments and
+    makes one line of their facts. Each child is answered in this
+    process by the check itself, once a module (``_answer``: the tests
+    above asked the same questions); what a child process adds is
+    ``test_kernels_phase_rehearses_on_the_cpu``'s to show."""
+    calls = _answered_in_process(monkeypatch)
+    rc, lines, _ = _run(capsys, TINY, chip_smoke.ONE_CHIP[3:])
+    assert rc == 0
+    assert calls == [
+        "hybrid_check", "ring_check", "kda_kernel_check", "kda_chunk_check",
+        "latent_check", "segment_check", "ssm_check"] + 3 * ["dsa_check"]
+    _check_lines(lines, ["hybrid"])
+    facts = lines[0]["checked"]
+    assert set(facts["rel_err"]) == {"9", "21"}
+    for errs in facts["rel_err"].values():
+        assert set(errs) == {"kda_out", "kda_state", "kda_conv", "mla_out",
+                             "mla_rows"}
+        assert errs["kda_conv"] == 0.0 and errs["mla_rows"] == 0.0
+        assert max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    # the KDA step's kernel (interpreted here) against the XLA body:
+    # the same float32 lines, an inactive slot's state untouched, and no
+    # kernel in the layer's own program off the TPU
+    kda = facts["kda_kernel"]
+    assert set(kda["rel_err"]) == {"state", "out"}
+    assert max(kda["rel_err"].values()) <= chip_smoke.KDA_KERNEL_TOLERANCE
+    assert kda["inactive_kept"] is True and kda["in_program"] is False
+    assert kda["steps"] == TINY.kda_steps == 5
+    # the chunkwise delta rule's kernel (interpreted) against the XLA
+    # body and the recurrence, two calls with S carried
+    chunk = facts["kda_chunk"]
+    assert set(chunk["rel_err"]) == {"kernel_body", "kernel_recurrence",
+                                     "body_recurrence"}
+    assert max(v for pair in chunk["rel_err"].values()
+               for v in pair.values()) <= chip_smoke.KDA_CHUNK_TOLERANCE
+    assert chunk["in_program"] is False
+    assert chunk["rows"] == TINY.kda_chunk_rows == 32
+    # the fourth block's gated MLA layer: the rows the two forms keep
+    # are the same rows, the outputs agree within the tolerance
+    assert set(facts["latent"]) == {"9", "21"}
+    for errs in facts["latent"].values():
+        assert errs["latent_rows"] == 0.0
+        assert 0 < errs["latent_out"] <= chip_smoke.HYBRID_TOLERANCE
+    # the fifth block's two kinds of layer, each in one segment here
+    assert facts["segment"]["segments"] == {"9": 1, "21": 1}
+    assert max(v for errs in facts["segment"]["rel_err"].values()
+               for v in errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    assert facts["ssm"]["segments"] == {"9": 1, "21": 1}
+    assert facts["ssm"]["inactive_kept"] and not facts["ssm"]["in_program"]
+    assert facts["ring"]["wraps"] == 2 and facts["ring"]["window"] == 8
+    assert set(facts["dsa"]) == {"dots", "glm_dsa", "glm_next"}
+    assert all(found["sets_equal"] and found["rows"] == 128
+               for found in facts["dsa"].values())
+
+
+@pytest.mark.parametrize("call, spoil, said", [
+    ("ring_check", {"rel_err": 1.0}, "sliding layer's ring"),
+    ("kda_kernel_check", {"inactive_kept": False}, "kda_step kernel"),
+    ("latent_check", {"device": {"platform": "tpu", "kind": "other",
+                                 "count": 1}}, "latent child")])
+def test_hybrid_phase_fails_when_one_child_does(call, spoil, said, capsys,
+                                                monkeypatch):
+    """One child's answer spoilt (an error over the tolerance, an
+    inactive slot's state moved, another platform): the phase fails by
+    that check's name, asks no child after it, and the run prints no
+    line for the phase and no result. (The second, third and fifth
+    child: a case that finds no answer made yet makes five at most.)"""
+    calls = _answered_in_process(monkeypatch, {call: spoil})
+    rc, lines, err = _run(capsys, TINY, chip_smoke.ONE_CHIP[3:])
+    assert rc != 0 and lines == [] and calls[-1] == call
+    assert said in err and "'hybrid' FAILED" in err
 
 
 @pytest.mark.slow

@@ -358,9 +358,10 @@ def test_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
 
 def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
     """The attention's and the experts' shapes, a leaf larger than a
-    block drawn block by block, the published count of parameters (the
-    types: ``tests/test_slot_protocol.py``)."""
-    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
+    block drawn block by block (a block is 4,096 numbers here, the
+    leaf read 6,144: two blocks), the published count of parameters
+    (the types: ``tests/test_slot_protocol.py``)."""
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 12)
     cfg = _cfg(dtype="bfloat16")
     params = exaone.init_params(cfg, jax.random.PRNGKey(0))
     attn = params["layers"][3]["attn"]

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _segments import forget_programs
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import instella, moe
@@ -189,18 +190,30 @@ def test_prefill_then_ragged_decode_is_the_references_forward(
     assert control > tol, (control, tol)
 
 
+def _past_the_trained_range():
+    rng = np.random.RandomState(4)
+    return [rng.randint(1, 256, TRAINED + 7).astype(np.int32)]
+
+
+@pytest.fixture(scope="module")
+def as_it_is(model):
+    """The comparison of ``test_a_part_left_out...`` on the program as
+    it is, made once for its four cases."""
+    return _worst(*model, _past_the_trained_range(), 6, np.max)
+
+
 @pytest.mark.parametrize("left_out", ["farskip", "gate", "yarn_blend",
                                       "float32_sigmoids_in_bf16"])
-def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model):
+def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model,
+                                              as_it_is):
     """The float32 comparison catches a dropped term: the plain pre-norm
     residual path for FarSkip's, the attention's output ungated, the
     plain rotary's frequencies under YaRN's softmax scale (a prompt past
     the trained range, every decoded position beyond it), and the
     float32 sigmoids (the router's scores, the gate) rounded to bf16."""
     cfg, params = model
-    rng = np.random.RandomState(4)
-    prompts = [rng.randint(1, 256, TRAINED + 7).astype(np.int32)]
-    assert _worst(cfg, params, prompts, 6, np.max) < F32_TOL
+    prompts = _past_the_trained_range()
+    assert as_it_is < F32_TOL
     if left_out == "farskip":
         cfg = _cfg(farskip=False)
     elif left_out == "gate":
@@ -216,10 +229,10 @@ def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model):
         sigmoid = jax.nn.sigmoid
         monkeypatch.setattr(jax.nn, "sigmoid",
                             lambda a: bf16(sigmoid(bf16(a))))
-    jax.clear_caches()  # (the engine's programs are cached by cfg alone)
+    forget_programs()
     ragged = _ragged_logits(cfg, params, prompts, 6)
     monkeypatch.undo()  # (the reference computes as it is written)
-    jax.clear_caches()
+    forget_programs()
     got = _worst(cfg, params, prompts, 6, np.max, ragged=ragged)
     print(f"{left_out}: {got}")
     assert got > 10 * F32_TOL, (left_out, got)
@@ -347,9 +360,10 @@ def test_spans_carry_the_latent_state_and_the_routing(model):
 
 def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
     """The attention's and the experts' shapes, a leaf larger than a
-    block drawn block by block, ``w_down`` scaled for the published
+    block drawn block by block (a block is 4,096 numbers here, the leaf
+    read 32,768: eight blocks), ``w_down`` scaled for the published
     depth and ``wo`` not (the types: ``tests/test_slot_protocol.py``)."""
-    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 12)
     cfg = _cfg(dtype="bfloat16")
     params = instella.init_params(cfg, jax.random.PRNGKey(0))
     attn = params["layers"][2]["attn"]

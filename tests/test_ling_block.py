@@ -366,8 +366,11 @@ def test_readback_counts_the_held_share_and_weights_can_be_swapped(model):
 def test_init_params_draws_a_large_leaf_in_blocks(monkeypatch):
     """A leaf larger than a block drawn block by block (the same values
     whatever the block size would be a different draw: only shapes and
-    statistics are held; the types: ``tests/test_slot_protocol.py``)."""
-    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
+    statistics are held; the types: ``tests/test_slot_protocol.py``).
+    A block is 4,096 numbers here: the leaf read, 16,384, is drawn in
+    four, and the small leaves whole (blocks of 1,024 put 75 of the
+    129 leaves through a loop of their own, 37 s of compiling)."""
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 12)
     cfg = _cfg(dtype="bfloat16")
     params = ling.init_params(cfg, jax.random.PRNGKey(0))
     w = np.asarray(params["layers"][1]["mlp"]["w_gate"], np.float32)

@@ -90,6 +90,29 @@ def test_metrics_from_remote_task(cluster):
     raise AssertionError(f"task metric never arrived: {rows}")
 
 
+def test_stop_dashboard_ends_the_heads_threads(cluster):
+    """A head that is stopped leaves no thread behind (its loop's, and
+    the pool's that served its requests) and answers nobody."""
+    import threading
+
+    from ray_tpu.dashboard import start_dashboard, stop_dashboard
+
+    def loops_and_pools():  # (the cluster's own loops have theirs too)
+        return {t for t in threading.enumerate()
+                if t.name == "ray_tpu-dashboard"
+                or t.name.startswith("asyncio_")}
+
+    before = loops_and_pools()
+    addr = start_dashboard()
+    assert _get(addr, "/api/cluster")[0] == 200
+    started = loops_and_pools() - before
+    assert len(started) >= 2, started  # the loop and one of its pool
+    stop_dashboard()
+    assert not [t for t in started if t.is_alive()]
+    with pytest.raises(OSError):
+        _get(addr, "/api/cluster")
+
+
 def test_dashboard_endpoints(cluster):
     from ray_tpu.dashboard import start_dashboard
 

@@ -167,8 +167,11 @@ def test_a_replica_holds_the_serving_types(name, monkeypatch):
     A block that unrolls its layers draws that tree itself (leaf by
     leaf, a leaf larger than a block in blocks), and it comes back from
     ``serving_params`` itself; float32 masters (the Llama block's, a
-    published tree of any block) are cast once, on adoption."""
-    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
+    published tree of any block) are cast once, on adoption. A block is
+    4,096 numbers here: every block that draws its own tree has leaves
+    above it, which come through the loop over blocks, and leaves
+    under it, which are drawn whole."""
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 12)
     mod, tiny = BLOCKS[name]
     cfg = tiny(dtype="bfloat16")
     slots = de.slot_model(cfg)
@@ -176,6 +179,9 @@ def test_a_replica_holds_the_serving_types(name, monkeypatch):
     if name in OWN:
         assert slots is mod.SLOTS
     made = mod.init_params(cfg, jax.random.PRNGKey(0))
+    if name in OWN:
+        sizes = [a.size for a in jax.tree_util.tree_leaves(made)]
+        assert min(sizes) < moe._BLOCK_ELEMS < max(sizes)
     masters = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), made)
     assert (slots.serving_params(cfg, made) is made) == (name in OWN)
     for tree in (slots.serving_params(cfg, made),

@@ -41,7 +41,7 @@ import numpy as np
 import pytest
 
 from _segments import (  # noqa: F401 (segments_of_16: a fixture)
-    segments_of_16, short_prompt_in_a_reused_slot)
+    forget_programs, segments_of_16, short_prompt_in_a_reused_slot)
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import ling, moe, solar
@@ -411,9 +411,17 @@ def test_a_short_prompt_in_a_long_bucket_is_the_reference_in_a_reused_slot(
             params, jnp.asarray([tokens]), M)[0], F32_TOL)
 
 
+@pytest.fixture(scope="module")
+def as_it_is(model):
+    """The comparison of ``test_a_part_left_out...`` on the program as
+    it is, made once for its four cases."""
+    return _worst(*model, _prompts(4), 6, np.max)
+
+
 @pytest.mark.parametrize("left_out", ["beta_doubled", "gqa_gate", "no_rotary",
                                       "low_rank_gate"])
-def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model):
+def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model,
+                                              as_it_is):
     """The float32 comparison catches each reading the configuration
     file had to choose: beta = sigmoid (not doubled:
     ``kda_allow_neg_eigval`` ignored), the GQA layers' output ungated
@@ -421,7 +429,7 @@ def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model):
     ignored), and a full-rank output gate in the KDA layers where the
     low rank is stated (``kda_use_full_proj`` false ignored)."""
     cfg, params = model
-    assert _worst(cfg, params, _prompts(4), 6, np.max) < F32_TOL
+    assert as_it_is < F32_TOL
     if left_out == "beta_doubled":
         inputs = solar._kda_inputs
 
@@ -455,10 +463,10 @@ def test_a_part_left_out_fails_the_comparison(left_out, monkeypatch, model):
             return ling._kda_out(cfg, p, o, gate)
 
         monkeypatch.setattr(solar, "_kda_out", full_rank)
-    jax.clear_caches()  # (the engine's programs are cached by cfg alone)
+    forget_programs()
     got = _worst(cfg, params, _prompts(4), 6, np.max)
     monkeypatch.undo()
-    jax.clear_caches()
+    forget_programs()
     print(f"{left_out}: {got}")
     assert got > 100 * F32_TOL, (left_out, got)
 
@@ -616,10 +624,11 @@ def test_both_kda_blocks_call_the_one_step_kernel():
 
 def test_init_params_draws_this_blocks_leaves_in_blocks(monkeypatch):
     """Both kinds of layer's leaves and shapes; a leaf larger than a
-    block drawn block by block; the matrices that write into the stream
+    block drawn block by block (a block is 4,096 numbers here, the leaf
+    read 6,144: two blocks); the matrices that write into the stream
     scaled for the published depth (the types:
     ``tests/test_slot_protocol.py``)."""
-    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 10)
+    monkeypatch.setattr(moe, "_BLOCK_ELEMS", 1 << 12)
     cfg = _cfg(dtype="bfloat16")
     params = solar.init_params(cfg, jax.random.PRNGKey(0))
     kda, gqa = params["layers"][1]["attn"], params["layers"][0]["attn"]

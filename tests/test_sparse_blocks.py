@@ -41,7 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _segments import decode_from, live_kv_case, prefill_slot
+from _segments import (
+    decode_from, forget_programs, live_kv_case, prefill_slot)
 from _sparse import only, sparse_block, tokens
 from ray_tpu.models import dots, glm_dsa, moe
 from ray_tpu.models.decode_engine import RaggedDecoder
@@ -78,10 +79,10 @@ def segments_of_16():
     """Every bucket of this file in segments of 16 rows (the engine's
     programs are cached by cfg alone: set once, cleared once)."""
     was, moe.SEGMENT_ROWS = moe.SEGMENT_ROWS, 16
-    jax.clear_caches()
+    forget_programs()
     yield
     moe.SEGMENT_ROWS = was
-    jax.clear_caches()
+    forget_programs()
 
 
 @pytest.fixture(scope="module", params=list(BLOCKS))

@@ -14,7 +14,7 @@ from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
     _kda_chunk_calls, _kda_inputs_calls, KERNEL, _lower_prefill, _mem, MIB,
-    _on, topo)
+    _on, lowered_counting_kda_bodies, once, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -114,7 +114,24 @@ def test_ling_decode_chunk_keeps_its_state_and_weights_where_they_lie(
             < 13 * 1024 * MIB), _mem(compiled)
 
 
-@pytest.mark.parametrize("bucket", [256, 512, 1024])
+def _prefill(cfg, params, state, vec, bucket):
+    """The cell's cold prefill call at ``bucket`` rows, lowered from
+    nothing (no earlier trace of this shape) with the two KDA kernels'
+    bodies counted, and compiled, once for the tests that read it. ->
+    (the lowered module's text, the keywords each body was traced with,
+    the compiled program)."""
+    def make():
+        lowered, traced = lowered_counting_kda_bodies(
+            lambda: _lower_prefill(cfg, vec(jnp.int32).sharding, bucket,
+                                   (params, state, vec)))
+        return lowered.as_text(), [kw for _, kw in traced], lowered.compile()
+
+    return once(("ling prefill", bucket), make)
+
+
+@pytest.mark.parametrize("bucket", [
+    256, pytest.param(512, marks=pytest.mark.slow),
+    pytest.param(1024, marks=pytest.mark.slow)])
 def test_ling_prefill_holds_one_kda_chunk_call_a_kda_layer(
         topo, monkeypatch, bucket):
     """``ling-3.0-flash-vl-ep4-1chip.reason-saturated``'s cold prefill
@@ -122,11 +139,14 @@ def test_ling_prefill_holds_one_kda_chunk_call_a_kda_layer(
     chunkwise delta rule is one ``kda_chunk`` call a KDA layer, six a
     program, on the arrays as the projections leave them
     (``_kda_chunk_calls``); arguments and temporaries stay under 13 GiB
-    of the chip's 16."""
+    of the chip's 16. Tier-1 holds the narrowest bucket, whose four
+    chunks a call already cross the chunk boundary and whose operands
+    XLA does prefetch; 512 and 1,024 rows (50 to 60 s each of the TPU's
+    compiler for the same lines at other extents) are ``-m slow``, and
+    the cell compiles both on the chip in every PR's check."""
     fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
     assert tuple(eng["prompt_buckets"]) == (256, 512, 1024)
-    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, bucket,
-                              (params, state, vec)).compile()
+    _, _, compiled = _prefill(cfg, params, state, vec, bucket)
     # (at 256 and 512 rows XLA prefetches a layer's 4 to 8 MB ``g`` into
     # VMEM ahead of two of the calls; at 1,024 nothing moves)
     text = compiled.as_text()
@@ -149,25 +169,17 @@ def test_lowering_lings_prefill_traces_the_kda_kernels_once(
         topo, monkeypatch):
     """What the kernel costs a process's start is its trace
     (``ops/kda_chunk.py``: a thousand lines of columns, seconds each):
-    the call is jitted by itself, so lowering the 1,024-row prefill with
-    its six KDA layers runs the kernel's body ONCE, not once a layer,
-    and the lowered module holds one copy of the kernel that the six
-    layers call. (Traced a layer, Ling's set-up read 120-160 s for the
-    parent's 80-88: ``PERF.md`` §6, PRs 45-47.)"""
-    from ray_tpu.ops import kda_chunk as kc
-    from ray_tpu.ops import kda_inputs as ki
-
+    the call is jitted by itself, so lowering the prefill with its six
+    KDA layers runs the kernel's body ONCE, not once a layer, and the
+    lowered module holds one copy of the kernel that the six layers
+    call. (Traced a layer, Ling's set-up read 120-160 s for the
+    parent's 80-88: ``PERF.md`` §6, PRs 45-47.) Read off the 256-row
+    call that the test above compiles (1,024 rows before PR 66: the
+    layers and the kernels' keywords are the same at every bucket)."""
     fam, m, cfg, eng, params, state, vec = _ling_cell(topo, monkeypatch)
-    traced = []
-    for module in (kc, ki):
-        monkeypatch.setattr(module, "_kernel", lambda *a, _body=module._kernel,
-                            **kw: (traced.append(kw), _body(*a, **kw))[1])
-    jax.clear_caches()  # (an earlier test's trace of this shape)
-    lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 1024,
-                             (params, state, vec))
+    text, traced, _ = _prefill(cfg, params, state, vec, 256)
     assert sorted(traced, key=len) == [
         {"hb": 16}, {"hb": 8, "dk": 128, "lower_bound": cfg.kda_lower_bound}]
-    text = lowered.as_text()
     # (``ops/kda_inputs.py``'s call is jitted by itself for the same
     # reason: one private function, which the six layers call)
     for fn in ("_kda_chunk", "_kda_inputs"):

@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _engine_args, INTERNLM2, KERNEL, _lower_prefill, _mem, OLMOE, _on,
+    _engine_args, INTERNLM2, KERNEL, _lower_prefill, _mem, OLMOE, _on, once,
     _serve_cfg, topo, _weight_casts, _whole_layer_ops)
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import llama
@@ -158,9 +158,35 @@ def test_decode_attention_kernel_compiles_at_the_cells_shapes(topo, cell):
         rf"= bf16\[(1,)?{slots},{rows},{hkv * 128}\]", text)
 
 
+def _doc_chunk(topo, model):
+    """The doc cell's decode program for ``model`` two layers deep (8
+    slots x 1296 rows, a chunk of 16, the serving tree, both kernels
+    asked for by name), compiled once for the two tests that read it.
+    -> (cfg, the engine's arguments, the compiled program, its text)."""
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops import grouped_matmul as gm
+
+    def make():
+        cfg = llama.LlamaConfig(**(
+            INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}))
+        args = _engine_args(cfg, SingleDeviceSharding(topo.devices[0]),
+                            slots=8, max_len=1296)
+        params, cache, vec = args
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(gm, "grouped_matmul", functools.partial(
+                gm.grouped_matmul, use_kernel=True))
+            patch.setattr(da, "decode_attention", functools.partial(
+                da.decode_attention, use_kernel=True))
+            compiled = de.decode_chunk.lower(
+                params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+                chunk=16).compile()
+        return cfg, args, compiled, compiled.as_text()
+
+    return once(("doc chunk", model), make)
+
+
 @pytest.mark.parametrize("model", ["internlm2", "olmoe"])
-def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
-                                                     model):
+def test_decode_chunk_leaves_the_cache_where_it_lies(topo, model):
     """The doc cell's decode program (8 slots x 1296 rows, 2 layers): a
     step writes 8 rows into the stacked cache and the ``decode_attn``
     kernel reads the layer's live blocks out of the stack in place. No
@@ -170,21 +196,7 @@ def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
     fifth of a chunk), copied or transposed, none is written back into
     the stack whole, the stack is never copied (OLMoE's ``copy.129`` /
     ``.130``), and the donated cache is updated in place."""
-    from ray_tpu.ops import decode_attention as da
-    from ray_tpu.ops import grouped_matmul as gm
-
-    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, use_kernel=True))
-    monkeypatch.setattr(da, "decode_attention", functools.partial(
-        da.decode_attention, use_kernel=True))
-    cfg = llama.LlamaConfig(**(
-        INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}))
-    chip = SingleDeviceSharding(topo.devices[0])
-    params, cache, vec = _engine_args(cfg, chip, slots=8, max_len=1296)
-    compiled = de.decode_chunk.lower(
-        params, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
-        chunk=16).compile()
-    text = compiled.as_text()
+    cfg, _, compiled, text = _doc_chunk(topo, model)
     assert "decode_attn" in text
     # one call in the layer loop's body (and OLMoE's three moe_gmm)
     assert text.count(KERNEL) == (1 if model == "internlm2" else 4)
@@ -206,19 +218,27 @@ def test_decode_chunk_leaves_the_cache_where_it_lies(topo, monkeypatch,
     assert mem.alias_size_in_bytes >= cache_bytes, _mem(compiled)
 
 
-@pytest.mark.parametrize("program", ["chunk", "prefill"])
+@pytest.mark.parametrize("program", [
+    "chunk", pytest.param("prefill", marks=pytest.mark.slow)])
 @pytest.mark.parametrize("model", ["internlm2", "olmoe"])
 def test_serving_programs_hold_no_cast_of_a_weight(topo, monkeypatch,
                                                    model, program):
-    """The greedy chunk and a one-row prefill call, handed the serving
-    tree (``_engine_args``): no f32 parameter larger than a norm stack,
-    no f32 array of a matrix's shape anywhere in the program. From the
-    f32 masters the same chunk holds both (the casts were 21% of a
-    chunk and half of a prefill call, PERF.md PR 28)."""
+    """The greedy chunk (``_doc_chunk``: the program of the test above,
+    the cell's own with both kernels) and a one-row prefill call, handed
+    the serving tree (``_engine_args``): no f32 parameter larger than a
+    norm stack, no f32 array of a matrix's shape anywhere in the
+    program. From the f32 masters the same chunk holds both (the casts
+    were 21% of a chunk and half of a prefill call, PERF.md PR 28).
+    The prefill case is ``-m slow`` (35 s a model on the driver's box):
+    every tier-1 bucket of that program is held to the same lines in
+    ``_tpu_compile._one_row_prefill_is_sized_by_its_bucket``."""
+    from ray_tpu.ops import decode_attention as da
     from ray_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
         gm.grouped_matmul, use_kernel=True))
+    monkeypatch.setattr(da, "decode_attention", functools.partial(
+        da.decode_attention, use_kernel=True))
     cfg = llama.LlamaConfig(**(
         INTERNLM2 if model == "internlm2" else {**OLMOE, "n_layers": 2}))
     chip = SingleDeviceSharding(topo.devices[0])
@@ -233,12 +253,12 @@ def test_serving_programs_hold_no_cast_of_a_weight(topo, monkeypatch,
         assert [c for c in _weight_casts(text, cfg)
                 if not c.startswith("lm_head: ")] == []
         return
-    params, cache, vec = _engine_args(cfg, chip, **shape)
+    _, (params, cache, vec), _, serving = _doc_chunk(topo, model)
     masters = _on(chip, jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
-    serving, from_masters = (de.decode_chunk.lower(
-        tree, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
-        chunk=16).compile().as_text() for tree in (params, masters))
+    from_masters = de.decode_chunk.lower(
+        masters, cache, vec(jnp.int32), vec(jnp.bool_), None, cfg=cfg,
+        chunk=16).compile().as_text()
     assert _weight_casts(serving, cfg) == []
     assert len(_weight_casts(from_masters, cfg)) >= 8
 

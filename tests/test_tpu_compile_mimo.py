@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
     KERNEL, MIB, MOSAIC_BODY, _dead_branch_hands_on_and_makes_zeros,
     _expert_branches, _loops_add_nothing_unscoped, _lower_prefill, _made_by,
-    _mem, _mosaic_text, _on, _segment_branches, topo)
+    _mem, _mosaic_text, _on, _segment_branches, once, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -49,6 +49,18 @@ def _mimo_cell(topo, monkeypatch):
     vec = lambda dt, n=eng["slots"]: jax.ShapeDtypeStruct(  # noqa: E731
         (n,), dt, sharding=chip)
     return fam, m, cfg, eng, params, state, vec
+
+
+def _widest_prefill(cfg, params, state, vec):
+    """The cell's cold prefill call at its widest bucket, one prompt of
+    32,768 rows in 16 segments of 2,048, compiled once for the three
+    tests that read it -> (the compiled program, its text)."""
+    def make():
+        compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, 32768,
+                                  (params, state, vec)).compile()
+        return compiled, compiled.as_text()
+
+    return once("mimo prefill 32768", make)
 
 
 def _kernel_calls(text: str) -> list:
@@ -125,9 +137,7 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
     fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
     assert eng["prompt_buckets"][-1] == 32768
     assert mimo.SLOTS.prefill_segments(cfg, 32768) == 16
-    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, 32768,
-                              (params, state, vec)).compile()
-    text = compiled.as_text()
+    compiled, text = _widest_prefill(cfg, params, state, vec)
     calls = _kernel_calls(text)
     assert sum(bool(re.match(r"%flash_fwd_window\b", c)) for c in calls) \
         == cfg.window_layers == 5
@@ -179,13 +189,14 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
 
 def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
         topo, monkeypatch):
-    """The cell's cold prefill call at 16,384 rows (eight segments): a
+    """The cell's cold prefill call at its widest bucket (32,768 rows,
+    sixteen segments; at eight the program is the same lines): a
     layer's one loop holds one ``conditional`` on a segment's first row
     against the prompt's rows, which the program reads from
     ``true_lens`` (``moe.in_segments`` with ``live``), and an expert
     layer's live branch one more (``moe.moe``'s). The dead branch
-    hands the k and v rows it carries on (``[1, Hkv, 16384, 192 / 128]``,
-    25 to 100 MB a layer) and makes zeros, nothing else: no kernel, no
+    hands the k and v rows it carries on (``[1, Hkv, 32768, 192 / 128]``,
+    50 to 200 MB a layer) and makes zeros, nothing else: no kernel, no
     fusion, no copy, and no loop copies them either (written a segment
     at a time in place); each layer's flash kernel is called in the live
     branch; the branch lands nothing in ``unscoped`` (three instructions
@@ -193,16 +204,15 @@ def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     from ray_tpu.models import mimo
 
     fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
-    assert mimo.SLOTS.prefill_segments(cfg, 16384) == 8
-    text = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
-                          (params, state, vec)).compile().as_text()
+    assert mimo.SLOTS.prefill_segments(cfg, 32768) == 16
+    _, text = _widest_prefill(cfg, params, state, vec)
     branches = _segment_branches(text)
     assert len(branches) == text.count(" while(") == cfg.n_layers == 7
     assert text.count(" conditional(") == cfg.n_layers + cfg.moe_layers
     for loop, dead, live in branches:
         _dead_branch_hands_on_and_makes_zeros(dead)
         copied = [ln[:160] for ln in loop + live if re.search(
-            r"= bf16\[1,[48],16384,(192|128)\]\S* (copy|copy-start)\(", ln)]
+            r"= bf16\[1,[48],32768,(192|128)\]\S* (copy|copy-start)\(", ln)]
         assert not copied, copied
         assert sum(KERNEL in ln and "flash_fwd" in ln.split(" = ")[0]
                    for ln in live) == 1
@@ -211,8 +221,9 @@ def test_mimo_prefill_skips_the_segments_behind_the_prompts_last_live_one(
 
 def test_mimo_segments_expert_layer_works_on_the_rows_its_experts_got(
         topo, monkeypatch):
-    """The cell's cold prefill call at 8,192 rows (four segments of
-    2,048: N = 16,384 assignments a segment, C = 2,048): every expert
+    """The cell's cold prefill call at its widest bucket (segments of
+    2,048 rows: N = 16,384 assignments a segment, C = 2,048, whatever
+    the bucket): every expert
     layer's live segment holds ONE ``conditional`` of two branches that
     each call ``moe_gmm`` three times. The compact branch multiplies
     ``[2048, 4096]`` and ``[2048, 2048]`` operands and makes ONE array
@@ -222,8 +233,7 @@ def test_mimo_segments_expert_layer_works_on_the_rows_its_experts_got(
     their product, y) live in the fall-back's computation and nowhere
     else in the program."""
     fam, m, cfg, eng, params, state, vec = _mimo_cell(topo, monkeypatch)
-    text = _lower_prefill(cfg, vec(jnp.int32).sharding, 8192,
-                          (params, state, vec)).compile().as_text()
+    _, text = _widest_prefill(cfg, params, state, vec)
     found = _expert_branches(text)
     assert len(found) == cfg.moe_layers == 6
     wide = re.compile(r"= \(?(?:bf16|f32)\[(?:\d+,)*16384,\d{3,}\]")

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
     _dead_branch_hands_on_and_makes_zeros, _kda_chunk_calls,
     _kda_inputs_calls, KERNEL,
-    _lower_prefill, _mem, MIB, _on,
+    lowered_counting_kda_bodies, _lower_prefill, _mem, MIB, _on, once,
     _loops_add_nothing_unscoped, _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
 
@@ -119,6 +119,22 @@ def test_solar_decode_chunk_keeps_both_kinds_of_state_where_they_lie(
             < 13 * 1024 * MIB), _mem(compiled)
 
 
+def _widest_prefill(cfg, params, state, vec):
+    """The cell's cold prefill call at its widest bucket, one prompt of
+    32,768 rows in 16 segments of 2,048, lowered from nothing (no
+    earlier trace of this shape) and compiled once for the two tests
+    that read it -> (the compiled program, its text, the modules whose
+    kernel's body the lowering ran, in order)."""
+    def make():
+        lowered, traced = lowered_counting_kda_bodies(
+            lambda: _lower_prefill(cfg, vec(jnp.int32).sharding, 32768,
+                                   (params, state, vec)))
+        compiled = lowered.compile()
+        return compiled, compiled.as_text(), [of for of, _ in traced]
+
+    return once("solar prefill 32768", make)
+
+
 def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
         topo, monkeypatch):
     """The cell's cold prefill call at its widest bucket, one prompt of
@@ -138,9 +154,7 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
     assert eng["prompt_buckets"][-1] == 32768
     assert solar.SLOTS.prefill_segments(cfg, 32768) == 16
-    compiled = _lower_prefill(cfg, vec(jnp.int32).sharding, 32768,
-                              (params, state, vec)).compile()
-    text = compiled.as_text()
+    compiled, text, _ = _widest_prefill(cfg, params, state, vec)
     assert text.count("flash_fwd") >= cfg.full_layers and "moe_gmm" in text
     # the chunkwise delta rule: one kernel call a KDA layer, in the scan
     calls = _kda_chunk_calls(text)
@@ -180,7 +194,8 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
 
 def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
         topo, monkeypatch):
-    """The cell's cold prefill call at 16,384 rows (eight segments): each
+    """The cell's cold prefill call at its widest bucket (32,768 rows,
+    sixteen segments; at eight the program is the same lines): each
     of a layer's loops (the GQA layer's projections and its rest, a KDA
     layer's one) holds one ``conditional`` on a segment's first row
     against the prompt's rows, which the program reads from
@@ -196,17 +211,9 @@ def test_solar_prefill_skips_the_segments_behind_the_prompts_last_live_one(
     from ray_tpu.ops import kda_inputs as ki
 
     fam, m, cfg, eng, params, state, vec = _solar_cell(topo, monkeypatch)
-    assert solar.SLOTS.prefill_segments(cfg, 16384) == 8
-    traced = []
-    for module in (kc, ki):
-        monkeypatch.setattr(module, "_kernel", lambda *a, _body=module._kernel,
-                            _of=module.__name__, **kw: (
-            traced.append(_of), _body(*a, **kw))[1])
-    jax.clear_caches()  # (an earlier test's trace of this shape)
-    lowered = _lower_prefill(cfg, vec(jnp.int32).sharding, 16384,
-                             (params, state, vec))
+    assert solar.SLOTS.prefill_segments(cfg, 32768) == 16
+    _, text, traced = _widest_prefill(cfg, params, state, vec)
     assert sorted(traced) == [kc.__name__, ki.__name__], traced
-    text = lowered.compile().as_text()
     branches = _segment_branches(text)
     assert len(branches) == text.count(" while(") \
         == 2 * cfg.full_layers + cfg.kda_layers == 5

@@ -12,17 +12,27 @@ import pytest
 from jax.sharding import Mesh
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, _mem, topo, _train_cfg, _train_step)
+    KERNEL, _mem, once, topo, _train_cfg, _train_step)
 from ray_tpu.models import llama
 from ray_tpu.parallel import AXES, MeshConfig, use_mesh
 
 
+def _cell_step(topo) -> str:
+    """2 layers at the four-chip cell's widths, batch and mesh
+    (``internlm2-1.8b.pretrain-4k-fsdp2tp2``: fsdp=2 x tp=2), compiled
+    once for the two tests that read its text."""
+    return once("four-chip train step", lambda: _train_step(
+        topo, _train_cfg(seq=4096, n_layers=2, d_ff=8192, vocab_size=92544,
+                         rope_theta=1e6),
+        MeshConfig(fsdp=2, tp=2), batch=6, seq=4096).as_text())
+
+
 def test_sharded_train_step_compiles_with_kernel(topo):
-    """2 layers at 1B widths on fsdp=2 x tp=2: before the shard_map in
-    ops/attention.py this failed with 'Mosaic kernels cannot be
-    automatically partitioned'."""
-    text = _train_step(topo, _train_cfg(n_layers=2),
-                       MeshConfig(fsdp=2, tp=2)).as_text()
+    """The four-chip cell's step on fsdp=2 x tp=2 (``_cell_step``; 2
+    layers at 1B widths on that mesh before PR 66, another program of
+    the same parts): before the shard_map in ops/attention.py this
+    failed with 'Mosaic kernels cannot be automatically partitioned'."""
+    text = _cell_step(topo)
     assert KERNEL in text
     assert "all-reduce" in text and "all-gather" in text
 
@@ -54,10 +64,7 @@ def test_sharded_train_step_hides_its_tp_transfers(topo):
     (``parallel/tp_products.py``). What is left of that shape is the
     head's input gradient, once a step. Says the mechanism engaged;
     only the chip says how much of a transfer its product hides."""
-    text = _train_step(
-        topo, _train_cfg(seq=4096, n_layers=2, d_ff=8192, vocab_size=92544,
-                         rope_theta=1e6),
-        MeshConfig(fsdp=2, tp=2), batch=6, seq=4096).as_text()
+    text = _cell_step(topo)
     assert KERNEL in text
     whole = [ln for ln in text.splitlines()
              if re.search(r"= bf16\[3,4096,2048\]\S* all-reduce\(", ln)]
