@@ -15,14 +15,27 @@ p = exp(s - lse) per tile and runs two kernels — dq with
 the k dimension innermost, dk/dv with the q dimension innermost — so memory
 stays O(block²) and nothing [T, S]-shaped ever materializes.
 
+Which call runs which body. :func:`flash_attention` (differentiable:
+every block's ``forward``, both train steps, the serving prefills that
+call ``ops.attention.attention``) runs :func:`_fwd_kernel`, or
+:func:`_fwd_kernel_1pass` where the keys are one tile, and ALWAYS makes
+the lse, since nothing there can see whether a call will be
+differentiated: two results, ``flash_fwd`` in a trace.
+
 Forward only (:func:`flash_fwd`, PR 50): keys wider than values (``d_qk``
 192 beside ``d_v`` 128: the output and the accumulator take v's width),
 the query rows at a TRACED ``offset`` behind the keys' first row (a
 segment of a prompt against the rows written so far: the grid covers
 every k block and an index map that stops at the last live one keeps
 the dead steps off the HBM), and a learned sink (a logit a head that
-joins the denominator and takes no value). With none of these the call
-is the parent's, text for text.
+joins the denominator and takes no value). Without a band it is a body
+of its own since PR 67 (:func:`_fwd_kernel_t`, ONE result, ``flash_fwd``
+in a trace as well): a cell is as many of a kv head's query heads as
+give 2,048 rows (:func:`_fwd_blocks`), the scores are formed TRANSPOSED
+as the band's below, and the online update LAGS a tile (a tile's
+exponentials are taken against the max of the tiles before it, so that
+none waits for its own tile's max); it shares no branch with the
+differentiable call's kernels, whose program text is their parent's.
 
 A band (``window``: row i sees keys i + offset - window + 1 ... i +
 offset) is a kernel of its own (:func:`_window_kernel`, PR 55, shown in
@@ -63,8 +76,9 @@ def _heads_per_block(flag: str, hq: int, group: int) -> int:
     """Clamped heads-per-grid-cell for `flag` in the single-pass forward
     and the fused backward: must divide hq, and with grouped kv heads
     (``group > 1``) it is one, since those kernels' cells pair head h of
-    q with head h of k (only the band's kernel, :func:`_window_kernel`,
-    puts a kv head's whole group in a cell). One helper so the forward
+    q with head h of k (only the forward-only kernels,
+    :func:`_fwd_kernel_t` and :func:`_window_kernel`, put a kv head's
+    query heads in a cell). One helper so the forward
     and fused-backward eligibility rules can't diverge."""
     from ray_tpu._private import config as _cfg
 
@@ -91,22 +105,26 @@ def _causal_mask(s, q_start, k_start, offset):
     return jnp.where(rows + offset >= cols, s, _NEG_INF)
 
 
-def _k_blocks(q_start, block_q, block_k, offset, nk):
-    """(first, last) k block that rows ``q_start`` .. of a q block see,
-    of ``nk``: from the first (a 0 that its callers add: the full
-    kernel's program text is held to its parent's) up to the
-    diagonal's."""
+def _last_block(q_start, block_q, block_k, offset, nk):
+    """The last k block, of ``nk``, that rows ``q_start`` .. of a q block
+    see: the diagonal's."""
     # (lax.div truncates: the numerator is >= 0)
-    return 0, jnp.minimum(
+    return jnp.minimum(
         jax.lax.div(q_start + block_q - 1 + offset, block_k), nk - 1)
+
+
+def _last_walked(q_start, block_q, block_k, offset, nk):
+    """:func:`_last_block`, or block 0 where the q block's rows lie
+    before every key (T > S): that block is walked, masked whole."""
+    return jnp.maximum(_last_block(q_start, block_q, block_k, offset, nk), 0)
 
 
 def _band_blocks(q_start, block_q, block_k, offset, window, nk):
     """(first, last) k block that a q block's band touches, of ``nk``:
     from its lower edge's up to the diagonal's."""
     return jax.lax.div(
-        jnp.maximum(q_start + offset - window + 1, 0), block_k), _k_blocks(
-            q_start, block_q, block_k, offset, nk)[1]
+        jnp.maximum(q_start + offset - window + 1, 0), block_k), _last_block(
+            q_start, block_q, block_k, offset, nk)
 
 
 def _block_live(causal, q_start, k_start, block_q, offset):
@@ -124,20 +142,11 @@ def _straddles(q_start, k_start, block_k, offset):
     return k_start + block_k - 1 > q_start + offset
 
 
-def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
-                sink=False):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
+                causal, scale, block_q, block_k, offset):
     """offset = S - T: the causal mask is end-aligned (query row i attends
     keys <= i + offset), matching attention_reference's tril(k=S-T) so decode
-    (T=1 against a long cache) sees the whole prefix.
-
-    The forward-only form (``nk_all``: the k blocks there are): ``offset``
-    is the first, scalar-prefetched operand; grid step ``ik`` is k block
-    ``first + ik`` of :func:`_k_blocks`, dead past the last; with ``sink``
-    a head's [1, 128] logit comes after v."""
-    if nk_all is not None:
-        offset_ref, *refs = refs
-        offset = offset_ref[0]
-    q_ref, k_ref, v_ref, *sink_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    (T=1 against a long cache) sees the whole prefix."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -149,11 +158,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     q_start = iq * block_q
-    if nk_all is None:
-        k_start = ik * block_k
-    else:
-        first, last = _k_blocks(q_start, block_q, block_k, offset, nk_all)
-        k_start = (first + ik) * block_k
+    k_start = ik * block_k
 
     def _compute(masked: bool):
         # Matmul operands stay in the input dtype (bf16 hits the MXU's native
@@ -193,10 +198,7 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if nk_all is not None:
-        live = first + ik <= last
-    else:
-        live = _block_live(causal, q_start, k_start, block_q, offset)
+    live = _block_live(causal, q_start, k_start, block_q, offset)
     if causal:
         straddle = _straddles(q_start, k_start, block_k, offset)
         pl.when(jnp.logical_and(live, straddle))(
@@ -211,16 +213,8 @@ def _fwd_kernel(*refs, causal, scale, block_q, block_k, offset, nk_all=None,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_scr[:, :1]
-        if sink_ref:  # the sink's term joins the sum; its value is nothing
-            sink2 = sink_ref[0][0, :, :1] * _LOG2E  # [1, 1], log2 domain
-            m_all = jnp.maximum(m_scr[:, :1], sink2)
-            shrink = jnp.exp2(m_scr[:, :1] - m_all)
-            l = l * shrink + jnp.exp2(sink2 - m_all)
-            m_scr[:] = jnp.broadcast_to(m_all, m_scr.shape)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         acc = acc_scr[:]
-        if sink_ref:
-            acc = acc * shrink
         o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
         # lse is exposed in NATURAL log (public residual contract); the
         # kernel's m statistic is log2-domain, so convert: ln Z =
@@ -277,18 +271,13 @@ def _fwd_kernel_1pass(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
         _one_head(h, masked=causal)
 
 
-def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
-               offset=None, sink=None):
-    """With ``offset`` (an int32 scalar, traced or not) the forward-only
-    form (module docstring; :func:`flash_fwd` is its caller): the tiled
-    kernel with ``offset`` scalar-prefetched, and ``sink`` [Hq] float32
-    where given."""
+def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     b, hq, t, d = q.shape
     _, hkv, s, _ = k.shape
     dv = v.shape[-1]
     group = hq // hkv
     block_q = min(block_q, t)
-    if s <= _FULL_INNER_MAX and offset is None:
+    if s <= _FULL_INNER_MAX:
         block_k = s  # one k tile per q row: no dead-block grid/DMA overhead
     else:
         block_k = min(block_k, s)
@@ -300,11 +289,6 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
     scale = d ** -0.5
     nk = cdiv(s, block_k)
 
-    if offset is not None:
-        return _flash_fwd_at(
-            q, k, v, jnp.asarray(offset, jnp.int32).reshape(1), sink,
-            scale=scale, block_q=block_q, block_k=block_k,
-            interpret=interpret)
     if nk == 1:
         hb = _heads_per_block("flash_heads_per_block", hq, group)
         kernel = functools.partial(
@@ -389,53 +373,202 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret,
     return out, lse4[..., 0]  # lse: [B, H, T] f32
 
 
-def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
+def _fwd_blocks(group: int, t: int, s: int):
+    """The forward-only call's (query heads a cell, block_q, block_k)
+    where the caller gives none, from its shapes: a cell is [1,024 keys,
+    2,048 rows] of scores, the rows as many of a kv head's query heads
+    as divide its group (k and v fetched once for them) over a q block
+    as short as whole lanes allow (128 rows: the least of a straddling
+    tile above the diagonal), one head's 2,048 rows where the group is
+    one. Read on the chip at groups of 16 to 1 (``PERF.md`` §6 PR 67:
+    every content of 2,048 rows reads the same at 1,024 keys, 512 keys
+    cost 4% and 4,096 rows by 1,024 keys do not fit)."""
+    heads = max(h for h in range(1, min(group, 16) + 1) if group % h == 0)
+    block_q = 128
+    while heads * block_q * 2 <= 2048:
+        block_q *= 2
+    return heads, min(t, block_q), min(s, 1024)
+
+
+# The lagged update's room, log2 domain: a tile's scores may stand this far
+# above the rows' max so far before the tile is redone with the max first
+# (2^64 x block_k probabilities x |v| is far inside float32).
+_LAG_MAX = 64.0
+
+
+def _fwd_kernel_t(offset_ref, q_ref, k_ref, v_ref, *refs, scale, block_q,
+                  block_k, nk, sink):
+    """The forward-only body: ``heads`` query heads of one kv head over
+    one q block, q_ref [1, heads, block_q, d_qk] ONE [heads * block_q,
+    d_qk] operand, against k block ``ik`` (the grid's step; dead past
+    :func:`_last_walked`'s, of ``nk``); ``refs`` are the cell's [1, 1,
+    heads * block_q] sink logits (a head's, once a row) with ``sink``, o
+    [1, heads, block_q, d_v] and the scratch.
+
+    The scores are formed TRANSPOSED, ``k @ q^T`` [block_k, rows], as
+    :func:`_window_kernel`'s: keys down the sublanes, the cell's rows
+    along the lanes, so that a row's max and sum are elementwise over
+    registers and ``m``, ``l`` and the correction whole lanes ([1,
+    rows]); the accumulator is ``v^T @ p^T`` [d_v, rows], scaled along
+    the sublanes and turned ONCE a q block, in the last live step, where
+    the sink joins. Statistics in float32 in the log2 domain as
+    :func:`_fwd_kernel`'s; no lse.
+
+    The update LAGS: behind a q block's first tile a tile's
+    probabilities are ``exp2(s - m)`` with ``m`` the rows' max over the
+    tiles BEFORE it, so that no exponential waits for the tile's own max
+    (that wait, not the statistics' passes, is what a tile cost beside
+    its products: ``PERF.md`` §6 PR 67); the tile's sum and product join
+    the state in that reference and the state is then moved to the new
+    max: the same sums, every term against another reference. Where a
+    tile's scores stand more than ``_LAG_MAX`` above the max so far (a
+    float32 overflow in sight) nothing of it is kept and the tile is
+    done again max first, as a q block's first tile is."""
+    *sink_ref, o_ref, m_scr, l_scr, acc_scr, redo_scr = refs
+    heads, _, d = q_ref.shape[1:]
+    rows = heads * block_q
+    ik = pl.program_id(3)
+    offset = offset_ref[0]
+    q_start = pl.program_id(2) * block_q
+    last = _last_walked(q_start, block_q, block_k, offset, nk)
+    k_start = ik * block_k
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def scores(masked: bool):
+        s = jax.lax.dot_general(
+            k_ref[0, 0], q_ref[0].reshape(rows, d), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * (scale * _LOG2E)  # [bk, rows], log2 domain
+        if masked:  # one head's [block_k, block_q], the same for the cell
+            key = jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0) + k_start
+            at = jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1) + (q_start + offset)
+            unseen = jnp.where(key <= at, 0.0, _NEG_INF)  # s + it: _NEG_INF
+            s = s + jnp.concatenate([unseen] * heads, axis=1)
+        return s
+
+    def weighted(p):
+        v = v_ref[0, 0]
+        return jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [dv, rows]
+
+    def finite(m):
+        # a row that has seen nothing keeps _NEG_INF: exp2(s - 0) is 0
+        # there (possible when T > S), not exp2(0)
+        return jnp.where(m > _NEG_INF * 0.5, m, 0.0)
+
+    def max_first():
+        s = scores(masked=True)
+        m_prev = m_scr[...]  # [1, rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp2(s - finite(m_new))
+        corr = jnp.exp2(m_prev - m_new)
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + weighted(p)
+        m_scr[...] = m_new
+
+    def lagged(masked: bool):
+        # (behind an unmasked tile every row has seen a key)
+        real = finite if masked else (lambda m: m)
+        s = scores(masked)
+        m_prev = m_scr[...]
+        ref = real(m_prev)
+        p = jnp.exp2(s - ref)
+        m_tile = jnp.max(s, axis=0, keepdims=True)
+        l_tile, acc_tile = jnp.sum(p, axis=0, keepdims=True), weighted(p)
+        # (a row's first keys stand _NEG_INF above nothing: max first)
+        fits = jnp.max(m_tile - m_prev) <= _LAG_MAX
+        redo_scr[0] = jnp.where(fits, 0, 1)
+
+        @pl.when(fits)
+        def _join():
+            m_new = jnp.maximum(m_prev, m_tile)
+            moved = jnp.exp2(ref - real(m_new))
+            l_scr[...] = (l_scr[...] + l_tile) * moved
+            acc_scr[...] = (acc_scr[...] + acc_tile) * moved
+            m_scr[...] = m_new
+
+    redo_scr[0] = 0
+    behind = jnp.logical_and(ik > 0, ik <= last)
+    straddle = _straddles(q_start, k_start, block_k, offset)
+    pl.when(jnp.logical_and(behind, straddle))(lambda: lagged(masked=True))
+    pl.when(jnp.logical_and(behind, jnp.logical_not(straddle)))(
+        lambda: lagged(masked=False))
+    pl.when(jnp.logical_or(ik == 0, redo_scr[0] == 1))(max_first)
+
+    @pl.when(ik == last)
+    def _finalize():
+        l, acc = l_scr[...], acc_scr[...]
+        if sink_ref:  # the sink's term joins the sum; its value is nothing
+            sink2 = sink_ref[0][0] * _LOG2E  # [1, rows], log2 domain
+            m_all = jnp.maximum(m_scr[...], sink2)
+            shrink = jnp.exp2(m_scr[...] - m_all)
+            l = l * shrink + jnp.exp2(sink2 - m_all)
+            acc = acc * shrink
+        o = (acc / jnp.where(l == 0.0, 1.0, l)).T  # [rows, dv]
+        o_ref[0] = o.reshape(heads, block_q, -1).astype(o_ref.dtype)
+
+
+def _flash_fwd_at(q, k, v, offset, sink, *, heads, block_q, block_k,
                   interpret):
-    """The forward-only call (``_flash_fwd`` says when): ``offset`` [1]
-    int32 is scalar-prefetched; the last grid dimension is every k
-    block; the k / v index map stops at a q block's last live block, so
-    that a dead step asks for the block that is there already."""
+    """The forward-only call: grid (batch, q heads / ``heads``, q block,
+    k block), ``offset`` [1] int32 scalar-prefetched; the last grid
+    dimension is the k blocks that ANY row of the call sees (a bound
+    traced with the offset: the blocks behind the call's diagonal are no
+    steps at all), and the k / v index map stops at a q block's own last
+    live block, so that a dead step asks for the block that is there
+    already. ONE result: nothing reads an lse."""
     b, hq, t, d = q.shape
     _, hkv, s, dv = v.shape
     group = hq // hkv
-    nk = cdiv(s, block_k)
+    if t % block_q or s % block_k or group % heads:
+        raise ValueError(
+            f"flash_fwd: T={t} / S={s} must be multiples of the blocks "
+            f"({block_q}, {block_k}) and a kv head's {group} query heads of "
+            f"a cell's {heads}; pad inputs or pass blocks.")
+    nk = s // block_k
+    walked = jnp.clip(jax.lax.div(offset[0] + t - 1, block_k) + 1, 1, nk)
+    rows = heads * block_q
 
     def q_idx(bi, hi, qi, ki, off):
         return (bi, hi, qi, 0)
 
     def kv_idx(bi, hi, qi, ki, off):
-        first, last = _k_blocks(qi * block_q, block_q, block_k, off[0], nk)
-        return (bi, hi // group, jnp.minimum(first + ki, last), 0)
+        last = _last_walked(qi * block_q, block_q, block_k, off[0], nk)
+        return (bi, hi * heads // group, jnp.minimum(ki, last), 0)
 
     operands, in_specs = [q, k, v], [
-        pl.BlockSpec((1, 1, block_q, d), q_idx),
+        pl.BlockSpec((1, heads, block_q, d), q_idx),
         pl.BlockSpec((1, 1, block_k, d), kv_idx),
         pl.BlockSpec((1, 1, block_k, dv), kv_idx)]
     if sink is not None:
-        operands.append(jnp.broadcast_to(
-            sink.astype(jnp.float32)[:, None, None], (hq, 1, 128)))
+        operands.append(jnp.repeat(
+            sink.astype(jnp.float32), block_q).reshape(hq // heads, 1, -1))
         in_specs.append(pl.BlockSpec(
-            (1, 1, 128), lambda bi, hi, qi, ki, off: (hi, 0, 0)))
-    out, lse4 = pl.pallas_call(
+            (1, 1, rows), lambda bi, hi, qi, ki, off: (hi, 0, 0)))
+    return pl.pallas_call(
         functools.partial(
-            _fwd_kernel, causal=True, scale=scale, block_q=block_q,
-            block_k=block_k, offset=None, nk_all=nk,
-            sink=sink is not None),
+            _fwd_kernel_t, scale=d ** -0.5, block_q=block_q,
+            block_k=block_k, nk=nk, sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, hq, cdiv(t, block_q), nk),
+            grid=(b, hq // heads, t // block_q, walked),
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, 1, block_q, dv), q_idx),
-                       pl.BlockSpec((1, 1, block_q, 8), q_idx)],
+            out_specs=pl.BlockSpec((1, heads, block_q, dv), q_idx),
             scratch_shapes=[
-                pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
-                pltpu.VMEM((block_q, 128), jnp.float32),  # running denom l
-                pltpu.VMEM((block_q, dv), jnp.float32),  # accumulator
+                pltpu.VMEM((1, rows), jnp.float32),  # running max m
+                pltpu.VMEM((1, rows), jnp.float32),  # running denom l
+                pltpu.VMEM((dv, rows), jnp.float32),  # accumulator
+                pltpu.SMEM((1,), jnp.int32),  # a tile to do again
             ]),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, t, 8), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -444,7 +577,6 @@ def _flash_fwd_at(q, k, v, offset, sink, *, scale, block_q, block_k,
         interpret=interpret,
         name="flash_fwd",
     )(offset, *operands)
-    return out, lse4[..., 0]
 
 
 def _window_blocks(window: int, group: int):
@@ -621,29 +753,26 @@ def flash_fwd(q, k, v, *, offset=None, window: int | None = None, sink=None,
     only: a band, a sink, an offset and k wider than v have no backward
     kernel, and a differentiated call raises (training such a block:
     ROADMAP A3)."""
+    group = q.shape[1] // k.shape[1]
     if window is not None:
-        wq, wk = _window_blocks(window, q.shape[1] // k.shape[1])
-        block_q = min(block_q or wq, q.shape[2])
-        block_k = min(block_k or wk, k.shape[2])
-    elif block_q is None or block_k is None:
-        from ray_tpu._private import config as _cfg
-
-        block_q = block_q or _cfg.get("flash_block_q")
-        block_k = block_k or _cfg.get("flash_block_k")
-
+        wq, wk = _window_blocks(window, group)
+    else:
+        heads, wq, wk = _fwd_blocks(group, q.shape[2], k.shape[2])
+    block_q = min(block_q or wq, q.shape[2])
+    block_k = min(block_k or wk, k.shape[2])
     if offset is None:
         offset = k.shape[2] - q.shape[2]
 
     @jax.custom_vjp
     def forward(q, k, v, offset, sink):
+        offset = jnp.asarray(offset, jnp.int32).reshape(1)
         if window is not None:
             return _flash_fwd_window(
-                q, k, v, jnp.asarray(offset, jnp.int32).reshape(1), sink,
-                window=window, block_q=block_q, block_k=block_k,
-                interpret=interpret)
-        return _flash_fwd(q, k, v, causal=True, block_q=block_q,
-                          block_k=block_k, interpret=interpret,
-                          offset=offset, sink=sink)[0]
+                q, k, v, offset, sink, window=window, block_q=block_q,
+                block_k=block_k, interpret=interpret)
+        return _flash_fwd_at(
+            q, k, v, offset, sink, heads=heads, block_q=block_q,
+            block_k=block_k, interpret=interpret)
 
     def refuse(*_):
         raise NotImplementedError(

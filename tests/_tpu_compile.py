@@ -87,6 +87,32 @@ def _mosaic_text(encoded: str) -> str:
             .operation.get_asm(enable_debug_info=False)
 
 
+def _flash_fwd_bodies(text: str) -> list:
+    """[(results, Mosaic module without locations)] of a compiled
+    program's ``flash_fwd`` calls (the full causal kernel's, not the
+    band's): how many arrays a call returns (two from the differentiable
+    ``flash_attention``, which makes an lse; one from the forward-only
+    ``flash_fwd``) and its kernel's text."""
+    out = []
+    for ln in text.splitlines():
+        if KERNEL in ln and re.match(r"\s*%flash_fwd(\.\d+)? = ", ln):
+            made = ln.split(" custom-call(")[0].split(" = ", 1)[1]
+            out.append((len(re.findall(r"\b[a-z]+\d+\[", made)),
+                        _mosaic_text(MOSAIC_BODY.search(ln).group(1))))
+    return out
+
+
+def _flash_fwd_calls(text: str) -> list:
+    """:func:`_flash_fwd_bodies` with sixteen hex digits of a text for
+    the text. A digest pinned in a test holds that kernel's text at that
+    program's shapes to the tree it was read on (PR 67: the
+    differentiable call's to PR 66's)."""
+    import hashlib
+
+    return [(n, hashlib.sha256(body.encode()).hexdigest()[:16])
+            for n, body in _flash_fwd_bodies(text)]
+
+
 def _made_by(lines) -> dict:
     """{instruction: the operation that makes it} of a program's lines."""
     return {m.group(1): m.group(2) for m in (

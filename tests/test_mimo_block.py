@@ -461,6 +461,142 @@ def test_banded_flash_fwd_at_the_published_head_widths_with_a_sink(
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+# (heads, kv heads, d_qk, T, S, offset, a sink, blocks or the rule's own)
+_FULL_CASES = [
+    # the published heads, 16 a cell over 128-row q blocks: the first
+    # rows, an offset of whole blocks, one that is not
+    (64, 4, 192, 256, 512, 0, False, 128),
+    (64, 4, 192, 256, 512, 0, True, 128),
+    (64, 4, 192, 256, 512, 256, True, 128),
+    (64, 4, 192, 256, 512, 256, False, 128),
+    (64, 4, 192, 256, 512, 200, True, 128),
+    (64, 4, 192, 256, 512, 200, False, 128),
+    # rows before every key (T > S): half of them, and a whole q block
+    (64, 4, 192, 256, 256, -128, True, 128),
+    (64, 4, 192, 256, 256, -128, False, 128),
+    (64, 4, 192, 256, 384, -200, False, 64),
+    # the rule's own blocks: two 1,024-key tiles behind 1,792 rows
+    (64, 4, 192, 256, 2048, 1792, True, None),
+    # 64 / 8 heads of 128 over a whole bucket (offset None: S - T = 0)
+    (64, 8, 128, 512, 512, None, False, None),
+    (64, 8, 128, 512, 512, None, False, 128),
+    # a group of one: a head's rows alone in a cell
+    (4, 4, 192, 256, 512, 256, True, 128), (4, 4, 192, 512, 512, 0, False, 64),
+]
+
+
+@pytest.mark.parametrize("hq, hkv, d, t, s, offset, sunk, block",
+                         _FULL_CASES)
+def test_full_flash_fwd_forms_its_scores_transposed_and_lags_a_tile(
+        hq, hkv, d, t, s, offset, sunk, block):
+    """The forward-only full causal body (``_fwd_kernel_t``) in the
+    interpreter against ``attend_rows``' XLA body, the offset traced: a
+    kv head's 16, 8 and 1 query heads in a cell, keys 192 and 128 wide,
+    with a sink and without. A row that lies before every key gives
+    zeros (the XLA body's plain softmax gives the mean of v there; with
+    a sink it gives zeros too)."""
+    ks = jax.random.split(jax.random.PRNGKey(t + s), 4)
+    q = jax.random.normal(ks[0], (1, hq, t, d))
+    k = jax.random.normal(ks[1], (1, hkv, s, d))
+    v = jax.random.normal(ks[2], (1, hkv, s, 128))
+    sink = 2.0 + jax.random.normal(ks[3], (hq,)) if sunk else None
+    at = s - t if offset is None else offset
+    want = attend_rows(q, k, v, offset=at, sink=sink, use_flash=False)
+    traced = {} if offset is None else {"offset": jnp.int32(offset)}
+    got = jax.jit(lambda q, k, v, kw: flash_fwd(
+        q, k, v, sink=sink, block_q=block, block_k=block, interpret=True,
+        **kw))(q, k, v, traced)
+    assert got.shape == (1, hq, t, 128)
+    blind = max(0, -at)  # rows that lie before every key
+    np.testing.assert_allclose(got[:, :, blind:], want[:, :, blind:],
+                               atol=2e-5)
+    assert not np.asarray(got[:, :, :blind]).any()
+    if sunk:
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("sunk", [True, False])
+def test_a_tile_far_above_the_max_so_far_is_done_again_max_first(sunk):
+    """Keys behind the first tile forty times as large: their scores
+    stand over ``_LAG_MAX`` above the rows' max so far, the lagged
+    update would overflow, and the tile is done again with its max
+    first; keys of ordinary size behind those join lagged again."""
+    from ray_tpu.ops import flash_attention as fa
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (1, 8, 128, 192))
+    k = jax.random.normal(ks[1], (1, 2, 512, 192))
+    k = k.at[:, :, 128:256].multiply(40.0)
+    v = jax.random.normal(ks[2], (1, 2, 512, 128))
+    sink = 2.0 + jax.random.normal(ks[3], (8,)) if sunk else None
+    scores = jnp.einsum("bhtd,bhsd->bhts", q[:, ::4], k) * 192 ** -0.5
+    gap = scores[..., 128:256].max(-1) - scores[..., :128].max(-1)
+    assert float(gap.min()) * 1.4427 > fa._LAG_MAX  # (every row's tile)
+    want = attend_rows(q, k, v, offset=384, sink=sink, use_flash=False)
+    got = jax.jit(lambda q, k, v, o: flash_fwd(
+        q, k, v, offset=o, sink=sink, block_q=64, block_k=128,
+        interpret=True))(q, k, v, jnp.int32(384))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_full_calls_cell_and_blocks_come_from_its_shapes():
+    """``_fwd_blocks``: 2,048 rows a cell, as many of a kv head's query
+    heads as divide its group over a q block of at least 128 rows, and
+    1,024 keys; a short segment or few keys are taken whole."""
+    from ray_tpu.ops.flash_attention import _fwd_blocks
+
+    assert _fwd_blocks(16, 2048, 32768) == (16, 128, 1024)  # MiMo-V2.5
+    assert _fwd_blocks(8, 32768, 32768) == (8, 256, 1024)  # 64 / 8 heads
+    assert _fwd_blocks(4, 2048, 8192) == (4, 512, 1024)
+    assert _fwd_blocks(2, 2048, 8192) == (2, 1024, 1024)
+    assert _fwd_blocks(1, 4096, 4096) == (1, 2048, 1024)
+    assert _fwd_blocks(32, 2048, 4096) == (16, 128, 1024)
+    assert _fwd_blocks(12, 2048, 4096) == (12, 128, 1024)
+    assert _fwd_blocks(3, 2048, 4096) == (3, 512, 1024)
+    assert _fwd_blocks(16, 64, 256) == (16, 64, 256)
+
+
+def _pallas_calls(jaxpr) -> list:
+    """(name, results) of every ``pallas_call`` in a jaxpr, the ones
+    inside its equations' own jaxprs among them."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"], len(eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _pallas_calls(sub)
+    return out
+
+
+def test_the_differentiable_call_keeps_its_two_results_and_the_forward_one():
+    """``flash_attention`` makes the lse whether it is differentiated or
+    not (nothing there can see which): its ``flash_fwd`` has two
+    results, under ``jax.grad`` and without, one k tile or several. The
+    forward-only call has one, and differentiating it raises by name."""
+    q = jnp.ones((1, 128, 2, 64))
+
+    def loss(q, **kw):
+        return flash_attention(q, q, q, interpret=True, **kw).sum()
+
+    for kw in ({}, {"block_q": 64, "block_k": 64}):
+        plain = _pallas_calls(jax.make_jaxpr(
+            lambda q: loss(q, **kw))(q).jaxpr)
+        assert plain == [("flash_fwd", 2)], plain
+        under_grad = _pallas_calls(jax.make_jaxpr(jax.grad(
+            lambda q: loss(q, **kw)))(q).jaxpr)
+        assert ("flash_fwd", 2) in under_grad and not [
+            c for c in under_grad if c[0] == "flash_fwd" and c[1] != 2]
+        assert any(name.startswith("flash_bwd") for name, _ in under_grad)
+    qh = q.transpose(0, 2, 1, 3)
+    alone = _pallas_calls(jax.make_jaxpr(lambda q, o: flash_fwd(
+        q, q, q, offset=o, interpret=True))(qh, jnp.int32(0)).jaxpr)
+    assert alone == [("flash_fwd", 1)], alone
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: flash_fwd(
+            q, qh, qh, offset=jnp.int32(0), interpret=True).sum())(qh)
+
+
 def test_the_bands_blocks_come_from_the_window_and_the_group():
     """``_window_blocks``: a k block is the window in whole lanes, a q
     block as long where the group's rows fill a cell (512 of them) and a

@@ -15,7 +15,7 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, _lower_prefill, _mem, _on, topo)
+    KERNEL, MIB, _flash_fwd_calls, _lower_prefill, _mem, _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -129,6 +129,8 @@ def test_granite_4096_row_prefill_is_two_segments_and_one_flash_kernel(
     calls = _kernel_calls(text)
     assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
         == cfg.full_layers == 1
+    # the differentiable call's kernel and its lse: PR 66's text
+    assert _flash_fwd_calls(text) == [(2, "b442ea24a3c6a115")]
     assert sum("moe_gmm" in c for c in calls) == 3 * cfg.moe_layers
     assert len(calls) == 1 + 3 * cfg.moe_layers
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))

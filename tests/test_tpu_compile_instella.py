@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, _mem, MIB, _on, topo)
+    KERNEL, _flash_fwd_calls, _mem, MIB, _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -111,6 +111,8 @@ def test_instella_16384_row_prefill_forms_no_scores(topo, monkeypatch):
     text = compiled.as_text()
     assert text.count(KERNEL) == cfg.n_layers + 3 * cfg.moe_layers
     assert text.count("flash_fwd") >= cfg.n_layers and "moe_gmm" in text
+    # the differentiable call's kernel and its lse: PR 66's text
+    assert _flash_fwd_calls(text) == [(2, "dc0d5840e825ba79")] * cfg.n_layers
     assert "16384,16384" not in text
     assert "bf16[1,16384,16,128]" in text or "bf16[1,16,16384,128]" in text
     # (no [P, vocabulary] logits either: the head sees the last real row)

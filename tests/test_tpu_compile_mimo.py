@@ -16,8 +16,9 @@ from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
     KERNEL, MIB, MOSAIC_BODY, _dead_branch_hands_on_and_makes_zeros,
-    _expert_branches, _loops_add_nothing_unscoped, _lower_prefill, _made_by,
-    _mem, _mosaic_text, _on, _segment_branches, once, topo)
+    _expert_branches, _flash_fwd_bodies, _loops_add_nothing_unscoped,
+    _lower_prefill, _made_by, _mem, _mosaic_text, _on, _segment_branches,
+    once, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -118,7 +119,10 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
     """The cell's cold prefill call at its widest bucket, one prompt of
     32,768 rows in 16 segments of 2,048, every layer one scan:
     ``flash_fwd`` in the two full layers (d_qk 192, d_v 128, a segment's
-    rows against the rows so far) and ``flash_fwd_window`` in the five
+    rows against the rows so far; since PR 67 the forward-only body, ONE
+    result and no lse, a cell ``[1, 16, 128, 192]`` of q over 1,024 keys
+    with the scores ``[1024, 2048]``, keys by the cell's rows) and
+    ``flash_fwd_window`` in the five
     window layers (a cell a kv head's eight query heads, ``[1, 8, 128,
     192]`` of q over three ``[1, 1, 128, ...]`` blocks of k and of v, the
     band's at most; the scores ``[128, 1024]``, keys by the group's rows,
@@ -143,6 +147,13 @@ def test_mimo_32768_row_prefill_is_segments_and_two_flash_kernels(
         == cfg.window_layers == 5
     assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
         == cfg.full_layers == 2
+    # the forward-only body: ONE result each (no lse), one text; a cell
+    # a kv head's 16 query heads over 128 rows, the scores keys by rows
+    full = _flash_fwd_bodies(text)
+    assert [n for n, _ in full] == [1, 1] and len(set(full)) == 1
+    assert f"memref<1x16x128x{cfg.head_dim}xbf16" in full[0][1]
+    assert "vector<1024x2048xf32>" in full[0][1]
+    assert "vector<2048x1024xf32>" not in full[0][1]
     lines = text.splitlines()
     made_by = _made_by(lines)
     group = cfg.n_heads // cfg.kv_heads(True)

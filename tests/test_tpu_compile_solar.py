@@ -14,8 +14,8 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _dead_branch_hands_on_and_makes_zeros, _kda_chunk_calls,
-    _kda_inputs_calls, KERNEL,
+    _dead_branch_hands_on_and_makes_zeros, _flash_fwd_calls,
+    _kda_chunk_calls, _kda_inputs_calls, KERNEL,
     lowered_counting_kda_bodies, _lower_prefill, _mem, MIB, _on, once,
     _loops_add_nothing_unscoped, _segment_branches, topo)
 from ray_tpu.models import decode_engine as de
@@ -156,6 +156,8 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     assert solar.SLOTS.prefill_segments(cfg, 32768) == 16
     compiled, text, _ = _widest_prefill(cfg, params, state, vec)
     assert text.count("flash_fwd") >= cfg.full_layers and "moe_gmm" in text
+    # the differentiable call's kernel and its lse: PR 66's text
+    assert _flash_fwd_calls(text) == [(2, "a636016a79e4c55c")]
     # the chunkwise delta rule: one kernel call a KDA layer, in the scan
     calls = _kda_chunk_calls(text)
     assert len(calls) == cfg.kda_layers == 3

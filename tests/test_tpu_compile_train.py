@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import Mesh
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, _mem, once, topo, _train_cfg, _train_step)
+    KERNEL, _flash_fwd_calls, _mem, once, topo, _train_cfg, _train_step)
 from ray_tpu.models import llama
 from ray_tpu.parallel import AXES, MeshConfig, use_mesh
 
@@ -35,6 +35,8 @@ def test_sharded_train_step_compiles_with_kernel(topo):
     text = _cell_step(topo)
     assert KERNEL in text
     assert "all-reduce" in text and "all-gather" in text
+    # the forward kernel with its lse, once a layer: PR 66's text
+    assert set(_flash_fwd_calls(text)) == {(2, "c45ea4c48f353cb0")}
 
 
 def _in_flight(text: str, shape: str) -> dict:
