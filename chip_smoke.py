@@ -107,6 +107,10 @@ class Plan:
     # and one Mamba-2 layer of models/granite.py at the published widths
     # (128 heads of 64 x 128): a prompt in one segment and in two
     ssm_lens: tuple = (200, 4096)
+    # and one gated short convolution and one GQA layer of 64-wide heads
+    # of models/lfm2.py at the published widths: a prompt in one segment
+    # and in two, the step's attention two heads a lane tile
+    sconv_lens: tuple = (200, 4096)
     # and the four kernels of ops/dsa.py at the published widths of
     # models/dots.py (64 index heads of 128, 128 heads of 128 + 64 / 128
     # over latent rows of 640) and of models/glm_dsa.py (32 index heads,
@@ -121,7 +125,7 @@ class Plan:
                     steps=3, flash_shape=(2, 128, 4, 2, 64),
                     hybrid_widths="tiny", hybrid_lens=(9, 21), ring_steps=20,
                     kda_steps=5, latent_lens=(9, 21), segment_lens=(9, 21),
-                    ssm_lens=(9, 21), dsa_rows=128,
+                    ssm_lens=(9, 21), sconv_lens=(9, 21), dsa_rows=128,
                     kda_chunk_rows=32)
         return Plan(**{**base, **kw})
 
@@ -1573,6 +1577,120 @@ def ssm_check(widths: str, lens: list, seed: int,
             "device": accelerator.device_report()}
 
 
+def sconv_check(widths: str, lens: list, seed: int,
+                interpret: bool = False) -> dict:
+    """Runs in a child that holds the chip: the eleventh block's two
+    kinds of layer (``models/lfm2.py``) at the published widths, each
+    form against the other, in the compute type, for prompts of ``lens``
+    tokens: a gated short convolution's prefill in row segments, the
+    two rows of ``u`` carried (2,048 rows a segment), against its own
+    stepping; a GQA layer of 64-wide heads with its head norms and
+    rotation through ``ops.attention.attend_rows`` in segments at an
+    offset (``flash_fwd`` on a TPU) against its decode step over the
+    slot's rows (on a TPU the ``decode_attn`` kernel, two heads a lane
+    tile; with ``interpret`` the kernel in the Pallas interpreter); and
+    that kernel against the XLA body over six slots at ragged lengths,
+    one inactive. -> relative errors by length, the kernel's against
+    the body, whether the layer's step compiles to a program with the
+    kernel in it, and each length's segments."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import accelerator
+    from ray_tpu.models import lfm2, moe
+    from ray_tpu.ops import decode_attention as da
+    from ray_tpu.ops.attention import attend_rows
+
+    accelerator.claim_device()
+    kw = dict(n_layers=2, layer_types=("conv", "full_attention"),
+              vocab_size=1024, n_dense_layers=2, dense_d_ff=256)
+    cfg = lfm2.Lfm2Config.tiny(**kw, dtype="bfloat16") \
+        if widths == "tiny" else lfm2.Lfm2Config(**kw)
+    conv, gqa = lfm2.init_params(cfg, jax.random.PRNGKey(seed))["layers"]
+    attend = functools.partial(da.decode_attention, **(
+        {"interpret": True} if interpret else {}))
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+
+    @jax.jit
+    def both(x):
+        t = x.shape[1]
+        seg = moe.segment_rows(t)
+        xs = jnp.moveaxis(x, 1, 0)[:, :, None]  # [T, 1, 1, D]
+        on, lens_ = jnp.ones((1,), bool), jnp.array([t])
+        rows0 = jnp.zeros((1, cfg.conv_kernel - 1, cfg.d_model), x.dtype)
+        rows, y = moe.in_segments(
+            lambda rows, s: lfm2.conv_segment(
+                cfg, conv, s[1], rows, s[0], lens_)[::-1], rows0, x, seg)
+        rows_step, y_step = jax.lax.scan(
+            lambda r, x_t: lfm2.conv_step(cfg, conv, x_t, r, on)[::-1],
+            rows0, xs)
+
+        def segment(carry, s):
+            k_all, v_all = carry
+            start, x_seg = s
+            at = start + jnp.arange(x_seg.shape[1], dtype=jnp.int32)
+            q, k, v = lfm2._qkv(cfg, gqa, x_seg, at[None])
+            k_all, v_all = (jax.lax.dynamic_update_slice(
+                a, r.transpose(0, 2, 1, 3), (0, 0, start, 0))
+                for a, r in ((k_all, k), (v_all, v)))
+            o = attend_rows(q.transpose(0, 2, 1, 3), k_all, v_all,
+                            offset=start)
+            return (k_all, v_all), o.transpose(0, 2, 1, 3).reshape(
+                1, x_seg.shape[1], -1)
+
+        heads = jnp.zeros((1, hkv, t, hd), x.dtype)
+        (k_all, _), y_gqa = moe.in_segments(segment, (heads, heads), x, seg)
+
+        def one(cache, xp):
+            x_t, pos = xp
+            q, k, v = lfm2._qkv(cfg, gqa, x_t, pos[None])
+            kc, vc = (c.at[0, 0, pos[0]].set(r.reshape(-1))
+                      for c, r in zip(cache, (k, v)))
+            o = attend(q, kc, vc, 0, (pos + 1).astype(jnp.int32))
+            return (kc, vc), o.reshape(1, 1, -1)
+
+        stack = jnp.zeros((1, 1, t, cfg.kv_width), x.dtype)
+        (kc, _), y_gqa_step = jax.lax.scan(
+            one, (stack, stack), (xs, jnp.arange(t)[:, None]))
+        return {"conv_out": (y, jnp.moveaxis(y_step[:, :, 0], 0, 1)),
+                "conv_rows": (rows, rows_step),
+                "gqa_out": (y_gqa, jnp.moveaxis(y_gqa_step[:, :, 0], 0, 1)),
+                "gqa_rows": (k_all.transpose(0, 2, 1, 3).reshape(1, t, -1),
+                             kc[0])}
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    errs = {}
+    for t in lens:
+        x = jax.random.normal(jax.random.PRNGKey(seed + t),
+                              (1, t, cfg.d_model), cfg.compute_dtype)
+        errs[str(t)] = {k: rel(a, b) for k, (a, b) in both(x).items()}
+    slots, rows = 6, 96
+    lengths = jnp.array([rows, 1, 0, 17, 64, 33], jnp.int32)
+    key = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    q = jax.random.normal(key[0], (slots, 1, cfg.n_heads, hd),
+                          cfg.compute_dtype)
+    k, v = (jax.random.normal(kk, (2, slots, rows, cfg.kv_width),
+                              cfg.compute_dtype) for kk in key[1:])
+    o_kernel = jax.jit(attend)(q, k, v, 1, lengths)
+    o_body = da.decode_attention(q, k, v, 1, lengths, use_kernel=False)
+    text = jax.jit(da.decode_attention).lower(
+        q, k, v, 1, lengths).compile().as_text()
+    return {"rel_err": errs, "kernel": rel(o_kernel, o_body),
+            "inactive_zero": not bool(jnp.any(o_kernel[2])),
+            "heads_a_tile": da._heads_a_tile(hd, hd, hkv),
+            "in_program": any(
+                KERNEL in line and "decode_attn" in line.split(" = ")[0]
+                for line in text.splitlines()),
+            "segments": {str(t): lfm2.SLOTS.prefill_segments(cfg, t)
+                         for t in lens},
+            "device": accelerator.device_report()}
+
+
 DSA_KERNEL_TOLERANCE = 2e-2  # bf16 outputs of either form, relative
 
 
@@ -1800,6 +1918,19 @@ def hybrid_phase(plan: Plan) -> dict:
           "its stepping, the ssd_step kernel from the XLA body, an "
           "inactive slot's state moved, or the layer's step holds no "
           "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
+    sconv = chip_child(plan, "sconv_check", {
+        "widths": plan.hybrid_widths, "lens": list(plan.sconv_lens),
+        "seed": plan.seed, "interpret": not plan.on_tpu})
+    check_device(plan, sconv["device"], 1, "sconv child")
+    check(max(v for by_len in sconv["rel_err"].values()
+              for v in by_len.values()) <= HYBRID_TOLERANCE
+          and sconv["kernel"] <= HYBRID_TOLERANCE and sconv["inactive_zero"]
+          and sconv["in_program"] == plan.on_tpu,
+          "a gated short convolution in carried segments parts from its "
+          "stepping, a GQA layer of narrow heads through the flash kernel "
+          "from its decode step, the decode_attn kernel at two heads a "
+          "tile from the XLA body, or the step holds no kernel on the "
+          "chip", got=sconv, tolerance=HYBRID_TOLERANCE)
     sparse = {}
     for block in ("dots", "glm_dsa", "glm_next"):
         sparse[block] = found = chip_child(plan, "dsa_check", {
@@ -1820,6 +1951,9 @@ def hybrid_phase(plan: Plan) -> dict:
                 for block, found in sparse.items()},
             "ssm": {k: ssm[k] for k in ("rel_err", "kernel", "segments",
                                         "inactive_kept", "in_program")},
+            "sconv": {k: sconv[k] for k in (
+                "rel_err", "kernel", "segments", "heads_a_tile",
+                "in_program")},
             "segment": {k: segment[k] for k in ("rel_err", "segments")},
             "ring": {k: ring[k] for k in ("rel_err", "wraps", "window")},
             "latent": latent["rel_err"],

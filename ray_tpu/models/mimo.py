@@ -500,18 +500,7 @@ class _Slots(Slots):
         layer's P rows onto the first P rows of the slot. What the
         slot's last stream wrote behind them stays: no reader looks past
         a slot's own length (``_prefill_batch_into_slots``' docstring)."""
-        def put(all_, layers):  # [L, slots, S, C] <- L x [F, P <= S, C]
-            # a layer and a stream at a time, each an update in place
-            # (as one scatter over ``slots`` XLA pads and selects whole
-            # float32 copies of the update)
-            for layer, new in enumerate(layers):
-                for f in range(new.shape[0]):
-                    all_ = jax.lax.dynamic_update_slice(
-                        all_, new[None, f:f + 1].astype(all_.dtype),
-                        (layer, slots[f], 0, 0))
-            return all_
-
-        return {**{name: put(state[name], new)
+        return {**{name: Slots.put_rows(state[name], slots, new)
                    for name, new in streams.items()},
                 "pos": state["pos"].at[slots].set(full_lens)}
 

@@ -1,6 +1,7 @@
 """What the blocks that unroll their layers share, one copy
 (``models/ling.py``, ``exaone.py``, ``instella.py``, ``solar.py``,
-``mimo.py``, ``granite.py``): the
+``mimo.py``, ``granite.py``, ``dots.py``, ``glm_dsa.py``,
+``glm_next.py``, ``lfm2.py``): the
 expert layer of a device that holds a part of its experts with the
 router in front of it (DeepSeek-V3's routing), a layer's MLP around it,
 the head, the loss, how the leaves these read are drawn, and how a
@@ -10,6 +11,9 @@ Sigmoid scores in float32 (softmax scores for a block whose
 configuration says ``router_softmax``), a bias added for selection only,
 ``topk_group`` of ``n_group`` groups kept by the sum of their two best,
 the ``top_k`` best of those chosen, their unbiased scores renormalised
+(by their sum, or by ``their sum + norm_topk_eps`` where a configuration
+states that field: LFM2's published ``1e-6``, :func:`route`; without it
+the older blocks' programs are their parent's text)
 and scaled; a shared expert beside them (``shared_d_ff`` 0: none, no
 leaves and no work). ``held`` = (first, count) tells
 the layer which experts live here: it routes over all of them and
@@ -189,7 +193,14 @@ def route(cfg, scores, bias):
     a tie), and the weights are the chosen experts' unbiased scores,
     renormalised to 1 and scaled. With one group (``n_group`` 1,
     ``topk_group`` 1) it is kept and nothing is masked: the plain
-    ``top_k`` of the biased scores."""
+    ``top_k`` of the biased scores.
+
+    Where the configuration states ``norm_topk_eps`` (``models/lfm2.py``:
+    transformers' ``Lfm2MoeSparseMoeBlock`` divides the chosen scores by
+    ``their sum + 1e-6``) the sum gains it before it divides; a
+    configuration without the field (every older block's) runs the old
+    line and its programs lower to their parent's text
+    (``tests/test_moe.py -k lowered_text``)."""
     e, ng = cfg.n_experts, cfg.n_group
     biased = scores + bias
     grouped = biased.reshape(*biased.shape[:-1], ng, e // ng)
@@ -199,8 +210,11 @@ def route(cfg, scores, bias):
     masked = jnp.where(keep[..., None], grouped, -jnp.inf)
     _, ids = jax.lax.top_k(masked.reshape(biased.shape), cfg.top_k)
     chosen = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
-        * cfg.routed_scaling_factor
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    eps = getattr(cfg, "norm_topk_eps", None)
+    if eps is not None:
+        total = total + eps
+    weights = chosen / total * cfg.routed_scaling_factor
     return weights, ids
 
 

@@ -41,7 +41,7 @@ VOCABULARY = (
 )
 # the kinds of attention, a second level under ``attn``
 ATTN_KINDS = ("attn_window", "attn_full", "attn_latent", "attn_linear",
-              "attn_ssm", "attn_index", "attn_sparse")
+              "attn_ssm", "attn_index", "attn_sparse", "attn_conv")
 LOOP, UNSCOPED = "loop", "unscoped"
 FILE = "program_parts.json"
 

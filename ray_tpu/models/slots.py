@@ -117,6 +117,20 @@ class Slots:
                 "the rows of k and v a prefix carries")
 
     @staticmethod
+    def put_rows(all_, slots, layers):
+        """A prefill's rows into their slots' first rows: ``all_`` [L,
+        slots, S, C] <- ``layers``, L x [F, P <= S, C], stream f into
+        slot ``slots[f]``. A layer and a stream at a time, each an
+        update in place (as one scatter over ``slots`` XLA pads and
+        selects whole float32 copies of the update)."""
+        for layer, new in enumerate(layers):
+            for f in range(new.shape[0]):
+                all_ = jax.lax.dynamic_update_slice(
+                    all_, new[None, f:f + 1].astype(all_.dtype),
+                    (layer, slots[f], 0, 0))
+        return all_
+
+    @staticmethod
     def first_token(logits_of, params, h, true_lens, seeds, temps, top_ps):
         """How every prefill ends. h [F, P, D]: the stream before the
         final norm, ``true_lens`` [F] of its rows real;

@@ -249,9 +249,9 @@ def _kernel(slot_ids, block_ids, lengths, layer_ref, q_ref, k_ref, v_ref,
 
 
 def _decode_attn(q, k, v, layer, lengths, plan, *, bs: int,
-                 interpret: bool, sink=None):
+                 interpret: bool, sink=None, scale: float | None = None):
     """The kernel's call: q [B, T, Hq, hd] -> [B, T, Hq, dv]; ``plan``
-    is ``visits(lengths, s, bs)``."""
+    is ``visits(lengths, s, bs)``; ``scale``: where not ``hd ** -0.5``."""
     b, t, hq, hd = q.shape
     s = k.shape[2]
     hkv = k.shape[3] // hd
@@ -292,7 +292,7 @@ def _decode_attn(q, k, v, layer, lengths, plan, *, bs: int,
 
     kernel = functools.partial(
         _kernel, s=s, bs=bs, t=t, group=group, hkv=hkv, hd=hd, dv=dv,
-        scale=hd ** -0.5)
+        scale=scale or hd ** -0.5)
     q_block = pl.BlockSpec((None, hkv, _ROW_PAD, width), q_index)
     operands, in_specs = [qh, k, v], [
         q_block, pl.BlockSpec((None, None, bs, hkv * hd), kv_index),
@@ -350,7 +350,7 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
 
     ``use_kernel=None`` takes the backend's: the Pallas kernel on a TPU
     (where a value head fills whole lanes and a key head's remainder
-    divides a tile), ``attend_ragged`` elsewhere, which
+    divides a tile, or heads share a tile), ``attend_ragged`` elsewhere, which
     reads every row of the layer and leaves an inactive slot's output to
     its frozen position. ``interpret=True`` runs the kernel in the
     Pallas interpreter (never inferred). ``rows`` overrides the block's
@@ -363,13 +363,13 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
     s = k.shape[2]
     hkv = k.shape[3] // hd
     if use_kernel is None:
-        rem = hd % 128  # (off a test, a head is a tile or more)
+        rem, per = hd % 128, _heads_a_tile(hd, v.shape[3] // hkv, hkv)
         use_kernel = interpret or (
             jax.default_backend() == "tpu"
-            and (v.shape[3] // hkv) % 128 == 0
-            and (not rem or (hd > 128 and 128 % rem == 0
-                             and hkv * rem % 128 == 0))
-            and t * (hq // hkv) <= _ROW_PAD)
+            and (per > 1 or (v.shape[3] // hkv) % 128 == 0)
+            and (per > 1 or not rem or (hd > 128 and 128 % rem == 0
+                                        and hkv * rem % 128 == 0))
+            and t * (hq // hkv) * per <= _ROW_PAD)
     if not use_kernel:
         qpos = (lengths - t)[:, None] + jnp.arange(t, dtype=jnp.int32)
         o = attend_ragged(q, unpack_heads(k[layer], hkv),
@@ -377,9 +377,9 @@ def decode_attention(q, k, v, layer, lengths, *, plan=None,
         return jnp.where((lengths > 0)[:, None, None, None], o, 0)
     bs = rows or block_rows(s)
     lengths = lengths.astype(jnp.int32)
-    return _decode_attn(q, k, v, layer, lengths,
+    return _kernel_call(hd, k, v)(q, k, v, layer, lengths,
                         plan or visits(lengths, s, bs), bs=bs,
-                        interpret=interpret, sink=sink)
+                        interpret=interpret, sink=sink)  # (columns: below)
 
 
 # --------------------------------------------------------------------------
@@ -544,3 +544,58 @@ def decode_attention_latent(q, rows, layer, lengths, *, dv: int,
     return _decode_attn_latent(
         q, rows, layer, lengths, plan or visits(lengths, s, bs), dv=dv,
         scale=scale, bs=bs, interpret=interpret)
+
+
+# --------------------------------------------------------------------------
+# A head that is a part of a lane tile (PR 68)
+# --------------------------------------------------------------------------
+#
+# (At the END of the file, and what stands above it changed only inside
+# its lines: the Mosaic modules of both kernels carry the lines AND
+# columns they were traced from, their callers' among them, so a line
+# added above one, or the kernel's call in ``decode_attention`` indented
+# anew, moves the cache key of every program that holds it.)
+#
+# A 64-wide head is half a tile and a row of 8 such kv heads four tiles
+# with two heads each (``128 // hd`` heads a tile). ``_kernel``'s body is
+# not told: the call hands it each TILE as one kv head of 128 whose query
+# rows are those of every head in it, a head's rows laid into a zeroed
+# tile at the head's own lanes (the form a 192-wide head's remainder has
+# in the module docstring, for the whole head). A row's scores against
+# the tile are then its own head's, since the other heads' lanes meet
+# zeros; its row of ``p v`` is the tile's 128 lanes, of which the head's
+# own ``hd`` are taken once the kernel is done. So the keys and values
+# pass the matrix unit once a tile, not once a head, and a step's 4 query
+# rows a head are 8 of a tile's 16. Keys and values are as wide as each
+# other there, and ``T x group x heads a tile`` is at most ``_ROW_PAD``.
+
+
+def _heads_a_tile(hd: int, dv: int, hkv: int) -> int:
+    """How many kv heads share a lane tile where the call lays them so:
+    ``128 // hd`` for a head that divides a tile, keys and values alike,
+    in a row of whole tiles; else 1."""
+    per = 128 // hd if hd < 128 and 128 % hd == 0 and dv == hd else 1
+    return per if hkv % per == 0 else 1
+
+
+def _kernel_call(hd: int, k, v):
+    """:func:`_decode_attn` for a head of ``hd`` over rows ``k``, ``v``,
+    or the call that hands it a tile of heads as one."""
+    hkv = k.shape[3] // hd
+    per = _heads_a_tile(hd, v.shape[3] // hkv, hkv)
+    return _decode_attn if per == 1 else functools.partial(_tiles_of_heads,
+                                                           per)
+
+
+def _tiles_of_heads(per: int, q, k, v, layer, lengths, plan, **kw):
+    """:func:`_decode_attn` with each tile of ``per`` kv heads as ONE
+    head of 128 (the comment above)."""
+    b, t, hq, hd = q.shape
+    hkv = k.shape[3] // hd
+    lanes = jnp.eye(per, dtype=q.dtype)[:, None, :, None]
+    tiles = q.reshape(b, t, hkv // per, per, hq // hkv, 1, hd) * lanes
+    out = _decode_attn(tiles.reshape(b, t, hq, 128), k, v, layer, lengths,
+                       plan, scale=hd ** -0.5, **kw)
+    out = out.reshape(b, t, hkv // per, per, hq // hkv, per, hd)
+    return jnp.stack([out[:, :, :, i, :, i] for i in range(per)],
+                     axis=3).reshape(b, t, hq, hd)

@@ -250,6 +250,31 @@ def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
     assert found["inactive_kept"] and found["in_program"] is False
 
 
+def test_sconv_check_rehearses_on_the_cpu(monkeypatch):
+    """What ``hybrid_phase`` asks of the block of gated short
+    convolutions (``models/lfm2.py``) on the chip, at tiny widths with
+    segments of 8 rows and the decode kernel in the Pallas interpreter:
+    prompts of 16 and 32 tokens run their conv layer in two and four
+    segments, the two rows of ``u`` carried, and agree with stepping;
+    the GQA layer's segments at an offset agree with its decode steps;
+    the kernel, eight heads of 16 a lane tile here, agrees with the XLA
+    body and leaves an inactive slot's output zeros."""
+    from ray_tpu.models import moe
+
+    monkeypatch.setattr(moe, "SEGMENT_ROWS", 8)
+    found = chip_smoke.sconv_check("tiny", [16, 32], TINY.seed,
+                                   interpret=True)
+    assert found["device"].items() >= CPU.items()
+    assert found["segments"] == {"16": 2, "32": 4}
+    for errs in found["rel_err"].values():
+        assert set(errs) == {"conv_out", "conv_rows", "gqa_out", "gqa_rows"}
+        assert errs["conv_rows"] == 0.0 and errs["gqa_rows"] == 0.0
+        assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
+    assert 0 < found["kernel"] <= chip_smoke.HYBRID_TOLERANCE
+    assert found["inactive_zero"] and found["in_program"] is False
+    assert found["heads_a_tile"] == 1  # (two kv heads of 16: no tile)
+
+
 @pytest.mark.parametrize("block, chosen", [("dots", 16), ("glm_dsa", 8),
                                            ("glm_next", 2)])
 def test_dsa_check_rehearses_on_the_cpu(block, chosen):
@@ -357,9 +382,10 @@ def test_latent_check_rehearses_on_the_cpu():
 
 
 @pytest.mark.parametrize("call, more", [("segment_check", {}),
-                                        ("ssm_check", {"interpret": True})])
+                                        ("ssm_check", {"interpret": True}),
+                                        ("sconv_check", {"interpret": True})])
 def test_the_phases_own_prompts_run_in_one_segment(call, more):
-    """``segment_check`` and ``ssm_check`` with the prompts the tiny
+    """``segment_check``, ``ssm_check`` and ``sconv_check`` with the prompts the tiny
     plan gives ``hybrid_phase`` (9 and 21 tokens under segments of
     2,048 rows: one segment each; the tests above cut segments of 8)."""
     found = _answer(call, **_hybrid_args(
@@ -386,7 +412,7 @@ def _answered_in_process(monkeypatch, spoil=None):
 
 
 def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
-    """The phase asks its ten children (the seven checks above and
+    """The phase asks its eleven children (the eight checks above and
     ``dsa_check`` a sparse block) with the plan's own arguments and
     makes one line of their facts. Each child is answered in this
     process by the check itself, once a module (``_answer``: the tests
@@ -397,7 +423,8 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
     assert rc == 0
     assert calls == [
         "hybrid_check", "ring_check", "kda_kernel_check", "kda_chunk_check",
-        "latent_check", "segment_check", "ssm_check"] + 3 * ["dsa_check"]
+        "latent_check", "segment_check", "ssm_check", "sconv_check"] \
+        + 3 * ["dsa_check"]
     _check_lines(lines, ["hybrid"])
     facts = lines[0]["checked"]
     assert set(facts["rel_err"]) == {"9", "21"}
@@ -435,6 +462,9 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
                for v in errs.values()) <= chip_smoke.HYBRID_TOLERANCE
     assert facts["ssm"]["segments"] == {"9": 1, "21": 1}
     assert facts["ssm"]["inactive_kept"] and not facts["ssm"]["in_program"]
+    assert facts["sconv"]["segments"] == {"9": 1, "21": 1}
+    assert facts["sconv"]["kernel"] <= chip_smoke.HYBRID_TOLERANCE
+    assert not facts["sconv"]["in_program"]
     assert facts["ring"]["wraps"] == 2 and facts["ring"]["window"] == 8
     assert set(facts["dsa"]) == {"dots", "glm_dsa", "glm_next"}
     assert all(found["sets_equal"] and found["rows"] == 128

@@ -200,3 +200,128 @@ def test_decode_chunk_through_the_kernel_returns_the_greedy_tokens(
     for i in (0, 1):
         np.testing.assert_array_equal(got[i], greedy_tokens(
             params, prompts[i, :lens[i]], cfg, 1 + 2 * chunk))
+
+
+# ---- a head that is a part of a lane tile (ISSUE 68) ----
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hd, hkv, group, t", [
+    (64, 8, 4, 1),   # LFM2's step: two heads a tile, 8 of a tile's 16 rows
+    (64, 2, 2, 3),   # a verify's three rows a head
+    (32, 4, 1, 2),   # four heads a tile
+    (16, 8, 2, 1),   # eight heads a tile: all 16 rows
+])
+def test_a_head_that_divides_a_tile_is_a_part_of_a_head_of_128(
+        hd, hkv, group, t, dtype):
+    """``decode_attention`` where ``128 // hd`` kv heads share a lane
+    tile: the kernel in the interpreter, handed each tile as ONE head of
+    128 (a head's query rows in a zeroed tile at the head's own lanes),
+    agrees with ``attend_ragged`` at ragged lengths with an inactive
+    slot, with a sink and without; junk beyond a slot's length does not
+    reach the output; and the lay-out is what the docstring says: four
+    tiles of 16 rows for LFM2's 8 x 64."""
+    dt = jnp.dtype(dtype)
+    layers, b = 2, 3
+    assert da._heads_a_tile(hd, hd, hkv) == 128 // hd
+    lengths = jnp.array([BLOCK + t, ROWS, 0], jnp.int32)
+    kq, kk, kv, ks, kj = jax.random.split(jax.random.PRNGKey(hd + t), 5)
+    q = jax.random.normal(kq, (b, t, hkv * group, hd), jnp.float32).astype(dt)
+    k, v = (jax.random.normal(key, (layers, b, ROWS, hkv * hd),
+                              jnp.float32).astype(dt) for key in (kk, kv))
+    sink = jax.random.normal(ks, (hkv * group,), jnp.float32)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    for s in (None, sink):
+        attend = functools.partial(da.decode_attention, interpret=True,
+                                   rows=BLOCK, sink=s)
+        got = attend(q, k, v, jnp.int32(1), lengths)
+        assert got.shape == q.shape and got.dtype == dt
+        assert not np.asarray(got[2], np.float32).any()
+        want = da.decode_attention(q, k, v, jnp.int32(1), lengths,
+                                   use_kernel=False, sink=s)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=tol, rtol=tol)
+        beyond = jnp.arange(ROWS)[None, :, None] >= lengths[:, None, None]
+        junk = (1e3 * jax.random.normal(kj, k.shape[1:], jnp.float32)
+                ).astype(dt)
+        kj_, vj_ = (jnp.where(beyond[None], junk[None], a) for a in (k, v))
+        np.testing.assert_array_equal(
+            attend(q, kj_, vj_, jnp.int32(1), lengths), got)
+    if (hd, hkv, group, t) == (64, 8, 4, 1):
+        text = jax.jit(functools.partial(
+            da.decode_attention, interpret=True, rows=BLOCK)).lower(
+                q, k, v, jnp.int32(1), lengths).as_text()
+        assert f"tensor<{b}x4x16x128x" in text  # q: four tiles of 16 rows
+
+
+def test_the_dispatch_takes_the_kernel_for_a_part_of_a_tile(monkeypatch):
+    """On a TPU backend the choice is the shapes': whole tiles (128,
+    256), whole tiles and a packed remainder (192 beside values of 128)
+    and a part of a tile (64, 32: LFM2's heads) take the kernel; a head
+    that divides no tile (48, 96), values of another width than such
+    keys, a row that is not whole tiles of heads and more query rows
+    than a tile's 16 take the XLA body."""
+    asked = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(da, "_decode_attn", lambda q, *a, **kw: asked.append(
+        q.shape) or jnp.zeros(q.shape[:3] + (a[1].shape[3] // (
+            a[0].shape[3] // q.shape[3]),), q.dtype))
+
+    def kernel(hd, hkv, group, t=1, dv=None):
+        dv = dv or hd
+        asked.clear()
+        da.decode_attention(
+            jnp.zeros((2, t, hkv * group, hd)), jnp.zeros((1, 2, 32, hkv * hd)),
+            jnp.zeros((1, 2, 32, hkv * dv)), 0, jnp.array([5, 0]))
+        return bool(asked)
+
+    assert kernel(128, 8, 2) and kernel(256, 2, 8) and kernel(192, 4, 4, dv=128)
+    assert kernel(64, 8, 4) and kernel(32, 4, 1) and kernel(64, 8, 4, t=2)
+    assert not kernel(48, 8, 2) and not kernel(96, 4, 2)
+    assert not kernel(64, 8, 4, dv=128)  # keys and values alike there
+    assert not kernel(64, 3, 4)  # three heads are a tile and a half
+    assert not kernel(64, 8, 4, t=3)  # 3 x 4 x 2 rows > 16
+    assert not kernel(128, 8, 4, t=5)
+
+
+def test_the_tile_of_heads_code_stands_behind_both_kernels():
+    """Both kernels' Mosaic modules carry the lines and columns they
+    were traced from, their callers' among them, so what PR 68 added
+    stands at the END of the module and the kernel's call in
+    ``decode_attention`` starts where it started: a line added above
+    either moves the compile-cache key of every older cell's programs.
+    And the other head's lanes of a tile's ``p v`` are NOT the head's:
+    the control the cell's limits are read against."""
+    import inspect
+
+    src = inspect.getsource(da)
+    behind = src.index("def decode_attention_latent(")
+    for name in ("_heads_a_tile", "_kernel_call", "_tiles_of_heads"):
+        assert src.index(f"def {name}(") > behind, name
+    assert "\n    return _kernel_call(hd, k, v)(q, k, v, layer, lengths,\n" \
+        "                        plan or visits(lengths, s, bs), bs=bs,\n" in src
+    q = jax.random.normal(jax.random.PRNGKey(0), (2, 1, 8, 64), jnp.float32)
+    k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 2, 32, 4 * 64),
+                              jnp.float32) for i in (1, 2))
+    lengths = jnp.array([20, 7], jnp.int32)
+    want = da.decode_attention(q, k, v, 0, lengths, use_kernel=False)
+    got = da.decode_attention(q, k, v, 0, lengths, interpret=True, rows=16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+    def other_lanes(per, q, k, v, layer, lengths, plan, **kw):
+        b, t, hq, hd = q.shape
+        hkv = k.shape[3] // hd
+        lanes = jnp.eye(per, dtype=q.dtype)[:, None, :, None]
+        tiles = q.reshape(b, t, hkv // per, per, hq // hkv, 1, hd) * lanes
+        out = da._decode_attn(tiles.reshape(b, t, hq, 128), k, v, layer,
+                              lengths, plan, scale=hd ** -0.5, **kw)
+        out = out.reshape(b, t, hkv // per, per, hq // hkv, per, hd)
+        return jnp.stack([out[:, :, :, i, :, per - 1 - i]
+                          for i in range(per)], axis=3).reshape(b, t, hq, hd)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(da, "_tiles_of_heads", other_lanes)
+        wrong = da.decode_attention(q, k, v, 0, lengths, interpret=True,
+                                    rows=16)
+    assert float(jnp.abs(wrong - want).max()) > 0.5

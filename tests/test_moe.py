@@ -203,3 +203,44 @@ def test_router_picks_exactly_top_k_and_renormalises_only_if_asked():
     raw = llama.moe_gates(_cfg(n_experts=8, top_k=3, norm_topk_prob=False),
                           router, x)
     np.testing.assert_allclose(np.asarray(raw.sum(-1)), 3 / 8, rtol=1e-6)
+
+
+def _route_before_pr_68(cfg, scores, bias):
+    """``models/moe.py: route`` as PR 67 had it, letter for letter."""
+    e, ng = cfg.n_experts, cfg.n_group
+    biased = scores + bias
+    grouped = biased.reshape(*biased.shape[:-1], ng, e // ng)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.any(jax.nn.one_hot(kept, ng, dtype=jnp.bool_), axis=-2)
+    masked = jnp.where(keep[..., None], grouped, -jnp.inf)
+    _, ids = jax.lax.top_k(masked.reshape(biased.shape), cfg.top_k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) \
+        * cfg.routed_scaling_factor
+    return weights, ids
+
+
+def test_a_configuration_without_the_1e_6_keeps_its_lowered_text(
+        monkeypatch):
+    """``moe.route`` adds ``norm_topk_eps`` to the chosen scores' sum
+    where a configuration states it (LFM2's published ``1e-6``). An
+    older configuration (K-EXAONE's block at its tiny size: sigmoid
+    scores, a selection bias, a shared expert) states none: its expert
+    layer lowers to the text the parent's ``route`` gives, and LFM2's
+    own text has one more addition."""
+    from ray_tpu.models import exaone, lfm2, moe
+
+    def lowered(cfg, init):
+        p = next(layer["mlp"] for layer in init(
+            cfg, jax.random.PRNGKey(0))["layers"] if "router" in layer["mlp"])
+        x = jnp.zeros((2, 8, cfg.d_model), cfg.compute_dtype)
+        return jax.jit(lambda p, x: moe.moe(cfg, p, x)).lower(p, x).as_text()
+
+    older = exaone.ExaoneConfig.tiny()
+    assert not hasattr(older, "norm_topk_eps")
+    now = lowered(older, exaone.init_params)
+    new = lowered(lfm2.Lfm2Config.tiny(), lfm2.init_params)
+    monkeypatch.setattr(moe, "route", _route_before_pr_68)
+    assert lowered(older, exaone.init_params) == now
+    assert lowered(lfm2.Lfm2Config.tiny(), lfm2.init_params) != new

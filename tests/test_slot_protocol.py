@@ -22,7 +22,7 @@ import pytest
 
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import (dots, exaone, glm_dsa, glm_next, granite,
-                            instella, ling, llama, mimo, moe, solar)
+                            instella, lfm2, ling, llama, mimo, moe, solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -40,6 +40,7 @@ BLOCKS = {
     "dots": (dots, dots.DotsConfig.tiny),
     "glm_dsa": (glm_dsa, glm_dsa.GlmDsaConfig.tiny),
     "glm_next": (glm_next, glm_next.GlmNextConfig.tiny),
+    "lfm2": (lfm2, lfm2.Lfm2Config.tiny),
 }
 # the blocks whose step counts what its indexers chose besides the
 # routing: their slots state ``step_counters`` of their own
@@ -215,7 +216,7 @@ def test_the_unrolled_blocks_share_one_copy(name):
 
 
 _BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo",
-                "granite", "dots", "glm", "glmdsa"}
+                "granite", "dots", "glm", "glmdsa", "lfm2"}
 
 
 def _names_a_block(word: str) -> bool:
@@ -315,7 +316,7 @@ def test_no_block_imports_the_engine():
 
     for name in ("llama", "llama_slots", "slots", "moe", "ling", "exaone",
                  "instella", "solar", "mimo", "granite", "dots",
-                 "glm_dsa", "glm_next"):
+                 "glm_dsa", "glm_next", "lfm2"):
         with open(f"{ray_tpu.__path__[0]}/models/{name}.py") as f:
             tree = ast.parse(f.read())
         for node in ast.walk(tree):

@@ -42,7 +42,8 @@ SERVING_CELLS = (("internlm2-1.8b", "doc-saturated"),
                  ("granite-4.0-h-small-ep4-1chip", "sessions-saturated"),
                  ("dots3-note-prev-ep8-1chip", "longreason-saturated-24"),
                  ("glm-5.2-ep16-1chip", "longreason-saturated-16"),
-                 ("glm-5.3-flash-ep8-1chip", "longreason-saturated-24"))
+                 ("glm-5.3-flash-ep8-1chip", "longreason-saturated-24"),
+                 ("lfm2-8b-a1b-ep2-1chip", "rag-saturated"))
 TRAIN_CELLS = (("mistral-7b-v0.3-1chip", "pretrain-4k"),
                ("internlm2-1.8b", "pretrain-4k-fsdp2tp2"))
 

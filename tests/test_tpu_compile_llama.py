@@ -301,6 +301,10 @@ def _toy_block(block: str):
 
         return granite.GraniteConfig.tiny(max_seq_len=64), \
             _ALWAYS | _MOE | {"moe_shared"}
+    if block == "lfm2":
+        from ray_tpu.models import lfm2
+
+        return lfm2.Lfm2Config.tiny(max_seq_len=64), _ALWAYS | _MOE | {"mlp"}
     from ray_tpu.models import exaone
 
     return exaone.ExaoneConfig.tiny(max_seq_len=64), \
@@ -309,7 +313,7 @@ def _toy_block(block: str):
 
 @pytest.mark.parametrize("program", ["decode_chunk", "prefill"])
 @pytest.mark.parametrize("block", ["llama", "olmoe", "ling", "exaone",
-                                   "instella", "solar", "granite"])
+                                   "instella", "solar", "granite", "lfm2"])
 def test_every_part_of_a_block_is_in_its_programs_map(topo, block, program):
     """The map a capture is read through, from the text the TPU compiler
     leaves: every part the block should have is there, the second level
@@ -348,7 +352,8 @@ def test_every_part_of_a_block_is_in_its_programs_map(topo, block, program):
              "exaone": {"attn/attn_window", "attn/attn_full"},
              "instella": {"attn/attn_latent"},
              "solar": {"attn/attn_linear", "attn/attn_full"},
-             "granite": {"attn/attn_ssm", "attn/attn_full"}}.get(
+             "granite": {"attn/attn_ssm", "attn/attn_full"},
+             "lfm2": {"attn/attn_conv", "attn/attn_full"}}.get(
         block, set())
     assert kinds <= found, sorted(kinds - found)
     if program == "decode_chunk":
