@@ -1362,7 +1362,7 @@ def ring_check(widths: str, steps: int, seed: int,
 def latent_check(widths: str, lens: list, seed: int) -> dict:
     """Runs in a child that holds the chip: one gated MLA layer of
     ``models/instella.py`` (YaRN's rotary on interleaved pairs), its
-    unabsorbed prefill (``ops.attention``: the flash kernel on a TPU)
+    unabsorbed prefill (the serving call: forward-only flash on a TPU)
     against its absorbed decode step over the slot's rows (on a TPU the
     ``decode_attn_latent`` kernel, lengths ``pos + 1``), position by
     position, in the compute type, for prompts of ``lens`` tokens.
@@ -1421,8 +1421,9 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
     tokens: a KDA layer's prefill in row segments, ``S`` and the
     convolution rows carried (2,048 rows a segment), against its own
     stepping (on a TPU the ``kda_step`` kernel at 64 heads, beta to 2);
-    a gated GQA layer without positions through ``ops.attention`` (the
-    flash kernel on a TPU) against its decode step over the slot's rows
+    a gated GQA layer without positions through ``attend_bucket`` (the
+    serving prefill's forward-only flash call on a TPU) against its
+    decode step over the slot's rows
     (``decode_attn``, lengths ``pos + 1``). -> relative errors by
     length, and how many segments each length ran in."""
     import jax
@@ -1431,7 +1432,7 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
     from ray_tpu._private import accelerator
     from ray_tpu.models import moe, solar
     from ray_tpu.ops import decode_attention as da
-    from ray_tpu.ops.attention import attention
+    from ray_tpu.ops.attention import attend_bucket
 
     accelerator.claim_device()
     kw = dict(n_layers=2, vocab_size=1024, n_experts=8, top_k=2)
@@ -1454,7 +1455,7 @@ def segment_check(widths: str, lens: list, seed: int) -> dict:
             lambda s, x_t: solar.kda_step(cfg, kda, x_t, s, on)[::-1],
             empty, xs)
         q, k, v = solar._qkv(cfg, gqa, x)
-        y_gqa = solar._gqa_out(cfg, gqa, x, attention(q, k, v, causal=True))
+        y_gqa = solar._gqa_out(cfg, gqa, x, attend_bucket(q, k, v))
 
         def one(cache, xp):
             x_t, pos = xp

@@ -80,7 +80,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attend_bucket, attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ssd_chunk import ssd_chunked as _ssd_chunk
 from ray_tpu.ops import ssd_step as _ss
@@ -424,7 +424,7 @@ def logits(cfg: GraniteConfig, params, h):
 
 
 def prefill(params, tokens, true_lens, cfg: GraniteConfig,
-            loads: bool = False, live=None):
+            loads: bool = False, live=None, differentiable: bool = False):
     """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
     state, the tokenwise parts in segments of ``moe.segment_rows`` rows
     (module docstring) -> (h [B, T, D] before the final norm, the
@@ -435,9 +435,13 @@ def prefill(params, tokens, true_lens, cfg: GraniteConfig,
     layer's calls that had and that took its compact branch [2]: none
     here, a quarter of the experts is held), else None). ``live`` as
     ``solar.prefill`` takes it: the serving call's ``max(true_lens)``
-    leaves the dead segments out; ``None`` runs every segment."""
+    leaves the dead segments out; ``None`` runs every segment.
+    ``differentiable`` as there too: ``forward`` alone sets it and keeps
+    ``ops.attention.attention``; a serving prefill's attention layer
+    takes the forward-only ``attend_bucket``."""
     b, t = tokens.shape
     seg = moe.segment_rows(t, cfg.ssm_chunk)
+    attend = attention if differentiable else attend_bucket  # (causal)
     h = _embed(cfg, params, tokens)
     ssm, k_rows, v_rows, counts = [], [], [], []
 
@@ -459,7 +463,7 @@ def prefill(params, tokens, true_lens, cfg: GraniteConfig,
 
             _, (q, k, v) = moe.in_segments(project, (), h, seg, live)
             with jax.named_scope("attn/attn_full"):
-                o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
+                o = attend(q, k, v, use_flash=cfg.use_flash)
 
             def rest(count, xs, p=p):
                 start, (h_seg, o_seg) = xs
@@ -511,7 +515,8 @@ def forward(params, tokens, cfg: GraniteConfig):
     """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
     chunked scan and the prompt's attention."""
     b, t = tokens.shape
-    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
+    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg,
+                      differentiable=True)
     return logits(cfg, params, h)
 
 

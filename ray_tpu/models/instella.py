@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attend_bucket, attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import (apply_rotary_interleaved, rotary_embedding,
                               yarn_inv_freq, yarn_mscale)
@@ -253,11 +253,14 @@ def _mla_out(cfg: InstellaConfig, p, o, gate):
     return o @ p["wo"]
 
 
-def mla_prefill(cfg: InstellaConfig, p, x, rotation):
+def mla_prefill(cfg: InstellaConfig, p, x, rotation,
+                differentiable: bool = False):
     """An MLA layer over whole prompts from position 0, unabsorbed: k
     and v are made from the latent and attended as any attention's of
     ``n_heads`` x ``qk_head_dim`` (``ops.attention``: the flash kernel
-    on a TPU, the reference product elsewhere). -> ([B, T, D], the
+    on a TPU, the reference product elsewhere; the forward-only
+    ``attend_bucket`` for a serving prefill, ``attention`` and its lse
+    where ``differentiable``: ``forward``'s). -> ([B, T, D], the
     prompts' cache rows [B, T, row_width])."""
     b, t, _ = x.shape
     h, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -268,8 +271,8 @@ def mla_prefill(cfg: InstellaConfig, p, x, rotation):
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
             k_rope[:, :, None], (b, t, h, cfg.qk_rope_head_dim))], axis=-1)
     with jax.named_scope("attn/attn_latent"):
-        o = attention(q, k, kv[..., dn:], causal=True,
-                      use_flash=cfg.use_flash)
+        attend = attention if differentiable else attend_bucket  # (causal)
+        o = attend(q, k, kv[..., dn:], use_flash=cfg.use_flash)
     with jax.named_scope("cache"):
         rows = _cache_rows(cfg, latent, k_rope)
     return _mla_out(cfg, p, o, gate), rows
@@ -339,12 +342,14 @@ def _layer(cfg: InstellaConfig, i: int, p, s, behind, attend,
     return s, seen if cfg.farskip else s
 
 
-def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None):
+def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None,
+            differentiable: bool = False):
     """tokens [B, T] from position 0 (right-padding sees nothing real
     behind it: causal) -> (the stream [B, T, D] before the final norm,
     every layer's cache rows [L, B, T, row_width]). With ``aux`` every
     expert layer's ids are left in ``aux["expert_ids"]`` [L_moe, B, T,
-    top_k]."""
+    top_k]. ``differentiable``: :func:`mla_prefill`'s, which ``forward``
+    alone sets."""
     b, t = tokens.shape
     with jax.named_scope("embed"):
         s = params["embed"][tokens]
@@ -354,7 +359,8 @@ def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None):
     behind, rows, ids = s, [], []
     for i, p in enumerate(params["layers"]):
         def attend(x, p=p):
-            y, made = mla_prefill(cfg, p["attn"], x, rotation)
+            y, made = mla_prefill(cfg, p["attn"], x, rotation,
+                                  differentiable)
             rows.append(made)
             return y
 
@@ -371,7 +377,8 @@ def prefill(params, tokens, cfg: InstellaConfig, aux: dict | None = None):
 def forward(params, tokens, cfg: InstellaConfig):
     """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
     unabsorbed attention."""
-    return moe.logits(cfg, params, prefill(params, tokens, cfg)[0])
+    return moe.logits(cfg, params, prefill(
+        params, tokens, cfg, differentiable=True)[0])
 
 
 loss_fn = moe.loss_fn(forward)

@@ -79,7 +79,7 @@ import jax.numpy as jnp
 from ray_tpu.models import ling, moe
 from ray_tpu.models.slots import Slots
 from ray_tpu.ops import decode_attention as _da
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attend_bucket, attention
 from ray_tpu.ops.kda_chunk import kda_chunk as _kda_chunk
 from ray_tpu.ops.kda_inputs import kda_inputs as _kda_qkvg, kept_rows
 from ray_tpu.ops.kda_step import kda_step as _kda_step
@@ -359,7 +359,7 @@ def _gqa_out(cfg: SolarConfig, p, x, o):
 # --------------------------------------------------------------------------
 
 def prefill(params, tokens, true_lens, cfg: SolarConfig,
-            loads: bool = False, live=None):
+            loads: bool = False, live=None, differentiable: bool = False):
     """tokens [B, T] (right-padded, ``true_lens`` [B] real) from empty
     state, the tokenwise parts in segments of ``moe.segment_rows`` rows
     (module docstring) -> (h [B, T, D] before the final norm, the
@@ -378,9 +378,15 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
     which nothing reads (the GQA layer's one flash call over the bucket
     takes them as it took the padding's: causal, behind every real
     row). ``None`` runs every segment: the whole sequences of
-    ``forward``."""
+    ``forward``.
+
+    ``differentiable`` (``forward`` alone sets it: what ``loss_fn``
+    differentiates) keeps the GQA layer on ``ops.attention.attention``
+    and its lse; a serving prefill takes the forward-only call
+    (``attend_bucket``; that docstring says which is whose)."""
     b, t = tokens.shape
     seg = moe.segment_rows(t, cfg.kda_chunk)
+    attend = attention if differentiable else attend_bucket  # (causal)
     with jax.named_scope("embed"):
         h = params["embed"][tokens]
     kda, k_rows, v_rows, counts = [], [], [], []
@@ -403,7 +409,7 @@ def prefill(params, tokens, true_lens, cfg: SolarConfig,
 
             _, (q, k, v) = moe.in_segments(project, (), h, seg, live)
             with jax.named_scope("attn/attn_full"):
-                o = attention(q, k, v, causal=True, use_flash=cfg.use_flash)
+                o = attend(q, k, v, use_flash=cfg.use_flash)
 
             def rest(count, xs, p=p):
                 start, (h_seg, o_seg) = xs
@@ -455,7 +461,8 @@ def forward(params, tokens, cfg: SolarConfig):
     """tokens [B, T] -> float32 logits [B, T, V]: whole sequences, the
     chunkwise KDA and the prompt's attention."""
     b, t = tokens.shape
-    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg)
+    h, _, _ = prefill(params, tokens, jnp.full((b,), t, jnp.int32), cfg,
+                      differentiable=True)
     return moe.logits(cfg, params, h)
 
 

@@ -67,20 +67,32 @@ def softmax_with_sink(logits, sink):
 
 def attend_rows(q, k, v, *, offset, window: int | None = None, sink=None,
                 use_flash: bool | None = None):
-    """Rows of a prompt over the rows written so far, forward only, in
-    the flash kernel's layout: q [B, Hq, T, d_qk] at positions
-    ``offset`` .. ``offset + T - 1`` (traced or not) over k [B, Hkv, S,
-    d_qk], v [B, Hkv, S, d_v] of positions 0 .. S - 1 -> [B, Hq, T,
-    d_v]. Row i sees keys <= i + offset, in a band (``window``) the last
-    ``window`` of them; ``sink`` [Hq] float32: a logit a head that joins
-    its softmax's denominator and takes no value. ``use_flash`` as
-    :func:`attention`'s: the kernel (``flash_attention.flash_fwd``) on a
-    TPU, else the XLA body below, which forms the [T, S] scores whole."""
+    """Rows of a prompt over the rows written so far, FORWARD ONLY (what
+    a serving prefill takes: :func:`attention` says which call is whose),
+    in the flash kernel's layout: q [B, Hq, T, d_qk] at positions
+    ``offset`` .. ``offset + T - 1`` (traced or not; 0: a whole bucket
+    from its first row, :func:`attend_bucket`) over k [B, Hkv, S, d_qk],
+    v [B, Hkv, S, d_v] of positions 0 .. S - 1 -> [B, Hq, T, d_v]. Row i
+    sees keys <= i + offset, in a band (``window``) the last ``window``
+    of them; ``sink`` [Hq] float32: a logit a head that joins its
+    softmax's denominator and takes no value. ``use_flash`` as
+    :func:`attention`'s: the kernel (``flash_attention.flash_fwd``, whose
+    blocks come from the call's shapes; differentiated it raises) on a
+    TPU, else the XLA body below, which forms the [T, S] scores whole.
+    The kernel is NOT wrapped for a mesh (the serving engines run one
+    device): under an ambient mesh of more it raises."""
     if use_flash is None:
         use_flash = jax.default_backend() == "tpu"
     if use_flash:
         from ray_tpu.ops.flash_attention import flash_fwd
 
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.size > 1:
+            raise NotImplementedError(
+                f"attend_rows under a mesh of {mesh.size} devices: the "
+                "forward-only kernel runs in no shard_map and GSPMD cannot "
+                "partition a Mosaic call; serve on one device, or call "
+                "attention (wrapped, differentiable) or use_flash=False")
         return flash_fwd(q, k, v, offset=offset, window=window, sink=sink)
     b, hq, t, d = q.shape
     hkv, s = k.shape[1:3]
@@ -101,8 +113,39 @@ def attend_rows(q, k, v, *, offset, window: int | None = None, sink=None,
     return o.reshape(b, hq, t, v.shape[-1])
 
 
+@functools.partial(jax.jit, static_argnames=("use_flash",))
+def attend_bucket(q, k, v, *, use_flash: bool | None = None):
+    """:func:`attend_rows` over a whole bucket from position 0 in
+    :func:`attention`'s layout ([B, T, H, D] in and out, causal): a
+    SERVING prefill's call, never differentiated. The four transposes
+    are the ones ``flash_attention`` makes round its own kernel. Jitted
+    by itself, as ``ops.kda_chunk``'s call is: a block that calls it
+    once a layer traces and lowers the kernel once a program (traced a
+    layer, Instella-MoE's seven layers by four buckets cost a replica's
+    start 9 s: ``PERF.md`` §6 PR 69)."""
+    def heads_first(a):
+        return a.transpose(0, 2, 1, 3)
+
+    return heads_first(attend_rows(
+        heads_first(q), heads_first(k), heads_first(v), offset=0,
+        use_flash=use_flash))
+
+
 def attention(q, k, v, *, causal: bool = True, use_flash: bool | None = None):
-    """Dispatching attention entry point.
+    """Dispatching attention entry point, DIFFERENTIABLE.
+
+    Which call is whose. This one is a block's ``forward`` (what
+    ``loss_fn`` differentiates, both train steps, the tests' references)
+    and the Llama block's prefill: on a TPU ``flash_attention``, whose
+    forward kernel ALWAYS makes the lse its backward reads (nothing there
+    can see whether a call will be differentiated), at the blocks
+    ``flash_block_q`` / ``_k`` give. A SERVING prefill, which is never
+    differentiated, takes :func:`attend_rows` (a segment behind the rows
+    so far: MiMo-V2.5, LFM2, the sparse blocks' window layers) or
+    :func:`attend_bucket` (a whole bucket from position 0: Solar-Open2,
+    Granite, Instella-MoE): ``flash_fwd``, one result, a body of its own
+    (``ops/flash_attention.py``'s docstring), and a ``jax.grad`` through
+    it raises instead of dropping gradients.
 
     use_flash=None → the backend's kernel: flash on a TPU backend, the
     reference elsewhere (the flash kernel is TPU-only: pltpu memory

@@ -15,20 +15,22 @@ p = exp(s - lse) per tile and runs two kernels — dq with
 the k dimension innermost, dk/dv with the q dimension innermost — so memory
 stays O(block²) and nothing [T, S]-shaped ever materializes.
 
-Which call runs which body. :func:`flash_attention` (differentiable:
-every block's ``forward``, both train steps, the serving prefills that
-call ``ops.attention.attention``) runs :func:`_fwd_kernel`, or
-:func:`_fwd_kernel_1pass` where the keys are one tile, and ALWAYS makes
-the lse, since nothing there can see whether a call will be
-differentiated: two results, ``flash_fwd`` in a trace.
+Which call runs which body (whose call is which: the docstring of
+``ops.attention.attention``). :func:`flash_attention` (differentiable:
+every block's ``forward``, both train steps, the Llama block's prefill)
+runs :func:`_fwd_kernel`, or :func:`_fwd_kernel_1pass` where the keys
+are one tile, and ALWAYS makes the lse, since nothing there can see
+whether a call will be differentiated: two results, ``flash_fwd`` in a
+trace. A SERVING prefill never is, and takes the call below (PR 69).
 
 Forward only (:func:`flash_fwd`, PR 50): keys wider than values (``d_qk``
 192 beside ``d_v`` 128: the output and the accumulator take v's width),
 the query rows at a TRACED ``offset`` behind the keys' first row (a
-segment of a prompt against the rows written so far: the grid covers
-every k block and an index map that stops at the last live one keeps
-the dead steps off the HBM), and a learned sink (a logit a head that
-joins the denominator and takes no value). Without a band it is a body
+segment of a prompt against the rows written so far, or at 0 a whole
+bucket: the grid is bounded by the call's diagonal and an index map
+that stops at a q block's last live k block keeps its dead steps off
+the HBM), and a learned sink (a logit a head that joins the
+denominator and takes no value). Without a band it is a body
 of its own since PR 67 (:func:`_fwd_kernel_t`, ONE result, ``flash_fwd``
 in a trace as well): a cell is as many of a kv head's query heads as
 give 2,048 rows (:func:`_fwd_blocks`), the scores are formed TRANSPOSED

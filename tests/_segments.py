@@ -3,8 +3,9 @@
 into segments of 16 rows, one prompt through the engine's prefill
 program into a slot, greedy steps of that slot alone,
 the comparison of a short prompt in a long bucket (dead segments behind
-it, ``moe.in_segments``) with the reference, and what holds a sparse
-layer's k and v to the rows its segment can see (:func:`live_kv_case`).
+it, ``moe.in_segments``) with the reference, what holds a sparse
+layer's k and v to the rows its segment can see (:func:`live_kv_case`),
+and which kernels a traced program calls (:func:`pallas_calls`).
 
 A test that changes what a program reads when it is traced (a module's
 constant, a function of a block) calls :func:`forget_programs` and not
@@ -37,6 +38,18 @@ def forget_programs():
             for fn in list(vars(module).values()):
                 if callable(getattr(fn, "clear_cache", None)):
                     fn.clear_cache()
+
+
+def pallas_calls(jaxpr) -> list:
+    """(name, results) of every ``pallas_call`` in a jaxpr, the ones
+    inside its equations' own jaxprs among them."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"], len(eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += pallas_calls(sub)
+    return out
 
 
 @pytest.fixture

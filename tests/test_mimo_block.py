@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from _segments import (  # noqa: F401 (segments_of_16: a fixture)
-    segments_of_16, short_prompt_in_a_reused_slot)
+    pallas_calls, segments_of_16, short_prompt_in_a_reused_slot)
 from benchmark import manifest
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import mimo, moe
@@ -461,7 +461,9 @@ def test_banded_flash_fwd_at_the_published_head_widths_with_a_sink(
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-# (heads, kv heads, d_qk, T, S, offset, a sink, blocks or the rule's own)
+# (heads, kv heads, d_qk, T, S, offset, a sink, blocks or the rule's own),
+# and for a whole bucket what its rows hold: a "padded" tail (the last
+# rows of q, k and v zeros: a dead segment's) or a "spiked" k tile
 _FULL_CASES = [
     # the published heads, 16 a cell over 128-row q blocks: the first
     # rows, an offset of whole blocks, one that is not
@@ -482,26 +484,61 @@ _FULL_CASES = [
     (64, 8, 128, 512, 512, None, False, 128),
     # a group of one: a head's rows alone in a cell
     (4, 4, 192, 256, 512, 256, True, 128), (4, 4, 192, 512, 512, 0, False, 64),
+    # a SERVING prefill's whole bucket (PR 69: Solar-Open2's 64 / 8,
+    # Granite's 32 / 8, Instella-MoE's 16 / 16 heads of 128), T = S with
+    # the offset left out and given as a traced 0, several tiles a q
+    # block and the rule's own blocks, a right-padded tail
+    (64, 8, 128, 512, 512, 0, False, 128),
+    (64, 8, 128, 512, 512, None, False, 128, "padded"),
+    (32, 8, 128, 512, 512, None, False, 128),
+    (32, 8, 128, 512, 512, 0, False, None, "padded"),
+    (16, 16, 128, 512, 512, None, False, 128, "padded"),
+    (16, 16, 128, 512, 512, 0, False, 128),
+    (16, 16, 128, 512, 512, 0, False, None),
+    # a k tile forty times as large behind a q block's first: the lagged
+    # update's room is passed and the tile is done again max first
+    (64, 8, 128, 512, 512, 0, False, 128, "spiked"),
+    (16, 16, 128, 512, 512, None, False, 128, "spiked"),
 ]
 
 
-@pytest.mark.parametrize("hq, hkv, d, t, s, offset, sunk, block",
-                         _FULL_CASES)
+@pytest.mark.parametrize(
+    "hq, hkv, d, t, s, offset, sunk, block, holds",
+    [(*c, None)[:9] for c in _FULL_CASES])
 def test_full_flash_fwd_forms_its_scores_transposed_and_lags_a_tile(
-        hq, hkv, d, t, s, offset, sunk, block):
+        hq, hkv, d, t, s, offset, sunk, block, holds):
     """The forward-only full causal body (``_fwd_kernel_t``) in the
     interpreter against ``attend_rows``' XLA body, the offset traced: a
-    kv head's 16, 8 and 1 query heads in a cell, keys 192 and 128 wide,
-    with a sink and without. A row that lies before every key gives
-    zeros (the XLA body's plain softmax gives the mean of v there; with
-    a sink it gives zeros too)."""
+    kv head's 16, 8, 4 and 1 query heads in a cell, keys 192 and 128
+    wide, with a sink and without; a whole bucket from position 0 (the
+    three serving prefills that reach it through ``attend_bucket``)
+    against ``attention_reference`` too. A row that lies before every
+    key gives zeros (the XLA body's plain softmax gives the mean of v
+    there; with a sink it gives zeros too)."""
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops.attention import attention_reference
+
     ks = jax.random.split(jax.random.PRNGKey(t + s), 4)
     q = jax.random.normal(ks[0], (1, hq, t, d))
     k = jax.random.normal(ks[1], (1, hkv, s, d))
     v = jax.random.normal(ks[2], (1, hkv, s, 128))
+    if holds == "padded":
+        q, k, v = (a.at[:, :, 3 * t // 4 - 19:].set(0.0) for a in (q, k, v))
+    if holds == "spiked":  # (every row behind it: over _LAG_MAX above)
+        k = k.at[:, :, 128:256].multiply(40.0)
+        scores = jnp.einsum("bhtd,bhsd->bhts", q[:, ::hq // hkv, 256:],
+                            k) * d ** -0.5
+        gap = scores[..., 128:256].max(-1) - scores[..., :128].max(-1)
+        assert float(gap.min()) * 1.4427 > fa._LAG_MAX
     sink = 2.0 + jax.random.normal(ks[3], (hq,)) if sunk else None
     at = s - t if offset is None else offset
     want = attend_rows(q, k, v, offset=at, sink=sink, use_flash=False)
+    if holds is not None or (t == s and at == 0 and d == 128 and not sunk):
+        def rows_first(a):
+            return a.transpose(0, 2, 1, 3)
+
+        np.testing.assert_allclose(want, rows_first(attention_reference(
+            rows_first(q), rows_first(k), rows_first(v))), atol=2e-5)
     traced = {} if offset is None else {"offset": jnp.int32(offset)}
     got = jax.jit(lambda q, k, v, kw: flash_fwd(
         q, k, v, sink=sink, block_q=block, block_k=block, interpret=True,
@@ -511,6 +548,7 @@ def test_full_flash_fwd_forms_its_scores_transposed_and_lags_a_tile(
     np.testing.assert_allclose(got[:, :, blind:], want[:, :, blind:],
                                atol=2e-5)
     assert not np.asarray(got[:, :, :blind]).any()
+    assert np.isfinite(np.asarray(got)).all()
     if sunk:
         np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -557,18 +595,6 @@ def test_the_full_calls_cell_and_blocks_come_from_its_shapes():
     assert _fwd_blocks(16, 64, 256) == (16, 64, 256)
 
 
-def _pallas_calls(jaxpr) -> list:
-    """(name, results) of every ``pallas_call`` in a jaxpr, the ones
-    inside its equations' own jaxprs among them."""
-    out = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            out.append((eqn.params["name"], len(eqn.outvars)))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            out += _pallas_calls(sub)
-    return out
-
-
 def test_the_differentiable_call_keeps_its_two_results_and_the_forward_one():
     """``flash_attention`` makes the lse whether it is differentiated or
     not (nothing there can see which): its ``flash_fwd`` has two
@@ -580,16 +606,16 @@ def test_the_differentiable_call_keeps_its_two_results_and_the_forward_one():
         return flash_attention(q, q, q, interpret=True, **kw).sum()
 
     for kw in ({}, {"block_q": 64, "block_k": 64}):
-        plain = _pallas_calls(jax.make_jaxpr(
+        plain = pallas_calls(jax.make_jaxpr(
             lambda q: loss(q, **kw))(q).jaxpr)
         assert plain == [("flash_fwd", 2)], plain
-        under_grad = _pallas_calls(jax.make_jaxpr(jax.grad(
+        under_grad = pallas_calls(jax.make_jaxpr(jax.grad(
             lambda q: loss(q, **kw)))(q).jaxpr)
         assert ("flash_fwd", 2) in under_grad and not [
             c for c in under_grad if c[0] == "flash_fwd" and c[1] != 2]
         assert any(name.startswith("flash_bwd") for name, _ in under_grad)
     qh = q.transpose(0, 2, 1, 3)
-    alone = _pallas_calls(jax.make_jaxpr(lambda q, o: flash_fwd(
+    alone = pallas_calls(jax.make_jaxpr(lambda q, o: flash_fwd(
         q, q, q, offset=o, interpret=True))(qh, jnp.int32(0)).jaxpr)
     assert alone == [("flash_fwd", 1)], alone
     with pytest.raises(NotImplementedError, match="forward only"):

@@ -15,7 +15,8 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, MIB, _flash_fwd_calls, _lower_prefill, _mem, _on, topo)
+    KERNEL, MIB, _flash_fwd_bodies, _flash_fwd_calls, _lower_prefill, _mem,
+    _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -129,8 +130,14 @@ def test_granite_4096_row_prefill_is_two_segments_and_one_flash_kernel(
     calls = _kernel_calls(text)
     assert sum(bool(re.match(r"%flash_fwd(\.\d+)?$", c)) for c in calls) \
         == cfg.full_layers == 1
-    # the differentiable call's kernel and its lse: PR 66's text
-    assert _flash_fwd_calls(text) == [(2, "b442ea24a3c6a115")]
+    # the SERVING call (PR 69): the forward-only body, ONE result (no
+    # lse; the parent's program held the differentiable call's kernel,
+    # ``(2, "b442ea24a3c6a115")``, which ``forward`` alone keeps): a
+    # cell a kv head's 4 query heads over 512 rows
+    assert _flash_fwd_calls(text) == [(1, "8659e3f2c62352a6")]
+    body = _flash_fwd_bodies(text)[0][1]
+    assert "memref<1x4x512x128xbf16" in body
+    assert "vector<1024x2048xf32>" in body
     assert sum("moe_gmm" in c for c in calls) == 3 * cfg.moe_layers
     assert len(calls) == 1 + 3 * cfg.moe_layers
     arrays = {(dt, tuple(int(d) for d in dims.split(",")))

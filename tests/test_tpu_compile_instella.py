@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    KERNEL, _flash_fwd_calls, _mem, MIB, _on, topo)
+    KERNEL, _flash_fwd_bodies, _flash_fwd_calls, _mem, MIB, _on, topo)
 from ray_tpu.models import decode_engine as de
 
 
@@ -104,15 +104,30 @@ def test_instella_16384_row_prefill_forms_no_scores(topo, monkeypatch):
     fam, m, cfg, eng, params, state, vec = _instella_cell(topo, monkeypatch)
     prompt = jax.ShapeDtypeStruct((1, 16384), jnp.int32,
                                   sharding=vec(jnp.int32).sharding)
-    compiled = de._prefill_batch_into_slots.lower(
+    lowered = de._prefill_batch_into_slots.lower(
         params, prompt, vec(jnp.int32, 1), vec(jnp.int32, 1),
         vec(jnp.uint32, 1), vec(jnp.float32, 1), vec(jnp.float32, 1),
-        state, vec(jnp.int32), cfg=cfg).compile()
+        state, vec(jnp.int32), cfg=cfg)
+    # the serving call is jitted by itself: the kernel is traced and
+    # lowered ONCE a program, one private function the layers call (a
+    # replica's start pays the trace, not the compile: PERF.md §6 PR 69)
+    module = lowered.as_text()
+    assert len(re.findall(r"func\.func private @attend_bucket\w*\(",
+                          module)) == 1
+    assert len(re.findall(r"call @attend_bucket\w*\(", module)) \
+        == cfg.n_layers
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count(KERNEL) == cfg.n_layers + 3 * cfg.moe_layers
     assert text.count("flash_fwd") >= cfg.n_layers and "moe_gmm" in text
-    # the differentiable call's kernel and its lse: PR 66's text
-    assert _flash_fwd_calls(text) == [(2, "dc0d5840e825ba79")] * cfg.n_layers
+    # the SERVING call (PR 69): the forward-only body, ONE result a
+    # layer (no lse; the parent's program held the differentiable
+    # call's kernel, ``(2, "dc0d5840e825ba79")``, which ``forward``
+    # alone keeps): a cell one head's 2,048 rows over 1,024 keys
+    assert _flash_fwd_calls(text) == [(1, "b8e30ac4c7b1fb13")] * cfg.n_layers
+    body = _flash_fwd_bodies(text)[0][1]
+    assert "memref<1x1x2048x128xbf16" in body
+    assert "vector<1024x2048xf32>" in body
     assert "16384,16384" not in text
     assert "bf16[1,16384,16,128]" in text or "bf16[1,16,16384,128]" in text
     # (no [P, vocabulary] logits either: the head sees the last real row)

@@ -14,7 +14,8 @@ import numpy as np
 from jax.sharding import SingleDeviceSharding
 
 from _tpu_compile import (  # noqa: F401 (topo: a fixture)
-    _dead_branch_hands_on_and_makes_zeros, _flash_fwd_calls,
+    _dead_branch_hands_on_and_makes_zeros, _flash_fwd_bodies,
+    _flash_fwd_calls,
     _kda_chunk_calls, _kda_inputs_calls, KERNEL,
     lowered_counting_kda_bodies, _lower_prefill, _mem, MIB, _on, once,
     _loops_add_nothing_unscoped, _segment_branches, topo)
@@ -156,8 +157,16 @@ def test_solar_32768_row_prefill_runs_its_tokenwise_work_in_segments(
     assert solar.SLOTS.prefill_segments(cfg, 32768) == 16
     compiled, text, _ = _widest_prefill(cfg, params, state, vec)
     assert text.count("flash_fwd") >= cfg.full_layers and "moe_gmm" in text
-    # the differentiable call's kernel and its lse: PR 66's text
-    assert _flash_fwd_calls(text) == [(2, "a636016a79e4c55c")]
+    # the SERVING call (PR 69): the forward-only body, ONE result (no
+    # lse; the parent's program held the differentiable call's kernel,
+    # ``(2, "a636016a79e4c55c")``, which ``forward`` alone keeps), its
+    # text PR 67's at this shape: a cell a kv head's 8 query heads over
+    # 256 rows, the scores 1,024 keys by the cell's 2,048 rows
+    assert _flash_fwd_calls(text) == [(1, "b6168d4c08bf4ecb")]
+    body = _flash_fwd_bodies(text)[0][1]
+    assert "memref<1x8x256x128xbf16" in body
+    assert "vector<1024x2048xf32>" in body
+    assert "vector<2048x1024xf32>" not in body
     # the chunkwise delta rule: one kernel call a KDA layer, in the scan
     calls = _kda_chunk_calls(text)
     assert len(calls) == cfg.kda_layers == 3
