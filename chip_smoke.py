@@ -1494,10 +1494,14 @@ SSD_KERNEL_TOLERANCE = 1e-5
 
 
 def ssm_check(widths: str, lens: list, seed: int,
-              interpret: bool = False) -> dict:
+              interpret: bool = False, block: str = "granite") -> dict:
     """Runs in a child that holds the chip: one Mamba-2 layer of the
-    seventh block (``models/granite.py``) at the published widths, in
-    the compute type, for prompts of ``lens`` tokens: its prefill in row
+    seventh block (``models/granite.py``: 128 heads, one group) or with
+    ``block="nemotron"`` of the twelfth (``models/nemotron.py``: 64
+    heads whose B and C come in eight groups, the gated norm by group;
+    the mixer's functions are the seventh block's) at the published
+    widths, in the compute type, for prompts of ``lens`` tokens: its
+    prefill in row
     segments, ``H`` and the convolution rows carried (the chunked scan,
     ``ops/ssd_chunk.py``), against its own stepping (on a TPU the
     ``ssd_step`` kernel in place; with ``interpret`` the kernel in the
@@ -1516,12 +1520,21 @@ def ssm_check(widths: str, lens: list, seed: int,
     from ray_tpu.ops import ssd_step as ss
 
     accelerator.claim_device()
-    kw = dict(n_layers=1, layer_types=("mamba",), vocab_size=1024,
-              n_experts=8, top_k=2)
-    cfg = granite.GraniteConfig.tiny(**kw, dtype="bfloat16") \
-        if widths == "tiny" else granite.GraniteConfig(**kw)
-    p = granite.init_params(cfg, jax.random.PRNGKey(seed))["layers"][0][
-        "attn"]
+    if block == "nemotron":
+        from ray_tpu.models import nemotron
+
+        kw = dict(pattern="M", vocab_size=1024, n_experts=8, top_k=2)
+        cfg = nemotron.NemotronConfig.tiny(**kw, dtype="bfloat16") \
+            if widths == "tiny" else nemotron.NemotronConfig(**kw)
+        p = nemotron.init_params(cfg, jax.random.PRNGKey(seed))["layers"][
+            0]["mix"]
+    else:
+        kw = dict(n_layers=1, layer_types=("mamba",), vocab_size=1024,
+                  n_experts=8, top_k=2)
+        cfg = granite.GraniteConfig.tiny(**kw, dtype="bfloat16") \
+            if widths == "tiny" else granite.GraniteConfig(**kw)
+        p = granite.init_params(cfg, jax.random.PRNGKey(seed))["layers"][0][
+            "attn"]
     kernel = functools.partial(ss.ssd_step, **(
         {"interpret": True} if interpret else {}))
 
@@ -1557,8 +1570,8 @@ def ssm_check(widths: str, lens: list, seed: int,
     h0 = ss.pack(jax.random.normal(key[0], (slots, h, hd, n), jnp.float32))
     vectors = (0.1 * jax.random.normal(key[1], (slots, h, hd)),
                jax.random.uniform(key[2], (slots, h)),
-               jax.random.normal(key[3], (slots, n)),
-               jax.random.normal(key[4], (slots, n)), active)
+               jax.random.normal(key[3], (slots, cfg.ssm_groups, n)),
+               jax.random.normal(key[4], (slots, cfg.ssm_groups, n)), active)
     h_kernel, y_kernel = jax.jit(kernel)(h0, *vectors)
     h_body, y_body = ss.ssd_step(h0, *vectors, use_kernel=False)
     state = granite.ssm_empty(cfg, slots)
@@ -1573,8 +1586,9 @@ def ssm_check(widths: str, lens: list, seed: int,
             "in_program": any(
                 KERNEL in line and "ssd_step" in line.split(" = ")[0]
                 for line in text.splitlines()),
-            "segments": {str(t): granite.SLOTS.prefill_segments(cfg, t)
+            "segments": {str(t): cfg.slot_model.prefill_segments(cfg, t)
                          for t in lens},
+            "groups": cfg.ssm_groups,
             "device": accelerator.device_report()}
 
 
@@ -1907,18 +1921,21 @@ def hybrid_phase(plan: Plan) -> dict:
           "form (KDA in carried segments / stepping at 64 heads, gated "
           "GQA through the flash kernel / over the slot's rows)",
           got=segment["rel_err"], tolerance=HYBRID_TOLERANCE)
-    ssm = chip_child(plan, "ssm_check", {
-        "widths": plan.hybrid_widths, "lens": list(plan.ssm_lens),
-        "seed": plan.seed, "interpret": not plan.on_tpu})
-    check_device(plan, ssm["device"], 1, "ssm child")
-    check(max(v for by_len in ssm["rel_err"].values()
-              for v in by_len.values()) <= HYBRID_TOLERANCE
-          and max(ssm["kernel"].values()) <= SSD_KERNEL_TOLERANCE
-          and ssm["inactive_kept"] and ssm["in_program"] == plan.on_tpu,
-          "a Mamba-2 layer's chunked scan in carried segments parts from "
-          "its stepping, the ssd_step kernel from the XLA body, an "
-          "inactive slot's state moved, or the layer's step holds no "
-          "kernel on the chip", got=ssm, tolerance=HYBRID_TOLERANCE)
+    mamba = {}
+    for block in ("granite", "nemotron"):
+        mamba[block] = ssm = chip_child(plan, "ssm_check", {
+            "widths": plan.hybrid_widths, "lens": list(plan.ssm_lens),
+            "seed": plan.seed, "interpret": not plan.on_tpu, "block": block})
+        check_device(plan, ssm["device"], 1, "ssm child")
+        check(max(v for by_len in ssm["rel_err"].values()
+                  for v in by_len.values()) <= HYBRID_TOLERANCE
+              and max(ssm["kernel"].values()) <= SSD_KERNEL_TOLERANCE
+              and ssm["inactive_kept"] and ssm["in_program"] == plan.on_tpu,
+              f"at the widths of models/{block}.py a Mamba-2 layer's "
+              "chunked scan in carried segments parts from its stepping, "
+              "the ssd_step kernel from the XLA body, an inactive slot's "
+              "state moved, or the layer's step holds no kernel on the "
+              "chip", got=ssm, tolerance=HYBRID_TOLERANCE)
     sconv = chip_child(plan, "sconv_check", {
         "widths": plan.hybrid_widths, "lens": list(plan.sconv_lens),
         "seed": plan.seed, "interpret": not plan.on_tpu})
@@ -1950,8 +1967,12 @@ def hybrid_phase(plan: Plan) -> dict:
             "dsa": {block: {k: found[k] for k in (
                 "rel_err", "sets_equal", "chosen", "rows")}
                 for block, found in sparse.items()},
-            "ssm": {k: ssm[k] for k in ("rel_err", "kernel", "segments",
-                                        "inactive_kept", "in_program")},
+            "ssm": {k: mamba["granite"][k] for k in (
+                "rel_err", "kernel", "segments", "inactive_kept",
+                "in_program")},
+            "ssm_groups": {k: mamba["nemotron"][k] for k in (
+                "rel_err", "kernel", "segments", "inactive_kept",
+                "in_program", "groups")},
             "sconv": {k: sconv[k] for k in (
                 "rel_err", "kernel", "segments", "heads_a_tile",
                 "in_program")},

@@ -16,7 +16,10 @@ programs unroll them.
   ``GraniteMoeHybridMambaLayer``, Bamba's): ``[z | xBC | dt] = u W_in``;
   ``xBC <- silu(conv_K(xBC) + bias)``, depthwise and causal; ``[x | B |
   C]``, x as ``ssm_heads`` heads of ``ssm_head_dim``, B and C ONE row of
-  ``ssm_state`` for all heads (``mamba_n_groups`` 1); ``dt = softplus(dt
+  ``ssm_state`` for all heads (``mamba_n_groups`` 1; the mixer's
+  functions and the ops take ``ssm_groups`` groups of heads, each with
+  a B, a C and a gated norm of its own: ``models/nemotron.py`` calls
+  them with eight); ``dt = softplus(dt
   + dt_bias)``, ``A = -exp(A_log)`` a head; a head's state ``H [P, N]``:
   ``H_t = exp(dt_t A) H_{t-1} + dt_t x_t B_t^T``, ``y_t = H_t C_t + D
   x_t``; ``y <- RMSNorm(y * silu(z)) * w`` over all of the inner width
@@ -102,6 +105,10 @@ class GraniteConfig(moe.HeldExperts):
     ssm_heads: int = 128
     ssm_head_dim: int = 64
     ssm_state: int = 128
+    # groups of heads that share a B and a C row (``mamba_n_groups``;
+    # the mixer's functions here are ``models/nemotron.py``'s too, whose
+    # configuration has eight)
+    ssm_groups: int = 1
     conv_kernel: int = 4
     ssm_chunk: int = 256  # rows of a chunk of the prefill's scan
     # mixture of experts: d_ff is ONE expert's width
@@ -151,8 +158,9 @@ class GraniteConfig(moe.HeldExperts):
 
     @property
     def conv_width(self) -> int:
-        """What the convolution runs over: x, B and C end to end."""
-        return self.inner + 2 * self.ssm_state
+        """What the convolution runs over: x, every group's B and every
+        group's C end to end."""
+        return self.inner + 2 * self.ssm_groups * self.ssm_state
 
     def full(self, i: int) -> bool:
         return self.layer_types[i] == "attention"
@@ -267,16 +275,19 @@ def init_params(cfg: GraniteConfig, key):
 # --------------------------------------------------------------------------
 
 @jax.named_scope("qkv")
-def _ssm_inputs(cfg: GraniteConfig, p, x, conv_rows):
-    """What both forms of the mixer start from. x [B, T, D] (normed);
-    ``conv_rows`` [B, K-1, inner + 2 N]: the ``xBC`` rows before x's
-    first. -> (z [B, T, inner]: the gate's input, xs [B, T, H, P], dt
-    [B, T, H] after its softplus, b, c [B, T, N], all float32, the
-    ``xBC`` rows [B, K-1+T, inner + 2 N] whose tail is the next
-    ``conv_rows``)."""
+def _ssm_inputs(cfg, p, x, conv_rows):
+    """What both forms of the mixer start from (``cfg``: this block's
+    configuration or ``models/nemotron.py``'s, which has the fields
+    read here). x [B, T, D] (normed); ``conv_rows`` [B, K-1, inner + 2 G
+    N]: the ``xBC`` rows before x's first. -> (z [B, T, inner]: the
+    gate's input, xs [B, T, H, P], dt [B, T, H] after its softplus, b, c
+    [B, T, G, N]: each group's rows (this block's one group under the
+    group axis that ``ops/ssd_step.py`` and ``ops/ssd_chunk.py`` take),
+    all float32, the ``xBC`` rows [B, K-1+T, inner + 2 G N] whose tail
+    is the next ``conv_rows``)."""
     b, t, _ = x.shape
     f32 = jnp.float32
-    inner, n = cfg.inner, cfg.ssm_state
+    inner, n, g = cfg.inner, cfg.ssm_state, cfg.ssm_groups
     proj = x @ p["w_in"]
     z = proj[..., :inner].astype(f32)
     u = jnp.concatenate(
@@ -287,21 +298,25 @@ def _ssm_inputs(cfg: GraniteConfig, p, x, conv_rows):
     dt = jax.nn.softplus(
         proj[..., inner + cfg.conv_width:].astype(f32) + p["dt_bias"])
     xs = xbc[..., :inner].reshape(b, t, cfg.ssm_heads, cfg.ssm_head_dim)
-    return z, xs, dt, xbc[..., inner:inner + n], xbc[..., inner + n:], u
+    return (z, xs, dt, xbc[..., inner:inner + g * n].reshape(b, t, g, n),
+            xbc[..., inner + g * n:].reshape(b, t, g, n), u)
 
 
 @jax.named_scope("attn_out")
-def _ssm_out(cfg: GraniteConfig, p, y, xs, z):
+def _ssm_out(cfg, p, y, xs, z):
     """y, xs [B, T, H, P] float32, z [B, T, inner] -> [B, T, D]: the
-    skip ``D x``, the gate, the norm over the whole inner width (one
-    group, the gate before it) and ``W_out``."""
+    skip ``D x``, the gate, the norm over each GROUP's channels (the
+    gate before it; this block's one group: over the whole inner width)
+    and ``W_out``."""
     b, t = y.shape[:2]
+    g = cfg.ssm_groups
     y = (y + p["d_skip"][:, None] * xs).reshape(b, t, -1) * jax.nn.silu(z)
-    return rms_norm(y, p["y_norm"], cfg.rms_eps).astype(
-        cfg.compute_dtype) @ p["w_out"]
+    y = rms_norm(y.reshape(b, t, g, -1), p["y_norm"].reshape(g, -1),
+                 cfg.rms_eps).reshape(b, t, -1)
+    return y.astype(cfg.compute_dtype) @ p["w_out"]
 
 
-def ssm_empty(cfg: GraniteConfig, b: int) -> dict:
+def ssm_empty(cfg, b: int) -> dict:
     """The state of ``b`` streams before their first token: ``h`` in the
     step kernel's layout (``ops.ssd_step.pack``: [B, H / g, N, g * P],
     ``g`` heads side by side in a row of lanes)."""
@@ -312,9 +327,9 @@ def ssm_empty(cfg: GraniteConfig, b: int) -> dict:
                               cfg.compute_dtype)}
 
 
-def ssm_step(cfg: GraniteConfig, p, x, state, active):
+def ssm_step(cfg, p, x, state, active):
     """A decode step of a Mamba layer. x [B, 1, D] (normed); ``state``
-    {"h" [B, H / g, N, g P] float32, "conv" [B, K-1, inner + 2 N]}. A slot
+    {"h" [B, H / g, N, g P] float32, "conv" [B, K-1, inner + 2 G N]}. A slot
     that is not ``active`` keeps its state. -> ([B, 1, D], state)."""
     z, xs, dt, b, c, u = _ssm_inputs(cfg, p, x, state["conv"])
     with jax.named_scope("attn/attn_ssm"):
@@ -328,7 +343,7 @@ def ssm_step(cfg: GraniteConfig, p, x, state, active):
     return _ssm_out(cfg, p, y[:, None], xs, z), new
 
 
-def ssm_segment(cfg: GraniteConfig, p, x, state, start, true_lens):
+def ssm_segment(cfg, p, x, state, start, true_lens):
     """A Mamba layer over one segment of whole prompts: rows ``start``
     .. ``start + T - 1`` of x [B, T, D] (normed, right-padded:
     ``true_lens`` [B] rows of each prompt are real), from the ``state``

@@ -255,30 +255,30 @@ def _held_part(cfg, matmul, c, w_gate, w_up, w_down, xf, weights, held,
     """The held experts' part of the layer's result, [tokens, D], from
     the first ``c`` rows of the sort (``None``: from every assignment's
     row). The rows gathered in the sort's order (a foreign assignment's
-    reads row 0), the three products over the held groups, each
-    assignment's row put back at its place (a foreign one's masked to
-    0) and a token's ``top_k`` summed in float32.
-
-    With ``c`` the gather, the products and the elementwise work
-    between them are ``c`` rows and not N: right when no more than ``c``
-    assignments are held, for the held ones sort first. The groups lie
-    at the same offsets, the put-back and the sum are the same lines, so
-    the result is the one ``c = None`` gives, bit for bit."""
-    kk = cfg.top_k
+    reads row 0), the three products over the held groups (two where
+    ``w_gate`` is None: ungated experts, the file's end), then
+    :func:`_summed`. With ``c`` the gather, the products and the
+    elementwise work between them are ``c`` rows and not N: right when
+    no more than ``c`` assignments are held, for the held ones sort
+    first; the groups lie at the same offsets, so the result is the one
+    ``c = None`` gives, bit for bit."""
     first = order if c is None else order[:c]
-    rows = xf[jnp.where(held[first], first // kk, 0)]
+    rows = xf[jnp.where(held[first], first // cfg.top_k, 0)]
     experts = functools.partial(matmul, group_sizes=group_sizes)
+    if w_gate is None:  # (w_up [count, F, D]: ``init_ungated_experts``)
+        return _summed(cfg, c, xf, weights, held, order, experts(relu2(
+            experts(rows, w_up, transpose_rhs=True)), w_down))
     gate = experts(rows, w_gate)
     up = experts(rows, w_up)
     y = experts(gated(gate, up, swiglu_limit(cfg)), w_down)
-    unsort = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    if c is not None:  # (a foreign assignment's place lies behind them)
-        unsort = jnp.minimum(unsort, c - 1)
-    y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
-    out = jnp.sum(y.reshape(-1, kk, xf.shape[1]) * weights[..., None],
-                  axis=1)
-    return out.astype(cfg.compute_dtype)
+    return _summed(cfg, c, xf, weights, held, order, y)
+
+
+def relu2(up):
+    """The middle of an ungated expert (what the file's end is about):
+    ``relu(up)^2``, squared in float32 and rounded once, to ``up``'s
+    type. (Defined here, in the lines ``_held_part``'s tail left.)"""
+    return jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(up.dtype)
 
 
 # (jitted by itself where the branch stands: a program traces the
@@ -328,7 +328,7 @@ def moe(cfg, p, x, aux: dict | None = None):
             aux["expert_ids"] = ids.reshape(b, t, kk)
     with jax.named_scope("moe_experts"):
         held, order, group_sizes = held_first(cfg, ids)
-        args = (p["w_gate"], p["w_up"], p["w_down"], xf, weights, held,
+        args = (p.get("w_gate"), p["w_up"], p["w_down"], xf, weights, held,
                 order, group_sizes)
         c = compact_rows(cfg, b * t * kk)
         if c is None:
@@ -341,11 +341,11 @@ def moe(cfg, p, x, aux: dict | None = None):
                 lambda: _held_part_once(cfg, grouped_matmul, None, *args))
             if aux is not None:
                 aux["compact"] = fits.astype(jnp.int32)
-    if "shared_gate" not in p:
+    if "shared_up" not in p:
         return out.reshape(b, t, d)
     with jax.named_scope("moe_shared"):
-        out = out + swiglu(xf, p["shared_gate"], p["shared_up"],
-                           p["shared_down"], swiglu_limit(cfg))
+        # (a SwiGLU, or two matrices round relu^2: the file's end)
+        out = out + _shared_expert(cfg, p, xf)
     return out.reshape(b, t, d)
 
 
@@ -368,7 +368,7 @@ def mlp_layer(cfg, sparse: bool, p, h, aux: dict | None = None,
     y = moe(cfg, p["mlp"], x, aux)
     if not residual:
         return y
-    with jax.named_scope("moe_shared" if "shared_gate" in p["mlp"]
+    with jax.named_scope("moe_shared" if "shared_up" in p["mlp"]
                          else "moe_experts"):
         return h + y
 
@@ -507,3 +507,64 @@ def prefill_loads(cfg, ids, true_lens):
     real = jnp.arange(ids.shape[2])[None, :] < true_lens[:, None]
     hit = jax.nn.one_hot(ids - first, count, dtype=jnp.int32)
     return jnp.sum(hit * real[None, :, :, None, None], axis=(1, 2, 3))
+
+
+# --------------------------------------------------------------------------
+# At the file's END, and what reaches it from above keeps its lines: the
+# expert kernel's Mosaic module carries the lines and columns of every
+# call on its way, so a line added above moves the compile-cache key of
+# every older block's programs (``PERF.md`` section 6, PR 70).
+#
+# Ungated experts (``models/nemotron.py``): experts of TWO matrices round
+# a squared relu, ``W_down relu(W_up x)^2`` (:func:`relu2`) with no gate,
+# the shared expert alike. The LEAVES say so (:func:`init_ungated_experts`
+# makes no ``w_gate`` / ``shared_gate``): that is what :func:`moe` and
+# :func:`_held_part` read, and no field of a configuration.
+# --------------------------------------------------------------------------
+
+def _summed(cfg, c, xf, weights, held, order, y):
+    """How :func:`_held_part` ends. The products' rows ``y`` (in the
+    sort's order, ``c`` of them or every assignment's) -> [tokens, D]:
+    each assignment's row put back at its place (a foreign one's masked
+    to 0) and a token's ``top_k`` summed in float32. The put-back and the
+    sum are the same lines with ``c`` and without."""
+    kk = cfg.top_k
+    unsort = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    if c is not None:  # (a foreign assignment's place lies behind them)
+        unsort = jnp.minimum(unsort, c - 1)
+    y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
+    out = jnp.sum(y.reshape(-1, kk, xf.shape[1]) * weights[..., None],
+                  axis=1)
+    return out.astype(cfg.compute_dtype)
+
+
+def _shared_expert(cfg, p, xf):
+    """The shared expert of an expert layer's leaves ``p`` on xf
+    [tokens, D], in the routed experts' form."""
+    if "shared_gate" in p:
+        return swiglu(xf, p["shared_gate"], p["shared_up"], p["shared_down"],
+                      swiglu_limit(cfg))
+    return relu2(xf @ p["shared_up"]) @ p["shared_down"]
+
+
+def init_ungated_experts(cfg, mat, keys) -> dict:
+    """:func:`init_experts` for experts of two matrices: the router with
+    its selection bias, the held experts' ``w_up`` stored ``[count, F,
+    D]``, the way ``w_down`` lies (``grouped_matmul(transpose_rhs=True)``
+    reads it in place: a width F that is not whole lane tiles is then
+    nowhere a matrix's minor dimension, which on a TPU XLA would keep
+    transposed in HBM and copy back for every call of the kernel), and
+    the shared expert's two."""
+    d, f, fs = cfg.d_model, cfg.d_ff, cfg.shared_d_ff
+    _, count = cfg.held
+    leaves = {"router": mat(d, cfg.n_experts),
+              "router_bias": 0.01 * jax.random.normal(
+                  next(keys), (cfg.n_experts,), jnp.float32),
+              "w_up": draw(next(keys), (count, f, d), d ** -0.5,
+                           cfg.compute_dtype),
+              "w_down": mat(count, f, d, out=True)}
+    if fs:
+        leaves.update({"shared_up": mat(d, fs),
+                       "shared_down": mat(fs, d, out=True)})
+    return leaves

@@ -71,9 +71,9 @@ def tiling(m: int, k: int, n: int, tm: int | None = None,
     columns: the preferred tile, no larger than the (16-padded) rows;
     ``tn`` a divisor of ``n`` whose [k, tn] block fits ``_BLOCK_BYTES``:
     the budget halved until it divides ``n`` or, where halving leaves
-    the lanes (6144 -> 2048: 640 columns fit, and 2048 has no divisor
-    among 640, 320, ...), the largest whole-lane divisor within the
-    budget (512); all of ``n`` only where it has none."""
+    the lanes (6144 -> 2048: of 640, 320, ... none divides 2048) or runs
+    out of twos on ONE lane tile (2688 = 21 x 128), the largest whole-lane
+    divisor within the budget (512; 896); else all of ``n`` (file's end)."""
     tm = min(tm or TILE_M, _round_up(m, 16))
     if tn is None:
         tn = max(128, _BLOCK_BYTES // (k * itemsize) // 128 * 128)
@@ -81,7 +81,7 @@ def tiling(m: int, k: int, n: int, tm: int | None = None,
     budget = tn = min(tn, n)
     while n % tn:
         tn //= 2
-    if tn % 128 and tn != n:
+    if (tn % 128 and tn != n) or tn == 128 < budget:
         tn = max((c for c in range(128, budget + 1, 128) if n % c == 0),
                  default=n)
     return tm, tn
@@ -310,19 +310,19 @@ def _gmm_bwd(tm, tn, interpret, res, g):
 _gmm_kernel.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, *, layer=None,
+def grouped_matmul(lhs, rhs, group_sizes, *, layer=None, transpose_rhs=False,
                    use_kernel: bool | None = None, interpret: bool = False,
                    tm: int | None = None, tn: int | None = None):
     """lhs [M, K], rhs [E, K, N], group_sizes [E] int32 summing to M ->
     [M, N] in lhs's dtype; differentiable in lhs and rhs. With ``layer``
     (an int32 scalar) rhs is a stack [L, E, K, N] of which ``rhs[layer]``
-    is used, read in place by the kernel (a serving program's layer
-    scan; not differentiable).
-
-    ``use_kernel=None`` takes the backend's: the Pallas kernel on a TPU,
-    ``jax.lax.ragged_dot`` elsewhere. ``interpret=True`` runs the kernel
-    in the Pallas interpreter (never inferred). ``tm`` / ``tn`` override
-    the tile (the chip's tuning sweep and the tests)."""
+    is used, read in place by the kernel (a layer scan; not differentiable).
+    ``use_kernel=None``: the Pallas kernel on a TPU, ``jax.lax.ragged_dot``
+    elsewhere; ``interpret=True``: the kernel in the Pallas interpreter
+    (never inferred); ``tm`` / ``tn`` override the tile (sweeps, tests)."""
+    if transpose_rhs:  # (rhs [E, N, K]: the file's end)
+        return _transposed(lhs, rhs, group_sizes, layer, use_kernel,
+                           interpret, tm, tn)
     if use_kernel is None:
         use_kernel = interpret or jax.default_backend() == "tpu"
     rhs = rhs.astype(lhs.dtype)
@@ -333,3 +333,62 @@ def grouped_matmul(lhs, rhs, group_sizes, *, layer=None,
     if layer is None:
         return _gmm_kernel(lhs, rhs, group_sizes, tm, tn, interpret)
     return _forward(lhs, rhs, group_sizes, tm, tn, interpret, layer)
+
+
+# --------------------------------------------------------------------------
+# At the file's END, and what reaches it from above keeps its lines: the
+# kernel's Mosaic module carries the lines and columns of every call on
+# its way, so a line added above moves the compile-cache key of every
+# program that holds a ``moe_gmm`` (``PERF.md`` section 6, PR 70).
+#
+# ``tiling``, the rest of it. A halving that stops on two lane tiles or
+# more stands, though a larger whole-lane divisor may lie within the
+# budget (5120 under 2048: 1024, not 1280): those are the tiles the older
+# widths were read at (``tests/test_grouped_matmul.py`` pins every one). A
+# width with no whole-lane divisor is one block whatever the budget (1856 =
+# 14.5 x 128: 9.98 MB beside a contracted 2688), its last lane tile half
+# full. Read on the chip at Nemotron-3-Nano's experts (2688 -> 1856 ->
+# 2688, bf16, 16 held; TPU v5 lite, my chip run, PR 70: the whole jitted
+# call, mean of 50, best of two), as microseconds at a decode step's 192
+# rows over 11 touched experts | a 1,024-row prompt's compact 1,536 rows
+# over 16 | a 512-row prompt's 768:
+#   - down (k 1856, n 2688), tn 128: 229.5 | 399.3 | 382.0; 384: 218.4 |
+#     319.8 | 298.1; **896**: 211.7 | 297.9 | 279.8; 2688 (9.98 MB, over
+#     the budget): 215.2 | 293.4 | 276.0. So 896: the clause above;
+#   - up (k 2688, n 1856) from a matrix stored ``[E, N, K]``
+#     (``transpose_rhs``), all of n in one block: 213.0 | 289.2 | 273.9
+#     (63% of the HBM's roofline at the decode step's rows); the same
+#     product from ``[E, K, N]`` reads 689 | 813 | 791: XLA keeps a matrix
+#     whose minor dimension is 14.5 lane tiles TRANSPOSED in HBM (its
+#     parameter's layout ``{1,2,0}``) and copies all sixteen experts back
+#     before every call of the kernel.
+# --------------------------------------------------------------------------
+
+def _transposed(lhs, rhs, group_sizes, layer, use_kernel, interpret, tm, tn):
+    """:func:`grouped_matmul` with rhs ``[E, N, K]`` (a stack ``[L, E, N,
+    K]`` with ``layer``): an expert's output columns down the sublanes
+    and the contracted width along the lanes, so that an N that is not
+    whole lane tiles is no matrix's minor dimension. The kernel's block
+    is an expert's ``[tn, K]``: all of N where it has no whole-lane
+    divisor (the output's lanes), refused where two such blocks would
+    not fit the kernel's VMEM. Not differentiable through the kernel."""
+    if use_kernel is None:
+        use_kernel = interpret or jax.default_backend() == "tpu"
+    rhs = rhs.astype(lhs.dtype)
+    if not use_kernel:
+        return jax.lax.ragged_dot(lhs, jnp.swapaxes(
+            rhs if layer is None else rhs[layer], 1, 2), group_sizes)
+    m, k = lhs.shape
+    tm, tn = tiling(m, k, rhs.shape[-2], tm, tn, lhs.dtype.itemsize)
+    if 4 * tn * k * lhs.dtype.itemsize > _VMEM_LIMIT:
+        raise ValueError(
+            f"an expert's [{tn}, {k}] block twice over is more than half "
+            f"of the kernel's {_VMEM_LIMIT >> 20} MiB of VMEM: lay the "
+            "weight out padded to whole lane tiles")
+    # (``_forward``'s lines with the other layout: its own call of
+    # ``_gmm`` keeps its text, which the older programs' keys hold)
+    m_padded = _round_up(m, tm)
+    meta, steps = group_metadata(group_sizes.astype(jnp.int32), m_padded,
+                                 tm, visit_empty=False)
+    return _gmm(_pad_rows(lhs, m_padded), rhs, meta, steps, tm=tm, tn=tn,
+                transpose_rhs=True, interpret=interpret, layer=layer)[:m]

@@ -1,6 +1,7 @@
 """The chunked SSD scan of a Mamba-2 prefill: T tokens onto a state.
 
-The prefill of a Mamba-2 layer (``models/granite.py``) is the chunked
+The prefill of a Mamba-2 layer (``models/granite.py``,
+``models/nemotron.py``) is the chunked
 form of :func:`ray_tpu.ops.ssd_step.ssd_recurrence` (Dao & Gu,
 arXiv:2405.21060, section 6). With ``l_t = dt_t A`` the log decay of row
 t and head h (<= 0), L the running sum of l from the chunk's start, and
@@ -9,7 +10,10 @@ t and head h (<= 0), L the running sum of l from the chunk's start, and
     Y = ((C B^T) * e^(L_t - L_s)[t >= s]) X  +  e^(L_t) (C H0^T)
     H' = e^(L_Q) H0 + (X e^(L_Q - L))^T B
 
-One ``C B^T`` of ``[Q, Q]`` serves every head (one group). The decays
+One ``C_g B_g^T`` of ``[Q, Q]`` serves every head of group g (``B`` and
+``C`` come a GROUP of heads, ``[.., G, N]``: Granite's one group serves
+all heads, Nemotron's eight serve eight heads each; head j reads group
+``j // (H / G)``). The decays
 are taken pairwise, ``e^(L_t - L_s) <= 1`` masked BEFORE the
 exponential, and against the chunk's end, never as ``e^(-L_s)``: a sum
 of l under -87 inside one chunk is ordinary (dt to 0.1 and more, A to
@@ -39,35 +43,43 @@ _HI = jax.lax.Precision.HIGHEST
 
 def ssd_chunked(x, dt, a, b, c, h0, *, chunk: int):
     """x [B, T, H, P], dt [B, T, H] (>= 0; 0 on a padding row), a [H]
-    (< 0), b, c [B, T, N], h0 [B, H, P, N]; all float32, T whole chunks.
-    -> (y [B, T, H, P] without the skip, the state after row T)."""
+    (< 0), b, c [B, T, G, N] (heads ``g H / G ..`` read group g's), h0
+    [B, H, P, N]; all float32, T whole chunks. -> (y [B, T, H, P]
+    without the skip, the state after row T)."""
     bsz, t, nh, p = x.shape
-    n = b.shape[-1]
+    g, n = b.shape[-2:]
+    if nh % g:
+        raise ValueError(f"{g} groups do not divide {nh} heads")
     nc = t // chunk
     mm = functools.partial(jnp.einsum, precision=_HI,
                            preferred_element_type=jnp.float32)
     cs = jnp.cumsum((dt * a).reshape(bsz, nc, chunk, nh), axis=2)
-    xdt = (x * dt[..., None]).reshape(bsz, nc, chunk, nh, p)
-    bq, cq = b.reshape(bsz, nc, chunk, n), c.reshape(bsz, nc, chunk, n)
+    # (a head's axis as [G, H / G] wherever it meets a group's B or C)
+    xdt = (x * dt[..., None]).reshape(bsz, nc, chunk, g, nh // g, p)
+    bq = b.reshape(bsz, nc, chunk, g, n)
+    cq = c.reshape(bsz, nc, chunk, g, n)
     # rows t >= s of a chunk, a head: e^(L_t - L_s)
-    by_head = jnp.moveaxis(cs, 3, 2)  # [B, nc, H, Q]
+    by_head = jnp.moveaxis(cs, 3, 2).reshape(
+        bsz, nc, g, nh // g, chunk)  # [B, nc, G, H / G, Q]
     seen = jnp.tril(jnp.ones((chunk, chunk), jnp.bool_))
     decay = jnp.exp(jnp.where(
         seen, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
-    scores = mm("bctn,bcsn->bcts", cq, bq)[:, :, None] * decay
-    y = mm("bchts,bcshp->bcthp", scores, xdt)
+    scores = mm("bctgn,bcsgn->bcgts", cq, bq)[:, :, :, None] * decay
+    y = mm("bcghts,bcsghp->bctghp", scores, xdt)
     # what each chunk adds to the state at its own end, and the states
     # at the chunks' starts
-    to_end = jnp.exp(cs[:, :, -1:] - cs)  # [B, nc, Q, H]
-    added = mm("bcshp,bcsn->bchpn", xdt * to_end[..., None], bq)
-    whole = jnp.exp(cs[:, :, -1])  # [B, nc, H]
+    to_end = jnp.exp(cs[:, :, -1:] - cs).reshape(
+        bsz, nc, chunk, g, nh // g)  # [B, nc, Q, G, H / G]
+    added = mm("bcsghp,bcsgn->bcghpn", xdt * to_end[..., None], bq)
+    whole = jnp.exp(cs[:, :, -1]).reshape(bsz, nc, g, nh // g)
 
     def over(h, chunk_):
         add, keep = chunk_
         return h * keep[..., None, None] + add, h
 
     last, starts = jax.lax.scan(
-        over, h0, (jnp.moveaxis(added, 1, 0), jnp.moveaxis(whole, 1, 0)))
-    y = y + mm("bctn,bchpn->bcthp", cq, jnp.moveaxis(starts, 0, 1)) \
-        * jnp.exp(cs)[..., None]
-    return y.reshape(bsz, t, nh, p), last
+        over, h0.reshape(bsz, g, nh // g, p, n),
+        (jnp.moveaxis(added, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    y = y + mm("bctgn,bcghpn->bctghp", cq, jnp.moveaxis(starts, 0, 1)) \
+        * jnp.exp(cs).reshape(bsz, nc, chunk, g, nh // g)[..., None]
+    return y.reshape(bsz, t, nh, p), last.reshape(bsz, nh, p, n)

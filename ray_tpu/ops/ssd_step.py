@@ -3,15 +3,18 @@ place.
 
 (The second recurrence in the tree. The delta rule of ``ops/kda_step.py``
 decays a channel, predicts and corrects; this one has ONE scalar decay a
-head and step, no prediction, and with one group a single ``B`` and a
-single ``C`` row that all heads share. A decode step is this file's
-recurrence, one token on every slot; a prefill is its chunked form over
-a prompt's rows, ``ops/ssd_chunk.py``.)
+head and step, no prediction, and a ``B`` and a ``C`` row a GROUP of
+heads: one group that all heads share (``models/granite.py``) or several
+(``models/nemotron.py``: eight groups of eight heads). A decode step is
+this file's recurrence, one token on every slot; a prefill is its
+chunked form over a prompt's rows, ``ops/ssd_chunk.py``.)
 
 A Mamba-2 layer (``models/granite.py``: 128 heads of 64 with a state of
-128) keeps a float32 state ``H [P, N]`` a head (P the head's width, N
-the state's); a decode step decays it, adds the outer product of the
-step's input and ``B`` and reads it against ``C``::
+128 in one group; ``models/nemotron.py``: 64 heads of 64 with a state of
+128 in eight groups) keeps a float32 state ``H [P, N]`` a head (P the
+head's width, N the state's); a decode step decays it, adds the outer
+product of the step's input and its group's ``B`` and reads it against
+its group's ``C``::
 
     H <- H * da + (dt x)[:, None] * B[None, :]
     y = sum_N H * C[None, :]
@@ -26,9 +29,15 @@ the skip ``D x`` is the caller's too.
 (``dt x``, ``da``, and the output ``y``) is a ROW of lanes, as XLA
 leaves it (``[slots, heads * P]`` read as ``[slots, heads / g, g * P]``:
 no transpose anywhere), broadcast along the sublanes by the load; ``B``
-and ``C`` are columns, broadcast along the lanes ONCE a grid step for
-all its heads; and the sum over N runs down the sublanes: fifteen adds
-of whole registers and one fold of eight sublanes a lane row.
+and ``C`` are columns, broadcast along the lanes ONCE a grid step and
+group for all the group's heads; and the sum over N runs down the
+sublanes: fifteen adds of whole registers and one fold of eight sublanes
+a lane row. **A group is whole lane rows** (heads ``g H / G .. (g + 1) H
+/ G - 1`` are lane rows ``g R / G ..`` of the R: refused at trace time
+otherwise), and a grid step's block of lane rows is whole groups or a
+part of one: its ``B`` and ``C`` columns come in with it, picked by the
+block's index (Granite: a block of 16 of the 64 lane rows of its one
+group; Nemotron: a block of 16 of 32 lane rows is four groups of four).
 
 Read on the chip (TPU v5 lite, my chip runs, PR 54; 96 slots x 128 heads
 x 64 x 128 float32, a call in a loop of 50 on a donated state, best of
@@ -94,14 +103,26 @@ def unpack(h, p: int):
                          (0, 1, 3, 4, 2)).reshape(b, rows * g, p, n)
 
 
+def group_rows(rows: int, groups: int) -> int:
+    """Lane rows a group of heads: ``rows / groups``, which must be
+    whole (a lane row's heads share one ``B`` and ``C``)."""
+    if groups < 1 or rows % groups:
+        raise ValueError(
+            f"{groups} groups of heads do not divide the state's {rows} "
+            "lane rows: a group's lane rows must be whole")
+    return rows // groups
+
+
 def ssd_recurrence(h, x, da, b, c):
-    """One token of the recurrence on the state h [B, G, N, L] (float32,
-    elementwise: no product is rounded). x [B, G, L]: the step's input
-    times its step size; da [B, G, L]: ``exp(dt A)``, a head's repeated
-    over its lanes; b, c [B, N]: the one group's rows. -> (h, y [B, G,
-    L])."""
-    h = h * da[:, :, None, :] + b[:, None, :, None] * x[:, :, None, :]
-    return h, jnp.sum(h * c[:, None, :, None], axis=2)
+    """One token of the recurrence on the state h [B, R, N, L] (float32,
+    elementwise: no product is rounded). x [B, R, L]: the step's input
+    times its step size; da [B, R, L]: ``exp(dt A)``, a head's repeated
+    over its lanes; b, c [B, G, N]: a group's rows, lane rows ``g R / G
+    ..`` group g's. -> (h, y [B, R, L])."""
+    per = group_rows(h.shape[1], b.shape[1])
+    b, c = (jnp.repeat(a, per, axis=1)[..., None] for a in (b, c))
+    h = h * da[:, :, None, :] + b * x[:, :, None, :]
+    return h, jnp.sum(h * c, axis=2)
 
 
 def block_rows(rows: int, most: int = BLOCK_ROWS) -> int:
@@ -112,13 +133,15 @@ def block_rows(rows: int, most: int = BLOCK_ROWS) -> int:
 
 
 def _kernel(active_ref, x_ref, da_ref, bc_ref, h_ref, h_out_ref, y_ref, *,
-            rb: int):
+            rb: int, per: int):
     active = active_ref[pl.program_id(0)] != 0
     n, lanes = h_ref.shape[1:]
-    # B and C down the sublanes, across every lane: once for the block
-    b = jnp.broadcast_to(bc_ref[:, 0:1], (n, lanes))
-    c = jnp.broadcast_to(bc_ref[:, 1:2], (n, lanes))
     for i in range(rb):
+        if i % per == 0:
+            # a group's B and C down the sublanes, across every lane:
+            # once for the ``per`` lane rows of the block that share them
+            b = jnp.broadcast_to(bc_ref[i // per, :, 0:1], (n, lanes))
+            c = jnp.broadcast_to(bc_ref[i // per, :, 1:2], (n, lanes))
         h = h_ref[i]  # [N, L]
         new = h * da_ref[i:i + 1, :] + b * x_ref[i:i + 1, :]
         y_ref[i:i + 1, :] = jnp.sum(new * c, axis=0, keepdims=True)
@@ -126,9 +149,17 @@ def _kernel(active_ref, x_ref, da_ref, bc_ref, h_ref, h_out_ref, y_ref, *,
 
 
 def _ssd_step(h, x, da, bc, active, *, rb: int, interpret: bool):
-    """The kernel's call. h [B, G, N, L]; x, da [B, G, L]; bc [B, N, 2]:
-    B and C as columns; active [B] int32. -> (h, y [B, G, L])."""
+    """The kernel's call. h [B, R, N, L]; x, da [B, R, L]; bc [B, G, N,
+    2]: each group's B and C as columns; active [B] int32. A block of
+    ``rb`` lane rows is whole groups or lies inside one. -> (h, y [B, R,
+    L])."""
     bsz, rows, n, lanes = h.shape
+    of_group = group_rows(rows, bc.shape[1])
+    if rb % of_group and of_group % rb:
+        raise ValueError(
+            f"a block of {rb} lane rows is neither whole groups of "
+            f"{of_group} lane rows nor a part of one")
+    per, span = min(rb, of_group), max(rb, of_group)
 
     def state(i, j, active_ref):
         return i, j, 0, 0
@@ -139,14 +170,16 @@ def _ssd_step(h, x, da, bc, active, *, rb: int, interpret: bool):
     h_block = pl.BlockSpec((None, rb, n, lanes), state)
     x_block = pl.BlockSpec((None, rb, lanes), vectors)
     return pl.pallas_call(
-        functools.partial(_kernel, rb=rb),
+        functools.partial(_kernel, rb=rb, per=per),
         out_shape=(jax.ShapeDtypeStruct(h.shape, h.dtype),
                    jax.ShapeDtypeStruct(x.shape, h.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             in_specs=[x_block, x_block,
-                      pl.BlockSpec((None, n, 2),
-                                   lambda i, j, active_ref: (i, 0, 0)),
+                      # (the block's groups: ``rb / per`` of them)
+                      pl.BlockSpec((None, rb // per, n, 2),
+                                   lambda i, j, active_ref: (
+                                       i, j * rb // span, 0, 0)),
                       h_block],
             out_specs=[h_block, x_block],
             grid=(bsz, rows // rb),
@@ -164,11 +197,11 @@ def _ssd_step(h, x, da, bc, active, *, rb: int, interpret: bool):
 
 def ssd_step(h, dtx, da, b, c, active, *, use_kernel: bool | None = None,
              interpret: bool = False, rows: int | None = None):
-    """A decode step of the recurrence on the slots' state: h [B, G, N,
+    """A decode step of the recurrence on the slots' state: h [B, R, N,
     L] float32 in this file's layout (:func:`pack`); dtx [B, H, P], da
-    [B, H], b, c [B, N] float32; ``active`` [B] bool. -> (h: updated
-    where ``active``, kept bit for bit elsewhere; y [B, H, P], every
-    slot's).
+    [B, H], b, c [B, G, N] float32 (heads ``g H / G ..`` read group g's:
+    whole lane rows); ``active`` [B] bool. -> (h: updated where
+    ``active``, kept bit for bit elsewhere; y [B, H, P], every slot's).
 
     ``use_kernel=None`` takes the backend's: the Pallas kernel on a TPU
     where a lane row is whole lanes and N whole sublanes,
@@ -176,20 +209,20 @@ def ssd_step(h, dtx, da, b, c, active, *, use_kernel: bool | None = None,
     runs the kernel in the Pallas interpreter (never inferred). ``rows``
     overrides the lane rows a block (the chip's tuning sweep and the
     tests)."""
-    bsz, groups, n, lanes = h.shape
+    bsz, lane_rows, n, lanes = h.shape
     heads, p = dtx.shape[1:]
     if use_kernel is None:
         use_kernel = interpret or (
             jax.default_backend() == "tpu" and lanes % 128 == 0
             and n % 8 == 0)
-    x = dtx.reshape(bsz, groups, lanes)
-    da = jnp.repeat(da, p, axis=1).reshape(bsz, groups, lanes)
+    x = dtx.reshape(bsz, lane_rows, lanes)
+    da = jnp.repeat(da, p, axis=1).reshape(bsz, lane_rows, lanes)
     if not use_kernel:
         new, y = ssd_recurrence(h, x, da, b, c)
         new = jnp.where(active[:, None, None, None], new, h)
     else:
-        new, y = _ssd_step(h, x, da, jnp.stack([b, c], axis=2),
+        new, y = _ssd_step(h, x, da, jnp.stack([b, c], axis=3),
                            active.astype(jnp.int32),
-                           rb=block_rows(groups, rows or BLOCK_ROWS),
+                           rb=block_rows(lane_rows, rows or BLOCK_ROWS),
                            interpret=interpret)
     return new, y.reshape(bsz, heads, p)

@@ -228,9 +228,12 @@ def test_segment_check_rehearses_on_the_cpu(monkeypatch):
         assert 0 < max(errs.values()) <= chip_smoke.HYBRID_TOLERANCE
 
 
-def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
-    """What ``hybrid_phase`` asks of the block of state-space layers
-    (``models/granite.py``) on the chip, at tiny widths with segments of
+@pytest.mark.parametrize("block, groups", [("granite", 1),
+                                           ("nemotron", 2)])
+def test_ssm_check_rehearses_on_the_cpu(block, groups, monkeypatch):
+    """What ``hybrid_phase`` asks of the blocks of state-space layers
+    (``models/granite.py``, one group; ``models/nemotron.py``, B and C
+    in groups) on the chip, at tiny widths with segments of
     8 rows and the step kernel in the Pallas interpreter: prompts of 16
     and 32 tokens run their Mamba layer in two and four segments, the
     state carried, and agree with stepping; the kernel agrees with the
@@ -239,9 +242,10 @@ def test_ssm_check_rehearses_on_the_cpu(monkeypatch):
 
     monkeypatch.setattr(moe, "SEGMENT_ROWS", 8)
     found = chip_smoke.ssm_check("tiny", [16, 32], TINY.seed,
-                                 interpret=True)
+                                 interpret=True, block=block)
     assert found["device"].items() >= CPU.items()
     assert found["segments"] == {"16": 2, "32": 4}
+    assert found["groups"] == groups
     for errs in found["rel_err"].values():
         assert set(errs) == {"out", "state", "conv"}
         assert errs["conv"] == 0.0
@@ -412,8 +416,8 @@ def _answered_in_process(monkeypatch, spoil=None):
 
 
 def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
-    """The phase asks its eleven children (the eight checks above and
-    ``dsa_check`` a sparse block) with the plan's own arguments and
+    """The phase asks its twelve children (the eight checks above,
+    ``ssm_check`` a state-space block and ``dsa_check`` a sparse block) with the plan's own arguments and
     makes one line of their facts. Each child is answered in this
     process by the check itself, once a module (``_answer``: the tests
     above asked the same questions); what a child process adds is
@@ -423,8 +427,8 @@ def test_hybrid_phase_rehearses_on_the_cpu(capsys, monkeypatch):
     assert rc == 0
     assert calls == [
         "hybrid_check", "ring_check", "kda_kernel_check", "kda_chunk_check",
-        "latent_check", "segment_check", "ssm_check", "sconv_check"] \
-        + 3 * ["dsa_check"]
+        "latent_check", "segment_check", "ssm_check", "ssm_check",
+        "sconv_check"] + 3 * ["dsa_check"]
     _check_lines(lines, ["hybrid"])
     facts = lines[0]["checked"]
     assert set(facts["rel_err"]) == {"9", "21"}

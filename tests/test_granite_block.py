@@ -222,15 +222,17 @@ def test_the_chunked_scan_survives_a_decay_that_underflows_in_a_chunk():
     bb, cc = (jax.random.normal(k, (b, t, n)) for k in key[3:5])
     h0 = jax.random.normal(key[5], (b, h, p, n))
     assert float(jnp.cumsum(dt * a, 1)[:, 7].min()) < -100
-    y, last = sc.ssd_chunked(x, dt, a, bb, cc, h0, chunk=8)
+    # (the ops take a group axis: the block's one group)
+    y, last = sc.ssd_chunked(x, dt, a, bb[:, :, None], cc[:, :, None], h0,
+                             chunk=8)
     y_ref, last_ref = REF.ssm_recurrence(x, dt, a, bb, cc, h0)
     assert np.isfinite(np.asarray(y)).all() and np.isfinite(
         np.asarray(last)).all()
     np.testing.assert_allclose(y, y_ref, atol=3e-5, rtol=2e-5)
     np.testing.assert_allclose(last, last_ref, atol=3e-5, rtol=2e-5)
     # a chunk of padding (dt 0) hands the state on bit for bit
-    _, kept = sc.ssd_chunked(x[:, :8], jnp.zeros((b, 8, h)), a, bb[:, :8],
-                             cc[:, :8], h0, chunk=8)
+    _, kept = sc.ssd_chunked(x[:, :8], jnp.zeros((b, 8, h)), a,
+                             bb[:, :8, None], cc[:, :8, None], h0, chunk=8)
     np.testing.assert_array_equal(kept, h0)
 
 
@@ -258,9 +260,11 @@ def test_the_step_kernel_is_the_xla_body_is_the_recurrence(heads, p, rows):
     da = jax.random.uniform(key[2], (b, heads)) ** 4
     bb, cc = (jax.random.normal(k, (b, n)) for k in key[3:5])
     active = jnp.array([True, False, True])
-    h_k, y_k = ss.ssd_step(h, dtx, da, bb, cc, active, interpret=True,
-                           rows=rows)
-    h_x, y_x = ss.ssd_step(h, dtx, da, bb, cc, active, use_kernel=False)
+    # (the ops take a group axis: the block's one group)
+    h_k, y_k = ss.ssd_step(h, dtx, da, bb[:, None], cc[:, None], active,
+                           interpret=True, rows=rows)
+    h_x, y_x = ss.ssd_step(h, dtx, da, bb[:, None], cc[:, None], active,
+                           use_kernel=False)
     np.testing.assert_array_equal(h_k[1], h[1])
     np.testing.assert_array_equal(h_x[1], h[1])
     np.testing.assert_allclose(h_k, h_x, atol=1e-5)

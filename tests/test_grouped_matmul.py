@@ -94,6 +94,110 @@ def test_tiling_fits_the_shapes():
     assert tiling(8192, 4096, 14336, itemsize=4) == (256, 512)
 
 
+def test_a_width_that_is_not_whole_lane_tiles_is_ragged_dot():
+    """Nemotron-3-Nano's experts at a tiny analogue (384 -> 232 -> 384
+    for 2,688 -> 1,856 -> 2,688: 232 = 14.5 x 16 as 1,856 = 14.5 x 128),
+    the kernel in the interpreter against ``ragged_dot``: the up product
+    with its matrix stored ``[E, N, K]`` (``transpose_rhs``: 232 is then
+    no matrix's minor dimension; one block holds all of N), the down
+    product with a CONTRACTED width of 232, rows that belong to no group
+    behind the held ones left alone, empty groups never visited."""
+    keys = jax.random.split(jax.random.PRNGKey(70), 4)
+    d, f, rows = 384, 232, 48
+    sizes = jnp.asarray([0, 7, 0, 12, 1, 0, 9, 3], jnp.int32)  # 32 of 48
+    held = int(sizes.sum())
+    x = jax.random.normal(keys[0], (rows, d), jnp.float32)
+    up_t = jax.random.normal(keys[1], (8, f, d), jnp.float32)
+    down = jax.random.normal(keys[2], (8, f, d), jnp.float32)
+    assert tiling(rows, d, f) == (48, f)  # (no whole-lane divisor: all)
+    want = jax.lax.ragged_dot(x, jnp.swapaxes(up_t, 1, 2), sizes)
+    for use in (dict(interpret=True), dict()):
+        got = grouped_matmul(x, up_t, sizes, transpose_rhs=True, **use)
+        assert got.shape == (rows, f)
+        np.testing.assert_allclose(got[:held], want[:held], atol=2e-4)
+    mid = jax.random.normal(keys[3], (rows, f), jnp.float32)
+    want = jax.lax.ragged_dot(mid, down, sizes)
+    for tn in (128, 384):
+        got = grouped_matmul(mid, down, sizes, interpret=True, tn=tn)
+        np.testing.assert_allclose(got[:held], want[:held], atol=2e-4)
+    # a stack read in place at the layer, transposed
+    stack = jnp.stack([up_t * 0, up_t])
+    got = jax.jit(lambda layer: grouped_matmul(
+        x, stack, sizes, layer=layer, transpose_rhs=True, interpret=True))(
+            jnp.int32(1))
+    np.testing.assert_allclose(
+        got[:held], jax.lax.ragged_dot(x, jnp.swapaxes(up_t, 1, 2),
+                                       sizes)[:held], atol=2e-4)
+
+
+def _older_expert_widths():
+    """(configuration, hidden size, an expert's width) of every
+    configuration file of the benchmark that has experts but this PR's."""
+    import os
+
+    from benchmark import manifest
+
+    out = []
+    for name in sorted(os.listdir(os.path.join(manifest.HERE, "configs"))):
+        name = name[:-len(".json")]
+        if name.startswith("nemotron"):
+            continue
+        _, m = manifest.model(name)
+        if m.get("n_experts"):
+            out.append((name, m["d_model"], m["d_ff"]))
+    return out
+
+
+# (k, n) -> tn at a decode step's rows and a prefill's, as the parent's
+# ``tiling`` gave them (PR 69, ``git show 0d27b89:ray_tpu/ops/
+# grouped_matmul.py``): the tiles the older cells were measured at
+PINNED = {
+    (5120, 1536): 768, (1536, 5120): 1024,     # dots3
+    (6144, 2048): 512, (2048, 6144): 2048,     # GLM-5.2, K-EXAONE
+    (4096, 2048): 1024, (2048, 4096): 2048,    # GLM-5.3-Flash, MiMo-V2.5
+    (4096, 768): 768, (768, 4096): 2048,       # Granite
+    (2048, 1408): 1408, (1408, 2048): 2048,    # Instella-MoE
+    (2048, 1792): 1792, (1792, 2048): 2048,    # LFM2
+    (2560, 768): 768, (768, 2560): 512,        # Ling
+    (2048, 1024): 1024, (1024, 2048): 2048,    # OLMoE
+    (4096, 1280): 256, (1280, 4096): 2048,     # Solar-Open2
+}
+
+
+def test_every_older_width_keeps_the_tile_it_had():
+    """``tiling``'s new clause (halving that runs out of twos on ONE
+    lane tile takes the largest whole-lane divisor within the budget:
+    2,688 = 21 x 128 gets 896, not 128) moves no pair an older
+    configuration sends: every (hidden size, expert width) of the
+    configuration files, both products, at a decode step's rows and a
+    prompt's, gets the tile the parent gave it. A halving that stops on
+    two lane tiles or more stands (dots3's 5,120 under 2,048: 1,024,
+    though 1,280 divides it), which is why the clause is no wider."""
+    found = _older_expert_widths()
+    assert len(found) == 11 and len({(d, f) for _, d, f in found}) == 9
+    for name, d, f in found:
+        for k, n in ((d, f), (f, d)):
+            for rows in (16, 192, 1536, 8192):
+                tm, tn = tiling(rows, k, n)
+                assert tn == PINNED[(k, n)], (name, k, n, rows, tn)
+                assert tm == min(256, -(-rows // 16) * 16)
+    # this PR's widths: the down product's 21 lane tiles in three blocks
+    # of seven, the up product's 14.5 lane tiles in one block (stored
+    # [F, D]: ``transpose_rhs``)
+    assert tiling(192, 1856, 2688) == (192, 896)
+    assert tiling(192, 2688, 1856) == (192, 1856)
+    assert tiling(1536, 1856, 2688) == (256, 896)
+    # an explicit tile stands
+    assert tiling(192, 1856, 2688, tn=128) == (192, 128)
+    # a transposed block that would not fit the kernel's VMEM twice over
+    # is refused when the program is traced
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        jax.eval_shape(lambda a, b: grouped_matmul(
+            a, b, jnp.zeros((2,), jnp.int32), transpose_rhs=True,
+            interpret=True), jax.ShapeDtypeStruct((64, 16384), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, 1000, 16384), jnp.bfloat16))
+
+
 @pytest.mark.parametrize("shape, want", [
     # K-EXAONE's experts, 6144 -> 2048: 640 columns fit the 8 MiB and
     # halving 640 never divides 2048; the largest whole-lane divisor

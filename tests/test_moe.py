@@ -244,3 +244,117 @@ def test_a_configuration_without_the_1e_6_keeps_its_lowered_text(
     monkeypatch.setattr(moe, "route", _route_before_pr_68)
     assert lowered(older, exaone.init_params) == now
     assert lowered(lfm2.Lfm2Config.tiny(), lfm2.init_params) != new
+
+
+def _held_part_before_pr_70(cfg, matmul, c, w_gate, w_up, w_down, xf,
+                            weights, held, order, group_sizes):
+    """``models/moe.py: _held_part`` as PR 69 had it, letter for letter
+    (three grouped products round ``gated()``)."""
+    import functools
+
+    from ray_tpu.models import moe
+
+    kk = cfg.top_k
+    first = order if c is None else order[:c]
+    rows = xf[jnp.where(held[first], first // kk, 0)]
+    experts = functools.partial(matmul, group_sizes=group_sizes)
+    gate = experts(rows, w_gate)
+    up = experts(rows, w_up)
+    y = experts(moe.gated(gate, up, moe.swiglu_limit(cfg)), w_down)
+    unsort = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    if c is not None:  # (a foreign assignment's place lies behind them)
+        unsort = jnp.minimum(unsort, c - 1)
+    y = jnp.where(held[:, None], y[unsort], 0).astype(jnp.float32)
+    out = jnp.sum(y.reshape(-1, kk, xf.shape[1]) * weights[..., None],
+                  axis=1)
+    return out.astype(cfg.compute_dtype)
+
+
+@pytest.mark.parametrize("block", ["exaone", "granite", "lfm2", "mimo"])
+def test_a_gated_blocks_expert_layer_keeps_its_lowered_text(block,
+                                                            monkeypatch):
+    """``moe.moe`` runs an UNGATED expert layer (two grouped products
+    round a squared relu, ``models/nemotron.py``) where the layer's
+    leaves have no ``w_gate``. An older block's have one: its expert
+    layer (a sigmoid router with a shared
+    expert, a softmax router with one, a sigmoid router with none, one
+    that holds a sixteenth and so has the compact branch at these rows)
+    lowers to the text the parent's ``_held_part`` gives (its lines in
+    ``models/moe.py`` keep their NUMBERS too: the expert kernel's Mosaic
+    module carries them, ``test_the_older_blocks_calls_keep_their_lines``);
+    Nemotron's own layer has no ``w_gate`` to multiply by."""
+    import importlib
+
+    from ray_tpu.models import moe, nemotron
+
+    def lowered(cfg, init, rows=8):
+        p = next(layer.get("mlp", layer.get("mix")) for layer in init(
+            cfg, jax.random.PRNGKey(0))["layers"]
+            if "router" in layer.get("mlp", layer.get("mix")))
+        x = jnp.zeros((2, rows, cfg.d_model), cfg.compute_dtype)
+        return jax.jit(lambda p, x: moe.moe(cfg, p, x)).lower(p, x).as_text()
+
+    mod = importlib.import_module(f"ray_tpu.models.{block}")
+    config = next(v for k, v in vars(mod).items() if k.endswith("Config"))
+    older = config.tiny()
+    rows = 8
+    if block == "mimo":  # (a sixteenth held: a capacity at 512 rows)
+        older = config.tiny(n_experts=32, held_experts=(0, 2))
+        rows = 512
+        assert moe.compact_rows(older, 2 * rows * older.top_k) is not None
+    now = lowered(older, mod.init_params, rows)
+    new = nemotron.NemotronConfig.tiny()
+    assert "w_gate" not in nemotron.init_params(
+        new, jax.random.PRNGKey(0))["layers"][1]["mix"]
+    assert lowered(new, nemotron.init_params) != now
+    monkeypatch.setattr(moe, "_held_part", _held_part_before_pr_70)
+    monkeypatch.setattr(moe, "_held_part_once", jax.jit(
+        _held_part_before_pr_70, static_argnums=(0, 1, 2)))
+    # (the jitted function's name is in the text: the parent's was
+    # ``_held_part`` too)
+    was = lowered(older, mod.init_params, rows).replace(
+        "_held_part_before_pr_70", "_held_part")
+    assert was == now
+
+
+def test_the_older_blocks_calls_keep_their_lines():
+    """The expert kernel's Mosaic module carries the file, line and
+    column of every call on the way to it, and the compile cache's key
+    holds that module: a line added above a call in ``models/moe.py`` or
+    ``ops/grouped_matmul.py`` makes every older expert cell compile its
+    programs again. So what PR 70 added stands at the files' ends, and
+    the calls an older block's program passes through are where PR 69
+    had them (``git show 0d27b89``: line, column)."""
+    import inspect
+
+    from ray_tpu.models import moe
+    from ray_tpu.ops import grouped_matmul as gm
+
+    def at(module, text):
+        lines = inspect.getsource(module).splitlines()
+        found = [(i + 1, line.index(text)) for i, line in enumerate(lines)
+                 if text in line]
+        assert found, text
+        return found[0]  # (the file's end may hold the text again)
+
+    for text, where in (
+            ("gate = experts(rows, w_gate)", (271, 4)),
+            ("up = experts(rows, w_up)", (272, 4)),
+            ("y = experts(gated(gate, up", (273, 4)),
+            ("out = _held_part(cfg, grouped_matmul, None", (335, 12)),
+            ("lambda: _held_part_once(cfg, grouped_matmul, c,", (340, 16)),
+            ("lambda: _held_part_once(cfg, grouped_matmul, None", (341, 16)),
+            ('y = moe(cfg, p["mlp"], x, aux)', (368, 4)),
+            ("carry, outs = jax.lax.scan(step, carry", (436, 4))):
+        assert at(moe, text) == where, text
+    for text, where in (
+            ("acc = jax.lax.dot_general(lhs_ref[...], rhs_ref[...]",
+             (150, 8)),
+            ("return _gmm(_pad_rows(lhs, m_padded), rhs", (280, 4)),
+            ("return (_forward(lhs, rhs, group_sizes, tm, tn, interpret),",
+             (285, 4)),
+            ("return _gmm_kernel(lhs, rhs, group_sizes, tm, tn", (334, 8)),
+            ("return _forward(lhs, rhs, group_sizes, tm, tn, interpret, "
+             "layer)", (335, 4))):
+        assert at(gm, text) == where, text

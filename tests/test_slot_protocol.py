@@ -22,7 +22,8 @@ import pytest
 
 from ray_tpu.models import decode_engine as de
 from ray_tpu.models import (dots, exaone, glm_dsa, glm_next, granite,
-                            instella, lfm2, ling, llama, mimo, moe, solar)
+                            instella, lfm2, ling, llama, mimo, moe, nemotron,
+                            solar)
 from ray_tpu.models.decode_engine import RaggedDecoder
 from ray_tpu.models.slots import Slots
 
@@ -41,6 +42,7 @@ BLOCKS = {
     "glm_dsa": (glm_dsa, glm_dsa.GlmDsaConfig.tiny),
     "glm_next": (glm_next, glm_next.GlmNextConfig.tiny),
     "lfm2": (lfm2, lfm2.Lfm2Config.tiny),
+    "nemotron": (nemotron, nemotron.NemotronConfig.tiny),
 }
 # the blocks whose step counts what its indexers chose besides the
 # routing: their slots state ``step_counters`` of their own
@@ -216,7 +218,7 @@ def test_the_unrolled_blocks_share_one_copy(name):
 
 
 _BLOCK_NAMES = {"llama", "ling", "exaone", "instella", "solar", "mimo",
-                "granite", "dots", "glm", "glmdsa", "lfm2"}
+                "granite", "dots", "glm", "glmdsa", "lfm2", "nemotron"}
 
 
 def _names_a_block(word: str) -> bool:
